@@ -53,63 +53,62 @@
 #                   (fmtrace --anatomy; needs trace_spans = true)
 #   make clean
 
-CXX ?= g++
-CXXFLAGS ?= -O3 -march=native -std=c++17 -shared -fPIC -pthread
+# The parser binary carries its build key in its name
+# (_parser.<hash of source, flags, CPU>.so; fast_tffm_tpu/data/cparser.py),
+# so the loader's own builder IS the build rule: make and a first run
+# cannot disagree about the flags or the name, and a binary copied in
+# from another machine is never the one that gets loaded.
+all: parser
 
-SO := fast_tffm_tpu/data/_parser.so
-SRC := fast_tffm_tpu/data/_parser.cc
+parser:
+	python -m fast_tffm_tpu.data.cparser
 
-all: $(SO)
-
-$(SO): $(SRC)
-	$(CXX) $(CXXFLAGS) -o $@ $<
-
-test: $(SO)
+test: parser
 	python -m pytest tests/ -q
 
-bench: $(SO)
+bench: parser
 	python bench.py
 
-bench-host: $(SO)
+bench-host: parser
 	JAX_PLATFORMS=cpu python bench.py --host-sweep
 
-bench-predict: $(SO)
+bench-predict: parser
 	python bench.py --predict
 
-bench-vocab: $(SO)
+bench-vocab: parser
 	python bench.py --vocab
 
-bench-wire: $(SO)
+bench-wire: parser
 	python bench.py --wire
 
-bench-memory: $(SO)
+bench-memory: parser
 	JAX_PLATFORMS=cpu python bench.py --memory
 
-bench-fleet: $(SO)
+bench-fleet: parser
 	JAX_PLATFORMS=cpu python bench.py --fleet
 
 lint:
 	python -m tools.fmlint --profile --json-out .fmlint_cache/findings.json
 
-chaos: $(SO)
+chaos: parser
 	JAX_PLATFORMS=cpu python -m tools.fmchaos
 
-stream-soak: $(SO)
+stream-soak: parser
 	JAX_PLATFORMS=cpu python -m tools.fmchaos stream-soak stream-truncate
 
-serve: $(SO)
+serve: parser
 	python run_tffm.py serve sample.cfg
 
-serve-soak: $(SO)
+serve-soak: parser
 	JAX_PLATFORMS=cpu python -m tools.fmchaos serve-soak
 
-slo-soak: $(SO)
+slo-soak: parser
 	JAX_PLATFORMS=cpu python -m tools.fmchaos slo-soak
 
-grow-soak: $(SO)
+grow-soak: parser
 	JAX_PLATFORMS=cpu python -m tools.fmchaos kill-then-grow grow-joiner-dies
 
-bench-multihost: $(SO)
+bench-multihost: parser
 	JAX_PLATFORMS=cpu python bench.py --multihost
 
 TOLERANCE ?= 0.85
@@ -121,6 +120,6 @@ anatomy:
 	python -m tools.fmtrace --anatomy $(METRICS) $(wildcard $(METRICS).p*)
 
 clean:
-	rm -f $(SO)
+	rm -f fast_tffm_tpu/data/_parser*.so
 
-.PHONY: all test bench bench-host bench-predict bench-vocab bench-wire bench-memory bench-fleet bench-multihost bench-diff anatomy lint chaos stream-soak serve serve-soak slo-soak grow-soak clean
+.PHONY: all parser test bench bench-host bench-predict bench-vocab bench-wire bench-memory bench-fleet bench-multihost bench-diff anatomy lint chaos stream-soak serve serve-soak slo-soak grow-soak clean

@@ -1271,6 +1271,14 @@ class CheckpointState:
                     policy=self._retry, op="checkpoint_restore"))
         except (ValueError, KeyError, OSError) as e:
             return None, e
+        except Exception as e:
+            # orbax 0.11.32 re-raises a tensorstore read failure as a
+            # bare Exception chained from the ValueError: the same
+            # torn-step signature one link down. Anything else (a
+            # programming error) propagates.
+            if _caused_by(e, (ValueError, KeyError, OSError)):
+                return None, e
+            raise
 
     def _restore_host_staged(self, s: int, template):
         """Restore step ``s`` with array leaves deserialized to host
@@ -1341,6 +1349,18 @@ class CheckpointState:
             self._flush_pending_manifest()
         finally:
             self._mngr.close()
+
+
+def _caused_by(e: BaseException, classes) -> bool:
+    """Whether any link of ``e``'s explicit ``raise ... from`` chain is
+    one of ``classes``."""
+    seen = set()
+    while e is not None and id(e) not in seen:
+        if isinstance(e, classes):
+            return True
+        seen.add(id(e))
+        e = e.__cause__
+    return False
 
 
 def _restore_tolerating_legacy_epoch(template, do_restore):

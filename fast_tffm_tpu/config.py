@@ -110,12 +110,12 @@ class FmConfig:
     # training. 0 = auto: measured from the data at startup
     # (data/pipeline.probe_uniq_bucket). Overfull batches spill safely.
     uniq_bucket: int = 0
-    # "auto" = the measured regime matrix (ops/kernel_choice.py,
-    # BASELINE.md "Kernel-choice matrix"): the fused Pallas kernel
-    # exactly where it measured faster (2nd-order FM on TPU, device
-    # dedup, bucket width >= 64), XLA everywhere else — resolved per
-    # bucket at trace time. Explicit values always win; re-measure on
-    # new hardware with tools/kernel_probe.py.
+    # "auto" = the regime matrix in ops/kernel_choice.py (taken on an
+    # earlier device, unverified on the v5e — ROADMAP D4): the fused
+    # Pallas kernel for 2nd-order FM on TPU with device dedup and
+    # bucket width >= 64, XLA everywhere else — resolved per bucket at
+    # trace time. Explicit values always win; re-measure with
+    # tools/kernel_probe.py.
     kernel: str = "auto"            # "auto" | "xla" | "pallas"
     # Where the per-batch unique-id pass runs. "host": the pipeline
     # dedups and ships (uniq_ids, local_idx) — required by mesh,

@@ -7,14 +7,26 @@ shared object built from ``_parser.cc`` with g++ on first use (no TF/pybind
 dependency; see SURVEY §7 layer 2). ``parse_lines_fast`` matches
 ``parser.parse_lines``'s contract bit-for-bit (golden tests enforce it).
 
-If the extension cannot be built/loaded, callers fall back to the Python
-parser (pipeline._parse_block).
+The binary is built with ``-march=native``, so it is only ever loaded
+under a name that carries its build key: a hash of the source, the
+compiler flags and this CPU's identity (``artifact_path``). A binary
+copied in with the tree from another CPU, or left over from an older
+source, has another name and is never dlopen'ed; the loader builds its
+own beside it. Binaries under other keys are left where they are (on a
+shared tree another host's CPU may own them); ``make clean`` removes
+them all.
+
+If the extension cannot be built/loaded, ``available()`` says so ONCE,
+at WARNING, and callers fall back to the Python parser.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
+import platform
 import subprocess
 import threading
 from typing import Optional, Sequence
@@ -22,6 +34,10 @@ from typing import Optional, Sequence
 import numpy as np
 
 from fast_tffm_tpu.data.parser import ParsedBlock, ParseError
+
+# The run logger by NAME (utils.logging.get_logger configures it): this
+# module stays importable without jax, which utils/ pulls in.
+_log = logging.getLogger("fast_tffm_tpu")
 
 
 def _tel():
@@ -35,26 +51,64 @@ def _tel():
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "_parser.cc")
+# The artifact's stem: the loaded file is ``_parser.<build key>.so``.
 _SO = os.path.join(_HERE, "_parser.so")
+_CXXFLAGS = ("-O3", "-march=native", "-std=c++17", "-shared", "-fPIC",
+             "-pthread")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _load_error: Optional[str] = None
 
 
-def _build() -> None:
+def _cpu_identity() -> str:
+    """What ``-march=native`` resolved against on this machine: the
+    first processor's model and ISA feature lines, plus the machine
+    type (the whole answer where /proc/cpuinfo does not exist)."""
+    keys = ("model name", "flags", "Features", "CPU implementer",
+            "CPU part")
+    found = {}
+    try:
+        with open("/proc/cpuinfo") as fh:
+            for line in fh:
+                k, _, v = line.partition(":")
+                k = k.strip()
+                if k in keys and k not in found:
+                    found[k] = " ".join(sorted(v.split()))
+                if not line.strip() and found:
+                    break  # end of the first processor block
+    except OSError:
+        pass
+    return platform.machine() + "|" + "|".join(
+        f"{k}={found[k]}" for k in keys if k in found)
+
+
+def build_key() -> str:
+    """Hash of everything the binary depends on: source bytes, compiler
+    flags, CPU identity."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as fh:
+        h.update(fh.read())
+    h.update(" ".join(_CXXFLAGS).encode())
+    h.update(_cpu_identity().encode())
+    return h.hexdigest()[:16]
+
+
+def artifact_path() -> str:
+    """``_parser.<build key>.so`` — the only name the loader opens and
+    the Makefile builds (``python -m fast_tffm_tpu.data.cparser``)."""
+    stem, ext = os.path.splitext(_SO)
+    return f"{stem}.{build_key()}{ext}"
+
+
+def _build(out: str) -> None:
     # Build to a temp name and os.replace: atomic for concurrent
-    # processes, and never rewrites a live mmap in place. NOTE this does
-    # NOT make a same-path retry dlopen see the new library — glibc
-    # dedups by pathname before stat'ing the inode — which is why
-    # _load's ABI-mismatch retry opens the rebuilt file through a
-    # one-off path.
-    tmp = f"{_SO}.tmp.{os.getpid()}"
-    cmd = ["g++", "-O3", "-march=native", "-std=c++17", "-shared", "-fPIC",
-           "-pthread", "-o", tmp, _SRC]
+    # processes, and never rewrites a live mmap in place.
+    tmp = f"{out}.tmp.{os.getpid()}"
+    cmd = ["g++", *_CXXFLAGS, "-o", tmp, _SRC]
     try:
         subprocess.run(cmd, check=True, capture_output=True, text=True)
-        os.replace(tmp, _SO)
+        os.replace(tmp, out)
     finally:
         if os.path.exists(tmp):
             os.unlink(tmp)
@@ -65,28 +119,26 @@ def _build() -> None:
 _ABI_VERSION = 7
 
 
-def _open_checked(path: Optional[str] = None) -> Optional[ctypes.CDLL]:
+def _open_checked(path: str) -> ctypes.CDLL:
     """dlopen the .so and verify every symbol exists AND the compiled-in
-    ABI version matches this wrapper. Returns None when the binary is
-    stale — wrong version OR missing symbols (a pre-versioning .so has
-    no fm_abi_version at all) — so the caller can rebuild once."""
-    lib = ctypes.CDLL(path or _SO)
+    ABI version matches this wrapper. The binary was built from THIS
+    source (its name says so), so a mismatch means wrapper and source
+    disagree and no rebuild can help: RuntimeError."""
+    lib = ctypes.CDLL(path)
     try:
-        lib.fm_abi_version
-        lib.fm_auto_threads
-        lib.fm_parse_block
-        lib.fm_dedup_ids
-        lib.fm_scan_examples
-        lib.fm_bb_new
-        lib.fm_bb_feed
-        lib.fm_bb_finish
-        lib.fm_bb_free
+        for sym in ("fm_abi_version", "fm_auto_threads", "fm_parse_block",
+                    "fm_dedup_ids", "fm_scan_examples", "fm_bb_new",
+                    "fm_bb_feed", "fm_bb_finish", "fm_bb_free"):
+            getattr(lib, sym)
+        lib.fm_abi_version.restype = ctypes.c_int64
+        lib.fm_abi_version.argtypes = []
+        ok = lib.fm_abi_version() == _ABI_VERSION
     except AttributeError:
-        return None  # stale binary predating a symbol: rebuildable
-    lib.fm_abi_version.restype = ctypes.c_int64
-    lib.fm_abi_version.argtypes = []
-    if lib.fm_abi_version() != _ABI_VERSION:
-        return None
+        ok = False
+    if not ok:
+        raise RuntimeError(
+            f"{path} is a stale ABI: _parser.cc and the wrapper's "
+            f"_ABI_VERSION = {_ABI_VERSION} disagree")
     return lib
 
 
@@ -98,42 +150,23 @@ def _load() -> ctypes.CDLL:
         if _load_error is not None:
             raise RuntimeError(_load_error)
         try:
-            if not os.path.exists(_SO) or (
-                    os.path.exists(_SRC)
-                    and os.path.getmtime(_SRC) > os.path.getmtime(_SO)):
-                if not os.path.exists(_SRC):
-                    raise FileNotFoundError(_SRC)
-                _build()
-            lib = _open_checked()
-            if lib is None:
-                # Stale binary (ABI drift or missing symbols) with
-                # source present: rebuild once and retry (an
-                # mtime-preserving deploy can leave a stale .so "newer"
-                # than the source; mtime/symbol checks alone can't catch
-                # changed argument layouts — silent corruption).
-                if not os.path.exists(_SRC):
-                    raise RuntimeError(
-                        f"{_SO} is a stale ABI and no source is present "
-                        "to rebuild")
-                _build()
-                # dlopen dedups by PATHNAME before inode: re-opening _SO
-                # would hand back the stale mapping we just probed. Open
-                # the rebuilt library through a one-off path instead
-                # (the mapping survives the unlink).
-                import shutil
-                reload_path = f"{_SO}.reload.{os.getpid()}"
-                shutil.copy2(_SO, reload_path)
-                try:
-                    lib = _open_checked(reload_path)
-                finally:
-                    os.unlink(reload_path)
-                if lib is None:
-                    raise RuntimeError(
-                        f"{_SO} is still a stale ABI after rebuild")
-        except (OSError, FileNotFoundError, AttributeError,
-                subprocess.CalledProcessError, RuntimeError) as e:
-            _load_error = f"C++ parser unavailable: {e}"
+            path = artifact_path()
+            built = not os.path.exists(path)
+            if built:
+                _build(path)
+            lib = _open_checked(path)
+        except (OSError, subprocess.CalledProcessError,
+                RuntimeError) as e:
+            detail = (e.stderr or "").strip()[-500:] if isinstance(
+                e, subprocess.CalledProcessError) else ""
+            _load_error = f"C++ parser unavailable: {e} {detail}".strip()
+            _log.warning(
+                "%s — host parsing falls back to the PYTHON parser, "
+                "far below the C++ rate", _load_error)
             raise RuntimeError(_load_error)
+        _log.info(
+            "host parser: C++ %s (%s)", os.path.basename(path),
+            "built here" if built else "build key matched")
         lib.fm_auto_threads.restype = ctypes.c_int
         lib.fm_auto_threads.argtypes = []
         lib.fm_parse_block.restype = ctypes.c_int
@@ -193,6 +226,8 @@ def _load() -> ctypes.CDLL:
 
 
 def available() -> bool:
+    """The one C++-or-Python decision, taken (and logged) once per
+    process at first use; every routing site asks here."""
     try:
         _load()
         return True
@@ -469,3 +504,10 @@ def dedup_ids_fast(ids: np.ndarray):
     inverse = np.empty(n, dtype=np.int32)
     n_uniq = lib.fm_dedup_ids(ids, n, uniq, inverse)
     return uniq[:n_uniq].copy(), inverse
+
+
+if __name__ == "__main__":
+    # The Makefile's build rule: build (if absent) and load the keyed
+    # artifact exactly as a run would, then print its path.
+    _load()
+    print(artifact_path())

@@ -428,14 +428,16 @@ def numpy_ffm_train_predict(train_batches, test_batches, vocab: int,
 
 def parse_file_blocks(path: str, vocab: int, batch_size: int):
     """Parse a libsvm file into CSR blocks via the (golden-tested) fast
-    parser — the shared input both trainers consume."""
-    from fast_tffm_tpu.data.pipeline import _parse_block
+    parser — the shared input both trainers consume. Raises when the
+    C++ extension is unusable: an oracle is not worth a silent detour
+    through another parser. Imports nothing that imports jax, so the
+    chip smoke's parent can run it while a child owns the chip."""
     from fast_tffm_tpu.data.cparser import parse_lines_fast
-    from fast_tffm_tpu.config import FmConfig
-    # _parse_block falls back to the Python parser itself if the C++
-    # extension turns out to be unusable at call time.
-    cfg = FmConfig(vocabulary_size=vocab, hash_feature_id=True,
-                   max_features_per_example=48)
+
+    def block(lines):
+        return parse_lines_fast(lines, vocab, hash_feature_id=True,
+                                max_features_per_example=48)
+
     out = []
     with open(path) as fh:
         buf = []
@@ -443,8 +445,8 @@ def parse_file_blocks(path: str, vocab: int, batch_size: int):
             if line.strip():
                 buf.append(line)
             if len(buf) == batch_size:
-                out.append(_parse_block(buf, cfg, parse_lines_fast))
+                out.append(block(buf))
                 buf = []
         if buf:
-            out.append(_parse_block(buf, cfg, parse_lines_fast))
+            out.append(block(buf))
     return out

@@ -407,8 +407,7 @@ def _predict_verdict(att: Dict[str, Any]) -> str:
     saturating the wall is the bound, BY NAME. Streams without the
     stage counters (pre-refactor files) fall back to the fetch-depth
     heuristic: the output-order buffer (ChunkedFetcher) backs up
-    exactly when D2H transfer lags scoring (BASELINE.md "Predict-path
-    rate")."""
+    exactly when D2H transfer lags scoring."""
     rate = att.get("predict_examples_per_sec")
     base = (f"predict: {rate:,.0f} examples/sec over "
             f"{att['predict_examples']:,.0f} examples"
@@ -933,7 +932,8 @@ def memory_table(summary: Dict[str, Any]) -> Optional[Dict[str, Any]]:
     if g.get("mem/live_bytes") is None and g.get("mem/peak_bytes") is None:
         return None
     totals = ("mem/live_bytes", "mem/peak_bytes", "mem/capacity_bytes",
-              "mem/host_live_bytes", "mem/device_in_use_bytes")
+              "mem/host_live_bytes", "mem/device_in_use_bytes",
+              "mem/device_peak_bytes")
     owners = {k[len("mem/"):-len("_bytes")]: v
               for k, v in g.items()
               if k.startswith("mem/") and k.endswith("_bytes")

@@ -46,7 +46,7 @@ from __future__ import annotations
 import contextlib
 import os
 import threading
-from typing import Any, Dict, Optional
+from typing import Any, Dict, List, Optional
 
 # The injected-capacity seam (tests, fmchaos oom-pressure): when set,
 # device_memory_stats() reports this many bytes as the capacity and
@@ -81,8 +81,19 @@ def table_bytes(cfg=None, *, rows: Optional[int] = None,
 
 # --- the memory_stats seam (fmlint R018) -----------------------------------
 
+def _stats_of(dev) -> Optional[Dict[str, Any]]:
+    """One device's ``memory_stats()``, None on the CPU backend by
+    policy. On an accelerator a failing ``memory_stats()`` PROPAGATES:
+    swallowing it would turn a broken runtime into "capacity UNKNOWN"
+    and the pre-flight into a no-op exactly where it matters."""
+    if dev.platform == "cpu":
+        return None
+    return dev.memory_stats() or None
+
+
 def device_memory_stats() -> Optional[Dict[str, Any]]:
-    """The one ``memory_stats()`` call site in the tree (fmlint R018).
+    """The ``memory_stats()`` seam (fmlint R018: no call site outside
+    this module).
 
     Returns the first local device's stats dict (``bytes_limit``,
     ``bytes_in_use``, ...) or None when the backend reports none. The
@@ -95,15 +106,19 @@ def device_memory_stats() -> Optional[Dict[str, Any]]:
     if env:
         return {"bytes_limit": int(env),
                 "bytes_in_use": LEDGER.live_bytes()}
-    try:
-        import jax
-        dev = jax.local_devices()[0]
-        if dev.platform == "cpu":
-            return None
-        stats = dev.memory_stats()
-    except Exception:  # noqa: BLE001 - no backend/device: unmeasured
+    import jax
+    return _stats_of(jax.local_devices()[0])
+
+
+def local_bytes_in_use() -> Optional[List[int]]:
+    """``bytes_in_use`` of EVERY local device, in device order — the
+    mesh paths' check that a row-sharded table did not land on the
+    first chip. None where unmeasured (the CPU backend)."""
+    import jax
+    all_stats = [_stats_of(dev) for dev in jax.local_devices()]
+    if any(not st or st.get("bytes_in_use") is None for st in all_stats):
         return None
-    return stats or None
+    return [st["bytes_in_use"] for st in all_stats]
 
 
 def device_capacity_bytes() -> Optional[int]:
@@ -232,6 +247,11 @@ def ledger_gauges() -> Dict[str, float]:
         in_use = stats.get("bytes_in_use")
         if in_use is not None:
             rows["mem/device_in_use_bytes"] = float(in_use)
+        peak_in_use = stats.get("peak_bytes_in_use")
+        if peak_in_use is not None:
+            # The runtime's own high-water mark, beside the ledger's:
+            # what the planner's resident bytes are checked against.
+            rows["mem/device_peak_bytes"] = float(peak_in_use)
     return rows
 
 
@@ -466,9 +486,17 @@ def preflight_capacity(cfg, kind: str = "train") -> None:
     resident bytes exceed the device capacity — the planner's
     breakdown plus the exact what-if invocation to explore fixes,
     instead of an XLA OOM minutes into bring-up. No-op when the
-    backend reports no capacity (the CPU container)."""
+    backend reports no capacity (the CPU container) — and the log line
+    says which it was, so a pre-flight that checked nothing is on
+    record."""
     p = plan(cfg, kind)
     cap = p.get("capacity_bytes")
+    from fast_tffm_tpu.utils.logging import get_logger
+    get_logger().info(
+        "capacity pre-flight (%s): predicted resident %d bytes, device "
+        "capacity %s", kind, p["total_bytes"],
+        f"{cap} bytes" if cap else "UNKNOWN (backend reports none; "
+        "nothing checked)")
     if not cap or p["total_bytes"] <= cap:
         return
     raise ValueError(

@@ -93,7 +93,9 @@ def _git_rev() -> Optional[str]:
 def run_meta(cfg, kind: str, process_index: Optional[int] = None,
              process_count: Optional[int] = None) -> Dict[str, Any]:
     """Run metadata stamped into every metrics event: config hash,
-    backend, device/process topology, git rev. ``process_index`` /
+    backend, the first device's platform and kind as jax reports them
+    (what a reader needs to tell a chip run from a CPU run),
+    device/process topology, git rev. ``process_index`` /
     ``process_count`` override jax's view — the train driver creates
     telemetry BEFORE the cluster join (so bring-up failures land in
     the stream), when jax would still claim a 1-process local world on
@@ -104,10 +106,13 @@ def run_meta(cfg, kind: str, process_index: Optional[int] = None,
     carry the real topology.)"""
     import os
     import jax
+    dev = jax.devices()[0]
     return {
         "kind": kind,
         "config_hash": config_hash(cfg) if cfg is not None else None,
         "backend": jax.default_backend(),
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
         "device_count": jax.device_count(),
         "process_index": (jax.process_index() if process_index is None
                           else int(process_index)),

@@ -1,9 +1,12 @@
 """Regime-aware kernel selection for the 2nd-order FM scorer.
 
 ``kernel = auto`` used to resolve unconditionally to the fused Pallas
-kernel on TPU. The measured matrix (BASELINE.md "Kernel-choice matrix",
-same-window interleaved pairs on the real chip, k=8, B=8192) says the
-winner depends on (L, dedup), not the backend alone:
+kernel on TPU. The matrix below is the rule in force, but it was taken
+on an EARLIER device (same-window interleaved pairs, k=8, B=8192; the
+record was removed in PR 21) and is UNVERIFIED on the v5e: PR 21 only
+established that the kernel compiles under Mosaic there and matches
+the XLA path's loss. ROADMAP D4 re-measures it or deletes the kernel.
+It says the winner depends on (L, dedup), not the backend alone:
 
     L   dedup    Pallas  XLA    Pallas/XLA
     48  device   302M    450M   0.67x
@@ -27,8 +30,8 @@ measured single-chip — the sharded-assembly regime itself has no
 direct measurement — so a cluster operator who measures otherwise can
 still force ``kernel = pallas`` (it runs under shard_map).
 
-The matrix is this chip's; on other hardware re-measure with
-``python tools/kernel_probe.py`` (interleaved A/B at your shapes) and,
+Re-measure with ``python tools/kernel_probe.py`` (interleaved A/B at
+your shapes) and,
 if the regime boundary moved, override per job with ``kernel =
 pallas|xla`` — the config knob always beats the matrix.
 """

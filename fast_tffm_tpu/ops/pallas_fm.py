@@ -26,50 +26,82 @@ The backward kernel recomputes ``s`` from inputs instead of saving
 residuals — one extra VMEM reduction in exchange for zero HBM residual
 traffic (the rematerialisation trade SURVEY §7 calls for).
 
-Falls back to interpret mode off-TPU so the same code path is testable
-on the CPU mesh (tests/test_pallas_fm.py pins parity vs the XLA path).
+Lowering mode is decided from the backend, never inferred from "not
+tpu": Mosaic on ``tpu``, the Pallas interpreter on ``cpu`` (the test
+path; tests/test_pallas_fm.py pins parity vs the XLA path), and an
+error anywhere else. The mode is logged once per process.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from fast_tffm_tpu.utils.logging import get_logger
+
+
+@functools.lru_cache(maxsize=None)
+def _interpret_on(backend: str) -> bool:
+    """Whether the kernel runs in the Pallas interpreter on
+    ``backend``. Cached, so each mode is logged once per process."""
+    if backend == "tpu":
+        get_logger().info("pallas FM kernel: lowering through Mosaic "
+                          "(backend tpu)")
+        return False
+    if backend == "cpu":
+        get_logger().warning(
+            "pallas FM kernel: INTERPRET mode on the cpu backend — a "
+            "correctness path for tests, never a fast path")
+        return True
+    raise RuntimeError(
+        f"the Pallas FM kernel lowers through Mosaic on tpu and runs "
+        f"interpreted on cpu; backend {backend!r} has neither — set "
+        "kernel = xla")
+
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    return _interpret_on(jax.default_backend())
 
 
 def _block_b(B: int, K: int, L: int) -> int:
-    """Largest power-of-two divisor of B keeping one v block (with its
-    lane padding to 128) within a ~2 MB VMEM budget — the kernels hold a
-    handful of block-sized temporaries and Mosaic double-buffers blocks
-    against the 16 MB scoped-vmem limit."""
+    """Rows of B per grid step: the largest divisor of B that keeps one
+    v block (lane-padded to 128) within a ~2 MB VMEM budget. The 2-D
+    w/x/g/out blocks carry bB on the sublane axis, where Mosaic takes a
+    multiple of 8 or the whole array, so the divisor is a multiple of 8
+    unless all of B fits one block."""
     lanes = -(-L // 128) * 128
     # K rounds UP to the 8-sublane tile (not max(K, 8)): Mosaic pads the
     # sublane axis, so e.g. K=9 occupies 16 sublanes — counting 9 would
     # understate the real block by up to ~78% and blow the budget for
     # K in 9..15 at large L.
     sublanes = -(-K // 8) * 8
-    bytes_per_row = sublanes * lanes * 4
-    budget = 2 << 20
-    b = 1
-    while B % (b * 2) == 0 and (b * 2) * bytes_per_row <= budget:
-        b *= 2
-    return b
+    rows = max(1, (2 << 20) // (sublanes * lanes * 4))
+    if B <= rows:
+        return B
+    for b in range(rows - rows % 8, 0, -8):
+        if B % b == 0:
+            return b
+    raise ValueError(
+        f"kernel = pallas cannot block batch_size {B} at K={K}, L={L}: "
+        f"no divisor of {B} up to {rows} rows is a multiple of 8; use a "
+        "batch_size divisible by 8, or kernel = xla")
 
 
 def _fwd_kernel(v_ref, w_ref, x_ref, out_ref):
-    v = v_ref[...]                      # [bB, K, L]
-    w = w_ref[...]                      # [bB, L]
-    x = x_ref[...]                      # [bB, L]
+    # keepdims throughout: Mosaic has no layout for the rank-1 [bB]
+    # intermediates a plain sum leaves (B = 1 failed to compile).
+    v = v_ref[...]                                  # [bB, K, L]
+    w = w_ref[...]                                  # [bB, L]
+    x = x_ref[...]                                  # [bB, L]
     z = v * x[:, None, :]
-    s = jnp.sum(z, axis=-1)             # [bB, K]
-    q = jnp.sum(z * z, axis=-1)         # [bB, K]
-    linear = jnp.sum(w * x, axis=-1)    # [bB]
-    pair = 0.5 * jnp.sum(s * s - q, axis=-1)
-    out_ref[...] = (linear + pair)[:, None]
+    s = jnp.sum(z, axis=-1, keepdims=True)          # [bB, K, 1]
+    q = jnp.sum(z * z, axis=-1, keepdims=True)      # [bB, K, 1]
+    pair = 0.5 * jnp.sum(s * s - q, axis=1)         # [bB, 1]
+    linear = jnp.sum(w * x, axis=-1, keepdims=True)  # [bB, 1]
+    out_ref[...] = linear + pair
 
 
 def _bwd_kernel(v_ref, w_ref, x_ref, g_ref, dv_ref, dw_ref, dx_ref):
@@ -168,23 +200,8 @@ def fm_batch_scores_pallas(params: jax.Array, local_idx: jax.Array,
     # check_vma=False: pallas_call declares no varying-mesh-axes rule;
     # the body is per-example with zero collectives, so the manual specs
     # are the whole contract.
-    fn = _shard_map(
-        fm_scores_pallas, mesh,
+    fn = jax.shard_map(
+        fm_scores_pallas, mesh=mesh,
         in_specs=(P("data", None, None), P("data", None), P("data", None)),
-        out_specs=P("data"))
+        out_specs=P("data"), check_vma=False)
     return fn(v, w, vals)
-
-
-def _shard_map(f, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across the API move: top-level (new jax, where
-    the replication-check kwarg is ``check_vma``) or
-    ``jax.experimental.shard_map`` (older installs, where it is
-    ``check_rep``). Both flags express the same opt-out: pallas_call
-    declares no replication rule, so the manual specs are the whole
-    contract."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)

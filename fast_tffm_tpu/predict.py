@@ -10,8 +10,9 @@ Both drivers run ONE continuous batch stream across ALL predict files
 (fast_tffm_tpu/scoring.py): file N's disk write, file N+1's D2H, file
 N+2's scoring, and file N+3's parse all overlap — no per-file fetcher
 drain, no per-file warmup, no per-file telemetry barrier (README
-"Predict path"; the pre-refactor per-file loop was the 15x
-predict-vs-train gap BENCH_r05 measured).
+"Predict path"; the pre-refactor per-file loop was most of a 15x
+predict-vs-train gap on an earlier device — not re-measured on the
+v5e, ROADMAP S4).
 """
 
 from __future__ import annotations

@@ -340,9 +340,12 @@ def score_sweep(cfg: FmConfig, table, files: Sequence[str],
     ScoreWriter/accumulator, both safe there. No per-file warmup, no
     per-file fetcher drain: the compiled scorer and the D2H overlap
     worker live across every boundary, which is where the 15x
-    predict-vs-train gap lived (BENCH_r05, ISSUE 10)."""
+    predict-vs-train gap lived (ISSUE 10; ROADMAP S4 re-measures)."""
     files = list(files)  # consumed twice (span field + iterator)
     scorer = CompiledScorer(cfg, mesh=mesh, backend=backend)
+    from fast_tffm_tpu.models.fm import regime_line
+    from fast_tffm_tpu.utils.logging import get_logger
+    get_logger().info("predict regime: %s", regime_line(scorer.spec, cfg))
     marks = FileMarks()
     demux = ScoreDemux(marks, on_file)
     fetcher = ChunkedFetcher(
@@ -379,8 +382,7 @@ def score_sweep(cfg: FmConfig, table, files: Sequence[str],
                     tel.count("predict/examples", batch.num_real)
                     # Output-order buffer: device score arrays held
                     # back so results land in input order — its depth
-                    # is the D2H backlog (BASELINE.md "Predict-path
-                    # rate").
+                    # is the D2H backlog.
                     tel.observe("predict/fetch_depth",
                                 fetcher.pending_depth,
                                 bounds=DEPTH_BUCKETS)

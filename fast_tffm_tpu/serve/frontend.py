@@ -156,10 +156,17 @@ def make_http_server(fm_server, port: int,
     return ScoreHTTPServer(fm_server, host, port)
 
 
-def run_serve(cfg) -> int:
+def run_serve(cfg, warmup: str = "sync") -> int:
     """The ``run_tffm.py serve <cfg>`` driver: load the published
     step, bind the HTTP front end, serve until SIGTERM/SIGINT, then
-    drain and close. Returns a process exit code."""
+    drain and close. Returns a process exit code.
+
+    ``warmup``: the single server compiles its shape ladder BEFORE it
+    binds (``sync``), so a program the device cannot compile ends the
+    process with the compiler's error instead of leaving a server that
+    is alive and never ready. A fleet replica passes ``background``
+    (serve/replica.py): its supervisor needs /healthz from the first
+    second and routes around a replica that is not ready."""
     import signal
     import threading
     from fast_tffm_tpu.serve.server import ScorerServer
@@ -181,14 +188,7 @@ def run_serve(cfg) -> int:
     httpd = None
     t = None
     try:
-        # Background warmup: the front end binds (and /healthz
-        # answers alive: true, ready: false) WHILE the shape ladder
-        # compiles, instead of the old behavior where a precompiling
-        # server was invisible to health checks and then answered as
-        # servable the instant it bound. The fleet supervisor
-        # restarts on alive and the proxy routes on ready, so both
-        # need the split from the first second of a replica's life.
-        server = ScorerServer(cfg, logger=logger, warmup="background")
+        server = ScorerServer(cfg, logger=logger, warmup=warmup)
         if not stop.is_set():
             httpd = make_http_server(server, cfg.serve_port,
                                      host=cfg.serve_host)

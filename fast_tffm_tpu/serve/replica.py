@@ -12,29 +12,11 @@ supervisor's terminate/reap sequence relies on.
 
 from __future__ import annotations
 
-import os
 import sys
 
 from fast_tffm_tpu.config import apply_env_overrides, load_config
-
-
-def _enable_compilation_cache() -> None:
-    """Same persistent-XLA-cache policy as run_tffm.py: a RESTARTED
-    replica re-warms its shape ladder from the cache in seconds
-    instead of recompiling the matrix — the difference between a
-    restart gap and a restart outage."""
-    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        return
-    path = os.path.join(os.path.expanduser("~"), ".cache",
-                        "fast_tffm_tpu", "jax_cache")
-    try:
-        os.makedirs(path, exist_ok=True)
-        import jax
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0)
-    except Exception:
-        pass  # cache is an optimization; never block the replica on it
+from fast_tffm_tpu.compile_cache import enable_compilation_cache
+from fast_tffm_tpu.utils.logging import get_logger
 
 
 def main(argv=None) -> int:
@@ -43,10 +25,16 @@ def main(argv=None) -> int:
         print("usage: python -m fast_tffm_tpu.serve.replica <cfg>",
               file=sys.stderr)
         return 2
-    _enable_compilation_cache()
     cfg = apply_env_overrides(load_config(argv[0]))
+    # A RESTARTED replica re-warms its shape ladder from the cache in
+    # seconds instead of recompiling the matrix — the difference
+    # between a restart gap and a restart outage.
+    enable_compilation_cache(get_logger(log_file=cfg.log_file or None))
     from fast_tffm_tpu.serve.frontend import run_serve
-    return run_serve(cfg)
+    # Background warm-up: the fleet supervisor restarts on alive and
+    # the proxy routes on ready, so a replica must answer /healthz
+    # (ready: false) from the first second of its life.
+    return run_serve(cfg, warmup="background")
 
 
 if __name__ == "__main__":

@@ -213,6 +213,9 @@ class ScorerServer:
         from fast_tffm_tpu.scoring import CompiledScorer
         self._scorer = CompiledScorer(cfg, dedup="device",
                                       serve_ladder=True)
+        from fast_tffm_tpu.models.fm import regime_line
+        self._logger.info("serve regime: %s",
+                          regime_line(self._scorer.spec, cfg))
         # The active wire mode, as gauges (README "Wire format"): the
         # serving flush inherits the packed path through the scorer's
         # encoder, and fmstat's attribution names the mode.

@@ -1,8 +1,9 @@
 """Wire-format layer: how a built batch crosses the host->device wall.
 
 ROADMAP item 2 named the next hard ceiling after the parallel host
-plane: ``h2d_only`` sits two orders of magnitude under ``device_only``
-(BENCH_r05: 4.1M vs 387M ex/s), so every end-to-end gain is gated on
+plane: ``h2d_only`` sat far under ``device_only`` on an earlier
+device (record removed in PR 21; ROADMAP S3/S5 re-measure both on the
+v5e), so every end-to-end gain is gated on
 bytes-per-example — and the pipeline already *measures* the lever
 (``padding-waste``, ``dedup-hit``, ``train/h2d_bytes``) without acting
 on it. This module acts on it:

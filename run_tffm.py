@@ -42,34 +42,8 @@ import os
 import sys
 
 from fast_tffm_tpu.config import apply_env_overrides, load_config
-
-
-def _enable_compilation_cache() -> None:
-    """Persistent XLA compilation cache across CLI invocations.
-
-    First compile of the train/score programs costs tens of seconds on
-    TPU; without a persistent cache every `run_tffm.py` process pays it
-    again (predict right after train recompiles everything; measured
-    49s -> 13s on the sample config). jax keys cache entries by
-    program/compiler fingerprint, so staleness is handled; an unusable
-    cache dir just disables itself.
-
-    An explicit JAX_COMPILATION_CACHE_DIR is left entirely to jax — it
-    honors the env var natively (including non-local URIs like gs://,
-    which a local makedirs would mangle)."""
-    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-        return
-    path = os.path.join(os.path.expanduser("~"), ".cache",
-                        "fast_tffm_tpu", "jax_cache")
-    try:
-        os.makedirs(path, exist_ok=True)
-        import jax
-        jax.config.update("jax_compilation_cache_dir", path)
-        # Cache everything, including sub-second compiles: the CLI's
-        # cost is dominated by many medium programs, not one giant one.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    except Exception:
-        pass  # cache is an optimization; never block the run on it
+from fast_tffm_tpu.compile_cache import enable_compilation_cache
+from fast_tffm_tpu.utils.logging import get_logger
 
 
 def _usage() -> int:
@@ -83,7 +57,6 @@ def main(argv=None) -> int:
         return _usage()
     mode, cfg_path = argv[0], argv[1]
     rest = argv[2:]
-    _enable_compilation_cache()
     cfg = load_config(cfg_path)
     # One-off per-process overrides without editing the config file:
     # FM_METRICS_FILE (the `metrics_file` knob's values; "auto" =
@@ -92,6 +65,9 @@ def main(argv=None) -> int:
     # for the timeline/health layer, and the serve-fleet knobs the
     # supervisor hands each replica (config.apply_env_overrides).
     cfg = apply_env_overrides(cfg)
+    # Config only, no jax client: the fleet supervisor below never
+    # opens the chip its replicas need.
+    enable_compilation_cache(get_logger(log_file=cfg.log_file or None))
 
     if mode == "serve":
         replicas = None

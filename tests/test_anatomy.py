@@ -365,7 +365,7 @@ def _run_compare(args):
 def test_bench_compare_flags_regressions(tmp_path):
     old = tmp_path / "old.json"
     new = tmp_path / "new.json"
-    # Wrapper form (BENCH_rNN.json): the parsed payload is the metric.
+    # Wrapper form: the parsed payload is the metric.
     old.write_text(json.dumps({
         "n": 1, "cmd": "python bench.py", "rc": 0,
         "parsed": {"metric": "examples_per_sec", "value": 1000.0,

@@ -1,5 +1,5 @@
 """Async checkpointing: periodic saves must not stall the train loop for
-the full serialization (VERDICT r2 item 7); final saves barrier."""
+the full serialization (round-2 review, item 7); final saves barrier."""
 
 import time
 

