@@ -14,6 +14,12 @@ serving replica re-warming its shape ladder).
   it is never a home directory, a temp name, a pid or a time.
 - Either way every program is cached, sub-second compiles included: the
   CLI's cost is many medium programs, not one giant one.
+- An entry is keyed by its operations' metadata too (jax leaves op
+  paths and source lines out of the key by default): an executable
+  carries the ``jax.named_scope`` paths it was compiled with into every
+  profiler trace, and a directory that outlives a change of the source
+  would otherwise hand back a program whose trace names the old code
+  (benchmarks/readers/scope_device_ms.py reads those paths).
 
 An unusable directory is an error, not a silently uncached run.
 
@@ -47,6 +53,7 @@ def enable_compilation_cache(logger=None) -> str:
             raise PermissionError(
                 f"compile cache directory {path} is not writable")
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     if logger is not None:
         n = cache_entries(path)
         logger.info("compile cache: %s (%s), %d programs at start (%s)",

@@ -28,7 +28,7 @@ from jax import lax
 from fast_tffm_tpu.config import FmConfig
 from fast_tffm_tpu.data.pipeline import DeviceBatch
 from fast_tffm_tpu.ops.interaction import (batch_reg, ffm_batch_scores,
-                                           fm_batch_scores)
+                                           fm_batch_scores, gather_rows)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -184,6 +184,13 @@ def loss_and_scores(spec: ModelSpec, gathered: jax.Array,
     """Weighted-mean data loss + batch-active L2 reg. Zero-weight padding
     examples drop out of both value and gradient."""
     scores = _scores(spec, gathered, local_idx, vals, fields, mesh=mesh)
+    with jax.named_scope("loss"):
+        return _loss_of_scores(spec, scores, gathered, labels, weights,
+                               uniq_ids), scores
+
+
+def _loss_of_scores(spec: ModelSpec, scores, gathered, labels, weights,
+                    uniq_ids) -> jax.Array:
     per = _per_example_loss(spec, scores, labels)
     # Exact-zero guard ONLY for the all-padding filler batch (sum(w)=0,
     # numerator 0 — the distributed lockstep's zero-weight filler). Any
@@ -199,7 +206,7 @@ def loss_and_scores(spec: ModelSpec, gathered: jax.Array,
     data_loss = jnp.where(nonzero, (per * weights).sum() / den, 0.0)
     reg = batch_reg(gathered, uniq_ids, spec.vocabulary_size,
                     spec.factor_lambda, spec.bias_lambda)
-    return data_loss + reg, scores
+    return data_loss + reg
 
 
 def _device_dedup(spec: ModelSpec, raw_idx: jax.Array):
@@ -210,12 +217,13 @@ def _device_dedup(spec: ModelSpec, raw_idx: jax.Array):
     (ids < vocab) so it sorts into the tail next to the fill slots —
     the same "padding slots hold pad_id" invariant the host path keeps.
     """
-    flat = raw_idx.ravel()
-    uniq, inv = jnp.unique(flat, size=flat.shape[0] + 1,
-                           fill_value=spec.vocabulary_size,
-                           return_inverse=True)
-    return (uniq.astype(jnp.int32),
-            inv.reshape(raw_idx.shape).astype(jnp.int32))
+    with jax.named_scope("dedup"):
+        flat = raw_idx.ravel()
+        uniq, inv = jnp.unique(flat, size=flat.shape[0] + 1,
+                               fill_value=spec.vocabulary_size,
+                               return_inverse=True)
+        return (uniq.astype(jnp.int32),
+                inv.reshape(raw_idx.shape).astype(jnp.int32))
 
 
 def sparse_adagrad_apply(table: jax.Array, acc: jax.Array,
@@ -227,9 +235,10 @@ def sparse_adagrad_apply(table: jax.Array, acc: jax.Array,
     already masked to zero, so duplicate scatter-adds at the dead row are
     no-ops and the dense-Adagrad semantics on touched rows are exact.
     """
-    acc = acc.at[uniq_ids].add(jnp.square(grad_rows))
-    upd = -lr * grad_rows * lax.rsqrt(acc[uniq_ids])
-    return table.at[uniq_ids].add(upd), acc
+    with jax.named_scope("adagrad"):
+        acc = acc.at[uniq_ids].add(jnp.square(grad_rows))
+        upd = -lr * grad_rows * lax.rsqrt(acc[uniq_ids])
+        return table.at[uniq_ids].add(upd), acc
 
 
 def grad_body(spec: ModelSpec, gathered, labels, weights, uniq_ids,
@@ -293,10 +302,7 @@ def train_step_body(spec: ModelSpec, table, acc, labels, weights, uniq_ids,
                 "set); build batches with raw_ids=True — slot indices "
                 "read as feature ids would silently corrupt training")
         uniq_ids, local_idx = _device_dedup(spec, local_idx)
-    # fmlint: disable=R011 -- the jitted step BELOW the slot seam:
-    # uniq_ids reaching here are already physical rows (the data
-    # plane remapped them in admit mode)
-    gathered = table[uniq_ids]
+    gathered = gather_rows(table, uniq_ids)
     loss, scores, grad = grad_body(spec, gathered, labels, weights,
                                    uniq_ids, local_idx, vals, fields,
                                    mesh=mesh)
@@ -428,10 +434,9 @@ def score_body(spec: ModelSpec, table, uniq_ids, local_idx, vals,
     train_step_body. dedup='device': raw ids in ``local_idx``,
     ``uniq_ids=None`` — and NO device unique: dedup buys the forward
     pass nothing (its U is padded to B*L+1, so ``table[uniq]`` moves
-    the same bytes a direct raw gather moves) while its sort-based
-    ``jnp.unique`` over B*L ids dominated the whole predict sweep
-    (PR 9, on an earlier device whose record is gone: 179 ms vs 5.3 ms
-    per B=8192 batch; not re-measured on the v5e — ROADMAP S5).
+    the same bytes a direct raw gather moves) while the device unique
+    costs a train step 14.7 ms at B=8192 x 64 slots on the v5e (scope
+    ``dedup``; PERF.md section 5, PR 25), most of one score call.
     The direct gather is BIT-identical: same table rows summed in the
     same slot order. Training keeps ``_device_dedup`` — the backward
     scatter needs unique rows for exact sparse Adagrad."""
@@ -441,15 +446,11 @@ def score_body(spec: ModelSpec, table, uniq_ids, local_idx, vals,
                 "dedup=device scorer got a host-deduped batch (uniq_ids "
                 "is set); build batches with raw_ids=True")
         B, L = local_idx.shape
-        # fmlint: disable=R011 -- raw-gather scorer below the slot
-        # seam: admit-mode callers remapped local_idx already
-        gathered = table[local_idx.ravel()]
+        gathered = gather_rows(table, local_idx.ravel())
         idx = jnp.arange(B * L, dtype=jnp.int32).reshape(B, L)
         return rows_score_body(spec, gathered, idx, vals, fields,
                                mesh=mesh)
-    # fmlint: disable=R011 -- score path below the slot seam (ids
-    # already physical)
-    gathered = table[uniq_ids]
+    gathered = gather_rows(table, uniq_ids)
     return rows_score_body(spec, gathered, local_idx, vals, fields,
                            mesh=mesh)
 

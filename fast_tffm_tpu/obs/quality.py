@@ -196,16 +196,16 @@ def emit_gate_held(tel, decision: Dict[str, Any]) -> None:
 
 
 def emit_quality(tel, step: int, auc: float, stats: QualityStats,
-                 n_examples: int, eval_seconds: float) -> None:
+                 n_examples: int) -> None:
     """The sweep's metrics-side landing: gauges + counters + one
     timeline scalar, all plain host floats (the zero-added-fetch
     contract — everything here was computed from already-fetched score
     chunks). Sets ``validation/auc`` too: the quality sweep IS this
-    stream's validation pass."""
+    stream's validation pass. (``quality/eval_seconds`` is counted by
+    the ``quality/eval`` span around the sweep.)"""
     if tel is None:
         return
     tel.count("quality/evals")
-    tel.count("quality/eval_seconds", float(eval_seconds))
     tel.count("quality/examples", float(n_examples))
     tel.set("quality/auc", float(auc))
     tel.set("validation/auc", float(auc))

@@ -30,6 +30,17 @@ from fast_tffm_tpu.obs.sink import JsonlSink
 
 _ACTIVE: Optional["RunTelemetry"] = None
 
+# jax.monitoring's names for what makes a program ready to run: the
+# backend compile (a persistent-cache load reports under it too, with
+# the load's time), the Python trace, and the persistent cache's
+# verdict on each request.
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_JAXPR_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "compile/cache_hits",
+    "/jax/compilation_cache/cache_misses": "compile/cache_misses",
+}
+
 
 def active() -> Optional["RunTelemetry"]:
     """The run telemetry instrumented library code should feed, or None
@@ -142,8 +153,11 @@ class RunTelemetry:
         self.registry = MetricsRegistry()
         self.sink = JsonlSink(path, meta=meta)
         self.flush_steps = max(0, int(flush_steps))
-        self._last_flush = time.perf_counter()
         self._closed = False
+        # The step of the latest heartbeat: what a ``compile`` event is
+        # stamped with, so a program compiled mid-run says when.
+        self.step = -1
+        self._watch_compiles()
         # Span tracing (obs/trace.py): span() reads this flag through
         # active(), so the off cost at every site stays one global read.
         self.trace_spans = bool(trace_spans)
@@ -194,6 +208,8 @@ class RunTelemetry:
         """Touch the watchdog's progress beat — the train/predict loops
         call this once per step. No watchdog configured: one attribute
         read and out."""
+        if step is not None:
+            self.step = step
         w = self.watchdog
         if w is not None:
             w.beat(step)
@@ -213,6 +229,40 @@ class RunTelemetry:
         })
         self.sink.flush()
 
+    # -- compiles (jax.monitoring) --------------------------------------
+    def _watch_compiles(self) -> None:
+        """Count every program jax makes ready while this run is open,
+        and write one ``compile`` event a program (``fun_name``,
+        seconds, the step it happened at): a steady loop compiles
+        nothing, so a count that moves mid-run names a shape the
+        warm-up missed. The counters start at 0 so that a reader's
+        difference between two snapshots reads 0, not "absent".
+        Listeners run on whichever thread compiled; registry and sink
+        take their own locks. Removed in close()."""
+        import jax.monitoring
+        for name in ("compile/backend_compiles",
+                     "compile/backend_compile_seconds", "compile/traces",
+                     *_CACHE_EVENTS.values()):
+            self.registry.count(name, 0)
+        jax.monitoring.register_event_duration_secs_listener(
+            self._on_jax_duration)
+        jax.monitoring.register_event_listener(self._on_jax_event)
+
+    def _on_jax_duration(self, event: str, seconds: float, **kw) -> None:
+        if event == _BACKEND_COMPILE:
+            self.registry.count("compile/backend_compiles")
+            self.registry.count("compile/backend_compile_seconds", seconds)
+            self.sink.emit("compile", {"fun_name": kw.get("fun_name"),
+                                       "seconds": seconds,
+                                       "step": self.step})
+        elif event == _JAXPR_TRACE:
+            self.registry.count("compile/traces")
+
+    def _on_jax_event(self, event: str, **kw) -> None:
+        name = _CACHE_EVENTS.get(event)
+        if name is not None:
+            self.registry.count(name)
+
     # -- flush cadence --------------------------------------------------
     def flush_due(self, step: int) -> bool:
         return bool(self.flush_steps) and step % self.flush_steps == 0
@@ -231,9 +281,6 @@ class RunTelemetry:
             self.sink.barrier()
 
     def _emit_metrics(self, step: int) -> None:
-        now = time.perf_counter()
-        self.registry.set("flush/window_seconds", now - self._last_flush)
-        self._last_flush = now
         snap = self.registry.snapshot()
         lease = self.lease
         if lease is not None:
@@ -275,6 +322,18 @@ class RunTelemetry:
         if self._closed:
             return
         self._closed = True
+        import jax.monitoring
+        for unregister, listener in (
+                (jax.monitoring.unregister_event_duration_listener,
+                 self._on_jax_duration),
+                (jax.monitoring.unregister_event_listener,
+                 self._on_jax_event)):
+            try:
+                unregister(listener)
+            except (AssertionError, ValueError):
+                # Already gone: something in the process called
+                # jax.monitoring.clear_event_listeners().
+                pass
         if self.watchdog is not None:
             # Stop BEFORE the final emit/close: a watchdog firing into
             # a closing sink would race the file handle.
@@ -295,7 +354,6 @@ class RunTelemetry:
         B, L = batch.local_idx.shape
         self.count("pipeline/batches")
         self.count("pipeline/examples", batch.num_real)
-        self.count("pipeline/example_capacity", B)
         if batch.uniq_ids is None:
             # raw-ids mode (dedup=device): pad cells hold pad_id
             # directly; the unique set is computed on device, so no

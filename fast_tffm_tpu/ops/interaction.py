@@ -32,30 +32,49 @@ from jax import lax
 _F32 = lax.Precision.HIGHEST
 
 
+# The device step's named parts (README "Observability"): each helper
+# below opens one ``jax.named_scope``, so every step and score program
+# built from them carries the part's name on its operations' op paths
+# (``jit(fm_train_step)/transpose(jvp(expand))/scatter-add``), which a
+# profiler trace keeps and benchmarks/readers/scope_device_ms.py reads.
+# The other three live in models/fm.py: dedup, loss, adagrad.
+
+
 def gather_rows(table: jax.Array, uniq_ids: jax.Array) -> jax.Array:
-    """Gather the batch's unique rows from the (possibly huge) table.
+    """Gather the batch's unique rows from the (possibly huge) table
+    (scope ``gather``).
 
     Padding slots hold ``pad_id == vocabulary_size`` which indexes the
     dead extra row (all-zero, never updated), so no clipping is needed.
     """
-    # fmlint: disable=R011 -- the one sanctioned batch gather below
-    # the slot seam (admit-mode ids are already physical rows here)
-    return table[uniq_ids]
+    with jax.named_scope("gather"):
+        # fmlint: disable=R011 -- the one sanctioned batch gather below
+        # the slot seam (admit-mode ids are already physical rows here)
+        return table[uniq_ids]
+
+
+def expand_rows(params: jax.Array, local_idx: jax.Array) -> jax.Array:
+    """The U gathered slots out to ``[B, L, D]`` (scope ``expand``).
+    Its transpose is the segment-sum of the row gradients back into the
+    slots, which therefore reads ``transpose(jvp(expand))``."""
+    with jax.named_scope("expand"):
+        return params[local_idx]
 
 
 def fm_batch_scores(params: jax.Array, local_idx: jax.Array,
                     vals: jax.Array, order: int = 2) -> jax.Array:
     """Per-example FM scores. order==2 uses the (Σv)²−Σv² identity; order>2
     adds ANOVA-kernel terms of degree 2..order (BASELINE config #4)."""
-    rows = params[local_idx]                      # [B, L, k+1]
-    v, w = rows[..., :-1], rows[..., -1]
-    linear = jnp.einsum("bl,bl->b", w, vals, precision=_F32)
-    z = v * vals[..., None]                       # [B, L, k]
-    if order == 2:
-        s = z.sum(axis=1)                         # [B, k]
-        q = jnp.square(z).sum(axis=1)
-        return linear + 0.5 * (jnp.square(s) - q).sum(axis=-1)
-    return linear + _anova_terms(z, order)
+    rows = expand_rows(params, local_idx)         # [B, L, k+1]
+    with jax.named_scope("interaction"):
+        v, w = rows[..., :-1], rows[..., -1]
+        linear = jnp.einsum("bl,bl->b", w, vals, precision=_F32)
+        z = v * vals[..., None]                   # [B, L, k]
+        if order == 2:
+            s = z.sum(axis=1)                     # [B, k]
+            q = jnp.square(z).sum(axis=1)
+            return linear + 0.5 * (jnp.square(s) - q).sum(axis=-1)
+        return linear + _anova_terms(z, order)
 
 
 def _anova_terms(z: jax.Array, order: int) -> jax.Array:
@@ -96,22 +115,25 @@ def ffm_batch_scores(params: jax.Array, field_num: int,
     and the L-contraction is a plain matmul the MXU tiles. Padded slots
     have x=0 and contribute zero everywhere.
     """
-    rows = params[local_idx]                       # [B, L, F*k+1]
-    B, L = local_idx.shape
-    w = rows[..., -1]
-    k = (rows.shape[-1] - 1) // field_num
-    v = rows[..., :-1].reshape(B, L, field_num, k)
-    linear = jnp.einsum("bl,bl->b", w, vals, precision=_F32)
-    onehot = jax.nn.one_hot(fields, field_num, dtype=v.dtype)  # [B, L, F]
-    # S[b,f,g,:] = Σ_l onehot[b,l,g] · x[b,l] · v[b,l,f,:]
-    s = jnp.einsum("blfk,blg,bl->bfgk", v, onehot, vals, precision=_F32)
-    cross = jnp.einsum("bfgk,bgfk->b", s, s, precision=_F32)
-    # i=j diagonal: v each feature uses against its own field.
-    v_self = jnp.take_along_axis(
-        v, fields[:, :, None, None], axis=2)[:, :, 0, :]       # [B, L, k]
-    diag = jnp.einsum("blk,blk,bl->b", v_self, v_self,
-                      jnp.square(vals), precision=_F32)
-    return linear + 0.5 * (cross - diag)
+    rows = expand_rows(params, local_idx)          # [B, L, F*k+1]
+    with jax.named_scope("interaction"):
+        B, L = local_idx.shape
+        w = rows[..., -1]
+        k = (rows.shape[-1] - 1) // field_num
+        v = rows[..., :-1].reshape(B, L, field_num, k)
+        linear = jnp.einsum("bl,bl->b", w, vals, precision=_F32)
+        onehot = jax.nn.one_hot(fields, field_num,
+                                dtype=v.dtype)                 # [B, L, F]
+        # S[b,f,g,:] = Σ_l onehot[b,l,g] · x[b,l] · v[b,l,f,:]
+        s = jnp.einsum("blfk,blg,bl->bfgk", v, onehot, vals,
+                       precision=_F32)
+        cross = jnp.einsum("bfgk,bgfk->b", s, s, precision=_F32)
+        # i=j diagonal: v each feature uses against its own field.
+        v_self = jnp.take_along_axis(
+            v, fields[:, :, None, None], axis=2)[:, :, 0, :]   # [B, L, k]
+        diag = jnp.einsum("blk,blk,bl->b", v_self, v_self,
+                          jnp.square(vals), precision=_F32)
+        return linear + 0.5 * (cross - diag)
 
 
 def batch_reg(params: jax.Array, uniq_ids: jax.Array, vocabulary_size: int,

@@ -191,17 +191,20 @@ def fm_batch_scores_pallas(params: jax.Array, local_idx: jax.Array,
     The gather stays outside in GSPMD-land, which owns the row-shard
     collectives. This is how kernel='pallas' survives the mesh paths
     (parallel/sharded.py binds the mesh)."""
-    rows = params[local_idx]
-    v = jnp.swapaxes(rows[..., :-1], 1, 2)   # [B, K, L]
-    w = rows[..., -1]
-    if mesh is None:
-        return fm_scores_pallas(v, w, vals)
-    from jax.sharding import PartitionSpec as P
-    # check_vma=False: pallas_call declares no varying-mesh-axes rule;
-    # the body is per-example with zero collectives, so the manual specs
-    # are the whole contract.
-    fn = jax.shard_map(
-        fm_scores_pallas, mesh=mesh,
-        in_specs=(P("data", None, None), P("data", None), P("data", None)),
-        out_specs=P("data"), check_vma=False)
-    return fn(v, w, vals)
+    from fast_tffm_tpu.ops.interaction import expand_rows
+    rows = expand_rows(params, local_idx)
+    with jax.named_scope("interaction"):
+        v = jnp.swapaxes(rows[..., :-1], 1, 2)   # [B, K, L]
+        w = rows[..., -1]
+        if mesh is None:
+            return fm_scores_pallas(v, w, vals)
+        from jax.sharding import PartitionSpec as P
+        # check_vma=False: pallas_call declares no varying-mesh-axes
+        # rule; the body is per-example with zero collectives, so the
+        # manual specs are the whole contract.
+        fn = jax.shard_map(
+            fm_scores_pallas, mesh=mesh,
+            in_specs=(P("data", None, None), P("data", None),
+                      P("data", None)),
+            out_specs=P("data"), check_vma=False)
+        return fn(v, w, vals)

@@ -30,7 +30,7 @@ from fast_tffm_tpu.data.pipeline import expand_files
 from fast_tffm_tpu.metrics import sigmoid
 from fast_tffm_tpu.obs.telemetry import (active, make_telemetry,
                                          pop_active, push_active)
-from fast_tffm_tpu.obs.trace import span
+from fast_tffm_tpu.obs.trace import begin, span
 from fast_tffm_tpu.scoring import ScoreWriter, score_sweep
 from fast_tffm_tpu.utils.logging import get_logger
 
@@ -141,8 +141,12 @@ def predict(cfg: FmConfig, table: Optional[jax.Array] = None,
                 tel.lease = lease
         guard_prev = install_guard(lease, cfg.collective_timeout_seconds)
         guard_installed = True
+    # Entry until the sweep's first dispatch: restore or adopt the
+    # table, build the scorer and the input pipeline. It is paid once
+    # a call, so it weighs on short sweeps (obs/trace.begin).
+    setup = begin("predict/setup", seconds="predict/setup_seconds")
     try:
-        written = _predict_body(cfg, table, logger)
+        written = _predict_body(cfg, table, logger, setup)
         return written
     except BaseException as e:
         # Crash forensics (obs/health.py): traceback + recent-event
@@ -167,6 +171,7 @@ def predict(cfg: FmConfig, table: Optional[jax.Array] = None,
                 logger.exception("crash event emission failed")
         raise
     finally:
+        setup.end()
         if lease is not None:
             try:
                 lease.stop()
@@ -188,7 +193,7 @@ def _score_out_path(cfg: FmConfig, path: str) -> str:
                         os.path.basename(path) + ".score")
 
 
-def _predict_body(cfg: FmConfig, table, logger) -> List[str]:
+def _predict_body(cfg: FmConfig, table, logger, setup) -> List[str]:
     tel = active()
     if jax.process_count() > 1:
         if cfg.lookup == "host":
@@ -198,7 +203,7 @@ def _predict_body(cfg: FmConfig, table, logger) -> List[str]:
                 "vocab_mode = admit predict is single-process (the "
                 "slot map is host state; see the train-side "
                 "restriction)")
-        return _predict_multiprocess(cfg, table, logger)
+        return _predict_multiprocess(cfg, table, logger, setup)
     mesh = None
     backend = None
     vocab = None
@@ -277,9 +282,9 @@ def _predict_body(cfg: FmConfig, table, logger) -> List[str]:
     # deferred write errors on the clean path; the finally's close is
     # the idempotent no-mask flush for the error path.
     writer = ScoreWriter(logger)
-    # fmlint: disable=R003 -- brackets the whole sweep for the
-    # predict/seconds counter and rate gauge (always-on aggregates;
-    # the predict/sweep span inside score_sweep is the timeline view)
+    # fmlint: disable=R003 -- the clock of the sweep's running rate
+    # (the log line, the gauge and each predict_file event); the
+    # predict/seconds counter is the predict/run span's below
     t0 = time.perf_counter()
     emitted = [0]  # cumulative examples cut so far (single-writer:
     # on_file runs on one thread at a time — score_sweep's contract)
@@ -307,18 +312,20 @@ def _predict_body(cfg: FmConfig, table, logger) -> List[str]:
                                emitted[0] / dt if dt > 0 else 0.0})
 
     try:
-        n = score_sweep(cfg, table, files, on_file=on_file, mesh=mesh,
-                        backend=backend, vocab=vocab)
-        writer.close()
+        with span("predict/run", seconds="predict/seconds", leaf=False):
+            n = score_sweep(cfg, table, files, on_file=on_file,
+                            mesh=mesh, backend=backend, vocab=vocab,
+                            before_first_dispatch=setup.end)
+            with span("predict/write_wait"):
+                writer.close()  # the writer thread's last files
     finally:
         writer.close(raise_error=False)
         from fast_tffm_tpu.obs.memory import LEDGER
         LEDGER.release("table")
-    # fmlint: disable=R003 -- closes the predict/seconds sample
+    # fmlint: disable=R003 -- closes the rate's clock
     dt = time.perf_counter() - t0
     rate = n / dt if dt > 0 else 0.0
     if tel is not None:
-        tel.count("predict/seconds", dt)
         tel.set("predict/examples_per_sec", rate)
         # One barrier for the sweep (scores are host-side; the flush
         # is pure file I/O) — the per-file barriers the old loop paid
@@ -329,7 +336,8 @@ def _predict_body(cfg: FmConfig, table, logger) -> List[str]:
     return written
 
 
-def _predict_multiprocess(cfg: FmConfig, table, logger) -> List[str]:
+def _predict_multiprocess(cfg: FmConfig, table, logger,
+                          setup) -> List[str]:
     """Sharded predict, one continuous stream: every process scores its
     byte-range shard of ALL files through the global-mesh score fn in
     lockstep (each call is a collective program — the filler-batch
@@ -416,8 +424,8 @@ def _predict_multiprocess(cfg: FmConfig, table, logger) -> List[str]:
                        label="predict/clean_barrier")
     writer = ScoreWriter(logger)
     merger = PartMerger(out_paths, P, logger) if p == 0 else None
-    # fmlint: disable=R003 -- brackets the whole sweep for the
-    # per-worker predict/seconds counter (always-on aggregate)
+    # fmlint: disable=R003 -- the clock of this worker's rate gauge;
+    # the predict/seconds counter is the predict/run span's below
     t0 = time.perf_counter()
     n_local = 0
 
@@ -435,31 +443,33 @@ def _predict_multiprocess(cfg: FmConfig, table, logger) -> List[str]:
                            "process_index": p})
 
     demux = ScoreDemux(marks, on_file)
+    setup.end()  # the lockstep windows dispatch from here on
     try:
-        with span("predict/sweep", files=len(files)):
-            for batch, local in lockstep_score_batches(cfg, it, mesh,
-                                                       score_fn, table,
-                                                       ub):
-                demux.consume(local[:batch.num_real])
-                n_local += batch.num_real
-                if tel is not None:
-                    tel.heartbeat()  # lockstep progress feeds the
-                    # watchdog; a hung peer stalls the whole cluster
-        demux.finalize()
-        writer.close()  # every part + marker of this worker is on disk
-        guarded_collective(multihost_utils.sync_global_devices,
-                           "predict_parts_done",
-                           label="predict/parts_barrier")
-        if merger is not None:
-            # All markers are durable past the barrier: the merge
-            # thread finishes its remaining files promptly (bounded
-            # per-marker grace; a missing marker raises by name).
-            merger.finish()
-        # Chief finished reading (and deleting) every part before
-        # anyone returns and could rewrite/reuse the score dir.
-        guarded_collective(multihost_utils.sync_global_devices,
-                           "predict_merged",
-                           label="predict/merge_barrier")
+        with span("predict/run", seconds="predict/seconds", leaf=False):
+            with span("predict/sweep", leaf=False, files=len(files)):
+                for batch, local in lockstep_score_batches(
+                        cfg, it, mesh, score_fn, table, ub):
+                    demux.consume(local[:batch.num_real])
+                    n_local += batch.num_real
+                    if tel is not None:
+                        tel.heartbeat()  # lockstep progress feeds the
+                        # watchdog; a hung peer stalls the whole cluster
+            demux.finalize()
+            writer.close()  # every part + marker of this worker is on
+            # disk
+            guarded_collective(multihost_utils.sync_global_devices,
+                               "predict_parts_done",
+                               label="predict/parts_barrier")
+            if merger is not None:
+                # All markers are durable past the barrier: the merge
+                # thread finishes its remaining files promptly (bounded
+                # per-marker grace; a missing marker raises by name).
+                merger.finish()
+            # Chief finished reading (and deleting) every part before
+            # anyone returns and could rewrite/reuse the score dir.
+            guarded_collective(multihost_utils.sync_global_devices,
+                               "predict_merged",
+                               label="predict/merge_barrier")
     finally:
         writer.close(raise_error=False)
         if merger is not None:
@@ -468,9 +478,8 @@ def _predict_multiprocess(cfg: FmConfig, table, logger) -> List[str]:
         # Per-WORKER rate for this worker's shard; the merged view
         # (fmstat over all .p<i> shards) sums examples and seconds
         # across processes, keyed by process index in the metadata.
-        # fmlint: disable=R003 -- closes the predict/seconds sample
+        # fmlint: disable=R003 -- closes the rate's clock
         dt = time.perf_counter() - t0
-        tel.count("predict/seconds", dt)
         tel.set("predict/examples_per_sec",
                 n_local / dt if dt > 0 else 0.0)
         tel.barrier_flush(step=len(out_paths))

@@ -187,8 +187,6 @@ class ScoreWriter:
             job = self._q.get()
             if job is self._sentinel:
                 return
-            tel = active()  # per job: one global read (writes are
-            # file-grained, not hot), robust to late activation
             with self._lock:
                 dead = self._error is not None
             if dead:
@@ -199,11 +197,10 @@ class ScoreWriter:
                 continue
             out_path, vals, marker = job
             try:
-                # fmlint: disable=R003 -- feeds the always-on
-                # predict/write_seconds counter (the fmstat write-share
-                # row); the span is the timeline view
-                t0 = time.perf_counter()
+                # The always-on predict/write_seconds counter is the
+                # fmstat write-share row.
                 with span("predict/write",
+                          seconds="predict/write_seconds",
                           path=os.path.basename(out_path)):
                     with open(out_path, "w") as fh:
                         for v in vals:
@@ -214,10 +211,6 @@ class ScoreWriter:
                         # thread watching the shared filesystem.
                         with open(marker, "w"):
                             pass
-                if tel is not None:
-                    # fmlint: disable=R003 -- closes the write sample
-                    tel.count("predict/write_seconds",
-                              time.perf_counter() - t0)
                 self._logger.info("wrote %d scores to %s", len(vals),
                                   out_path)
             except BaseException as e:  # surfaced at submit()/close()
@@ -328,7 +321,9 @@ class ScoreDemux:
 
 def score_sweep(cfg: FmConfig, table, files: Sequence[str],
                 on_file: Callable[[str, np.ndarray], None],
-                mesh=None, backend=None, vocab=None) -> int:
+                mesh=None, backend=None, vocab=None,
+                before_first_dispatch: Optional[Callable[[], None]] = None
+                ) -> int:
     """Single-process continuous scoring sweep: one batch stream over
     ALL ``files`` (keep_empty: score files stay line-aligned), one
     overlap ChunkedFetcher for the whole sweep, per-file RAW score
@@ -340,7 +335,15 @@ def score_sweep(cfg: FmConfig, table, files: Sequence[str],
     ScoreWriter/accumulator, both safe there. No per-file warmup, no
     per-file fetcher drain: the compiled scorer and the D2H overlap
     worker live across every boundary, which is where the 15x
-    predict-vs-train gap lived (ISSUE 10; ROADMAP S4 re-measures)."""
+    predict-vs-train gap lived (ISSUE 10; ROADMAP S4 re-measures).
+
+    The host loop's phases are spans (obs/trace.py): ``predict/
+    input_wait`` around each next() and ``predict/score_dispatch``
+    around each dispatch on this thread, ``predict/drain`` for the
+    tail, ``fetch/bulk`` and ``predict/write`` on their workers (and
+    predict()'s ``predict/write_wait``); ``before_first_dispatch`` lets
+    predict() end its ``predict/setup`` phase where the sweep's work
+    begins."""
     files = list(files)  # consumed twice (span field + iterator)
     scorer = CompiledScorer(cfg, mesh=mesh, backend=backend)
     from fast_tffm_tpu.models.fm import regime_line
@@ -362,23 +365,31 @@ def score_sweep(cfg: FmConfig, table, files: Sequence[str],
     # queued chunk of device score arrays pinned in HBM — close()
     # drains and joins the worker without masking the original error.
     try:
-        with span("predict/sweep", files=len(files)):
+        with span("predict/sweep", leaf=False, files=len(files)):
             # ``vocab`` (vocab_mode = admit): the pipeline builds in
             # the hashed space and remaps through the checkpoint's
             # slot map — the sweep scores exactly the rows training
             # assigned (predict.py loads the (table, slot map, step)
             # triple together).
-            it = batch_iterator(cfg, files, training=False, epochs=1,
-                                keep_empty=True, raw_ids=scorer.raw,
-                                file_marks=marks, vocab=vocab)
-            for batch in prefetch(it, depth=cfg.prefetch_depth,
-                                  gil_bound=gil_bound_iteration(
-                                      cfg, keep_empty=True)):
-                fetcher.add(scorer.score_batch(table, batch),
-                            batch.num_real)
+            it = prefetch(
+                batch_iterator(cfg, files, training=False, epochs=1,
+                               keep_empty=True, raw_ids=scorer.raw,
+                               file_marks=marks, vocab=vocab),
+                depth=cfg.prefetch_depth,
+                gil_bound=gil_bound_iteration(cfg, keep_empty=True))
+            while True:
+                with span("predict/input_wait"):
+                    batch = next(it, None)
+                if batch is None:
+                    break
+                if before_first_dispatch is not None:
+                    before_first_dispatch()
+                    before_first_dispatch = None
+                with span("predict/score_dispatch"):
+                    scores = scorer.score_batch(table, batch)
+                fetcher.add(scores, batch.num_real)
                 n_examples += batch.num_real
                 if tel is not None:
-                    tel.count("predict/batches")
                     tel.count("predict/examples", batch.num_real)
                     # Output-order buffer: device score arrays held
                     # back so results land in input order — its depth
@@ -389,10 +400,12 @@ def score_sweep(cfg: FmConfig, table, files: Sequence[str],
                     # Watchdog beat: a scored batch is progress
                     # (obs/health.py).
                     tel.heartbeat()
-            fetcher.flush()
-        # All scores are host-side and consumed (flush joined the
-        # worker): cut the tail files on this thread.
-        demux.finalize()
+            # The sweep's tail: this thread waits for the fetch worker
+            # to bring the last chunk home, then cuts the tail files
+            # (all scores are host-side once flush joined the worker).
+            with span("predict/drain"):
+                fetcher.flush()
+                demux.finalize()
     finally:
         fetcher.close()
     return n_examples

@@ -13,7 +13,6 @@ a device mesh (parallel/), with the table row-sharded across it; the
 
 from __future__ import annotations
 
-import contextlib
 import signal
 import time
 from typing import Optional, Tuple
@@ -40,10 +39,10 @@ from fast_tffm_tpu.obs.memory import (LEDGER, local_bytes_in_use,
                                       table_bytes)
 from fast_tffm_tpu.obs.telemetry import (active, make_telemetry,
                                          pop_active, push_active)
-from fast_tffm_tpu.obs.trace import span
+from fast_tffm_tpu.obs.trace import begin, span
 from fast_tffm_tpu.utils.fetch import ChunkedFetcher, bulk_fetch
 from fast_tffm_tpu.utils.logging import get_logger
-from fast_tffm_tpu.utils.timing import StepTimer, trace_span
+from fast_tffm_tpu.utils.timing import StepTimer
 
 
 # First-log-step probe threshold (train()): a materialized-scalar fetch
@@ -641,6 +640,9 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
     # raises before reaching their real definitions.
     summaries = None
     profiling = False
+    # Phases held open across loop iterations (obs/trace.begin); the
+    # finally ends whatever an exception left open.
+    starting = barrier = None
     prev_handlers = {}
     global_step = 0
     ckpt = None
@@ -842,7 +844,6 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
         wire_spec = resolve_wire(cfg, mesh=mesh, backend=lk,
                                  multi_process=multi_process, train=True)
         wire_enc = WireEncoder(wire_spec, pad_id=cfg.pad_id)
-        wire_stage = (not multi_process and mesh is None and not offload)
         packed_step = None
         if wire_spec.packed:
             from fast_tffm_tpu.models.fm import make_packed_train_step
@@ -855,6 +856,12 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
             # attribution names it beside the bytes-per-example row.
             tel.set("wire/packed", 1.0 if wire_spec.packed else 0.0)
             tel.set("wire/narrow", 1.0 if wire_spec.narrow else 0.0)
+            # What only a sync point or an epoch barrier feeds starts
+            # at 0: a reader that differences two snapshots of the
+            # stream must find "none yet" as 0, not as absent.
+            for name in ("train/epochs", "train/epoch_barrier_seconds",
+                         "train/loss_sync_seconds"):
+                tel.count(name, 0)
 
         # Step-anatomy join keys (obs/anatomy.py; README "Step
         # anatomy"): when on, the loops stamp the step id into the
@@ -862,6 +869,21 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
         # across ranks) and feed the host-side phase-seconds counters
         # the anatomy/* gauges aggregate at barrier flushes.
         anat = tel is not None and getattr(tel, "anatomy", False)
+
+        def _place(batch, wb):
+            """This dispatch path's host-to-device placement of one
+            encoded batch (the offload step takes host arrays and
+            never gets here)."""
+            if multi_process:
+                # The global-array assembly ships every shard's bytes.
+                return global_batch(mesh, len(batch.uniq_ids), **wb.args)
+            if mesh is not None:
+                return shard_batch(mesh, **wb.args)
+            # Plain single-device jit, depth-2 double buffer: the
+            # explicit async put rides the copy stream while the
+            # PREVIOUS step is still executing, instead of serializing
+            # at the head of this step's dispatch.
+            return wire_enc.device_put(wb)
 
         def _wire_place(batch, step=0):
             """Encode one batch and place its arrays for dispatch —
@@ -871,42 +893,28 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
             the arrays ACTUALLY shipped; the padded-layout size rides
             on wb.logical_bytes for the savings counter. ``step``
             (anatomy on) rides the h2d span as the cross-rank join
-            key; the placed arms also feed the train/h2d_seconds
-            anatomy phase counter."""
-            wb = wire_enc.encode_train(batch)
+            key."""
+            with span("train/encode", seconds="train/encode_seconds"):
+                wb = wire_enc.encode_train(batch)
+            if offload:
+                return wb, wb.args
             ids = {"step": step} if (anat and step) else {}
-            t_h2d = time.perf_counter()
-            placed = True
-            if multi_process:
-                # The global-array assembly ships every shard's bytes.
-                with span("train/h2d", bytes=wb.wire_bytes, **ids):
-                    args = global_batch(mesh, len(batch.uniq_ids),
-                                        **wb.args)
-            elif mesh is not None:
-                with span("train/h2d", bytes=wb.wire_bytes, **ids):
-                    args = shard_batch(mesh, **wb.args)
-            elif wire_stage:
-                # Depth-2 double buffer: the explicit async put rides
-                # the copy stream while the PREVIOUS step is still
-                # executing, instead of serializing at the head of
-                # this step's dispatch.
-                with span("train/h2d", bytes=wb.wire_bytes, **ids):
-                    args = wire_enc.device_put(wb)
-            else:
-                args = wb.args
-                placed = False
-            if placed and tel is not None:
-                tel.count("train/h2d_seconds",
-                          time.perf_counter() - t_h2d)
-            return wb, args
+            with span("train/h2d", seconds="train/h2d_seconds",
+                      bytes=wb.wire_bytes, **ids):
+                return wb, _place(batch, wb)
 
-        def _wire_step(wb, args, table, acc):
+        def _wire_step(wb, args, table, acc, step):
             """Dispatch one placed batch through the right compiled
-            step (shared by both loops, like _wire_place). Runs under
+            step (shared by both loops, like _wire_place), as the
+            ``train/step`` phase: jax dispatch is async (returns at
+            enqueue), so time spent HERE is queue backpressure — the
+            previous program still executing somewhere. Runs under
             oom_guard: a RESOURCE_EXHAUSTED here re-raises with the
             per-owner ledger attached (obs/memory.py)."""
-            with oom_guard("train/step"):
-                return _wire_step_inner(wb, args, table, acc)
+            with span("train/step", seconds="train/dispatch_seconds",
+                      step=step):
+                with oom_guard("train/step"):
+                    return _wire_step_inner(wb, args, table, acc)
 
         def _wire_step_inner(wb, args, table, acc):
             if multi_process:
@@ -914,20 +922,12 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                 # cluster its dispatch blocks inside the program's
                 # collectives exactly like a host allgather (pinned by
                 # the hang-worker chaos stack dumps), so it runs under
-                # the same deadline guard. The dispatch wait is an
-                # anatomy phase: jax dispatch is async (returns at
-                # enqueue), so time spent HERE is queue backpressure —
-                # the previous program still executing somewhere.
+                # the same deadline guard.
                 from fast_tffm_tpu.parallel.liveness import (
                     guarded_collective)
-                t_disp = time.perf_counter()
-                out = guarded_collective(
+                return guarded_collective(
                     step_fn, table, acc,
                     label="train/step_dispatch", **args)
-                if tel is not None:
-                    tel.count("train/dispatch_seconds",
-                              time.perf_counter() - t_disp)
-                return out
             if wb.packed:
                 return packed_step(wb.L, table, acc, **args)
             return step_fn(table, acc, **args)
@@ -1032,10 +1032,11 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                                                    emit_quality)
             stats = QualityStats(cfg.loss_type)
             vmb = cfg.validation_max_batches or None
-            # fmlint: disable=R003 -- feeds the quality/eval_seconds
-            # counter (the quality/eval span is the timeline view)
-            t_q = time.perf_counter()
-            with span("quality/eval", step=global_step):
+            # Chief-only counter, like emit_quality below: per-worker
+            # shard counters merge by SUM in fmstat.
+            with span("quality/eval", leaf=False, step=global_step,
+                      seconds=("quality/eval_seconds"
+                               if jax.process_index() == 0 else None)):
                 if multi_process:
                     # preempt rides the lockstep window allgather like
                     # every other multi-process sweep: a SIGTERM mid-
@@ -1056,26 +1057,23 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                         weight_files=cfg.validation_weight_files,
                         bad_lines=bad_tracker, vocab=vocab,
                         collect=stats)
-            # fmlint: disable=R003 -- closes the eval-cost sample
-            dt_q = time.perf_counter() - t_q
             if jax.process_index() == 0:
                 # Chief-only: n and the merged stats are already
                 # job-global, and per-worker shard counters merge by
                 # SUM in fmstat — every worker emitting would inflate
                 # quality/evals and quality/examples by P.
-                emit_quality(tel, global_step, float(auc), stats, n,
-                             dt_q)
+                emit_quality(tel, global_step, float(auc), stats, n)
             if tel is not None:
                 tel.heartbeat()  # a long sweep is progress, not a stall
             if jax.process_index() == 0:
                 logger.info(
                     "publish quality eval at step %d: AUC %.6f, loss "
-                    "%s, calibration %s over %d examples (%.2fs)",
+                    "%s, calibration %s over %d examples",
                     global_step, auc,
                     "-" if stats.loss is None
                     else f"{stats.loss:.6f}",
                     "-" if stats.calibration is None
-                    else f"{stats.calibration:.4f}", n, dt_q)
+                    else f"{stats.calibration:.4f}", n)
             if gate is None:
                 return {"held": False, "auc": float(auc),
                         "examples": int(n)}
@@ -1214,7 +1212,14 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                 if len(log_buffer) >= LOG_BUFFER_MAX:
                     flush_log()
                 return
-            log_line(s, ep, float(loss_arr), eps)
+            # The loop's sync point: the host waits here until the
+            # device has caught up, so this phase's share of the wall
+            # says how far the device sets the pace. The line itself
+            # is written outside the phase.
+            with span("train/loss_sync",
+                      seconds="train/loss_sync_seconds"):
+                val = float(loss_arr)
+            log_line(s, ep, val, eps)
 
         def flush_log():
             if not log_buffer:
@@ -1224,9 +1229,15 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
             # where a per-element list fetch costs ~200 ms EACH
             # (utils/fetch.py) — a full 1024-entry buffer would stall for
             # minutes.
-            bulk_fetch([(arr, (s, ep, eps))
-                        for s, ep, arr, eps in log_buffer],
-                       lambda v, m: log_line(m[0], m[1], float(v), m[2]))
+            lines: list = []
+            with span("train/loss_sync",
+                      seconds="train/loss_sync_seconds"):
+                bulk_fetch([(arr, (s, ep, eps))
+                            for s, ep, arr, eps in log_buffer],
+                           lambda v, m: lines.append(
+                               (m[0], m[1], float(v), m[2])))
+            for line in lines:
+                log_line(*line)
             log_buffer.clear()
         # Handlers stay installed (absorbing re-signals) until the finally
         # below — i.e. until the final checkpoint/export is safely on disk,
@@ -1382,8 +1393,6 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                           vocab_state=(vocab.state_payload()
                                        if vocab is not None else None))
                 last_periodic_save = (global_step, 0)
-                if tel is not None:
-                    tel.count("train/checkpoints")
 
             def do_publish() -> None:
                 """Quality eval + gate, then save + settle the
@@ -1396,11 +1405,9 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                 the decision is chief-broadcast, so every worker runs
                 the save's commit barrier (or skips it) together; only
                 process 0 flips the pointer."""
-                with span("checkpoint/publish", step=global_step):
-                    # fmlint: disable=R003 -- feeds the train/
-                    # checkpoint_pause_seconds counter (the publish
-                    # span is the timeline view)
-                    t_pub = time.perf_counter()
+                with span("checkpoint/publish", leaf=False,
+                          seconds="train/checkpoint_pause_seconds",
+                          step=global_step):
                     # Publish settle IS a vocab barrier point: the
                     # published (table, slot map, step) triple a
                     # scorer hot-reloads must be post-admission/
@@ -1439,10 +1446,6 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                     # published step reaches the retention boundary,
                     # step_once pauses periodic saves too, so GC can
                     # never evict the last-good checkpoint mid-hold.
-                    if tel is not None:
-                        # fmlint: disable=R003 -- closes the sample
-                        tel.count("train/checkpoint_pause_seconds",
-                                  time.perf_counter() - t_pub)
                 last_publish[0] = time.monotonic()
                 stream_gauges()
                 if grow_ctx is not None and not gate_holding[0]:
@@ -1475,9 +1478,8 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                     batch = vocab.ensure_current(batch)
                 wb, args = _wire_place(batch, global_step + 1)
                 h2d_bytes = wb.wire_bytes
-                with span("train/step", step=global_step + 1):
-                    table, acc, loss, _ = _wire_step(wb, args,
-                                                     table, acc)
+                table, acc, loss, _ = _wire_step(wb, args, table, acc,
+                                                 global_step + 1)
                 global_step += 1
                 if batch.stream_pos is not None:
                     # The durable position advances ONLY with stepped
@@ -1519,10 +1521,9 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                 if tel_due:
                     tel.add_scalar("train/loss", global_step, loss)
                     tel.set("train/examples_per_sec_window", eps_now)
-                    tel.set("train/examples_per_sec_total",
-                            timer.total_examples_per_sec)
                     stream_gauges()
-                    tel.maybe_flush(global_step)
+                    with span("obs/flush", seconds="obs/flush_seconds"):
+                        tel.maybe_flush(global_step)
                 if cfg.save_steps and global_step % cfg.save_steps == 0:
                     # margin=2: stop one slot shy of the boundary so
                     # the mandatory final/preemption save can still
@@ -1552,9 +1553,6 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                                 "checkpoint; heal the input stream "
                                 "(or raise max_to_keep) to resume")
                     else:
-                        # fmlint: disable=R003 -- feeds the train/
-                        # checkpoint_pause_seconds counter
-                        t_ck = time.perf_counter()
                         # Gated runs save SYNCHRONOUSLY: the retention
                         # math protecting the published step (the
                         # margin=2 risk arm + the hold pause above)
@@ -1565,13 +1563,12 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                         # the exact last-good checkpoint the gate
                         # pinned (caught by the retention-pause e2e
                         # test).
-                        stream_save(wait=offload or gate is not None)
+                        with span("train/checkpoint_pause",
+                                  seconds="train/checkpoint_pause_seconds"
+                                  ) as pause:
+                            stream_save(wait=offload or gate is not None)
                         if tel is not None:
-                            # fmlint: disable=R003 -- closes the sample
-                            dt_ck = time.perf_counter() - t_ck
-                            tel.count("train/checkpoint_pause_seconds",
-                                      dt_ck)
-                            t_prev[0] += dt_ck
+                            t_prev[0] += pause.dur
 
             def emit_preempted() -> None:
                 nonlocal stopping
@@ -1600,21 +1597,15 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                         # join key).
                         ids = ({"step": global_step + 1} if anat
                                else {})
-                        # fmlint: disable=R003 -- feeds the train/
-                        # step_flags_seconds anatomy counter
-                        t_fl = time.perf_counter()
-                        with span("stream/step_flags", **ids):
+                        with span("stream/step_flags",
+                                  seconds="train/step_flags_seconds",
+                                  **ids):
                             flags = np.asarray(guarded_collective(
                                 multihost_utils.process_allgather,
                                 np.asarray([has, bool(preempted),
                                             done, pub_due]),
                                 label="stream/step_flags"
                                 )).reshape(-1, 4)
-                        if tel is not None:
-                            # fmlint: disable=R003 -- closes the
-                            # flags-wait sample
-                            tel.count("train/step_flags_seconds",
-                                      time.perf_counter() - t_fl)
                         if bool(flags[:, 1].any()):
                             emit_preempted()
                             break
@@ -1698,6 +1689,10 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
             if stopping:
                 break
             epoch_stats = SpillStats()
+            # Building the input pipeline until its first batch is out
+            # (closed below, after the epoch's first next()).
+            starting = begin("pipeline/start",
+                             seconds="pipeline/start_seconds")
             it = prefetch(batch_iterator(
                 cfg, cfg.train_files, training=True,
                 weight_files=cfg.weight_files, shard_index=shard_index,
@@ -1718,14 +1713,10 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                 # host-bound signal and misdiagnose a device-bound run
                 # (the producer-side build cost is timed separately in
                 # pipeline.batch_iterator on the worker thread).
-                # fmlint: disable=R003 -- feeds the train/
-                # input_wait_seconds counter (always-on aggregate)
-                t_in = time.perf_counter() if tel is not None else 0.0
-                batch = next(it, None)
-                if tel is not None:
-                    # fmlint: disable=R003 -- closes the input-wait sample
-                    tel.count("train/input_wait_seconds",
-                              time.perf_counter() - t_in)
+                with span("train/input_wait",
+                          seconds="train/input_wait_seconds"):
+                    batch = next(it, None)
+                starting.end()
                 if multi_process:
                     # Lockstep: line-index sharding can give processes
                     # batch counts differing by one; every step is a
@@ -1747,20 +1738,13 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                     # blocks behind queued device work — which is
                     # exactly what the anatomy report names.
                     ids = {"step": global_step + 1} if anat else {}
-                    # fmlint: disable=R003 -- feeds the train/
-                    # step_flags_seconds anatomy counter
-                    t_fl = time.perf_counter()
-                    with span("train/step_flags", **ids):
+                    with span("train/step_flags",
+                              seconds="train/step_flags_seconds", **ids):
                         flags = guarded_collective(
                             multihost_utils.process_allgather,
                             np.asarray([batch is None,
                                         bool(preempted)]),
                             label="train/step_flags")
-                    if tel is not None:
-                        # fmlint: disable=R003 -- closes the flags-
-                        # wait sample
-                        tel.count("train/step_flags_seconds",
-                                  time.perf_counter() - t_fl)
                     if bool(flags[..., 1].any()):
                         stopping = True
                         logger.info(
@@ -1809,17 +1793,10 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                     batch = vocab.ensure_current(batch)
                 wb, args = _wire_place(batch, global_step + 1)
                 h2d_bytes = wb.wire_bytes
-                # trace_span only while a profiler window is open: a
-                # per-step TraceAnnotation costs ~14x throughput on this
-                # platform when nothing is tracing. (Distinct from the
-                # obs/trace JSONL span around it: that one is a no-op
-                # unless the run enabled trace_spans.)
-                prof_ann = (trace_span("train_step") if profiling
-                            else contextlib.nullcontext())
-                with span("train/step", step=global_step + 1):
-                    with prof_ann:
-                        table, acc, loss, _ = _wire_step(wb, args,
-                                                         table, acc)
+                table, acc, loss, _ = _wire_step(wb, args, table, acc,
+                                                 global_step + 1)
+                if barrier is not None:
+                    barrier.end()
                 global_step += 1
                 last_val = None  # table advanced; any cached AUC is stale
                 if vocab is not None:
@@ -1869,36 +1846,36 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                     # the next epoch barrier (sink link-safety contract).
                     tel.add_scalar("train/loss", global_step, loss)
                     tel.set("train/examples_per_sec_window", eps_now)
-                    tel.set("train/examples_per_sec_total",
-                            timer.total_examples_per_sec)
-                    tel.maybe_flush(global_step)  # file I/O only
+                    with span("obs/flush", seconds="obs/flush_seconds"):
+                        tel.maybe_flush(global_step)  # file I/O only
                 if cfg.save_steps and global_step % cfg.save_steps == 0:
-                    # fmlint: disable=R003 -- feeds the train/
-                    # checkpoint_pause_seconds counter (the
-                    # checkpoint/save span is the timeline view)
-                    t_ck = time.perf_counter()
-                    state = (lk.state() if offload
-                             else ckpt_state(cfg, table, acc))
-                    # Device arrays: async save (orbax D2H-snapshots
-                    # synchronously, writes in background — the loop
-                    # doesn't stall for serialization). Host-offload
-                    # state: wait, because the background writer would
-                    # race the in-place numpy Adagrad updates.
-                    ckpt.save(global_step, *state,
-                              vocabulary_size=cfg.vocabulary_size,
-                              wait=offload, epoch=completed_epochs,
-                              vocab_state=(vocab.state_payload()
-                                           if vocab is not None
-                                           else None))
+                    with span("train/checkpoint_pause",
+                              seconds="train/checkpoint_pause_seconds"
+                              ) as pause:
+                        state = (lk.state() if offload
+                                 else ckpt_state(cfg, table, acc))
+                        # Device arrays: async save (orbax D2H-snapshots
+                        # synchronously, writes in background — the loop
+                        # doesn't stall for serialization). Host-offload
+                        # state: wait, because the background writer
+                        # would race the in-place numpy Adagrad updates.
+                        ckpt.save(global_step, *state,
+                                  vocabulary_size=cfg.vocabulary_size,
+                                  wait=offload, epoch=completed_epochs,
+                                  vocab_state=(vocab.state_payload()
+                                               if vocab is not None
+                                               else None))
                     last_periodic_save = (global_step, completed_epochs)
                     if tel is not None:
-                        # fmlint: disable=R003 -- closes the pause sample
-                        dt_ck = time.perf_counter() - t_ck
-                        tel.count("train/checkpoint_pause_seconds",
-                                  dt_ck)
-                        tel.count("train/checkpoints")
-                        t_step_prev += dt_ck  # keep the pause out of
-                        # the next step's step_seconds sample
+                        t_step_prev += pause.dur  # keep the pause out
+                        # of the next step's step_seconds sample
+            if not stopping:
+                # The epoch barrier: from the iterator's exhaustion
+                # until the NEXT epoch's first dispatch returns (or the
+                # loop's end) — telemetry flush, validation, the cold
+                # input pipeline: what the steady step rate leaves out.
+                barrier = begin("train/epoch_barrier",
+                                seconds="train/epoch_barrier_seconds")
             flush_log()  # deferred loss lines land at the epoch barrier
             if bad_tracker is not None and bad_tracker.bad:
                 # Cumulative run-level view: the breaker and quarantine
@@ -1948,12 +1925,10 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                 # against the refreshed map + reset rows.
                 _vocab_barrier(f"epoch {epoch}")
             if cfg.validation_files and not stopping:
-                # fmlint: disable=R003 -- feeds the train/
-                # validation_seconds counter (the train/validation span
-                # is the timeline view)
-                t_val = time.perf_counter()
                 vmb = cfg.validation_max_batches or None
-                with span("train/validation", epoch=epoch):
+                with span("train/validation", leaf=False,
+                          seconds="train/validation_seconds",
+                          epoch=epoch):
                     if multi_process:
                         # preempt rides the lockstep window allgather:
                         # a SIGTERM during a long validation sweep
@@ -1983,23 +1958,15 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                 if summaries is not None:
                     summaries.add("validation/auc", global_step, auc)
                 if tel is not None:
-                    # fmlint: disable=R003 -- closes the pause sample
-                    tel.count("train/validation_seconds",
-                              time.perf_counter() - t_val)
                     tel.set("validation/auc", auc)
                     # fmlint: disable=R001 -- auc is already a host
                     # python float from the streamed AUC merge
                     tel.add_scalar("validation/auc", global_step,
                                    float(auc))
             if summaries is not None:  # epoch barrier: bulk-fetch + write
-                # fmlint: disable=R003 -- feeds the train/
-                # summary_pause_seconds counter (always-on aggregate)
-                t_sum = time.perf_counter()
-                summaries.flush()
-                if tel is not None:
-                    # fmlint: disable=R003 -- closes the pause sample
-                    tel.count("train/summary_pause_seconds",
-                              time.perf_counter() - t_sum)
+                with span("train/summary_flush",
+                          seconds="train/summary_pause_seconds"):
+                    summaries.flush()
             if tel is not None:
                 # Epoch barrier: the one point buffered device scalars
                 # are bulk-fetched and the JSONL reaches disk for sure.
@@ -2033,6 +2000,8 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                     last_periodic_save = (global_step,
                                           completed_epochs)
                     raise ClusterGrowth(plan)
+        if barrier is not None:
+            barrier.end()
         flush_log()
         loss_val = float(loss) if loss is not None else loss_val
         # The final save IS a barrier point (vocab/table.py's contract):
@@ -2165,6 +2134,9 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
             _record_crash(tel, logger, e, global_step)
         raise
     finally:
+        for phase in (starting, barrier):
+            if phase is not None:
+                phase.end()
         # The session's resident allocations leave the ledger here —
         # crash or clean exit — so an elastic-recovered session
         # re-registers fresh sizes instead of double-counting, and the

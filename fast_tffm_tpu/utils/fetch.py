@@ -198,24 +198,16 @@ class ChunkedFetcher:
         self._err.clear()
 
     def _fetch_and_consume(self, pending) -> None:
-        # span (obs/trace; no-op unless the run traces): every bulk
-        # D2H — predict/evaluate chunks AND barrier scalar drains —
-        # shows up on the timeline, on the thread that paid for it.
-        # The always-on fetch/d2h_seconds counter beside it is the D2H
-        # share of the fmstat predict attribution (one sample per
-        # CHUNK — FETCH_CHUNK_BATCHES batches — not per batch).
-        import time
-        from fast_tffm_tpu.obs.telemetry import active
+        # span (obs/trace): every bulk D2H — predict/evaluate chunks
+        # AND barrier scalar drains — shows up on the timeline, on the
+        # thread that paid for it. Its always-on fetch/d2h_seconds
+        # counter is the D2H share of the fmstat predict attribution
+        # (one sample per CHUNK — FETCH_CHUNK_BATCHES batches — not
+        # per batch).
         from fast_tffm_tpu.obs.trace import span
-        tel = active()
-        # fmlint: disable=R003 -- feeds the always-on aggregate; the
-        # span beside it is the timeline view
-        t0 = time.perf_counter()
-        with span("fetch/bulk", n=len(pending)):
+        with span("fetch/bulk", seconds="fetch/d2h_seconds",
+                  n=len(pending)):
             self._fetch_and_consume_inner(pending)
-        if tel is not None:
-            # fmlint: disable=R003 -- closes the d2h sample
-            tel.count("fetch/d2h_seconds", time.perf_counter() - t0)
 
     def _fetch_and_consume_inner(self, pending) -> None:
         arrs = [a for a, _ in pending]

@@ -1,17 +1,9 @@
-"""Step timing + profiling hooks.
-
-The reference has no project-owned profiling (SURVEY.md §5 "Tracing");
-here every train step can be wrapped in a ``jax.profiler`` trace
-annotation and throughput is measured with ``block_until_ready`` fences.
-"""
+"""Step timing: the windowed examples/sec of the loss lines. (Stages
+are timed and put on the profiler's clock by ``obs/trace.span``.)"""
 
 from __future__ import annotations
 
-import contextlib
-import os
 import time
-
-import jax
 
 
 class StepTimer:
@@ -61,31 +53,3 @@ class StepTimer:
     @property
     def steps(self) -> int:
         return self._steps
-
-
-@contextlib.contextmanager
-def trace_span(name: str):
-    """jax.profiler annotation; shows up in TensorBoard/Perfetto traces."""
-    with jax.profiler.TraceAnnotation(name):
-        yield
-
-
-@contextlib.contextmanager
-def profile_to(log_dir: str):
-    """Trace the body into ``log_dir`` (created if missing — jax's own
-    error for a missing dir is an opaque profiler failure mid-run).
-
-    stop_trace runs EXACTLY once, and only if start_trace succeeded: a
-    start_trace that raises (unwritable dir, trace already running)
-    must not trigger a stop here — that would either mask the original
-    error with "no trace in progress" or, worse, stop an OUTER trace
-    the caller still owns."""
-    os.makedirs(log_dir, exist_ok=True)
-    started = False
-    try:
-        jax.profiler.start_trace(log_dir)
-        started = True
-        yield
-    finally:
-        if started:
-            jax.profiler.stop_trace()

@@ -231,7 +231,6 @@ def test_train_emits_parseable_jsonl_with_all_stages(tmp_path, rng):
     assert c["train/epochs"] == 2
     # examples/sec gauges from the shared StepTimer window
     assert g["train/examples_per_sec_window"] > 0
-    assert g["train/examples_per_sec_total"] > 0
     assert 0.0 <= g["validation/auc"] <= 1.0
     # run metadata on the event itself
     assert last["run"]["kind"] == "train"

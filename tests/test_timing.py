@@ -1,12 +1,9 @@
-"""utils/timing.py: StepTimer window semantics and profile_to's
-start/stop lifecycle (ISSUE 2 satellites)."""
-
-import os
+"""utils/timing.py: StepTimer window semantics (ISSUE 2 satellites)."""
 
 import pytest
 
 import fast_tffm_tpu.utils.timing as timing
-from fast_tffm_tpu.utils.timing import StepTimer, profile_to
+from fast_tffm_tpu.utils.timing import StepTimer
 
 
 class FakeClock:
@@ -71,54 +68,3 @@ def test_reset_clears_everything(clock):
     t.tick(10)
     assert t.steps == 1
     assert t.total_examples_per_sec == pytest.approx(5.0)
-
-
-# ---------------------------------------------------------------- profile_to
-
-class FakeProfiler:
-    def __init__(self, fail_start=False):
-        self.starts = []
-        self.stops = 0
-        self.fail_start = fail_start
-
-    def start_trace(self, log_dir):
-        if self.fail_start:
-            raise RuntimeError("trace already in progress")
-        self.starts.append(log_dir)
-
-    def stop_trace(self):
-        self.stops += 1
-
-
-@pytest.fixture
-def profiler(monkeypatch):
-    p = FakeProfiler()
-    monkeypatch.setattr(timing.jax, "profiler", p)
-    return p
-
-
-def test_profile_to_creates_log_dir_and_stops_once(tmp_path, profiler):
-    d = str(tmp_path / "a" / "b")  # parent missing too
-    with profile_to(d):
-        pass
-    assert os.path.isdir(d)
-    assert profiler.starts == [d] and profiler.stops == 1
-
-
-def test_profile_to_stops_once_when_body_raises(tmp_path, profiler):
-    d = str(tmp_path / "t")
-    with pytest.raises(ValueError, match="body failed"):
-        with profile_to(d):
-            raise ValueError("body failed")
-    assert profiler.stops == 1
-
-
-def test_profile_to_no_stop_when_start_fails(tmp_path, monkeypatch):
-    """start_trace raising must NOT trigger a stop: that would mask
-    the original error or stop an outer trace the caller owns."""
-    p = FakeProfiler(fail_start=True)
-    monkeypatch.setattr(timing.jax, "profiler", p)
-    with pytest.raises(RuntimeError, match="trace already in progress"):
-        with profile_to(str(tmp_path / "t")):
-            pass  # pragma: no cover - never reached
-    assert p.stops == 0
