@@ -39,9 +39,9 @@ rounds, the same JSON carries the attribution breakdown:
   (the reference's second workload: parse keep_empty -> score ->
   ordered scores),
 - ``l64_e2e``: the DEFAULT production regime (auto ladder -> L=64 for
-  Criteo-39 data; kernel auto -> Pallas there) — the headline's
-  hand-tuned L=48 is the XLA cell, so this line both documents the
-  default path and keeps the Pallas kernel exercised end-to-end.
+  Criteo-39 data; a one-chip train step takes the host unique, so
+  kernel auto -> XLA there as at the headline's hand-tuned L=48) —
+  this line documents the default path.
 
 Every e2e line (headline, ffm, order3, hashed, predict, k16, l64) is the median of TRIALS
 runs with the per-trial values alongside: a single late-in-the-run
@@ -117,8 +117,9 @@ def make_cfg(path):
     from fast_tffm_tpu.config import FmConfig
     # L=48 covers Criteo's 39 features with the least padding; it won
     # over 64 on an earlier device where the loop was H2D-bound (record
-    # removed in PR 21). The DEFAULT ladder puts this data at L=64, the
-    # Pallas cell — chip_smoke.py runs that one; ROADMAP S1/D8.
+    # removed in PR 21). The DEFAULT ladder puts this data at L=64 (the
+    # Pallas cell only under an explicit dedup = device) —
+    # chip_smoke.py runs that width; ROADMAP S1/D8.
     return FmConfig(vocabulary_size=1 << 20, factor_num=8, batch_size=B,
                     learning_rate=0.05, factor_lambda=1e-6,
                     bias_lambda=1e-6, max_features_per_example=48,
@@ -127,10 +128,11 @@ def make_cfg(path):
 
 
 def _raw_mode(cfg):
-    """Whether the resolved spec ships raw ids (dedup=device on the one
-    real chip) — the pipeline must build matching batches."""
+    """Whether the resolved TRAIN spec ships raw ids (an explicit
+    dedup = device; auto gives a one-chip train step the host unique)
+    — the pipeline must build matching batches."""
     from fast_tffm_tpu.models.fm import ModelSpec
-    return ModelSpec.from_config(cfg).dedup == "device"
+    return ModelSpec.from_config(cfg, training=True).dedup == "device"
 
 
 def _wire_dispatch(cfg, step):
@@ -145,7 +147,8 @@ def _wire_dispatch(cfg, step):
     wire = resolve_wire(cfg, train=True)
     enc = WireEncoder(wire, pad_id=cfg.pad_id)
     if wire.packed:
-        pstep = make_packed_train_step(ModelSpec.from_config(cfg))
+        pstep = make_packed_train_step(
+            ModelSpec.from_config(cfg, training=True))
 
         def dispatch(table, acc, batch):
             wb = enc.encode_train(batch)
@@ -274,7 +277,7 @@ def run_ffm_e2e(tmp):
     with open(cfg.train_files[0], "w") as fh:
         fh.write("\n".join(synth_ffm_lines((n_warm + n_timed) * B_ffm,
                                            1 << 18)) + "\n")
-    step = make_train_step(ModelSpec.from_config(cfg))
+    step = make_train_step(ModelSpec.from_config(cfg, training=True))
     return [run_e2e(cfg, step, n_warm=n_warm) for _ in range(TRIALS)]
 
 
@@ -294,7 +297,7 @@ def run_order3_e2e(tmp):
     run_ffm_e2e on why). Reuses the FM data file already in ``tmp``."""
     from fast_tffm_tpu.models.fm import ModelSpec, make_train_step
     cfg = order3_cfg(tmp)
-    step = make_train_step(ModelSpec.from_config(cfg))
+    step = make_train_step(ModelSpec.from_config(cfg, training=True))
     return [run_e2e(cfg, step, n_warm=3) for _ in range(TRIALS)]
 
 
@@ -305,7 +308,7 @@ def run_k16(cfg16):
     Reuses the headline data file via ``cfg16``."""
     import dataclasses
     from fast_tffm_tpu.models.fm import ModelSpec, make_train_step
-    spec = ModelSpec.from_config(cfg16)
+    spec = ModelSpec.from_config(cfg16, training=True)
     step = make_train_step(spec)
     e2e = [run_e2e(cfg16, step, n_warm=3) for _ in range(TRIALS)]
     dev = {}
@@ -358,7 +361,7 @@ def run_wire_sweep(path):
     for name, wf, wd in WIRE_VARIANTS:
         cfg = dataclasses.replace(make_cfg(path), wire_format=wf,
                                   wire_dtypes=wd)
-        step = make_train_step(ModelSpec.from_config(cfg))
+        step = make_train_step(ModelSpec.from_config(cfg, training=True))
         h2d, wire_bytes, logical_bytes = run_h2d_only(cfg)
         e2e = statistics.median(
             run_e2e(cfg, step, n_warm=3) for _ in range(TRIALS))
@@ -527,7 +530,7 @@ def cfg_e2e_trials(cfg):
     timing protocol — the one body behind every cfg-generic e2e line
     (hashed, l64), so their protocols cannot drift apart."""
     from fast_tffm_tpu.models.fm import ModelSpec, make_train_step
-    step = make_train_step(ModelSpec.from_config(cfg))
+    step = make_train_step(ModelSpec.from_config(cfg, training=True))
     return [run_e2e(cfg, step, n_warm=3) for _ in range(TRIALS)]
 
 
@@ -570,7 +573,7 @@ def run_predict_e2e(cfg):
     return [one_sweep(cfg) for _ in range(TRIALS)], best, search
 
 
-def regime_stamp(cfg):
+def regime_stamp(cfg, training=True):
     """The (L, dedup, kernel) a config's hot loop actually runs —
     stamped into every bench line so a future reader of the JSON
     alone can tell WHICH cell of the kernel/bucket matrix
@@ -578,10 +581,11 @@ def regime_stamp(cfg):
     the cell where the Pallas/XLA winner flips, and the JSON didn't say
     so). Kernel goes through models.fm.resolved_kernel — the same
     resolution the traced step uses, so the stamp can't drift from the
-    dispatch."""
+    dispatch. ``training``: whether the line trains or scores (dedup =
+    auto resolves by use on one chip)."""
     from fast_tffm_tpu.data.pipeline import effective_L_cap
     from fast_tffm_tpu.models.fm import ModelSpec, resolved_kernel
-    spec = ModelSpec.from_config(cfg)
+    spec = ModelSpec.from_config(cfg, training=training)
     if cfg.max_features_per_example == 0:
         # Unlimited features: the generic path extends buckets per
         # BATCH, so the widest width is data-dependent. auto's kernel
@@ -636,10 +640,9 @@ def _line_cfg(name, train_path):
         return dataclasses.replace(make_cfg(train_path), factor_num=16)
     if name == "l64":
         # The DEFAULT production regime for Criteo-39 data (auto ladder
-        # lands at L=64; dedup=device on one chip -> kernel auto
-        # resolves to Pallas): the headline's hand-tuned L=48 is the
-        # XLA cell, so without this line the bench would never run the
-        # Pallas path end-to-end (round-4 review weak #6).
+        # lands at L=64). A one-chip train step takes the host unique,
+        # where auto resolves to XLA at every width, so Pallas runs
+        # only in run_k16's device-only pair.
         return dataclasses.replace(make_cfg(train_path),
                                    bucket_ladder=(64,))
     raise SystemExit(f"unknown bench line {name!r}")
@@ -651,7 +654,7 @@ def _run_line(name, train_path):
     through, so they cannot drift apart."""
     tmp = os.path.dirname(train_path)
     cfg = _line_cfg(name, train_path)  # raises on unknown names
-    out = {"regime": regime_stamp(cfg)}
+    out = {"regime": regime_stamp(cfg, training=name != "predict")}
     if name == "ffm":
         out["trials"] = run_ffm_e2e(tmp)
     elif name == "order3":
@@ -1022,7 +1025,7 @@ def main():
         l64_res = _isolated_line("l64", path)
 
         cfg = make_cfg(path)
-        spec = ModelSpec.from_config(cfg)
+        spec = ModelSpec.from_config(cfg, training=True)
         step = make_train_step(spec)
 
         # e2e regime search over the parallel host data plane: one
@@ -1322,7 +1325,8 @@ def vocab_overhead_main():
         admit_cfg = dataclasses.replace(
             base, vocab_mode="admit", vocab_admit_threshold=2.0,
             vocab_decay=0.5, vocab_sketch_mb=1.0)
-        fixed_step = make_train_step(ModelSpec.from_config(base))
+        fixed_step = make_train_step(
+            ModelSpec.from_config(base, training=True))
         fixed = [run_e2e(base, fixed_step) for _ in range(TRIALS)]
         vocab = VocabRuntime.from_config(admit_cfg)
         # Populate the slot map the way a running stream would: one
@@ -1336,7 +1340,8 @@ def vocab_overhead_main():
                                     vocab=vocab):
             vocab.note_trained(batch)
         vocab.barrier(None)
-        admit_step = make_train_step(ModelSpec.from_config(admit_cfg))
+        admit_step = make_train_step(
+            ModelSpec.from_config(admit_cfg, training=True))
         admit = [run_e2e(admit_cfg, admit_step, vocab=vocab)
                  for _ in range(TRIALS)]
     f_med = statistics.median(fixed)

@@ -118,11 +118,15 @@ class FmConfig:
     # tools/kernel_probe.py.
     kernel: str = "auto"            # "auto" | "xla" | "pallas"
     # Where the per-batch unique-id pass runs. "host": the pipeline
-    # dedups and ships (uniq_ids, local_idx) — required by mesh,
-    # multi-process, and offload paths. "device": the pipeline ships raw
-    # ids and the jitted step runs jnp.unique on the chip — ~40% less
-    # host->device traffic per step for ~3 us of TPU sort (single-device
-    # jit only). "auto" picks device where it applies. Resolved in
+    # dedups (the C++ builder, while it parses) and ships (uniq_ids[U],
+    # local_idx), U the power-of-two rung of the batch's distinct rows
+    # — required by mesh, multi-process, and offload paths. "device":
+    # the pipeline ships raw ids; a scorer gathers them directly, a
+    # train step runs jnp.unique on the chip over U = B*L + 1 slots
+    # (single-device jit only). "auto" on one device resolves by use:
+    # training takes "host", because the step pays for every slot its
+    # scatters walk (the v5e's readings: PERF.md section 5); scoring
+    # needs no unique and ships raw ids. Resolved in
     # ModelSpec.from_config.
     dedup: str = "auto"             # "auto" | "host" | "device"
     # Wire format (README "Wire format"; fast_tffm_tpu/wire.py): how a

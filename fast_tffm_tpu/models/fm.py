@@ -45,16 +45,23 @@ class ModelSpec:
     bias_lambda: float
     learning_rate: float
     kernel: str = "xla"
-    # "host": the pipeline dedups ids and ships (uniq_ids, local_idx).
-    # "device": the pipeline ships raw ids [B, L] and the step runs
-    # jnp.unique on device — ~40% less H2D per step (no uniq_ids array,
-    # and the pipeline skips its dedup pass) for ~3 us of TPU sort.
-    # Only the single-device jit paths support "device" (mesh/offload/
-    # multi-process need the host-side unique contract).
+    # "host": the pipeline dedups ids and ships (uniq_ids[U],
+    # local_idx), U fitted to the batch's distinct rows (power-of-two
+    # ladder). "device": the pipeline ships raw ids [B, L]; a scorer
+    # gathers them directly, a train step runs jnp.unique on device
+    # over U = B*L + 1 slots. Only the single-device jit paths support
+    # "device" (mesh/offload/multi-process need the host-side unique
+    # contract). Resolution of "auto": from_config.
     dedup: str = "host"
 
     @classmethod
-    def from_config(cls, cfg: FmConfig) -> "ModelSpec":
+    def from_config(cls, cfg: FmConfig, *,
+                    training: Optional[bool] = None) -> "ModelSpec":
+        """``training`` says what the spec's programs are for: True a
+        train step, False scoring. It decides ``dedup = auto`` on one
+        device (below), and there is no default: a caller that leaves
+        it out where the answer depends on it gets an error, not the
+        other use's wire."""
         kernel = cfg.kernel
         if kernel == "pallas" and (cfg.model_type == "ffm"
                                    or cfg.order != 2):
@@ -81,11 +88,23 @@ class ModelSpec:
                 kernel = "xla"
         dedup = cfg.dedup
         if dedup == "auto":
-            # Device dedup wherever it applies: the plain single-device
-            # jit (mesh, offload, and multi-process all rely on the
-            # host-side unique contract).
-            dedup = ("device" if jax.device_count() == 1
-                     and cfg.lookup == "device" else "host")
+            # Resolved by use. Mesh, offload and multi-process rely on
+            # the host-side unique contract whatever they run. On the
+            # plain single-device jit, scoring needs no unique at all
+            # (score_body gathers the raw ids) and is host-bound, so it
+            # ships raw ids; a train step needs unique slots for its
+            # backward scatter and pays for every slot it walks (113
+            # ns in each scatter-add on the v5e, pad slot or real row;
+            # PERF.md section 5), so it takes the host unique, whose U
+            # is the ladder rung of the batch's distinct rows, not the
+            # device unique's B*L + 1.
+            one_chip = jax.device_count() == 1 and cfg.lookup == "device"
+            if one_chip and training is None:
+                raise TypeError(
+                    "ModelSpec.from_config: dedup = auto on one device "
+                    "resolves by use; pass training=True (a train "
+                    "step) or training=False (scoring)")
+            dedup = "device" if one_chip and not training else "host"
         return cls(model_type=cfg.model_type, order=cfg.order,
                    factor_num=cfg.factor_num, field_num=cfg.field_num,
                    vocabulary_size=cfg.vocabulary_size,
@@ -291,9 +310,10 @@ def train_step_body(spec: ModelSpec, table, acc, labels, weights, uniq_ids,
     device lookup backend, fused into the jit (lookup.py documents the
     seam; grad_body is the shared middle).
 
-    With ``spec.dedup == 'device'`` the caller ships RAW ids in
-    ``local_idx`` and ``uniq_ids=None``; the unique pass runs here on
-    device (_device_dedup) instead of on the host.
+    With ``spec.dedup == 'device'`` (an explicit ``dedup = device``;
+    ``auto`` never resolves a train step to it) the caller ships RAW
+    ids in ``local_idx`` and ``uniq_ids=None``; the unique pass runs
+    here on device (_device_dedup) instead of on the host.
     """
     if spec.dedup == "device":
         if uniq_ids is not None:  # trace-time: batches must be raw-ids
@@ -438,8 +458,9 @@ def score_body(spec: ModelSpec, table, uniq_ids, local_idx, vals,
     costs a train step 14.7 ms at B=8192 x 64 slots on the v5e (scope
     ``dedup``; PERF.md section 5, PR 25), most of one score call.
     The direct gather is BIT-identical: same table rows summed in the
-    same slot order. Training keeps ``_device_dedup`` — the backward
-    scatter needs unique rows for exact sparse Adagrad."""
+    same slot order. A train step needs unique rows for its backward
+    scatter (exact sparse Adagrad): ``auto`` gives it the host unique,
+    an explicit ``dedup = device`` ``_device_dedup``."""
     if spec.dedup == "device":
         if uniq_ids is not None:
             raise ValueError(

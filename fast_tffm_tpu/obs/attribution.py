@@ -195,6 +195,10 @@ def attribution(summary: Dict[str, Any]) -> Dict[str, Any]:
             c.get("pipeline/build_seconds")),
         "ring_occupancy": g.get("pipeline/ring_occupancy"),
         "dedup_hit_rate": dedup_hit_rate(c),
+        # Distinct rows over the uniq_ids slots shipped (telemetry.
+        # pipeline_batch). None in raw-ids mode.
+        "uniq_slot_fill": _frac(c.get("pipeline/uniq_rows"),
+                                c.get("pipeline/uniq_slots")),
         "padding_waste_fraction": padding_waste(c),
         "parse_errors": c.get("pipeline/parse_errors", 0),
         # Fault-tolerance accounting (README "Fault tolerance"): lines
@@ -1001,6 +1005,7 @@ def render(summary: Dict[str, Any]) -> str:
          f"{_fmt(att['host_build_concurrency'])}"),
         ("ring occupancy (last)", att["ring_occupancy"]),
         ("dedup hit rate", att["dedup_hit_rate"]),
+        ("unique-slot fill", att["uniq_slot_fill"]),
         ("padding-waste fraction", att["padding_waste_fraction"]),
         ("parse errors", att["parse_errors"]),
         ("bad lines skipped", att["bad_lines"]),

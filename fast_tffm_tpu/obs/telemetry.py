@@ -365,6 +365,10 @@ class RunTelemetry:
                 (np.asarray(batch.uniq_ids)[batch.local_idx]
                  != pad_id).sum())
             self.count("pipeline/uniq_rows", real_uniq)
+            # The U shipped (ladder rung, pad slots included): rows
+            # over slots is the fill of the fitted unique table, the
+            # share of the step's gather/scatter slots that do work.
+            self.count("pipeline/uniq_slots", len(batch.uniq_ids))
         self.count("pipeline/feature_slots", B * L)
         self.count("pipeline/feature_nnz", real)
         if build_seconds is not None:

@@ -74,7 +74,7 @@ class CompiledScorer:
                                              make_batch_scorer,
                                              ships_raw_batches)
         from fast_tffm_tpu.wire import WireEncoder, resolve_wire
-        spec = ModelSpec.from_config(cfg)
+        spec = ModelSpec.from_config(cfg, training=False)
         if dedup is not None:
             spec = dataclasses.replace(spec, dedup=dedup)
         self.spec = spec

@@ -76,7 +76,7 @@ def evaluate(cfg: FmConfig, table: jax.Array, files,
     ``update(scores, labels, weights)`` surface) is fed the SAME host
     score chunks the AUC update consumes — the publish-gate quality
     loop's zero-added-device-fetch seam."""
-    spec = ModelSpec.from_config(cfg)
+    spec = ModelSpec.from_config(cfg, training=False)
     score_fn = make_batch_scorer(spec, mesh=mesh, backend=backend)
     raw = ships_raw_batches(spec, mesh=mesh, backend=backend)
     if vocab is not None:
@@ -167,7 +167,7 @@ def evaluate_distributed(cfg: FmConfig, table: jax.Array, files, mesh,
     from fast_tffm_tpu.parallel.liveness import guarded_collective
     from fast_tffm_tpu.parallel.sharded import (lockstep_score_batches,
                                                 make_sharded_score_fn)
-    spec = ModelSpec.from_config(cfg)
+    spec = ModelSpec.from_config(cfg, training=False)
     score_fn = make_sharded_score_fn(spec, mesh)
     auc = StreamingAUC()
     n = 0
@@ -595,7 +595,7 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
     created here (checkpoint manager, summaries, signal handlers,
     profiler) is torn down here, so the driver can safely re-enter
     after a recovery."""
-    spec = ModelSpec.from_config(cfg)
+    spec = ModelSpec.from_config(cfg, training=True)
     logger.info("train regime: %s", regime_line(spec, cfg))
     multi_process = jax.process_count() > 1
     stream_mode = getattr(cfg, "run_mode", "epochs") == "stream"

@@ -1,6 +1,7 @@
 """dedup=device (raw-ids) mode: the pipeline ships raw feature ids and
 the jitted step runs jnp.unique on device — must be bit-equivalent to
-the host-dedup path and wired end-to-end through the CLI."""
+the host-dedup path; and dedup=auto on one device, wired end-to-end
+through the CLI (training: host unique; scoring: raw ids)."""
 
 import dataclasses
 import os
@@ -135,11 +136,13 @@ def test_mode_mismatch_raises(tmp_path):
                             raw_ids=True, fixed_shape=True))
 
 
-def test_cli_e2e_device_dedup_auto(tmp_path):
-    """On a single device, dedup=auto resolves to device mode; the full
-    CLI train->predict must work and produce sane scores (run in a
-    subprocess with exactly one CPU device — the in-process test env
-    pins 8 virtual devices, which resolves auto to host)."""
+def test_cli_e2e_auto_resolves_by_use(tmp_path):
+    """On a single device, dedup=auto resolves by use: training takes
+    the host unique (slots fitted to the batch's distinct rows),
+    scoring ships raw ids and no uniq_ids. The full CLI train->predict
+    must work and produce sane scores (run in a subprocess with exactly
+    one CPU device — the in-process test env pins 8 virtual devices,
+    which resolves auto to host for every use)."""
     path = _write(tmp_path, n=64, seed=17)
     cfg_path = tmp_path / "dd.cfg"
     cfg_path.write_text(f"""
@@ -163,17 +166,42 @@ score_path = {tmp_path}/score
     env.pop("XLA_FLAGS", None)
     code = (
         "import jax, numpy as np, run_tffm\n"
+        "from fast_tffm_tpu import scoring, train as tr\n"
         "from fast_tffm_tpu.config import load_config\n"
-        "from fast_tffm_tpu.models.fm import ModelSpec\n"
+        "from fast_tffm_tpu.models import fm\n"
         "assert jax.device_count() == 1, jax.device_count()\n"
         f"cfg = load_config(r'{cfg_path}')\n"
-        "assert ModelSpec.from_config(cfg).dedup == 'device'\n"
+        "t = fm.ModelSpec.from_config(cfg, training=True)\n"
+        "s = fm.ModelSpec.from_config(cfg, training=False)\n"
+        "assert (t.dedup, s.dedup) == ('host', 'device')\n"
+        "assert 'dedup=host ' in fm.regime_line(t, cfg)\n"
+        "assert 'dedup=device ' in fm.regime_line(s, cfg)\n"
+        "assert fm.ships_raw_batches(s) and not fm.ships_raw_batches(t)\n"
+        "slots, raw = [], []\n"
+        "make = tr.make_train_step\n"
+        "def probed(spec):\n"
+        "    step = make(spec)\n"
+        "    def call(table, acc, **kw):\n"
+        "        slots.append((kw['uniq_ids'].shape[0],\n"
+        "                      kw['local_idx'].size + 1))\n"
+        "        return step(table, acc, **kw)\n"
+        "    return call\n"
+        "tr.make_train_step = probed\n"
+        "score_batch = scoring.CompiledScorer.score_batch\n"
+        "def scored(self, table, batch):\n"
+        "    raw.append(batch.uniq_ids is None)\n"
+        "    return score_batch(self, table, batch)\n"
+        "scoring.CompiledScorer.score_batch = scored\n"
         f"assert run_tffm.main(['train', r'{cfg_path}']) == 0\n"
         f"assert run_tffm.main(['predict', r'{cfg_path}']) == 0\n"
+        "assert len(slots) == 8 and all(u < bl1 for u, bl1 in slots), slots\n"
+        "assert raw and all(raw), raw\n"
     )
     out = subprocess.run([sys.executable, "-c", code], cwd=REPO, env=env,
                          capture_output=True, text=True, timeout=300)
     assert out.returncode == 0, out.stdout + out.stderr
+    assert "train regime: backend=cpu devices=1 dedup=host" in out.stderr
+    assert "predict regime: backend=cpu devices=1 dedup=device" in out.stderr
     scores = np.loadtxt(tmp_path / "score" / "d.txt.score")
     assert len(scores) == 64
     assert np.isfinite(scores).all() and (0 <= scores).all() \

@@ -1,0 +1,334 @@
+"""The one-chip train step runs on slots fitted to the batch's distinct
+rows: ``dedup = auto`` resolves by use (training: host unique on the
+power-of-two ladder; scoring: raw ids), the pipeline counts the slots
+it ships, and the benchmark's two metric files read that count."""
+
+import dataclasses
+import json
+import os
+import re
+import subprocess
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+from fast_tffm_tpu.config import FmConfig
+from fast_tffm_tpu.data import cparser
+from fast_tffm_tpu.data.pipeline import (_ladder_fit, _uniq_ladder,
+                                         batch_iterator)
+from fast_tffm_tpu.models.fm import (ModelSpec, batch_args,
+                                     init_accumulator, init_table,
+                                     make_train_step, regime_line,
+                                     ships_raw_batches)
+from fast_tffm_tpu.obs.telemetry import RunTelemetry, activate
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+B, L, VOCAB = 64, 16, 5000
+
+
+def _zipf_corpus(tmp_path, ffm, n=3 * B, seed=7, field_num=4):
+    """Click-log shaped: every example has L features whose ids repeat
+    across the batch (Zipf a=1.35, as the benchmark's corpora)."""
+    rng = np.random.default_rng(seed)
+    lines = []
+    for _ in range(n):
+        ids = np.unique(rng.zipf(1.35, size=L) % VOCAB)
+        toks = [(f"{int(rng.integers(0, field_num))}:" if ffm else "")
+                + f"{i}:{rng.random():.4f}" for i in ids]
+        lines.append(" ".join(["1" if rng.random() < 0.3 else "0"] + toks))
+    p = tmp_path / "zipf.txt"
+    p.write_text("\n".join(lines) + "\n")
+    return str(p)
+
+
+def _cfg(path, **kw):
+    base = dict(vocabulary_size=VOCAB, factor_num=4, batch_size=B,
+                train_files=(path,), shuffle=False,
+                bucket_ladder=(4, 8, L), max_features_per_example=L,
+                learning_rate=0.1, factor_lambda=1e-4, bias_lambda=1e-4)
+    base.update(kw)
+    return FmConfig(**base)
+
+
+# ---- (a) the resolution, by use ----------------------------------------
+
+@pytest.mark.parametrize("devices,lookup,configured,training,want", [
+    (1, "device", "auto", True, "host"),     # one chip trains on fitted slots
+    (1, "device", "auto", False, "device"),  # and scores raw ids
+    (8, "device", "auto", True, "host"),     # a mesh: as before, both uses
+    (8, "device", "auto", False, "host"),
+    (1, "host", "auto", True, "host"),       # offload: as before
+    (1, "host", "auto", False, "host"),
+    (1, "device", "device", True, "device"),  # explicit values keep their
+    (1, "device", "host", False, "host"),     # meaning for either use
+])
+def test_auto_dedup_resolves_by_use(monkeypatch, tmp_path, devices, lookup,
+                                    configured, training, want):
+    monkeypatch.setattr(jax, "device_count", lambda: devices)
+    cfg = _cfg(str(tmp_path / "none.txt"), lookup=lookup, dedup=configured)
+    spec = ModelSpec.from_config(cfg, training=training)
+    assert spec.dedup == want
+    assert f"dedup={want} " in regime_line(spec, cfg)
+    assert ships_raw_batches(spec) is (want == "device")
+    # no default use: where the answer depends on it, leaving it out is
+    # an error, not the other use's wire
+    if devices == 1 and lookup == "device" and configured == "auto":
+        with pytest.raises(TypeError, match="training=True"):
+            ModelSpec.from_config(cfg)
+    else:
+        assert ModelSpec.from_config(cfg) == spec
+
+
+def test_every_program_says_what_its_spec_is_for():
+    """The 8-device rig never reaches the one-chip resolution, so a
+    caller that forgets ``training=`` would pass every test here and
+    fail on the chip. Hold the programs to it by their source."""
+    import ast
+    roots = [os.path.join(REPO, "fast_tffm_tpu"), os.path.join(REPO, "tools"),
+             os.path.join(REPO, "benchmarks")]
+    files = [os.path.join(REPO, f) for f in (
+        "bench.py", "chip_smoke.py", "run_tffm.py", "__graft_entry__.py")]
+    for root in roots:
+        for d, _, names in os.walk(root):
+            files += [os.path.join(d, n) for n in names if n.endswith(".py")]
+    calls, missing = 0, []
+    for path in files:
+        with open(path) as fh:
+            tree = ast.parse(fh.read())
+        for node in ast.walk(tree):
+            if (isinstance(node, ast.Call)
+                    and isinstance(node.func, ast.Attribute)
+                    and node.func.attr == "from_config"
+                    and isinstance(node.func.value, ast.Name)
+                    and node.func.value.id == "ModelSpec"):
+                calls += 1
+                if not any(k.arg == "training" for k in node.keywords):
+                    missing.append(f"{path}:{node.lineno}")
+    assert calls >= 15 and missing == []
+
+
+# ---- (b) the step's U, and the same three steps either way -------------
+
+def _three_steps(cfg, spec):
+    table, acc = init_table(cfg, 0), init_accumulator(cfg)
+    step = make_train_step(spec)
+    losses, batches = [], []
+    for b in batch_iterator(cfg, cfg.train_files, training=True,
+                            raw_ids=ships_raw_batches(spec)):
+        table, acc, loss, _ = step(table, acc, **batch_args(b))
+        losses.append(float(loss))
+        batches.append(b)
+    assert len(batches) == 3
+    return np.asarray(table), np.asarray(acc), losses, batches
+
+
+@pytest.mark.parametrize("model", ["fm", "ffm"])
+def test_one_chip_auto_step_runs_on_the_rung_of_distinct_rows(
+        monkeypatch, tmp_path, model):
+    ffm = model == "ffm"
+    cfg = _cfg(_zipf_corpus(tmp_path, ffm),
+               **(dict(model_type="ffm", field_num=4) if ffm else {}))
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    auto = ModelSpec.from_config(cfg, training=True)
+    assert auto.dedup == "host"
+    explicit = ModelSpec.from_config(
+        dataclasses.replace(cfg, dedup="device"), training=True)
+    assert explicit.dedup == "device"
+    t_a, acc_a, loss_a, fitted = _three_steps(cfg, auto)
+    t_d, acc_d, loss_d, raw = _three_steps(cfg, explicit)
+    ladder = _uniq_ladder(B, L)
+    for f, r in zip(fitted, raw):
+        assert r.uniq_ids is None and r.local_idx.shape == (B, L)
+        distinct = len(np.unique(r.local_idx[r.local_idx != cfg.pad_id]))
+        U = f.uniq_ids.shape[0]
+        assert U == _ladder_fit(distinct + 1, ladder)   # + the pad slot
+        assert U < B * L + 1 and U <= 2 * (distinct + 1)
+        # the same rows, each once, pad row first (the builder's order)
+        real = f.uniq_ids[f.uniq_ids != cfg.pad_id]
+        assert len(real) == distinct == len(np.unique(real))
+        np.testing.assert_array_equal(f.uniq_ids[f.local_idx], r.local_idx)
+    np.testing.assert_allclose(loss_a, loss_d, rtol=1e-6)
+    touched = np.unique(np.concatenate([r.local_idx.ravel() for r in raw]))
+    np.testing.assert_allclose(t_a[touched], t_d[touched],
+                               rtol=1e-6, atol=1e-7)
+    np.testing.assert_allclose(acc_a[touched], acc_d[touched],
+                               rtol=1e-6, atol=1e-7)
+    untouched = np.setdiff1d(np.arange(cfg.num_rows), touched)
+    np.testing.assert_array_equal(t_a[untouched], t_d[untouched])
+
+
+# ---- (c) the counter ---------------------------------------------------
+
+@pytest.mark.parametrize("host_threads", [1, pytest.param(
+    4, marks=pytest.mark.skipif(not cparser.available(),
+                                reason="C++ parser extension unavailable"))])
+def test_uniq_slots_counts_the_slots_shipped(tmp_path, host_threads):
+    from fast_tffm_tpu.obs.attribution import attribution
+    cfg = _cfg(_zipf_corpus(tmp_path, ffm=False, n=5 * B),
+               host_threads=host_threads)
+    tel = RunTelemetry(str(tmp_path / "m.jsonl"), meta={"kind": "t"})
+    try:
+        with activate(tel):
+            batches = list(batch_iterator(cfg, cfg.train_files,
+                                          training=True))
+            snap = tel.registry.snapshot()["counters"]
+            raw = list(batch_iterator(cfg, cfg.train_files, training=True,
+                                      raw_ids=True))
+            after_raw = tel.registry.snapshot()["counters"]
+    finally:
+        tel.close()
+    assert len(batches) == len(raw) == 5
+    slots = sum(len(b.uniq_ids) for b in batches)
+    rows = sum(int((b.uniq_ids != cfg.pad_id).sum()) for b in batches)
+    assert snap["pipeline/uniq_slots"] == slots
+    assert snap["pipeline/uniq_rows"] == rows
+    assert 0.5 <= rows / slots <= 1.0    # a power-of-two ladder: half or more
+    att = attribution({"counters": snap, "gauges": {}, "hists": {}})
+    assert att["uniq_slot_fill"] == pytest.approx(rows / slots)
+    # raw ids ship no unique table: the sweep adds batches and no slots
+    assert after_raw["pipeline/batches"] == 10
+    assert after_raw["pipeline/uniq_slots"] == slots
+    if host_threads > 1:
+        assert snap["pipeline/worker_build_seconds"] > 0
+
+
+# ---- (d) the benchmark's two metric files ------------------------------
+
+def _stream(tmp_path, counters_by_step):
+    p = tmp_path / "metrics.jsonl"
+    p.write_text("".join(
+        json.dumps({"event": "metrics", "step": s, "counters": c}) + "\n"
+        for s, c in counters_by_step.items()))
+    return str(p)
+
+
+@pytest.mark.parametrize("name,with_counter,want", [
+    ("uniq_slot_fill", True, 19200 / 32768),
+    ("uniq_slot_fill", False, None),          # the parent: raw ids
+    ("host_build_s_per_batch", True, 0.04),
+    ("host_build_s_per_batch", False, None),  # a serial build
+])
+def test_new_metric_files_read_the_stream_or_nothing(tmp_path, name,
+                                                     with_counter, want):
+    from benchmarks.readers import telemetry_window
+    with open(os.path.join(REPO, "benchmarks", "layer_metrics",
+                           name + ".json")) as fh:
+        spec = json.load(fh)
+    assert spec["reader"] == "telemetry_window"
+    assert spec["layer"] == "host parse + build (data/)"
+    with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
+        entry = next(m for m in json.load(fh)["per_layer"]
+                     if m["name"] == name)
+    assert entry == {**{k: spec[k] for k in (
+        "name", "unit", "better", "source", "layer", "moves")},
+        "workloads": ["fm16-train-zipf", "ffm4-train-zipf"]}
+    first = {"pipeline/batches": 20, "train/examples": 16}
+    last = {"pipeline/batches": 120, "train/examples": 816}
+    if with_counter:
+        first.update({"pipeline/uniq_rows": 19200 * 20,
+                      "pipeline/uniq_slots": 32768 * 20,
+                      "pipeline/worker_build_seconds": 1.0})
+        last.update({"pipeline/uniq_rows": 19200 * 120,
+                     "pipeline/uniq_slots": 32768 * 120,
+                     "pipeline/worker_build_seconds": 5.0})
+    ctx = {"telemetry_path": _stream(tmp_path, {16: first, 816: last}),
+           "window_steps": (16, 816), "window_wall_s": 30.0}
+    value = telemetry_window.read(ctx, **spec["args"])
+    if want is None:
+        assert value is None
+    else:
+        assert value == pytest.approx(want)
+
+
+# ---- (e) a benchmark cell on one device, end to end --------------------
+# What tests/benchmarks/test_benchmark_harness.py's
+# test_a_cell_added_as_files_runs_without_editing_any checked beyond
+# the raw-id wire it pins (8*8+8 B an example; CHANGES.md, PR 26), on
+# the wire the one-chip step now takes.
+
+@pytest.fixture(scope="module")
+def tiny_root(tmp_path_factory):
+    sys.path.insert(0, os.path.join(REPO, "tests", "benchmarks"))
+    try:
+        import tiny_tree
+    finally:
+        sys.path.pop(0)
+    return tiny_tree, tiny_tree.make(str(tmp_path_factory.mktemp("tree")))
+
+
+@pytest.mark.parametrize("workload,cell_bytes", [
+    ("tiny-train", 4 + 4),              # a feature's slot index and value
+    ("tiny-ffm-train", 4 + 4 + 4),      # and its field
+])
+def test_a_one_device_cell_ships_the_fitted_unique_and_checks_out(
+        tiny_root, workload, cell_bytes):
+    tiny_tree, root = tiny_root
+    p = subprocess.run(
+        [sys.executable, "-m", "benchmarks.run", "--workload", workload,
+         "--seed", str(2 ** 31 + 26), "--seconds", "1.5", "--trace", "1",
+         "--rehearse-cpu"], cwd=root, env=tiny_tree.env(),
+        capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    out = p.stdout.strip().splitlines()
+    last = json.loads(out[-1])
+    assert last["correct"] is True and last["failed"] == 0
+    assert last["attempted"] > 0 and last["metrics"] == {}   # a rehearsal
+    assert 0 < last["device"]["busy_s"] <= last["device"]["window_s"]
+    shown = json.loads(next(l for l in out if l.startswith("metrics: "))
+                       [len("metrics: "):])
+    # B = 64 examples a step at the bucket ladder's lowest rung, L = 8:
+    # the padded rectangles and label + weight, then 4 B for each of
+    # the U slots of the unique table, U a rung under B*L + 1.
+    rect = 8 * cell_bytes + 8
+    slots = (shown["h2d_bytes_per_example"]["value"] - rect) * 64 / 4
+    assert _uniq_ladder(64, 8)[0] <= slots <= 256 < 64 * 8 + 1
+    assert 0.5 <= shown["uniq_slot_fill"]["value"] <= 1.0
+    if cparser.available():
+        assert shown["host_build_s_per_batch"]["value"] > 0
+    assert "dedup_sort_ms" not in shown      # no such scope in the step
+    assert any(l.startswith("check loss_rel_gap_max") for l in out)
+    assert any(l.startswith("check span_examples_credited_not_counted: 0 ")
+               for l in out)
+    said = next(l for l in out if l.startswith("all work over all time"))
+    n, cycles = map(int, re.search(r"of (\d+) readings, (\d+) cycles",
+                                   said).groups())
+    assert cycles >= 1 and n == cycles * 2   # 4 batches x 2 passes / 4
+
+
+def test_benchmark_json_lists_every_metric_with_its_file_and_reader():
+    """What tests/benchmarks/test_scope_metrics.py's
+    test_benchmark_json_lists_the_nine_for_both_train_cells checks
+    past its first line, which pins PR 25's nine metrics as the LAST
+    nine of per_layer (CHANGES.md, PR 26): they are all still there,
+    in their order, before the two this PR appends."""
+    from benchmarks import harness
+    with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
+        spec = json.load(fh)
+    names = [m["name"] for m in spec["per_layer"]]
+    nine = ["dedup_sort_ms", "table_gather_ms", "slot_expand_ms",
+            "interaction_ms", "table_scatter_ms", "step_unscoped_ms",
+            "loss_sync_share", "epoch_barrier_s", "compiles_per_epoch"]
+    assert names[-11:] == nine + ["uniq_slot_fill", "host_build_s_per_batch"]
+    assert set(spec) == {"command", "paths", "run_seconds", "configs",
+                         "workloads", "end_to_end", "per_layer"}
+    name = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+    e2e = {m["name"]: m for m in spec["end_to_end"]}
+    cells = {w["name"] for w in spec["workloads"]}
+    assert cells == {"fm16-train-zipf", "ffm4-train-zipf"}
+    for w in spec["workloads"]:
+        cell = harness.load_cell(w["name"])
+        assert {m["name"] for m in cell.end_to_end} > {"setup_s"}
+        assert {"uniq_slot_fill", "host_build_s_per_batch"} <= {
+            m["name"] for m in cell.per_layer}
+    for m in spec["per_layer"]:
+        assert name.match(m["name"]) and m["moves"] in e2e
+        assert set(m.get("workloads", cells)) <= cells
+        with open(os.path.join(REPO, "benchmarks", "layer_metrics",
+                               m["name"] + ".json")) as fh:
+            own = json.load(fh)
+        for k in ("unit", "better", "source", "layer", "moves"):
+            assert own[k] == m[k], (m["name"], k)
+        assert os.path.exists(os.path.join(
+            REPO, "benchmarks", "readers", own["reader"] + ".py"))

@@ -379,10 +379,16 @@ from fast_tffm_tpu.train import train
 wd = sys.argv[1]
 path = os.path.join(wd, "corpus.txt")
 out = {}
-for name, kw in (("padded", {}),
-                 ("packed", {"wire_format": "packed"}),
+# The trio pins dedup = device: the raw-id wire the packed format's 2x
+# bar was written for. The *_host pair runs the default, the host
+# unique for a one-device train step.
+dev = {"dedup": "device"}
+for name, kw in (("padded", dev),
+                 ("packed", {"wire_format": "packed", **dev}),
                  ("narrow", {"wire_format": "packed",
-                             "wire_dtypes": "narrow"})):
+                             "wire_dtypes": "narrow", **dev}),
+                 ("padded_host", {}),
+                 ("packed_host", {"wire_format": "packed"})):
     cfg = FmConfig(vocabulary_size=400, factor_num=4, batch_size=16,
                    learning_rate=0.1, shuffle=False, seed=0,
                    log_steps=0, train_files=(path,), epoch_num=1,
@@ -416,8 +422,7 @@ def trained_trio(tmp_path_factory):
         env=env, capture_output=True, text=True, timeout=600)
     assert res.returncode == 0, res.stderr[-4000:]
     metrics = json.loads(res.stdout.strip().splitlines()[-1])
-    tables = {k: np.load(os.path.join(wd, k + ".npy"))
-              for k in ("padded", "packed", "narrow")}
+    tables = {k: np.load(os.path.join(wd, k + ".npy")) for k in metrics}
     return metrics, tables
 
 
@@ -455,6 +460,30 @@ def test_train_packed_bitwise_and_h2d_savings(trained_trio):
             <= c_pack["train/h2d_bytes_logical"] / 2.0)
     assert g_pad["wire/packed"] == 0.0
     assert g_pack["wire/packed"] == 1.0 and g_pack["wire/narrow"] == 0.0
+
+
+def test_train_packed_h2d_under_the_host_unique(trained_trio):
+    """What ``dedup = auto`` ships since PR 26: a one-device train step
+    takes the host unique, so ``uniq_ids[U]`` crosses beside the
+    rectangles, 4 B a slot, in both formats, and packing cannot cut
+    it. Packed still removes the same bytes and stays bit-identical to
+    padded, but from a larger total: at the default config it no
+    longer halves train H2D (1.89x here, 2.35x on the raw-id wire the
+    test above pins)."""
+    metrics, tables = trained_trio
+    assert np.array_equal(tables["padded_host"], tables["packed_host"])
+    c_pad, _ = _counters(metrics["padded"])
+    c_pack, _ = _counters(metrics["packed"])
+    h_pad, _ = _counters(metrics["padded_host"])
+    h_pack, g_pack = _counters(metrics["packed_host"])
+    assert g_pack["wire/packed"] == 1.0
+    uniq = 4 * h_pack["pipeline/uniq_slots"]
+    assert 0 < uniq == 4 * h_pad["pipeline/uniq_slots"]
+    assert h_pad["train/h2d_bytes"] == c_pad["train/h2d_bytes"] + uniq
+    assert h_pack["train/h2d_bytes"] == c_pack["train/h2d_bytes"] + uniq
+    assert h_pack["train/h2d_bytes_logical"] == h_pad["train/h2d_bytes"]
+    ratio = h_pack["train/h2d_bytes_logical"] / h_pack["train/h2d_bytes"]
+    assert 1.8 <= ratio < 2.0, ratio
 
 
 def test_fmstat_wire_rows_and_verdict(trained_trio):
