@@ -434,7 +434,9 @@ def run_memory_profile(tmp):
     train(cfg)
     g = summarize([cfg.metrics_file]).get("gauges", {})
     model = table_bytes(cfg)
-    p = plan(cfg, "train")
+    # The ledger books one device's share of the session's mesh.
+    p = plan(cfg, "train",
+             shards=int(g.get("train/mesh_devices") or 1))
     # The stream's LAST mem/live_bytes is post-release (0); the
     # resident set the planner predicts is the mid-run maximum.
     live = 0.0

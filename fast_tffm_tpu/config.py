@@ -30,6 +30,13 @@ def _split_files(raw: str) -> Tuple[str, ...]:
     return tuple(out)
 
 
+def mesh_rows(num_rows: int) -> int:
+    """``num_rows`` rounded up to a multiple of 4096: the row layout of
+    checkpoints and of any mesh (``FmConfig.ckpt_rows``; the capacity
+    planner sizes a what-if table by the same rule)."""
+    return -(-int(num_rows) // 4096) * 4096
+
+
 @dataclasses.dataclass(frozen=True)
 class FmConfig:
     # --- [General] ---------------------------------------------------------
@@ -885,7 +892,7 @@ class FmConfig:
         restore row-sharded on ANY topology without ever assembling the
         table on one host — jax shardings require evenly divisible dims.
         The pad rows sit past pad_id: no feature id can reach them."""
-        return -(-self.num_rows // 4096) * 4096
+        return mesh_rows(self.num_rows)
 
 
 _GENERAL_KEYS = {

@@ -601,8 +601,10 @@ extern "C" {
 // param (block-parse path for the predict alignment mode — until this
 // the BLOCK parser had no blank-line-preserving mode, so every
 // tolerant/weighted keep_empty input fell back to the Python parser
-// and the tolerant keep_empty shape routed serial).
-int64_t fm_abi_version() { return 7; }
+// and the tolerant keep_empty shape routed serial); 8 = the builder
+// stages cells flat and fm_bb_finish takes the output width (fm_bb_peek
+// sizes it): a batch costs its own cells, not B x the feature cap.
+int64_t fm_abi_version() { return 8; }
 
 // Scan complete lines of [blob, blob+blob_len) until `n_target` lines
 // that PRODUCE AN EXAMPLE have been seen. The counting rule must equal
@@ -735,20 +737,28 @@ struct BatchBuilder {
   int64_t B, L, vocab;
   bool hash_ids;
   bool field_aware = false;  // FFM `field:fid[:val]` tokens
-  bool raw_ids = false;      // dedup=device: li holds raw ids, no dedup
+  bool raw_ids = false;      // dedup=device: cells hold raw ids, no dedup
   bool keep_empty = false;   // blank line -> zero-feature example
   int64_t field_num = 0;
   int max_feats;
   int64_t max_uniq;  // 0 = unlimited; else batch closes BEFORE exceeding
   int T = 1;         // feed parse threads (1 = the serial in-line path)
-  std::vector<float> labels;    // [B]
-  std::vector<int32_t> uniq;    // [B*L + 1]
-  std::vector<int32_t> li;      // [B*L], default 0 (pad slot)
-  std::vector<float> vals;      // [B*L], default 0
-  std::vector<int32_t> fields;  // [B*L] (field_aware only), default 0
+  // The batch under construction, staged FLAT: example e owns the next
+  // sizes[e] cells. fm_bb_finish pads them out to the width the caller
+  // asks for, so building and resetting cost the batch's own cells and
+  // not B x L (at B = 32768 and the default cap L = 256 the padded
+  // staging was 67 MB to clear and 67 MB to copy out, every batch).
+  std::vector<float> labels;    // [n_ex]
+  std::vector<int32_t> sizes;   // [n_ex] cells per example
+  std::vector<int32_t> idx;     // per cell: unique slot (raw id in raw mode)
+  std::vector<float> vals;      // per cell
+  std::vector<int32_t> fields;  // per cell (field_aware only)
+  std::vector<int32_t> uniq;    // [n_uniq], slot 0 = pad
+  // Dedup table, stamped per batch (no per-batch clears). It starts
+  // small and doubles when half full, so its size follows the batch's
+  // distinct rows and stays in cache (sized for B x L it was 134 MB).
   std::vector<int32_t> slot;    // dedup table -> slot index
   std::vector<uint32_t> stamp;  // dedup table stamping
-  std::vector<uint32_t> line_slots;  // hash slots inserted by current line
   uint32_t cur_stamp = 0;
   uint32_t mask = 0;
   int64_t n_ex = 0;
@@ -759,10 +769,10 @@ struct BatchBuilder {
   // Threaded feed (T > 1): each fed chunk's complete lines are parsed
   // by T threads into this pending CSR queue (the expensive tokenize/
   // float-parse/hash phase); a cheap serial drain then does the
-  // order-dependent work (dedup slots, padded scatter, uniq-budget
-  // spill). A parse error is DEFERRED: examples before it drain
-  // normally and the error surfaces only when consumption reaches it —
-  // the exact observable behavior of the serial path.
+  // order-dependent work (dedup slots, uniq-budget spill). A parse
+  // error is DEFERRED: examples before it drain normally and the error
+  // surfaces only when consumption reaches it — the exact observable
+  // behavior of the serial path.
   std::vector<float> p_labels;
   std::vector<int32_t> p_sizes;
   std::vector<int64_t> p_linenos;
@@ -782,25 +792,45 @@ void bb_reset(BatchBuilder* bb) {
   bb->n_uniq = 1;
   bb->max_nnz = 0;
   bb->cur_stamp++;
-  // Raw mode: padding cells hold the raw pad id (== vocab, the dead
-  // row) — there is no "pad slot 0" indirection without a unique table.
-  std::fill(bb->li.begin(), bb->li.end(),
-            bb->raw_ids ? int32_t(bb->vocab) : 0);
-  std::memset(bb->vals.data(), 0, size_t(bb->B * bb->L) * sizeof(float));
-  if (bb->field_aware) {
-    std::memset(bb->fields.data(), 0,
-                size_t(bb->B * bb->L) * sizeof(int32_t));
+  bb->labels.clear();
+  bb->sizes.clear();
+  bb->idx.clear();
+  bb->vals.clear();
+  bb->fields.clear();
+  bb->uniq.resize(1);
+}
+
+inline uint32_t bb_hash(const BatchBuilder* bb, int32_t key) {
+  return (uint32_t(key) * 2654435761u) & bb->mask;
+}
+
+// Double the dedup table and re-seat this batch's uniques.
+void bb_grow(BatchBuilder* bb) {
+  const size_t cap = (size_t(bb->mask) + 1) << 1;
+  bb->mask = uint32_t(cap - 1);
+  bb->slot.assign(cap, 0);
+  bb->stamp.assign(cap, 0);
+  bb->cur_stamp = 1;
+  for (int32_t s = 1; s < bb->n_uniq; s++) {
+    uint32_t h = bb_hash(bb, bb->uniq[size_t(s)]);
+    while (bb->stamp[h] == bb->cur_stamp) h = (h + 1) & bb->mask;
+    bb->stamp[h] = bb->cur_stamp;
+    bb->slot[h] = s;
   }
 }
 
 inline int32_t bb_slot(BatchBuilder* bb, int32_t key) {
-  uint32_t h = (uint32_t(key) * 2654435761u) & bb->mask;
+  uint32_t h = bb_hash(bb, key);
   for (;;) {
     if (bb->stamp[h] != bb->cur_stamp) {
+      if (size_t(bb->n_uniq) * 2 > size_t(bb->mask)) {
+        bb_grow(bb);
+        h = bb_hash(bb, key);
+        continue;
+      }
       bb->stamp[h] = bb->cur_stamp;
       bb->slot[h] = bb->n_uniq;
-      bb->uniq[size_t(bb->n_uniq)] = key;
-      bb->line_slots.push_back(h);  // for per-line rollback (uniq cap)
+      bb->uniq.push_back(key);
       return bb->n_uniq++;
     }
     if (bb->uniq[size_t(bb->slot[h])] == key) return bb->slot[h];
@@ -808,29 +838,38 @@ inline int32_t bb_slot(BatchBuilder* bb, int32_t key) {
   }
 }
 
-// Undo the current line's unique insertions. Un-stamping (stamp 0 never
-// equals cur_stamp >= 1) is probe-chain-safe: a committed key's probe
-// path to its slot runs over slots that were already occupied at its
-// insertion time, and the rolled-back slots were all claimed later, so
-// they can't sit on any committed path.
+// Undo the current line's unique insertions, newest first: a key's
+// probe path runs over slots that were occupied when it was inserted,
+// so while every OLDER key is still seated the newest is found where
+// bb_slot left it. Un-stamping (stamp 0 never equals cur_stamp >= 1)
+// frees its seat; committed keys were all seated before the line's and
+// no path of theirs crosses a freed seat.
 inline void bb_rollback_line(BatchBuilder* bb, int32_t saved_uniq) {
-  for (uint32_t h : bb->line_slots) bb->stamp[h] = 0;
+  for (int32_t s = bb->n_uniq - 1; s >= saved_uniq; s--) {
+    const int32_t key = bb->uniq[size_t(s)];
+    uint32_t h = bb_hash(bb, key);
+    while (bb->slot[h] != s || bb->stamp[h] != bb->cur_stamp) {
+      h = (h + 1) & bb->mask;
+    }
+    bb->stamp[h] = 0;
+  }
   bb->n_uniq = saved_uniq;
+  bb->uniq.resize(size_t(saved_uniq));
 }
 
 // The unique-budget close-out, shared by the serial feed and the
-// threaded drain so the spill protocol (rollback + row scrub + the
-// budget error message) has exactly one implementation. Returns 1 when
-// the batch closes early (spill — the example stays unconsumed), -1
-// when the batch is empty so the example can never fit (error).
-inline int bb_budget_close(BatchBuilder* bb, int32_t* irow, float* vrow,
-                           int32_t* frow, int32_t nf, int32_t saved_uniq,
-                           int64_t lineno, char* err_out,
-                           int64_t err_cap) {
+// threaded drain so the spill protocol (rollback + dropping the line's
+// cells + the budget error message) has exactly one implementation.
+// ``cells`` is the flat cell count before the line. Returns 1 when the
+// batch closes early (spill — the example stays unconsumed), -1 when
+// the batch is empty so the example can never fit (error).
+inline int bb_budget_close(BatchBuilder* bb, size_t cells,
+                           int32_t saved_uniq, int64_t lineno,
+                           char* err_out, int64_t err_cap) {
   bb_rollback_line(bb, saved_uniq);
-  std::memset(irow, 0, size_t(nf) * sizeof(int32_t));
-  std::memset(vrow, 0, size_t(nf) * sizeof(float));
-  if (frow != nullptr) std::memset(frow, 0, size_t(nf) * sizeof(int32_t));
+  bb->idx.resize(cells);
+  bb->vals.resize(cells);
+  if (bb->field_aware) bb->fields.resize(cells);
   if (bb->n_ex == 0) {
     std::snprintf(err_out, size_t(err_cap),
                   "line %lld: single example exceeds the unique-row "
@@ -839,6 +878,15 @@ inline int bb_budget_close(BatchBuilder* bb, int32_t* irow, float* vrow,
     return -1;
   }
   return 1;
+}
+
+// One example is complete: its cells are the flat tail past ``cells``.
+inline void bb_commit(BatchBuilder* bb, size_t cells, float label) {
+  const int32_t nf = int32_t(bb->idx.size() - cells);
+  bb->labels.push_back(label);
+  bb->sizes.push_back(nf);
+  if (nf > bb->max_nnz) bb->max_nnz = nf;
+  bb->n_ex++;
 }
 
 // Drain pending (threaded-parse) examples into the batch. Returns 1
@@ -859,26 +907,21 @@ int bb_drain(BatchBuilder* bb, char* err_out, int64_t err_cap) {
     const int32_t nf = bb->p_sizes[e];
     const int32_t* ids = bb->p_ids.data() + bb->p_nnz;
     const float* vals = bb->p_vals.data() + bb->p_nnz;
-    const int32_t* flds =
-        bb->field_aware ? bb->p_fields.data() + bb->p_nnz : nullptr;
-    float* vrow = bb->vals.data() + bb->n_ex * bb->L;
-    int32_t* irow = bb->li.data() + bb->n_ex * bb->L;
-    int32_t* frow =
-        bb->field_aware ? bb->fields.data() + bb->n_ex * bb->L : nullptr;
-    bb->line_slots.clear();
+    const size_t cells = bb->idx.size();
     const int32_t saved_uniq = bb->n_uniq;
     for (int32_t j = 0; j < nf; j++) {
-      irow[j] = bb->raw_ids ? ids[j] : bb_slot(bb, ids[j]);
-      vrow[j] = vals[j];
-      if (frow != nullptr) frow[j] = flds[j];
+      bb->idx.push_back(bb->raw_ids ? ids[j] : bb_slot(bb, ids[j]));
+    }
+    bb->vals.insert(bb->vals.end(), vals, vals + nf);
+    if (bb->field_aware) {
+      const int32_t* flds = bb->p_fields.data() + bb->p_nnz;
+      bb->fields.insert(bb->fields.end(), flds, flds + nf);
     }
     if (bb->max_uniq != 0 && bb->n_uniq > bb->max_uniq) {
-      return bb_budget_close(bb, irow, vrow, frow, nf, saved_uniq,
-                             bb->p_linenos[e], err_out, err_cap);
+      return bb_budget_close(bb, cells, saved_uniq, bb->p_linenos[e],
+                             err_out, err_cap);
     }
-    bb->labels[size_t(bb->n_ex)] = bb->p_labels[e];
-    if (nf > bb->max_nnz) bb->max_nnz = nf;
-    bb->n_ex++;
+    bb_commit(bb, cells, bb->p_labels[e]);
     bb->p_cursor++;
     bb->p_nnz += size_t(nf);
   }
@@ -1004,16 +1047,12 @@ void* fm_bb_new(int64_t B, int64_t L, int64_t vocab, int hash_ids,
   // only add buffer traffic.
   const int T = num_threads > 0 ? num_threads : fm_auto_threads();
   bb->T = T < 1 ? 1 : T;
-  bb->labels.resize(size_t(B));
-  bb->uniq.resize(size_t(B * L + 1));
-  bb->uniq[0] = int32_t(vocab);  // pad slot
-  bb->li.assign(size_t(B * L), bb->raw_ids ? int32_t(vocab) : 0);
-  bb->vals.assign(size_t(B * L), 0.0f);
-  if (bb->field_aware) bb->fields.assign(size_t(B * L), 0);
-  size_t cap = 16;
-  while (cap < size_t(B * L) * 2) cap <<= 1;
+  bb->labels.reserve(size_t(B));
+  bb->sizes.reserve(size_t(B));
+  bb->uniq.assign(1, int32_t(vocab));  // pad slot
+  const size_t cap = size_t(1) << 16;
   bb->mask = uint32_t(cap - 1);
-  bb->slot.resize(cap);
+  bb->slot.assign(cap, 0);
   bb->stamp.assign(cap, 0);
   bb->cur_stamp = 1;
   return bb;
@@ -1045,9 +1084,8 @@ int fm_bb_feed(void* h, const char* blob, int64_t blob_len,
     if (q == line_end) {
       if (bb->keep_empty) {
         // Blank line -> zero-feature example, label 0 (predict owes one
-        // score per input line; the row buffers are already pad/zero).
-        bb->labels[size_t(bb->n_ex)] = 0.0f;
-        bb->n_ex++;
+        // score per input line).
+        bb_commit(bb, bb->idx.size(), 0.0f);
       }
       p = line_end + 1;
       continue;
@@ -1060,13 +1098,8 @@ int fm_bb_feed(void* h, const char* blob, int64_t blob_len,
                     (long long)bb->lineno, int(tok_end - q), q);
       return -1;
     }
-    float* vrow = bb->vals.data() + bb->n_ex * bb->L;
-    int32_t* irow = bb->li.data() + bb->n_ex * bb->L;
-    int32_t* frow = bb->field_aware
-                        ? bb->fields.data() + bb->n_ex * bb->L
-                        : nullptr;
+    const size_t cells = bb->idx.size();
     int n_feats = 0;
-    bb->line_slots.clear();
     const int32_t saved_uniq = bb->n_uniq;
     q = tok_end;
     while (true) {
@@ -1088,14 +1121,19 @@ int fm_bb_feed(void* h, const char* blob, int64_t blob_len,
         if (parse_token(q, tok_end, c1, c2, extra, bb->vocab,
                         bb->hash_ids, bb->field_aware, bb->field_num, &t,
                         &terr)) {
+          // The batch so far stays whole: drop the bad line's cells.
+          bb_rollback_line(bb, saved_uniq);
+          bb->idx.resize(cells);
+          bb->vals.resize(cells);
+          if (bb->field_aware) bb->fields.resize(cells);
           std::snprintf(err_out, size_t(err_cap), "line %lld: %s",
                         (long long)bb->lineno, terr.c_str());
           return -1;
         }
       }
-      irow[n_feats] = bb->raw_ids ? t.row : bb_slot(bb, t.row);
-      vrow[n_feats] = t.val;
-      if (frow != nullptr) frow[n_feats] = t.field;
+      bb->idx.push_back(bb->raw_ids ? t.row : bb_slot(bb, t.row));
+      bb->vals.push_back(t.val);
+      if (bb->field_aware) bb->fields.push_back(t.field);
       n_feats++;
       q = tok_end;
     }
@@ -1106,43 +1144,65 @@ int fm_bb_feed(void* h, const char* blob, int64_t blob_len,
       // guarantees a single line always fits an empty batch.
       const int64_t spill_lineno = bb->lineno;
       bb->lineno--;  // will be re-fed
-      const int rc = bb_budget_close(bb, irow, vrow, frow, n_feats,
-                                     saved_uniq, spill_lineno, err_out,
-                                     err_cap);
+      const int rc = bb_budget_close(bb, cells, saved_uniq, spill_lineno,
+                                     err_out, err_cap);
       if (rc < 0) return -1;
       *consumed_out = p - blob;
       return 1;
     }
-    bb->labels[size_t(bb->n_ex)] = label;
-    if (n_feats > bb->max_nnz) bb->max_nnz = n_feats;
-    bb->n_ex++;
+    bb_commit(bb, cells, label);
     p = line_end + 1;
   }
   *consumed_out = p - blob;
   return bb->n_ex >= bb->B ? 1 : 0;
 }
 
-// Copy the accumulated batch out and reset for the next one.
-// labels_out[B], uniq_out[n_uniq] (slot 0 = pad_id), li_out[B*L],
-// vals_out[B*L], fields_out[B*L] (field_aware builders only; may be
-// null otherwise). Returns n_examples (0 if the batch is empty).
-int64_t fm_bb_finish(void* h, float* labels_out, int32_t* uniq_out,
-                     int32_t* li_out, float* vals_out, int32_t* fields_out,
-                     int64_t* n_uniq_out, int64_t* max_nnz_out) {
+// What the batch under construction holds: returns n_examples and the
+// two numbers a caller sizes fm_bb_finish's buffers from.
+int64_t fm_bb_peek(void* h, int64_t* n_uniq_out, int64_t* max_nnz_out) {
   auto* bb = static_cast<BatchBuilder*>(h);
-  const int64_t n = bb->n_ex;
-  std::memcpy(labels_out, bb->labels.data(), size_t(n) * sizeof(float));
-  std::memcpy(uniq_out, bb->uniq.data(),
-              size_t(bb->n_uniq) * sizeof(int32_t));
-  std::memcpy(li_out, bb->li.data(), size_t(bb->B * bb->L) * sizeof(int32_t));
-  std::memcpy(vals_out, bb->vals.data(),
-              size_t(bb->B * bb->L) * sizeof(float));
-  if (bb->field_aware && fields_out != nullptr) {
-    std::memcpy(fields_out, bb->fields.data(),
-                size_t(bb->B * bb->L) * sizeof(int32_t));
-  }
   *n_uniq_out = bb->n_uniq;
   *max_nnz_out = bb->max_nnz;
+  return bb->n_ex;
+}
+
+// Pad the accumulated batch out to [B, cols] and reset for the next one.
+// labels_out[B], uniq_out[n_uniq] (slot 0 = pad_id), li_out[B*cols],
+// vals_out[B*cols], fields_out[B*cols] (field_aware builders only; may
+// be null otherwise); fm_bb_peek gives n_uniq and the widest example,
+// which ``cols`` must cover (<= L). Every output cell is written: pad
+// cells are slot 0 (the raw pad id == vocab in raw mode) with value 0,
+// as are the rows past n_examples. Returns n_examples (0 if the batch
+// is empty), -1 when ``cols`` is too narrow (nothing is reset).
+int64_t fm_bb_finish(void* h, int64_t cols, float* labels_out,
+                     int32_t* uniq_out, int32_t* li_out, float* vals_out,
+                     int32_t* fields_out) {
+  auto* bb = static_cast<BatchBuilder*>(h);
+  if (cols < bb->max_nnz || cols > bb->L || cols <= 0) return -1;
+  const int64_t n = bb->n_ex;
+  const size_t C = size_t(cols);
+  const int32_t pad = bb->raw_ids ? int32_t(bb->vocab) : 0;
+  const bool with_fields = bb->field_aware && fields_out != nullptr;
+  std::memcpy(labels_out, bb->labels.data(), size_t(n) * sizeof(float));
+  std::fill(labels_out + n, labels_out + bb->B, 0.0f);
+  std::memcpy(uniq_out, bb->uniq.data(),
+              size_t(bb->n_uniq) * sizeof(int32_t));
+  size_t z = 0;
+  for (int64_t r = 0; r < bb->B; r++) {
+    const size_t nf = r < n ? size_t(bb->sizes[size_t(r)]) : 0;
+    int32_t* irow = li_out + size_t(r) * C;
+    float* vrow = vals_out + size_t(r) * C;
+    std::memcpy(irow, bb->idx.data() + z, nf * sizeof(int32_t));
+    std::fill(irow + nf, irow + C, pad);
+    std::memcpy(vrow, bb->vals.data() + z, nf * sizeof(float));
+    std::memset(vrow + nf, 0, (C - nf) * sizeof(float));
+    if (with_fields) {
+      int32_t* frow = fields_out + size_t(r) * C;
+      std::memcpy(frow, bb->fields.data() + z, nf * sizeof(int32_t));
+      std::memset(frow + nf, 0, (C - nf) * sizeof(int32_t));
+    }
+    z += nf;
+  }
   bb_reset(bb);
   return n;
 }

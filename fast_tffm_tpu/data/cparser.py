@@ -116,7 +116,7 @@ def _build(out: str) -> None:
 
 # Must equal fm_abi_version() in _parser.cc. Bump both together whenever
 # an exported signature changes.
-_ABI_VERSION = 7
+_ABI_VERSION = 8
 
 
 def _open_checked(path: str) -> ctypes.CDLL:
@@ -128,7 +128,8 @@ def _open_checked(path: str) -> ctypes.CDLL:
     try:
         for sym in ("fm_abi_version", "fm_auto_threads", "fm_parse_block",
                     "fm_dedup_ids", "fm_scan_examples", "fm_bb_new",
-                    "fm_bb_feed", "fm_bb_finish", "fm_bb_free"):
+                    "fm_bb_feed", "fm_bb_peek", "fm_bb_finish",
+                    "fm_bb_free"):
             getattr(lib, sym)
         lib.fm_abi_version.restype = ctypes.c_int64
         lib.fm_abi_version.argtypes = []
@@ -211,16 +212,19 @@ def _load() -> ctypes.CDLL:
         lib.fm_bb_feed.argtypes = [
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
             ctypes.POINTER(ctypes.c_int64), ctypes.c_char_p, ctypes.c_int64]
+        lib.fm_bb_peek.restype = ctypes.c_int64
+        lib.fm_bb_peek.argtypes = [
+            ctypes.c_void_p,
+            ctypes.POINTER(ctypes.c_int64),               # n_uniq
+            ctypes.POINTER(ctypes.c_int64)]               # max_nnz
         lib.fm_bb_finish.restype = ctypes.c_int64
         lib.fm_bb_finish.argtypes = [
-            ctypes.c_void_p,
+            ctypes.c_void_p, ctypes.c_int64,              # handle, cols
             np.ctypeslib.ndpointer(np.float32),           # labels
             np.ctypeslib.ndpointer(np.int32),             # uniq
             np.ctypeslib.ndpointer(np.int32),             # local_idx
             np.ctypeslib.ndpointer(np.float32),           # vals
-            np.ctypeslib.ndpointer(np.int32),             # fields
-            ctypes.POINTER(ctypes.c_int64),               # n_uniq
-            ctypes.POINTER(ctypes.c_int64)]               # max_nnz
+            np.ctypeslib.ndpointer(np.int32)]             # fields
         _lib = lib
         return lib
 
@@ -464,23 +468,31 @@ class BatchBuilder:
             tel.count("pipeline/bytes_fed", consumed.value)
         return rc == 1, consumed.value
 
-    def finish(self):
-        """-> (n_examples, labels[B], uniq[n_uniq], local_idx[B,L],
-        vals[B,L], fields[B,L]-or-None, max_nnz); resets the builder."""
-        labels = np.empty(self.B, np.float32)
-        uniq = np.empty(self.B * self.L + 1, np.int32)
-        li = np.empty((self.B, self.L), np.int32)
-        vals = np.empty((self.B, self.L), np.float32)
-        fields = np.empty((self.B, self.L) if self.field_aware else (1, 1),
-                          np.int32)
+    def finish(self, cols=None):
+        """-> (n_examples, labels[B], uniq[n_uniq], local_idx[B,C],
+        vals[B,C], fields[B,C]-or-None, max_nnz); resets the builder.
+        C is ``max_cols``, or ``cols(max_nnz)`` where a caller fits the
+        width to the batch's widest example (the builder stages cells
+        flat, so a narrow batch is padded out once, to the width it
+        ships at)."""
         n_uniq = ctypes.c_int64(0)
         max_nnz = ctypes.c_int64(0)
-        n = self._lib.fm_bb_finish(self._h, labels, uniq, li, vals, fields,
-                                   ctypes.byref(n_uniq),
-                                   ctypes.byref(max_nnz))
-        return (int(n), labels,
-                None if self.raw_ids else uniq[:n_uniq.value].copy(),
-                li, vals,
+        self._lib.fm_bb_peek(self._h, ctypes.byref(n_uniq),
+                             ctypes.byref(max_nnz))
+        C = self.L if cols is None else int(cols(int(max_nnz.value)))
+        labels = np.empty(self.B, np.float32)
+        uniq = np.empty(n_uniq.value, np.int32)
+        li = np.empty((self.B, C), np.int32)
+        vals = np.empty((self.B, C), np.float32)
+        fields = np.empty((self.B, C) if self.field_aware else (1, 1),
+                          np.int32)
+        n = self._lib.fm_bb_finish(self._h, C, labels, uniq, li, vals,
+                                   fields)
+        if n < 0:
+            raise ValueError(f"finish: {C} columns do not hold the "
+                             f"batch's widest example ({max_nnz.value}) "
+                             f"or exceed max_cols ({self.L})")
+        return (int(n), labels, None if self.raw_ids else uniq, li, vals,
                 fields if self.field_aware else None, int(max_nnz.value))
 
     def __del__(self):

@@ -800,6 +800,13 @@ class _BatchEmitter:
         self.window_cap = (max(2, cfg.queue_size // B) if shuffle
                            else 1)
 
+    def cols(self, max_nnz: int) -> int:
+        """The width a batch ships at: the ladder rung over its widest
+        example. Pure, so build workers hand it to finish() and get
+        their arrays at that width."""
+        return (self.L_cap if self.fixed_shape
+                else _ladder_fit(max(max_nnz, 1), self.cfg.bucket_ladder))
+
     def emit_drain(self, out, spilled: bool) -> Iterator[DeviceBatch]:
         """Emit one builder finish() tuple and drain through the
         bounded shuffle window (a passthrough when shuffle is off)."""
@@ -823,9 +830,8 @@ class _BatchEmitter:
         if self.stats is not None:
             self.stats.count(n, B, spilled,
                              num_uniq=_num_uniq(uniq, cfg.pad_id))
-        L = (self.L_cap if self.fixed_shape
-             else _ladder_fit(max(max_nnz, 1), cfg.bucket_ladder))
-        if L < self.L_cap:
+        L = self.cols(max_nnz)
+        if L < li.shape[1]:  # a finish() that was not given cols
             li = np.ascontiguousarray(li[:, :L])
             vals = np.ascontiguousarray(vals[:, :L])
             if fields is not None:
@@ -1070,9 +1076,16 @@ class _GroupScanner:
 
     def next_group(self) -> Optional[_Group]:
         from fast_tffm_tpu.data.cparser import scan_examples
+        # The scan resumes where it stopped when a chunk is appended
+        # (complete lines are counted once, a partial tail is never
+        # consumed), so a group is scanned once however many chunks it
+        # spans.
+        found = consumed = nlines = 0
         while True:
-            found, consumed, nlines = scan_examples(
-                self._buf, self._B, self._keep_empty, offset=self._pos)
+            f, c, n = scan_examples(
+                self._buf, self._B - found, self._keep_empty,
+                offset=self._pos + consumed)
+            found, consumed, nlines = found + f, consumed + c, nlines + n
             if found >= self._B:
                 return self._cut(consumed, nlines)
             chunk = self._next_chunk()
@@ -1127,8 +1140,14 @@ class _GroupScanner:
                 # examples, and a spill rewind re-counts to the same
                 # values — the recorded base never moves.
                 self._file_marks.start_file(path, base)
-            self._chunks = _iter_owned_chunks(path, start, end,
-                                              retry=self._retry)
+            # Chunks of half a large group: 4 MB ones re-copy the
+            # growing buffer four times over at B = 32768. No larger:
+            # the buffer has to stay under glibc's 32 MB mmap ceiling,
+            # past which every append is fresh pages to fault in.
+            self._chunks = _iter_owned_chunks(
+                path, start, end,
+                chunk_bytes=min(max(4 << 20, self._B << 8), 8 << 20),
+                retry=self._retry)
 
 
 class _FastWorkerState:
@@ -1138,9 +1157,10 @@ class _FastWorkerState:
     rebasing builder-relative error linenos onto the stream. Created
     inside the worker thread and never shared."""
 
-    def __init__(self, make_builder):
+    def __init__(self, make_builder, cols=None):
         self._make_builder = make_builder
         self.bb = make_builder()
+        self.cols = cols  # finish()'s width rule (_BatchEmitter.cols)
         self.fed = 0  # lines consumed by self.bb since creation
 
     def reset(self) -> None:
@@ -1164,7 +1184,7 @@ def _fast_group_work(state: _FastWorkerState, group: _Group):
     fed_before = state.fed
     try:
         _full, consumed = bb.feed(group.blob, 0)
-        out = bb.finish()
+        out = bb.finish(state.cols)
     except ParseError as e:
         state.reset()
         m = _LINE_MSG.match(str(e))
@@ -1226,10 +1246,12 @@ def _parallel_fast_batch_iterator(cfg: FmConfig, files: List[str],
     file_seed = cfg.seed if seed is None else seed
     ring = _BuildRing(workers, depth=2 * workers,
                       work=_fast_group_work,
-                      make_state=lambda: _FastWorkerState(make_builder))
+                      make_state=lambda: _FastWorkerState(make_builder,
+                                                          emitter.cols))
     tel = active()
     if tel is not None:
         tel.set("pipeline/host_threads", workers)
+    ahead = None
     try:
         for epoch in range(n_epochs):
             scanner = _GroupScanner(
@@ -1239,9 +1261,26 @@ def _parallel_fast_batch_iterator(cfg: FmConfig, files: List[str],
             inflight: Dict[int, _Group] = {}
             order: collections.deque = collections.deque()
             scan_done = False
+            next_group = scanner.next_group
+            if not spill_capable:
+                # No rewind ever reaches the scanner, so it cuts groups
+                # on a thread of its own, ahead of the ring: reading,
+                # appending and cutting a 15 MB group (14 ms at B =
+                # 32768) no longer waits for the emit beside it.
+                ahead = _read_ahead(iter(scanner.next_group, None), 2,
+                                    "fm-scan")
+                next_group = functools.partial(next, ahead, None)
             while True:
-                while not scan_done and len(inflight) < ring.depth:
-                    g = scanner.next_group()
+                # Fill the ring — a group for the batch just emitted,
+                # then on to depth, but not past a finished head: the
+                # batch that is ready goes out first (an epoch's first
+                # batch sat behind the cutting of depth groups, 0.35 s
+                # at B = 32768).
+                filled = 0
+                while (not scan_done and len(inflight) < ring.depth
+                       and not (filled and ring.has(order[0]))):
+                    filled += 1
+                    g = next_group()
                     if g is None:
                         scan_done = True
                         break
@@ -1282,6 +1321,8 @@ def _parallel_fast_batch_iterator(cfg: FmConfig, files: List[str],
                     scan_done = False
             yield from emitter.flush_window()
     finally:
+        if ahead is not None:
+            ahead.close()
         ring.close()
 
 
@@ -1337,7 +1378,7 @@ def _fast_batch_iterator(cfg: FmConfig, bb, files: List[str], B: int,
             off += consumed
             if not full:
                 break
-            out = bb.finish()
+            out = bb.finish(emitter.cols)
             # The builder returns "full" either at B examples or when a
             # line would blow the unique budget — the latter closes the
             # batch short (the spill being counted).
@@ -1365,7 +1406,7 @@ def _fast_batch_iterator(cfg: FmConfig, bb, files: List[str], B: int,
                     yield from feed_all(tail + chunk if tail else chunk)
                 if tail:  # final owned line missing its newline
                     yield from feed_all(tail + b"\n")
-            out = bb.finish()
+            out = bb.finish(emitter.cols)
             if out[0]:  # short final batch of the epoch
                 yield from emitter.emit_drain(out, spilled=False)
             yield from emitter.flush_window()
@@ -1978,6 +2019,39 @@ def prefetch(iterator: Iterator[DeviceBatch], depth: int = 2,
             yield from iterator
             return
 
+    ledgered = False
+    ahead = _read_ahead(iterator, depth, "prefetch")
+    try:
+        for item in ahead:
+            if not ledgered:
+                # Ledger (obs/memory.py): the prefetch window's
+                # standing footprint — queue depth + the in-hand batch,
+                # sized from the first batch (bucketed shapes keep
+                # later ones comparable). Host-resident numpy until the
+                # wire layer places it (host=True: gauged, excluded
+                # from the device live total). Once, not per batch —
+                # this is the hottest host loop in the tree.
+                ledgered = True
+                nb = 0
+                for v in getattr(item, "__dict__", {}).values():
+                    nb += getattr(v, "nbytes", 0)
+                if nb:
+                    from fast_tffm_tpu.obs.memory import LEDGER
+                    LEDGER.register("prefetch_batches",
+                                    (max(depth, 1) + 1) * nb,
+                                    host=True)
+            yield item
+    finally:
+        ahead.close()
+        from fast_tffm_tpu.obs.memory import LEDGER
+        LEDGER.release("prefetch_batches")
+
+
+def _read_ahead(iterator: Iterator, depth: int, name: str) -> Iterator:
+    """``iterator`` run on a daemon thread called ``name``, at most
+    ``depth`` items ahead of the consumer; what it raises is raised
+    here. Shared by prefetch() (batches ahead of the step loop) and the
+    parallel plane's group scanner (groups ahead of the build ring)."""
     import queue
     import threading
 
@@ -2014,8 +2088,7 @@ def prefetch(iterator: Iterator[DeviceBatch], depth: int = 2,
 
     # Named thread: span events from the pipeline carry the thread name
     # as their Perfetto track (tools/fmtrace).
-    threading.Thread(target=worker, name="prefetch", daemon=True).start()
-    ledgered = False
+    threading.Thread(target=worker, name=name, daemon=True).start()
     try:
         while True:
             item = q.get()
@@ -2023,28 +2096,9 @@ def prefetch(iterator: Iterator[DeviceBatch], depth: int = 2,
                 if errbox:
                     raise errbox[0]
                 return
-            if not ledgered:
-                # Ledger (obs/memory.py): the prefetch window's
-                # standing footprint — queue depth + the in-hand batch,
-                # sized from the first batch (bucketed shapes keep
-                # later ones comparable). Host-resident numpy until the
-                # wire layer places it (host=True: gauged, excluded
-                # from the device live total). Once, not per batch —
-                # this is the hottest host loop in the tree.
-                ledgered = True
-                nb = 0
-                for v in getattr(item, "__dict__", {}).values():
-                    nb += getattr(v, "nbytes", 0)
-                if nb:
-                    from fast_tffm_tpu.obs.memory import LEDGER
-                    LEDGER.register("prefetch_batches",
-                                    (max(depth, 1) + 1) * nb,
-                                    host=True)
             yield item
     finally:
         stop.set()
-        from fast_tffm_tpu.obs.memory import LEDGER
-        LEDGER.release("prefetch_batches")
 
 
 def _salvage_block(lines: Sequence[str], cfg: FmConfig,

@@ -783,7 +783,7 @@ class StreamSource:
             if self._ring is not None:
                 self._ring_flush()
             else:
-                out = self._bb.finish()
+                out = self._bb.finish(self._emitter.cols)
                 if out[0]:
                     self._emit(out, spilled=False)
         else:
@@ -804,7 +804,7 @@ class StreamSource:
             if not full:
                 return
             try:
-                out = self._bb.finish()
+                out = self._bb.finish(self._emitter.cols)
             except ParseError as e:
                 raise self._attach_source(e) from None
             # A finish() under the fixed unique budget that closed
@@ -820,7 +820,8 @@ class StreamSource:
         self._ring = pl._BuildRing(
             self._workers, depth=2 * self._workers,
             work=pl._fast_group_work,
-            make_state=lambda: pl._FastWorkerState(self._make_builder))
+            make_state=lambda: pl._FastWorkerState(
+                self._make_builder, self._emitter.cols))
         self._buf = b""
         self._buf_pos = 0
         self._segments: collections.deque = collections.deque()

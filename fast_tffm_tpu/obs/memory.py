@@ -32,13 +32,15 @@ allocation an OWNER:
   RESOURCE_EXHAUSTED as ``HbmExhaustedError`` carrying the rendered
   per-owner ledger — an OOM names WHICH owner grew.
 - **Planner** (``plan`` / ``fmstat capacity``): predicts
-  table/accumulator/wire/serve-resident bytes against device capacity
-  from config alone — with ``--what-if vocabulary_size=N,dtype=f16,
-  shards=K`` overrides, so ROADMAP items 1 (sharded tables) and 4
-  (quantized resident tables) can be sized before a line of
-  sharding/quantization code is written. ``preflight_capacity`` is the
-  same prediction as a fail-fast guard at train()/ScorerServer
-  startup.
+  table/accumulator/wire/serve-resident bytes against one device's
+  capacity from config alone — with ``--what-if vocabulary_size=N,
+  dtype=f16,shards=K`` overrides, so a row-sharded table (``shards``:
+  each device holds 1/K of table and accumulator) or a quantized
+  resident one (ROADMAP item 4) can be sized from any box.
+  ``preflight_capacity`` is the same prediction as a fail-fast guard
+  at ScorerServer startup and in the train session, which passes the
+  size of the mesh it has just built as ``shards``: the check is per
+  device of that mesh.
 """
 
 from __future__ import annotations
@@ -384,11 +386,12 @@ def parse_what_if(spec: str) -> Dict[str, Any]:
 
 
 def plan(cfg, kind: str = "train",
-         overrides: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
-    """Predicted resident device bytes per owner, from config alone —
-    what ``fmstat capacity`` renders and ``preflight_capacity``
-    enforces, cross-checked against the live ledger by a tier-1 test
-    (within 10% for the default shapes).
+         overrides: Optional[Dict[str, Any]] = None,
+         shards: int = 1) -> Dict[str, Any]:
+    """Predicted resident bytes per owner on one device, from config
+    alone — what ``fmstat capacity`` renders and
+    ``preflight_capacity`` enforces, cross-checked against the live
+    ledger by a tier-1 test (within 10% for the default shapes).
 
     ``overrides`` (the --what-if surface): ``vocabulary_size``,
     ``factor_num``, ``field_num``, ``batch_size``,
@@ -396,8 +399,13 @@ def plan(cfg, kind: str = "train",
     resizes the resident table (ROADMAP item 4 — the Adagrad
     accumulator stays f32: the quantization frontier quantizes the
     serving/resident table, not the optimizer state); ``shards``
-    divides the per-device table/accumulator share (ROADMAP item 1's
-    row-sharded mesh).
+    (an override, or the argument the train session's pre-flight
+    passes) is the number of devices a mesh shards the rows over.
+    With more than one the rows are the mesh's padded layout
+    (``config.mesh_rows``), ``table``/``adagrad_acc`` are one device's
+    share and ``sharded_owners`` keeps the whole. The wire ceiling
+    stays the whole batch's: the mesh path places its batch through
+    ``shard_batch`` and books none of it.
 
     ``kind="train"``: table + accumulator + wire double-buffers (+
     prefetch window). With ``lookup = host`` the table/accumulator
@@ -412,11 +420,14 @@ def plan(cfg, kind: str = "train",
     dim = (k * field + 1
            if getattr(cfg, "model_type", "fm") == "ffm" else k + 1)
     dtype = o.get("dtype", "f32")
-    shards = max(1, int(o.get("shards", 1)))
+    shards = max(1, int(o.get("shards", shards)))
     batch = int(o.get("batch_size", cfg.batch_size))
     feats = int(o.get("max_features_per_example",
                       cfg.max_features_per_example))
     rows = vocab + 1  # num_rows: + the shared padding row
+    if shards > 1:
+        from fast_tffm_tpu.config import mesh_rows
+        rows = mesh_rows(rows)
     tbl = table_bytes(rows=rows, dim=dim,
                       dtype_bytes=DTYPE_BYTES[dtype])
     acc = table_bytes(rows=rows, dim=dim)  # optimizer state stays f32
@@ -447,6 +458,9 @@ def plan(cfg, kind: str = "train",
         "overrides": o,
         "owners": owners,
         "host_owners": host_owners,
+        "shards": shards,
+        "sharded_owners": ({"table": tbl, "adagrad_acc": acc}
+                           if shards > 1 and "table" in owners else {}),
         "total_bytes": int(total),
         "capacity_bytes": cap,
     }
@@ -465,13 +479,17 @@ def render_plan(p: Dict[str, Any]) -> str:
     lines = [f"capacity plan ({p['kind']})"
              + (f" what-if {p['overrides']}" if p["overrides"] else "")
              + ":"]
+    whole = p["sharded_owners"]
     for name, v in sorted(p["owners"].items(), key=lambda kv: -kv[1]):
-        lines.append(f"  {name:<24} {_mb(v)}")
+        share = (f" (per device, 1/{p['shards']} of {_mb(whole[name])})"
+                 if name in whole else "")
+        lines.append(f"  {name:<24} {_mb(v)}{share}")
     for name, v in sorted(p["host_owners"].items(),
                           key=lambda kv: -kv[1]):
         lines.append(f"  {name:<24} {_mb(v)} (host-resident)")
     lines.append(f"  {'predicted device total':<24} "
-                 f"{_mb(p['total_bytes'])}")
+                 f"{_mb(p['total_bytes'])}"
+                 + (" per device" if whole else ""))
     cap = p.get("capacity_bytes")
     if cap:
         lines.append(f"  {'device capacity':<24} {_mb(cap)}")
@@ -481,30 +499,38 @@ def render_plan(p: Dict[str, Any]) -> str:
     return "\n".join(lines)
 
 
-def preflight_capacity(cfg, kind: str = "train") -> None:
+def preflight_capacity(cfg, kind: str = "train", shards: int = 1) -> None:
     """Fail fast at train()/ScorerServer startup when the PREDICTED
     resident bytes exceed the device capacity — the planner's
-    breakdown plus the exact what-if invocation to explore fixes,
-    instead of an XLA OOM minutes into bring-up. No-op when the
-    backend reports no capacity (the CPU container) — and the log line
-    says which it was, so a pre-flight that checked nothing is on
-    record."""
-    p = plan(cfg, kind)
+    breakdown plus what the session can do about it, instead of an XLA
+    OOM minutes into bring-up. ``shards`` is the size of the mesh the
+    train session row-shards table and accumulator over: one device's
+    share stands against one device's capacity. No-op when the backend
+    reports no capacity (the CPU container) — and the log line says
+    which it was, so a pre-flight that checked nothing is on record."""
+    p = plan(cfg, kind, shards=shards)
     cap = p.get("capacity_bytes")
     from fast_tffm_tpu.utils.logging import get_logger
+    whole = sum(p["sharded_owners"].values())
     get_logger().info(
-        "capacity pre-flight (%s): predicted resident %d bytes, device "
-        "capacity %s", kind, p["total_bytes"],
+        "capacity pre-flight (%s): predicted resident %d bytes%s, "
+        "device capacity %s", kind, p["total_bytes"],
+        f" per device, 1/{p['shards']} of {whole} bytes of table and "
+        f"accumulator row-sharded over {p['shards']} devices"
+        if whole else "",
         f"{cap} bytes" if cap else "UNKNOWN (backend reports none; "
         "nothing checked)")
     if not cap or p["total_bytes"] <= cap:
         return
     raise ValueError(
         f"predicted resident device memory for this config exceeds "
-        f"the device capacity ({_mb(p['total_bytes'])} > {_mb(cap)}) "
-        f"— refusing to start rather than OOM mid-bring-up.\n"
+        f"the device capacity ({_mb(p['total_bytes'])} > {_mb(cap)}"
+        + (f" on each of the {p['shards']} devices the rows are "
+           "sharded over" if whole else "")
+        + ") — refusing to start rather than OOM mid-bring-up.\n"
         f"{render_plan(p)}\n"
-        "explore fixes with: python -m tools.fmstat capacity "
-        "<your.cfg> --what-if vocabulary_size=...,dtype=f16,shards=K "
-        "(ROADMAP items 1 and 4), or lookup = host for the beyond-HBM "
-        "offload path")
+        "what this session can do: run on a host with more devices "
+        "(a train session row-shards table and accumulator over every "
+        "device it sees), or set lookup = host for the beyond-HBM "
+        "offload path; size either first with: python -m tools.fmstat "
+        "capacity <your.cfg> --what-if shards=K,vocabulary_size=...")

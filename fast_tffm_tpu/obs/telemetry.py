@@ -361,9 +361,8 @@ class RunTelemetry:
             real = int((batch.local_idx != pad_id).sum())
         else:
             real_uniq = int((batch.uniq_ids != pad_id).sum())
-            real = int(
-                (np.asarray(batch.uniq_ids)[batch.local_idx]
-                 != pad_id).sum())
+            real = int(np.count_nonzero(np.take(
+                np.asarray(batch.uniq_ids) != pad_id, batch.local_idx)))
             self.count("pipeline/uniq_rows", real_uniq)
             # The U shipped (ladder rung, pad slots included): rows
             # over slots is the fill of the fitted unique table, the

@@ -362,12 +362,6 @@ def train(cfg: FmConfig, job_name: Optional[str] = None,
     guard_prev = None
     guard_installed = False
     try:
-        # Pre-flight capacity check (obs/memory.py): when the backend
-        # reports a device capacity, a config whose PREDICTED resident
-        # bytes exceed it is refused here with the planner's per-owner
-        # breakdown — not minutes later as a raw XLA OOM. No-op when
-        # capacity is unmeasured (the CPU container).
-        preflight_capacity(cfg, "train")
         shard_index, num_shards = 0, 1
         generation = 0
         members = [0]
@@ -619,6 +613,19 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
             global_batch, init_sharded_state, make_mesh,
             make_sharded_train_step, shard_batch)
         mesh = make_mesh()
+    # Pre-flight capacity check (obs/memory.py), here because only the
+    # session knows its devices (a cluster has joined by now): when
+    # the backend reports a device capacity, a config whose PREDICTED
+    # resident bytes per device — table and accumulator divided over
+    # the mesh just built — exceed it is refused with the planner's
+    # per-owner breakdown, not minutes later as a raw XLA OOM. No-op
+    # when capacity is unmeasured (the CPU container).
+    mesh_devices = int(mesh.devices.size) if mesh is not None else 1
+    preflight_capacity(cfg, "train", shards=mesh_devices)
+    if tel is not None:
+        # Set once: a reader of the stream can tell a mesh run (and
+        # over how many devices its rows lie) from a one-device run.
+        tel.set("train/mesh_devices", float(mesh_devices))
 
     if multi_process:
         from fast_tffm_tpu.data.pipeline import require_bounded_examples
@@ -830,8 +837,10 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                             table_bytes(rows=lk.rows, dim=lk.dim),
                             host=True)
         else:
-            LEDGER.register("table", table.nbytes)
-            LEDGER.register("adagrad_acc", acc.nbytes)
+            # One device's share: the ledger's live total stands beside
+            # ONE device's capacity (pressure alarm, mem/utilization).
+            LEDGER.register("table", table.nbytes // mesh_devices)
+            LEDGER.register("adagrad_acc", acc.nbytes // mesh_devices)
 
         # Wire format (README "Wire format"; wire.py): resolve the
         # knobs for THIS dispatch path, build the one encoder every
@@ -910,7 +919,9 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
             enqueue), so time spent HERE is queue backpressure — the
             previous program still executing somewhere. Runs under
             oom_guard: a RESOURCE_EXHAUSTED here re-raises with the
-            per-owner ledger attached (obs/memory.py)."""
+            per-owner ledger attached (obs/memory.py). A live loss
+            line still owed (log_tick) is synced first."""
+            sync_live_line()
             with span("train/step", seconds="train/dispatch_seconds",
                       step=step):
                 with oom_guard("train/step"):
@@ -1196,6 +1207,7 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
 
         log_mode = _probe_link()
         log_buffer: list = []    # deferred: (step, epoch, loss_arr, eps)
+        live_line: list = []     # live: the one line whose sync is due
 
         def log_line(s, ep, val, eps):
             nonlocal loss_val
@@ -1212,16 +1224,29 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                 if len(log_buffer) >= LOG_BUFFER_MAX:
                     flush_log()
                 return
-            # The loop's sync point: the host waits here until the
-            # device has caught up, so this phase's share of the wall
-            # says how far the device sets the pace. The line itself
-            # is written outside the phase.
+            # Live: the sync is taken at the NEXT dispatch
+            # (_wire_step), once the next batch is fetched and placed:
+            # the device then waits for the host one dispatch after
+            # each loss line, and not a placement too (13 ms of every
+            # eight steps on the four-chip mesh).
+            sync_live_line()  # never two owed
+            live_line.append((s, ep, loss_arr, eps))
+
+        def sync_live_line():
+            """The loop's sync point: the host waits here until the
+            device has caught up, so this phase's share of the wall
+            says how far the device sets the pace. The line itself is
+            written outside the phase."""
+            if not live_line:
+                return
+            s, ep, loss_arr, eps = live_line.pop()
             with span("train/loss_sync",
                       seconds="train/loss_sync_seconds"):
                 val = float(loss_arr)
             log_line(s, ep, val, eps)
 
         def flush_log():
+            sync_live_line()
             if not log_buffer:
                 return
             # bulk_fetch stacks the same-shaped scalars into ONE transfer:
@@ -1869,6 +1894,7 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                     if tel is not None:
                         t_step_prev += pause.dur  # keep the pause out
                         # of the next step's step_seconds sample
+            sync_live_line()  # the epoch's last line, ahead of the barrier
             if not stopping:
                 # The epoch barrier: from the iterator's exhaustion
                 # until the NEXT epoch's first dispatch returns (or the

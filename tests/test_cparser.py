@@ -385,6 +385,81 @@ def test_threaded_builder_defers_parse_error(rng):
     _assert_batches_equal(got, want)
 
 
+@pytest.mark.parametrize("kw", [
+    dict(),
+    dict(raw_ids=True),
+    dict(keep_empty=True),
+    dict(field_aware=True, field_num=3),
+])
+@pytest.mark.parametrize("threads", [1, 4])
+def test_builder_finish_fits_columns(rng, kw, threads):
+    """finish(cols) pads the flat-staged batch out to the width the
+    caller fits to its widest example: the same cells as the full-width
+    finish(), every cell past them pad (slot 0, or the raw pad id) and
+    zero, and a width under the widest example is refused."""
+    blob = _builder_corpus(rng, n_lines=4,
+                           field_aware=kw.get("field_aware", False),
+                           blanks=False)
+    wide = cparser.BatchBuilder(6, 16, 500, num_threads=threads, **kw)
+    fit = cparser.BatchBuilder(6, 16, 500, num_threads=threads, **kw)
+    wide.feed(blob), fit.feed(blob)
+    n, labels, uniq, li, vals, fields, max_nnz = wide.finish()
+    assert li.shape == (6, 16) and n == 4
+    with pytest.raises(ValueError, match="widest example"):
+        fit.finish(lambda m: m - 1)
+    got = fit.finish(lambda m: m + 1)   # refused finish reset nothing
+    C = max_nnz + 1
+    assert got[0] == n and got[6] == max_nnz
+    assert got[3].shape == got[4].shape == (6, C)
+    np.testing.assert_array_equal(got[1], labels)
+    np.testing.assert_array_equal(got[3], li[:, :C])
+    np.testing.assert_array_equal(got[4], vals[:, :C])
+    pad = 500 if kw.get("raw_ids") else 0
+    assert (li[:, max_nnz:] == pad).all() and (li[n:] == pad).all()
+    assert not vals[:, max_nnz:].any() and not labels[n:].any()
+    if fields is None:
+        assert got[2] is None if kw.get("raw_ids") else True
+        assert got[5] is None
+    else:
+        np.testing.assert_array_equal(got[5], fields[:, :C])
+    if uniq is not None:
+        np.testing.assert_array_equal(got[2], uniq)
+    # the builder is reset: the next batch starts from nothing
+    assert fit.finish()[0] == 0
+
+
+@pytest.mark.parametrize("threads", [1, 4])
+@pytest.mark.parametrize("max_uniq", [0, 70000])
+def test_builder_dedup_table_grows(rng, threads, max_uniq):
+    """The dedup table starts at 2^16 seats and doubles when half full:
+    a batch with more distinct rows than that keeps first-seen order and
+    maps every cell back to its id, and a unique-budget spill that comes
+    after the table grew rolls its line back cleanly."""
+    B, L, vocab = 4096, 32, 1 << 22
+    ids = rng.choice(vocab, size=(B, L), replace=False).astype(np.int64)
+    ids[1::2, :24] = ids[0::2, :24]          # repeats, 8 new ids a row
+    blob = ("\n".join("1 " + " ".join(f"{j}:1" for j in row)
+                      for row in ids) + "\n").encode()
+    bb = cparser.BatchBuilder(B, L, vocab, num_threads=threads,
+                              max_uniq=max_uniq,
+                              max_features_per_example=L)
+    seen, off = 0, 0
+    while seen < B:
+        full, consumed = bb.feed(blob, off)
+        off += consumed
+        n, _, uniq, li, vals, _, _ = bb.finish()
+        assert n and (max_uniq == 0 or len(uniq) <= max_uniq)
+        want = ids[seen:seen + n]
+        np.testing.assert_array_equal(uniq[li[:n]], want)
+        flat = want.ravel()
+        first = flat[np.sort(np.unique(flat, return_index=True)[1])]
+        np.testing.assert_array_equal(uniq[1:], first)  # slot 0 = pad
+        assert (li[n:] == 0).all() and not vals[n:].any()
+        seen += n
+    assert off == len(blob)
+    assert (seen == B) and (max_uniq == 0) == (n == B)
+
+
 def test_threaded_builder_scales(rng):
     """host-side build rate must scale with parse threads (>= 1.5x at
     T=4). Skipped where the cores to show it don't exist."""

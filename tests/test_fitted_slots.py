@@ -223,7 +223,8 @@ def test_new_metric_files_read_the_stream_or_nothing(tmp_path, name,
                      if m["name"] == name)
     assert entry == {**{k: spec[k] for k in (
         "name", "unit", "better", "source", "layer", "moves")},
-        "workloads": ["fm16-train-zipf", "ffm4-train-zipf"]}
+        "workloads": ["fm16-train-zipf", "ffm4-train-zipf",
+                      "fm16x4-train-zipf"]}     # PR 27 appended the last
     first = {"pipeline/batches": 20, "train/examples": 16}
     last = {"pipeline/batches": 120, "train/examples": 816}
     if with_counter:
@@ -302,7 +303,7 @@ def test_benchmark_json_lists_every_metric_with_its_file_and_reader():
     test_benchmark_json_lists_the_nine_for_both_train_cells checks
     past its first line, which pins PR 25's nine metrics as the LAST
     nine of per_layer (CHANGES.md, PR 26): they are all still there,
-    in their order, before the two this PR appends."""
+    in their order, before the two PR 26 appended and PR 27's one."""
     from benchmarks import harness
     with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
         spec = json.load(fh)
@@ -310,13 +311,15 @@ def test_benchmark_json_lists_every_metric_with_its_file_and_reader():
     nine = ["dedup_sort_ms", "table_gather_ms", "slot_expand_ms",
             "interaction_ms", "table_scatter_ms", "step_unscoped_ms",
             "loss_sync_share", "epoch_barrier_s", "compiles_per_epoch"]
-    assert names[-11:] == nine + ["uniq_slot_fill", "host_build_s_per_batch"]
+    assert names[-12:] == nine + ["uniq_slot_fill", "host_build_s_per_batch",
+                                  "collective_exposed_ms"]     # PR 27's
     assert set(spec) == {"command", "paths", "run_seconds", "configs",
                          "workloads", "end_to_end", "per_layer"}
     name = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
     e2e = {m["name"]: m for m in spec["end_to_end"]}
     cells = {w["name"] for w in spec["workloads"]}
-    assert cells == {"fm16-train-zipf", "ffm4-train-zipf"}
+    assert cells == {"fm16-train-zipf", "ffm4-train-zipf",
+                     "fm16x4-train-zipf"}
     for w in spec["workloads"]:
         cell = harness.load_cell(w["name"])
         assert {m["name"] for m in cell.end_to_end} > {"setup_s"}

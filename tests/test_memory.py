@@ -352,8 +352,14 @@ def test_plan_train_owners_and_overrides():
     p3 = mem.plan(cfg, "train", {"dtype": "f16"})
     assert p3["owners"]["table"] == tbl // 2
     assert p3["owners"]["adagrad_acc"] == tbl
+    # More than one shard: the mesh's padded rows (ckpt_rows), a
+    # device's share beside the whole.
     p4 = mem.plan(cfg, "train", {"shards": 4})
-    assert p4["owners"]["table"] == -(-tbl // 4)
+    whole = mem.table_bytes(cfg, rows=cfg.ckpt_rows)
+    assert p4["owners"]["table"] == whole // 4
+    assert p4["sharded_owners"] == {"table": whole, "adagrad_acc": whole}
+    assert p4["shards"] == 4 and p["shards"] == 1
+    assert p["sharded_owners"] == {}
 
 
 def test_plan_serve_and_offload_host_owners():
@@ -399,14 +405,91 @@ def test_preflight_refuses_oversized_and_noop_without_capacity(
     mem.preflight_capacity(cfg, "train")  # fits: silent
 
 
-def test_train_preflight_fails_fast(tmp_path, monkeypatch):
+@pytest.mark.parametrize("capacity,refused", [
+    (65536, True),       # under a device's share of the mesh: refused
+    (2 << 20, False),    # under the whole 7.4 MB, over a 0.9 MB share
+])
+def test_train_preflight_fails_fast(tmp_path, monkeypatch, capacity,
+                                    refused):
     """Satellite 2: the oversized config is refused BEFORE any device
-    allocation, with the planner breakdown in the error."""
+    allocation, with the planner breakdown in the error. The session
+    passes the mesh it built (the suite's eight devices): a table that
+    exceeds one device and fits the mesh trains."""
+    import jax
     from fast_tffm_tpu.train import train
     cfg = _train_cfg(tmp_path, vocabulary_size=100_000)
-    monkeypatch.setenv(mem.FAKE_CAPACITY_ENV, "65536")
-    with pytest.raises(ValueError, match="predicted device total"):
+    monkeypatch.setenv(mem.FAKE_CAPACITY_ENV, str(capacity))
+    assert 2 * mem.table_bytes(cfg) > capacity
+    if not refused:
         train(cfg)
+        return
+    with pytest.raises(ValueError, match="predicted device total") as ei:
+        train(cfg)
+    assert f"on each of the {jax.device_count()} devices" in str(ei.value)
+
+
+@pytest.mark.parametrize("devices,capacity,refused", [
+    (1, 3 << 20, True),      # 7.3 MB of state on one 3 MB device
+    (4, 3 << 20, False),     # 1.8 MB a device on four
+    (4, 1 << 20, True),      # exceeds four: refused by name
+])
+def test_preflight_is_per_device_of_the_mesh(monkeypatch, devices,
+                                             capacity, refused):
+    """ISSUE 27: the pre-flight holds ONE device's share of table and
+    accumulator against one device's capacity. A table that exceeds
+    one device and fits four starts on four and is refused on one."""
+    import logging
+    from fast_tffm_tpu.utils.logging import get_logger
+    cfg = FmConfig(vocabulary_size=100_000, factor_num=8, batch_size=32,
+                   max_features_per_example=16)
+    monkeypatch.setenv(mem.FAKE_CAPACITY_ENV, str(capacity))
+    whole = mem.table_bytes(
+        cfg, rows=cfg.ckpt_rows if devices > 1 else cfg.num_rows)
+    if not refused:
+        said = []
+        handler = logging.Handler()
+        handler.emit = lambda r: said.append(r.getMessage())
+        get_logger().addHandler(handler)
+        try:
+            mem.preflight_capacity(cfg, "train", shards=devices)
+        finally:
+            get_logger().removeHandler(handler)
+        assert f"per device, 1/4 of {2 * whole} bytes" in said[0]
+        return
+    with pytest.raises(ValueError) as ei:
+        mem.preflight_capacity(cfg, "train", shards=devices)
+    msg = str(ei.value)
+    assert "adagrad_acc" in msg and "verdict: EXCEEDS" in msg
+    assert "what-if {" not in msg  # the session's own mesh, no what-if
+    assert "more devices" in msg and "lookup = host" in msg
+    assert "ROADMAP" not in msg
+    if devices > 1:
+        assert "on each of the 4 devices" in msg
+        assert f"(per device, 1/4 of {mem._mb(whole)})" in msg
+        assert "per device" in msg.split("predicted device total")[1]
+    else:
+        assert "per device" not in msg
+
+
+def test_offload_and_serve_plans_do_not_follow_the_mesh():
+    """Offload keeps the table on the host (its session builds no
+    mesh), a server holds a whole table per replica: neither plan is
+    divided unless a what-if says so, and a plan is a function of the
+    config alone, whatever devices the box that asks has."""
+    cfg = FmConfig(vocabulary_size=1000, factor_num=4)
+    tbl = mem.table_bytes(cfg)
+    ps = mem.plan(cfg, "serve")
+    assert ps["shards"] == 1 and ps["owners"]["serve_table"] == tbl
+    off = FmConfig(vocabulary_size=1000, factor_num=4, lookup="host",
+                   dedup="host")
+    po = mem.plan(off, "train", shards=4)
+    assert po["sharded_owners"] == {}
+    assert "per device" not in mem.render_plan(po)
+    assert mem.plan(off, "train")["host_owners"]["offload_table"] == tbl
+    import jax
+    assert jax.device_count() > 1  # the suite's eight CPU devices
+    assert mem.plan(cfg, "train")["shards"] == 1
+    assert mem.plan(cfg, "train")["owners"]["table"] == tbl
 
 
 # ------------------------------- plan vs live ledger (the 10% check)
@@ -416,17 +499,24 @@ def test_plan_within_10pct_of_live_ledger_train(tmp_path):
     a REAL train run registered, within 10%, for the default train
     shape."""
     from fast_tffm_tpu.train import train
-    cfg = _train_cfg(tmp_path)
+    # 40,000 rows: a device's share of table and accumulator, not the
+    # whole batch's wire ceiling, is what the plan is made of.
+    cfg = _train_cfg(tmp_path, vocabulary_size=40_000)
     train(cfg)
     live = 0.0
     for ev in read_events(cfg.model_file + ".metrics.jsonl"):
         if ev.get("event") == "metrics":
             live = max(live, ev["gauges"].get("mem/live_bytes", 0.0))
     assert live > 0
-    p = mem.plan(cfg, "train")
+    # The session meshes over every device it sees (eight here) and
+    # books one device's share of the mesh's padded rows.
+    import jax
+    n = jax.device_count()
+    p = mem.plan(cfg, "train", shards=n)
     assert p["total_bytes"] == pytest.approx(live, rel=0.10)
     # The model state itself is predicted exactly.
-    assert p["owners"]["table"] == mem.table_bytes(cfg)
+    assert p["owners"]["table"] == mem.table_bytes(
+        cfg, rows=cfg.ckpt_rows) // n
 
 
 def _served(tmp_path, **overrides):
@@ -609,7 +699,9 @@ def test_render_memory_section_and_pressure_verdict(tmp_path,
                                                summarize)
     from fast_tffm_tpu.train import train
     cfg = _train_cfg(tmp_path, mem_pressure_fraction=0.5)
-    resident = 2 * mem.table_bytes(cfg)
+    import jax  # the session books one device's share of its mesh
+    owners = mem.plan(cfg, "train", shards=jax.device_count())["owners"]
+    resident = owners["table"] + owners["adagrad_acc"]
     monkeypatch.setenv(mem.FAKE_CAPACITY_ENV, str(int(resident / 0.6)))
     train(cfg)
     summary = summarize([cfg.model_file + ".metrics.jsonl"])

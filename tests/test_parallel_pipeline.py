@@ -239,6 +239,116 @@ def test_no_worker_leak_on_completion_and_abandon(tmp_path):
     assert not leaked()
 
 
+@needs_cpp
+@pytest.mark.parametrize("chunk_bytes", [7, 64, 1000, 1 << 20])
+def test_group_scanner_cuts_the_same_groups_at_any_chunk(tmp_path,
+                                                         monkeypatch,
+                                                         chunk_bytes):
+    """A group is scanned once, the scan resuming where it stopped as
+    chunks are appended: whatever the chunk size, the groups are the
+    file's example lines B at a time, blank lines carried along, and
+    the parallel plane's stream is the serial one."""
+    from fast_tffm_tpu.data import pipeline as pl
+    path = _write(tmp_path, n=120, seed=5, blanks=True)
+    real = pl._iter_owned_chunks
+    monkeypatch.setattr(
+        pl, "_iter_owned_chunks",
+        lambda p, s, e, chunk_bytes_=chunk_bytes, **kw: real(
+            p, s, e, **{**kw, "chunk_bytes": chunk_bytes_}))
+    sc = pl._GroupScanner([path, path], 0, 1, 16, False, None)
+    groups = list(iter(sc.next_group, None))
+    data = open(path, "rb").read()
+    assert b"".join(g.blob for g in groups) == (data + data).rstrip()+b"\n"
+    for g in groups[:-1]:
+        assert sum(bool(ln.strip()) for ln in g.blob.splitlines()) == 16
+    assert [g.line_start for g in groups] == list(np.cumsum(
+        [0] + [g.lines for g in groups[:-1]]))
+    _assert_parity(path)
+
+
+@needs_cpp
+def test_a_built_batch_is_emitted_before_the_ring_is_filled(tmp_path,
+                                                            monkeypatch):
+    """The coordinator stops filling the ring when the batch at its head
+    is built: an epoch's first batch does not wait for the cutting of
+    ``depth`` groups (8 here, each made slow), only for its own."""
+    from fast_tffm_tpu.data import pipeline as pl
+    path = _write(tmp_path, n=400, seed=14)
+    cut = []
+    real = pl._GroupScanner.next_group
+
+    def slow(self):
+        time.sleep(0.05)
+        cut.append(1)
+        return real(self)
+    monkeypatch.setattr(pl._GroupScanner, "next_group", slow)
+    cfg = _cfg(path, 4)
+    # fixed U: the scanner is driven inline, no thread cuts ahead
+    it = batch_iterator(cfg, cfg.train_files, training=True,
+                        fixed_shape=True, uniq_bucket=280)
+    next(it)
+    assert len(cut) <= 3
+    rest = list(it)
+    monkeypatch.undo()
+    want = list(batch_iterator(_cfg(path, 1), cfg.train_files,
+                               training=True, fixed_shape=True,
+                               uniq_bucket=280))
+    assert len(rest) + 1 == len(want) == 25
+
+
+def test_read_ahead_orders_raises_and_stops():
+    """_read_ahead: the items in order, what the source raises raised at
+    the consumer, and a closed consumer stops the thread."""
+    from fast_tffm_tpu.data.pipeline import _read_ahead
+
+    def alive(name):
+        return [t for t in threading.enumerate() if t.name == name]
+
+    assert list(_read_ahead(iter(range(50)), 2, "ra-all")) == list(
+        range(50))
+
+    def bad():
+        yield 1
+        raise KeyError("from the source")
+    it = _read_ahead(bad(), 2, "ra-bad")
+    assert next(it) == 1
+    with pytest.raises(KeyError, match="from the source"):
+        next(it)
+    it = _read_ahead(iter(range(10 ** 9)), 2, "ra-closed")
+    assert next(it) == 0
+    it.close()
+    deadline = time.monotonic() + 5
+    while (alive("ra-closed") or alive("ra-all") or alive("ra-bad")) \
+            and time.monotonic() < deadline:
+        time.sleep(0.05)
+    assert not (alive("ra-closed") or alive("ra-all") or alive("ra-bad"))
+
+
+@needs_cpp
+def test_scan_thread_stops_with_the_iterator(tmp_path):
+    """The scanner's read-ahead thread (fm-scan) ends with its epoch and
+    with an abandoned iterator, like the build pool."""
+    path = _write(tmp_path, n=400, seed=13)
+    cfg = _cfg(path, 4)
+
+    def scanning():
+        return [t for t in threading.enumerate()
+                if t.name == "fm-scan" and t.is_alive()]
+
+    def gone():
+        deadline = time.monotonic() + 5
+        while scanning() and time.monotonic() < deadline:
+            time.sleep(0.05)
+        return not scanning()
+
+    it = batch_iterator(cfg, cfg.train_files, training=True)
+    next(it)
+    it.close()
+    assert gone()
+    list(batch_iterator(cfg, cfg.train_files, training=True))
+    assert gone()
+
+
 def test_resolve_host_threads():
     path_free = dict(vocabulary_size=8, batch_size=4)
     assert resolve_host_threads(FmConfig(host_threads=3,

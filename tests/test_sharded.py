@@ -239,3 +239,149 @@ def test_sharded_order3_step_matches_single_device(tmp_path):
                                    rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(np.asarray(table_s)[:cfg.num_rows],
                                np.asarray(table_1), rtol=1e-4, atol=1e-6)
+
+
+# ---- the mesh step on a global batch against the plain reference -------
+# (ISSUE 27: four devices, mesh (4,1), as the cell fm16x4-train-zipf)
+
+def _global_batches(cfg, rng, n_batches, hot_rows):
+    """Seeded global batches whose ids crowd the table's first rows:
+    shard 0 of four is hot, shard 1 holds the rest and the live pad
+    row, shards 2 and 3 hold nothing but the dead tail past it."""
+    lines = []
+    for _ in range(n_batches * cfg.batch_size):
+        nnz = int(rng.integers(2, 7))
+        hot = rng.choice(hot_rows, size=nnz - 1, replace=False)
+        cold = rng.integers(hot_rows, cfg.vocabulary_size)
+        ids = np.concatenate([hot, [cold]])
+        feats = " ".join(f"{i}:{rng.random() + 0.1:.3f}" for i in ids)
+        lines.append(f"{int(rng.random() < 0.4)} {feats}")
+    return lines
+
+
+def _oracle_examples(batch):
+    uniq = np.asarray(batch.uniq_ids, np.int64)
+    out = []
+    for idx, vals in zip(batch.local_idx, batch.vals):
+        keep = np.asarray(vals) != 0
+        out.append((uniq[np.asarray(idx)[keep]],
+                    np.asarray(vals, np.float64)[keep]))
+    return out
+
+
+def test_mesh_step_on_a_global_batch_matches_the_float64_oracle(tmp_path):
+    """Synchronous updates (the configuration's guarantee): four
+    devices on a global batch owe what one device gives on it. Loss,
+    first gradient (from the state after one step, as
+    benchmarks/check.py reads it), table and accumulator after three
+    steps, against models/oracle.py in float64 on seeded weights. The
+    1,501 rows are padded to 4,096 and cut in four: the shards are as
+    uneven as they can be (hot, lukewarm, two dead)."""
+    from fast_tffm_tpu.models import oracle
+    rng = np.random.default_rng(27)
+    path = tmp_path / "train.txt"
+    cfg = _cfg(str(path), vocabulary_size=1500, batch_size=32,
+               max_features_per_example=8, bucket_ladder=(8,))
+    path.write_text("\n".join(_global_batches(cfg, rng, 3, 40)) + "\n")
+    spec = ModelSpec.from_config(cfg)
+    mesh = make_mesh(jax.devices()[:4])
+    assert dict(mesh.shape) == {"data": 4, "model": 1}
+    table_s, acc_s = init_sharded_state(cfg, mesh, seed=5)
+    assert table_s.shape == (4096, cfg.row_dim)
+    table = np.asarray(table_s, np.float64)[:cfg.num_rows]
+    acc = np.asarray(acc_s, np.float64)[:cfg.num_rows]
+    step = make_sharded_train_step(spec, mesh)
+    n = 0
+    for n, batch in enumerate(batch_iterator(cfg, cfg.train_files,
+                                             training=True), 1):
+        assert len(batch.uniq_ids) % 4 == 0
+        examples = _oracle_examples(batch)
+        labels = np.asarray(batch.labels, np.float64)
+        want_loss = (oracle.logistic_loss(
+            oracle.batch_scores(table, examples), labels)
+            + oracle.regularization(table, examples, cfg.factor_lambda,
+                                    cfg.bias_lambda))
+        grad = oracle.grad_fd(table, examples, labels, cfg.factor_lambda,
+                              cfg.bias_lambda)
+        before = table
+        table, acc = oracle.adagrad_step(table, acc, grad,
+                                         cfg.learning_rate)
+        table_s, acc_s, loss, _ = step(
+            table_s, acc_s, **shard_batch(mesh, **batch_args(batch)))
+        assert float(loss) == pytest.approx(want_loss, rel=2e-6)
+        if n == 1:
+            got = ((before - np.asarray(table_s, np.float64)[:cfg.num_rows])
+                   * np.sqrt(np.asarray(acc_s, np.float64)[:cfg.num_rows])
+                   / cfg.learning_rate)
+            np.testing.assert_allclose(got, grad, rtol=2e-4, atol=2e-8)
+    assert n == 3
+    np.testing.assert_allclose(np.asarray(table_s)[:cfg.num_rows], table,
+                               rtol=1e-5, atol=1e-7)
+    np.testing.assert_allclose(np.asarray(acc_s)[:cfg.num_rows], acc,
+                               rtol=1e-5, atol=1e-9)
+    # the dead tail past the pad row is as it was made
+    assert not np.asarray(table_s)[cfg.num_rows - 1:].any()
+
+
+def test_each_shards_rows_add_up_to_the_uncut_gather(tmp_path):
+    """The share tied to the whole: what each shard gathers from its
+    own rows for the slots of ``uniq_ids`` that fall in its range,
+    the rest left zero (the masked local pass GSPMD makes of the
+    gather), adds up to the gather from the uncut table, every slot
+    claimed once. The host unique is in first-seen order, pad slot
+    first, NOT sorted: a shard's slots are no contiguous range, so an
+    explicit exchange would have to sort or mask as GSPMD does."""
+    rng = np.random.default_rng(28)
+    path = tmp_path / "train.txt"
+    cfg = _cfg(str(path), vocabulary_size=1500, batch_size=32,
+               max_features_per_example=8, bucket_ladder=(8,))
+    path.write_text("\n".join(_global_batches(cfg, rng, 1, 40)) + "\n")
+    mesh = make_mesh(jax.devices()[:4])
+    table_s, _ = init_sharded_state(cfg, mesh, seed=6)
+    batch = next(iter(batch_iterator(cfg, cfg.train_files, training=True)))
+    uniq = np.asarray(batch.uniq_ids)
+    assert uniq[0] == cfg.pad_id and (np.diff(uniq) < 0).any()
+    whole = np.asarray(table_s)[uniq]
+    parts, claimed = np.zeros_like(whole), np.zeros(len(uniq), int)
+    sizes = []
+    for shard in table_s.addressable_shards:
+        lo, hi = shard.index[0].start, shard.index[0].stop
+        mine = np.flatnonzero((uniq >= lo) & (uniq < hi))
+        sizes.append(int((uniq[mine] != cfg.pad_id).sum()))
+        parts[mine] += np.asarray(shard.data)[uniq[mine] - lo]
+        claimed[mine] += 1
+    np.testing.assert_array_equal(claimed, 1)
+    np.testing.assert_array_equal(parts, whole)
+    # real rows: a hot shard, a lukewarm one (which also answers every
+    # pad slot), and two that own no slot at all
+    assert sizes[0] > sizes[1] > 0 and sizes[2:] == [0, 0]
+
+
+def test_mesh_run_feeds_the_counters_a_reader_needs(tmp_path):
+    """ISSUE 27, step 4: on the mesh path ``train/examples`` counts
+    the global batch, ``train/h2d`` covers ``shard_batch`` with the
+    bytes of the arrays shipped, the unique counters are fed with the
+    mesh's U, and ``train/mesh_devices`` tells the run from a
+    one-device run's stream."""
+    from fast_tffm_tpu.obs.sink import read_events
+    from fast_tffm_tpu.train import train
+    rng = np.random.default_rng(29)
+    path = tmp_path / "train.txt"
+    cfg = _cfg(str(path), vocabulary_size=1500, batch_size=32,
+               max_features_per_example=8, bucket_ladder=(8,),
+               model_file=str(tmp_path / "m" / "fm"),
+               metrics_file=str(tmp_path / "metrics.jsonl"),
+               metrics_flush_steps=1, log_steps=0)
+    path.write_text("\n".join(_global_batches(cfg, rng, 3, 40)) + "\n")
+    train(cfg)
+    last = [e for e in read_events(cfg.metrics_file)
+            if e.get("event") == "metrics"][-1]
+    c, g = last["counters"], last["gauges"]
+    assert g["train/mesh_devices"] == jax.device_count() == 8
+    assert c["train/steps"] == 3 and c["train/examples"] == 96
+    assert c["pipeline/uniq_slots"] % 8 == 0
+    assert 0 < c["pipeline/uniq_rows"] < c["pipeline/uniq_slots"]
+    # labels, weights, uniq_ids[U], local_idx[B, L], vals[B, L]: 4 B each
+    assert c["train/h2d_bytes"] == (
+        3 * (32 * 4 * 2 + 2 * 32 * 8 * 4) + 4 * c["pipeline/uniq_slots"])
+    assert c["train/h2d_seconds"] > 0

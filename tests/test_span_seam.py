@@ -286,6 +286,33 @@ def test_the_epoch_barrier_encloses_flush_and_pipeline_start(traced_run):
             "train/step"} <= inside
 
 
+@pytest.mark.parametrize("which", ["mid-epoch", "epoch's last"])
+def test_a_loss_line_syncs_after_the_next_batch_is_placed(traced_run, which):
+    """A live loss line waits for the device only once the next batch is
+    fetched and placed, just ahead of its dispatch, so the device waits
+    for the host one dispatch after a line and not a placement too; the
+    epoch's last line syncs before the barrier opens."""
+    events, _ = traced_run
+    spans = sorted((e for e in events if e["event"] == "span"
+                    and e["name"] in ("train/loss_sync", "train/h2d",
+                                      "train/step", "train/epoch_barrier")),
+                   key=lambda e: e["ts"])
+    names = [s["name"] for s in spans]
+    syncs = [i for i, n in enumerate(names) if n == "train/loss_sync"]
+    assert len(syncs) == 4          # steps 2, 4 (epoch 1), 6, 8 (epoch 2)
+    if which == "mid-epoch":
+        for i in (syncs[0], syncs[2]):
+            assert names[i - 1] == "train/h2d"      # step 3's, step 7's
+            assert names[i + 1] == "train/step"
+            assert (spans[i]["ts"] + spans[i]["dur"]
+                    <= spans[i + 1]["ts"] + 1e-6)
+    else:
+        i = syncs[1]
+        assert names[i - 1] == "train/step"         # step 4's dispatch
+        assert names[i + 1] == "train/epoch_barrier"
+        assert names[syncs[3] - 1] == "train/step"  # step 8, then the end
+
+
 def test_dead_gauges_are_gone(traced_run):
     events, predict_events = traced_run
     for evs in traced_run:

@@ -2664,8 +2664,7 @@ def scenario_oom_pressure(workdir: str, seed: int = 0) -> str:
     borderline config trains to completion while emitting
     ``health: hbm_pressure`` exactly once per episode, and fmstat
     renders the HBM-PRESSURE verdict."""
-    from fast_tffm_tpu.obs.memory import (FAKE_CAPACITY_ENV, LEDGER,
-                                          plan, table_bytes)
+    from fast_tffm_tpu.obs.memory import FAKE_CAPACITY_ENV, LEDGER, plan
     from fast_tffm_tpu.train import train
     corpus = os.path.join(workdir, "train_oom.txt")
     _write_corpus(corpus, 400, seed)
@@ -2696,11 +2695,15 @@ def scenario_oom_pressure(workdir: str, seed: int = 0) -> str:
         # threshold at every flush (ONE episode, never re-armed), but
         # the full predicted set still FITS, so pre-flight lets it
         # run.
-        cfg = _cfg(workdir, corpus, vocabulary_size=20000,
+        cfg = _cfg(workdir, corpus, vocabulary_size=200000,
                    factor_num=8, mem_pressure_fraction=0.5)
-        resident = 2 * table_bytes(cfg)
+        # (one device's share: the session row-shards both over the
+        # mesh of every device here, and the ledger books the share)
+        import jax
+        p = plan(cfg, "train", shards=jax.device_count())
+        resident = p["owners"]["table"] + p["owners"]["adagrad_acc"]
         cap = int(resident / 0.6)
-        assert plan(cfg, "train")["total_bytes"] <= cap, (
+        assert p["total_bytes"] <= cap, (
             "scenario shape drifted: the borderline config no longer "
             "fits its own injected capacity")
         os.environ[FAKE_CAPACITY_ENV] = str(cap)

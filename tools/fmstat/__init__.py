@@ -236,8 +236,9 @@ def main_capacity(argv=None) -> int:
                          "field_num, batch_size, "
                          "max_features_per_example, dtype "
                          "(f32|f16|bf16|int8, resident table only), "
-                         "shards (per-device share under row "
-                         "sharding)")
+                         "shards (devices a train session's mesh "
+                         "shards the rows over: a device's share, as "
+                         "the session's pre-flight checks it)")
     ap.add_argument("--capacity-bytes", type=int, default=0,
                     help="assume this device capacity instead of "
                          "asking the backend (sizing for a target "
