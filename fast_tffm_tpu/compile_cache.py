@@ -30,6 +30,7 @@ the same directory rule from here.
 
 from __future__ import annotations
 
+import contextlib
 import os
 
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -77,3 +78,23 @@ def cache_entries(path: str) -> int:
         return 0
     return sum(1 for name in os.listdir(path)
                if not name.endswith("-atime"))
+
+
+@contextlib.contextmanager
+def uncached():
+    """Compile in this process: the body's programs are neither read
+    from the persistent cache nor written to it (``models/fm.py``,
+    ``_Relabel``, says who needs that and why). The switch is
+    process-wide for the body's duration, jax has no narrower one: a
+    compile on another thread meanwhile misses the cache and nothing
+    else."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()

@@ -24,11 +24,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental.layout import Format, Layout
 
 from fast_tffm_tpu.config import FmConfig
 from fast_tffm_tpu.data.pipeline import DeviceBatch
 from fast_tffm_tpu.ops.interaction import (batch_reg, ffm_batch_scores,
                                            fm_batch_scores, gather_rows)
+from fast_tffm_tpu.compile_cache import uncached
+from fast_tffm_tpu.obs.telemetry import active
+from fast_tffm_tpu.utils.logging import get_logger
 
 
 @dataclasses.dataclass(frozen=True)
@@ -331,15 +335,179 @@ def train_step_body(spec: ModelSpec, table, acc, labels, weights, uniq_ids,
     return table, acc, loss, scores
 
 
+class TrainStep:
+    """The one-device train step, compiled per batch-shape bucket, with
+    the table and the accumulator held in the layout the step's own
+    gather and scatters work in.
+
+    The runtime's default layout of a long, narrow array and the one a
+    gather or a scatter-add takes its operand in can differ (on the
+    v5e: FFM's [2^23+1, 89] arrives rows-minor, the step works
+    row-contiguous), and a step compiled for the default then copies
+    the whole table and accumulator in and out, every step. So the
+    FIRST compile leaves the layout of the two donated arguments and
+    of the two state results to the compiler (``Layout.AUTO``) and
+    reads back what it chose; every later bucket is compiled with that
+    layout pinned on both sides. A state that arrives in another
+    layout is re-laid once (counter ``train/state_relayouts``); the
+    step's results come back in the layout and feed the next call as
+    they are. Where the compiler keeps the default (FM's 17 columns on
+    the v5e, any shape on the CPU) nothing is re-laid and the program
+    is the one a plain ``jax.jit`` builds. Other readers of the state
+    (scorers, gathers, checkpoint saves) need nothing: jax compiles a
+    function that names no layout for the layout its committed
+    argument has (``_Relabel`` keeps that true)."""
+
+    def __init__(self, spec: ModelSpec):
+        self._body = _bind(train_step_body, spec, "fm_train_step")
+        self._layout = None     # the compiler's choice, once made
+        self._relabel = None    # set where that is not the default
+        self._programs = {}     # argument shapes -> compiled program
+
+    def __call__(self, table, acc, labels, weights, uniq_ids, local_idx,
+                 vals, fields=None):
+        batch = (labels, weights, uniq_ids, local_idx, vals, fields)
+        if isinstance(table, jax.core.Tracer):
+            # Inside someone else's trace there is no buffer to lay
+            # out: the layouts are the enclosing program's to choose.
+            return jax.jit(self._body)(table, acc, *batch)
+        table, acc = (x if isinstance(x, jax.Array) else jnp.asarray(x)
+                      for x in (table, acc))
+        args = (table, acc) + batch
+        key = tuple(None if x is None else (x.shape, x.dtype) for x in args)
+        program = self._programs.get(key)
+        if program is None:
+            program = self._programs[key] = self.compile(*args)
+        if self._relabel is None:
+            return program(table, acc, *batch)
+        table, acc, loss, scores = program(*self._laid(table, acc), *batch)
+        return (*self._relabel(table, acc), loss, scores)
+
+    def compile(self, table, acc, *batch):
+        """The program of one bucket, from arrays or their
+        ``ShapeDtypeStruct``s (the state's carry the device). The
+        first one compiled decides the layout."""
+        first = self._layout is None
+        state = Format(Layout.AUTO if first else self._layout,
+                       table.sharding)
+        shapes = [None if x is None else jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=getattr(x, "sharding", None))
+            for x in (table, acc) + batch]
+        program = jax.jit(
+            self._body, donate_argnums=(0, 1),
+            in_shardings=(state, state) + (None,) * len(batch),
+            out_shardings=(state, state, None, None),
+        ).lower(*shapes).compile()
+        if first:
+            chosen = {"table": program.input_formats[0][0].layout,
+                      "acc": program.input_formats[0][1].layout,
+                      "table result": program.output_formats[0].layout,
+                      "acc result": program.output_formats[1].layout}
+            if len(set(chosen.values())) != 1:
+                raise RuntimeError(
+                    "the compiler chose different layouts for the train "
+                    f"step's state and its results ({chosen}): the step "
+                    "would copy a whole array every call")
+            self._layout = chosen["table"]
+        default = self._layout == _default_layout(shapes[0])
+        if first:
+            get_logger().info(
+                "train state layout: %s%s%s, the compiler's choice for "
+                "this step's gather and scatters, %s", table.dtype,
+                list(table.shape), _xla_text(self._layout),
+                "as the runtime lays it out anyway" if default else
+                "not the runtime's default: the state is re-laid once")
+        if self._relabel is None and not default:
+            self._relabel = _Relabel(self._layout, *shapes[:2])
+        return program
+
+    def _laid(self, *state):
+        """The state in the step's layout: re-laid where it arrives in
+        another one, as it is where it does not."""
+        if all(x.format.layout == self._layout for x in state):
+            return state
+        return self._relabel(*(self._relay(x) for x in state))
+
+    def _relay(self, x):
+        if x.format.layout == self._layout:
+            return x
+        tel = active()
+        if tel is not None:
+            tel.count("train/state_relayouts")
+        laid = jax.device_put(x, Format(self._layout, x.sharding))
+        # The old buffer goes once the copy has run, by hand and waited
+        # for: a buffer of another size is no use to the copy as a
+        # donation and would live on with the caller's reference, and
+        # the step program's load reserves its temporaries at dispatch
+        # beside whatever is still allocated (on the v5e FFM's two old
+        # arrays and two new ones left 1.74 GiB for a 3.01 GiB program).
+        jax.block_until_ready(laid)
+        x.delete()
+        return laid
+
+
+class _Relabel:
+    """Hands a train step's table and accumulator back under the layout
+    they have.
+
+    jaxlib 0.9.0 labels every result of an executable it DESERIALIZED
+    from the persistent compile cache with the runtime's default layout,
+    whatever layout the buffer has. ``x.format`` then lies, jax refuses
+    the array to the next step ("compiled for input layouts that
+    disagree"), and any other program it compiles for that array reads
+    it wrongly (CPU: other values, silently) or is refused by the
+    runtime (TPU: "expected parameter 0 of size ..."). An executable
+    compiled in the process labels its results as they are. So the
+    results of the step, and of the re-lay, pass through this identity,
+    compiled here in the process with both arrays donated and aliased:
+    no operation on the device, one launch, and arrays whose label is
+    true. It is run at the executable's own entry, below jax's check of
+    argument layouts, which would read the false label. The step's own
+    programs keep coming from the cache. Take this class out when a
+    jaxlib labels them rightly: the warm-cache run in
+    tests/test_state_layout.py fails without it until then."""
+
+    def __init__(self, layout: Layout, table, acc):
+        formats = (Format(layout, table.sharding),
+                   Format(layout, acc.sharding))
+        with uncached():
+            self._identity = jax.jit(
+                lambda table, acc: (table, acc), donate_argnums=(0, 1),
+                in_shardings=formats, out_shardings=formats,
+            ).lower(table, acc).compile().runtime_executable()
+
+    def __call__(self, table, acc):
+        results = self._identity.execute_sharded([table, acc])
+        table, acc = (per_device[0] for per_device in
+                      results.disassemble_into_single_device_arrays())
+        return table, acc
+
+
+def _xla_text(layout: Layout) -> str:
+    """A layout as XLA writes it, minor dimension first:
+    ``{1,0:T(8,128)}``."""
+    tiles = "".join(":T(%s)" % ",".join(map(str, t))
+                    for t in layout.tiling or ())
+    return "{%s%s}" % (",".join(map(str, reversed(layout.major_to_minor))),
+                       tiles)
+
+
+def _default_layout(x) -> Layout:
+    """What the runtime gives an array of x's shape on x's device when
+    nobody asks for a layout."""
+    device = next(iter(x.sharding.device_set))
+    return Layout.from_pjrt_layout(device.client.get_default_layout(
+        jnp.dtype(x.dtype), x.shape, device))
+
+
 @functools.lru_cache(maxsize=None)
-def make_train_step(spec: ModelSpec):
-    """Build the jitted train step. Signature:
+def make_train_step(spec: ModelSpec) -> TrainStep:
+    """Build the train step. Signature:
     (table, acc, labels, weights, uniq_ids, local_idx, vals, fields)
       -> (table, acc, loss, scores)
     Buffers are donated; one executable per batch-shape bucket. Cached per
     spec so repeated train()/evaluate() calls reuse compiled code."""
-    return jax.jit(_bind(train_step_body, spec, "fm_train_step"),
-                   donate_argnums=(0, 1))
+    return TrainStep(spec)
 
 
 def _unpack_wire(spec: ModelSpec, L: int, uniq_ids, lengths, flat_idx,

@@ -868,8 +868,11 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
             # What only a sync point or an epoch barrier feeds starts
             # at 0: a reader that differences two snapshots of the
             # stream must find "none yet" as 0, not as absent.
+            # So does what only a re-laid state feeds (models/fm.py,
+            # TrainStep): an FM run's answer is 0, not silence.
             for name in ("train/epochs", "train/epoch_barrier_seconds",
-                         "train/loss_sync_seconds"):
+                         "train/loss_sync_seconds",
+                         "train/state_relayouts"):
                 tel.count(name, 0)
 
         # Step-anatomy join keys (obs/anatomy.py; README "Step

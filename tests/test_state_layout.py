@@ -1,0 +1,369 @@
+"""The one-device train state's layout (models/fm.py ``TrainStep``,
+ISSUE 28): the step compiles its first bucket with the layout of table
+and accumulator left to the compiler, holds what the compiler chose,
+re-lays a state that arrives otherwise ONCE, and hands its results to
+the next call as they are. On the CPU the compiler's choice is the
+default, so the forced cases pin a column-major layout on a fresh step
+object to drive the re-lay path; the compile-only tests at the bottom
+ask the TPU's own compiler, for a described v5e, what it chooses at the
+benchmark's two one-chip sizes."""
+
+import dataclasses
+import os
+import re
+import subprocess
+import sys
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.experimental.layout import Format, Layout
+from jax.sharding import SingleDeviceSharding
+
+from fast_tffm_tpu.checkpoint import CheckpointState
+from fast_tffm_tpu.config import FmConfig
+from fast_tffm_tpu.data.pipeline import batch_iterator
+from fast_tffm_tpu.models import fm
+from fast_tffm_tpu.models.fm import (ModelSpec, TrainStep, batch_args,
+                                     init_accumulator, init_table,
+                                     make_batch_scorer, train_step_body)
+from fast_tffm_tpu.obs.telemetry import RunTelemetry, activate
+from fast_tffm_tpu.train import checkpoint_template, ckpt_state
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+COUNTER = "train/state_relayouts"
+COLUMN_MAJOR = Layout(major_to_minor=(1, 0), tiling=())
+MODELS = ("fm", "ffm")
+LAYOUTS = ("compilers", "column_major")
+
+
+def _corpus(tmp_path, model, n=64, seed=5):
+    """Blocks of 16 lines, alternately narrow (the 4 rung) and wide
+    (the 8 rung): two ladder rungs, so two programs of one step."""
+    rng = np.random.default_rng(seed)
+    lines = []
+    for i in range(n):
+        lo, hi = (1, 5) if (i // 16) % 2 == 0 else (5, 9)
+        ids = rng.choice(300, size=int(rng.integers(lo, hi)), replace=False)
+        field = (lambda: f"{int(rng.integers(0, 3))}:") if model == "ffm" \
+            else (lambda: "")
+        lines.append(" ".join(
+            ["1" if rng.random() < 0.4 else "0"]
+            + [f"{field()}{j}:{rng.random():.4f}" for j in ids]))
+    path = tmp_path / f"{model}.txt"
+    path.write_text("\n".join(lines) + "\n")
+    return str(path)
+
+
+def _cfg(tmp_path, model, **kw):
+    more = dict(model_type="ffm", field_num=3) if model == "ffm" else {}
+    return FmConfig(vocabulary_size=300, factor_num=4, batch_size=16,
+                    shuffle=False, bucket_ladder=(4, 8),
+                    max_features_per_example=8, learning_rate=0.1,
+                    factor_lambda=1e-3, bias_lambda=1e-3,
+                    model_file=str(tmp_path / "m" / "fm"), **more, **kw)
+
+
+def _setup(tmp_path, model):
+    cfg = _cfg(tmp_path, model)
+    spec = dataclasses.replace(ModelSpec.from_config(cfg, training=True),
+                               dedup="host")
+    batches = list(batch_iterator(cfg, [_corpus(tmp_path, model)],
+                                  training=True, epochs=1))
+    assert {b.vals.shape[-1] for b in batches} == {4, 8}
+    return cfg, spec, batches
+
+
+def _step(spec, layout):
+    """A step object of this test's own (make_train_step's is shared by
+    the process); ``column_major`` pins the layout the compiler would
+    otherwise choose."""
+    step = TrainStep(spec)
+    if layout == "column_major":
+        step._layout = COLUMN_MAJOR
+    return step
+
+
+def _relayouts(tel):
+    return tel.registry.snapshot()["counters"].get(COUNTER, 0)
+
+
+@pytest.fixture
+def tel(tmp_path):
+    t = RunTelemetry(str(tmp_path / "metrics.jsonl"), meta={})
+    with activate(t):
+        yield t
+    t.close()
+
+
+@pytest.mark.parametrize("layout", LAYOUTS)
+@pytest.mark.parametrize("model", MODELS)
+def test_four_steps_equal_a_plain_jit_bit_for_bit(tmp_path, tel, model,
+                                                  layout):
+    """Table, accumulator and loss after each of four steps over two
+    rungs are those of ``jax.jit(train_step_body)``; the state is
+    re-laid before the first step or never, and a step's results go
+    into the next step untouched."""
+    cfg, spec, batches = _setup(tmp_path, model)
+    step = _step(spec, layout)
+    plain = jax.jit(lambda t, a, **b: train_step_body(spec, t, a, **b))
+    table, acc = init_table(cfg, 3), init_accumulator(cfg)
+    want_t, want_a = init_table(cfg, 3), init_accumulator(cfg)
+    counts = []
+    for b in batches[:4]:
+        fed = table
+        table, acc, loss, _ = step(table, acc, **batch_args(b))
+        want_t, want_a, want_loss, _ = plain(want_t, want_a,
+                                             **batch_args(b))
+        counts.append(_relayouts(tel))
+        assert fed.is_deleted()         # donated, directly or re-laid
+        np.testing.assert_array_equal(np.asarray(table),
+                                      np.asarray(want_t))
+        np.testing.assert_array_equal(np.asarray(acc), np.asarray(want_a))
+        assert float(loss) == float(want_loss)
+        assert table.format.layout == acc.format.layout == step._layout
+    assert len(step._programs) == 2
+    assert counts == [2 if layout == "column_major" else 0] * 4
+
+
+def test_the_compilers_choice_is_logged_once_and_held(tmp_path, caplog):
+    cfg, spec, batches = _setup(tmp_path, "fm")
+    step = _step(spec, "compilers")
+    logger = fm.get_logger()
+    logger.addHandler(caplog.handler)
+    try:
+        table, acc = init_table(cfg), init_accumulator(cfg)
+        for b in batches:
+            table, acc, _, _ = step(table, acc, **batch_args(b))
+    finally:
+        logger.removeHandler(caplog.handler)
+    lines = [r.getMessage() for r in caplog.records
+             if r.getMessage().startswith("train state layout:")]
+    assert lines == ["train state layout: float32[301, 5]{1,0}, the "
+                     "compiler's choice for this step's gather and "
+                     "scatters, as the runtime lays it out anyway"]
+    assert step._layout == init_table(cfg).format.layout
+
+
+def test_a_numpy_state_and_a_traced_call_still_work(tmp_path):
+    """Callers that hand the step host arrays (tests), and callers that
+    trace it inside a program of their own (the benchmark's scope
+    test), get the plain step."""
+    cfg, spec, batches = _setup(tmp_path, "fm")
+    step = _step(spec, "column_major")
+    args = batch_args(batches[0])
+    t0, a0 = np.asarray(init_table(cfg)), np.asarray(init_accumulator(cfg))
+    t1, a1, loss, _ = step(t0, a0, **args)
+    t2, a2, loss2, _ = jax.jit(lambda t, a: step(t, a, **args))(t0, a0)
+    np.testing.assert_array_equal(np.asarray(t1), np.asarray(t2))
+    np.testing.assert_array_equal(np.asarray(a1), np.asarray(a2))
+    assert float(loss) == float(loss2)
+
+
+def test_layouts_that_differ_fail_by_name(tmp_path, monkeypatch):
+    """A compiler that laid the accumulator out otherwise than the
+    table would make every step copy one of them: refused, not run."""
+    cfg, spec, batches = _setup(tmp_path, "fm")
+    step = _step(spec, "compilers")
+
+    class Program:
+        def __init__(self, program):
+            (t, a, *rest), kw = program.input_formats
+            self.input_formats = (
+                (t, Format(COLUMN_MAJOR, a.sharding), *rest), kw)
+            self.output_formats = program.output_formats
+
+    lower = jax.stages.Lowered.compile
+    monkeypatch.setattr(jax.stages.Lowered, "compile",
+                        lambda self, *a, **k: Program(lower(self, *a, **k)))
+    with pytest.raises(RuntimeError, match="different layouts.*'acc'"):
+        step(init_table(cfg), init_accumulator(cfg),
+             **batch_args(batches[0]))
+
+
+@pytest.mark.parametrize("model", MODELS)
+def test_other_readers_take_a_relaid_state_as_they_find_it(tmp_path,
+                                                            model):
+    """Validation scoring, a jitted row gather (the benchmark's probe),
+    and a checkpoint save and restore read a column-major table and
+    accumulator to the same values as the default layout's."""
+    cfg, spec, batches = _setup(tmp_path, model)
+    step = _step(spec, "column_major")
+    table, acc = init_table(cfg, 7), init_accumulator(cfg)
+    for b in batches[:2]:
+        table, acc, _, _ = step(table, acc, **batch_args(b))
+    assert table.format.layout == COLUMN_MAJOR
+    plain_t, plain_a = jnp.array(np.asarray(table)), jnp.array(
+        np.asarray(acc))
+    assert plain_t.format.layout != COLUMN_MAJOR
+
+    def scores(t):
+        args = batch_args(batches[2])
+        del args["labels"], args["weights"]
+        return np.asarray(make_batch_scorer(spec)(t, args))
+    np.testing.assert_array_equal(scores(table), scores(plain_t))
+
+    gather = jax.jit(lambda t, i: t[i])
+    ids = np.array([0, 5, 17, 300, 300], np.int32)
+    np.testing.assert_array_equal(np.asarray(gather(acc, ids)),
+                                  np.asarray(gather(plain_a, ids)))
+
+    ckpt = CheckpointState(cfg.model_file)
+    ckpt.save(2, *ckpt_state(cfg, table, acc),
+              vocabulary_size=cfg.vocabulary_size, wait=True)
+    restored = ckpt.restore(template=checkpoint_template(cfg))
+    ckpt.close()
+    got_t = restored["table"][:cfg.num_rows]
+    got_a = restored["acc"][:cfg.num_rows]
+    np.testing.assert_array_equal(np.asarray(got_t), np.asarray(plain_t))
+    np.testing.assert_array_equal(np.asarray(got_a), np.asarray(plain_a))
+    # the restored slice arrives in the default layout and is re-laid
+    assert got_t.format.layout != COLUMN_MAJOR
+    t3, a3, loss, _ = step(got_t, got_a, **batch_args(batches[3]))
+    want = jax.jit(lambda t, a, **b: train_step_body(spec, t, a, **b))(
+        plain_t, plain_a, **batch_args(batches[3]))
+    assert t3.format.layout == a3.format.layout == COLUMN_MAJOR
+    np.testing.assert_array_equal(np.asarray(t3), np.asarray(want[0]))
+    assert float(loss) == float(want[2])
+
+
+_TRAIN_TWICE = """
+import sys
+import numpy as np
+import jax
+from jax.experimental.layout import Layout
+from fast_tffm_tpu.compile_cache import enable_compilation_cache
+from fast_tffm_tpu.config import FmConfig
+from fast_tffm_tpu.models import fm
+from fast_tffm_tpu.train import train
+assert jax.device_count() == 1
+enable_compilation_cache()
+model, forced, path, out = sys.argv[1:5]
+if forced == "column_major":
+    init = fm.TrainStep.__init__
+    def pinned(self, spec):
+        init(self, spec)
+        self._layout = Layout(major_to_minor=(1, 0), tiling=())
+    fm.TrainStep.__init__ = pinned
+more = dict(model_type="ffm", field_num=3) if model == "ffm" else {}
+def cfg(epochs):
+    return FmConfig(vocabulary_size=300, factor_num=4, batch_size=16,
+                    shuffle=False, bucket_ladder=(4, 8),
+                    max_features_per_example=8, learning_rate=0.1,
+                    train_files=(path,), validation_files=(path,),
+                    epoch_num=epochs, model_file=out + "/m/fm",
+                    metrics_file=out + "/metrics.jsonl",
+                    metrics_flush_steps=1, log_steps=1, **more)
+train(cfg(1))
+table = train(cfg(3))       # restores the first run's save, then saves
+np.save(out + "/table.npy", np.asarray(table))
+"""
+
+
+@pytest.mark.parametrize("model", MODELS)
+def test_train_resumes_from_a_checkpoint_slice_in_either_layout(tmp_path,
+                                                                model):
+    """``train()`` on one device (a subprocess: this process has
+    eight): a run, then a run that restores its checkpoint, validates,
+    saves and exports; with the compiler's layout, with a column-major
+    one pinned, and with that again in a process that finds the first
+    one's programs in the persistent cache (an executable read back
+    from there labels its results with the default layout whatever
+    they have, compile_cache.uncached). Same table to the bit; the
+    stream counts 0 re-lays, or 2 a session; the log names the layout
+    the compiler chose."""
+    from fast_tffm_tpu.obs.sink import read_events
+    path = _corpus(tmp_path, model)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    env.pop("XLA_FLAGS", None)
+    tables = {}
+    for run_name in ("compilers", "column_major", "column_major.warm"):
+        layout = run_name.split(".")[0]
+        out = tmp_path / run_name
+        out.mkdir()
+        run = subprocess.run(
+            [sys.executable, "-c", _TRAIN_TWICE, model, layout, path,
+             str(out)], cwd=REPO, env=env, capture_output=True, text=True,
+            timeout=300)
+        assert run.returncode == 0, run.stdout + run.stderr
+        assert run.stderr.count("restored checkpoint") == 1, run.stderr
+        chosen = run.stderr.count("train state layout: float32[301, ")
+        assert chosen == (1 if layout == "compilers" else 0), run.stderr
+        last = [e for e in read_events(str(out / "metrics.jsonl"))
+                if e.get("event") == "metrics"][-1]
+        assert last["counters"][COUNTER] == (
+            0 if layout == "compilers" else 2)
+        tables[run_name] = np.load(out / "table.npy")
+        assert np.isfinite(tables[run_name]).all()
+    for run_name in ("column_major", "column_major.warm"):
+        np.testing.assert_array_equal(tables["compilers"],
+                                      tables[run_name])
+
+
+# ---- what the TPU's compiler chooses, asked without a chip -----------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# The two one-chip cells of BENCHMARK.json: rows, batch, rung, slots.
+CELLS = {
+    "ffm4-train-zipf": (ModelSpec("ffm", 2, 4, 22, 1 << 23, "logistic",
+                                  0.0, 0.0, 0.01), 8192, 32, 16384),
+    "fm16-train-zipf": (ModelSpec("fm", 2, 16, 0, 1 << 26, "logistic",
+                                  0.0, 0.0, 0.01), 8192, 64, 32768),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_v5e_step_has_no_copy_of_the_whole_state(one_chip, cell):
+    """Compiled for a described v5e at the cell's real size through the
+    step's own ``compile``: no ``copy`` whose result has the table's
+    shape, both state arguments aliased to their results, and for FM's
+    17 columns the layout the runtime gives the state anyway (nothing
+    to re-lay), for FFM's 89 another one. A compile says nothing of
+    times."""
+    from jax.experimental.compilation_cache import compilation_cache
+    spec, B, L, U = CELLS[cell]
+    rows, dim = spec.vocabulary_size + 1, spec.row_dim
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    state = sd((rows, dim), jnp.float32)
+    batch = (sd((B,), jnp.float32), sd((B,), jnp.float32),
+             sd((U,), jnp.int32), sd((B, L), jnp.int32),
+             sd((B, L), jnp.float32),
+             sd((B, L), jnp.int32) if spec.model_type == "ffm" else None)
+    step = TrainStep(spec)
+    # A program compiled for a described chip can be written to the
+    # persistent cache but not read back without one.
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = step.compile(state, state, *batch).as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    copies = re.findall(rf"= f32\[{rows},{dim}\]\S* copy\(", text)
+    assert not copies, copies
+    alias = re.search(r"input_output_alias=\{(.*?)\}, \w+=", text).group(1)
+    assert "{0}: (0, {}" in alias and "{1}: (1, {}" in alias, alias
+    device = one_chip._device_assignment[0]
+    default = Layout.from_pjrt_layout(device.client.get_default_layout(
+        jnp.dtype(jnp.float32), (rows, dim), device))
+    if spec.model_type == "fm":
+        assert step._layout == default
+    else:
+        assert step._layout != default
+        assert step._layout.major_to_minor == (0, 1)    # a row contiguous
