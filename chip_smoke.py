@@ -84,6 +84,12 @@ CHIP_ROWS, REHEARSAL_ROWS = (262144, 32768), (65536, 8192)
 PYTHON_PARSER_MARK = "PYTHON parser"
 INTERPRET_MARK = "INTERPRET mode"
 WARMUP_FAILED_MARK = "serve warmup failed"
+# obs/memory.preflight_capacity's log line: planned bytes, capacity. On
+# a mesh it says "N bytes per device, 1/4 of ... over 4 devices, device
+# capacity ..." (tests/test_bringup.py holds both forms to this).
+PREFLIGHT_LINE = re.compile(
+    r"capacity pre-flight \(train\): predicted resident (\d+) bytes"
+    r"(?: per device, [^\n]*?)?, device capacity (\d+|UNKNOWN)")
 
 PROBE = ("import json, jax; d = jax.devices(); print(json.dumps("
          "{'platform': d[0].platform, 'kind': d[0].device_kind, "
@@ -348,8 +354,7 @@ serve_port = {self.port}
             if dt > 0:
                 out["steps_per_sec_after_first_window"] = round(
                     (lines[-1][0] - lines[1][0]) / dt, 2)
-        m = re.search(r"capacity pre-flight \(train\): predicted resident "
-                      r"(\d+) bytes, device capacity (\d+|UNKNOWN)", text)
+        m = PREFLIGHT_LINE.search(text)
         check(m is not None, f"leg {name} logged no capacity pre-flight")
         out["plan_resident_bytes"] = int(m.group(1))
         if not self.rehearsal:

@@ -1351,6 +1351,79 @@ class CheckpointState:
             self._mngr.close()
 
 
+def ckpt_state(cfg, table: jax.Array, acc: jax.Array):
+    """Checkpoint contract: always store [ckpt_rows, D] — the fixed
+    4096-aligned row layout (FmConfig.ckpt_rows) every topology shares,
+    so a checkpoint saved by any mesh restores row-sharded on any other
+    without assembling the table on one host. Mesh tables are already
+    this shape (orbax saves them sharded — each host writes only its
+    rows); single-device tables get the dead pad tail appended."""
+    n_pad = cfg.ckpt_rows - int(table.shape[0])
+    if n_pad == 0:
+        return table, acc
+    import jax.numpy as jnp
+    pad_t = jnp.zeros((n_pad, cfg.row_dim), jnp.float32)
+    pad_a = jnp.full((n_pad, cfg.row_dim), cfg.adagrad_init, jnp.float32)
+    return (jnp.concatenate([table, pad_t], axis=0),
+            jnp.concatenate([acc, pad_a], axis=0))
+
+
+def checkpoint_template(cfg, mesh=None, host: bool = False):
+    """Abstract pytree matching CheckpointState.save's layout — orbax
+    needs it to restore from a process that didn't do the saving.
+
+    The explicit sharding makes restore topology-portable: orbax places
+    the arrays per THIS run's layout instead of repopulating whatever
+    sharding the saving topology recorded (which, for a multi-host save
+    restored elsewhere, would yield non-addressable arrays).
+
+    ``host`` leaves the leaves sharding-free, which makes orbax restore
+    plain np.ndarrays into host RAM — the offload-backend path, where
+    the table must never land on a device."""
+    shape = (cfg.ckpt_rows, cfg.row_dim)
+    if host:
+        return {"table": jax.ShapeDtypeStruct(shape, np.float32),
+                "acc": jax.ShapeDtypeStruct(shape, np.float32),
+                "step": 0, "epoch": 0, "vocab": 0}
+    if mesh is not None:
+        from jax.sharding import NamedSharding
+        from fast_tffm_tpu.parallel.sharded import ROW_SPEC
+        sh = NamedSharding(mesh, ROW_SPEC)
+    else:
+        sh = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+    return {"table": jax.ShapeDtypeStruct(shape, np.float32, sharding=sh),
+            "acc": jax.ShapeDtypeStruct(shape, np.float32, sharding=sh),
+            "step": 0, "epoch": 0, "vocab": 0}
+
+
+def resume_start_epoch(stored_epoch: int, epoch_num: int) -> int:
+    """Where a restarted run's epoch loop begins.
+
+    An INTERRUPTED schedule (0 < stored < epoch_num) resumes at the
+    first incomplete epoch — restarting from zero would revisit the
+    same data under the same per-epoch seeds and, under preemptions
+    recurring faster than a full schedule, never terminate. A COMPLETED
+    checkpoint (stored >= epoch_num, or a smaller epoch_num configured
+    since) keeps the reference's semantics: invoking train again runs a
+    fresh epoch_num-epoch schedule on top of the restored weights (the
+    reference's TF1 queue epoch counters were process-local and never
+    checkpointed, so it behaved exactly this way)."""
+    return stored_epoch if 0 < stored_epoch < epoch_num else 0
+
+
+def check_restored_vocab(cfg, restored) -> None:
+    """The 4096-aligned storage shape can't distinguish vocabularies in
+    the same bucket, so the stored vocab is verified explicitly — a
+    mismatch would silently turn a trained row into the pad row."""
+    v = int(restored["vocab"])
+    if v != cfg.vocabulary_size:
+        raise ValueError(
+            f"checkpoint was written with vocabulary_size={v}, but this "
+            f"config has vocabulary_size={cfg.vocabulary_size}; restoring "
+            "would misalign the pad row and feature ids. Retrain, or fix "
+            "the config.")
+
+
 def _caused_by(e: BaseException, classes) -> bool:
     """Whether any link of ``e``'s explicit ``raise ... from`` chain is
     one of ``classes``."""

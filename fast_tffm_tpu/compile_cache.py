@@ -1,7 +1,7 @@
 """The one persistent-XLA-compile-cache policy (README "Compile cache").
 
 Every entry point that compiles — ``run_tffm.py``, the fleet's replica
-child, ``bench.py``, ``tools/offload_smoke.py`` — calls
+child, ``benchmarks/``, ``tools/offload_smoke.py`` — calls
 ``enable_compilation_cache`` before its first jit. First compile of the
 train/score programs costs tens of seconds on a TPU; without the cache
 every process pays it again (predict right after train, a restarted

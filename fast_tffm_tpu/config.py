@@ -90,7 +90,7 @@ class FmConfig:
     # this is a pure throughput knob. 0 = auto (min(4, host cores));
     # 1 = the serial pipeline (pre-parallel behavior). Resolved by
     # data/pipeline.resolve_host_threads; distinct from the C++
-    # builder's internal feed parse threads (bench reports both).
+    # builder's internal feed parse threads.
     host_threads: int = 0
     shuffle: bool = True
     seed: int = 0

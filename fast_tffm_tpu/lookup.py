@@ -214,9 +214,9 @@ class HostOffloadLookup:
         ``with_acc=False`` (the predict path) restores the table leaf
         only: inference never touches the Adagrad accumulator, and at
         offload scale materializing it would double peak host RSS."""
-        from fast_tffm_tpu.checkpoint import CheckpointState
-        from fast_tffm_tpu.train import (check_restored_vocab,
-                                         checkpoint_template)
+        from fast_tffm_tpu.checkpoint import (CheckpointState,
+                                              check_restored_vocab,
+                                              checkpoint_template)
         from fast_tffm_tpu.utils.retry import RetryPolicy
         ckpt = CheckpointState(cfg.model_file,
                                retry=RetryPolicy.from_config(cfg),

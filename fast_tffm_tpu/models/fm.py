@@ -148,7 +148,7 @@ def resolved_kernel(spec: ModelSpec, L: int) -> str:
     the bucketed pipeline compiles one executable per (spec, L), so
     each bucket independently gets the kernel the measured matrix says
     wins at its width; ops/kernel_choice.py). Shared by _scores and by
-    bench.py's per-line regime stamp so the stamp can't drift from the
+    the start-up log's regime line so the line can't drift from the
     dispatch."""
     if spec.model_type == "ffm":
         return "xla"  # field-bucketed XLA scorer; no Pallas FFM kernel

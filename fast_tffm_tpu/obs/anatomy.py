@@ -1,8 +1,8 @@
 """Cross-rank step anatomy: the clock-aligned critical-path profiler
 (README "Step anatomy"; ``fmtrace --anatomy`` is the CLI).
 
-``bench.py --multihost`` says a 2-process cluster runs at ~0.2x
-per-worker efficiency; this module says WHERE the other 80% goes. The
+A 2-process cluster runs well under one process's per-worker rate;
+this module says WHERE the rest goes. The
 telemetry stream already records every ingredient — per-rank ``span``
 events (obs/trace.py), per-rank ``collective`` seq events
 (parallel/liveness.py), lockstep counters — but each rank stamps spans
@@ -219,8 +219,8 @@ def build_report(ranks: Dict[int, List[Dict[str, Any]]],
     """The full anatomy report for per-rank event lists (the testable
     core; ``report(paths)`` is the file-reading wrapper).
 
-    ``baseline_eps`` — a single-process examples/sec rate (e.g. the
-    1-worker leg of ``bench.py --multihost``) — unlocks the absolute
+    ``baseline_eps`` — a single-process examples/sec rate (the same
+    job on one worker) — unlocks the absolute
     per-worker efficiency: useful compute time (examples /
     baseline_eps) over wall. Host spans alone cannot see stalls
     INSIDE the dispatched step program (the gradient allreduce runs
@@ -339,7 +339,7 @@ def build_report(ranks: Dict[int, List[Dict[str, Any]]],
             # The dominant time is inside the dispatched XLA program,
             # where the gradient allreduce runs on multi-host — host
             # spans cannot split that stall from compute. A baseline
-            # rate (--baseline-eps / bench --multihost) quantifies it.
+            # rate (--baseline-eps) quantifies it.
             verdict = (
                 f"step dispatch {frac:.0%} of step — the wall is "
                 "inside the dispatched program (in-program gradient "

@@ -1,12 +1,10 @@
 """Attribution analysis over a metrics JSONL stream.
 
-The bench learned this lesson first: a single throughput number that
-moves is undiagnosable until it's broken into host-only / device-only /
-transfer-only ceilings (bench.py's ``host_only``/``device_only``/
-``h2d_only``). This module computes the same style of breakdown from a
-run's (or bench's) JSONL event stream, so a production train/predict
-run is diagnosable with the exact vocabulary the bench artifacts use:
-a host-bound vs device/transfer-bound vs pause-bound verdict.
+A single throughput number that moves is undiagnosable until it is
+broken into host, device and transfer shares. This module computes
+that breakdown from a run's JSONL event stream, so a production
+train/predict run gets a host-bound vs device/transfer-bound vs
+pause-bound verdict.
 
 Pure functions over parsed events — shared by ``tools/fmstat`` (CLI)
 and tests; no jax import.
@@ -21,7 +19,7 @@ from fast_tffm_tpu.obs.sink import read_events
 
 # Verdict thresholds over the train-loop time split. Above HOST_BOUND
 # of loop wall spent waiting on the input pipeline, the host is the
-# bottleneck (the bench's host_only ceiling binding); above PAUSE_BOUND
+# bottleneck; above PAUSE_BOUND
 # in checkpoint/summary pauses, cadence knobs are. Otherwise the time
 # is in dispatched device work + H2D, which host-side timing cannot
 # split further — the verdict says so rather than guessing.
@@ -139,9 +137,7 @@ def wire_mode(gauges: Dict[str, Any]) -> Optional[str]:
 def attribution(summary: Dict[str, Any]) -> Dict[str, Any]:
     """The host/device/transfer split + verdict for one summary.
 
-    Two sources, same table: a bench stream carries explicit ceiling
-    gauges (``bench/host_only`` etc. — measured in isolation); a
-    train/predict stream carries the loop-time split (input wait,
+    A train/predict stream carries the loop-time split (input wait,
     pauses, step time) and the H2D byte rate.
     """
     c = summary.get("counters", {})
@@ -329,15 +325,6 @@ def attribution(summary: Dict[str, Any]) -> Dict[str, Any]:
         out["predict_d2h_share"] = None
         out["predict_write_share"] = None
 
-    # Bench ceilings, when the stream carries them (bench.py emits
-    # these; a production run can be laid side by side with them).
-    ceilings = {k.split("/", 1)[1]: v for k, v in g.items()
-                if k.startswith("bench/")}
-    if ceilings:
-        out["ceilings"] = ceilings
-        out["verdict"] = _bench_verdict(ceilings)
-        return out
-
     iw = out["input_wait_fraction"]
     pf = out["pause_fraction"]
     if loop_s <= 0 and p_ex:
@@ -436,23 +423,6 @@ def _predict_verdict(att: Dict[str, Any]) -> str:
                 f"sits at {p90:.0f} batches (>= the {FETCH_CHUNK_BATCHES}"
                 "-batch fetch chunk), scores wait on D2H")
     return base + " — host/scoring-bound (output-order buffer shallow)"
-
-
-def _bench_verdict(ceil: Dict[str, float]) -> str:
-    e2e = ceil.get("e2e")
-    named = [(k, v) for k, v in ceil.items()
-             if k in ("host_only", "device_only", "h2d_only") and v]
-    if not e2e or not named:
-        return "bench stream without e2e/ceiling gauges"
-    # The binding constraint is the smallest ceiling; whichever ceiling
-    # sits nearest the e2e number names the bottleneck (bench.py's
-    # reading rule).
-    name, v = min(named, key=lambda kv: abs(kv[1] - e2e))
-    label = {"host_only": "host-bound",
-             "device_only": "device-bound",
-             "h2d_only": "transfer-bound"}[name]
-    return (f"{label}: e2e {e2e:,.0f} ex/s tracks the {name} ceiling "
-            f"({v:,.0f} ex/s)")
 
 
 # Every `health: <kind>` event the codebase can emit, by status
@@ -1194,12 +1164,6 @@ def render(summary: Dict[str, Any]) -> str:
         lines.append("  workers (per-process liveness):")
         for row in worker_rows:
             lines.append(f"    {row}")
-    if "ceilings" in att:
-        lines.append("  bench ceilings (examples/sec):")
-        for k in ("e2e", "host_only", "device_only", "h2d_only"):
-            if k in att["ceilings"]:
-                lines.append(f"    {k:<32} "
-                             f"{_fmt(att['ceilings'][k])}")
     lines.append("")
     lines.append(f"verdict: {att['verdict']}")
     return "\n".join(lines)

@@ -24,7 +24,9 @@ from typing import List, Optional
 import jax
 import numpy as np
 
-from fast_tffm_tpu.checkpoint import CheckpointState
+from fast_tffm_tpu.checkpoint import (CheckpointState,
+                                      check_restored_vocab,
+                                      checkpoint_template)
 from fast_tffm_tpu.config import FmConfig
 from fast_tffm_tpu.data.pipeline import expand_files
 from fast_tffm_tpu.metrics import sigmoid
@@ -53,7 +55,6 @@ def load_table(cfg: FmConfig, mesh=None,
     pair the table with its step's sidecars (the admit-mode vocab slot
     map) need to know which step the walk-back actually restored."""
     import jax.numpy as jnp
-    from fast_tffm_tpu.train import checkpoint_template
     from fast_tffm_tpu.utils.retry import RetryPolicy
     ckpt = CheckpointState(cfg.model_file,
                            retry=RetryPolicy.from_config(cfg),
@@ -65,7 +66,6 @@ def load_table(cfg: FmConfig, mesh=None,
         raise FileNotFoundError(
             f"no checkpoint found under {cfg.model_file}.ckpt "
             "(run training first)")
-    from fast_tffm_tpu.train import check_restored_vocab
     check_restored_vocab(cfg, restored)
     loaded_step = int(restored["step"])
     if mesh is not None:

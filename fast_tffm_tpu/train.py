@@ -20,7 +20,10 @@ from typing import Optional, Tuple
 import jax
 import numpy as np
 
-from fast_tffm_tpu.checkpoint import CheckpointState, export_npz
+from fast_tffm_tpu.checkpoint import (CheckpointState,
+                                      check_restored_vocab,
+                                      checkpoint_template, ckpt_state,
+                                      export_npz, resume_start_epoch)
 from fast_tffm_tpu.config import FmConfig
 from fast_tffm_tpu.data.badlines import BadLineTracker
 from fast_tffm_tpu.data.pipeline import (SPILL_WARN_FRACTION, SpillStats,
@@ -576,6 +579,138 @@ def _record_crash(tel, logger, e: BaseException, step: int = -1) -> None:
         logger.exception("crash event emission failed")
 
 
+class _Session:
+    """What one training session is built from and what its parts
+    share: the run-scoped arrivals from the elastic driver (telemetry,
+    the bad-line tracker, this process's place in the membership), the
+    dispatch path this process resolved (mesh, offload, raw ids) and —
+    filled in by ``_restore`` and ``_build_state_and_step`` — the
+    checkpoint, the vocabulary runtime, the compiled step and the wire
+    encoder. The teardown asks this object what exists: every
+    attribute it reads has a value from ``__init__`` on."""
+
+    def __init__(self, cfg: FmConfig, logger, tel, bad_tracker,
+                 shard_index: int, num_shards: int, grow_ctx=None):
+        self.cfg, self.logger, self.tel = cfg, logger, tel
+        # Run telemetry (tel) and the bad-line tracker arrive from the
+        # elastic driver (train()): both are run-scoped — they must
+        # span every session a recovery re-enters, so the driver owns
+        # their lifecycle and this session only feeds them.
+        self.bad_tracker = bad_tracker
+        self.shard_index, self.num_shards = shard_index, num_shards
+        self.grow_ctx = grow_ctx
+        self.ckpt = self.summaries = None
+        self.prev_handlers: dict = {}
+        self.preempted: list = []   # signal numbers, in arrival order
+        # Filled in by _restore ...
+        self.vocab = self.restored = None
+        self.restored_step = self.restored_epoch = self.start_epoch = 0
+        self.uniq_bucket = self.val_bucket = 0
+        self.vocab_fresh_over_restore = False
+        # ... by _build_state_and_step ...
+        self.lk = self.step_fn = self.packed_step = self.wire_enc = None
+        # ... and by _arm_publish_gate.
+        self.gate = None
+        self.quality_on = False
+        self.spec = ModelSpec.from_config(cfg, training=True)
+        logger.info("train regime: %s", regime_line(self.spec, cfg))
+        self.multi_process = jax.process_count() > 1
+        self.stream_mode = getattr(cfg, "run_mode", "epochs") == "stream"
+        self.offload = cfg.lookup == "host"
+        if self.offload and self.multi_process:
+            # Design position, not a gap: any multi-host v5e job has
+            # >= 8 chips, whose aggregate HBM covers config #5's 72 GB
+            # state row-sharded; a cross-process host-RAM table would
+            # re-implement the mesh with a slower transport.
+            raise ValueError(
+                "lookup = host is single-process by design: multi-host "
+                "scale uses the row-sharded mesh (lookup = device), "
+                "whose aggregate HBM holds a table no single chip can")
+        self.mesh = None
+        if jax.device_count() > 1 and not self.offload:
+            # More than one device (one host of a TPU slice, or the
+            # whole jax.distributed job): row-shard the table over the
+            # global mesh and data-shard the batch
+            # (parallel/sharded.py). One device: the plain jitted
+            # step, no mesh machinery.
+            from fast_tffm_tpu.parallel.sharded import make_mesh
+            self.mesh = make_mesh()
+        # Pre-flight capacity check (obs/memory.py), here because only
+        # the session knows its devices (a cluster has joined by now):
+        # when the backend reports a device capacity, a config whose
+        # PREDICTED resident bytes per device — table and accumulator
+        # divided over the mesh just built — exceed it is refused with
+        # the planner's per-owner breakdown, not minutes later as a
+        # raw XLA OOM. No-op when capacity is unmeasured (the CPU
+        # container).
+        self.mesh_devices = (int(self.mesh.devices.size)
+                             if self.mesh is not None else 1)
+        preflight_capacity(cfg, "train", shards=self.mesh_devices)
+        if tel is not None:
+            # Set once: a reader of the stream can tell a mesh run (and
+            # over how many devices its rows lie) from a one-device run.
+            tel.set("train/mesh_devices", float(self.mesh_devices))
+        if self.multi_process:
+            from fast_tffm_tpu.data.pipeline import (
+                require_bounded_examples)
+            require_bounded_examples(cfg, "multi-process training")
+        self.raw_mode = self.spec.dedup == "device"
+        if self.raw_mode and (self.mesh is not None
+                              or self.multi_process):
+            # Unreachable via dedup=auto (it resolves to host whenever
+            # more than one device exists); an explicit config gets a
+            # clear error.
+            raise ValueError(
+                "dedup = device is single-device only: mesh and "
+                "multi-process paths rely on the host-side unique "
+                "contract (fixed-U buckets, global_batch local_idx "
+                "offsets)")
+
+    def install_signal_handlers(self) -> None:
+        """Preemption handling (SURVEY §5 "Failure detection": the
+        reference only recovers via restart+restore; we additionally
+        save on the way down). SIGTERM/SIGINT sets a flag the loop
+        drains at the next step boundary — in multi-process mode the
+        flag rides the lockstep allgather so every process saves/exits
+        together even when only one received the signal. The handlers
+        stay installed (absorbing re-signals) until the session's
+        finally — i.e. until the final checkpoint/export is safely on
+        disk, the window a second SIGTERM is most likely to arrive in.
+        The finally also covers exceptions, so a failed in-process
+        train() can't leave the surviving process (pytest, REPL,
+        server) with SIGTERM/SIGINT swallowed into a dead flag list."""
+        for sig in (signal.SIGTERM, signal.SIGINT):
+            try:
+                self.prev_handlers[sig] = signal.signal(
+                    sig, lambda s, f: self.preempted.append(s))
+            except ValueError:  # not the main thread (e.g. under a test)
+                pass
+
+    def validate(self, table, collect=None, preempt=None):
+        """One validation sweep of ``table`` on this session's dispatch
+        path; returns ``(auc, n_examples)``. ``preempt`` rides the
+        lockstep window allgather of a multi-process sweep: a SIGTERM
+        mid-sweep stops EVERY worker at the same window boundary (the
+        signalled worker alone bailing would desync the collective
+        program stream)."""
+        cfg = self.cfg
+        vmb = cfg.validation_max_batches or None
+        if self.multi_process:
+            return evaluate_distributed(
+                cfg, table, cfg.validation_files, self.mesh,
+                self.shard_index, self.num_shards,
+                uniq_bucket=self.val_bucket, max_batches=vmb,
+                weight_files=cfg.validation_weight_files,
+                bad_lines=self.bad_tracker, collect=collect,
+                preempt=preempt)
+        return evaluate(
+            cfg, table, cfg.validation_files, mesh=self.mesh,
+            backend=self.lk, max_batches=vmb,
+            weight_files=cfg.validation_weight_files,
+            bad_lines=self.bad_tracker, vocab=self.vocab,
+            collect=collect)
+
+
 def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                    shard_index: int, num_shards: int,
                    grow_ctx=None) -> jax.Array:
@@ -588,388 +723,22 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
     first, so the newcomer restores exactly this point). Everything
     created here (checkpoint manager, summaries, signal handlers,
     profiler) is torn down here, so the driver can safely re-enter
-    after a recovery."""
-    spec = ModelSpec.from_config(cfg, training=True)
-    logger.info("train regime: %s", regime_line(spec, cfg))
-    multi_process = jax.process_count() > 1
-    stream_mode = getattr(cfg, "run_mode", "epochs") == "stream"
-    offload = cfg.lookup == "host"
-    if offload and multi_process:
-        # Design position, not a gap: any multi-host v5e job has >= 8
-        # chips, whose aggregate HBM covers config #5's 72 GB state
-        # row-sharded; a cross-process host-RAM table would
-        # re-implement the mesh with a slower transport.
-        raise ValueError(
-            "lookup = host is single-process by design: multi-host scale "
-            "uses the row-sharded mesh (lookup = device), whose "
-            "aggregate HBM holds a table no single chip can")
-    mesh = None
-    if jax.device_count() > 1 and not offload:
-        # More than one device (one host of a TPU slice, or the whole
-        # jax.distributed job): row-shard the table over the global mesh
-        # and data-shard the batch (parallel/sharded.py). One device:
-        # the plain jitted step, no mesh machinery.
-        from fast_tffm_tpu.parallel.sharded import (
-            global_batch, init_sharded_state, make_mesh,
-            make_sharded_train_step, shard_batch)
-        mesh = make_mesh()
-    # Pre-flight capacity check (obs/memory.py), here because only the
-    # session knows its devices (a cluster has joined by now): when
-    # the backend reports a device capacity, a config whose PREDICTED
-    # resident bytes per device — table and accumulator divided over
-    # the mesh just built — exceed it is refused with the planner's
-    # per-owner breakdown, not minutes later as a raw XLA OOM. No-op
-    # when capacity is unmeasured (the CPU container).
-    mesh_devices = int(mesh.devices.size) if mesh is not None else 1
-    preflight_capacity(cfg, "train", shards=mesh_devices)
-    if tel is not None:
-        # Set once: a reader of the stream can tell a mesh run (and
-        # over how many devices its rows lie) from a one-device run.
-        tel.set("train/mesh_devices", float(mesh_devices))
+    after a recovery.
 
-    if multi_process:
-        from fast_tffm_tpu.data.pipeline import require_bounded_examples
-        require_bounded_examples(cfg, "multi-process training")
-    raw_mode = spec.dedup == "device"
-    if raw_mode and (mesh is not None or multi_process):
-        # Unreachable via dedup=auto (it resolves to host whenever more
-        # than one device exists); an explicit config gets a clear error.
-        raise ValueError(
-            "dedup = device is single-device only: mesh and multi-process "
-            "paths rely on the host-side unique contract (fixed-U "
-            "buckets, global_batch local_idx offsets)")
-
-    # Run telemetry (tel) and the bad-line tracker arrive from the
-    # elastic driver (train()): both are run-scoped — they must span
-    # every session a recovery re-enters, so the driver owns their
-    # lifecycle and this session only feeds them.
-    # Names the finally below reads; they must exist even when setup
-    # raises before reaching their real definitions.
-    summaries = None
-    profiling = False
-    # Phases held open across loop iterations (obs/trace.begin); the
-    # finally ends whatever an exception left open.
-    starting = barrier = None
-    prev_handlers = {}
-    global_step = 0
-    ckpt = None
-
-    def flush_log():  # rebound once the deferred log buffer exists
-        pass
-
+    The parts, in the order they run: ``_Session`` (what was
+    resolved), ``_restore`` and ``_build_state_and_step`` (checkpoint,
+    state, compiled step, wire), ``StepLoop`` (the one step body and
+    the state it advances), ``_run_epochs`` or ``_run_stream`` (what a
+    mode does around a step), ``_finish`` (final save, exit publish,
+    validation, export)."""
+    s = _Session(cfg, logger, tel, bad_tracker, shard_index, num_shards,
+                 grow_ctx)
+    loop = None
     worker_lost = False
     try:
-        # Visibility only — the plane lives inside batch_iterator.
-        # host_parallel_workers is the SAME predicate the routing
-        # uses, so this log never claims a fan-out the pipeline won't
-        # perform for THIS run's inputs (C++ missing, weight sidecars,
-        # tolerant fixed-shape all route serial).
-        host_workers = host_parallel_workers(
-            cfg, cfg.weight_files, fixed_shape=multi_process)
-        if host_workers > 1 and not stream_mode:
-            logger.info(
-                "host data plane: %d parallel batch-build workers "
-                "(host_threads = %s; bounded ordered ring)",
-                host_workers, cfg.host_threads)
-        uniq_bucket = 0
-        if multi_process and not stream_mode:
-            # Fixed-shape batches need one U for the whole job. Auto mode
-            # measures the data (probe is deterministic and identical on
-            # every process) instead of assuming the next_pow2(B*L) worst
-            # case — a ~50x smaller gather/scatter per step at Criteo-like
-            # density; denser-than-probed batches spill, never break.
-            # (Stream mode probes the discovered SEALED shards instead,
-            # chief-decided — data/stream.probe_stream_uniq_bucket.)
-            from fast_tffm_tpu.data.pipeline import probe_uniq_bucket
-            uniq_bucket = cfg.uniq_bucket or probe_uniq_bucket(
-                cfg, cfg.train_files)
-            logger.info("fixed unique-row bucket: %d", uniq_bucket)
-        val_bucket = 0
-        if multi_process and cfg.validation_files:
-            val_bucket = cfg.uniq_bucket or probe_uniq_bucket(
-                cfg, cfg.validation_files)
-
-        # Vocabulary admission (README "Unbounded vocabulary";
-        # fast_tffm_tpu/vocab/): the runtime owns the sketch + slot
-        # map; the data plane builds batches in the hashed space and
-        # remaps through it; barriers run at the existing epoch/
-        # publish synchronization points below.
-        vocab = None
-        if getattr(cfg, "vocab_mode", "fixed") == "admit":
-            if multi_process:
-                raise ValueError(
-                    "vocab_mode = admit is single-process: the slot "
-                    "map is host state, and lockstep workers would "
-                    "need a chief-broadcast admission protocol to "
-                    "agree on it (ROADMAP item 3's sharded-table "
-                    "leg). Run admit-mode training on one process.")
-            from fast_tffm_tpu.vocab.table import VocabRuntime
-            vocab = VocabRuntime.from_config(cfg)
-            logger.info(
-                "vocab admission: %d physical rows (row 0 = shared "
-                "cold row) over a 2^30 hashed id space; admit/evict "
-                "threshold %.1f, decay %.2f/barrier, sketch %.1f MB",
-                cfg.vocabulary_size, cfg.vocab_admit_threshold,
-                cfg.vocab_decay, cfg.vocab_sketch_mb)
-
-        ckpt = CheckpointState(cfg.model_file,
-                               retry=RetryPolicy.from_config(cfg),
-                               verify=getattr(cfg, "ckpt_verify", "size"))
-        global_step = 0
-        restored = ckpt.restore(
-            template=checkpoint_template(cfg, mesh, host=offload))
-        restored_epoch = 0
-        if restored is not None:
-            check_restored_vocab(cfg, restored)
-            global_step = int(restored["step"])
-            restored_epoch = int(restored["epoch"])
-            logger.info("restored checkpoint at step %d", global_step)
-        vocab_fresh_over_restore = False
-        if vocab is not None and restored is not None:
-            payload = restored.get("vocab_admission")
-            if payload is None:
-                logger.warning(
-                    "restored checkpoint at step %d carries no vocab "
-                    "admission sidecar (a fixed-mode warm start, or a "
-                    "lost/garbled sidecar): admission state starts "
-                    "FRESH — previously admitted ids serve from the "
-                    "cold row until they re-cross the threshold",
-                    global_step)
-                # The restored table still holds the LOST mapping's
-                # trained rows; fresh admission must not hand them to
-                # new owners (see the cold-start reset below, once the
-                # table is materialized).
-                vocab_fresh_over_restore = True
-            else:
-                vocab.load(cfg, payload)
-                logger.info(
-                    "restored vocab admission state at step %d: %d "
-                    "live rows", global_step, vocab.live_rows)
-        elif restored is not None:
-            from fast_tffm_tpu.checkpoint import (
-                refuse_fixed_mode_admit_step)
-            refuse_fixed_mode_admit_step(
-                cfg, ckpt.directory, global_step,
-                payload=restored.get("vocab_admission"))
-        restored_step = global_step
-        start_epoch = resume_start_epoch(restored_epoch, cfg.epoch_num)
-        if start_epoch:
-            logger.info("resuming interrupted epoch schedule at epoch %d/%d",
-                        start_epoch, cfg.epoch_num)
-        lk = None
-        if offload:
-            # Offload backend (lookup.py; BASELINE config #5): the table/
-            # accumulator live outside HBM. make_offload_backend picks the
-            # in-jit pinned-host implementation (whole step stays in the
-            # async dispatch stream) where the backend compiles it, else the
-            # numpy fallback with its inherent per-step gradient fetch.
-            from fast_tffm_tpu.lookup import (PinnedHostLookup,
-                                              make_offload_backend,
-                                              make_offload_train_step)
-            lk = make_offload_backend(cfg, cfg.seed, restored=restored)
-            if restored is not None:
-                # The backend adopted the arrays (numpy backend: zero-copy)
-                # or copied them into accelerator-host memory (pinned
-                # backend); keeping these references for the rest of
-                # train() would pin a SECOND full table+accumulator in
-                # local RAM for the whole resumed run — a sustained 2x that
-                # is an OOM at config-#5 scale (the same concern
-                # HostOffloadLookup.load documents for transient copies).
-                restored["table"] = restored["acc"] = None
-            kind = (f"pinned-host in-jit ({lk.mode})"
-                    if isinstance(lk, PinnedHostLookup) else "host-numpy")
-            logger.info("offload lookup [%s]: table [%d, %d] outside HBM "
-                        "(%.2f GB + accumulator)", kind, lk.rows, lk.dim,
-                        lk.rows * lk.dim * 4 / 2**30)
-            offload_step = make_offload_train_step(spec, lk,
-                                                   cfg.learning_rate)
-            table = acc = None
-
-            def step_fn(_t, _a, labels, weights, uniq_ids, local_idx, vals,
-                        fields=None):
-                loss, scores = offload_step(labels, weights, uniq_ids,
-                                            local_idx, vals, fields)
-                return None, None, loss, scores
-        elif mesh is not None:
-            if restored is not None:
-                # The sharded template already placed these row-sharded on
-                # this mesh in the runtime [ckpt_rows, D] layout — use as-is.
-                table, acc = restored["table"], restored["acc"]
-            else:
-                table, acc = init_sharded_state(cfg, mesh, cfg.seed)
-            step_fn = make_sharded_train_step(spec, mesh)
-            # Logged once the state exists, so the line can say where
-            # it landed: a row-sharded table shows near-equal bytes on
-            # every local device, one that fell onto the first chip
-            # does not (chip_smoke.py fails past 1.5x).
-            jax.block_until_ready((table, acc))
-            logger.info(
-                "mesh training: %s over %d devices, %d processes; "
-                "bytes in use per local device: %s",
-                dict(mesh.shape), jax.device_count(),
-                jax.process_count(), local_bytes_in_use() or "unmeasured")
-        else:
-            if restored is not None:
-                table = restored["table"][:cfg.num_rows]
-                acc = restored["acc"][:cfg.num_rows]
-                # The slices above are NEW device buffers; drop the full
-                # [ckpt_rows, D] restored arrays so they free once the
-                # slice completes — holding them for the whole run is a
-                # sustained ~2x HBM cost that only bites on resume.
-                restored["table"] = restored["acc"] = None
-            else:
-                table = init_table(cfg, cfg.seed)
-                acc = init_accumulator(cfg)
-            step_fn = make_train_step(spec)
-
-        # Ownership ledger (obs/memory.py; README "Memory
-        # observability"): the session's long-lived allocations
-        # register with their owner tag so every flush carries mem/*
-        # gauges and an OOM names which owner grew. .nbytes is host
-        # metadata — no fetch. Offload state is host-resident by
-        # construction (host=True: gauged, excluded from the device
-        # live total). Released in this session's finally.
-        if offload:
-            LEDGER.register("offload_table",
-                            table_bytes(rows=lk.rows, dim=lk.dim),
-                            host=True)
-            LEDGER.register("offload_acc",
-                            table_bytes(rows=lk.rows, dim=lk.dim),
-                            host=True)
-        else:
-            # One device's share: the ledger's live total stands beside
-            # ONE device's capacity (pressure alarm, mem/utilization).
-            LEDGER.register("table", table.nbytes // mesh_devices)
-            LEDGER.register("adagrad_acc", acc.nbytes // mesh_devices)
-
-        # Wire format (README "Wire format"; wire.py): resolve the
-        # knobs for THIS dispatch path, build the one encoder every
-        # step ships through, and pre-build the packed step when
-        # active. Staging (the explicit async device_put double
-        # buffer) applies on the plain single-device jit path only —
-        # mesh/lockstep placement and the offload host gather have
-        # their own protocols.
-        from fast_tffm_tpu.wire import WireEncoder, resolve_wire
-        wire_spec = resolve_wire(cfg, mesh=mesh, backend=lk,
-                                 multi_process=multi_process, train=True)
-        wire_enc = WireEncoder(wire_spec, pad_id=cfg.pad_id)
-        packed_step = None
-        if wire_spec.packed:
-            from fast_tffm_tpu.models.fm import make_packed_train_step
-            packed_step = make_packed_train_step(spec)
-            logger.info(
-                "wire format: %s (flat CSR + on-device unpack, "
-                "double-buffered H2D)", wire_spec.describe())
-        if tel is not None:
-            # The active wire mode, as gauges — fmstat's transfer-bound
-            # attribution names it beside the bytes-per-example row.
-            tel.set("wire/packed", 1.0 if wire_spec.packed else 0.0)
-            tel.set("wire/narrow", 1.0 if wire_spec.narrow else 0.0)
-            # What only a sync point or an epoch barrier feeds starts
-            # at 0: a reader that differences two snapshots of the
-            # stream must find "none yet" as 0, not as absent.
-            # So does what only a re-laid state feeds (models/fm.py,
-            # TrainStep): an FM run's answer is 0, not silence.
-            for name in ("train/epochs", "train/epoch_barrier_seconds",
-                         "train/loss_sync_seconds",
-                         "train/state_relayouts"):
-                tel.count(name, 0)
-
-        # Step-anatomy join keys (obs/anatomy.py; README "Step
-        # anatomy"): when on, the loops stamp the step id into the
-        # h2d/step/flags spans (so fmtrace --anatomy can join phases
-        # across ranks) and feed the host-side phase-seconds counters
-        # the anatomy/* gauges aggregate at barrier flushes.
-        anat = tel is not None and getattr(tel, "anatomy", False)
-
-        def _place(batch, wb):
-            """This dispatch path's host-to-device placement of one
-            encoded batch (the offload step takes host arrays and
-            never gets here)."""
-            if multi_process:
-                # The global-array assembly ships every shard's bytes.
-                return global_batch(mesh, len(batch.uniq_ids), **wb.args)
-            if mesh is not None:
-                return shard_batch(mesh, **wb.args)
-            # Plain single-device jit, depth-2 double buffer: the
-            # explicit async put rides the copy stream while the
-            # PREVIOUS step is still executing, instead of serializing
-            # at the head of this step's dispatch.
-            return wire_enc.device_put(wb)
-
-        def _wire_place(batch, step=0):
-            """Encode one batch and place its arrays for dispatch —
-            the ONE body both run-mode loops share (a drifted copy
-            here is how the two modes' h2d accounting or placement
-            would silently diverge). h2d_bytes = wb.wire_bytes sizes
-            the arrays ACTUALLY shipped; the padded-layout size rides
-            on wb.logical_bytes for the savings counter. ``step``
-            (anatomy on) rides the h2d span as the cross-rank join
-            key."""
-            with span("train/encode", seconds="train/encode_seconds"):
-                wb = wire_enc.encode_train(batch)
-            if offload:
-                return wb, wb.args
-            ids = {"step": step} if (anat and step) else {}
-            with span("train/h2d", seconds="train/h2d_seconds",
-                      bytes=wb.wire_bytes, **ids):
-                return wb, _place(batch, wb)
-
-        def _wire_step(wb, args, table, acc, step):
-            """Dispatch one placed batch through the right compiled
-            step (shared by both loops, like _wire_place), as the
-            ``train/step`` phase: jax dispatch is async (returns at
-            enqueue), so time spent HERE is queue backpressure — the
-            previous program still executing somewhere. Runs under
-            oom_guard: a RESOURCE_EXHAUSTED here re-raises with the
-            per-owner ledger attached (obs/memory.py). A live loss
-            line still owed (log_tick) is synced first."""
-            sync_live_line()
-            with span("train/step", seconds="train/dispatch_seconds",
-                      step=step):
-                with oom_guard("train/step"):
-                    return _wire_step_inner(wb, args, table, acc)
-
-        def _wire_step_inner(wb, args, table, acc):
-            if multi_process:
-                # The sharded step IS a collective program: on a dead
-                # cluster its dispatch blocks inside the program's
-                # collectives exactly like a host allgather (pinned by
-                # the hang-worker chaos stack dumps), so it runs under
-                # the same deadline guard.
-                from fast_tffm_tpu.parallel.liveness import (
-                    guarded_collective)
-                return guarded_collective(
-                    step_fn, table, acc,
-                    label="train/step_dispatch", **args)
-            if wb.packed:
-                return packed_step(wb.L, table, acc, **args)
-            return step_fn(table, acc, **args)
-
-        def _vocab_reset(rows):
-            """The eviction hook: cold-start freed rows through the
-            backend's half of the slot seam (lookup.reset_rows for
-            offload state, the fixed-width compiled scatter for
-            device/mesh state — either way no per-count recompiles)."""
-            nonlocal table, acc
-            if offload:
-                lk.reset_rows(rows, cfg.adagrad_init)
-            else:
-                from fast_tffm_tpu.vocab.table import reset_table_rows
-                table, acc = reset_table_rows(table, acc, rows,
-                                              cfg.pad_id,
-                                              cfg.adagrad_init)
-
-        def _vocab_barrier(where: str) -> None:
-            if vocab is None:
-                return
-            st = vocab.barrier(_vocab_reset)
-            logger.info(
-                "vocab barrier (%s): +%d admitted, -%d evicted, %d/%d "
-                "live rows", where, st["admitted"], st["evicted"],
-                st["live"], cfg.vocabulary_size - 1)
-
-        if vocab_fresh_over_restore:
+        _restore(s)
+        table, acc = _build_state_and_step(s)
+        if s.vocab_fresh_over_restore:
             # Fresh admission over a restored table: every row —
             # including row 0, which becomes the shared COLD row but
             # held a fixed-mode mapping's trained embedding — still
@@ -977,1177 +746,32 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
             # so neither the communal tail nor a newly admitted id
             # ever trains through another id's vector (the documented
             # row-owner invariant).
-            _vocab_reset(np.arange(0, cfg.vocabulary_size,
-                                   dtype=np.int32))
+            table, acc = _reset_rows(
+                s, table, acc,
+                np.arange(0, cfg.vocabulary_size, dtype=np.int32))
             logger.info(
                 "cold-started %d table rows for the fresh admission "
                 "state", cfg.vocabulary_size)
-
-        # Per-publish quality loop + publish gate (README "SLOs &
-        # quality gate"; obs/quality.py). When a stream run has a
-        # validation corpus, every publish settle runs one validation
-        # sweep — AUC/loss/calibration gauges ride the sweep's own
-        # score fetches (zero added device traffic) — and the
-        # configured gate decides whether the `published` pointer may
-        # move. The helper is session-scoped (not inside _run_stream)
-        # because the EXIT publish after the final save is gated too.
-        from fast_tffm_tpu.obs.quality import PublishGate
-        gate = PublishGate.from_config(cfg) if stream_mode else None
-        # "auto" opts in exactly when the run declared a quality
-        # objective (a gate knob, or slo_min_auc) — an existing stream
-        # config with validation_files must not silently start paying
-        # a validation sweep per publish on upgrade.
-        _qmode = getattr(cfg, "publish_quality_eval", "auto")
-        quality_on = (stream_mode and bool(cfg.validation_files)
-                      and float(getattr(cfg, "publish_interval_seconds",
-                                        0.0)) > 0
-                      and (_qmode == "on"
-                           or (_qmode == "auto"
-                               and (gate is not None
-                                    or getattr(cfg, "slo_min_auc",
-                                               0.0) > 0))))
-        if gate is not None:
-            # The drop baseline survives restarts beside the pointer
-            # (checkpoint.GATE_BASELINE): a preempt-resume must not
-            # exempt its first publish from publish_max_auc_drop.
-            from fast_tffm_tpu.checkpoint import read_gate_baseline
-            gate.note_published(read_gate_baseline(ckpt.directory))
-            logger.info(
-                "publish gate armed: min AUC %s, max AUC drop %s%s "
-                "(validation sweep at every publish settle)",
-                cfg.publish_min_auc or "off",
-                cfg.publish_max_auc_drop or "off",
-                "" if gate.baseline is None
-                else f", restored baseline {gate.baseline:.6f}")
-
-        def _gate_published(decision) -> None:
-            """Advance (and persist) the drop baseline after a publish
-            actually landed — the one baseline-write path for both the
-            interval publishes and the exit publish."""
-            if gate is None or decision is None:
-                return
-            gate.note_published(decision.get("auc"))
-            if gate.baseline is not None and jax.process_index() == 0:
-                from fast_tffm_tpu.checkpoint import write_gate_baseline
-                write_gate_baseline(ckpt.directory, gate.baseline)
-
-        def _publish_decision() -> Optional[dict]:
-            """Quality sweep + gate decision for the publish about to
-            happen; None when no quality loop is configured (publish
-            unconditionally). Rides the publish settle point the
-            caller already synchronized at; multi-host safe: the sweep
-            merge is collective and the chief's decision is broadcast
-            (obs/quality.PublishGate docstring), so all workers skip
-            or run the save/publish below together."""
-            if not quality_on:
-                return None
-            from fast_tffm_tpu.obs.quality import (QualityStats,
-                                                   emit_gate_held,
-                                                   emit_quality)
-            stats = QualityStats(cfg.loss_type)
-            vmb = cfg.validation_max_batches or None
-            # Chief-only counter, like emit_quality below: per-worker
-            # shard counters merge by SUM in fmstat.
-            with span("quality/eval", leaf=False, step=global_step,
-                      seconds=("quality/eval_seconds"
-                               if jax.process_index() == 0 else None)):
-                if multi_process:
-                    # preempt rides the lockstep window allgather like
-                    # every other multi-process sweep: a SIGTERM mid-
-                    # sweep stops ALL workers at the same window
-                    # boundary instead of finishing the full
-                    # validation pass inside the kill grace window.
-                    auc, n = evaluate_distributed(
-                        cfg, table, cfg.validation_files, mesh,
-                        shard_index, num_shards,
-                        uniq_bucket=val_bucket, max_batches=vmb,
-                        weight_files=cfg.validation_weight_files,
-                        bad_lines=bad_tracker, collect=stats,
-                        preempt=lambda: bool(preempted))
-                else:
-                    auc, n = evaluate(
-                        cfg, table, cfg.validation_files, mesh=mesh,
-                        backend=lk, max_batches=vmb,
-                        weight_files=cfg.validation_weight_files,
-                        bad_lines=bad_tracker, vocab=vocab,
-                        collect=stats)
-            if jax.process_index() == 0:
-                # Chief-only: n and the merged stats are already
-                # job-global, and per-worker shard counters merge by
-                # SUM in fmstat — every worker emitting would inflate
-                # quality/evals and quality/examples by P.
-                emit_quality(tel, global_step, float(auc), stats, n)
-            if tel is not None:
-                tel.heartbeat()  # a long sweep is progress, not a stall
-            if jax.process_index() == 0:
-                logger.info(
-                    "publish quality eval at step %d: AUC %.6f, loss "
-                    "%s, calibration %s over %d examples",
-                    global_step, auc,
-                    "-" if stats.loss is None
-                    else f"{stats.loss:.6f}",
-                    "-" if stats.calibration is None
-                    else f"{stats.calibration:.4f}", n)
-            if gate is None:
-                return {"held": False, "auc": float(auc),
-                        "examples": int(n)}
-            # Chief decides, broadcast: identity single-process; every
-            # worker applies the byte-identical decision.
-            from fast_tffm_tpu.data.stream import broadcast_blob
-            decision = broadcast_blob(
-                gate.decide(float(auc), global_step),
-                "quality/gate_decision")
-            # n is already job-global (the sweep merge), so adding it
-            # after the broadcast stays identical on every worker.
-            decision["examples"] = int(n)
-            if decision["held"]:
-                if jax.process_index() == 0:
-                    # Chief-only, like emit_quality: one hold must
-                    # count once, not once per worker shard.
-                    emit_gate_held(tel, decision)
-                logger.warning(
-                    "publish GATE HELD at step %d: %s — the published "
-                    "pointer stays on the last passing step",
-                    global_step, "; ".join(decision["reasons"]))
-            return decision
-
-        # Preemption handling (SURVEY §5 "Failure detection": the reference
-        # only recovers via restart+restore; we additionally save on the way
-        # down). SIGTERM/SIGINT sets a flag the loop drains at the next step
-        # boundary — in multi-process mode the flag rides the lockstep
-        # allgather so every process saves/exits together even when only one
-        # received the signal.
-        preempted: list = []
-        for sig in (signal.SIGTERM, signal.SIGINT):
-            try:
-                prev_handlers[sig] = signal.signal(
-                    sig, lambda s, f: preempted.append(s))
-            except ValueError:  # not the main thread (e.g. under a test)
-                pass
-
-        run_start_step = global_step  # profile window counts THIS run's steps
-        # (a resumed job would otherwise skip past the window silently)
-
-        def profile_tick(step_done: int) -> None:
-            nonlocal profiling
-            if not cfg.profile_dir or jax.process_index() != 0:
-                return
-            step_done -= run_start_step
-            if (not profiling and step_done >= cfg.profile_start_step
-                    and step_done < cfg.profile_start_step
-                    + cfg.profile_num_steps):
-                jax.profiler.start_trace(cfg.profile_dir)
-                profiling = True
-            elif profiling and step_done >= (cfg.profile_start_step
-                                             + cfg.profile_num_steps):
-                if table is not None:
-                    jax.block_until_ready(table)
-                jax.profiler.stop_trace()
-                profiling = False
-                logger.info("profiler trace written to %s", cfg.profile_dir)
-
-        timer = StepTimer()
-        loss = None
-        loss_val = float("nan")
-        stopping = False
-        last_val = None  # (auc, n) of the most recent validation pass
-
-
+        _arm_publish_gate(s)
+        s.install_signal_handlers()
+        loop = StepLoop(s, table, acc)
+        del table, acc  # the loop owns them (donated at every step)
         # TensorBoard scalars (save_summaries_steps; utils/summaries.py).
-        # Chief-only, and flushed ONLY at epoch barriers: values buffer as
-        # device scalars so the cadence adds zero mid-stream fetches.
+        # Chief-only, and flushed ONLY at epoch barriers: values buffer
+        # as device scalars so the cadence adds zero mid-stream fetches.
         if cfg.save_summaries_steps and jax.process_index() == 0:
             from fast_tffm_tpu.utils.summaries import make_summaries
-            summaries = make_summaries(cfg)
-            if summaries is not None:
+            s.summaries = make_summaries(cfg)
+            if s.summaries is not None:
                 logger.info("writing TensorBoard summaries every %d steps "
                             "to %s", cfg.save_summaries_steps,
-                            summaries.logdir)
-
-        # Adaptive loss logging. float(loss) is a synchronous device->host
-        # fetch: a mid-stream scalar fetch stalls async dispatch until
-        # the device has caught up. On a direct-attached device the
-        # fetch itself costs microseconds; over a slow or proxied link
-        # it can cost seconds (copy_to_host_async is no better). So the
-        # first log step measures the fetch once: if
-        # it is cheap, logging stays live (the normal-hardware behavior);
-        # if not, loss values are buffered ON DEVICE (scalars) and flushed
-        # at epoch boundaries — a natural barrier — with correct per-step
-        # attribution.
-        # Probe the link BEFORE the hot loop, with an empty dispatch queue:
-        # a mid-stream probe on a slow link drains the queue through
-        # the slow path, where this costs one clean round-trip.
-        def _probe_link() -> str:
-            import time as _time
-            if cfg.log_steps <= 0:
-                return "deferred"  # mode never consulted without log lines
-            # fmlint: disable=R013 -- a one-scalar link-latency probe,
-            # not a batch: the wire encoder has nothing to encode here
-            probe = jax.device_put(np.float32(0.0))
-            jax.block_until_ready(probe)
-            float(probe)  # throwaway: lazy transfer-path init stays untimed
-            cost = float("inf")
-            for _ in range(3):  # min of 3: jitter must not misclassify
-                # fmlint: disable=R003 -- this IS the link probe's
-                # deliberate timer, before the hot loop starts
-                t0 = _time.perf_counter()
-                # fmlint: disable=R001 -- this IS the link probe: one
-                # deliberate timed scalar fetch, before the hot loop starts
-                float(probe)
-                # fmlint: disable=R003 -- closes the probe sample
-                cost = min(cost, _time.perf_counter() - t0)
-            if cost < LIVE_FETCH_BUDGET_S:
-                # Log the decision either way: a user wondering why loss
-                # lines are (or aren't) live gets the probe's answer.
-                logger.info("scalar fetch costs %.3f ms on this device link; "
-                            "loss log lines stay live", cost * 1e3)
-                return "live"
-            logger.info(
-                "scalar fetch costs %.0f ms on this device link; deferring "
-                "loss log lines to epoch boundaries to keep the dispatch "
-                "pipeline hot", cost * 1e3)
-            return "deferred"
-
-        log_mode = _probe_link()
-        log_buffer: list = []    # deferred: (step, epoch, loss_arr, eps)
-        live_line: list = []     # live: the one line whose sync is due
-
-        def log_line(s, ep, val, eps):
-            nonlocal loss_val
-            loss_val = val
-            logger.info("step %d epoch %d loss %.6f examples/sec %.0f",
-                        s, ep, val, eps)
-
-        def log_tick(s, ep, loss_arr, eps):
-            if log_mode == "deferred":
-                log_buffer.append((s, ep, loss_arr, eps))
-                # Bound the buffer: log_steps=1 on a months-long epoch must
-                # not retain unbounded device scalars; one rare mid-epoch
-                # sync is the lesser evil.
-                if len(log_buffer) >= LOG_BUFFER_MAX:
-                    flush_log()
-                return
-            # Live: the sync is taken at the NEXT dispatch
-            # (_wire_step), once the next batch is fetched and placed:
-            # the device then waits for the host one dispatch after
-            # each loss line, and not a placement too (13 ms of every
-            # eight steps on the four-chip mesh).
-            sync_live_line()  # never two owed
-            live_line.append((s, ep, loss_arr, eps))
-
-        def sync_live_line():
-            """The loop's sync point: the host waits here until the
-            device has caught up, so this phase's share of the wall
-            says how far the device sets the pace. The line itself is
-            written outside the phase."""
-            if not live_line:
-                return
-            s, ep, loss_arr, eps = live_line.pop()
-            with span("train/loss_sync",
-                      seconds="train/loss_sync_seconds"):
-                val = float(loss_arr)
-            log_line(s, ep, val, eps)
-
-        def flush_log():
-            sync_live_line()
-            if not log_buffer:
-                return
-            # bulk_fetch stacks the same-shaped scalars into ONE transfer:
-            # deferred mode is only ever active on a slow device link,
-            # where a per-element list fetch costs ~200 ms EACH
-            # (utils/fetch.py) — a full 1024-entry buffer would stall for
-            # minutes.
-            lines: list = []
-            with span("train/loss_sync",
-                      seconds="train/loss_sync_seconds"):
-                bulk_fetch([(arr, (s, ep, eps))
-                            for s, ep, arr, eps in log_buffer],
-                           lambda v, m: lines.append(
-                               (m[0], m[1], float(v), m[2])))
-            for line in lines:
-                log_line(*line)
-            log_buffer.clear()
-        # Handlers stay installed (absorbing re-signals) until the finally
-        # below — i.e. until the final checkpoint/export is safely on disk,
-        # the window a second SIGTERM is most likely to arrive in. The
-        # finally also covers exceptions, so a failed in-process train()
-        # can't leave the surviving process (pytest, REPL, server) with
-        # SIGTERM/SIGINT swallowed into a dead flag list.
-        completed_epochs = start_epoch
-        last_periodic_save = (None, None)  # (step, epoch) of the latest
-        # Streaming run mode (README "Streaming / online learning"):
-        # the durable stream position adopted from STEPPED batches —
-        # what every checkpoint records beside the arrays, so restore
-        # resumes with no example duplicated or skipped. None in epoch
-        # mode (saves then carry no watermark sidecar).
-        stream_watermark = None
-
-        def _stream_state_for_save():
-            """The watermark payload a save should carry right now:
-            merged across workers at this lockstep point (a collective
-            when multi-process — callers must invoke it at
-            step-deterministic points only)."""
-            if not stream_mode:
-                return None
-            from fast_tffm_tpu.data.stream import exchange_watermarks
-            wm = stream_watermark or {"format": 1, "files": []}
-            return (exchange_watermarks(wm, num_shards)
-                    if multi_process else wm)
-
-        def _run_stream():
-            """The indefinitely-surviving online loop: poll the stream
-            source, step every arriving batch, save with the watermark,
-            and publish a manifest-verified checkpoint every
-            ``publish_interval_seconds``. Single-process overlaps build
-            and compute through the prefetch thread; multi-worker runs
-            the source inline on this thread so its one discovery
-            collective per iteration stays aligned with the lockstep
-            flags allgather and the step program (collectives from two
-            threads would interleave nondeterministically across
-            workers — the deadlock class the window protocol exists to
-            prevent)."""
-            nonlocal global_step, loss, stopping, stream_watermark, \
-                last_periodic_save, table, acc
-            from fast_tffm_tpu.data import stream as streamlib
-            from fast_tffm_tpu.data.pipeline import empty_batch
-            restored_wm = (restored or {}).get("stream")
-            # Seed the adopted position from the restored sidecar: a
-            # recovered session (elastic shrink/grow, preempt-resume)
-            # saves at its restored step BEFORE any new batch steps —
-            # publish settles fire on idle ticks — and an empty
-            # in-memory watermark there would REWRITE the step's
-            # sidecar to empty, wiping the durable position and
-            # double-training the whole consumed prefix after the
-            # next restore (caught by the kill-then-grow soak).
-            stream_watermark = restored_wm
-            if restored is not None and restored_wm is None:
-                logger.warning(
-                    "restored checkpoint at step %d carries no stream "
-                    "watermark (an epoch-mode warm start, or a lost "
-                    "watermark sidecar): streaming starts from the "
-                    "BEGINNING of %s — any stream bytes this model "
-                    "already trained on will be trained again",
-                    global_step, cfg.stream_dir)
-            tracker = streamlib.StreamTracker(
-                cfg.stream_dir, cfg.stream_poll_seconds,
-                cfg.seal_policy, retry=RetryPolicy.from_config(cfg),
-                shard_index=shard_index, num_shards=num_shards,
-                bad_lines=bad_tracker, watermark=restored_wm,
-                lockstep=multi_process)
-            u_bucket = 0
-            if multi_process:
-                u_bucket = (cfg.uniq_bucket
-                            or streamlib.probe_stream_uniq_bucket(
-                                cfg, tracker))
-                logger.info("fixed unique-row bucket: %d", u_bucket)
-            workers = streamlib.stream_workers(
-                cfg, fixed_shape=multi_process)
-            if workers > 1:
-                logger.info(
-                    "stream host data plane: %d parallel batch-build "
-                    "workers (host_threads = %s; sealed line groups "
-                    "through the bounded ordered ring)",
-                    workers, cfg.host_threads)
-            source = streamlib.StreamSource(
-                cfg, tracker,
-                stop=(None if multi_process
-                      else (lambda: bool(preempted))),
-                fixed_shape=multi_process, uniq_bucket=u_bucket,
-                raw_ids=raw_mode, workers=workers,
-                bad_lines=bad_tracker, vocab=vocab)
-            publish_every = float(
-                getattr(cfg, "publish_interval_seconds", 0.0))
-            last_publish = [time.monotonic()]
-            # The freshness gauge (and the STALE PUBLISH verdict) track
-            # the last SUCCESSFUL publish, separately from the attempt
-            # clock above: a gate that keeps holding advances the
-            # cadence but NOT the pointer — the age must keep growing
-            # so a long hold surfaces as STALE PUBLISH, the closed
-            # loop's designed failure signal.
-            last_publish_ok = [time.monotonic()]
-            # Whether the LAST gate decision held. While holding, the
-            # retention-pressure publish trigger below is disarmed: a
-            # republish attempt cannot succeed (the gate would hold
-            # the same regressed state again), so re-arming it would
-            # spin a full validation sweep per loop iteration for the
-            # whole hold. The interval arm keeps re-evaluating at the
-            # publish cadence — the bounded re-check that notices
-            # recovery.
-            gate_holding = [False]
-            # One retention-pause log per hold episode (see step_once).
-            risk_pause_logged = [False]
-            if tel is not None:
-                tel.set("stream/publish_interval_seconds",
-                        publish_every)
-
-            def publish_due() -> bool:
-                """Interval elapsed, OR retention pressure: periodic
-                save_steps saves must never GC the published step out
-                from under a scorer mid-interval — republishing first
-                repoints at fresh state instead of letting the pointer
-                dangle. Chief-only in lockstep mode (the decision
-                rides the flags allgather)."""
-                if publish_every <= 0:
-                    return False
-                if time.monotonic() - last_publish[0] >= publish_every:
-                    return True
-                # Gated runs check one retention slot EARLY (margin=2):
-                # the very tick this arm triggers may turn out HELD,
-                # and a hold starting at the margin-1 boundary would
-                # leave the mandatory final/preemption save to evict
-                # the last-good step — the reserve the save pause
-                # depends on must exist BEFORE the hold begins.
-                return (bool(cfg.save_steps) and not gate_holding[0]
-                        and ckpt.published_at_risk(
-                            margin=2 if gate is not None else 1))
-
-            def stream_gauges():
-                if tel is None:
-                    return
-                tel.set("stream/watermark_lag_seconds",
-                        tracker.watermark_lag_seconds())
-                if publish_every > 0:
-                    tel.set("stream/last_publish_age_seconds",
-                            time.monotonic() - last_publish_ok[0])
-
-            def stream_save(wait: bool, force: bool = False) -> None:
-                nonlocal last_periodic_save
-                state = (lk.state() if offload
-                         else ckpt_state(cfg, table, acc))
-                ckpt.save(global_step, *state,
-                          vocabulary_size=cfg.vocabulary_size,
-                          force=force, wait=wait, epoch=0,
-                          stream_state=_stream_state_for_save(),
-                          vocab_state=(vocab.state_payload()
-                                       if vocab is not None else None))
-                last_periodic_save = (global_step, 0)
-
-            def do_publish() -> None:
-                """Quality eval + gate, then save + settle the
-                manifest + verify + atomically repoint the
-                ``published`` pointer. A HELD decision skips the save
-                too: a held tick must not mint a new step — retention
-                (max_to_keep) could otherwise use held steps to lap
-                the published pointer, deleting the exact "last good
-                triple" the gate exists to keep serving. Lockstep-safe:
-                the decision is chief-broadcast, so every worker runs
-                the save's commit barrier (or skips it) together; only
-                process 0 flips the pointer."""
-                with span("checkpoint/publish", leaf=False,
-                          seconds="train/checkpoint_pause_seconds",
-                          step=global_step):
-                    # Publish settle IS a vocab barrier point: the
-                    # published (table, slot map, step) triple a
-                    # scorer hot-reloads must be post-admission/
-                    # eviction coherent — evicted rows reset BEFORE
-                    # the save, so the published step serves evicted
-                    # ids from the cold row, never stale embeddings.
-                    # (It runs before the quality eval, so the sweep
-                    # measures exactly the state a pass would publish.)
-                    _vocab_barrier(f"publish step {global_step}")
-                    decision = _publish_decision()
-                    gate_holding[0] = bool(decision
-                                           and decision.get("held"))
-                    if not gate_holding[0]:
-                        risk_pause_logged[0] = False
-                    if decision is None or not decision.get("held"):
-                        # force=True: a publish can land on the SAME
-                        # step as the last periodic save, and the
-                        # barrier above just moved the in-memory
-                        # (table, slot map) pair — the benign same-
-                        # step-collision skip would pair the old
-                        # arrays with the new sidecar. Forcing
-                        # rewrites both, so the published triple is
-                        # coherent.
-                        stream_save(wait=True, force=vocab is not None)
-                        ok = ckpt.publish_step(global_step) is not None
-                        # Non-chief workers assume the chief's verify
-                        # passed (publish_step is process-0-only; a
-                        # verify failure is already counted and the
-                        # decision stream stays chief-broadcast, so a
-                        # rare divergent baseline here cannot diverge
-                        # an outcome).
-                        if ok or jax.process_index() != 0:
-                            last_publish_ok[0] = time.monotonic()
-                            _gate_published(decision)
-                    # held: no save, no publish — and once the
-                    # published step reaches the retention boundary,
-                    # step_once pauses periodic saves too, so GC can
-                    # never evict the last-good checkpoint mid-hold.
-                last_publish[0] = time.monotonic()
-                stream_gauges()
-                if grow_ctx is not None and not gate_holding[0]:
-                    # The publish settle IS the grow barrier in stream
-                    # mode (the same sync point the vocab barrier
-                    # rides): the save above just landed with the
-                    # merged watermark (wait=True), so a newcomer's
-                    # verified restore resumes the stream exactly-once
-                    # from this point. A HELD publish skipped the save
-                    # — no durable barrier state, no admission; the
-                    # chief-broadcast hold decision keeps every worker
-                    # on the same arm.
-                    plan = grow_ctx.check_barrier()
-                    if plan is not None:
-                        raise ClusterGrowth(plan)
-
-            # fmlint: disable=R003 -- anchors the stream step-seconds
-            # window (always-on aggregate)
-            t_prev = [time.perf_counter()]
-
-            def step_once(batch) -> None:
-                nonlocal global_step, loss, stream_watermark
-                nonlocal table, acc
-                if vocab is not None:
-                    # A publish barrier may have moved the slot map
-                    # while this batch sat in the prefetch queue —
-                    # redo its remap so it never scatters into rows
-                    # the barrier evicted/reset/reassigned (one int
-                    # compare when nothing moved).
-                    batch = vocab.ensure_current(batch)
-                wb, args = _wire_place(batch, global_step + 1)
-                h2d_bytes = wb.wire_bytes
-                table, acc, loss, _ = _wire_step(wb, args, table, acc,
-                                                 global_step + 1)
-                global_step += 1
-                if batch.stream_pos is not None:
-                    # The durable position advances ONLY with stepped
-                    # batches (lockstep fillers carry None).
-                    stream_watermark = batch.stream_pos
-                if vocab is not None:
-                    # Adopt-on-step, like the watermark: the sketch
-                    # advances only for trained batches, so the
-                    # checkpointed admission state and the stream
-                    # position describe the same prefix.
-                    vocab.note_trained(batch)
-                # Log-line rate: the job-global estimate (x P assumes
-                # symmetric shards — exact under line sharding, an
-                # estimate under whole-file stream ownership). The
-                # COUNTER is this worker's OWN real examples: shard
-                # files merge by sum, so anything else would inflate
-                # the exactly-once accounting P-fold (and whole-file
-                # ownership pays fillers as phantom examples).
-                n_global = batch.num_real * (jax.process_count()
-                                             if multi_process else 1)
-                timer.tick(n_global)
-                if tel is not None:
-                    # fmlint: disable=R003 -- feeds the train/
-                    # step_seconds histogram (always-on aggregate)
-                    now = time.perf_counter()
-                    tel.train_step(now - t_prev[0], batch.num_real,
-                                   h2d_bytes, wb.logical_bytes)
-                    t_prev[0] = now
-                    tel.heartbeat(global_step)
-                profile_tick(global_step)
-                log_due = (cfg.log_steps
-                           and global_step % cfg.log_steps == 0)
-                tel_due = (tel is not None
-                           and tel.flush_due(global_step))
-                eps_now = (timer.consume_window_rate()
-                           if (log_due or tel_due) else None)
-                if log_due:
-                    log_tick(global_step, 0, loss, eps_now)
-                if tel_due:
-                    tel.add_scalar("train/loss", global_step, loss)
-                    tel.set("train/examples_per_sec_window", eps_now)
-                    stream_gauges()
-                    with span("obs/flush", seconds="obs/flush_seconds"):
-                        tel.maybe_flush(global_step)
-                if cfg.save_steps and global_step % cfg.save_steps == 0:
-                    # margin=2: stop one slot shy of the boundary so
-                    # the mandatory final/preemption save can still
-                    # land without evicting the last-good step.
-                    if (gate_holding[0]
-                            and ckpt.published_at_risk(margin=2)):
-                        # Retention pause: while the gate is HOLDING,
-                        # a periodic save that would push the
-                        # published (last-good) step past max_to_keep
-                        # must not run — orbax's newest-N eviction has
-                        # no pin, so minting the step would delete the
-                        # exact checkpoint the fleet is serving from
-                        # (published_at_risk's "the pointer never
-                        # names a deleted step" contract). Durability
-                        # pauses for the hold — progress since the
-                        # last save is re-trained on a crash, exactly
-                        # once via the watermark — and resumes when
-                        # the gate passes (the publish repoints at
-                        # fresh state, clearing the risk).
-                        if not risk_pause_logged[0]:
-                            risk_pause_logged[0] = True
-                            logger.warning(
-                                "publish gate holding with the "
-                                "published step at the retention "
-                                "boundary: pausing periodic saves so "
-                                "GC cannot evict the last-good "
-                                "checkpoint; heal the input stream "
-                                "(or raise max_to_keep) to resume")
-                    else:
-                        # Gated runs save SYNCHRONOUSLY: the retention
-                        # math protecting the published step (the
-                        # margin=2 risk arm + the hold pause above)
-                        # reasons over COMMITTED step dirs — an async
-                        # save's invisible in-flight step would let a
-                        # hold latch with the window already full, and
-                        # the mandatory final save would then evict
-                        # the exact last-good checkpoint the gate
-                        # pinned (caught by the retention-pause e2e
-                        # test).
-                        with span("train/checkpoint_pause",
-                                  seconds="train/checkpoint_pause_seconds"
-                                  ) as pause:
-                            stream_save(wait=offload or gate is not None)
-                        if tel is not None:
-                            t_prev[0] += pause.dur
-
-            def emit_preempted() -> None:
-                nonlocal stopping
-                stopping = True
-                logger.info("preemption signalled; saving the stream "
-                            "position and exiting")
-                if tel is not None:
-                    tel.sink.emit("health", {
-                        "status": "preempted", "step": global_step,
-                        "epoch": 0})
-
-            try:
-                if multi_process:
-                    from jax.experimental import multihost_utils
-                    from fast_tffm_tpu.parallel.liveness import (
-                        guarded_collective)
-                    while True:
-                        b = source.next_batch(block=False)
-                        has = b not in (streamlib.IDLE, streamlib.DONE)
-                        done = b is streamlib.DONE
-                        pub_due = publish_due()
-                        # The flags allgather is the stream loop's
-                        # rank barrier: time parked here is waiting
-                        # for the slowest peer (anatomy flags-wait
-                        # phase; the span's step id is the cross-rank
-                        # join key).
-                        ids = ({"step": global_step + 1} if anat
-                               else {})
-                        with span("stream/step_flags",
-                                  seconds="train/step_flags_seconds",
-                                  **ids):
-                            flags = np.asarray(guarded_collective(
-                                multihost_utils.process_allgather,
-                                np.asarray([has, bool(preempted),
-                                            done, pub_due]),
-                                label="stream/step_flags"
-                                )).reshape(-1, 4)
-                        if bool(flags[:, 1].any()):
-                            emit_preempted()
-                            break
-                        if bool(flags[:, 2].all()) and not bool(
-                                flags[:, 0].any()):
-                            break
-                        if bool(flags[:, 0].any()):
-                            batch = (b if has else empty_batch(
-                                cfg, uniq_bucket=u_bucket))
-                            step_once(batch)
-                        else:
-                            if tel is not None:
-                                tel.heartbeat()
-                            stream_gauges()
-                            time.sleep(min(cfg.stream_poll_seconds,
-                                           0.5))
-                        if bool(flags[0, 3]):  # the CHIEF's clock
-                            do_publish()
-                else:
-                    # StreamPrefetcher, not pipeline.prefetch: the
-                    # driver must keep its publish clock and
-                    # preemption checks ticking while the stream
-                    # idles — a blocking queue get would starve
-                    # publishing for as long as no batch arrives.
-                    pf = streamlib.StreamPrefetcher(
-                        source, depth=cfg.prefetch_depth)
-                    try:
-                        while True:
-                            if preempted:
-                                emit_preempted()
-                                break
-                            batch = pf.get(timeout=min(
-                                cfg.stream_poll_seconds, 0.5))
-                            # fmlint: disable=R007 -- single-process
-                            # arm (the lockstep arm above is the
-                            # multi-worker path): step_once's
-                            # collectives are themselves gated on
-                            # multi_process, so no peer exists to
-                            # diverge from; `batch` reads as
-                            # rank-tainted only through the tracker's
-                            # shard_index plumbing
-                            # fmlint: disable=R014 -- same
-                            # single-process-arm justification: the
-                            # loop's collectives are all gated on
-                            # multi_process, so this escape leaves no
-                            # peer's sequence unmatched
-                            if batch is streamlib.DONE:
-                                if preempted:
-                                    emit_preempted()
-                                break
-                            # fmlint: disable=R007 -- same
-                            # single-process-arm justification as above
-                            if batch is streamlib.IDLE:
-                                if tel is not None:
-                                    tel.heartbeat()
-                                stream_gauges()
-                            else:
-                                step_once(batch)
-                            if publish_due():
-                                do_publish()
-                    finally:
-                        pf.close()
-            finally:
-                source.close()
-            stream_gauges()  # the exit metrics snapshot carries the
-            # freshness gauges even when the run never hit a flush step
-            flush_log()
-            if bad_tracker is not None and bad_tracker.bad:
-                logger.info("bad-line policy through the stream run: "
-                            "%s", bad_tracker.describe())
-            if source.stats.batches:
-                logger.info("stream input: %s",
-                            source.stats.describe())
-
-        if stream_mode:
-            _run_stream()
-            epoch_schedule = range(0)  # the epoch loop never runs
+                            s.summaries.logdir)
+        loop.log_mode = _probe_link(cfg, logger)
+        if s.stream_mode:
+            _run_stream(s, loop)
         else:
-            epoch_schedule = range(start_epoch, cfg.epoch_num)
-        for epoch in epoch_schedule:
-            if stopping:
-                break
-            epoch_stats = SpillStats()
-            # Building the input pipeline until its first batch is out
-            # (closed below, after the epoch's first next()).
-            starting = begin("pipeline/start",
-                             seconds="pipeline/start_seconds")
-            it = prefetch(batch_iterator(
-                cfg, cfg.train_files, training=True,
-                weight_files=cfg.weight_files, shard_index=shard_index,
-                num_shards=num_shards, epochs=1, seed=cfg.seed + epoch,
-                fixed_shape=multi_process, uniq_bucket=uniq_bucket,
-                stats=epoch_stats, raw_ids=raw_mode,
-                bad_lines=bad_tracker, vocab=vocab),
-                depth=cfg.prefetch_depth,
-                gil_bound=gil_bound_iteration(cfg, cfg.weight_files))
-            # fmlint: disable=R003 -- anchors the per-epoch
-            # step-seconds window (always-on aggregate)
-            t_step_prev = time.perf_counter()
-            while True:
-                # Consumer-side stall: time blocked INSIDE next() only —
-                # bracketing it any wider would fold end-of-step
-                # bookkeeping (notably live-mode's deliberate
-                # float(loss) device sync in log_tick) into the
-                # host-bound signal and misdiagnose a device-bound run
-                # (the producer-side build cost is timed separately in
-                # pipeline.batch_iterator on the worker thread).
-                with span("train/input_wait",
-                          seconds="train/input_wait_seconds"):
-                    batch = next(it, None)
-                starting.end()
-                if multi_process:
-                    # Lockstep: line-index sharding can give processes
-                    # batch counts differing by one; every step is a
-                    # collective program, so a process that stepped alone
-                    # would hang the cluster. Agree on exhaustion/
-                    # preemption each step (tiny host allgather) and feed
-                    # all-padding filler batches (zero weight -> zero
-                    # loss/grad) until everyone is done. The deadline
-                    # guard bounds the wait: a dead peer raises
-                    # WorkerLostError naming it instead of parking the
-                    # survivors here forever (parallel/liveness.py).
-                    from jax.experimental import multihost_utils
-                    from fast_tffm_tpu.parallel.liveness import (
-                        guarded_collective)
-                    # The epoch loop's rank barrier (anatomy flags-
-                    # wait phase; span step id = cross-rank join key).
-                    # On CPU+gloo this wait also absorbs the PREVIOUS
-                    # step's still-executing program — allgather
-                    # blocks behind queued device work — which is
-                    # exactly what the anatomy report names.
-                    ids = {"step": global_step + 1} if anat else {}
-                    with span("train/step_flags",
-                              seconds="train/step_flags_seconds", **ids):
-                        flags = guarded_collective(
-                            multihost_utils.process_allgather,
-                            np.asarray([batch is None,
-                                        bool(preempted)]),
-                            label="train/step_flags")
-                    if bool(flags[..., 1].any()):
-                        stopping = True
-                        logger.info(
-                            "preemption signalled; saving and exiting")
-                        if tel is not None:
-                            # Distinct health event: fmstat must report
-                            # a clean preemption exit as PREEMPTED, not
-                            # conflate it with a crash (obs/attribution
-                            # health_verdict).
-                            tel.sink.emit("health", {
-                                "status": "preempted",
-                                "step": global_step, "epoch": epoch})
-                        break
-                    if bool(flags[..., 0].all()):
-                        break
-                    if batch is None:
-                        from fast_tffm_tpu.data.pipeline import empty_batch
-                        batch = empty_batch(cfg, uniq_bucket=uniq_bucket)
-                else:
-                    if preempted:
-                        stopping = True
-                        logger.info(
-                            "preemption signalled; saving and exiting")
-                        if tel is not None:
-                            # fmlint: disable=R001 -- preempted holds
-                            # host signal numbers from the handler,
-                            # never device arrays
-                            sigs = [int(s) for s in preempted]
-                            tel.sink.emit("health", {
-                                "status": "preempted",
-                                "step": global_step, "epoch": epoch,
-                                "signals": sigs})
-                        break
-                    # fmlint: disable=R014 -- single-process arm (the
-                    # multi_process arm above agrees on exhaustion via
-                    # the train/step_flags allgather before breaking);
-                    # the loop's collectives are gated on multi_process
-                    # so this escape leaves no peer unmatched
-                    if batch is None:
-                        break
-                if vocab is not None:
-                    # Epoch barriers only run once the epoch's iterator
-                    # is exhausted, so nothing should be stale here —
-                    # this is the one-integer-compare insurance the
-                    # stream loop actually needs (see step_once).
-                    batch = vocab.ensure_current(batch)
-                wb, args = _wire_place(batch, global_step + 1)
-                h2d_bytes = wb.wire_bytes
-                table, acc, loss, _ = _wire_step(wb, args, table, acc,
-                                                 global_step + 1)
-                if barrier is not None:
-                    barrier.end()
-                global_step += 1
-                last_val = None  # table advanced; any cached AUC is stale
-                if vocab is not None:
-                    vocab.note_trained(batch)  # adopt-on-step: only
-                    # TRAINED batches feed the admission sketch
-                # Counter = LOCAL real examples (shard files merge by
-                # sum — see the stream loop's note); n_global feeds
-                # only the log-line rate estimate.
-                n_global = batch.num_real * (jax.process_count()
-                                             if multi_process else 1)
-                timer.tick(n_global)
-                if tel is not None:
-                    # Wall time since the previous step's bookkeeping —
-                    # dispatch-loop time, never a device sync. Reset per
-                    # epoch so validation/pause gaps stay out of the
-                    # histogram (they have their own counters).
-                    # fmlint: disable=R003 -- feeds the train/
-                    # step_seconds histogram (always-on aggregate; the
-                    # train/step span is the timeline view)
-                    now = time.perf_counter()
-                    tel.train_step(now - t_step_prev, batch.num_real,
-                                   h2d_bytes, wb.logical_bytes)
-                    t_step_prev = now
-                    # Watchdog progress beat: one tuple assignment
-                    # (obs/health.py) — the stall detector's only
-                    # hot-path cost.
-                    tel.heartbeat(global_step)
-                profile_tick(global_step)
-                log_due = (cfg.log_steps
-                           and global_step % cfg.log_steps == 0)
-                sum_due = (summaries is not None and global_step
-                           % cfg.save_summaries_steps == 0)
-                tel_due = tel is not None and tel.flush_due(global_step)
-                # One windowed-rate read per step: the read consumes
-                # the window, so the log line, the summary, and the
-                # metrics gauge all share it.
-                eps_now = (timer.consume_window_rate()
-                           if (log_due or sum_due or tel_due) else None)
-                if log_due:
-                    log_tick(global_step, epoch, loss, eps_now)
-                if sum_due:
-                    summaries.add("train/loss", global_step, loss)
-                    summaries.add("train/examples_per_sec", global_step,
-                                  eps_now)
-                if tel_due:
-                    # loss is a DEVICE scalar: buffered, fetched only at
-                    # the next epoch barrier (sink link-safety contract).
-                    tel.add_scalar("train/loss", global_step, loss)
-                    tel.set("train/examples_per_sec_window", eps_now)
-                    with span("obs/flush", seconds="obs/flush_seconds"):
-                        tel.maybe_flush(global_step)  # file I/O only
-                if cfg.save_steps and global_step % cfg.save_steps == 0:
-                    with span("train/checkpoint_pause",
-                              seconds="train/checkpoint_pause_seconds"
-                              ) as pause:
-                        state = (lk.state() if offload
-                                 else ckpt_state(cfg, table, acc))
-                        # Device arrays: async save (orbax D2H-snapshots
-                        # synchronously, writes in background — the loop
-                        # doesn't stall for serialization). Host-offload
-                        # state: wait, because the background writer
-                        # would race the in-place numpy Adagrad updates.
-                        ckpt.save(global_step, *state,
-                                  vocabulary_size=cfg.vocabulary_size,
-                                  wait=offload, epoch=completed_epochs,
-                                  vocab_state=(vocab.state_payload()
-                                               if vocab is not None
-                                               else None))
-                    last_periodic_save = (global_step, completed_epochs)
-                    if tel is not None:
-                        t_step_prev += pause.dur  # keep the pause out
-                        # of the next step's step_seconds sample
-            sync_live_line()  # the epoch's last line, ahead of the barrier
-            if not stopping:
-                # The epoch barrier: from the iterator's exhaustion
-                # until the NEXT epoch's first dispatch returns (or the
-                # loop's end) — telemetry flush, validation, the cold
-                # input pipeline: what the steady step rate leaves out.
-                barrier = begin("train/epoch_barrier",
-                                seconds="train/epoch_barrier_seconds")
-            flush_log()  # deferred loss lines land at the epoch barrier
-            if bad_tracker is not None and bad_tracker.bad:
-                # Cumulative run-level view: the breaker and quarantine
-                # are run-scoped, so the log line is too.
-                logger.info("bad-line policy through epoch %d: %s",
-                            epoch, bad_tracker.describe())
-            if epoch_stats.spilled_batches or (multi_process
-                                               and epoch_stats.batches):
-                # Spill visibility (fixed-U mode): a probe-missed dense
-                # stretch degrades fill silently otherwise.
-                logger.info("epoch %d input: %s", epoch,
-                            epoch_stats.describe())
-                if epoch_stats.spill_fraction > SPILL_WARN_FRACTION:
-                    logger.warning(
-                        "uniq_bucket %d is undersized for this data: "
-                        "%.0f%% of batches closed early on the "
-                        "unique-row budget; raise uniq_bucket (or set 0 "
-                        "to re-probe) to recover effective batch size",
-                        uniq_bucket, 100 * epoch_stats.spill_fraction)
-            if multi_process and not stopping and epoch + 1 < cfg.epoch_num:
-                # Adaptive bucket: a probe-missed dense stretch spills
-                # every epoch otherwise. The job-wide spill fraction is
-                # allgathered (per-process stats see only their own
-                # shard — a local decision would desynchronize shapes
-                # and deadlock the collective program), and every
-                # process applies the same doubling.
-                from jax.experimental import multihost_utils
-                from fast_tffm_tpu.parallel.liveness import (
-                    guarded_collective)
-                tot = guarded_collective(
-                    multihost_utils.process_allgather,
-                    np.asarray(
-                        [epoch_stats.spilled_batches, epoch_stats.batches,
-                         epoch_stats.max_uniq]),
-                    label="train/spill_stats")
-                tot = tot.reshape(-1, 3)
-                # fmlint: disable=R001 -- tot is the HOST numpy result
-                # of process_allgather; these ints never touch a device
-                uniq_bucket = adapt_uniq_bucket(
-                    cfg, uniq_bucket, int(tot[:, 0].sum()),
-                    int(tot[:, 1].sum()), logger,
-                    max_uniq=int(tot[:, 2].max()))
-            if not stopping:
-                # The epoch boundary IS a vocab barrier point: the
-                # epoch's observations admit/evict here, so the next
-                # epoch (and the validation sweep just below) runs
-                # against the refreshed map + reset rows.
-                _vocab_barrier(f"epoch {epoch}")
-            if cfg.validation_files and not stopping:
-                vmb = cfg.validation_max_batches or None
-                with span("train/validation", leaf=False,
-                          seconds="train/validation_seconds",
-                          epoch=epoch):
-                    if multi_process:
-                        # preempt rides the lockstep window allgather:
-                        # a SIGTERM during a long validation sweep
-                        # stops EVERY worker at the same window
-                        # boundary (the signalled worker alone bailing
-                        # would desync the collective program stream);
-                        # the step loop below then drains the flag and
-                        # all workers save together.
-                        auc, n = evaluate_distributed(
-                            cfg, table, cfg.validation_files, mesh,
-                            shard_index, num_shards,
-                            uniq_bucket=val_bucket, max_batches=vmb,
-                            weight_files=cfg.validation_weight_files,
-                            bad_lines=bad_tracker,
-                            preempt=lambda: bool(preempted))
-                    else:
-                        auc, n = evaluate(
-                            cfg, table, cfg.validation_files,
-                            mesh=mesh, backend=lk, max_batches=vmb,
-                            weight_files=cfg.validation_weight_files,
-                            bad_lines=bad_tracker, vocab=vocab)
-                last_val = (auc, n)
-                if jax.process_index() == 0:
-                    logger.info(
-                        "epoch %d validation AUC %.6f over %d examples",
-                        epoch, auc, n)
-                if summaries is not None:
-                    summaries.add("validation/auc", global_step, auc)
-                if tel is not None:
-                    tel.set("validation/auc", auc)
-                    # fmlint: disable=R001 -- auc is already a host
-                    # python float from the streamed AUC merge
-                    tel.add_scalar("validation/auc", global_step,
-                                   float(auc))
-            if summaries is not None:  # epoch barrier: bulk-fetch + write
-                with span("train/summary_flush",
-                          seconds="train/summary_pause_seconds"):
-                    summaries.flush()
-            if tel is not None:
-                # Epoch barrier: the one point buffered device scalars
-                # are bulk-fetched and the JSONL reaches disk for sure.
-                tel.count("train/epochs")
-                tel.barrier_flush(global_step)
-            if not stopping:  # a preemption-cut epoch is NOT completed
-                completed_epochs = epoch + 1
-            if (grow_ctx is not None and not stopping
-                    and completed_epochs < cfg.epoch_num):
-                # The epoch boundary IS the grow barrier in epochs
-                # mode: every worker is synchronized here (the same
-                # point the vocab barrier uses), and the chief's
-                # admission plan is broadcast so everyone raises
-                # together or nobody does. The barrier state is saved
-                # durably FIRST (force rewrites a same-step periodic
-                # save with the completed epoch count) — it is exactly
-                # what the newcomer's verified restore comes up on.
-                # The last epoch never grows: the run is about to
-                # finish, and a reform would only delay its exit.
-                plan = grow_ctx.check_barrier()
-                if plan is not None:
-                    state = (lk.state() if offload
-                             else ckpt_state(cfg, table, acc))
-                    ckpt.save(global_step, *state,
-                              vocabulary_size=cfg.vocabulary_size,
-                              force=True, wait=True,
-                              epoch=completed_epochs,
-                              vocab_state=(vocab.state_payload()
-                                           if vocab is not None
-                                           else None))
-                    last_periodic_save = (global_step,
-                                          completed_epochs)
-                    raise ClusterGrowth(plan)
-        if barrier is not None:
-            barrier.end()
-        flush_log()
-        loss_val = float(loss) if loss is not None else loss_val
-        # The final save IS a barrier point (vocab/table.py's contract):
-        # nothing is in flight here — the stream is drained or the
-        # epoch iterators exhausted — so the durable (table, slot map)
-        # pair admits the last interval's crossers and evicts/resets
-        # its cold rows before the bytes land (the exit publish below
-        # repoints at exactly this state). MUST run before state() is
-        # captured: the row resets donate (and for the device path
-        # reassign) the table/acc buffers.
-        _vocab_barrier(f"final save step {global_step}")
-        state = lk.state() if offload else ckpt_state(cfg, table, acc)
-        # Final/preemption save: barrier until durably written — the
-        # process may exit right after.
-        # If this step's existing checkpoint carries a stale epoch
-        # count — from THIS run's last periodic save, or from the
-        # RESTORED checkpoint when a resumed run advanced the schedule
-        # without a single global step (every shard's input empty —
-        # note a multi-process job with ANY data still advances
-        # global_step via lockstep fillers, so that case needs the
-        # whole job dry) — tell save() to correct it (an atomic epoch
-        # sidecar written by process 0; restore overlays it). Both
-        # signals are deterministic (lockstep-consistent state, not
-        # disk reads), so every process of a multi-host job agrees the
-        # correction exists — restore's process-0-read + broadcast does
-        # the rest.
-        stale = ((last_periodic_save[0] == global_step
-                  and last_periodic_save[1] != completed_epochs)
-                 or (restored is not None
-                     and global_step == restored_step
-                     and completed_epochs != restored_epoch))
-        ckpt.save(global_step, *state,
-                  vocabulary_size=cfg.vocabulary_size, force=True,
-                  wait=True, epoch=completed_epochs,
-                  rewrite_stale_metadata=stale,
-                  stream_state=_stream_state_for_save(),
-                  vocab_state=(vocab.state_payload()
-                               if vocab is not None else None))
-        if stream_mode and getattr(cfg, "publish_interval_seconds",
-                                   0.0) > 0:
-            # The exit publish: a clean STOP drain (or a preemption's
-            # durable save) is the freshest verified state a scorer
-            # can hot-reload; the save above already settled the
-            # manifest (wait=True). Gated like every other publish —
-            # a run whose tail regressed quality must exit with the
-            # pointer still on the last passing step (the final save
-            # itself always lands: resume durability is not gated).
-            # A PREEMPTED exit skips the quality sweep: the grace
-            # window between SIGTERM and the orchestrator's SIGKILL
-            # has no budget for a validation pass, and a mid-sweep
-            # kill would lose the publish entirely — so a gate-less
-            # run publishes immediately (the historical behavior) and
-            # a gated run leaves the pointer on the last step the gate
-            # actually passed rather than publishing unevaluated state.
-            if stopping and gate is not None:
-                logger.info(
-                    "preempted with a publish gate configured: exit "
-                    "publish skipped (no quality sweep inside the "
-                    "grace window); the pointer stays on the last "
-                    "passing step")
-            else:
-                decision = (None if stopping
-                            else _publish_decision())
-                if decision is not None:
-                    # The exit sweep IS this table's final validation:
-                    # _chief_finalize (multi-process) must not re-run
-                    # it.
-                    last_val = (decision["auc"], decision["examples"])
-                if decision is None or not decision.get("held"):
-                    if ckpt.publish_step(global_step) is not None:
-                        # Persist the exit publish's baseline too —
-                        # it is exactly what the NEXT run's gate must
-                        # re-arm from.
-                        _gate_published(decision)
-                        if tel is not None:
-                            tel.set("stream/last_publish_age_seconds",
-                                    0.0)
-        if (stream_mode and not multi_process and cfg.validation_files
-                and not quality_on):
-            # Stream mode has no per-epoch sweeps; a configured
-            # validation corpus gets one final scored pass here
-            # (multi-process streams validate in _chief_finalize below;
-            # publishing streams already validated through the exit
-            # publish's quality sweep just above) — silently
-            # accepting-and-ignoring the knob would be a config trap.
-            auc, n = evaluate(
-                cfg, table, cfg.validation_files, mesh=mesh,
-                backend=lk, max_batches=cfg.validation_max_batches
-                or None, weight_files=cfg.validation_weight_files,
-                bad_lines=bad_tracker, vocab=vocab)
-            logger.info("final validation AUC %.6f over %d examples",
-                        auc, n)
-            if tel is not None:
-                tel.set("validation/auc", auc)
-                # fmlint: disable=R001 -- auc is already a host float
-                # from the streamed AUC merge
-                tel.add_scalar("validation/auc", global_step,
-                               float(auc))
-        if multi_process:
-            _chief_finalize(cfg, table, logger, mesh, shard_index,
-                            num_shards, last_val, val_bucket,
-                            bad_tracker)
-        else:
-            # Same size gate on EVERY dense-export path: a single-host
-            # mesh whose aggregate row-sharded table exceeds host RAM
-            # must not OOM assembling the .npz after a successful run.
-            nbytes = table_bytes(cfg)
-            if nbytes > EXPORT_NPZ_MAX_BYTES:
-                logger.info(
-                    "skipping dense .npz export: table is "
-                    "%.1f GB > %.1f GB threshold; use the checkpoint at "
-                    "%s.ckpt", nbytes / 2**30,
-                    EXPORT_NPZ_MAX_BYTES / 2**30, cfg.model_file)
-            else:
-                export_npz(lk.table if offload else table,
-                           cfg.model_file + ".npz",
-                           vocabulary_size=cfg.vocabulary_size)
+            _run_epochs(s, loop)
+        _finish(s, loop)
     except BaseException as e:
         # Crash forensics: the stream's last substantive event carries
         # the traceback and the recent-event ring, with the step
@@ -2160,12 +784,11 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
             # ClusterGrowth is a planned, durably-saved barrier exit —
             # the driver reforms and re-enters; branding it a crash
             # would flip every healed run's verdict to CRASHED.
-            _record_crash(tel, logger, e, global_step)
+            _record_crash(tel, logger, e, _step_now(s, loop))
         raise
     finally:
-        for phase in (starting, barrier):
-            if phase is not None:
-                phase.end()
+        if loop is not None:
+            loop.end_barrier()
         # The session's resident allocations leave the ledger here —
         # crash or clean exit — so an elastic-recovered session
         # re-registers fresh sizes instead of double-counting, and the
@@ -2177,97 +800,1521 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
             LEDGER.release(_owner)
         try:
             if worker_lost:
-                # HOST-ONLY teardown: a peer is dead, so any device
-                # fetch (buffered loss scalars, TB summaries, the
-                # deferred log buffer — all outputs of collective
-                # programs that will never complete) and any orbax
-                # multi-host commit barrier (ckpt.close) can block
-                # forever — the exact hang the deadline guard just
-                # escaped. Drop the device-side buffers (counted, not
-                # silent), flush host events, and let the elastic
-                # driver rebuild the checkpoint manager; the verified
-                # restore walk-back owns anything torn.
-                if tel is not None:
-                    try:
-                        dropped = tel.sink.discard_scalars()
-                        if dropped:
-                            tel.count("cluster/scalars_dropped", dropped)
-                        tel.sink.flush()
-                    except Exception:
-                        logger.exception("host-only metrics flush "
-                                         "failed")
-                logger.warning(
-                    "worker lost: skipped checkpoint close and "
-                    "device-scalar drains (device fetches could hang "
-                    "on the dead peer's collectives)")
+                _drop_device_buffers(s)
             else:
-                # Checkpoint lifecycle on ALL normal exit paths: an
-                # exception (or preemption) between the last periodic
-                # save and the normal close must not leave an async
-                # save in flight — the process would exit mid-write
-                # and tear the newest step. close() waits for the
-                # in-flight write, settles the owed integrity
-                # manifest, and releases the manager; isolated so a
-                # failed close can't starve the sink drains below.
-                if ckpt is not None:
-                    try:
-                        ckpt.close()
-                    except Exception:
-                        logger.exception("checkpoint close failed")
-                # Sink lifecycle on error paths: a crash mid-epoch
-                # must not drop everything buffered since the last
-                # flush — the log buffer and the TensorBoard scalars
-                # drain here, each isolated so one broken writer can't
-                # starve the others. (The metrics sink and bad-line
-                # tracker are DRIVER-scoped: they survive elastic
-                # recoveries and close in train().)
-                try:
-                    flush_log()
-                except Exception:
-                    logger.exception("deferred loss-log flush failed")
-                if summaries is not None:
-                    # Buffered scalars must reach the event file even
-                    # when the loop raised or a preemption cut the
-                    # final epoch.
-                    try:
-                        summaries.close()
-                    except Exception:
-                        logger.exception("summary writer close failed")
-                if tel is not None:
-                    try:
-                        # Barrier, not close: buffered device scalars
-                        # and the final counter snapshot reach disk
-                        # with this session's step attached, and the
-                        # stream stays open for a recovered session to
-                        # continue.
-                        tel.barrier_flush(global_step)
-                    except Exception:
-                        logger.exception("metrics barrier flush failed")
-            if profiling:
+                _close_sinks(s, loop)
+            if loop is not None and loop.profiling:
                 # Window ran past the end of training — or the loop
                 # raised with the window open; either way the trace must
                 # be closed here or the next start_trace in this process
                 # fails with "trace already in progress".
                 jax.profiler.stop_trace()
-                profiling = False
+                loop.profiling = False
         finally:
             # Must run even if stop_trace raises (unwritable profile_dir):
             # leaving these handlers installed would swallow SIGTERM/
             # SIGINT into a dead flag list in the surviving process.
-            for sig, h in prev_handlers.items():
+            for sig, h in s.prev_handlers.items():
                 signal.signal(sig, h)
     logger.info("training done: %d steps, final loss %.6f, %.0f examples/sec",
-                global_step, loss_val, timer.total_examples_per_sec)
-    if offload:
+                loop.global_step, loop.loss_val,
+                loop.timer.total_examples_per_sec)
+    if s.offload:
         # The logical table as host numpy (the offload analogue of the
         # device table return; dead ckpt-alignment tail sliced off).
         # The pinned backend's table is a jax array in accelerator-host
         # memory: fetch it (callers of train() expect host bytes; at
         # true config-#5 scale callers use the checkpoint instead).
-        tbl = (lk.table if isinstance(lk.table, np.ndarray)
-               else np.asarray(jax.device_get(lk.table)))
+        tbl = (s.lk.table if isinstance(s.lk.table, np.ndarray)
+               else np.asarray(jax.device_get(s.lk.table)))
         return tbl[:cfg.num_rows]
-    return table
+    return loop.table
+
+
+def _step_now(s: _Session, loop) -> int:
+    """The step a crash record or the teardown's flush carries: the
+    loop's once it exists, else what the restore found."""
+    return loop.global_step if loop is not None else s.restored_step
+
+
+def _drop_device_buffers(s: _Session) -> None:
+    """HOST-ONLY teardown: a peer is dead, so any device fetch
+    (buffered loss scalars, TB summaries, the deferred log buffer — all
+    outputs of collective programs that will never complete) and any
+    orbax multi-host commit barrier (ckpt.close) can block forever —
+    the exact hang the deadline guard just escaped. Drop the
+    device-side buffers (counted, not silent), flush host events, and
+    let the elastic driver rebuild the checkpoint manager; the verified
+    restore walk-back owns anything torn."""
+    tel = s.tel
+    if tel is not None:
+        try:
+            dropped = tel.sink.discard_scalars()
+            if dropped:
+                tel.count("cluster/scalars_dropped", dropped)
+            tel.sink.flush()
+        except Exception:
+            s.logger.exception("host-only metrics flush failed")
+    s.logger.warning(
+        "worker lost: skipped checkpoint close and device-scalar "
+        "drains (device fetches could hang on the dead peer's "
+        "collectives)")
+
+
+def _close_sinks(s: _Session, loop) -> None:
+    """Checkpoint and sink lifecycle on ALL normal exit paths, each
+    isolated so one broken writer can't starve the others. (The
+    metrics sink and bad-line tracker are DRIVER-scoped: they survive
+    elastic recoveries and close in train().)"""
+    logger = s.logger
+    # An exception (or preemption) between the last periodic save and
+    # the normal close must not leave an async save in flight — the
+    # process would exit mid-write and tear the newest step. close()
+    # waits for the in-flight write, settles the owed integrity
+    # manifest, and releases the manager.
+    if s.ckpt is not None:
+        try:
+            s.ckpt.close()
+        except Exception:
+            logger.exception("checkpoint close failed")
+    # A crash mid-epoch must not drop everything buffered since the
+    # last flush — the log buffer and the TensorBoard scalars drain
+    # here.
+    if loop is not None:
+        try:
+            loop.flush_log()
+        except Exception:
+            logger.exception("deferred loss-log flush failed")
+    if s.summaries is not None:
+        # Buffered scalars must reach the event file even when the
+        # loop raised or a preemption cut the final epoch.
+        try:
+            s.summaries.close()
+        except Exception:
+            logger.exception("summary writer close failed")
+    if s.tel is not None:
+        try:
+            # Barrier, not close: buffered device scalars and the
+            # final counter snapshot reach disk with this session's
+            # step attached, and the stream stays open for a recovered
+            # session to continue.
+            s.tel.barrier_flush(_step_now(s, loop))
+        except Exception:
+            logger.exception("metrics barrier flush failed")
+
+
+def _restore(s: _Session) -> None:
+    """The session's host-side start: the unique-row buckets, the
+    vocabulary runtime, the checkpoint manager and what it restored
+    (arrays, step, epoch, the admission sidecar)."""
+    cfg, logger = s.cfg, s.logger
+    multi_process = s.multi_process
+    # Visibility only — the plane lives inside batch_iterator.
+    # host_parallel_workers is the SAME predicate the routing uses, so
+    # this log never claims a fan-out the pipeline won't perform for
+    # THIS run's inputs (C++ missing, weight sidecars, tolerant
+    # fixed-shape all route serial).
+    host_workers = host_parallel_workers(
+        cfg, cfg.weight_files, fixed_shape=multi_process)
+    if host_workers > 1 and not s.stream_mode:
+        logger.info(
+            "host data plane: %d parallel batch-build workers "
+            "(host_threads = %s; bounded ordered ring)",
+            host_workers, cfg.host_threads)
+    if multi_process and not s.stream_mode:
+        # Fixed-shape batches need one U for the whole job. Auto mode
+        # measures the data (probe is deterministic and identical on
+        # every process) instead of assuming the next_pow2(B*L) worst
+        # case — a ~50x smaller gather/scatter per step at Criteo-like
+        # density; denser-than-probed batches spill, never break.
+        # (Stream mode probes the discovered SEALED shards instead,
+        # chief-decided — data/stream.probe_stream_uniq_bucket.)
+        from fast_tffm_tpu.data.pipeline import probe_uniq_bucket
+        s.uniq_bucket = cfg.uniq_bucket or probe_uniq_bucket(
+            cfg, cfg.train_files)
+        logger.info("fixed unique-row bucket: %d", s.uniq_bucket)
+    if multi_process and cfg.validation_files:
+        from fast_tffm_tpu.data.pipeline import probe_uniq_bucket
+        s.val_bucket = cfg.uniq_bucket or probe_uniq_bucket(
+            cfg, cfg.validation_files)
+
+    # Vocabulary admission (README "Unbounded vocabulary";
+    # fast_tffm_tpu/vocab/): the runtime owns the sketch + slot map;
+    # the data plane builds batches in the hashed space and remaps
+    # through it; barriers run at the existing epoch/publish
+    # synchronization points.
+    if getattr(cfg, "vocab_mode", "fixed") == "admit":
+        if multi_process:
+            raise ValueError(
+                "vocab_mode = admit is single-process: the slot "
+                "map is host state, and lockstep workers would "
+                "need a chief-broadcast admission protocol to "
+                "agree on it (ROADMAP item 3's sharded-table "
+                "leg). Run admit-mode training on one process.")
+        from fast_tffm_tpu.vocab.table import VocabRuntime
+        s.vocab = VocabRuntime.from_config(cfg)
+        logger.info(
+            "vocab admission: %d physical rows (row 0 = shared "
+            "cold row) over a 2^30 hashed id space; admit/evict "
+            "threshold %.1f, decay %.2f/barrier, sketch %.1f MB",
+            cfg.vocabulary_size, cfg.vocab_admit_threshold,
+            cfg.vocab_decay, cfg.vocab_sketch_mb)
+
+    s.ckpt = CheckpointState(cfg.model_file,
+                             retry=RetryPolicy.from_config(cfg),
+                             verify=getattr(cfg, "ckpt_verify", "size"))
+    restored = s.restored = s.ckpt.restore(
+        template=checkpoint_template(cfg, s.mesh, host=s.offload))
+    if restored is not None:
+        check_restored_vocab(cfg, restored)
+        s.restored_step = int(restored["step"])
+        s.restored_epoch = int(restored["epoch"])
+        logger.info("restored checkpoint at step %d", s.restored_step)
+    if s.vocab is not None and restored is not None:
+        payload = restored.get("vocab_admission")
+        if payload is None:
+            logger.warning(
+                "restored checkpoint at step %d carries no vocab "
+                "admission sidecar (a fixed-mode warm start, or a "
+                "lost/garbled sidecar): admission state starts "
+                "FRESH — previously admitted ids serve from the "
+                "cold row until they re-cross the threshold",
+                s.restored_step)
+            # The restored table still holds the LOST mapping's
+            # trained rows; fresh admission must not hand them to new
+            # owners (_train_session cold-starts them once the table
+            # is materialized).
+            s.vocab_fresh_over_restore = True
+        else:
+            s.vocab.load(cfg, payload)
+            logger.info(
+                "restored vocab admission state at step %d: %d "
+                "live rows", s.restored_step, s.vocab.live_rows)
+    elif restored is not None:
+        from fast_tffm_tpu.checkpoint import (
+            refuse_fixed_mode_admit_step)
+        refuse_fixed_mode_admit_step(
+            cfg, s.ckpt.directory, s.restored_step,
+            payload=restored.get("vocab_admission"))
+    s.start_epoch = resume_start_epoch(s.restored_epoch, cfg.epoch_num)
+    if s.start_epoch:
+        logger.info("resuming interrupted epoch schedule at epoch %d/%d",
+                    s.start_epoch, cfg.epoch_num)
+
+
+def _build_state_and_step(s: _Session):
+    """The train state (``table``, ``acc``; None when the offload
+    backend holds them), the compiled step for this dispatch path, the
+    ownership-ledger entries and the wire encoder. The benchmark
+    rebinds ``make_train_step`` / ``init_table`` on this module and
+    the sharded and packed builders on theirs, so every one of them is
+    looked up when this runs (benchmarks/drivers/train.py,
+    STEP_SEAMS)."""
+    cfg, logger, spec, restored = s.cfg, s.logger, s.spec, s.restored
+    if s.offload:
+        # Offload backend (lookup.py; BASELINE config #5): the table/
+        # accumulator live outside HBM. make_offload_backend picks the
+        # in-jit pinned-host implementation (whole step stays in the
+        # async dispatch stream) where the backend compiles it, else the
+        # numpy fallback with its inherent per-step gradient fetch.
+        from fast_tffm_tpu.lookup import (PinnedHostLookup,
+                                          make_offload_backend,
+                                          make_offload_train_step)
+        lk = s.lk = make_offload_backend(cfg, cfg.seed, restored=restored)
+        if restored is not None:
+            # The backend adopted the arrays (numpy backend: zero-copy)
+            # or copied them into accelerator-host memory (pinned
+            # backend); keeping these references for the rest of
+            # train() would pin a SECOND full table+accumulator in
+            # local RAM for the whole resumed run — a sustained 2x that
+            # is an OOM at config-#5 scale (the same concern
+            # HostOffloadLookup.load documents for transient copies).
+            restored["table"] = restored["acc"] = None
+        kind = (f"pinned-host in-jit ({lk.mode})"
+                if isinstance(lk, PinnedHostLookup) else "host-numpy")
+        logger.info("offload lookup [%s]: table [%d, %d] outside HBM "
+                    "(%.2f GB + accumulator)", kind, lk.rows, lk.dim,
+                    lk.rows * lk.dim * 4 / 2**30)
+        offload_step = make_offload_train_step(spec, lk,
+                                               cfg.learning_rate)
+        table = acc = None
+
+        def step_fn(_t, _a, labels, weights, uniq_ids, local_idx, vals,
+                    fields=None):
+            loss, scores = offload_step(labels, weights, uniq_ids,
+                                        local_idx, vals, fields)
+            return None, None, loss, scores
+    elif s.mesh is not None:
+        from fast_tffm_tpu.parallel.sharded import (
+            init_sharded_state, make_sharded_train_step)
+        if restored is not None:
+            # The sharded template already placed these row-sharded on
+            # this mesh in the runtime [ckpt_rows, D] layout — use as-is.
+            table, acc = restored["table"], restored["acc"]
+        else:
+            table, acc = init_sharded_state(cfg, s.mesh, cfg.seed)
+        step_fn = make_sharded_train_step(spec, s.mesh)
+        # Logged once the state exists, so the line can say where
+        # it landed: a row-sharded table shows near-equal bytes on
+        # every local device, one that fell onto the first chip
+        # does not (chip_smoke.py fails past 1.5x).
+        jax.block_until_ready((table, acc))
+        logger.info(
+            "mesh training: %s over %d devices, %d processes; "
+            "bytes in use per local device: %s",
+            dict(s.mesh.shape), jax.device_count(),
+            jax.process_count(), local_bytes_in_use() or "unmeasured")
+    else:
+        if restored is not None:
+            table = restored["table"][:cfg.num_rows]
+            acc = restored["acc"][:cfg.num_rows]
+            # The slices above are NEW device buffers; drop the full
+            # [ckpt_rows, D] restored arrays so they free once the
+            # slice completes — holding them for the whole run is a
+            # sustained ~2x HBM cost that only bites on resume.
+            restored["table"] = restored["acc"] = None
+        else:
+            table = init_table(cfg, cfg.seed)
+            acc = init_accumulator(cfg)
+        step_fn = make_train_step(spec)
+    s.step_fn = step_fn
+
+    # Ownership ledger (obs/memory.py; README "Memory observability"):
+    # the session's long-lived allocations register with their owner
+    # tag so every flush carries mem/* gauges and an OOM names which
+    # owner grew. .nbytes is host metadata — no fetch. Offload state is
+    # host-resident by construction (host=True: gauged, excluded from
+    # the device live total). Released in the session's finally.
+    if s.offload:
+        LEDGER.register("offload_table",
+                        table_bytes(rows=lk.rows, dim=lk.dim),
+                        host=True)
+        LEDGER.register("offload_acc",
+                        table_bytes(rows=lk.rows, dim=lk.dim),
+                        host=True)
+    else:
+        # One device's share: the ledger's live total stands beside
+        # ONE device's capacity (pressure alarm, mem/utilization).
+        LEDGER.register("table", table.nbytes // s.mesh_devices)
+        LEDGER.register("adagrad_acc", acc.nbytes // s.mesh_devices)
+
+    # Wire format (README "Wire format"; wire.py): resolve the knobs
+    # for THIS dispatch path, build the one encoder every step ships
+    # through, and pre-build the packed step when active. Staging (the
+    # explicit async device_put double buffer) applies on the plain
+    # single-device jit path only — mesh/lockstep placement and the
+    # offload host gather have their own protocols.
+    from fast_tffm_tpu.wire import WireEncoder, resolve_wire
+    wire_spec = resolve_wire(cfg, mesh=s.mesh, backend=s.lk,
+                             multi_process=s.multi_process, train=True)
+    s.wire_enc = WireEncoder(wire_spec, pad_id=cfg.pad_id)
+    if wire_spec.packed:
+        from fast_tffm_tpu.models.fm import make_packed_train_step
+        s.packed_step = make_packed_train_step(spec)
+        logger.info(
+            "wire format: %s (flat CSR + on-device unpack, "
+            "double-buffered H2D)", wire_spec.describe())
+    tel = s.tel
+    if tel is not None:
+        # The active wire mode, as gauges — fmstat's transfer-bound
+        # attribution names it beside the bytes-per-example row.
+        tel.set("wire/packed", 1.0 if wire_spec.packed else 0.0)
+        tel.set("wire/narrow", 1.0 if wire_spec.narrow else 0.0)
+        # What only a sync point or an epoch barrier feeds starts
+        # at 0: a reader that differences two snapshots of the
+        # stream must find "none yet" as 0, not as absent.
+        # So does what only a re-laid state feeds (models/fm.py,
+        # TrainStep): an FM run's answer is 0, not silence.
+        for name in ("train/epochs", "train/epoch_barrier_seconds",
+                     "train/loss_sync_seconds",
+                     "train/state_relayouts"):
+            tel.count(name, 0)
+    return table, acc
+
+
+def _reset_rows(s: _Session, table, acc, rows):
+    """Cold-start ``rows`` through the backend's half of the slot seam
+    (lookup.reset_rows for offload state, the fixed-width compiled
+    scatter for device/mesh state — either way no per-count
+    recompiles); returns the state to go on with (the device path
+    donates and reassigns it)."""
+    if s.offload:
+        s.lk.reset_rows(rows, s.cfg.adagrad_init)
+        return table, acc
+    from fast_tffm_tpu.vocab.table import reset_table_rows
+    return reset_table_rows(table, acc, rows, s.cfg.pad_id,
+                            s.cfg.adagrad_init)
+
+
+def _arm_publish_gate(s: _Session) -> None:
+    """Per-publish quality loop + publish gate (README "SLOs & quality
+    gate"; obs/quality.py). When a stream run has a validation corpus,
+    every publish settle runs one validation sweep — AUC/loss/
+    calibration gauges ride the sweep's own score fetches (zero added
+    device traffic) — and the configured gate decides whether the
+    `published` pointer may move. Session-scoped (not the stream
+    loop's) because the EXIT publish after the final save is gated
+    too."""
+    cfg = s.cfg
+    from fast_tffm_tpu.obs.quality import PublishGate
+    gate = s.gate = PublishGate.from_config(cfg) if s.stream_mode else None
+    # "auto" opts in exactly when the run declared a quality objective
+    # (a gate knob, or slo_min_auc) — an existing stream config with
+    # validation_files must not silently start paying a validation
+    # sweep per publish on upgrade.
+    qmode = getattr(cfg, "publish_quality_eval", "auto")
+    s.quality_on = (s.stream_mode and bool(cfg.validation_files)
+                    and float(getattr(cfg, "publish_interval_seconds",
+                                      0.0)) > 0
+                    and (qmode == "on"
+                         or (qmode == "auto"
+                             and (gate is not None
+                                  or getattr(cfg, "slo_min_auc",
+                                             0.0) > 0))))
+    if gate is not None:
+        # The drop baseline survives restarts beside the pointer
+        # (checkpoint.GATE_BASELINE): a preempt-resume must not exempt
+        # its first publish from publish_max_auc_drop.
+        from fast_tffm_tpu.checkpoint import read_gate_baseline
+        gate.note_published(read_gate_baseline(s.ckpt.directory))
+        s.logger.info(
+            "publish gate armed: min AUC %s, max AUC drop %s%s "
+            "(validation sweep at every publish settle)",
+            cfg.publish_min_auc or "off",
+            cfg.publish_max_auc_drop or "off",
+            "" if gate.baseline is None
+            else f", restored baseline {gate.baseline:.6f}")
+
+
+def _gate_published(s: _Session, decision) -> None:
+    """Advance (and persist) the drop baseline after a publish actually
+    landed — the one baseline-write path for both the interval
+    publishes and the exit publish."""
+    gate = s.gate
+    if gate is None or decision is None:
+        return
+    gate.note_published(decision.get("auc"))
+    if gate.baseline is not None and jax.process_index() == 0:
+        from fast_tffm_tpu.checkpoint import write_gate_baseline
+        write_gate_baseline(s.ckpt.directory, gate.baseline)
+
+
+# Adaptive loss logging. float(loss) is a synchronous device->host
+# fetch: a mid-stream scalar fetch stalls async dispatch until the
+# device has caught up. On a direct-attached device the fetch itself
+# costs microseconds; over a slow or proxied link it can cost seconds
+# (copy_to_host_async is no better). So the link is measured once: if
+# the fetch is cheap, logging stays live (the normal-hardware
+# behavior); if not, loss values are buffered ON DEVICE (scalars) and
+# flushed at epoch boundaries — a natural barrier — with correct
+# per-step attribution.
+def _probe_link(cfg: FmConfig, logger) -> str:
+    """``"live"`` or ``"deferred"``. Probes the link BEFORE the hot
+    loop, with an empty dispatch queue: a mid-stream probe on a slow
+    link drains the queue through the slow path, where this costs one
+    clean round-trip."""
+    if cfg.log_steps <= 0:
+        return "deferred"  # mode never consulted without log lines
+    # fmlint: disable=R013 -- a one-scalar link-latency probe,
+    # not a batch: the wire encoder has nothing to encode here
+    probe = jax.device_put(np.float32(0.0))
+    jax.block_until_ready(probe)
+    float(probe)  # throwaway: lazy transfer-path init stays untimed
+    cost = float("inf")
+    for _ in range(3):  # min of 3: jitter must not misclassify
+        # fmlint: disable=R003 -- this IS the link probe's
+        # deliberate timer, before the hot loop starts
+        t0 = time.perf_counter()
+        # fmlint: disable=R001 -- this IS the link probe: one
+        # deliberate timed scalar fetch, before the hot loop starts
+        float(probe)
+        # fmlint: disable=R003 -- closes the probe sample
+        cost = min(cost, time.perf_counter() - t0)
+    if cost < LIVE_FETCH_BUDGET_S:
+        # Log the decision either way: a user wondering why loss
+        # lines are (or aren't) live gets the probe's answer.
+        logger.info("scalar fetch costs %.3f ms on this device link; "
+                    "loss log lines stay live", cost * 1e3)
+        return "live"
+    logger.info(
+        "scalar fetch costs %.0f ms on this device link; deferring "
+        "loss log lines to epoch boundaries to keep the dispatch "
+        "pipeline hot", cost * 1e3)
+    return "deferred"
+
+
+class StepLoop:
+    """The one step body both run modes drive, and the state a step
+    advances: the train state (``table``, ``acc``), ``global_step``,
+    the last ``loss``, the rate timer, the loss lines still owed, the
+    profiler window, and what a save has to record beside the arrays
+    (completed epochs, the stream watermark). A mode's loop
+    (``_run_epochs``, ``_run_stream``) fetches a batch, calls
+    ``step`` and does its own work around the call; a caller that
+    holds a ``_Session`` can drive ``step`` itself."""
+
+    def __init__(self, s: _Session, table, acc):
+        self.s = s
+        self.table, self.acc = table, acc
+        self.global_step = s.restored_step
+        # The profile window counts THIS run's steps (a resumed job
+        # would otherwise skip past the window silently).
+        self.run_start_step = self.global_step
+        self.profiling = False
+        self.timer = StepTimer()
+        self.loss = None
+        self.loss_val = float("nan")
+        self.stopping = False
+        # (auc, n) of the most recent validation pass of the table as
+        # it stands; a step clears it.
+        self.last_val = None
+        self.log_mode = "deferred"  # set from _probe_link before a step
+        self.log_buffer: list = []  # deferred: (step, epoch, loss_arr, eps)
+        self.live_line: list = []   # live: the one line whose sync is due
+        # Where the train/step_seconds sample of the next step starts;
+        # a mode's loop re-anchors it around its own pauses.
+        self.t_prev = time.perf_counter()
+        # The epoch barrier, held open across loop iterations
+        # (obs/trace.begin): the first dispatch to return ends it.
+        self.barrier = None
+        self.completed_epochs = s.start_epoch
+        self.last_periodic_save = (None, None)  # (step, epoch) of the latest
+        # Streaming run mode (README "Streaming / online learning"):
+        # the durable stream position adopted from STEPPED batches —
+        # what every checkpoint records beside the arrays, so restore
+        # resumes with no example duplicated or skipped. None in epoch
+        # mode (saves then carry no watermark sidecar).
+        self.stream_watermark = None
+        # Step-anatomy join keys (obs/anatomy.py; README "Step
+        # anatomy"): when on, the loops stamp the step id into the
+        # h2d/step/flags spans (so fmtrace --anatomy can join phases
+        # across ranks) and feed the host-side phase-seconds counters
+        # the anatomy/* gauges aggregate at barrier flushes.
+        self.anat = s.tel is not None and getattr(s.tel, "anatomy", False)
+        if s.mesh is not None:
+            from fast_tffm_tpu.parallel.sharded import (global_batch,
+                                                        shard_batch)
+            self._global_batch, self._shard_batch = global_batch, shard_batch
+
+    # -- one step ---------------------------------------------------
+
+    def place(self, batch, wb):
+        """This dispatch path's host-to-device placement of one
+        encoded batch (the offload step takes host arrays and never
+        gets here)."""
+        s = self.s
+        if s.multi_process:
+            # The global-array assembly ships every shard's bytes.
+            return self._global_batch(s.mesh, len(batch.uniq_ids),
+                                      **wb.args)
+        if s.mesh is not None:
+            return self._shard_batch(s.mesh, **wb.args)
+        # Plain single-device jit, depth-2 double buffer: the
+        # explicit async put rides the copy stream while the
+        # PREVIOUS step is still executing, instead of serializing
+        # at the head of this step's dispatch.
+        return s.wire_enc.device_put(wb)
+
+    def wire_place(self, batch, step=0):
+        """Encode one batch and place its arrays for dispatch.
+        h2d_bytes = wb.wire_bytes sizes the arrays ACTUALLY shipped;
+        the padded-layout size rides on wb.logical_bytes for the
+        savings counter. ``step`` (anatomy on) rides the h2d span as
+        the cross-rank join key."""
+        with span("train/encode", seconds="train/encode_seconds"):
+            wb = self.s.wire_enc.encode_train(batch)
+        if self.s.offload:
+            return wb, wb.args
+        ids = {"step": step} if (self.anat and step) else {}
+        with span("train/h2d", seconds="train/h2d_seconds",
+                  bytes=wb.wire_bytes, **ids):
+            return wb, self.place(batch, wb)
+
+    def dispatch(self, wb, args, step):
+        """Dispatch one placed batch through the right compiled step,
+        as the ``train/step`` phase: jax dispatch is async (returns at
+        enqueue), so time spent HERE is queue backpressure — the
+        previous program still executing somewhere. Runs under
+        oom_guard: a RESOURCE_EXHAUSTED here re-raises with the
+        per-owner ledger attached (obs/memory.py). A live loss line
+        still owed (log_tick) is synced first."""
+        self.sync_live_line()
+        s = self.s
+        with span("train/step", seconds="train/dispatch_seconds",
+                  step=step):
+            with oom_guard("train/step"):
+                if s.multi_process:
+                    # The sharded step IS a collective program: on a
+                    # dead cluster its dispatch blocks inside the
+                    # program's collectives exactly like a host
+                    # allgather (pinned by the hang-worker chaos stack
+                    # dumps), so it runs under the same deadline guard.
+                    from fast_tffm_tpu.parallel.liveness import (
+                        guarded_collective)
+                    return guarded_collective(
+                        s.step_fn, self.table, self.acc,
+                        label="train/step_dispatch", **args)
+                if wb.packed:
+                    return s.packed_step(wb.L, self.table, self.acc,
+                                         **args)
+                return s.step_fn(self.table, self.acc, **args)
+
+    def step(self, batch, epoch: int, summaries=None, gauges=None):
+        """Train on one batch: the sequence every step of either run
+        mode goes through, in this order. ``summaries`` (the epoch
+        loop's TensorBoard writer) and ``gauges`` (a callable the
+        stream loop sets its freshness gauges with, just ahead of a
+        due telemetry flush) are the two things a mode hands in;
+        everything else a mode does, it does around this call."""
+        s = self.s
+        cfg, tel, vocab = s.cfg, s.tel, s.vocab
+        if vocab is not None:
+            # A publish barrier may have moved the slot map while this
+            # batch sat in the prefetch queue — redo its remap so it
+            # never scatters into rows the barrier evicted/reset/
+            # reassigned (one int compare when nothing moved; epoch
+            # barriers only run once the iterator is exhausted, so
+            # there it is insurance).
+            batch = vocab.ensure_current(batch)
+        wb, args = self.wire_place(batch, self.global_step + 1)
+        self.table, self.acc, self.loss, _ = self.dispatch(
+            wb, args, self.global_step + 1)
+        self.end_barrier()
+        self.global_step = step = self.global_step + 1
+        self.last_val = None  # table advanced; any cached AUC is stale
+        if vocab is not None:
+            # Adopt-on-step, like the stream watermark: the sketch
+            # advances only for TRAINED batches, so the checkpointed
+            # admission state and the stream position describe the
+            # same prefix.
+            vocab.note_trained(batch)
+        # Log-line rate: the job-global estimate (x P assumes
+        # symmetric shards — exact under line sharding, an estimate
+        # under whole-file stream ownership). The COUNTER is this
+        # worker's OWN real examples: shard files merge by sum, so
+        # anything else would inflate the exactly-once accounting
+        # P-fold (and whole-file ownership pays fillers as phantom
+        # examples).
+        n_global = batch.num_real * (jax.process_count()
+                                     if s.multi_process else 1)
+        self.timer.tick(n_global)
+        if tel is not None:
+            # Wall time since the previous step's bookkeeping —
+            # dispatch-loop time, never a device sync. Re-anchored per
+            # epoch so validation/pause gaps stay out of the histogram
+            # (they have their own counters).
+            # fmlint: disable=R003 -- feeds the train/step_seconds
+            # histogram (always-on aggregate; the train/step span is
+            # the timeline view)
+            now = time.perf_counter()
+            tel.train_step(now - self.t_prev, batch.num_real,
+                           wb.wire_bytes, wb.logical_bytes)
+            self.t_prev = now
+            # Watchdog progress beat: one tuple assignment
+            # (obs/health.py) — the stall detector's only hot-path
+            # cost.
+            tel.heartbeat(step)
+        self.profile_tick(step)
+        log_due = cfg.log_steps and step % cfg.log_steps == 0
+        sum_due = (summaries is not None
+                   and step % cfg.save_summaries_steps == 0)
+        tel_due = tel is not None and tel.flush_due(step)
+        # One windowed-rate read per step: the read consumes the
+        # window, so the log line, the summary, and the metrics gauge
+        # all share it.
+        eps_now = (self.timer.consume_window_rate()
+                   if (log_due or sum_due or tel_due) else None)
+        if log_due:
+            self.log_tick(step, epoch, self.loss, eps_now)
+        if sum_due:
+            summaries.add("train/loss", step, self.loss)
+            summaries.add("train/examples_per_sec", step, eps_now)
+        if tel_due:
+            # loss is a DEVICE scalar: buffered, fetched only at the
+            # next barrier flush (sink link-safety contract).
+            tel.add_scalar("train/loss", step, self.loss)
+            tel.set("train/examples_per_sec_window", eps_now)
+            if gauges is not None:
+                gauges()
+            with span("obs/flush", seconds="obs/flush_seconds"):
+                tel.maybe_flush(step)  # file I/O only
+
+    def end_barrier(self) -> None:
+        if self.barrier is not None:
+            self.barrier.end()
+            self.barrier = None
+
+    def profile_tick(self, step_done: int) -> None:
+        cfg = self.s.cfg
+        if not cfg.profile_dir or jax.process_index() != 0:
+            return
+        step_done -= self.run_start_step
+        if (not self.profiling and step_done >= cfg.profile_start_step
+                and step_done < cfg.profile_start_step
+                + cfg.profile_num_steps):
+            jax.profiler.start_trace(cfg.profile_dir)
+            self.profiling = True
+        elif self.profiling and step_done >= (cfg.profile_start_step
+                                              + cfg.profile_num_steps):
+            if self.table is not None:
+                jax.block_until_ready(self.table)
+            jax.profiler.stop_trace()
+            self.profiling = False
+            self.s.logger.info("profiler trace written to %s",
+                               cfg.profile_dir)
+
+    # -- loss lines -------------------------------------------------
+
+    def log_line(self, step, epoch, val, eps) -> None:
+        self.loss_val = val
+        self.s.logger.info("step %d epoch %d loss %.6f examples/sec %.0f",
+                           step, epoch, val, eps)
+
+    def log_tick(self, step, epoch, loss_arr, eps) -> None:
+        if self.log_mode == "deferred":
+            self.log_buffer.append((step, epoch, loss_arr, eps))
+            # Bound the buffer: log_steps=1 on a months-long epoch must
+            # not retain unbounded device scalars; one rare mid-epoch
+            # sync is the lesser evil.
+            if len(self.log_buffer) >= LOG_BUFFER_MAX:
+                self.flush_log()
+            return
+        # Live: the sync is taken at the NEXT dispatch, once the next
+        # batch is fetched and placed: the device then waits for the
+        # host one dispatch after each loss line, and not a placement
+        # too (13 ms of every eight steps on the four-chip mesh).
+        self.sync_live_line()  # never two owed
+        self.live_line.append((step, epoch, loss_arr, eps))
+
+    def sync_live_line(self) -> None:
+        """The loop's sync point: the host waits here until the device
+        has caught up, so this phase's share of the wall says how far
+        the device sets the pace. The line itself is written outside
+        the phase."""
+        if not self.live_line:
+            return
+        step, epoch, loss_arr, eps = self.live_line.pop()
+        with span("train/loss_sync", seconds="train/loss_sync_seconds"):
+            val = float(loss_arr)
+        self.log_line(step, epoch, val, eps)
+
+    def flush_log(self) -> None:
+        self.sync_live_line()
+        if not self.log_buffer:
+            return
+        # bulk_fetch stacks the same-shaped scalars into ONE transfer:
+        # deferred mode is only ever active on a slow device link,
+        # where a per-element list fetch costs ~200 ms EACH
+        # (utils/fetch.py) — a full 1024-entry buffer would stall for
+        # minutes.
+        lines: list = []
+        with span("train/loss_sync", seconds="train/loss_sync_seconds"):
+            bulk_fetch([(arr, (step, epoch, eps))
+                        for step, epoch, arr, eps in self.log_buffer],
+                       lambda v, m: lines.append(
+                           (m[0], m[1], float(v), m[2])))
+        for line in lines:
+            self.log_line(*line)
+        self.log_buffer.clear()
+
+    # -- what a barrier or a save needs of the state ------------------
+
+    def note_preempted(self, epoch: int, doing: str, signals=None) -> None:
+        """A drained preemption flag: stop after this boundary, and say
+        so in the log and as the distinct health event fmstat reports
+        as PREEMPTED instead of conflating a clean preemption exit
+        with a crash (obs/attribution health_verdict)."""
+        self.stopping = True
+        self.s.logger.info("preemption signalled; %s", doing)
+        if self.s.tel is not None:
+            event = {"status": "preempted", "step": self.global_step,
+                     "epoch": epoch}
+            if signals is not None:
+                event["signals"] = signals
+            self.s.tel.sink.emit("health", event)
+
+    def vocab_reset(self, rows) -> None:
+        """The eviction hook."""
+        self.table, self.acc = _reset_rows(self.s, self.table, self.acc,
+                                           rows)
+
+    def vocab_barrier(self, where: str) -> None:
+        s = self.s
+        if s.vocab is None:
+            return
+        st = s.vocab.barrier(self.vocab_reset)
+        s.logger.info(
+            "vocab barrier (%s): +%d admitted, -%d evicted, %d/%d "
+            "live rows", where, st["admitted"], st["evicted"],
+            st["live"], s.cfg.vocabulary_size - 1)
+
+    def stream_state(self):
+        """The watermark payload a save should carry right now: merged
+        across workers at this lockstep point (a collective when
+        multi-process — callers must invoke it at step-deterministic
+        points only)."""
+        s = self.s
+        if not s.stream_mode:
+            return None
+        from fast_tffm_tpu.data.stream import exchange_watermarks
+        wm = self.stream_watermark or {"format": 1, "files": []}
+        return (exchange_watermarks(wm, s.num_shards)
+                if s.multi_process else wm)
+
+    def save(self, epoch: int, wait: bool, force: bool = False,
+             rewrite_stale_metadata: bool = False) -> None:
+        """Checkpoint the state as it stands at ``global_step``, with
+        the watermark and admission sidecars that describe the same
+        prefix. Device arrays save async unless ``wait`` (orbax
+        D2H-snapshots synchronously, writes in background — the loop
+        doesn't stall for serialization)."""
+        s = self.s
+        state = (s.lk.state() if s.offload
+                 else ckpt_state(s.cfg, self.table, self.acc))
+        s.ckpt.save(self.global_step, *state,
+                    vocabulary_size=s.cfg.vocabulary_size, force=force,
+                    wait=wait, epoch=epoch,
+                    rewrite_stale_metadata=rewrite_stale_metadata,
+                    stream_state=self.stream_state(),
+                    vocab_state=(s.vocab.state_payload()
+                                 if s.vocab is not None else None))
+        self.last_periodic_save = (self.global_step, epoch)
+
+
+def _publish_decision(s: _Session, loop: StepLoop) -> Optional[dict]:
+    """Quality sweep + gate decision for the publish about to happen;
+    None when no quality loop is configured (publish unconditionally).
+    Rides the publish settle point the caller already synchronized at;
+    multi-host safe: the sweep merge is collective and the chief's
+    decision is broadcast (obs/quality.PublishGate docstring), so all
+    workers skip or run the save/publish that follows together."""
+    if not s.quality_on:
+        return None
+    from fast_tffm_tpu.obs.quality import (QualityStats, emit_gate_held,
+                                           emit_quality)
+    cfg, tel, logger, gate = s.cfg, s.tel, s.logger, s.gate
+    global_step = loop.global_step
+    stats = QualityStats(cfg.loss_type)
+    # Chief-only counter, like emit_quality below: per-worker shard
+    # counters merge by SUM in fmstat.
+    with span("quality/eval", leaf=False, step=global_step,
+              seconds=("quality/eval_seconds"
+                       if jax.process_index() == 0 else None)):
+        # preempt: a SIGTERM mid-sweep stops ALL workers at the same
+        # window boundary instead of finishing the full validation
+        # pass inside the kill grace window.
+        auc, n = s.validate(loop.table, collect=stats,
+                            preempt=lambda: bool(s.preempted))
+    if jax.process_index() == 0:
+        # Chief-only: n and the merged stats are already job-global,
+        # and per-worker shard counters merge by SUM in fmstat — every
+        # worker emitting would inflate quality/evals and
+        # quality/examples by P.
+        emit_quality(tel, global_step, float(auc), stats, n)
+    if tel is not None:
+        tel.heartbeat()  # a long sweep is progress, not a stall
+    if jax.process_index() == 0:
+        logger.info(
+            "publish quality eval at step %d: AUC %.6f, loss "
+            "%s, calibration %s over %d examples",
+            global_step, auc,
+            "-" if stats.loss is None else f"{stats.loss:.6f}",
+            "-" if stats.calibration is None
+            else f"{stats.calibration:.4f}", n)
+    if gate is None:
+        return {"held": False, "auc": float(auc), "examples": int(n)}
+    # Chief decides, broadcast: identity single-process; every worker
+    # applies the byte-identical decision.
+    from fast_tffm_tpu.data.stream import broadcast_blob
+    decision = broadcast_blob(gate.decide(float(auc), global_step),
+                              "quality/gate_decision")
+    # n is already job-global (the sweep merge), so adding it after
+    # the broadcast stays identical on every worker.
+    decision["examples"] = int(n)
+    if decision["held"]:
+        if jax.process_index() == 0:
+            # Chief-only, like emit_quality: one hold must count once,
+            # not once per worker shard.
+            emit_gate_held(tel, decision)
+        logger.warning(
+            "publish GATE HELD at step %d: %s — the published "
+            "pointer stays on the last passing step",
+            global_step, "; ".join(decision["reasons"]))
+    return decision
+
+
+def _agreed_batch(s: _Session, loop: StepLoop, batch, epoch: int):
+    """What the epoch loop trains on next, or None when the epoch is
+    over for this process and — in multi-process mode — for every
+    other one too.
+
+    Lockstep: line-index sharding can give processes batch counts
+    differing by one; every step is a collective program, so a process
+    that stepped alone would hang the cluster. Agree on exhaustion/
+    preemption each step (tiny host allgather) and feed all-padding
+    filler batches (zero weight -> zero loss/grad) until everyone is
+    done. The deadline guard bounds the wait: a dead peer raises
+    WorkerLostError naming it instead of parking the survivors here
+    forever (parallel/liveness.py)."""
+    if not s.multi_process:
+        if s.preempted:
+            # fmlint: disable=R001 -- preempted holds host signal
+            # numbers from the handler, never device arrays
+            loop.note_preempted(epoch, "saving and exiting",
+                                signals=[int(x) for x in s.preempted])
+            return None
+        return batch
+    from jax.experimental import multihost_utils
+    from fast_tffm_tpu.parallel.liveness import guarded_collective
+    # The epoch loop's rank barrier (anatomy flags-wait phase; span
+    # step id = cross-rank join key). On CPU+gloo this wait also
+    # absorbs the PREVIOUS step's still-executing program — allgather
+    # blocks behind queued device work — which is exactly what the
+    # anatomy report names.
+    ids = {"step": loop.global_step + 1} if loop.anat else {}
+    with span("train/step_flags", seconds="train/step_flags_seconds",
+              **ids):
+        flags = guarded_collective(
+            multihost_utils.process_allgather,
+            np.asarray([batch is None, bool(s.preempted)]),
+            label="train/step_flags")
+    if bool(flags[..., 1].any()):
+        loop.note_preempted(epoch, "saving and exiting")
+        return None
+    if bool(flags[..., 0].all()):
+        return None
+    if batch is None:
+        from fast_tffm_tpu.data.pipeline import empty_batch
+        batch = empty_batch(s.cfg, uniq_bucket=s.uniq_bucket)
+    return batch
+
+
+def _run_epochs(s: _Session, loop: StepLoop) -> None:
+    """``run_mode = epochs``: ``epoch_num`` passes over ``train_files``
+    from where the restored schedule stands, each ending in the epoch
+    barrier (``_epoch_barrier``)."""
+    cfg = s.cfg
+    for epoch in range(s.start_epoch, cfg.epoch_num):
+        if loop.stopping:
+            break
+        epoch_stats = SpillStats()
+        # Building the input pipeline until its first batch is out
+        # (ended after the epoch's first next(); again by the finally,
+        # for whatever an exception left open).
+        starting = begin("pipeline/start",
+                         seconds="pipeline/start_seconds")
+        try:
+            it = prefetch(batch_iterator(
+                cfg, cfg.train_files, training=True,
+                weight_files=cfg.weight_files,
+                shard_index=s.shard_index, num_shards=s.num_shards,
+                epochs=1, seed=cfg.seed + epoch,
+                fixed_shape=s.multi_process, uniq_bucket=s.uniq_bucket,
+                stats=epoch_stats, raw_ids=s.raw_mode,
+                bad_lines=s.bad_tracker, vocab=s.vocab),
+                depth=cfg.prefetch_depth,
+                gil_bound=gil_bound_iteration(cfg, cfg.weight_files))
+            # fmlint: disable=R003 -- anchors the per-epoch
+            # step-seconds window (always-on aggregate)
+            loop.t_prev = time.perf_counter()
+            while True:
+                # Consumer-side stall: time blocked INSIDE next() only —
+                # bracketing it any wider would fold end-of-step
+                # bookkeeping (notably live-mode's deliberate
+                # float(loss) device sync) into the host-bound signal
+                # and misdiagnose a device-bound run (the producer-side
+                # build cost is timed separately in
+                # pipeline.batch_iterator on the worker thread).
+                with span("train/input_wait",
+                          seconds="train/input_wait_seconds"):
+                    batch = next(it, None)
+                starting.end()
+                batch = _agreed_batch(s, loop, batch, epoch)
+                # fmlint: disable=R014 -- _agreed_batch returns None
+                # on every process together in multi-process mode (it
+                # agrees on exhaustion and preemption through the
+                # train/step_flags allgather first); single-process,
+                # the loop's collectives are gated on multi_process, so
+                # this escape leaves no peer unmatched
+                if batch is None:
+                    break
+                loop.step(batch, epoch, summaries=s.summaries)
+                if cfg.save_steps and loop.global_step % cfg.save_steps == 0:
+                    with span("train/checkpoint_pause",
+                              seconds="train/checkpoint_pause_seconds"
+                              ) as pause:
+                        # Host-offload state: wait, because the
+                        # background writer would race the in-place
+                        # numpy Adagrad updates.
+                        loop.save(loop.completed_epochs, wait=s.offload)
+                    if s.tel is not None:
+                        loop.t_prev += pause.dur  # keep the pause out
+                        # of the next step's step_seconds sample
+        finally:
+            starting.end()
+        _epoch_barrier(s, loop, epoch, epoch_stats)
+
+
+def _epoch_barrier(s: _Session, loop: StepLoop, epoch: int,
+                   epoch_stats) -> None:
+    """What runs between an epoch's last step and the next epoch's
+    first: the owed loss lines, the input's spill report and the
+    bucket it adapts, the vocab barrier, validation, the summary and
+    telemetry flushes, and the grow barrier."""
+    cfg, logger, tel = s.cfg, s.logger, s.tel
+    stopping = loop.stopping
+    loop.sync_live_line()  # the epoch's last line, ahead of the barrier
+    if not stopping:
+        # The epoch barrier: from the iterator's exhaustion until the
+        # NEXT epoch's first dispatch returns (or the loop's end) —
+        # telemetry flush, validation, the cold input pipeline: what
+        # the steady step rate leaves out.
+        loop.barrier = begin("train/epoch_barrier",
+                             seconds="train/epoch_barrier_seconds")
+    loop.flush_log()  # deferred loss lines land at the epoch barrier
+    if s.bad_tracker is not None and s.bad_tracker.bad:
+        # Cumulative run-level view: the breaker and quarantine are
+        # run-scoped, so the log line is too.
+        logger.info("bad-line policy through epoch %d: %s",
+                    epoch, s.bad_tracker.describe())
+    if epoch_stats.spilled_batches or (s.multi_process
+                                       and epoch_stats.batches):
+        # Spill visibility (fixed-U mode): a probe-missed dense
+        # stretch degrades fill silently otherwise.
+        logger.info("epoch %d input: %s", epoch, epoch_stats.describe())
+        if epoch_stats.spill_fraction > SPILL_WARN_FRACTION:
+            logger.warning(
+                "uniq_bucket %d is undersized for this data: "
+                "%.0f%% of batches closed early on the "
+                "unique-row budget; raise uniq_bucket (or set 0 "
+                "to re-probe) to recover effective batch size",
+                s.uniq_bucket, 100 * epoch_stats.spill_fraction)
+    if s.multi_process and not stopping and epoch + 1 < cfg.epoch_num:
+        # Adaptive bucket: a probe-missed dense stretch spills every
+        # epoch otherwise. The job-wide spill fraction is allgathered
+        # (per-process stats see only their own shard — a local
+        # decision would desynchronize shapes and deadlock the
+        # collective program), and every process applies the same
+        # doubling.
+        from jax.experimental import multihost_utils
+        from fast_tffm_tpu.parallel.liveness import guarded_collective
+        tot = guarded_collective(
+            multihost_utils.process_allgather,
+            np.asarray(
+                [epoch_stats.spilled_batches, epoch_stats.batches,
+                 epoch_stats.max_uniq]),
+            label="train/spill_stats")
+        tot = tot.reshape(-1, 3)
+        # fmlint: disable=R001 -- tot is the HOST numpy result
+        # of process_allgather; these ints never touch a device
+        s.uniq_bucket = adapt_uniq_bucket(
+            cfg, s.uniq_bucket, int(tot[:, 0].sum()),
+            int(tot[:, 1].sum()), logger,
+            max_uniq=int(tot[:, 2].max()))
+    if not stopping:
+        # The epoch boundary IS a vocab barrier point: the epoch's
+        # observations admit/evict here, so the next epoch (and the
+        # validation sweep just below) runs against the refreshed map
+        # + reset rows.
+        loop.vocab_barrier(f"epoch {epoch}")
+    if cfg.validation_files and not stopping:
+        with span("train/validation", leaf=False,
+                  seconds="train/validation_seconds", epoch=epoch):
+            # A preempted sweep stops on every worker together; the
+            # step loop then drains the flag and all workers save
+            # together.
+            auc, n = s.validate(loop.table,
+                                preempt=lambda: bool(s.preempted))
+        loop.last_val = (auc, n)
+        if jax.process_index() == 0:
+            logger.info(
+                "epoch %d validation AUC %.6f over %d examples",
+                epoch, auc, n)
+        if s.summaries is not None:
+            s.summaries.add("validation/auc", loop.global_step, auc)
+        if tel is not None:
+            tel.set("validation/auc", auc)
+            # fmlint: disable=R001 -- auc is already a host
+            # python float from the streamed AUC merge
+            tel.add_scalar("validation/auc", loop.global_step,
+                           float(auc))
+    if s.summaries is not None:  # epoch barrier: bulk-fetch + write
+        with span("train/summary_flush",
+                  seconds="train/summary_pause_seconds"):
+            s.summaries.flush()
+    if tel is not None:
+        # Epoch barrier: the one point buffered device scalars
+        # are bulk-fetched and the JSONL reaches disk for sure.
+        tel.count("train/epochs")
+        tel.barrier_flush(loop.global_step)
+    if not stopping:  # a preemption-cut epoch is NOT completed
+        loop.completed_epochs = epoch + 1
+    if (s.grow_ctx is not None and not stopping
+            and loop.completed_epochs < cfg.epoch_num):
+        # The epoch boundary IS the grow barrier in epochs mode: every
+        # worker is synchronized here (the same point the vocab
+        # barrier uses), and the chief's admission plan is broadcast
+        # so everyone raises together or nobody does. The barrier
+        # state is saved durably FIRST (force rewrites a same-step
+        # periodic save with the completed epoch count) — it is
+        # exactly what the newcomer's verified restore comes up on.
+        # The last epoch never grows: the run is about to finish, and
+        # a reform would only delay its exit.
+        plan = s.grow_ctx.check_barrier()
+        if plan is not None:
+            loop.save(loop.completed_epochs, wait=True, force=True)
+            raise ClusterGrowth(plan)
+
+
+class _StreamClock:
+    """The stream run's publish clock and gate-hold state, beside the
+    tracker whose lag its gauges report."""
+
+    def __init__(self, s: _Session, tracker):
+        self.tel, self.tracker = s.tel, tracker
+        self.publish_every = float(
+            getattr(s.cfg, "publish_interval_seconds", 0.0))
+        self.last_publish = time.monotonic()
+        # The freshness gauge (and the STALE PUBLISH verdict) track
+        # the last SUCCESSFUL publish, separately from the attempt
+        # clock above: a gate that keeps holding advances the cadence
+        # but NOT the pointer — the age must keep growing so a long
+        # hold surfaces as STALE PUBLISH, the closed loop's designed
+        # failure signal.
+        self.last_publish_ok = time.monotonic()
+        # Whether the LAST gate decision held. While holding, the
+        # retention-pressure publish trigger (_publish_due) is
+        # disarmed: a republish attempt cannot succeed (the gate would
+        # hold the same regressed state again), so re-arming it would
+        # spin a full validation sweep per loop iteration for the
+        # whole hold. The interval arm keeps re-evaluating at the
+        # publish cadence — the bounded re-check that notices
+        # recovery.
+        self.gate_holding = False
+        # One retention-pause log per hold episode (_stream_step).
+        self.risk_pause_logged = False
+
+    def gauges(self) -> None:
+        """The stream's freshness gauges, as of now."""
+        tel = self.tel
+        if tel is None:
+            return
+        tel.set("stream/watermark_lag_seconds",
+                self.tracker.watermark_lag_seconds())
+        if self.publish_every > 0:
+            tel.set("stream/last_publish_age_seconds",
+                    time.monotonic() - self.last_publish_ok)
+
+
+def _publish_due(s: _Session, clock: _StreamClock) -> bool:
+    """Interval elapsed, OR retention pressure: periodic save_steps
+    saves must never GC the published step out from under a scorer
+    mid-interval — republishing first repoints at fresh state instead
+    of letting the pointer dangle. Chief-only in lockstep mode (the
+    decision rides the flags allgather)."""
+    if clock.publish_every <= 0:
+        return False
+    if time.monotonic() - clock.last_publish >= clock.publish_every:
+        return True
+    # Gated runs check one retention slot EARLY (margin=2): the very
+    # tick this arm triggers may turn out HELD, and a hold starting at
+    # the margin-1 boundary would leave the mandatory final/preemption
+    # save to evict the last-good step — the reserve the save pause
+    # depends on must exist BEFORE the hold begins.
+    return (bool(s.cfg.save_steps) and not clock.gate_holding
+            and s.ckpt.published_at_risk(
+                margin=2 if s.gate is not None else 1))
+
+
+def _stream_publish(s: _Session, loop: StepLoop,
+                    clock: _StreamClock) -> None:
+    """Quality eval + gate, then save + settle the manifest + verify +
+    atomically repoint the ``published`` pointer. A HELD decision
+    skips the save too: a held tick must not mint a new step —
+    retention (max_to_keep) could otherwise use held steps to lap the
+    published pointer, deleting the exact "last good triple" the gate
+    exists to keep serving. Lockstep-safe: the decision is
+    chief-broadcast, so every worker runs the save's commit barrier
+    (or skips it) together; only process 0 flips the pointer."""
+    with span("checkpoint/publish", leaf=False,
+              seconds="train/checkpoint_pause_seconds",
+              step=loop.global_step):
+        # Publish settle IS a vocab barrier point: the published
+        # (table, slot map, step) triple a scorer hot-reloads must be
+        # post-admission/eviction coherent — evicted rows reset BEFORE
+        # the save, so the published step serves evicted ids from the
+        # cold row, never stale embeddings. (It runs before the
+        # quality eval, so the sweep measures exactly the state a
+        # pass would publish.)
+        loop.vocab_barrier(f"publish step {loop.global_step}")
+        decision = _publish_decision(s, loop)
+        clock.gate_holding = bool(decision and decision.get("held"))
+        if not clock.gate_holding:
+            clock.risk_pause_logged = False
+        if decision is None or not decision.get("held"):
+            # force=True: a publish can land on the SAME step as the
+            # last periodic save, and the barrier above just moved the
+            # in-memory (table, slot map) pair — the benign same-step-
+            # collision skip would pair the old arrays with the new
+            # sidecar. Forcing rewrites both, so the published triple
+            # is coherent.
+            loop.save(0, wait=True, force=s.vocab is not None)
+            ok = s.ckpt.publish_step(loop.global_step) is not None
+            # Non-chief workers assume the chief's verify passed
+            # (publish_step is process-0-only; a verify failure is
+            # already counted and the decision stream stays
+            # chief-broadcast, so a rare divergent baseline here
+            # cannot diverge an outcome).
+            if ok or jax.process_index() != 0:
+                clock.last_publish_ok = time.monotonic()
+                _gate_published(s, decision)
+        # held: no save, no publish — and once the published step
+        # reaches the retention boundary, _stream_step pauses
+        # periodic saves too, so GC can never evict the last-good
+        # checkpoint mid-hold.
+    clock.last_publish = time.monotonic()
+    clock.gauges()
+    if s.grow_ctx is not None and not clock.gate_holding:
+        # The publish settle IS the grow barrier in stream mode (the
+        # same sync point the vocab barrier rides): the save above
+        # just landed with the merged watermark (wait=True), so a
+        # newcomer's verified restore resumes the stream exactly-once
+        # from this point. A HELD publish skipped the save — no
+        # durable barrier state, no admission; the chief-broadcast
+        # hold decision keeps every worker on the same arm.
+        plan = s.grow_ctx.check_barrier()
+        if plan is not None:
+            raise ClusterGrowth(plan)
+
+
+def _stream_step(s: _Session, loop: StepLoop, clock: _StreamClock,
+                 batch) -> None:
+    """One stream step and what the stream does around it: adopt the
+    batch's position, then the periodic save under the gate's
+    retention policy."""
+    cfg = s.cfg
+    loop.step(batch, 0, gauges=clock.gauges)
+    if batch.stream_pos is not None:
+        # The durable position advances ONLY with stepped batches
+        # (lockstep fillers carry None).
+        loop.stream_watermark = batch.stream_pos
+    if not (cfg.save_steps and loop.global_step % cfg.save_steps == 0):
+        return
+    # margin=2: stop one slot shy of the boundary so the mandatory
+    # final/preemption save can still land without evicting the
+    # last-good step.
+    if clock.gate_holding and s.ckpt.published_at_risk(margin=2):
+        # Retention pause: while the gate is HOLDING, a periodic save
+        # that would push the published (last-good) step past
+        # max_to_keep must not run — orbax's newest-N eviction has no
+        # pin, so minting the step would delete the exact checkpoint
+        # the fleet is serving from (published_at_risk's "the pointer
+        # never names a deleted step" contract). Durability pauses for
+        # the hold — progress since the last save is re-trained on a
+        # crash, exactly once via the watermark — and resumes when the
+        # gate passes (the publish repoints at fresh state, clearing
+        # the risk).
+        if not clock.risk_pause_logged:
+            clock.risk_pause_logged = True
+            s.logger.warning(
+                "publish gate holding with the published step at the "
+                "retention boundary: pausing periodic saves so GC "
+                "cannot evict the last-good checkpoint; heal the "
+                "input stream (or raise max_to_keep) to resume")
+        return
+    # Gated runs save SYNCHRONOUSLY: the retention math protecting the
+    # published step (the margin=2 risk arm + the hold pause above)
+    # reasons over COMMITTED step dirs — an async save's invisible
+    # in-flight step would let a hold latch with the window already
+    # full, and the mandatory final save would then evict the exact
+    # last-good checkpoint the gate pinned (caught by the
+    # retention-pause e2e test).
+    with span("train/checkpoint_pause",
+              seconds="train/checkpoint_pause_seconds") as pause:
+        loop.save(0, wait=s.offload or s.gate is not None)
+    if s.tel is not None:
+        loop.t_prev += pause.dur
+
+
+def _run_stream(s: _Session, loop: StepLoop) -> None:
+    """``run_mode = stream``, the indefinitely-surviving online loop:
+    poll the stream source, step every arriving batch, save with the
+    watermark, and publish a manifest-verified checkpoint every
+    ``publish_interval_seconds``. Single-process overlaps build and
+    compute through the prefetch thread; multi-worker runs the source
+    inline on this thread so its one discovery collective per
+    iteration stays aligned with the lockstep flags allgather and the
+    step program (collectives from two threads would interleave
+    nondeterministically across workers — the deadlock class the
+    window protocol exists to prevent)."""
+    from fast_tffm_tpu.data import stream as streamlib
+    cfg, logger, tel = s.cfg, s.logger, s.tel
+    multi_process = s.multi_process
+    restored_wm = (s.restored or {}).get("stream")
+    # Seed the adopted position from the restored sidecar: a recovered
+    # session (elastic shrink/grow, preempt-resume) saves at its
+    # restored step BEFORE any new batch steps — publish settles fire
+    # on idle ticks — and an empty in-memory watermark there would
+    # REWRITE the step's sidecar to empty, wiping the durable position
+    # and double-training the whole consumed prefix after the next
+    # restore (caught by the kill-then-grow soak).
+    loop.stream_watermark = restored_wm
+    if s.restored is not None and restored_wm is None:
+        logger.warning(
+            "restored checkpoint at step %d carries no stream "
+            "watermark (an epoch-mode warm start, or a lost "
+            "watermark sidecar): streaming starts from the "
+            "BEGINNING of %s — any stream bytes this model "
+            "already trained on will be trained again",
+            loop.global_step, cfg.stream_dir)
+    tracker = streamlib.StreamTracker(
+        cfg.stream_dir, cfg.stream_poll_seconds,
+        cfg.seal_policy, retry=RetryPolicy.from_config(cfg),
+        shard_index=s.shard_index, num_shards=s.num_shards,
+        bad_lines=s.bad_tracker, watermark=restored_wm,
+        lockstep=multi_process)
+    u_bucket = 0
+    if multi_process:
+        u_bucket = (cfg.uniq_bucket
+                    or streamlib.probe_stream_uniq_bucket(cfg, tracker))
+        logger.info("fixed unique-row bucket: %d", u_bucket)
+    workers = streamlib.stream_workers(cfg, fixed_shape=multi_process)
+    if workers > 1:
+        logger.info(
+            "stream host data plane: %d parallel batch-build "
+            "workers (host_threads = %s; sealed line groups "
+            "through the bounded ordered ring)",
+            workers, cfg.host_threads)
+    source = streamlib.StreamSource(
+        cfg, tracker,
+        stop=(None if multi_process else (lambda: bool(s.preempted))),
+        fixed_shape=multi_process, uniq_bucket=u_bucket,
+        raw_ids=s.raw_mode, workers=workers,
+        bad_lines=s.bad_tracker, vocab=s.vocab)
+    clock = _StreamClock(s, tracker)
+    if tel is not None:
+        tel.set("stream/publish_interval_seconds", clock.publish_every)
+    # fmlint: disable=R003 -- anchors the stream step-seconds
+    # window (always-on aggregate)
+    loop.t_prev = time.perf_counter()
+    try:
+        if multi_process:
+            _stream_lockstep(s, loop, clock, source, u_bucket)
+        else:
+            _stream_single(s, loop, clock, source)
+    finally:
+        source.close()
+    clock.gauges()  # the exit metrics snapshot carries the
+    # freshness gauges even when the run never hit a flush step
+    loop.flush_log()
+    if s.bad_tracker is not None and s.bad_tracker.bad:
+        logger.info("bad-line policy through the stream run: "
+                    "%s", s.bad_tracker.describe())
+    if source.stats.batches:
+        logger.info("stream input: %s", source.stats.describe())
+
+
+_STREAM_PREEMPTED = "saving the stream position and exiting"
+
+
+def _stream_lockstep(s: _Session, loop: StepLoop, clock: _StreamClock,
+                     source, u_bucket: int) -> None:
+    """The multi-worker stream loop: every iteration agrees, through
+    one flags allgather, on whether anyone has a batch, was signalled,
+    is done, or (the chief) is due a publish."""
+    from jax.experimental import multihost_utils
+    from fast_tffm_tpu.data import stream as streamlib
+    from fast_tffm_tpu.data.pipeline import empty_batch
+    from fast_tffm_tpu.parallel.liveness import guarded_collective
+    cfg, tel = s.cfg, s.tel
+    while True:
+        b = source.next_batch(block=False)
+        has = b not in (streamlib.IDLE, streamlib.DONE)
+        done = b is streamlib.DONE
+        pub_due = _publish_due(s, clock)
+        # The flags allgather is the stream loop's rank barrier: time
+        # parked here is waiting for the slowest peer (anatomy
+        # flags-wait phase; the span's step id is the cross-rank join
+        # key).
+        ids = {"step": loop.global_step + 1} if loop.anat else {}
+        with span("stream/step_flags",
+                  seconds="train/step_flags_seconds", **ids):
+            flags = np.asarray(guarded_collective(
+                multihost_utils.process_allgather,
+                np.asarray([has, bool(s.preempted), done, pub_due]),
+                label="stream/step_flags")).reshape(-1, 4)
+        if bool(flags[:, 1].any()):
+            loop.note_preempted(0, _STREAM_PREEMPTED)
+            break
+        if bool(flags[:, 2].all()) and not bool(flags[:, 0].any()):
+            break
+        if bool(flags[:, 0].any()):
+            batch = (b if has else empty_batch(cfg, uniq_bucket=u_bucket))
+            _stream_step(s, loop, clock, batch)
+        else:
+            if tel is not None:
+                tel.heartbeat()
+            clock.gauges()
+            time.sleep(min(cfg.stream_poll_seconds, 0.5))
+        if bool(flags[0, 3]):  # the CHIEF's clock
+            _stream_publish(s, loop, clock)
+
+
+def _stream_single(s: _Session, loop: StepLoop, clock: _StreamClock,
+                   source) -> None:
+    """The one-process stream loop. StreamPrefetcher, not
+    pipeline.prefetch: the driver must keep its publish clock and
+    preemption checks ticking while the stream idles — a blocking
+    queue get would starve publishing for as long as no batch
+    arrives."""
+    from fast_tffm_tpu.data import stream as streamlib
+    cfg, tel = s.cfg, s.tel
+    pf = streamlib.StreamPrefetcher(source, depth=cfg.prefetch_depth)
+    try:
+        while True:
+            if s.preempted:
+                loop.note_preempted(0, _STREAM_PREEMPTED)
+                break
+            batch = pf.get(timeout=min(cfg.stream_poll_seconds, 0.5))
+            # fmlint: disable=R007 -- single-process loop
+            # (_stream_lockstep is the multi-worker path): the step's
+            # collectives are themselves gated on multi_process, so no
+            # peer exists to diverge from; `batch` reads as
+            # rank-tainted only through the tracker's shard_index
+            # plumbing
+            # fmlint: disable=R014 -- same single-process
+            # justification: the loop's collectives are all gated on
+            # multi_process, so this escape leaves no peer's sequence
+            # unmatched
+            if batch is streamlib.DONE:
+                if s.preempted:
+                    loop.note_preempted(0, _STREAM_PREEMPTED)
+                break
+            # fmlint: disable=R007 -- same single-process
+            # justification as above
+            if batch is streamlib.IDLE:
+                if tel is not None:
+                    tel.heartbeat()
+                clock.gauges()
+            else:
+                _stream_step(s, loop, clock, batch)
+            if _publish_due(s, clock):
+                _stream_publish(s, loop, clock)
+    finally:
+        pf.close()
+
+
+def _finish(s: _Session, loop: StepLoop) -> None:
+    """After the last step of either mode: the final save, the exit
+    publish, a stream run's one validation pass, and the export."""
+    cfg, logger, tel = s.cfg, s.logger, s.tel
+    loop.end_barrier()
+    loop.flush_log()
+    if loop.loss is not None:
+        loop.loss_val = float(loop.loss)
+    # The final save IS a barrier point (vocab/table.py's contract):
+    # nothing is in flight here — the stream is drained or the epoch
+    # iterators exhausted — so the durable (table, slot map) pair
+    # admits the last interval's crossers and evicts/resets its cold
+    # rows before the bytes land (the exit publish below repoints at
+    # exactly this state). MUST run before the save captures the
+    # state: the row resets donate (and for the device path reassign)
+    # the table/acc buffers.
+    loop.vocab_barrier(f"final save step {loop.global_step}")
+    # Final/preemption save: barrier until durably written — the
+    # process may exit right after.
+    # If this step's existing checkpoint carries a stale epoch
+    # count — from THIS run's last periodic save, or from the
+    # RESTORED checkpoint when a resumed run advanced the schedule
+    # without a single global step (every shard's input empty —
+    # note a multi-process job with ANY data still advances
+    # global_step via lockstep fillers, so that case needs the
+    # whole job dry) — tell save() to correct it (an atomic epoch
+    # sidecar written by process 0; restore overlays it). Both
+    # signals are deterministic (lockstep-consistent state, not
+    # disk reads), so every process of a multi-host job agrees the
+    # correction exists — restore's process-0-read + broadcast does
+    # the rest.
+    stale = ((loop.last_periodic_save[0] == loop.global_step
+              and loop.last_periodic_save[1] != loop.completed_epochs)
+             or (s.restored is not None
+                 and loop.global_step == s.restored_step
+                 and loop.completed_epochs != s.restored_epoch))
+    loop.save(loop.completed_epochs, wait=True, force=True,
+              rewrite_stale_metadata=stale)
+    if s.stream_mode and getattr(cfg, "publish_interval_seconds",
+                                 0.0) > 0:
+        _exit_publish(s, loop)
+    if (s.stream_mode and not s.multi_process and cfg.validation_files
+            and not s.quality_on):
+        # Stream mode has no per-epoch sweeps; a configured
+        # validation corpus gets one final scored pass here
+        # (multi-process streams validate in _chief_finalize below;
+        # publishing streams already validated through the exit
+        # publish's quality sweep just above) — silently
+        # accepting-and-ignoring the knob would be a config trap.
+        auc, n = s.validate(loop.table)
+        logger.info("final validation AUC %.6f over %d examples",
+                    auc, n)
+        if tel is not None:
+            tel.set("validation/auc", auc)
+            # fmlint: disable=R001 -- auc is already a host float
+            # from the streamed AUC merge
+            tel.add_scalar("validation/auc", loop.global_step,
+                           float(auc))
+    if s.multi_process:
+        _chief_finalize(cfg, loop.table, logger, s.mesh, s.shard_index,
+                        s.num_shards, loop.last_val, s.val_bucket,
+                        s.bad_tracker)
+    else:
+        # Same size gate on EVERY dense-export path: a single-host
+        # mesh whose aggregate row-sharded table exceeds host RAM
+        # must not OOM assembling the .npz after a successful run.
+        nbytes = table_bytes(cfg)
+        if nbytes > EXPORT_NPZ_MAX_BYTES:
+            logger.info(
+                "skipping dense .npz export: table is "
+                "%.1f GB > %.1f GB threshold; use the checkpoint at "
+                "%s.ckpt", nbytes / 2**30,
+                EXPORT_NPZ_MAX_BYTES / 2**30, cfg.model_file)
+        else:
+            export_npz(s.lk.table if s.offload else loop.table,
+                       cfg.model_file + ".npz",
+                       vocabulary_size=cfg.vocabulary_size)
+
+
+def _exit_publish(s: _Session, loop: StepLoop) -> None:
+    """The exit publish: a clean STOP drain (or a preemption's durable
+    save) is the freshest verified state a scorer can hot-reload; the
+    final save already settled the manifest (wait=True). Gated like
+    every other publish — a run whose tail regressed quality must exit
+    with the pointer still on the last passing step (the final save
+    itself always lands: resume durability is not gated). A PREEMPTED
+    exit skips the quality sweep: the grace window between SIGTERM and
+    the orchestrator's SIGKILL has no budget for a validation pass,
+    and a mid-sweep kill would lose the publish entirely — so a
+    gate-less run publishes immediately (the historical behavior) and
+    a gated run leaves the pointer on the last step the gate actually
+    passed rather than publishing unevaluated state."""
+    if loop.stopping and s.gate is not None:
+        s.logger.info(
+            "preempted with a publish gate configured: exit "
+            "publish skipped (no quality sweep inside the "
+            "grace window); the pointer stays on the last "
+            "passing step")
+        return
+    decision = None if loop.stopping else _publish_decision(s, loop)
+    if decision is not None:
+        # The exit sweep IS this table's final validation:
+        # _chief_finalize (multi-process) must not re-run it.
+        loop.last_val = (decision["auc"], decision["examples"])
+    if decision is None or not decision.get("held"):
+        if s.ckpt.publish_step(loop.global_step) is not None:
+            # Persist the exit publish's baseline too — it is exactly
+            # what the NEXT run's gate must re-arm from.
+            _gate_published(s, decision)
+            if s.tel is not None:
+                s.tel.set("stream/last_publish_age_seconds", 0.0)
 
 
 # Above this, the dense .npz convenience export is skipped (the real
@@ -2385,76 +2432,3 @@ def _chief_finalize(cfg: FmConfig, table: jax.Array, logger, mesh,
                        vocabulary_size=cfg.vocabulary_size)
     guarded_collective(multihost_utils.sync_global_devices,
                        "fast_tffm_tpu_finalize", label="finalize/sync")
-
-
-def ckpt_state(cfg: FmConfig, table: jax.Array, acc: jax.Array):
-    """Checkpoint contract: always store [ckpt_rows, D] — the fixed
-    4096-aligned row layout (FmConfig.ckpt_rows) every topology shares,
-    so a checkpoint saved by any mesh restores row-sharded on any other
-    without assembling the table on one host. Mesh tables are already
-    this shape (orbax saves them sharded — each host writes only its
-    rows); single-device tables get the dead pad tail appended."""
-    n_pad = cfg.ckpt_rows - int(table.shape[0])
-    if n_pad == 0:
-        return table, acc
-    import jax.numpy as jnp
-    pad_t = jnp.zeros((n_pad, cfg.row_dim), jnp.float32)
-    pad_a = jnp.full((n_pad, cfg.row_dim), cfg.adagrad_init, jnp.float32)
-    return (jnp.concatenate([table, pad_t], axis=0),
-            jnp.concatenate([acc, pad_a], axis=0))
-
-
-def checkpoint_template(cfg: FmConfig, mesh=None, host: bool = False):
-    """Abstract pytree matching CheckpointState.save's layout — orbax
-    needs it to restore from a process that didn't do the saving.
-
-    The explicit sharding makes restore topology-portable: orbax places
-    the arrays per THIS run's layout instead of repopulating whatever
-    sharding the saving topology recorded (which, for a multi-host save
-    restored elsewhere, would yield non-addressable arrays).
-
-    ``host`` leaves the leaves sharding-free, which makes orbax restore
-    plain np.ndarrays into host RAM — the offload-backend path, where
-    the table must never land on a device."""
-    shape = (cfg.ckpt_rows, cfg.row_dim)
-    if host:
-        return {"table": jax.ShapeDtypeStruct(shape, np.float32),
-                "acc": jax.ShapeDtypeStruct(shape, np.float32),
-                "step": 0, "epoch": 0, "vocab": 0}
-    if mesh is not None:
-        from jax.sharding import NamedSharding
-        from fast_tffm_tpu.parallel.sharded import ROW_SPEC
-        sh = NamedSharding(mesh, ROW_SPEC)
-    else:
-        sh = jax.sharding.SingleDeviceSharding(jax.devices()[0])
-    return {"table": jax.ShapeDtypeStruct(shape, np.float32, sharding=sh),
-            "acc": jax.ShapeDtypeStruct(shape, np.float32, sharding=sh),
-            "step": 0, "epoch": 0, "vocab": 0}
-
-
-def resume_start_epoch(stored_epoch: int, epoch_num: int) -> int:
-    """Where a restarted run's epoch loop begins.
-
-    An INTERRUPTED schedule (0 < stored < epoch_num) resumes at the
-    first incomplete epoch — restarting from zero would revisit the
-    same data under the same per-epoch seeds and, under preemptions
-    recurring faster than a full schedule, never terminate. A COMPLETED
-    checkpoint (stored >= epoch_num, or a smaller epoch_num configured
-    since) keeps the reference's semantics: invoking train again runs a
-    fresh epoch_num-epoch schedule on top of the restored weights (the
-    reference's TF1 queue epoch counters were process-local and never
-    checkpointed, so it behaved exactly this way)."""
-    return stored_epoch if 0 < stored_epoch < epoch_num else 0
-
-
-def check_restored_vocab(cfg: FmConfig, restored) -> None:
-    """The 4096-aligned storage shape can't distinguish vocabularies in
-    the same bucket, so the stored vocab is verified explicitly — a
-    mismatch would silently turn a trained row into the pad row."""
-    v = int(restored["vocab"])
-    if v != cfg.vocabulary_size:
-        raise ValueError(
-            f"checkpoint was written with vocabulary_size={v}, but this "
-            f"config has vocabulary_size={cfg.vocabulary_size}; restoring "
-            "would misalign the pad row and feature ids. Retrain, or fix "
-            "the config.")

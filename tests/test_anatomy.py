@@ -351,42 +351,6 @@ def test_fmstat_renders_efficiency_section(tmp_path, capsys):
     assert "collective wait" in out
 
 
-# --------------------------------------------------- bench --compare
-
-def _run_compare(args):
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    return subprocess.run(
-        [sys.executable, "bench.py", "--compare"] + args,
-        cwd=repo, env=env, capture_output=True, text=True)
-
-
-@pytest.mark.slow
-def test_bench_compare_flags_regressions(tmp_path):
-    old = tmp_path / "old.json"
-    new = tmp_path / "new.json"
-    # Wrapper form: the parsed payload is the metric.
-    old.write_text(json.dumps({
-        "n": 1, "cmd": "python bench.py", "rc": 0,
-        "parsed": {"metric": "examples_per_sec", "value": 1000.0,
-                   "step_p50_ms": 10.0}}))
-    new.write_text(json.dumps({"metric": "examples_per_sec",
-                               "value": 990.0, "step_p50_ms": 10.5}))
-    r = _run_compare([str(old), str(new)])
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "no regressions" in r.stdout
-    # A 40% rate drop and a 2x latency blowup both trip the gate.
-    new.write_text(json.dumps({"metric": "examples_per_sec",
-                               "value": 600.0, "step_p50_ms": 25.0}))
-    r = _run_compare([str(old), str(new)])
-    assert r.returncode == 1
-    assert "REGRESSION" in r.stdout
-    assert "value" in r.stdout and "step_p50_ms" in r.stdout
-    # ...and a generous tolerance waves the same diff through.
-    r = _run_compare([str(old), str(new), "--tolerance", "0.1"])
-    assert r.returncode == 0
-
-
 # ------------------------------------------- real 2-process anatomy
 
 @pytest.mark.slow

@@ -217,6 +217,40 @@ def test_fallback_log_lines_carry_the_marks_chip_smoke_greps(
         assert sum(mark in m for m in loud) == 1, (mark, loud)
 
 
+@pytest.mark.parametrize("shards", [1, 4])
+def test_chip_smoke_reads_the_preflight_line_of_a_mesh_too(
+        monkeypatch, shards):
+    """The pre-flight's log line gained "per device, 1/4 of ..." on a
+    mesh (PR 27) and chip_smoke.py's reader did not follow: the
+    four-chip smoke failed with "logged no capacity pre-flight" until
+    PR 29 ran it again. Hold the reader to the line the program
+    writes, for one device and for a mesh."""
+    sys.path.insert(0, REPO)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(REPO)
+    from fast_tffm_tpu.config import FmConfig
+    from fast_tffm_tpu.obs import memory
+    monkeypatch.setenv(memory.FAKE_CAPACITY_ENV, str(1 << 30))
+    seen = _Records()
+    logger = logging.getLogger("fast_tffm_tpu")
+    logger.addHandler(seen)
+    try:
+        memory.preflight_capacity(
+            FmConfig(vocabulary_size=4096, factor_num=8), "train",
+            shards=shards)
+    finally:
+        logger.removeHandler(seen)
+    lines = [r.getMessage() for r in seen.records]
+    found = [chip_smoke.PREFLIGHT_LINE.search(m) for m in lines]
+    found = [m for m in found if m]
+    assert len(found) == 1, lines
+    assert ("per device" in found[0].group(0)) == (shards > 1)
+    assert int(found[0].group(1)) > 0
+    assert int(found[0].group(2)) == 1 << 30
+
+
 def _smoke(cwd, script):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     return subprocess.run([sys.executable, script], cwd=cwd, env=env,

@@ -8,7 +8,8 @@ import numpy as np
 from fast_tffm_tpu.checkpoint import CheckpointState
 from fast_tffm_tpu.config import FmConfig
 from fast_tffm_tpu.models.fm import init_accumulator, init_table
-from fast_tffm_tpu.train import checkpoint_template, ckpt_state, train
+from fast_tffm_tpu.checkpoint import checkpoint_template, ckpt_state
+from fast_tffm_tpu.train import train
 
 
 def test_async_save_returns_before_commit_and_restores(tmp_path):

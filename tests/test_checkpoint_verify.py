@@ -18,7 +18,7 @@ from fast_tffm_tpu.checkpoint import (CheckpointState, QUARANTINE_PREFIX,
                                       write_manifest)
 from fast_tffm_tpu.config import FmConfig
 from fast_tffm_tpu.models.fm import init_accumulator, init_table
-from fast_tffm_tpu.train import checkpoint_template, ckpt_state
+from fast_tffm_tpu.checkpoint import checkpoint_template, ckpt_state
 from tests.orbax_caps import orbax_supports_partial_restore
 
 

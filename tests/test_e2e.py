@@ -116,7 +116,7 @@ def test_interrupted_epoch_schedule_resumes(workdir):
     # Run the full schedule once, then rewrite the final checkpoint's
     # metadata to look like a preemption cut it at 5 completed epochs.
     assert run_tffm.main(["train", str(cfg_path)]) == 0
-    from fast_tffm_tpu.train import checkpoint_template
+    from fast_tffm_tpu.checkpoint import checkpoint_template
     ckpt = CheckpointState(cfg.model_file)
     restored = ckpt.restore(template=checkpoint_template(cfg))
     steps_full = int(restored["step"])

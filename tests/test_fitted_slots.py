@@ -89,7 +89,7 @@ def test_every_program_says_what_its_spec_is_for():
     roots = [os.path.join(REPO, "fast_tffm_tpu"), os.path.join(REPO, "tools"),
              os.path.join(REPO, "benchmarks")]
     files = [os.path.join(REPO, f) for f in (
-        "bench.py", "chip_smoke.py", "run_tffm.py", "__graft_entry__.py")]
+        "chip_smoke.py", "run_tffm.py", "__graft_entry__.py")]
     for root in roots:
         for d, _, names in os.walk(root):
             files += [os.path.join(d, n) for n in names if n.endswith(".py")]
@@ -106,7 +106,7 @@ def test_every_program_says_what_its_spec_is_for():
                 calls += 1
                 if not any(k.arg == "training" for k in node.keywords):
                     missing.append(f"{path}:{node.lineno}")
-    assert calls >= 15 and missing == []
+    assert calls >= 11 and missing == []
 
 
 # ---- (b) the step's U, and the same three steps either way -------------

@@ -11,7 +11,7 @@ from fast_tffm_tpu.checkpoint import (CheckpointState, QUARANTINE_PREFIX,
                                       list_step_dirs, manifest_path)
 from fast_tffm_tpu.config import FmConfig
 from fast_tffm_tpu.models.fm import init_accumulator, init_table
-from fast_tffm_tpu.train import ckpt_state
+from fast_tffm_tpu.checkpoint import ckpt_state
 from tools.fmckpt import main, resolve_ckpt_dir, scan
 
 

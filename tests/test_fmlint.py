@@ -22,7 +22,7 @@ def _hot_file(tmp_path, body):
 
 def test_repo_surface_is_clean():
     """THE lint gate: the full default surface — fast_tffm_tpu/,
-    tools/ (fmlint lints itself), run_tffm.py, bench.py — must have
+    tools/ (fmlint lints itself), run_tffm.py — must have
     zero findings under every rule, per-file AND whole-program
     (deliberate exceptions carry justified pragmas; the committed
     baseline is empty). R999 parse failures anywhere on this surface
@@ -38,8 +38,7 @@ def test_default_surface_includes_tools_and_cli():
     the package to the tools and CLI entry points."""
     from tools.fmlint.core import default_paths
     names = [os.path.basename(p) for p in default_paths()]
-    assert names == ["fast_tffm_tpu", "tools", "run_tffm.py",
-                     "bench.py"]
+    assert names == ["fast_tffm_tpu", "tools", "run_tffm.py"]
 
 
 def test_collect_files_is_deterministic_and_sorted(tmp_path):
@@ -65,7 +64,7 @@ def test_is_hot_module_scope():
     assert is_hot_module("x/fast_tffm_tpu/data/pipeline.py")
     assert is_hot_module("x/fast_tffm_tpu/obs/sink.py")
     assert not is_hot_module("x/fast_tffm_tpu/metrics.py")
-    assert not is_hot_module("x/bench.py")
+    assert not is_hot_module("x/run_tffm.py")
     assert not is_hot_module("x/tools/fmstat/__init__.py")
 
 

@@ -67,6 +67,31 @@ def test_import_and_call_graph_resolution(tmp_path):
     assert fn.calls == {"pkg.b.helper", "pkg.b.other"}
 
 
+def test_call_on_a_parameter_annotated_with_a_class_resolves(tmp_path):
+    """``loop.step()`` reaches ``Loop.step`` when the parameter says
+    what it is (train.py's loops drive ``StepLoop`` this way, and the
+    protocol checker has to see the step's collectives through the
+    call); an unannotated or foreign-typed receiver stays unresolved."""
+    proj = _load(tmp_path, {
+        "pkg/__init__.py": "",
+        "pkg/a.py": """\
+            class Loop:
+                def step(self):
+                    guarded_collective(f, label="x")
+            def drive(loop: Loop, other, named: "Loop", n: int):
+                loop.step()
+                other.step()
+                named.step()
+                n.step()
+                def inner():
+                    loop.step()
+        """})
+    assert proj.functions["pkg.a.drive"].calls == {"pkg.a.Loop.step"}
+    assert proj.functions["pkg.a.drive.inner"].calls == {
+        "pkg.a.Loop.step"}
+    assert "guarded_collective" in proj.may_collectives["pkg.a.drive"]
+
+
 def test_collective_summary_is_transitive(tmp_path):
     proj = _load(tmp_path, {
         "m.py": """\

@@ -417,11 +417,3 @@ def test_lockstep_counters_feed_active_telemetry(tmp_path, rng):
     tel.close()
 
 
-def test_attribution_bench_verdict():
-    from fast_tffm_tpu.obs.attribution import attribution
-    summary = {"counters": {}, "hists": {}, "gauges": {
-        "bench/e2e": 450_000.0, "bench/host_only": 470_000.0,
-        "bench/device_only": 4_000_000.0, "bench/h2d_only": 900_000.0}}
-    att = attribution(summary)
-    assert att["verdict"].startswith("host-bound")
-    assert att["ceilings"]["e2e"] == 450_000.0

@@ -1,7 +1,7 @@
 """Pallas fused FM kernel == XLA path, values and gradients.
 
 Runs in interpret mode on the CPU mesh (the kernel compiles for real on
-TPU; bench.py / the driver exercise that). Parity tolerances are tight
+TPU; chip_smoke.py exercises that). Parity tolerances are tight
 because both paths accumulate in f32.
 """
 

@@ -11,8 +11,8 @@ import pytest
 from fast_tffm_tpu.checkpoint import CheckpointState
 from fast_tffm_tpu.config import FmConfig
 from fast_tffm_tpu.testing.faults import preempt_after_steps
-from fast_tffm_tpu.train import (checkpoint_template,
-                                 resume_start_epoch, train)
+from fast_tffm_tpu.checkpoint import checkpoint_template, resume_start_epoch
+from fast_tffm_tpu.train import train
 
 N_LINES = 240
 BATCH = 16

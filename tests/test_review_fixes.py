@@ -128,7 +128,7 @@ def test_checkpoint_shape_mismatch_is_actionable(tmp_path):
     # internal shape error.
     from fast_tffm_tpu.checkpoint import CheckpointState
     from fast_tffm_tpu.models.fm import init_accumulator, init_table
-    from fast_tffm_tpu.train import checkpoint_template, ckpt_state
+    from fast_tffm_tpu.checkpoint import checkpoint_template, ckpt_state
     model = str(tmp_path / "m" / "fm")
     cfg = FmConfig(vocabulary_size=64, factor_num=4, model_file=model)
     ckpt = CheckpointState(model)
@@ -149,8 +149,8 @@ def test_checkpoint_vocab_change_same_bucket_rejected(tmp_path):
     # a trained row into the pad row).
     from fast_tffm_tpu.checkpoint import CheckpointState
     from fast_tffm_tpu.models.fm import init_accumulator, init_table
-    from fast_tffm_tpu.train import (check_restored_vocab,
-                                     checkpoint_template, ckpt_state)
+    from fast_tffm_tpu.checkpoint import (check_restored_vocab,
+                                          checkpoint_template, ckpt_state)
     model = str(tmp_path / "m" / "fm")
     cfg = FmConfig(vocabulary_size=2000, factor_num=4, model_file=model)
     ckpt = CheckpointState(model)

@@ -301,7 +301,7 @@ def test_held_step_gcd_pointer_still_valid(tmp_path):
                                           verify_step_dir)
     from fast_tffm_tpu.models.fm import init_accumulator, init_table
     from fast_tffm_tpu.testing.faults import truncate_checkpoint
-    from fast_tffm_tpu.train import checkpoint_template, ckpt_state
+    from fast_tffm_tpu.checkpoint import checkpoint_template, ckpt_state
     cfg = _eval_cfg(tmp_path, vocabulary_size=50, factor_num=2)
     model = cfg.model_file
     ckpt = CheckpointState(model, max_to_keep=3, verify="size")

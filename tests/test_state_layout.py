@@ -30,7 +30,7 @@ from fast_tffm_tpu.models.fm import (ModelSpec, TrainStep, batch_args,
                                      init_accumulator, init_table,
                                      make_batch_scorer, train_step_body)
 from fast_tffm_tpu.obs.telemetry import RunTelemetry, activate
-from fast_tffm_tpu.train import checkpoint_template, ckpt_state
+from fast_tffm_tpu.checkpoint import checkpoint_template, ckpt_state
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 COUNTER = "train/state_relayouts"

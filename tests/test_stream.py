@@ -321,7 +321,7 @@ def test_watermark_checkpoint_roundtrip_and_walkback(tmp_path):
     from fast_tffm_tpu.checkpoint import (CheckpointState,
                                           read_watermark)
     from fast_tffm_tpu.testing.faults import truncate_checkpoint
-    from fast_tffm_tpu.train import checkpoint_template
+    from fast_tffm_tpu.checkpoint import checkpoint_template
     sd = tmp_path / "s"
     sd.mkdir()
     _write_lines(sd / "a.txt", _numbered(0, 40))
@@ -374,7 +374,7 @@ def test_watermark_checkpoint_roundtrip_and_walkback(tmp_path):
 
 def test_epoch_mode_checkpoints_carry_no_watermark(tmp_path):
     from fast_tffm_tpu.checkpoint import CheckpointState
-    from fast_tffm_tpu.train import checkpoint_template
+    from fast_tffm_tpu.checkpoint import checkpoint_template
     cfg = FmConfig(vocabulary_size=256, factor_num=2,
                    model_file=str(tmp_path / "m" / "fm"))
     table = np.zeros((cfg.ckpt_rows, cfg.row_dim), np.float32)

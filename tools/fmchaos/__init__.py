@@ -379,8 +379,9 @@ def scenario_preempt_resume(workdir: str, seed: int = 0) -> str:
     a restart resumes the interrupted schedule and finishes OK."""
     from fast_tffm_tpu.checkpoint import CheckpointState
     from fast_tffm_tpu.testing.faults import preempt_after_steps
-    from fast_tffm_tpu.train import (checkpoint_template,
-                                     resume_start_epoch, train)
+    from fast_tffm_tpu.checkpoint import (checkpoint_template,
+                                          resume_start_epoch)
+    from fast_tffm_tpu.train import train
     data = os.path.join(workdir, "train_preempt.txt")
     _write_corpus(data, 400, seed)
     cfg = _cfg(workdir, data, epoch_num=3)
@@ -424,7 +425,8 @@ def scenario_truncate_latest(workdir: str, seed: int = 0) -> str:
                                           QUARANTINE_PREFIX,
                                           list_step_dirs, manifest_path)
     from fast_tffm_tpu.testing.faults import truncate_checkpoint
-    from fast_tffm_tpu.train import checkpoint_template, train
+    from fast_tffm_tpu.checkpoint import checkpoint_template
+    from fast_tffm_tpu.train import train
     workdir = os.path.abspath(workdir)
     data = os.path.join(workdir, "train_trunc.txt")
     _write_corpus(data, 400, seed)
@@ -2125,7 +2127,7 @@ def scenario_kill_worker_midwindow(workdir: str, seed: int = 0) -> str:
     from fast_tffm_tpu.config import FmConfig
     from fast_tffm_tpu.checkpoint import CheckpointState
     from fast_tffm_tpu.testing.faults import committed_steps, wait_until
-    from fast_tffm_tpu.train import checkpoint_template
+    from fast_tffm_tpu.checkpoint import checkpoint_template
     workdir = os.path.abspath(workdir)
     data = os.path.join(workdir, "train_elastic.txt")
     n_lines, batch = 4864, 32         # 152 exact steps per single pass
@@ -2434,7 +2436,7 @@ def scenario_kill_then_grow(workdir: str, seed: int = 0) -> str:
     from fast_tffm_tpu.checkpoint import CheckpointState
     from fast_tffm_tpu.config import load_config
     from fast_tffm_tpu.testing.faults import wait_until
-    from fast_tffm_tpu.train import checkpoint_template
+    from fast_tffm_tpu.checkpoint import checkpoint_template
     workdir = os.path.abspath(workdir)
     lines_per, batch = 416, 32      # 13 EXACT steps per shard: batch
     steps_per = lines_per // batch  # grouping never spans shards, so

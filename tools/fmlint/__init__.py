@@ -45,7 +45,7 @@ gradual adoption (``--update-baseline`` / ``--baseline``); ``--json``
 emits machine-readable findings.
 
 Run: ``python -m tools.fmlint`` (whole repo surface: fast_tffm_tpu/,
-tools/, run_tffm.py, bench.py) or pass files/dirs.
+tools/, run_tffm.py) or pass files/dirs.
 """
 
 from tools.fmlint.core import Finding, main, run_file, run_paths

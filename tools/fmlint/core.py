@@ -460,8 +460,7 @@ def default_paths() -> List[str]:
     here = repo_root()
     return [os.path.join(here, "fast_tffm_tpu"),
             os.path.join(here, "tools"),
-            os.path.join(here, "run_tffm.py"),
-            os.path.join(here, "bench.py")]
+            os.path.join(here, "run_tffm.py")]
 
 
 def default_baseline_path() -> Optional[str]:

@@ -11,10 +11,13 @@ Every module on the lint surface is parsed ONCE into a ``Project``:
   (``pkg.mod.Class.method``, ``pkg.mod.outer.worker``);
 - a call graph restricted to what static resolution can PROVE:
   bare names through local/nested/module scope and imports,
-  ``self.method()`` within the enclosing class, and
-  ``imported_module.func()`` chains. Attribute calls on arbitrary
-  objects stay unresolved — the summaries underclaim rather than
-  guess, so rule findings are evidence, not speculation;
+  ``self.method()`` within the enclosing class,
+  ``param.method()`` where the parameter (this function's or an
+  enclosing one's) is annotated with a class of the same module
+  (``loop: StepLoop``), and ``imported_module.func()`` chains.
+  Attribute calls on arbitrary objects stay unresolved — the
+  summaries underclaim rather than guess, so rule findings are
+  evidence, not speculation;
 - fixpoint summaries over that graph:
 
   * ``may_collectives[qualname]`` — which blocking collectives
@@ -380,6 +383,9 @@ def resolve_call(proj: Project, fn: FunctionInfo,
     if parts[0] in ("self", "cls") and fn.cls is not None and len(
             parts) == 2:
         return f"{mod.modname}.{fn.cls}.{parts[1]}"
+    cls = _annotated_class(proj, fn, parts[0]) if len(parts) == 2 else None
+    if cls is not None and f"{mod.modname}.{cls}.{parts[1]}" in proj.functions:
+        return f"{mod.modname}.{cls}.{parts[1]}"
     # imported_module.func (or pkg.sub.func through an import alias)
     for split in range(len(parts) - 1, 0, -1):
         alias = ".".join(parts[:split])
@@ -391,6 +397,28 @@ def resolve_call(proj: Project, fn: FunctionInfo,
             return cand
     cand = ".".join(parts)
     return cand if cand in proj.functions else None
+
+
+def _annotated_class(proj: Project, fn: FunctionInfo,
+                     name: str) -> Optional[str]:
+    """The class name a parameter called ``name`` is annotated with
+    (``loop: StepLoop`` or ``loop: "StepLoop"``), looking from ``fn``
+    outwards through its enclosing functions; None when the innermost
+    parameter of that name carries no plain-name annotation."""
+    cur: Optional[FunctionInfo] = fn
+    while cur is not None:
+        args = getattr(cur.node, "args", None)
+        if args is not None:
+            for a in (args.posonlyargs + args.args + args.kwonlyargs):
+                if a.arg != name:
+                    continue
+                ann = a.annotation
+                if isinstance(ann, ast.Constant) and isinstance(
+                        ann.value, str):
+                    return ann.value
+                return ann.id if isinstance(ann, ast.Name) else None
+        cur = proj.functions.get(cur.parent) if cur.parent else None
+    return None
 
 
 def _call_basename(func_expr) -> Optional[str]:

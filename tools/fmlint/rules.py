@@ -322,8 +322,7 @@ def r011_raw_table_index(path: str, tree: ast.AST) -> List[Finding]:
 # jax.device_put of raw [B, L] rectangles bypasses the packed format,
 # the double-buffered dispatch, AND the h2d byte accounting at once.
 # wire.py itself (the encoder's own put) is out of scope by
-# construction; bench.py measures raw transfer deliberately and is
-# not in scope either.
+# construction.
 R013_MODULE_SUFFIXES = (
     "fast_tffm_tpu/train.py",
     "fast_tffm_tpu/predict.py",

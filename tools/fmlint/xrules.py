@@ -413,8 +413,7 @@ def r009_config_drift(proj: Project) -> List[Finding]:
     # 4. cfg.<attr> reads against the FmConfig surface (package
     # modules only — `cfg` is FmConfig by convention there)
     pkg_prefix = os.path.dirname(cfg_mod.path) + os.sep
-    extra_ok = {os.path.join(root, "run_tffm.py"),
-                os.path.join(root, "bench.py")}
+    extra_ok = {os.path.join(root, "run_tffm.py")}
     for read in proj.knob_reads:
         if read.obj != "cfg" or read.attr.startswith("_"):
             continue

@@ -13,8 +13,7 @@ The read-side of the obs/ telemetry subsystem:
 Summary mode merges every given file (a multi-process run's chief file
 plus its ``.p<i>`` worker shards — pass a glob) through the registry's
 merge rules (counters add, histograms bucket-merge, gauges per
-process) and renders the same attribution table bench.py's breakdown
-teaches: examples/sec, step-time quantiles, input-wait / pause /
+process) and renders the attribution table: examples/sec, step-time quantiles, input-wait / pause /
 transfer split, dedup hit rate, padding waste, and a host-bound vs
 device/transfer-bound vs pause-bound verdict. Multi-worker runs with
 the heartbeat lease on additionally get a per-worker liveness table

@@ -332,8 +332,8 @@ def main(argv=None) -> int:
                          "report instead of the table")
     ap.add_argument("--baseline-eps", type=float, default=None,
                     help="with --anatomy: a single-process "
-                         "examples/sec rate (e.g. bench.py "
-                         "--multihost's 1-worker leg); unlocks "
+                         "examples/sec rate (the same job on "
+                         "one worker); unlocks "
                          "absolute per-worker efficiency = useful "
                          "compute time / wall, which also counts "
                          "stalls inside the dispatched program")
