@@ -496,7 +496,7 @@ class FmConfig:
             if self.order != 2:
                 raise ValueError("ffm supports order=2 only")
             # The field-bucketed scorer's biggest intermediate is
-            # [B, F, F, k] (ops/interaction.py); warn before a config
+            # [B, F, F*k+1] (ops/interaction.py); warn before a config
             # quietly asks for a multi-GB tensor per step.
             ffm_bytes = (self.batch_size * self.field_num ** 2
                          * self.factor_num * 4)

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 # FM latent values are tiny (init ±0.01) and scores are heavy on
@@ -98,41 +99,59 @@ def ffm_batch_scores(params: jax.Array, field_num: int,
                      local_idx: jax.Array, fields: jax.Array,
                      vals: jax.Array) -> jax.Array:
     """Field-aware FM (BASELINE config #3): row layout [U, field_num*k+1];
-    v[i, f] is the latent vector row i uses against field f.
+    v[i, f], the latent vector row i uses against field f, sits at
+    columns f*k .. f*k+k-1, the linear weight w in the last one.
 
         score = Σ_j w_j x_j + Σ_{i<j} <v[i, f_j], v[j, f_i]> x_i x_j
 
     Computed by bucketing features by field instead of forming the
-    [B, L, L, k] pair tensor (which is ~2.7 GB at L=256/B=1024):
+    [B, L, L, k] pair tensor, and with the rows kept whole: no array
+    here has the factor axis k as its minor dimension (a TPU tiles the
+    last two dimensions (8, 128), so a [..., k=4] array pads 32-fold or
+    is re-laid, forward and backward; PERF.md section 6, PR 30). With
+    F = field_num, D = F*k + 1 and the columns taken factor-major,
+    column κ*F + f holding v[., f][κ]:
 
-        S[b, f, g, :] = Σ_{l : fields[b,l]=g} x_l · v[b, l, f, :]
-        Σ_{i,j} <v_i[f_j], v_j[f_i]> x_i x_j = Σ_{f,g} <S[f,g], S[g,f]>
+        a[b, l, g] = [fields[b, l] = g] · x[b, l]                [B, L, F]
+        S[b, g, :] = Σ_l a[b, l, g] · rows[b, l, :]               [B, F, D]
+        Σ_{i,j} <v_i[f_j], v_j[f_i]> x_i x_j
+                   = Σ_κ Σ_{g,f} S[b, g, κ*F+f] · S[b, f, κ*F+g]
 
-    (each ordered pair (i, j) lands in the (f, g) = (f_j, f_i) bucket
-    exactly once), then the i=j diagonal Σ_l x_l²·||v_l[f_l]||² is
-    subtracted and the sum halved. The biggest intermediate is
-    [B, F, F, k] — bounded by the field count, not the feature bucket —
-    and the L-contraction is a plain matmul the MXU tiles. Padded slots
-    have x=0 and contribute zero everywhere.
+    (each ordered pair (i, j) lands in the (g, f) = (f_i, f_j) bucket
+    exactly once: per κ an [F, F] slab against its own transpose), then
+    the i=j diagonal Σ_l x_l²·||v_l[f_l]||², a mask over the columns,
+    is subtracted and the sum halved. S is ONE batched matmul over L
+    whose last column is the linear term, summed by field, so the rows
+    are never sliced and the row gradient is born [B, L, D], the shape
+    ``expand_rows``' segment-sum takes: a·dS less the masked diagonal.
+    The columns are put factor-major on the U gathered slots, not on
+    the B·L expanded rows, by a 0/1 matrix (exact in float32 at
+    ``HIGHEST``; its transpose brings the slot gradient back), so the
+    table and its checkpoints keep their layout. Nothing here depends
+    on which of F, k, L is large: the biggest intermediates are
+    [B, L, D] and [B, F, D]. Padded slots have x=0 and contribute
+    exactly zero to score and gradient; ``fields`` lie in [0, F) (the
+    parsers refuse any other), a feature outside would drop out whole.
     """
-    rows = expand_rows(params, local_idx)          # [B, L, F*k+1]
     with jax.named_scope("interaction"):
-        B, L = local_idx.shape
-        w = rows[..., -1]
-        k = (rows.shape[-1] - 1) // field_num
-        v = rows[..., :-1].reshape(B, L, field_num, k)
-        linear = jnp.einsum("bl,bl->b", w, vals, precision=_F32)
-        onehot = jax.nn.one_hot(fields, field_num,
-                                dtype=v.dtype)                 # [B, L, F]
-        # S[b,f,g,:] = Σ_l onehot[b,l,g] · x[b,l] · v[b,l,f,:]
-        s = jnp.einsum("blfk,blg,bl->bfgk", v, onehot, vals,
-                       precision=_F32)
-        cross = jnp.einsum("bfgk,bgfk->b", s, s, precision=_F32)
-        # i=j diagonal: v each feature uses against its own field.
-        v_self = jnp.take_along_axis(
-            v, fields[:, :, None, None], axis=2)[:, :, 0, :]   # [B, L, k]
-        diag = jnp.einsum("blk,blk,bl->b", v_self, v_self,
-                          jnp.square(vals), precision=_F32)
+        F, D = field_num, params.shape[-1]
+        k = (D - 1) // F
+        major = np.arange(F * k).reshape(F, k).T.ravel()   # κ*F+f <- f*k+κ
+        params = jnp.dot(
+            params, np.eye(D, dtype=params.dtype)[:, np.append(major, D - 1)],
+            precision=_F32)
+    rows = expand_rows(params, local_idx)          # [B, L, D], factor-major
+    with jax.named_scope("interaction"):
+        a = jax.nn.one_hot(fields, F, dtype=rows.dtype) * vals[..., None]
+        s = jnp.einsum("blg,blm->bgm", a, rows, precision=_F32)
+        linear = s[:, :, -1].sum(axis=1)
+        slabs = s[:, :, :-1].reshape(-1, F, k, F)  # [b, g, κ, f]
+        cross = jnp.einsum("bgkf,bfkg->b", slabs, slabs, precision=_F32)
+        # i=j diagonal: the columns each feature uses against its own
+        # field (column κ*F+f belongs to field f; the last to none).
+        own = np.append(np.tile(np.arange(F), k), -1) == fields[..., None]
+        diag = jnp.sum(jnp.where(own, jnp.square(rows * vals[..., None]),
+                                 0.0), axis=(1, 2))
         return linear + 0.5 * (cross - diag)
 
 

@@ -1,11 +1,13 @@
 """Device math (ops/interaction.py) vs the NumPy oracle, through the real
 pipeline (bucketed padding, host-side unique)."""
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from fast_tffm_tpu.config import FmConfig
-from fast_tffm_tpu.data.parser import ParsedBlock
+from fast_tffm_tpu.data.parser import ParsedBlock, parse_lines
 from fast_tffm_tpu.data.pipeline import make_device_batch
 from fast_tffm_tpu.models import oracle
 from fast_tffm_tpu.ops.interaction import (batch_reg, ffm_batch_scores,
@@ -71,20 +73,172 @@ def test_scores_match_oracle(rng, order):
     np.testing.assert_array_equal(got[b.num_real:], 0.0)
 
 
-def test_ffm_scores_match_oracle(rng):
-    field_num = 3
-    cfg = make_cfg(model_type="ffm", field_num=field_num)
-    examples, block = random_batch(rng, 4, with_fields=True,
+# (field_num, k, bucket width L): the original small case, the benchmark
+# cell's shape (ffm-k4-avazu: 22 fields, k=4, the 32 rung), and a k that
+# is no power of two.
+FFM_SHAPES = [(3, 4, 8), (22, 4, 32), (5, 3, 16)]
+
+
+def ffm_oracle_scores(table, field_num, examples):
+    return np.array([
+        oracle.ffm_score(table[:-1].astype(np.float64), field_num, i, f, x)
+        for i, f, x in examples])
+
+
+@pytest.mark.parametrize("field_num,k,L", FFM_SHAPES)
+def test_ffm_scores_match_oracle(rng, field_num, k, L):
+    cfg = make_cfg(model_type="ffm", field_num=field_num, factor_num=k,
+                   bucket_ladder=(L,))
+    examples, block = random_batch(rng, 4, max_nnz=L - 2, with_fields=True,
                                    field_num=field_num)
     b = make_device_batch(block, cfg)
     table = padded_table(rng, cfg)
     gathered = gather_rows(table, b.uniq_ids)
     got = np.asarray(ffm_batch_scores(gathered, field_num, b.local_idx,
                                       b.fields, b.vals))
-    want = np.array([
-        oracle.ffm_score(table[:-1].astype(np.float64), field_num, i, f, x)
-        for i, f, x in examples])
+    want = ffm_oracle_scores(table, field_num, examples)
     np.testing.assert_allclose(got[:b.num_real], want, rtol=2e-4, atol=2e-4)
+    # padded dummy examples score exactly 0
+    np.testing.assert_array_equal(got[b.num_real:], 0.0)
+
+
+@pytest.mark.parametrize("fields_of,why", [
+    ([[0, 0, 2], [1, 1, 1, 1]], "two features share a field"),
+    ([[0, 2], [2]], "a field no feature of the example has"),
+])
+def test_ffm_scores_crowded_and_empty_fields(rng, fields_of, why):
+    field_num = 3
+    cfg = make_cfg(model_type="ffm", field_num=field_num)
+    examples = [(rng.choice(V, size=len(f), replace=False).tolist(), f,
+                 rng.normal(size=len(f)).round(3).tolist())
+                for f in fields_of]
+    block = parse_lines(
+        ["0 " + " ".join(f"{f}:{i}:{x}" for i, f, x in zip(*example))
+         for example in examples], V, field_aware=True, field_num=field_num)
+    b = make_device_batch(block, cfg)
+    table = padded_table(rng, cfg)
+    got = np.asarray(ffm_batch_scores(gather_rows(table, b.uniq_ids),
+                                      field_num, b.local_idx, b.fields,
+                                      b.vals))
+    np.testing.assert_allclose(got[:b.num_real],
+                               ffm_oracle_scores(table, field_num, examples),
+                               rtol=2e-4, atol=2e-4, err_msg=why)
+
+
+def ffm_pairwise_scores(params, field_num, local_idx, fields, vals):
+    """The definition, pair by pair over [B, L, L]: what the bucketed
+    body must equal, value and gradient."""
+    rows = params[local_idx]
+    B, L = local_idx.shape
+    v = rows[..., :-1].reshape(B, L, field_num, -1)
+    # vs[b, i, j] = v[b, i, fields[b, j]]: what i uses against j's field
+    vs = jnp.take_along_axis(
+        v[:, :, None], fields[:, None, :, None, None], axis=3)[:, :, :, 0]
+    pair = jnp.einsum("bijk,bjik,bi,bj->bij", vs, vs, vals, vals)
+    off_diagonal = 1.0 - jnp.eye(L, dtype=pair.dtype)
+    return ((rows[..., -1] * vals).sum(axis=1)
+            + 0.5 * (pair * off_diagonal).sum(axis=(1, 2)))
+
+
+def random_ffm_arrays(rng, B, L, field_num, k, U):
+    params = rng.normal(size=(U, field_num * k + 1)).astype(np.float32) * 0.3
+    local_idx = rng.integers(0, U, size=(B, L)).astype(np.int32)
+    fields = rng.integers(0, field_num, size=(B, L)).astype(np.int32)
+    vals = rng.normal(size=(B, L)).astype(np.float32)
+    return params, local_idx, fields, vals
+
+
+@pytest.mark.parametrize("field_num,k,L", FFM_SHAPES)
+def test_ffm_gradient_matches_pairwise_reference(rng, field_num, k, L):
+    B, U = 6, 40
+    params, local_idx, fields, vals = random_ffm_arrays(rng, B, L, field_num,
+                                                        k, U)
+    cot = rng.normal(size=B).astype(np.float32)   # d loss / d score
+
+    def summed(score_fn):
+        return lambda p: (score_fn(p, field_num, local_idx, fields, vals)
+                          * cot).sum()
+
+    with jax.default_matmul_precision("highest"):
+        want_s = ffm_pairwise_scores(params, field_num, local_idx, fields,
+                                     vals)
+        want_g = jax.grad(summed(ffm_pairwise_scores))(params)
+    got_s = ffm_batch_scores(params, field_num, local_idx, fields, vals)
+    got_g = jax.grad(summed(ffm_batch_scores))(params)
+    np.testing.assert_allclose(got_s, want_s, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(got_g, want_g, rtol=1e-5,
+                               atol=1e-5 * float(np.abs(want_g).max()))
+
+
+def test_ffm_padded_slots_and_zero_weight_rows_are_exact_zeros(rng):
+    """x = 0 slots and examples whose loss weight is 0 give the rows
+    they point at a gradient of exactly 0.0, not a small number: the
+    step scatter-adds every slot's gradient into the table."""
+    field_num, k, L, B, U = 22, 4, 32, 6, 40
+    params, local_idx, fields, vals = random_ffm_arrays(rng, B, L, field_num,
+                                                        k, U - 2)
+    pad_row, dead_row = U - 2, U - 1
+    params = np.concatenate(
+        [params, rng.normal(size=(2, params.shape[1])).astype(np.float32)])
+    local_idx[:, 20:], vals[:, 20:] = pad_row, 0.0      # padded slots
+    local_idx[4, :20] = dead_row                         # a zero-weight example
+    vals[5] = 0.0                                        # an all-padding one
+    cot = rng.normal(size=B).astype(np.float32)
+    cot[4] = 0.0
+
+    def loss(p):
+        return (ffm_batch_scores(p, field_num, local_idx, fields, vals)
+                * cot).sum()
+
+    scores = np.asarray(ffm_batch_scores(params, field_num, local_idx,
+                                         fields, vals))
+    grad = np.asarray(jax.grad(loss)(params))
+    assert scores[5] == 0.0
+    np.testing.assert_array_equal(grad[[pad_row, dead_row]], 0.0)
+    assert np.abs(grad[:pad_row]).max() > 0.0
+
+
+def _walk(jaxpr, scope=""):
+    """Every equation with the name stack it runs under: its own, or,
+    inside a call (one_hot, where), the calling equation's."""
+    for eqn in jaxpr.eqns:
+        stack = scope or str(eqn.source_info.name_stack)
+        yield eqn, stack
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk(inner, stack)
+
+
+def test_ffm_grad_keeps_the_factor_axis_off_the_minor_dimension():
+    """The record that the mechanism is in every FFM program (PERF.md
+    section 6, PR 30): the grad of the FFM loss at the benchmark cell's
+    F, k, L holds no [B,L,F,k], [B,F,F,k], [B,L,1,k] or [B,L,k] array,
+    which a TPU pads 32-fold or re-lays, and every equation of it runs
+    under one of the step's named scopes, so a trace can place it."""
+    from fast_tffm_tpu.models.fm import ModelSpec, loss_and_scores
+    B, L, F, k, U = 16, 32, 22, 4, 100
+    spec = ModelSpec(model_type="ffm", order=2, factor_num=k, field_num=F,
+                     vocabulary_size=1000, loss_type="logistic",
+                     factor_lambda=1e-6, bias_lambda=1e-6, learning_rate=0.05)
+    f32, i32 = jnp.float32, jnp.int32
+    S = jax.ShapeDtypeStruct
+
+    def loss(gathered, *batch):
+        return loss_and_scores(spec, gathered, *batch)[0]
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss))(
+        S((U, F * k + 1), f32), S((B,), f32), S((B,), f32), S((U,), i32),
+        S((B, L), i32), S((B, L), f32), S((B, L), i32)).jaxpr
+    k_minor_rows = {B * L * F, B * F * F, B * L}
+    eqns = list(_walk(jaxpr))
+    assert len(eqns) > 50
+    for eqn, stack in eqns:
+        for var in eqn.outvars:
+            shape = getattr(var.aval, "shape", ())
+            assert not (len(shape) > 1 and shape[-1] == k
+                        and int(np.prod(shape[:-1])) in k_minor_rows), (
+                f"{eqn.primitive.name} makes {shape} under {stack!r}")
+        assert any(s in stack for s in ("interaction", "expand", "loss")), (
+            f"{eqn.primitive.name} runs under no scope ({stack!r})")
 
 
 def test_reg_matches_oracle(rng):
