@@ -5,7 +5,9 @@ Train: what the first steps of the timed step object produced — each
 step's loss, the first gradient as the optimizer got it (worked out
 from the state after one step), the parameters' change after the
 checked steps — against the NumPy float64 reference following the same
-examples from its own copy of the corpus and of the weights.
+examples from its own copy of the corpus and of the weights. ``model``
+is ``harness.model_of``'s description; the reference reaches the
+model's family through it (``reference.family_of``).
 Predict: a seeded sample of the scores the window's last sweep wrote,
 against the reference's."""
 
@@ -49,9 +51,9 @@ def feed_rows(feed: Dict[str, np.ndarray]) -> np.ndarray:
         np.asarray(feed["local_idx"])].astype(np.int64)
 
 
-def train_checks(model: dict, cfg_rows: int, row_dim: int,
-                 value_range: float, seed: int, corpus: Corpus, probe,
-                 limits: dict, batch_size: int) -> List[dict]:
+def train_checks(model: dict, cfg_rows: int, value_range: float,
+                 seed: int, corpus: Corpus, probe, limits: dict,
+                 batch_size: int) -> List[dict]:
     n = len(probe.feeds)
     batches = []
     unmatched = short = 0
@@ -81,8 +83,8 @@ def train_checks(model: dict, cfg_rows: int, row_dim: int,
     pad = cfg_rows - 1
     rows_all = np.unique(np.concatenate(
         [b[0].ravel() for b in batches] + [np.array([pad])]))
-    t0 = weights.table_rows_numpy(rows_all, row_dim, seed, value_range,
-                                  cfg_rows)
+    t0 = weights.table_rows_numpy(rows_all, model["row_dim"], seed,
+                                  value_range, cfg_rows)
     ref = reference.ReferenceTrainer(model, rows_all, t0)
     losses, g1, rows1 = [], None, None
     for i, (r, x, y, w) in enumerate(batches):
@@ -116,10 +118,10 @@ def train_checks(model: dict, cfg_rows: int, row_dim: int,
     return checks
 
 
-def predict_checks(model: dict, cfg_rows: int, row_dim: int,
-                   value_range: float, seed: int, corpus: Corpus,
-                   scores: np.ndarray, sample: np.ndarray,
-                   limits: dict, swept_lines: int) -> List[dict]:
+def predict_checks(model: dict, cfg_rows: int, value_range: float,
+                   seed: int, corpus: Corpus, scores: np.ndarray,
+                   sample: np.ndarray, limits: dict,
+                   swept_lines: int) -> List[dict]:
     """``scores``: what one call wrote, in the order of its files: the
     corpus ``swept_lines / len(corpus)`` times over. ``sample``
     indexes the swept lines."""
@@ -128,20 +130,20 @@ def predict_checks(model: dict, cfg_rows: int, row_dim: int,
                "limit": 0}]
     if checks[0]["value"]:
         return checks
-    ref = reference_scores(model, cfg_rows, row_dim, value_range, seed,
-                           corpus, sample % len(corpus.labels))
+    ref = reference_scores(model, cfg_rows, value_range, seed, corpus,
+                           sample % len(corpus.labels))
     gap = float(np.abs(scores[sample] - ref).max())
     checks.append({"name": "score_abs_gap_max", "value": gap,
                    "limit": limits["score_abs_gap_max"]})
     return checks
 
 
-def reference_scores(model, cfg_rows, row_dim, value_range, seed, corpus,
-                     sample, quant=None):
+def reference_scores(model, cfg_rows, value_range, seed, corpus, sample,
+                     quant=None):
     rows = corpus.rows[sample]
     uniq, inv = np.unique(rows, return_inverse=True)
-    t = weights.table_rows_numpy(uniq, row_dim, seed, value_range,
-                                 cfg_rows)
+    t = weights.table_rows_numpy(uniq, model["row_dim"], seed,
+                                 value_range, cfg_rows)
     return reference.predict_scores(model, t, inv.reshape(rows.shape),
                                     corpus.vals[sample], corpus.fields,
                                     quant)

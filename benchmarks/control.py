@@ -5,9 +5,11 @@ by the same numbers a run compares. It must come out NOT correct.
 
     python3 -m benchmarks.control --workload <name> --seeds 1,2,3
 
-Host only (NumPy): it needs no chip and reads the same anywhere. The
-benchmark's own runs never run it; tests/benchmarks keeps it at a small
-size, and PERF.md gives its readings at each cell's own size."""
+Host only (NumPy, and the program's own reading of the cell's INI
+sections for ``harness.model_of``): it needs no chip and reads the same
+anywhere. The benchmark's own runs never run it; tests/benchmarks keeps
+it at a small size, and PERF.md gives its readings at each cell's own
+size."""
 
 from __future__ import annotations
 
@@ -26,17 +28,9 @@ def control_numbers(cell: harness.Cell, seed: int, work: str) -> dict:
     gen, trn = prog["General"], prog["Train"]
     batch = int(trn["batch_size"])
     vocab = int(gen["vocabulary_size"])
-    model = {"model_type": gen.get("model_type", "fm"),
-             "field_num": int(gen.get("field_num", 0)),
-             "factor_num": int(gen["factor_num"]),
-             "loss_type": trn.get("loss_type", "logistic"),
-             "factor_lambda": float(trn.get("factor_lambda", 0.0)),
-             "bias_lambda": float(trn.get("bias_lambda", 0.0)),
-             "learning_rate": float(trn["learning_rate"]),
-             "adagrad_init": float(trn.get("adagrad_init", 0.1))}
-    k = model["factor_num"]
-    dim = (k * model["field_num"] + 1 if model["model_type"] == "ffm"
-           else k + 1)
+    model = harness.model_of(harness.program_cfg(cell.config, {}, work),
+                             cell.config)
+    dim = model["row_dim"]
     c = corpus_mod.generate(cell.config["features"], model["model_type"],
                             vocab, int(tr["corpus_batches"]) * batch, seed,
                             work, int(tr["corpus_files"]), "control")
@@ -46,9 +40,9 @@ def control_numbers(cell: harness.Cell, seed: int, work: str) -> dict:
         rng = np.random.default_rng([int(seed), 0x5A3B1E])
         sample = np.unique(rng.integers(0, len(c.labels),
                                         size=int(tr["checked_lines"])))
-        ref = check.reference_scores(model, rows_n, dim, vr, seed, c, sample)
-        low = check.reference_scores(model, rows_n, dim, vr, seed, c,
-                                     sample, quant="bf16")
+        ref = check.reference_scores(model, rows_n, vr, seed, c, sample)
+        low = check.reference_scores(model, rows_n, vr, seed, c, sample,
+                                     quant="bf16")
         # what predict() writes: %.6f text
         return {"score_abs_gap_max": float(np.abs(
             np.round(low, 6) - ref).max())}

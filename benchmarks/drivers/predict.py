@@ -37,13 +37,15 @@ def run(run, device, breaker=None) -> str:
     passes = int(tr.get("corpus_passes", 1))
     n_lines = len(corpus.labels) * passes       # one call sweeps these
     metrics_path = os.path.join(run.work_dir, "metrics.jsonl")
-    cfg = harness.write_program_cfg(run, {
+    cfg = harness.program_cfg(run.cell.config, {
         "General": {"model_file": os.path.join(run.work_dir, "model", "fm")},
         "Train": {"seed": run.program_seed,
                   "metrics_file": metrics_path},
         "Predict": {"predict_files": corpus_mod.listed(corpus.files,
                                                        passes),
-                    "score_path": os.path.join(run.work_dir, "score")}})
+                    "score_path": os.path.join(run.work_dir, "score")}},
+        run.work_dir)
+    model = harness.model_of(cfg, run.cell.config)
     value_range = float(tr["table_value_range"])
     t = time.monotonic()
     table = weights.make_table(cfg.num_rows, cfg.row_dim, run.seed,
@@ -85,8 +87,8 @@ def run(run, device, breaker=None) -> str:
         [rng.integers(0, n_lines, size=int(tr["checked_lines"])),
          [0, n_lines - 1]]))
     checks = check.predict_checks(
-        harness.model_of(cfg), cfg.num_rows, cfg.row_dim, value_range,
-        run.seed, corpus, read_scores(written), sample,
+        model, cfg.num_rows, value_range, run.seed, corpus,
+        read_scores(written), sample,
         run.cell.config["check_limits"]["predict"], n_lines)
     return harness.finish(
         run, device, {E2E_RATE: rate["rate"]}, checks,

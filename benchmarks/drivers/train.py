@@ -207,14 +207,16 @@ def run(run, device, breaker=None) -> str:
     steps_per_reading = int(tr["steps_per_reading"])
     passes = int(tr.get("corpus_passes", 1))
     metrics_path = os.path.join(run.work_dir, "metrics.jsonl")
-    cfg = harness.write_program_cfg(run, {
+    cfg = harness.program_cfg(run.cell.config, {
         "General": {"model_file": os.path.join(run.work_dir, "model", "fm")},
         "Train": {"train_files": corpus_mod.listed(corpus.files, passes),
                   "epoch_num": 1000000,
                   "seed": run.program_seed,
                   "log_steps": steps_per_reading,
                   "metrics_file": metrics_path,
-                  "metrics_flush_steps": steps_per_reading}})
+                  "metrics_flush_steps": steps_per_reading}},
+        run.work_dir)
+    model = harness.model_of(cfg, run.cell.config)
     n_check = int(tr["checked_steps"])
     warmup_steps = int(tr["warmup_readings"]) * steps_per_reading
     if warmup_steps < n_check:
@@ -261,8 +263,7 @@ def run(run, device, breaker=None) -> str:
            "median_reading": rate["median"]}
     t = time.monotonic()
     checks = check.train_checks(
-        harness.model_of(cfg), cfg.num_rows, cfg.row_dim,
-        cfg.init_value_range, run.seed, corpus, probe,
+        model, cfg.num_rows, cfg.init_value_range, run.seed, corpus, probe,
         run.cell.config["check_limits"]["train"], global_batch)
     # The rate credits steps x batch: the program's own count of the
     # real examples it trained on in the span has to be that many.

@@ -127,14 +127,15 @@ def enable_cache() -> str:
     return enable_compilation_cache()
 
 
-def write_program_cfg(run: Run, extra: Dict[str, Dict[str, Any]]):
+def program_cfg(config: dict, extra: Dict[str, Dict[str, Any]],
+                work_dir: str):
     """The configuration as a user writes it: an INI file read by the
-    program's own load_config, the cell's run-time paths added."""
-    sections = {k: dict(v) for k, v in run.cell.config["program"].items()}
+    program's own load_config, ``extra`` keys added to its sections."""
+    sections = {k: dict(v) for k, v in config["program"].items()}
     for sec, kv in extra.items():
         sections.setdefault(sec, {}).update(kv)
-    os.makedirs(run.work_dir, exist_ok=True)
-    path = os.path.join(run.work_dir, "run.cfg")
+    os.makedirs(work_dir, exist_ok=True)
+    path = os.path.join(work_dir, "run.cfg")
     with open(path, "w", encoding="utf-8") as fh:
         for sec, kv in sections.items():
             fh.write(f"[{sec}]\n")
@@ -146,13 +147,35 @@ def write_program_cfg(run: Run, extra: Dict[str, Dict[str, Any]]):
     return apply_env_overrides(load_config(path))
 
 
-def model_of(cfg) -> dict:
-    return {"model_type": cfg.model_type, "field_num": cfg.field_num,
-            "factor_num": cfg.factor_num, "loss_type": cfg.loss_type,
-            "factor_lambda": cfg.factor_lambda,
-            "bias_lambda": cfg.bias_lambda,
-            "learning_rate": cfg.learning_rate,
-            "adagrad_init": cfg.adagrad_init}
+# What of the program's configuration defines the mathematics the
+# reference follows; MODEL_APART is what of ModelSpec does not.
+MODEL_FIELDS = ("model_type", "order", "factor_num", "field_num", "row_dim",
+                "loss_type", "factor_lambda", "bias_lambda",
+                "learning_rate", "adagrad_init")
+MODEL_APART = ("vocabulary_size", "kernel", "dedup")
+
+
+def model_of(cfg, config: dict) -> dict:
+    """The one description of the model that the reference, the check
+    and the control work from: the ``FmConfig`` fields that define the
+    score (``model_type``, ``order``, ``factor_num``, ``field_num``,
+    and ``row_dim``, the width they give a row), the loss
+    (``loss_type``, ``factor_lambda``, ``bias_lambda``) and the update
+    (``learning_rate``, ``adagrad_init``: sparse Adagrad over the
+    touched rows), with the ``reference_family`` the configuration's
+    file names. A field the program's step depends on and this drops is
+    how a wrong reference passes unseen: tests/benchmarks holds the
+    list against ``ModelSpec``, whose other fields (``MODEL_APART``)
+    size the table or choose how the same mathematics is computed. A
+    family that is not there fails here, before anything is timed."""
+    from benchmarks import reference
+    model = {k: getattr(cfg, k) for k in MODEL_FIELDS}
+    model["reference_family"] = config.get("reference_family")
+    try:
+        reference.family_of(model)
+    except (KeyError, ValueError) as e:
+        raise RunFailed(e.args[0]) from None
+    return model
 
 
 def fresh_dir(path: str) -> str:

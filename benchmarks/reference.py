@@ -1,11 +1,14 @@
-"""The plain reference: FM / FFM forward, logistic or squared loss,
-hand-derived gradients and sparse Adagrad in NumPy float64. It imports
-nothing of the program and takes nothing the program made: examples
-come from the benchmark's corpus, weights from benchmarks/weights.py.
+"""The plain reference: forward, logistic or squared loss, hand-derived
+gradients and sparse Adagrad in NumPy float64. It imports nothing of
+the program and takes nothing the program made: examples come from the
+benchmark's corpus, weights from benchmarks/weights.py.
 
-Arithmetic copied from fast_tffm_tpu/models/oracle.py and
-data/synth.numpy_*_train_predict (sound; listed in PERF.md for a later
-PR to fold), vectorised over the batch.
+What differs between model families is one function and the row width,
+and lives in a file of its own, ``references/<family>.py``, which a
+configuration names (``reference_family``; README "Adding ... a model
+family"). ``family_of`` is the one seam to it. What the families share
+is here, once: the loss, the regulariser, Adagrad over the touched
+rows, what predict() writes, the worst-leaf gap, bfloat16 rounding.
 
 ``quant="bf16"`` is the control of "How correct is decided": the same
 mathematics with the gathered rows, the values and the interaction's
@@ -14,9 +17,14 @@ the float32 the configurations state."""
 
 from __future__ import annotations
 
+import importlib
+import os
 from typing import Dict, Optional, Tuple
 
 import numpy as np
+
+REFERENCES_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                              "references")
 
 
 def to_bf16(x: np.ndarray) -> np.ndarray:
@@ -28,8 +36,53 @@ def to_bf16(x: np.ndarray) -> np.ndarray:
     return u.astype(np.uint32).view(np.float32).astype(np.float64)
 
 
-def _q(x, quant):
+def quantize(x, quant):
     return to_bf16(x) if quant == "bf16" else x
+
+
+def scatter_rows(inv: np.ndarray, g_rows: np.ndarray, U: int) -> np.ndarray:
+    """Sum the per-cell row gradients [B, L, D] into the rows they
+    were read from: [U, D]."""
+    D = g_rows.shape[-1]
+    flat = inv.ravel()
+    g2 = g_rows.reshape(flat.size, D)
+    out = np.empty((U, D))
+    for c in range(D):
+        out[:, c] = np.bincount(flat, weights=g2[:, c], minlength=U)
+    return out
+
+
+def families() -> list:
+    return sorted(f[:-3] for f in os.listdir(REFERENCES_DIR)
+                  if f.endswith(".py") and not f.startswith("_"))
+
+
+def family_of(model: dict):
+    """The module ``references/<model["reference_family"]>.py``: its
+    ``scores_and_row_grads`` and ``row_dim``. A description without the
+    key, a name with no file, or a family whose row is not as wide as
+    the program's is an error, never a default."""
+    name = model.get("reference_family")
+    if not name:
+        raise KeyError(
+            "the configuration names no reference_family: its file must "
+            "say which benchmarks/references/<family>.py is the plain "
+            f"reference of its model (there: {families()})")
+    try:
+        mod = importlib.import_module("benchmarks.references." + name)
+    except ModuleNotFoundError as e:
+        if e.name != "benchmarks.references." + name:
+            raise
+        raise KeyError(
+            f"no reference family {name!r}: add benchmarks/references/"
+            f"{name}.py with scores_and_row_grads and row_dim (there: "
+            f"{families()})") from None
+    if mod.row_dim(model) != model["row_dim"]:
+        raise ValueError(
+            f"reference family {name!r} has rows of {mod.row_dim(model)} "
+            f"columns and the program's configuration {model['row_dim']}: "
+            "it is the reference of another model")
+    return mod
 
 
 def scores_and_row_grads(model: dict, P: np.ndarray, inv: np.ndarray,
@@ -38,60 +91,10 @@ def scores_and_row_grads(model: dict, P: np.ndarray, inv: np.ndarray,
                          ) -> Tuple[np.ndarray, "callable"]:
     """Scores [B] of a batch whose feature (b, l) reads row
     ``P[inv[b, l]]`` with value ``x[b, l]``, and a function mapping
-    dLoss/dscore [B] to the gradient w.r.t. ``P`` ([U, D])."""
-    B, L = inv.shape
-    U, D = P.shape
-    rows = _q(P, quant)[inv]                          # [B, L, D]
-    xq = _q(x, quant)
-    w = rows[..., -1]
-    flat = inv.ravel()
-
-    def scatter(g_rows):                              # [B, L, D] -> [U, D]
-        out = np.empty((U, D))
-        g2 = g_rows.reshape(B * L, D)
-        for c in range(D):
-            out[:, c] = np.bincount(flat, weights=g2[:, c], minlength=U)
-        return out
-
-    if model["model_type"] == "fm":
-        v = rows[..., :-1]
-        z = _q(v * xq[..., None], quant)              # [B, L, k]
-        s = _q(z.sum(axis=1), quant)                  # [B, k]
-        score = (w * xq).sum(axis=1) + 0.5 * (
-            np.square(s) - np.square(z).sum(axis=1)).sum(axis=-1)
-
-        def backward(ds):
-            g = np.empty((B, L, D))
-            g[..., -1] = ds[:, None] * xq
-            g[..., :-1] = (ds[:, None, None] * xq[..., None]
-                           * (s[:, None, :] - z))
-            return scatter(g)
-        return score, backward
-
-    F = int(model["field_num"])
-    k = (D - 1) // F
-    v = rows[..., :-1].reshape(B, L, F, k)
-    f = np.broadcast_to(np.asarray(fields), (B, L))
-    # a[b, i, j, :] = x_i * v_i[field_j]
-    a = _q(np.take_along_axis(
-        v, np.broadcast_to(f[:, None, :, None], (B, L, L, 1)), axis=2)
-        * xq[:, :, None, None], quant)
-    pair = np.einsum("bijk,bjik->bij", a, a)
-    off = ~np.eye(L, dtype=bool)
-    score = (w * xq).sum(axis=1) + 0.5 * (pair * off).sum(axis=(1, 2))
-
-    def backward(ds):
-        # d score / d v_i[g] = x_i * sum_{j != i, field_j = g} a[j, i]
-        at = np.swapaxes(a, 1, 2) * off[None, :, :, None]   # [b, i, j, k]
-        onehot = (f[:, :, None] == np.arange(F)[None, None, :]
-                  ).astype(np.float64)                      # [b, j, g]
-        gv = np.einsum("bijk,bjg->bigk", at, onehot)
-        g = np.empty((B, L, D))
-        g[..., -1] = ds[:, None] * xq
-        g[..., :-1] = (ds[:, None, None, None] * xq[:, :, None, None]
-                       * gv).reshape(B, L, F * k)
-        return scatter(g)
-    return score, backward
+    dLoss/dscore [B] to the gradient w.r.t. ``P`` ([U, D]): the
+    model's family computes both."""
+    return family_of(model).scores_and_row_grads(model, P, inv, x, fields,
+                                                 quant)
 
 
 def per_example_loss(model: dict, score, y):
@@ -132,7 +135,7 @@ class ReferenceTrainer:
         wsum = weights.sum()
         touched = np.zeros(len(uniq), dtype=bool)
         touched[inv[live]] = True
-        Pq = _q(P, self.quant)
+        Pq = quantize(P, self.quant)
         reg = (m["factor_lambda"] * np.square(Pq[touched, :-1]).sum()
                + m["bias_lambda"] * np.square(Pq[touched, -1]).sum())
         loss = float((per * weights).sum() / wsum + reg)

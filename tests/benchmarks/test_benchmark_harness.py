@@ -1,7 +1,10 @@
 """Fast CPU tests of the benchmark's own code (benchmarks/): the
 reading rule, the corpus and weights the reference shares with no one,
-the reference against the program's arithmetic, the control that must
-fail, the harness taking a new cell as files, and the last line.
+the reference against the program's arithmetic, each model family's file
+against finite differences, the one description of the model, the
+control that must fail, the harness taking a new cell and a new model
+family as files, and the last line. (What BENCHMARK.json must hold is
+test_benchmark_json.py's.)
 
 Nothing here describes a TPU topology; subprocess runs see one CPU
 device (the one-chip path), in-process runs see conftest's eight (the
@@ -204,19 +207,54 @@ def test_device_table_equals_the_rows_numpy_makes():
     assert abs(t[:1000].mean()) < 5e-4
 
 
-@pytest.mark.parametrize("model_type,F,k", [("fm", 0, 4), ("ffm", 5, 3)])
-def test_reference_follows_the_programs_step_arithmetic(model_type, F, k):
+@pytest.fixture
+def tree_families(tiny_root, monkeypatch):
+    """The tree's references/ beside the repo's for this process: the
+    order-3 file exists only there (subprocess runs from the tree find
+    it by themselves)."""
+    import benchmarks.references as pkg
+    monkeypatch.setattr(pkg, "__path__", list(pkg.__path__) + [
+        os.path.join(tiny_root, "benchmarks", "references")])
+    monkeypatch.setattr(reference, "REFERENCES_DIR", os.path.join(
+        tiny_root, "benchmarks", "references"))
+    yield
+    sys.modules.pop("benchmarks.references.fm_order3_tiny", None)
+
+
+def _model(family, model_type, order, F, k):
+    return dict(
+        model_type=model_type, order=order, factor_num=k, field_num=F,
+        row_dim=k * F + 1 if model_type == "ffm" else k + 1,
+        loss_type="logistic", factor_lambda=1e-3, bias_lambda=1e-4,
+        learning_rate=0.05, adagrad_init=0.1, reference_family=family)
+
+
+FAMILIES = [("fm_order2", "fm", 2, 0, 4), ("ffm", "ffm", 2, 5, 3),
+            ("fm_order3_tiny", "fm", 3, 0, 4)]
+
+
+def test_every_family_file_of_the_directory_is_tried_below():
+    assert reference.families() == sorted(f for f, *_ in FAMILIES[:2])
+    assert reference.families() == sorted(
+        f[:-3] for f in os.listdir(os.path.join(REPO, "benchmarks",
+                                                "references"))
+        if f.endswith(".py") and f != "__init__.py")
+
+
+@pytest.mark.parametrize("family,model_type,order,F,k", FAMILIES)
+def test_reference_follows_the_programs_step_arithmetic(
+        tree_families, family, model_type, order, F, k):
     import jax.numpy as jnp
     from fast_tffm_tpu.models.fm import ModelSpec, train_step_body
     rng = np.random.default_rng(0)
     V, B, L = 50, 16, 5
-    D = k * F + 1 if model_type == "ffm" else k + 1
-    model = dict(model_type=model_type, field_num=F, loss_type="logistic",
-                 factor_lambda=1e-3, bias_lambda=1e-4, learning_rate=0.05,
-                 adagrad_init=0.1)
-    T = rng.uniform(-.1, .1, (V + 1, D)).astype(np.float32)
+    model = _model(family, model_type, order, F, k)
+    D = model["row_dim"]
+    # order 3 at a range where its cubic term weighs (tiny_tree.py)
+    T = rng.uniform(-.1, .1, (V + 1, D)).astype(np.float32) * (
+        3 if order == 3 else 1)
     T[-1] = 0
-    spec = ModelSpec(model_type=model_type, order=2, factor_num=k,
+    spec = ModelSpec(model_type=model_type, order=order, factor_num=k,
                      field_num=F, vocabulary_size=V, loss_type="logistic",
                      factor_lambda=1e-3, bias_lambda=1e-4,
                      learning_rate=0.05, kernel="xla", dedup="device")
@@ -227,6 +265,7 @@ def test_reference_follows_the_programs_step_arithmetic(model_type, F, k):
     fields = np.arange(L) % max(F, 1)
     t, a = jnp.asarray(T), jnp.full(T.shape, 0.1, jnp.float32)
     ref = reference.ReferenceTrainer(model, np.arange(V + 1), T)
+    first = None
     for _ in range(3):
         t, a, loss, _ = train_step_body(
             spec, t, a, jnp.asarray(y, jnp.float32), jnp.ones(B), None,
@@ -235,13 +274,99 @@ def test_reference_follows_the_programs_step_arithmetic(model_type, F, k):
             if model_type == "ffm" else None)
         assert ref.step(rows, x, y, np.ones(B), fields) == pytest.approx(
             float(loss), rel=2e-6)
+        first = float(loss) if first is None else first
     assert np.abs(np.asarray(t) - ref.table).max() < 1e-7
+    if order == 3:
+        # and the second-order file in its place is far off: the
+        # degree-3 term is no rounding at this range
+        low = reference.ReferenceTrainer(
+            dict(model, reference_family="fm_order2"), np.arange(V + 1), T)
+        assert abs(low.step(rows, x, y, np.ones(B), fields)
+                   - first) > 1e-4 * first
+
+
+@pytest.mark.parametrize("family,model_type,order,F,k", FAMILIES)
+def test_a_familys_backward_is_the_gradient_of_its_score(
+        tree_families, family, model_type, order, F, k):
+    """Central finite differences of ``score`` in float64, every entry
+    of the gathered rows, against ``backward``; and the bfloat16
+    control path changes the score (a family that ignores ``quant``
+    would make the control pass)."""
+    rng = np.random.default_rng(5)
+    U, B, L = 9, 6, 5
+    model = _model(family, model_type, order, F, k)
+    D = model["row_dim"]
+    P = rng.uniform(-.4, .4, (U, D))
+    inv = rng.integers(0, U, (B, L))
+    x = rng.uniform(.5, 2, (B, L)).round(3)
+    x[0, 3:] = 0
+    fields = np.arange(L) % max(F, 1)
+    c = rng.normal(size=B)                  # dLoss/dscore, any direction
+
+    def f(Q):
+        return reference.scores_and_row_grads(model, Q, inv, x, fields)[0]
+
+    score, backward = reference.scores_and_row_grads(model, P, inv, x,
+                                                     fields)
+    g = backward(c)
+    assert g.shape == (U, D)
+    fd, h = np.empty_like(P), 1e-6
+    for u in range(U):
+        for d in range(D):
+            E = np.zeros_like(P)
+            E[u, d] = h
+            fd[u, d] = c @ (f(P + E) - f(P - E)) / (2 * h)
+    assert np.abs(g - fd).max() < 1e-6 * max(1.0, np.abs(fd).max())
+    low, _ = reference.scores_and_row_grads(model, P, inv, x, fields,
+                                            "bf16")
+    assert 1e-5 < np.abs(low - score).max() < 0.1 * np.abs(score).max()
+
+
+# ---- the one description of the model ---------------------------------
+
+def test_model_of_holds_every_field_the_step_depends_on():
+    """``ModelSpec`` is what the program's compiled step closes over:
+    each of its fields is in the description (``MODEL_FIELDS``) or is
+    named as what does not define the mathematics (``MODEL_APART``). A
+    field added to the step and to neither list fails here."""
+    import dataclasses
+    from fast_tffm_tpu.config import FmConfig
+    from fast_tffm_tpu.models.fm import ModelSpec
+    spec_fields = {f.name for f in dataclasses.fields(ModelSpec)}
+    assert spec_fields <= set(harness.MODEL_FIELDS) | set(harness.MODEL_APART)
+    assert not set(harness.MODEL_FIELDS) & set(harness.MODEL_APART)
+    assert {"order", "row_dim", "adagrad_init"} <= set(harness.MODEL_FIELDS)
+    cfg = FmConfig(vocabulary_size=100, factor_num=4, order=3,
+                   adagrad_init=0.2)
+    model = harness.model_of(cfg, {"reference_family": "fm_order2"})
+    assert set(model) == set(harness.MODEL_FIELDS) | {"reference_family"}
+    assert (model["order"], model["row_dim"], model["adagrad_init"]) == (
+        3, 5, 0.2)
+    for name in harness.MODEL_FIELDS:
+        assert name in harness.model_of.__doc__, name
+
+
+@pytest.mark.parametrize("config,cfg_kw,said", [
+    ({}, {}, "names no reference_family"),
+    ({"reference_family": "no_such_family"}, {}, "no reference family"),
+    ({"reference_family": "fm_order2"},
+     {"model_type": "ffm", "field_num": 3}, "another model"),
+])
+def test_no_family_is_an_error_that_names_the_directory(config, cfg_kw,
+                                                        said):
+    from fast_tffm_tpu.config import FmConfig
+    cfg = FmConfig(vocabulary_size=100, factor_num=4, **cfg_kw)
+    with pytest.raises(harness.RunFailed, match=said) as e:
+        harness.model_of(cfg, config)
+    if "another model" not in said:
+        assert "benchmarks/references/" in str(e.value)
+        assert "fm_order2" in str(e.value)      # what is there
 
 
 @pytest.mark.parametrize("workload", ["tiny-train", "tiny-ffm-train",
-                                      "tiny-predict"])
+                                      "tiny-predict", "tiny-fm3-train"])
 def test_the_bf16_control_comes_out_not_correct(tiny_root, tmp_path,
-                                                workload):
+                                                tree_families, workload):
     """The reference computed in bfloat16 in the program's place fails
     at least one of the cell's numbers at its limit (PERF.md gives the
     readings at each cell's own size)."""
@@ -280,14 +405,48 @@ def _bench(root, *args):
     return p.returncode, p.stdout.strip().splitlines(), p.stderr
 
 
+def _h2d_is_what_the_feed_ships(tiny_root, shown):
+    """``h2d_bytes_per_example`` against the arrays a step is fed: the
+    program's own pipeline over the run's corpus and configuration
+    (the run leaves both in its work directory), every array of a
+    batch's feed counted, per example. On one device the train step
+    takes the host unique, so a feed holds ``uniq_ids`` [U] beside the
+    padded rectangles; the raw-id wire (8 B a cell) would read less."""
+    from fast_tffm_tpu.config import load_config
+    from fast_tffm_tpu.data.pipeline import batch_iterator
+    from fast_tffm_tpu.models.fm import batch_args
+    cfg = load_config(os.path.join(tiny_root, ".bench_work", "tiny-train",
+                                   "run.cfg"))
+    per_example, raw = set(), None
+    for b in batch_iterator(cfg, cfg.train_files, training=True, epochs=3,
+                            raw_ids=False):
+        feed = {k: v for k, v in batch_args(b).items() if v is not None}
+        assert {"uniq_ids", "local_idx", "vals", "labels",
+                "weights"} == set(feed)
+        per_example.add(sum(v.nbytes for v in feed.values())
+                        / len(b.labels))
+        raw = (sum(v.nbytes for v in feed.values())
+               - feed["uniq_ids"].nbytes) / len(b.labels)
+    got = shown["h2d_bytes_per_example"]["value"]
+    assert raw < min(per_example) <= got <= max(per_example)
+    assert 0.5 <= shown["uniq_slot_fill"]["value"] <= 1.0
+
+
 def test_a_cell_added_as_files_runs_without_editing_any(tiny_root):
     """A configuration, a traffic file, a per-layer metric and a cell
     that exist only as new files and new BENCHMARK.json entries run
-    end to end (one CPU device: the one-chip path, device dedup)."""
+    end to end (one CPU device: the one-chip path, host unique)."""
     before = _digest(REPO)
     after = _digest(tiny_root)
     assert all(after[k] == v for k, v in before.items())   # none edited
-    assert len(after) == len(before) + 5                   # five added
+    assert sorted(set(after) - set(before)) == sorted(
+        os.path.join("benchmarks", *p.split("/")) for p in (
+            "configs/tiny-fm.json", "configs/tiny-ffm.json",
+            "configs/tiny-fm3.json",
+            "configs/tiny-fm3-order2-reference.json",
+            "traffic/tiny-train.json", "traffic/tiny-predict.json",
+            "layer_metrics/tiny_steps_per_s.json",
+            "references/fm_order3_tiny.py"))
     rc, out, err = _bench(tiny_root, "--workload", "tiny-train", "--seed",
                           str(2 ** 31 + 11), "--seconds", "1.5",
                           "--trace", "1", "--rehearse-cpu")
@@ -305,7 +464,7 @@ def test_a_cell_added_as_files_runs_without_editing_any(tiny_root):
     assert {"tiny_steps_per_s", "step_device_ms", "input_wait_share",
             "h2d_bytes_per_example", "setup_start_s",
             "setup_compile_s"} <= set(shown)
-    assert shown["h2d_bytes_per_example"]["value"] == 8 * 8 + 8
+    _h2d_is_what_the_feed_ships(tiny_root, shown)
     assert any(l.startswith("check loss_rel_gap_max") for l in out)
     assert any(l.startswith("check span_examples_credited_not_counted: 0 ")
                for l in out)
@@ -333,6 +492,71 @@ def test_predict_cell_runs_and_checks_its_scores(tiny_root):
     last = json.loads(out[-1])
     assert set(last) == RESULT_KEYS and last["correct"] is True
     assert any(l.startswith("check score_abs_gap_max") for l in out)
+
+
+@pytest.mark.parametrize("workload,correct", [
+    ("tiny-fm3-train", True),
+    ("tiny-fm3-order2-reference-train", False)])
+def test_a_model_family_added_as_files_reaches_the_check(tiny_root,
+                                                         workload, correct):
+    """FM of order 3: benchmarks/references/ has no file for it, the
+    tree brings one (power sums; tiny_tree.ORDER3_REFERENCE) and no
+    file of the repo's is edited (the digest test above). With its own
+    reference the order-3 program is correct; the same configuration
+    naming the second-order file is not, on all three numbers: `order`
+    and the family reach the check."""
+    rc, out, err = _bench(tiny_root, "--workload", workload, "--seed",
+                          str(2 ** 31 + 31), "--seconds", "1.5", "--trace",
+                          "0", "--rehearse-cpu")
+    assert rc == 0, err[-3000:]
+    last = json.loads(out[-1])
+    assert last["correct"] is correct and last["failed"] == 0
+    checks = {l.split()[1].rstrip(":"): l.split()[-1] for l in out
+              if l.startswith("check ")}
+    gaps = [k for k in checks if "gap" in k]
+    assert len(gaps) == 3
+    assert all(checks[k] == ("ok" if correct else "FAILED") for k in gaps)
+    assert all(v == "ok" for k, v in checks.items() if k not in gaps)
+
+
+def test_the_control_of_a_family_added_as_files_fails(tiny_root):
+    """``python3 -m benchmarks.control`` from the tree, as a builder
+    runs it: the order-3 reference in bfloat16 in the program's place
+    fails at least one limit on every seed."""
+    p = subprocess.run(
+        [sys.executable, "-m", "benchmarks.control", "--workload",
+         "tiny-fm3-train", "--seeds", "1,2,3"], cwd=tiny_root,
+        env=tiny_tree.env(), capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    lines = [json.loads(l) for l in p.stdout.strip().splitlines()]
+    assert [l["seed"] for l in lines] == [1, 2, 3]
+    assert all(l["correct"] is False and l["fails"] for l in lines)
+
+
+def test_a_configuration_that_names_no_family_fails_at_once(tiny_root,
+                                                            tmp_path):
+    """Before anything is timed, with exit code 1 and no result line,
+    as a device with no peaks does."""
+    import shutil
+    root = str(tmp_path / "tree")
+    shutil.copytree(tiny_root, root,
+                    ignore=shutil.ignore_patterns(".bench_work"))
+    path = os.path.join(root, "benchmarks", "configs", "tiny-fm3.json")
+    with open(path) as fh:
+        config = json.load(fh)
+    for family, said in ((None, "names no reference_family"),
+                         ("fm_order4", "no reference family 'fm_order4'")):
+        config.pop("reference_family")
+        if family:
+            config["reference_family"] = family
+        with open(path, "w") as fh:
+            json.dump(config, fh)
+        rc, out, err = _bench(root, "--workload", "tiny-fm3-train",
+                              "--seed", "1", "--seconds", "600", "--trace",
+                              "0", "--rehearse-cpu")
+        assert rc == 1 and said in err and "benchmarks/references/" in err
+        assert not any(l.startswith("{") for l in out)
+        config["reference_family"] = "x"
 
 
 def test_no_chip_no_result(tiny_root):
@@ -446,30 +670,3 @@ def test_trace_reduction_on_a_recorded_tpu_trace():
     assert sum(gaps.values()) == pytest.approx(t.window_s - t.busy_s,
                                                rel=1e-6)
     assert max(gaps, key=gaps.get) == "python_between_runtime_calls"
-
-
-def test_benchmark_json_finds_every_file_it_names():
-    import re
-    with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
-        spec = json.load(fh)
-    assert set(spec) == {"command", "paths", "run_seconds", "configs",
-                         "workloads", "end_to_end", "per_layer"}
-    name = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-    e2e = {m["name"]: m for m in spec["end_to_end"]}
-    assert "setup_s" in e2e and all(m["bound"] <= 0.1 for m in e2e.values())
-    cells = {w["name"] for w in spec["workloads"]}
-    for w in spec["workloads"]:
-        assert name.match(w["name"]) and len(w["why"]) <= 200
-        cell = harness.load_cell(w["name"])
-        assert cell.kind in ("train", "predict")
-        assert {m["name"] for m in cell.end_to_end} > {"setup_s"}
-        assert cell.per_layer
-    for m in spec["per_layer"]:
-        assert name.match(m["name"]) and m["moves"] in e2e
-        assert set(m.get("workloads", cells)) <= cells
-        with open(os.path.join(REPO, "benchmarks", "layer_metrics",
-                               m["name"] + ".json")) as fh:
-            own = json.load(fh)
-        assert own["layer"] == m["layer"] and own["moves"] == m["moves"]
-        assert os.path.exists(os.path.join(
-            REPO, "benchmarks", "readers", own["reader"] + ".py"))

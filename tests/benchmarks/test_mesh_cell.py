@@ -50,6 +50,7 @@ def test_the_x4_configuration_is_the_one_chip_share_times_four(spec):
     assert x4["mesh_chips"] == 4 and x4["deployment_chips"] == 64
     assert "synchronous" in x4["guarantees"]
     assert "reference.py" in x4["reference"]
+    assert x4["reference_family"] == one["reference_family"] == "fm_order2"
     assert set(x4["reduced"]) == set(x4["reduced_why"]) == {
         "vocabulary_size", "corpus_lines", "mesh_chips"}
     entry = next(c for c in spec["configs"] if c["name"] == x4["name"])
@@ -69,7 +70,7 @@ def test_the_x4_table_needs_the_four_chips(monkeypatch, tmp_path,
     run = harness.Run(cell=harness.load_cell(CELL), seed=1, seconds=1.0,
                       trace=False, rehearse=True, t0=0.0)
     monkeypatch.setattr(harness, "WORK_ROOT", str(tmp_path / "work"))
-    cfg = harness.write_program_cfg(run, {})
+    cfg = harness.program_cfg(run.cell.config, {}, run.work_dir)
     monkeypatch.setenv(mem.FAKE_CAPACITY_ENV, str(V5E_HBM))
     p = mem.plan(cfg, "train", {"shards": shards})
     assert p["verdict"] == verdict
@@ -83,8 +84,8 @@ def test_the_cell_asks_for_four_chips_and_reports_what_it_should(spec):
     assert cell.chips == 4 and cell.kind == "train"
     assert cell.traffic == _json("benchmarks", "traffic",
                                  "train-zipf.json")
-    four = [w["name"] for w in spec["workloads"] if w["chips"] == 4]
-    assert four == [CELL] and spec["workloads"][-1]["name"] == CELL
+    # (how many cells may ask for four, and that this one keeps its
+    # place in every list, is test_benchmark_json.py's)
     assert {m["name"] for m in cell.end_to_end} == {
         "train_examples_per_s_per_chip", "setup_s"}
     reported = {m["name"] for m in cell.per_layer}
@@ -94,20 +95,15 @@ def test_the_cell_asks_for_four_chips_and_reports_what_it_should(spec):
             "table_scatter_ms", "step_unscoped_ms", "setup_start_s",
             "setup_compile_s"} <= reported
     assert "dedup_sort_ms" not in reported   # no such scope in the step
-    # appended: an entry put anywhere but the end reads as an edit
-    for m in spec["end_to_end"] + spec["per_layer"]:
-        if CELL in m.get("workloads", ()):
-            assert m["workloads"][-1] == CELL
 
 
 # ---- the metric and its reader ----------------------------------------
 
 def test_the_metric_file_matches_its_entry(spec):
     own = _json("benchmarks", "layer_metrics", "collective_exposed_ms.json")
-    entry = spec["per_layer"][-1]
-    assert entry == {k: own[k] for k in ("name", "unit", "better",
-                                         "source", "layer", "moves")
-                     } | {"workloads": [CELL]}
+    entry = next(m for m in spec["per_layer"]
+                 if m["name"] == "collective_exposed_ms")
+    assert entry["workloads"][0] == CELL    # a mesh's metric: x4 cells
     assert own["reader"] == "collective_device_ms"
     assert own["args"] == {"programs": MESH_STEP}
     assert own["source"] == "device_trace" and own["better"] == "lower"

@@ -327,20 +327,26 @@ def test_counters_read_zero_not_absent_before_the_first_barrier(
 
 # ---- BENCHMARK.json and the predict files ------------------------------
 
-def test_benchmark_json_lists_the_nine_for_both_train_cells():
+def test_benchmark_json_lists_the_nine_among_one_layers_metrics():
+    """PR 25's nine are in ``per_layer``, in their order (where in the
+    list, and that none is gone, is test_benchmark_json.py's prefix
+    rule), each moving the train rate on a layer the list already
+    had, with the file it was given then."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
         spec = json.load(fh)
     by = {m["name"]: m for m in spec["per_layer"]}
-    assert [m["name"] for m in spec["per_layer"]][-9:] == list(NEW_METRICS)
+    names = [m["name"] for m in spec["per_layer"]]
+    assert [n for n in names if n in NEW_METRICS] == list(NEW_METRICS)
+    first = names.index(NEW_METRICS[0])
+    layers = {m["layer"] for m in spec["per_layer"][:first]}
     for name in NEW_METRICS:
-        own = _metric_file(name)
-        for k in ("unit", "better", "source", "layer", "moves"):
-            assert by[name][k] == own[k], (name, k)
-        assert by[name]["workloads"] == ["fm16-train-zipf",
-                                         "ffm4-train-zipf"]
         assert by[name]["moves"] == "train_examples_per_s_per_chip"
-    layers = {m["layer"] for m in spec["per_layer"][:-9]}
-    assert {by[n]["layer"] for n in NEW_METRICS} <= layers
+        assert by[name]["layer"] in layers
+        assert by[name]["workloads"][:2] == ["fm16-train-zipf",
+                                             "ffm4-train-zipf"]
+        reader = _metric_file(name)["reader"]
+        assert reader == ("scope_device_ms" if name.endswith("_ms")
+                          else "telemetry_window")
 
 
 def test_span_idle_share_reads_each_phases_own_part_of_the_gaps():

@@ -1,5 +1,6 @@
-"""A copy of the benchmark with one tiny configuration, traffic file,
-per-layer metric and cell ADDED AS FILES, and one new entry each in
+"""A copy of the benchmark with tiny configurations, traffic files, a
+per-layer metric, cells and the reference of a model family the
+benchmark has none for, all ADDED AS FILES, and new entries in
 BENCHMARK.json: what a later PR does, at a size the CPU holds. The
 predict cell comes with its end-to-end metric and the per-layer
 metrics whose files benchmarks/layer_metrics/ already holds: what the
@@ -17,6 +18,7 @@ TINY_CONFIG = {
     "name": "tiny-fm",
     "source": "test",
     "reduced": [],
+    "reference_family": "fm_order2",
     "program": {
         "General": {"vocabulary_size": 4096, "hash_feature_id": True,
                     "factor_num": 4, "model_type": "fm"},
@@ -32,13 +34,73 @@ TINY_CONFIG = {
         "predict": {"score_abs_gap_max": 2e-5}},
 }
 TINY_FFM = dict(
-    TINY_CONFIG, name="tiny-ffm",
+    TINY_CONFIG, name="tiny-ffm", reference_family="ffm",
     program={"General": {"vocabulary_size": 4096, "hash_feature_id": True,
                          "factor_num": 2, "model_type": "ffm",
                          "field_num": 4},
              "Train": TINY_CONFIG["program"]["Train"]},
     features={"numeric": 1, "categorical_cardinalities": [50, 7, 300],
               "zipf_a": 1.35, "positive_rate": 0.3})
+# FM of order 3 (the ANOVA kernels of degree 2 and 3 over one factor
+# matrix): a family benchmarks/references/ has no file for, so the tree
+# brings its own (ORDER3_REFERENCE). init_value_range is 0.3, not the
+# 0.01 of the others: at 0.01 the degree-3 term is about 7e-3 of the
+# gradient and, being uncorrelated with the rest, moves a leaf's norm
+# by about 2.5e-5, UNDER the 5e-5 limit, so a reference that dropped it
+# would pass; at 0.3 it stands far above every limit.
+TINY_FM3 = dict(
+    TINY_CONFIG, name="tiny-fm3", reference_family="fm_order3_tiny",
+    program={"General": dict(TINY_CONFIG["program"]["General"], order=3),
+             "Train": dict(TINY_CONFIG["program"]["Train"],
+                           init_value_range=0.3)})
+# The same configuration with the second-order file named: `order`
+# and the family have to reach the check, so this is NOT correct.
+TINY_FM3_WRONG = dict(TINY_FM3, name="tiny-fm3-order2-reference",
+                      reference_family="fm_order2")
+ORDER3_REFERENCE = '''"""FM of order 3 (Blondel et al. 2016): rows ``[v (k) | w]``, the ANOVA
+kernels of degree 2 and 3 over ONE factor matrix, per factor column on
+power sums (Newton's identities), with z_l = x_l v_l, p_m = sum_l z_l^m:
+
+    A2 = (p1^2 - p2) / 2           dA2/dz_l = p1 - z_l
+    A3 = (p1^3 - 3 p1 p2 + 2 p3)/6 dA3/dz_l = (p1^2 - p2)/2 - p1 z_l + z_l^2
+    score = sum_l w_l x_l + sum_k (A2 + A3)
+
+Independent of the program's recurrence over the feature slots."""
+
+import numpy as np
+
+from benchmarks.reference import quantize, scatter_rows
+
+
+def row_dim(model):
+    return int(model["factor_num"]) + 1
+
+
+def scores_and_row_grads(model, P, inv, x, fields, quant=None):
+    if int(model["order"]) != 3:
+        raise ValueError("this is the reference of order 3")
+    B, L = inv.shape
+    U, D = P.shape
+    rows = quantize(P, quant)[inv]
+    xq = quantize(x, quant)
+    w, v = rows[..., -1], rows[..., :-1]
+    z = quantize(v * xq[..., None], quant)              # [B, L, k]
+    p1 = quantize(z.sum(axis=1), quant)
+    p2 = quantize(np.square(z).sum(axis=1), quant)
+    p3 = quantize((z ** 3).sum(axis=1), quant)
+    a2 = 0.5 * (np.square(p1) - p2)
+    a3 = (p1 ** 3 - 3.0 * p1 * p2 + 2.0 * p3) / 6.0
+    score = (w * xq).sum(axis=1) + (a2 + a3).sum(axis=-1)
+
+    def backward(ds):
+        dz = ((p1[:, None, :] - z)
+              + (a2[:, None, :] - p1[:, None, :] * z + np.square(z)))
+        g = np.empty((B, L, D))
+        g[..., -1] = ds[:, None] * xq
+        g[..., :-1] = ds[:, None, None] * xq[..., None] * dz
+        return scatter_rows(inv, g, U)
+    return score, backward
+'''
 TINY_TRAIN = {"kind": "train", "corpus_batches": 4, "corpus_files": 2,
               "corpus_passes": 2, "steps_per_reading": 4, "warmup_readings": 1,
               "checked_steps": 3, "trace_seconds": 0.3}
@@ -73,26 +135,32 @@ def make(dst: str) -> str:
     with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
         spec = json.load(fh)
     b = os.path.join(dst, "benchmarks")
-    spec["end_to_end"].append(dict(PREDICT_E2E))
+    spec["end_to_end"].append(dict(PREDICT_E2E, workloads=[]))
     for name in PREDICT_LAYER:
         with open(os.path.join(b, "layer_metrics", name + ".json")) as fh:
             own = json.load(fh)
         spec["per_layer"].append(
             {k: own[k] for k in ("name", "unit", "better", "source",
                                  "layer", "moves")} | {"workloads": []})
-    _dump(os.path.join(b, "configs", "tiny-fm.json"), TINY_CONFIG)
-    _dump(os.path.join(b, "configs", "tiny-ffm.json"), TINY_FFM)
+    for config in (TINY_CONFIG, TINY_FFM, TINY_FM3, TINY_FM3_WRONG):
+        name = config["name"]
+        _dump(os.path.join(b, "configs", name + ".json"), config)
+        spec["configs"].append({
+            "name": name, "source": "test", "reduced": [], "why": "test",
+            "file": f"benchmarks/configs/{name}.json"})
+    with open(os.path.join(b, "references", "fm_order3_tiny.py"), "w",
+              encoding="utf-8") as fh:
+        fh.write(ORDER3_REFERENCE)
     _dump(os.path.join(b, "traffic", "tiny-train.json"), TINY_TRAIN)
     _dump(os.path.join(b, "traffic", "tiny-predict.json"), TINY_PREDICT)
     _dump(os.path.join(b, "layer_metrics", "tiny_steps_per_s.json"),
           TINY_METRIC)
-    for name in ("tiny-fm", "tiny-ffm"):
-        spec["configs"].append({
-            "name": name, "source": "test", "reduced": [], "why": "test",
-            "file": f"benchmarks/configs/{name}.json"})
     cells = [("tiny-train", "tiny-fm", "tiny-train"),
              ("tiny-ffm-train", "tiny-ffm", "tiny-train"),
-             ("tiny-predict", "tiny-fm", "tiny-predict")]
+             ("tiny-predict", "tiny-fm", "tiny-predict"),
+             ("tiny-fm3-train", "tiny-fm3", "tiny-train"),
+             ("tiny-fm3-order2-reference-train",
+              "tiny-fm3-order2-reference", "tiny-train")]
     for name, config, traffic in cells:
         spec["workloads"].append({"name": name, "config": config,
                                   "traffic": traffic, "chips": 1,
