@@ -604,7 +604,7 @@ extern "C" {
 // and the tolerant keep_empty shape routed serial); 8 = the builder
 // stages cells flat and fm_bb_finish takes the output width (fm_bb_peek
 // sizes it): a batch costs its own cells, not B x the feature cap.
-int64_t fm_abi_version() { return 8; }
+int64_t fm_abi_version() { return 9; }
 
 // Scan complete lines of [blob, blob+blob_len) until `n_target` lines
 // that PRODUCE AN EXAMPLE have been seen. The counting rule must equal
@@ -742,6 +742,14 @@ struct BatchBuilder {
   int64_t field_num = 0;
   int max_feats;
   int64_t max_uniq;  // 0 = unlimited; else batch closes BEFORE exceeding
+  // A mesh's feed (fm_bb_row_shards): the unique rows ship as one
+  // segment per row shard, so beside max_uniq each shard's rows (row /
+  // shard_rows names the shard) close the batch BEFORE exceeding
+  // shard_cap. shard_rows 0 = one list, no such budget.
+  int64_t shard_rows = 0;
+  int32_t shard_cap = 0;
+  std::vector<int32_t> shard_cnt;  // this batch's unique rows, by shard
+  int32_t shards_over = 0;         // shards past shard_cap right now
   int T = 1;         // feed parse threads (1 = the serial in-line path)
   // The batch under construction, staged FLAT: example e owns the next
   // sizes[e] cells. fm_bb_finish pads them out to the width the caller
@@ -798,6 +806,15 @@ void bb_reset(BatchBuilder* bb) {
   bb->vals.clear();
   bb->fields.clear();
   bb->uniq.resize(1);
+  std::fill(bb->shard_cnt.begin(), bb->shard_cnt.end(), 0);
+  bb->shards_over = 0;
+}
+
+// The batch is past a unique-row budget: the whole list's, or one row
+// shard's segment of it.
+inline bool bb_over_budget(const BatchBuilder* bb) {
+  return (bb->max_uniq != 0 && bb->n_uniq > bb->max_uniq) ||
+         bb->shards_over > 0;
 }
 
 inline uint32_t bb_hash(const BatchBuilder* bb, int32_t key) {
@@ -831,6 +848,11 @@ inline int32_t bb_slot(BatchBuilder* bb, int32_t key) {
       bb->stamp[h] = bb->cur_stamp;
       bb->slot[h] = bb->n_uniq;
       bb->uniq.push_back(key);
+      if (bb->shard_rows != 0 &&
+          ++bb->shard_cnt[size_t(key / bb->shard_rows)] ==
+              bb->shard_cap + 1) {
+        bb->shards_over++;
+      }
       return bb->n_uniq++;
     }
     if (bb->uniq[size_t(bb->slot[h])] == key) return bb->slot[h];
@@ -852,6 +874,11 @@ inline void bb_rollback_line(BatchBuilder* bb, int32_t saved_uniq) {
       h = (h + 1) & bb->mask;
     }
     bb->stamp[h] = 0;
+    if (bb->shard_rows != 0 &&
+        bb->shard_cnt[size_t(key / bb->shard_rows)]-- ==
+            bb->shard_cap + 1) {
+      bb->shards_over--;
+    }
   }
   bb->n_uniq = saved_uniq;
   bb->uniq.resize(size_t(saved_uniq));
@@ -917,7 +944,7 @@ int bb_drain(BatchBuilder* bb, char* err_out, int64_t err_cap) {
       const int32_t* flds = bb->p_fields.data() + bb->p_nnz;
       bb->fields.insert(bb->fields.end(), flds, flds + nf);
     }
-    if (bb->max_uniq != 0 && bb->n_uniq > bb->max_uniq) {
+    if (bb_over_budget(bb)) {
       return bb_budget_close(bb, cells, saved_uniq, bb->p_linenos[e],
                              err_out, err_cap);
     }
@@ -1058,6 +1085,28 @@ void* fm_bb_new(int64_t B, int64_t L, int64_t vocab, int hash_ids,
   return bb;
 }
 
+
+// A mesh's feed: ``n_shards`` row shards of ``shard_rows`` rows each,
+// at most ``shard_cap`` unique rows of one shard in a batch (its
+// segment of the fixed unique bucket, less the pad slot). Returns 0,
+// or -1 where one line's features could overflow a segment of an empty
+// batch (shard_cap < the per-example feature cap) or no dedup runs.
+int fm_bb_row_shards(void* h, int64_t shard_rows, int64_t n_shards,
+                     int64_t shard_cap) {
+  auto* bb = static_cast<BatchBuilder*>(h);
+  if (bb->raw_ids || shard_rows <= 0 || n_shards <= 0 ||
+      shard_cap < bb->max_feats) {
+    return -1;
+  }
+  bb->shard_rows = shard_rows;
+  bb->shard_cap = int32_t(shard_cap);
+  // pad_id's own shard is never counted (the pad slot is no row), but
+  // vocab / shard_rows may name the last shard: size for it.
+  bb->shard_cnt.assign(size_t(n_shards), 0);
+  bb->shards_over = 0;
+  return 0;
+}
+
 void fm_bb_free(void* h) { delete static_cast<BatchBuilder*>(h); }
 
 // Parse lines from blob until the batch has B examples or the blob's
@@ -1137,7 +1186,7 @@ int fm_bb_feed(void* h, const char* blob, int64_t blob_len,
       n_feats++;
       q = tok_end;
     }
-    if (bb->max_uniq != 0 && bb->n_uniq > bb->max_uniq) {
+    if (bb_over_budget(bb)) {
       // This line would push the batch past its unique-row budget:
       // roll it back, close the batch early (spill protocol — the line
       // is left unconsumed and opens the next batch). fm_bb_new
@@ -1166,22 +1215,41 @@ int64_t fm_bb_peek(void* h, int64_t* n_uniq_out, int64_t* max_nnz_out) {
   return bb->n_ex;
 }
 
+// Feature cells of the batch under construction (every example's real
+// features; padding is not staged).
+int64_t fm_bb_cells(void* h) {
+  return int64_t(static_cast<BatchBuilder*>(h)->idx.size());
+}
+
+// The unique slots of the batch under construction, uniq_out[n_uniq]
+// (slot 0 = pad_id): what a caller needs to give fm_bb_finish a remap.
+void fm_bb_uniq(void* h, int32_t* uniq_out) {
+  auto* bb = static_cast<BatchBuilder*>(h);
+  std::memcpy(uniq_out, bb->uniq.data(),
+              size_t(bb->n_uniq) * sizeof(int32_t));
+}
+
 // Pad the accumulated batch out to [B, cols] and reset for the next one.
 // labels_out[B], uniq_out[n_uniq] (slot 0 = pad_id), li_out[B*cols],
 // vals_out[B*cols], fields_out[B*cols] (field_aware builders only; may
 // be null otherwise); fm_bb_peek gives n_uniq and the widest example,
 // which ``cols`` must cover (<= L). Every output cell is written: pad
 // cells are slot 0 (the raw pad id == vocab in raw mode) with value 0,
-// as are the rows past n_examples. Returns n_examples (0 if the batch
-// is empty), -1 when ``cols`` is too narrow (nothing is reset).
+// as are the rows past n_examples. ``remap`` (may be null; [n_uniq]):
+// every cell is written as remap[slot], for a caller that ships the
+// unique slots in another order (a mesh's feed, by owning row shard) —
+// the cells are re-pointed as they are padded out, not in a pass of
+// their own. Returns n_examples (0 if the batch is empty), -1 when
+// ``cols`` is too narrow (nothing is reset).
 int64_t fm_bb_finish(void* h, int64_t cols, float* labels_out,
                      int32_t* uniq_out, int32_t* li_out, float* vals_out,
-                     int32_t* fields_out) {
+                     int32_t* fields_out, const int32_t* remap) {
   auto* bb = static_cast<BatchBuilder*>(h);
   if (cols < bb->max_nnz || cols > bb->L || cols <= 0) return -1;
   const int64_t n = bb->n_ex;
   const size_t C = size_t(cols);
-  const int32_t pad = bb->raw_ids ? int32_t(bb->vocab) : 0;
+  const int32_t pad =
+      bb->raw_ids ? int32_t(bb->vocab) : (remap != nullptr ? remap[0] : 0);
   const bool with_fields = bb->field_aware && fields_out != nullptr;
   std::memcpy(labels_out, bb->labels.data(), size_t(n) * sizeof(float));
   std::fill(labels_out + n, labels_out + bb->B, 0.0f);
@@ -1192,7 +1260,12 @@ int64_t fm_bb_finish(void* h, int64_t cols, float* labels_out,
     const size_t nf = r < n ? size_t(bb->sizes[size_t(r)]) : 0;
     int32_t* irow = li_out + size_t(r) * C;
     float* vrow = vals_out + size_t(r) * C;
-    std::memcpy(irow, bb->idx.data() + z, nf * sizeof(int32_t));
+    if (remap != nullptr) {
+      const int32_t* cells = bb->idx.data() + z;
+      for (size_t j = 0; j < nf; j++) irow[j] = remap[cells[j]];
+    } else {
+      std::memcpy(irow, bb->idx.data() + z, nf * sizeof(int32_t));
+    }
     std::fill(irow + nf, irow + C, pad);
     std::memcpy(vrow, bb->vals.data() + z, nf * sizeof(float));
     std::memset(vrow + nf, 0, (C - nf) * sizeof(float));

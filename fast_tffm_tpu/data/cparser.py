@@ -116,7 +116,7 @@ def _build(out: str) -> None:
 
 # Must equal fm_abi_version() in _parser.cc. Bump both together whenever
 # an exported signature changes.
-_ABI_VERSION = 8
+_ABI_VERSION = 9
 
 
 def _open_checked(path: str) -> ctypes.CDLL:
@@ -129,6 +129,7 @@ def _open_checked(path: str) -> ctypes.CDLL:
         for sym in ("fm_abi_version", "fm_auto_threads", "fm_parse_block",
                     "fm_dedup_ids", "fm_scan_examples", "fm_bb_new",
                     "fm_bb_feed", "fm_bb_peek", "fm_bb_finish",
+                    "fm_bb_row_shards", "fm_bb_uniq", "fm_bb_cells",
                     "fm_bb_free"):
             getattr(lib, sym)
         lib.fm_abi_version.restype = ctypes.c_int64
@@ -208,6 +209,9 @@ def _load() -> ctypes.CDLL:
                                   ctypes.c_int, ctypes.c_int64,
                                   ctypes.c_int]                  # num_threads
         lib.fm_bb_free.argtypes = [ctypes.c_void_p]
+        lib.fm_bb_row_shards.restype = ctypes.c_int
+        lib.fm_bb_row_shards.argtypes = [ctypes.c_void_p, ctypes.c_int64,
+                                         ctypes.c_int64, ctypes.c_int64]
         lib.fm_bb_feed.restype = ctypes.c_int
         lib.fm_bb_feed.argtypes = [
             ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
@@ -224,7 +228,13 @@ def _load() -> ctypes.CDLL:
             np.ctypeslib.ndpointer(np.int32),             # uniq
             np.ctypeslib.ndpointer(np.int32),             # local_idx
             np.ctypeslib.ndpointer(np.float32),           # vals
-            np.ctypeslib.ndpointer(np.int32)]             # fields
+            np.ctypeslib.ndpointer(np.int32),             # fields
+            ctypes.c_void_p]                              # remap or NULL
+        lib.fm_bb_cells.restype = ctypes.c_int64
+        lib.fm_bb_cells.argtypes = [ctypes.c_void_p]
+        lib.fm_bb_uniq.restype = None
+        lib.fm_bb_uniq.argtypes = [ctypes.c_void_p,
+                                   np.ctypeslib.ndpointer(np.int32)]
         _lib = lib
         return lib
 
@@ -409,7 +419,7 @@ class BatchBuilder:
                  field_aware: bool = False, field_num: int = 0,
                  raw_ids: bool = False, keep_empty: bool = False,
                  max_features_per_example: int = 0, max_uniq: int = 0,
-                 num_threads: int = 0):
+                 num_threads: int = 0, row_shards=None):
         """``max_uniq`` > 0 caps the batch's unique-row count (incl. the
         pad slot): a line that would exceed it closes the batch early
         (spill) and opens the next one — the fixed-U protocol for
@@ -423,7 +433,11 @@ class BatchBuilder:
         one-score-per-input-line alignment. ``num_threads`` sets the
         feed parse-thread count (0 = auto: min(8, cores)); with more
         than one thread each fed chunk is parsed in parallel and
-        drained serially, with byte-identical outputs."""
+        drained serially, with byte-identical outputs. ``row_shards``
+        (a mesh's fixed-U feed): ``(rows per shard, shards, cap)`` — a
+        line that would give one row shard more than ``cap`` unique
+        rows closes the batch early too, as its rows ship in that
+        shard's segment of the bucket (pipeline.segment_plan)."""
         self._lib = _load()
         self.B, self.L = batch_size, max_cols
         self.field_aware = field_aware
@@ -442,6 +456,12 @@ class BatchBuilder:
             raise ValueError("fm_bb_new rejected its arguments (bad "
                              "sizes, or max_uniq <= max feature count "
                              "per example)")
+        if row_shards is not None and self._lib.fm_bb_row_shards(
+                self._h, *row_shards):
+            raise ValueError(
+                f"row shards {row_shards} (rows per shard, shards, "
+                "unique rows a shard may hold): one example's features "
+                "have to fit a shard's segment of uniq_bucket")
         self._err = ctypes.create_string_buffer(512)
 
     def feed(self, chunk: bytes, offset: int = 0) -> "tuple[bool, int]":
@@ -468,13 +488,19 @@ class BatchBuilder:
             tel.count("pipeline/bytes_fed", consumed.value)
         return rc == 1, consumed.value
 
-    def finish(self, cols=None):
+    def finish(self, cols=None, slots=None):
         """-> (n_examples, labels[B], uniq[n_uniq], local_idx[B,C],
         vals[B,C], fields[B,C]-or-None, max_nnz); resets the builder.
         C is ``max_cols``, or ``cols(max_nnz)`` where a caller fits the
         width to the batch's widest example (the builder stages cells
         flat, so a narrow batch is padded out once, to the width it
-        ships at)."""
+        ships at). ``slots(uniq, max_nnz) -> (uniq_ids, remap)`` says
+        how the unique slots ship (pipeline._BatchEmitter.slots): the
+        tuple then holds ``uniq_ids`` for ``uniq``, and where ``remap``
+        is not None every cell of ``local_idx`` is ``remap[slot]``,
+        re-pointed as the cells are padded out. ``self.cells`` then
+        holds the batch's feature cells (padding not counted)."""
+        self.cells = int(self._lib.fm_bb_cells(self._h))
         n_uniq = ctypes.c_int64(0)
         max_nnz = ctypes.c_int64(0)
         self._lib.fm_bb_peek(self._h, ctypes.byref(n_uniq),
@@ -482,16 +508,25 @@ class BatchBuilder:
         C = self.L if cols is None else int(cols(int(max_nnz.value)))
         labels = np.empty(self.B, np.float32)
         uniq = np.empty(n_uniq.value, np.int32)
+        ships = remap = None
+        if slots is not None and not self.raw_ids:
+            self._lib.fm_bb_uniq(self._h, uniq)
+            ships, remap = slots(uniq, int(max_nnz.value))
+            if remap is not None:
+                remap = np.ascontiguousarray(remap, np.int32)
         li = np.empty((self.B, C), np.int32)
         vals = np.empty((self.B, C), np.float32)
         fields = np.empty((self.B, C) if self.field_aware else (1, 1),
                           np.int32)
-        n = self._lib.fm_bb_finish(self._h, C, labels, uniq, li, vals,
-                                   fields)
+        n = self._lib.fm_bb_finish(
+            self._h, C, labels, uniq, li, vals, fields,
+            None if remap is None else remap.ctypes.data)
         if n < 0:
             raise ValueError(f"finish: {C} columns do not hold the "
                              f"batch's widest example ({max_nnz.value}) "
                              f"or exceed max_cols ({self.L})")
+        if ships is not None:
+            uniq = ships
         return (int(n), labels, None if self.raw_ids else uniq, li, vals,
                 fields if self.field_aware else None, int(max_nnz.value))
 

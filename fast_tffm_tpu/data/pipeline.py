@@ -142,6 +142,12 @@ class DeviceBatch:
     vals: np.ndarray         # f32 [B, L]; 0.0 padding
     fields: Optional[np.ndarray] = None  # i32 [B, L]; 0 padding (FFM)
     num_real: int = 0        # examples that are not padding
+    # Segments of ``uniq_ids``, one per row shard of the mesh the batch
+    # was built for (segment_plan); 1 = one list in first-seen order.
+    row_shards: int = 1
+    # Real feature cells (padding not counted), where the C++ builder
+    # counted them as it parsed; None: a reader counts them itself.
+    nnz: Optional[int] = None
     # Streaming run mode only (data/stream.py): the durable stream
     # position AFTER this batch's lines — a watermark payload dict the
     # train loop adopts once the batch has actually been stepped, so
@@ -262,14 +268,21 @@ def _ladder_fit(n: int, ladder: Sequence[int]) -> int:
     return b
 
 
+# The unique-row ladder's smallest rung. A mesh cuts every rung into
+# one segment per row shard (segment_plan), so it may have no more row
+# shards than this (parallel/sharded.make_mesh checks).
+UNIQ_LADDER_MIN = 64
+
+
 def _uniq_ladder(batch_size: int, max_l: int) -> List[int]:
     """Power-of-two ladder for the unique-row bucket; the top rung is the
     first power of two > B*L (so a padding slot exists even when every id
     is distinct). All rungs stay powers of two because mesh-sharded runs
-    split the U axis across devices (parallel/sharded.py) and explicit
-    shardings need divisible dims."""
+    split the U axis across devices (parallel/sharded.py: one segment of
+    every rung per row shard) and explicit shardings need divisible
+    dims."""
     cap = batch_size * max_l + 1
-    out, b = [], 64
+    out, b = [], UNIQ_LADDER_MIN
     while b < cap:
         out.append(b)
         b *= 2
@@ -277,12 +290,104 @@ def _uniq_ladder(batch_size: int, max_l: int) -> List[int]:
     return out
 
 
+@dataclasses.dataclass(frozen=True)
+class RowShards:
+    """How a mesh cuts the table's rows (parallel/sharded.ROW_SPEC):
+    ``n`` equal blocks of ``rows`` rows, block s on shard s."""
+    n: int
+    rows: int
+    pad_id: int
+
+    @classmethod
+    def of(cls, cfg: FmConfig, n: int) -> Optional["RowShards"]:
+        """``n`` shards of ``cfg``'s table, or None where one device
+        holds every row and the slots stay in first-seen order."""
+        return cls(n, cfg.ckpt_rows // n, cfg.pad_id) if n > 1 else None
+
+
+def segment_plan(uniq: np.ndarray, shards: RowShards,
+                 fit) -> Tuple[np.ndarray, np.ndarray]:
+    """Order a batch's unique rows by the shard that holds them.
+
+    ``uniq`` is the batch's slot list (distinct rows in first-seen
+    order, ``pad_id`` in any slot that holds none). Returns
+    ``(uniq_ids[U], slot)``: ``uniq_ids`` is ``shards.n`` segments of
+    ``U / n`` slots, segment s holding the rows of ``[s * shards.rows,
+    (s + 1) * shards.rows)`` in first-seen order, then ``pad_id``; and
+    ``slot[j]`` is where ``uniq[j]`` went, so an index ``i`` into
+    ``uniq`` becomes ``slot[i]`` into ``uniq_ids`` and names the same
+    row. ``uniq_ids`` ships ``P("data")``, so on the mesh segment s
+    lands on the shard that holds its rows, and that shard's gather
+    and Adagrad passes walk ``U / n`` slots instead of all U
+    (parallel/sharded.sharded_train_step_body).
+
+    ``fit(need) -> U`` is the caller's rule for the slot count (the
+    ladder's rung, or the fixed bucket); ``need`` is ``n`` times one
+    more than the FULLEST shard's rows: every segment keeps a pad slot,
+    so the last slot is padding (the pipeline's invariant; a pad slot
+    of ``uniq`` goes there) and U follows the fullest shard, never the
+    total. A pad slot names ``pad_id`` in EVERY segment: one shard
+    holds that dead row and gathers zeros from it, the others find it
+    outside their block (they gather with fill and scatter with drop),
+    and its gradient is masked to exactly zero either way, so the
+    masks of ``grad_body`` and ``batch_reg`` stay as they are."""
+    n = shards.n
+    # Pad slots sort behind every shard's rows; uint16 makes the stable
+    # argsort a radix sort.
+    owner = np.where(uniq != shards.pad_id, uniq // shards.rows,
+                     n).astype(np.uint16)
+    order = np.argsort(owner, kind="stable")
+    counts = np.bincount(owner, minlength=n + 1)[:n]
+    n_real = int(counts.sum())
+    U = fit(n * (int(counts.max()) + 1))
+    seg, rem = divmod(U, n)
+    if rem:
+        raise ValueError(f"{U} unique-row slots do not cut into {n} "
+                         "equal segments, one per row shard of the mesh")
+    # Slot j of the sorted run belongs to shard s's segment at offset
+    # j - (start of s's run).
+    dest = np.arange(n_real) + np.repeat(
+        np.arange(n) * seg - (np.cumsum(counts) - counts), counts)
+    rows = order[:n_real]
+    uniq_ids = np.full(U, shards.pad_id, np.int32)
+    uniq_ids[dest] = uniq[rows]
+    slot = np.full(len(uniq), U - 1, np.int32)
+    slot[rows] = dest
+    return uniq_ids, slot
+
+
+def segment_slots(uniq: np.ndarray, idx: np.ndarray, shards: RowShards,
+                  fit) -> Tuple[np.ndarray, np.ndarray]:
+    """``segment_plan`` applied: ``(uniq_ids[U], idx')`` with
+    ``uniq_ids[idx']`` equal to ``uniq[idx]`` cell by cell."""
+    uniq_ids, slot = segment_plan(uniq, shards, fit)
+    return uniq_ids, np.take(slot, idx)
+
+
+def _fit_slots(need: int, B: int, L: int, fixed_shape: bool,
+               uniq_bucket: int) -> int:
+    """U for a batch that needs ``need`` unique-row slots, its pad slot
+    counted: the ladder's rung, or under ``fixed_shape`` the pinned
+    bucket (UniqOverflow where the batch does not fit it)."""
+    uladder = _uniq_ladder(B, L)
+    if not fixed_shape:
+        return _ladder_fit(need, uladder)
+    U = uniq_bucket or uladder[-1]
+    if need > U:
+        raise UniqOverflow(
+            f"the batch needs {need} unique-row slots (on a mesh: the "
+            "row shards times one more than the fullest shard's rows) "
+            f"and the fixed unique bucket holds {U}; raise uniq_bucket")
+    return U
+
+
 def make_device_batch(block: ParsedBlock, cfg: FmConfig,
                       weights: Optional[np.ndarray] = None,
                       batch_size: Optional[int] = None,
                       fixed_shape: bool = False,
                       uniq_bucket: int = 0,
-                      raw_ids: bool = False) -> DeviceBatch:
+                      raw_ids: bool = False,
+                      shards: Optional[RowShards] = None) -> DeviceBatch:
     """CSR block -> fixed-shape DeviceBatch (pad + host-side unique).
 
     ``fixed_shape`` pins L and U instead of fitting this batch —
@@ -296,6 +401,9 @@ def make_device_batch(block: ParsedBlock, cfg: FmConfig,
     ``raw_ids`` (dedup=device mode, incompatible with fixed_shape):
     skip the host unique pass entirely — local_idx holds raw ids,
     uniq_ids is None, the device runs the unique.
+
+    ``shards`` (a mesh step's feed): the unique rows are ordered by
+    owning row shard (segment_plan) and U follows the fullest shard.
     """
     B = batch_size or cfg.batch_size
     n_real = block.batch_size
@@ -322,19 +430,16 @@ def make_device_batch(block: ParsedBlock, cfg: FmConfig,
             uniq, inverse = dedup_ids_fast(block.ids)
         except RuntimeError:  # C++ extension unavailable
             uniq, inverse = np.unique(block.ids, return_inverse=True)
-        uladder = _uniq_ladder(B, L)
-        if fixed_shape:
-            U = uniq_bucket or uladder[-1]
-            if len(uniq) + 1 > U:
-                raise UniqOverflow(
-                    f"{len(uniq)} unique ids exceed the fixed unique "
-                    f"bucket {U} (one slot is reserved for padding)")
+        fit = functools.partial(_fit_slots, B=B, L=L,
+                                fixed_shape=fixed_shape,
+                                uniq_bucket=uniq_bucket)
+        if shards is not None:
+            uniq_ids, inverse = segment_slots(uniq, inverse, shards, fit)
         else:
-            U = _ladder_fit(len(uniq) + 1, uladder)
-
-        uniq_ids = np.full(U, cfg.pad_id, dtype=np.int32)
-        uniq_ids[:len(uniq)] = uniq
-        pad_slot = U - 1  # always a pad_id slot by construction
+            uniq_ids = np.full(fit(len(uniq) + 1), cfg.pad_id,
+                               dtype=np.int32)
+            uniq_ids[:len(uniq)] = uniq
+        pad_slot = len(uniq_ids) - 1  # a pad_id slot by construction
 
     local_idx = np.full((B, L), pad_slot, dtype=np.int32)
     vals = np.zeros((B, L), dtype=np.float32)
@@ -361,7 +466,8 @@ def make_device_batch(block: ParsedBlock, cfg: FmConfig,
         w[:n_real] = 1.0
     return DeviceBatch(labels=labels, weights=w, uniq_ids=uniq_ids,
                        local_idx=local_idx, vals=vals, fields=fields,
-                       num_real=n_real)
+                       num_real=n_real,
+                       row_shards=shards.n if shards else 1)
 
 
 def epoch_file_order(files: List[str], shuffle: bool, seed: int,
@@ -753,7 +859,8 @@ def _worker_feed_threads(workers: int, spill_capable: bool) -> int:
 
 def _make_builder(cfg: FmConfig, B: int, raw_ids: bool, keep_empty: bool,
                   fixed_shape: bool, uniq_bucket: int,
-                  num_threads: int = 0):
+                  num_threads: int = 0,
+                  shards: Optional["RowShards"] = None):
     """The ONE BatchBuilder construction, shared by the serial fast
     path and the parallel plane's per-worker builders — a knob threaded
     into one and missed in the other would silently fork the batch
@@ -765,6 +872,13 @@ def _make_builder(cfg: FmConfig, B: int, raw_ids: bool, keep_empty: bool,
     # max_features_per_example > ladder[-1] land in the same extended
     # pow2 buckets the generic path compiles for.
     L_cap = effective_L_cap(cfg)
+    row_shards = None
+    if fixed_shape and shards is not None:
+        # The fixed bucket ships as one segment per row shard, each
+        # with its pad slot (segment_plan): the builder closes a batch
+        # before a shard's rows outgrow theirs, as it does at max_uniq.
+        U = uniq_bucket or _uniq_ladder(B, L_cap)[-1]
+        row_shards = (shards.rows, shards.n, U // shards.n - 1)
     return BatchBuilder(B, L_cap, cfg.vocabulary_size,
                         hash_feature_id=cfg.hash_feature_id,
                         field_aware=cfg.model_type == "ffm",
@@ -773,7 +887,7 @@ def _make_builder(cfg: FmConfig, B: int, raw_ids: bool, keep_empty: bool,
                         max_features_per_example=(
                             cfg.max_features_per_example),
                         max_uniq=(uniq_bucket if fixed_shape else 0),
-                        num_threads=num_threads)
+                        num_threads=num_threads, row_shards=row_shards)
 
 
 class _BatchEmitter:
@@ -786,12 +900,14 @@ class _BatchEmitter:
 
     def __init__(self, cfg: FmConfig, B: int, L_cap: int,
                  fixed_shape: bool, uniq_bucket: int, shuffle: bool,
-                 seed: Optional[int], stats: Optional[SpillStats]):
+                 seed: Optional[int], stats: Optional[SpillStats],
+                 shards: Optional[RowShards] = None):
         self.cfg = cfg
         self.B = B
         self.L_cap = L_cap
         self.fixed_shape = fixed_shape
         self.uniq_bucket = uniq_bucket
+        self.shards = shards
         self.shuffle = shuffle
         self.stats = stats
         self.pyrng = random.Random(cfg.seed if seed is None else seed)
@@ -807,9 +923,38 @@ class _BatchEmitter:
         return (self.L_cap if self.fixed_shape
                 else _ladder_fit(max(max_nnz, 1), self.cfg.bucket_ladder))
 
+    def slots(self, uniq, max_nnz):
+        """How a batch's unique slots ship, for the builder's finish():
+        ``(uniq_ids[U], remap)`` with U the ladder's rung or the fixed
+        bucket and, for a mesh step's feed (``shards``), the rows
+        ordered by owning row shard and ``remap`` re-pointing the
+        cells (segment_plan). Pure, so build workers hand it to
+        finish() and the cells are written once, where they ship."""
+        fit = functools.partial(_fit_slots, B=self.B,
+                                L=self.cols(max_nnz),
+                                fixed_shape=self.fixed_shape,
+                                uniq_bucket=self.uniq_bucket)
+        if self.shards is not None:
+            return segment_plan(uniq, self.shards, fit)
+        # The builder's uniq already CONTAINS the reserved pad slot
+        # (index 0), unlike the generic path's real-ids-only set —
+        # fitting len+1 here would double-reserve and inflate U to the
+        # next rung exactly at boundaries (2x gather/scatter width, and
+        # a fast-vs-generic shape divergence that defeats compile-cache
+        # reuse).
+        uniq_ids = np.full(fit(len(uniq)), self.cfg.pad_id, dtype=np.int32)
+        uniq_ids[:len(uniq)] = uniq  # slot 0 already pad_id (C++)
+        return uniq_ids, None
+
+    def finish(self, bb):
+        """A builder's batch as ``emit_drain`` takes it: its finish()
+        at the width and in the slots it ships, and its cell count.
+        Pure in ``bb``: build workers run it."""
+        return bb.finish(self.cols, self.slots) + (bb.cells,)
+
     def emit_drain(self, out, spilled: bool) -> Iterator[DeviceBatch]:
-        """Emit one builder finish() tuple and drain through the
-        bounded shuffle window (a passthrough when shuffle is off)."""
+        """Emit one ``finish(bb)`` tuple and drain through the bounded
+        shuffle window (a passthrough when shuffle is off)."""
         batch = self._emit(*out, spilled=spilled)
         if self.shuffle:
             self.window.append(batch)
@@ -824,35 +969,20 @@ class _BatchEmitter:
             yield self.window.pop(
                 self.pyrng.randrange(len(self.window)))
 
-    def _emit(self, n, labels, uniq, li, vals, fields, max_nnz,
-              spilled: bool = False) -> DeviceBatch:
+    def _emit(self, n, labels, uniq_ids, li, vals, fields, max_nnz,
+              cells=None, spilled: bool = False) -> DeviceBatch:
         cfg, B = self.cfg, self.B
+        row_shards = self.shards.n if self.shards else 1
         if self.stats is not None:
             self.stats.count(n, B, spilled,
-                             num_uniq=_num_uniq(uniq, cfg.pad_id))
+                             num_uniq=_num_uniq(uniq_ids, cfg.pad_id,
+                                                row_shards))
         L = self.cols(max_nnz)
         if L < li.shape[1]:  # a finish() that was not given cols
             li = np.ascontiguousarray(li[:, :L])
             vals = np.ascontiguousarray(vals[:, :L])
             if fields is not None:
                 fields = np.ascontiguousarray(fields[:, :L])
-        if uniq is None:  # raw-ids mode: li holds raw ids, no unique set
-            uniq_ids = None
-        else:
-            if self.fixed_shape and self.uniq_bucket:
-                U = self.uniq_bucket  # builder guarantees len(uniq) <= U
-            else:
-                uladder = _uniq_ladder(B, L)
-                # The builder's uniq already CONTAINS the reserved pad
-                # slot (index 0), unlike the generic path's real-ids-only
-                # set — fitting len+1 here would double-reserve and
-                # inflate U to the next rung exactly at boundaries
-                # (2x gather/scatter width, and a fast-vs-generic shape
-                # divergence that defeats compile-cache reuse).
-                U = (uladder[-1] if self.fixed_shape
-                     else _ladder_fit(len(uniq), uladder))
-            uniq_ids = np.full(U, cfg.pad_id, dtype=np.int32)
-            uniq_ids[:len(uniq)] = uniq  # slot 0 already pad_id (C++)
         weights = np.zeros(B, np.float32)
         weights[:n] = 1.0
         labels[n:] = 0.0  # C++ buffer may hold stale labels past n
@@ -867,7 +997,8 @@ class _BatchEmitter:
                 fields = fields[perm]
         return DeviceBatch(labels=labels, weights=weights,
                            uniq_ids=uniq_ids, local_idx=li, vals=vals,
-                           fields=fields, num_real=n)
+                           fields=fields, num_real=n,
+                           row_shards=row_shards, nnz=cells)
 
 
 class _BuildRing:
@@ -1157,10 +1288,12 @@ class _FastWorkerState:
     rebasing builder-relative error linenos onto the stream. Created
     inside the worker thread and never shared."""
 
-    def __init__(self, make_builder, cols=None):
+    def __init__(self, make_builder, finish=None):
         self._make_builder = make_builder
         self.bb = make_builder()
-        self.cols = cols  # finish()'s width rule (_BatchEmitter.cols)
+        # How a built batch leaves the builder (_BatchEmitter.finish:
+        # the width and the slots it ships at, its cell count).
+        self.finish = finish or (lambda bb: bb.finish())
         self.fed = 0  # lines consumed by self.bb since creation
 
     def reset(self) -> None:
@@ -1184,7 +1317,7 @@ def _fast_group_work(state: _FastWorkerState, group: _Group):
     fed_before = state.fed
     try:
         _full, consumed = bb.feed(group.blob, 0)
-        out = bb.finish(state.cols)
+        out = state.finish(bb)
     except ParseError as e:
         state.reset()
         m = _LINE_MSG.match(str(e))
@@ -1206,7 +1339,8 @@ def _parallel_fast_batch_iterator(cfg: FmConfig, files: List[str],
                                   stats: Optional[SpillStats],
                                   raw_ids: bool, keep_empty: bool,
                                   workers: int,
-                                  file_marks: Optional[FileMarks] = None
+                                  file_marks: Optional[FileMarks] = None,
+                                  row_shards: Optional[RowShards] = None
                                   ) -> Iterator[DeviceBatch]:
     """Parallel host data plane, fast path: parse+hash+dedup+pack fans
     out across ``workers`` pool threads — each owning its own C++
@@ -1239,15 +1373,17 @@ def _parallel_fast_batch_iterator(cfg: FmConfig, files: List[str],
     feed_threads = _worker_feed_threads(workers, spill_capable)
     make_builder = functools.partial(_make_builder, cfg, B, raw_ids,
                                      keep_empty, fixed_shape,
-                                     uniq_bucket, feed_threads)
+                                     uniq_bucket, feed_threads,
+                                     shards=row_shards)
     emitter = _BatchEmitter(cfg, B, effective_L_cap(cfg), fixed_shape,
-                            uniq_bucket, shuffle, seed, stats)
+                            uniq_bucket, shuffle, seed, stats,
+                            shards=row_shards)
     retry = RetryPolicy.from_config(cfg)
     file_seed = cfg.seed if seed is None else seed
     ring = _BuildRing(workers, depth=2 * workers,
                       work=_fast_group_work,
-                      make_state=lambda: _FastWorkerState(make_builder,
-                                                          emitter.cols))
+                      make_state=lambda: _FastWorkerState(
+                          make_builder, emitter.finish))
     tel = active()
     if tel is not None:
         tel.set("pipeline/host_threads", workers)
@@ -1332,7 +1468,8 @@ def _fast_batch_iterator(cfg: FmConfig, bb, files: List[str], B: int,
                          shard_index: int = 0, num_shards: int = 1,
                          uniq_bucket: int = 0,
                          stats: Optional[SpillStats] = None,
-                         file_marks: Optional[FileMarks] = None
+                         file_marks: Optional[FileMarks] = None,
+                         row_shards: Optional[RowShards] = None
                          ) -> Iterator[DeviceBatch]:
     """Chunked C++ fast path: raw file bytes stream straight into the
     C++ BatchBuilder (parse + hash + dedup + padded scatter in one native
@@ -1358,7 +1495,7 @@ def _fast_batch_iterator(cfg: FmConfig, bb, files: List[str], B: int,
     ``host_threads`` a pure throughput knob (bit-identical streams).
     """
     emitter = _BatchEmitter(cfg, B, bb.L, fixed_shape, uniq_bucket,
-                            shuffle, seed, stats)
+                            shuffle, seed, stats, shards=row_shards)
 
     tail = b""
     fed_lines = 0       # complete lines fed to the builder so far —
@@ -1378,7 +1515,7 @@ def _fast_batch_iterator(cfg: FmConfig, bb, files: List[str], B: int,
             off += consumed
             if not full:
                 break
-            out = bb.finish(emitter.cols)
+            out = emitter.finish(bb)
             # The builder returns "full" either at B examples or when a
             # line would blow the unique budget — the latter closes the
             # batch short (the spill being counted).
@@ -1406,7 +1543,7 @@ def _fast_batch_iterator(cfg: FmConfig, bb, files: List[str], B: int,
                     yield from feed_all(tail + chunk if tail else chunk)
                 if tail:  # final owned line missing its newline
                     yield from feed_all(tail + b"\n")
-            out = bb.finish(emitter.cols)
+            out = emitter.finish(bb)
             if out[0]:  # short final batch of the epoch
                 yield from emitter.emit_drain(out, spilled=False)
             yield from emitter.flush_window()
@@ -1443,18 +1580,22 @@ def _attach_stream_source(e: ParseError,
     return ParseError(f"{path} line {abs_ln}{note}: {m.group(2)}")
 
 
-def _num_uniq(uniq_ids, pad_id: int) -> int:
+def _num_uniq(uniq_ids, pad_id: int, segments: int = 1) -> int:
     """Real unique-row count of a host-deduped uniq array (pad_id slots
     are fill; no real feature id can equal it). 0 for raw-ids (None).
-    The ONE counting rule for both pipeline paths — the shrink decision
-    in train.adapt_uniq_bucket compares their stats directly."""
+    Of a segmented array (``segments``, one per row shard of a mesh)
+    that many times the fullest segment's rows: the slots the batch
+    needs, which is what its U follows. The ONE counting rule for both pipeline paths — the shrink
+    decision in train.adapt_uniq_bucket compares their stats
+    directly."""
     if uniq_ids is None:
         return 0
-    return int((uniq_ids != pad_id).sum())
+    return segments * int(
+        (uniq_ids.reshape(segments, -1) != pad_id).sum(axis=1).max())
 
 
 def _batch_num_uniq(batch: DeviceBatch, cfg: FmConfig) -> int:
-    return _num_uniq(batch.uniq_ids, cfg.pad_id)
+    return _num_uniq(batch.uniq_ids, cfg.pad_id, batch.row_shards)
 
 
 def batch_iterator(cfg: FmConfig, files: Sequence[str],
@@ -1471,7 +1612,8 @@ def batch_iterator(cfg: FmConfig, files: Sequence[str],
                    raw_ids: bool = False,
                    bad_lines: Optional[BadLineTracker] = None,
                    file_marks: Optional[FileMarks] = None,
-                   vocab=None
+                   vocab=None,
+                   row_shards: Optional[RowShards] = None
                    ) -> Iterator[DeviceBatch]:
     """Epoch/shuffle/batch loop over text files (see _batch_iterator_impl
     for the full contract). This wrapper is the pipeline's telemetry
@@ -1489,7 +1631,13 @@ def batch_iterator(cfg: FmConfig, files: Sequence[str],
     mods into it), and every emitted batch is remapped to physical
     rows before anything downstream — telemetry included — sees it.
     None (the default, and always for vocab_mode = fixed) is
-    bit-identical to the historical pipeline."""
+    bit-identical to the historical pipeline.
+
+    ``row_shards`` (a mesh train step's feed): how the mesh cuts the
+    table's rows; every batch's unique rows come ordered by owning
+    shard (segment_plan; ``DeviceBatch.row_shards`` says so). Under
+    ``vocab`` the rows are only known after the remap, which then
+    orders them itself (``vocab.row_shards``)."""
     from fast_tffm_tpu.obs.telemetry import active
     it = _batch_iterator_impl(cfg if vocab is None
                               else vocab.build_cfg(cfg), files,
@@ -1502,7 +1650,9 @@ def batch_iterator(cfg: FmConfig, files: Sequence[str],
                               fixed_shape=fixed_shape,
                               uniq_bucket=uniq_bucket, stats=stats,
                               raw_ids=raw_ids, bad_lines=bad_lines,
-                              file_marks=file_marks)
+                              file_marks=file_marks,
+                              row_shards=(row_shards if vocab is None
+                                          else None))
     tel = active()
     if tel is None:
         if vocab is None:
@@ -1549,7 +1699,8 @@ def _batch_iterator_impl(cfg: FmConfig, files: Sequence[str],
                          stats: Optional[SpillStats] = None,
                          raw_ids: bool = False,
                          bad_lines: Optional[BadLineTracker] = None,
-                         file_marks: Optional[FileMarks] = None
+                         file_marks: Optional[FileMarks] = None,
+                         row_shards: Optional[RowShards] = None
                          ) -> Iterator[DeviceBatch]:
     """Epoch/shuffle/batch loop over text files.
 
@@ -1629,7 +1780,7 @@ def _batch_iterator_impl(cfg: FmConfig, files: Sequence[str],
         # tests/test_sharded_input.py forces the generic path through.
         try:
             bb = _make_builder(cfg, B, raw_ids, keep_empty, fixed_shape,
-                               uniq_bucket)
+                               uniq_bucket, shards=row_shards)
         except RuntimeError:
             bb = None
         if bb is not None:
@@ -1640,12 +1791,13 @@ def _batch_iterator_impl(cfg: FmConfig, files: Sequence[str],
                     cfg, files, B, n_epochs, do_shuffle, seed,
                     fixed_shape, shard_index, num_shards, uniq_bucket,
                     stats, raw_ids, keep_empty, workers,
-                    file_marks=file_marks)
+                    file_marks=file_marks, row_shards=row_shards)
             else:
                 yield from _fast_batch_iterator(
                     cfg, bb, files, B, n_epochs, do_shuffle, seed,
                     fixed_shape, shard_index, num_shards, uniq_bucket,
-                    stats=stats, file_marks=file_marks)
+                    stats=stats, file_marks=file_marks,
+                    row_shards=row_shards)
             return
     # Blank-line-preserving parse rides the C++ block parser too since
     # ABI 7 (keep_empty mode); _parse_block threads the flag through.
@@ -1728,7 +1880,7 @@ def _batch_iterator_impl(cfg: FmConfig, files: Sequence[str],
                                      batch_size=B,
                                      fixed_shape=fixed_shape,
                                      uniq_bucket=uniq_bucket,
-                                     raw_ids=raw_ids)
+                                     raw_ids=raw_ids, shards=row_shards)
         pool = _BuildRing(workers, depth=2 * workers,
                           work=_pool_work)
         from fast_tffm_tpu.obs.telemetry import active as _active
@@ -1785,7 +1937,8 @@ def _batch_iterator_impl(cfg: FmConfig, files: Sequence[str],
                                                 batch_size=B,
                                                 fixed_shape=fixed_shape,
                                                 uniq_bucket=uniq_bucket,
-                                                raw_ids=raw_ids)
+                                                raw_ids=raw_ids,
+                                                shards=row_shards)
                         if stats is not None:
                             stats.count(out.num_real, B, False,
                                         num_uniq=_batch_num_uniq(out,
@@ -1795,7 +1948,8 @@ def _batch_iterator_impl(cfg: FmConfig, files: Sequence[str],
                         # Spill: emit the longest example prefix that
                         # fits the unique budget; the tail reopens the
                         # queue.
-                        m = _uniq_prefix_examples(block, uniq_bucket)
+                        m = _uniq_prefix_examples(block, uniq_bucket,
+                                                  row_shards)
                         if m == 0:
                             raise ValueError(
                                 "single example exceeds uniq_bucket "
@@ -1815,7 +1969,8 @@ def _batch_iterator_impl(cfg: FmConfig, files: Sequence[str],
                                                 weights=w[:m],
                                                 batch_size=B,
                                                 fixed_shape=fixed_shape,
-                                                uniq_bucket=uniq_bucket)
+                                                uniq_bucket=uniq_bucket,
+                                                shards=row_shards)
                         if stats is not None:
                             stats.count(out.num_real, B, True,
                                         num_uniq=_batch_num_uniq(out,
@@ -1851,21 +2006,29 @@ def _batch_iterator_impl(cfg: FmConfig, files: Sequence[str],
             tracker.close()
 
 
-def _uniq_prefix_examples(block: ParsedBlock, uniq_bucket: int) -> int:
+def _uniq_prefix_examples(block: ParsedBlock, uniq_bucket: int,
+                          shards: Optional[RowShards] = None) -> int:
     """Largest count of leading examples whose id union fits the unique
     bucket (one slot reserved for padding) — the generic-path spill
-    split point."""
+    split point. On a mesh (``shards``) each row shard's ids have to
+    fit its segment of the bucket."""
     if block.batch_size == 0:
         return 0
-    _, first_pos = np.unique(block.ids, return_index=True)
+    ids, first_pos = np.unique(block.ids, return_index=True)
     # Example index owning each first occurrence -> uniques per example.
     ex = np.searchsorted(block.poses, first_pos, side="right") - 1
-    cum = np.cumsum(np.bincount(ex, minlength=block.batch_size))
-    return int(np.searchsorted(cum, uniq_bucket - 1, side="right"))
+    if shards is None:
+        cum = np.cumsum(np.bincount(ex, minlength=block.batch_size))
+        return int(np.searchsorted(cum, uniq_bucket - 1, side="right"))
+    per = np.zeros((shards.n, block.batch_size), np.int64)
+    np.add.at(per, (ids // shards.rows, ex), 1)
+    fits = (np.cumsum(per, axis=1) < uniq_bucket // shards.n).all(axis=0)
+    return block.batch_size if fits.all() else int(fits.argmin())
 
 
 def probe_uniq_bucket(cfg: FmConfig, files: Sequence[str],
-                      batch_size: Optional[int] = None) -> int:
+                      batch_size: Optional[int] = None,
+                      shards: Optional[RowShards] = None) -> int:
     """Pick the fixed unique-row bucket for multi-process training by
     measuring the data instead of assuming the worst case (the ladder
     top is next_pow2(B*L) — ~50x a realistic Criteo batch's uniques).
@@ -1879,10 +2042,15 @@ def probe_uniq_bucket(cfg: FmConfig, files: Sequence[str],
     probe still missed are absorbed by the spill protocol, costing
     throughput, never correctness — counted by SpillStats, warned at
     epoch end, and recovered by train()'s epoch-boundary bucket raise.
+
+    For a mesh train step's feed (``shards``) the count is the row
+    shards times the fullest shard's rows, as the batch's U follows the
+    fullest shard (segment_plan).
     """
     B = batch_size or cfg.batch_size
     files = expand_files(files)
-    top = _uniq_ladder(B, effective_L_cap(cfg))[-1]
+    top = uniq_bucket_top(cfg, B, shards)
+    n = shards.n if shards else 1
     retry = RetryPolicy.from_config(cfg)
     from fast_tffm_tpu.data.cparser import parse_lines_fast
     parse = parse_lines_fast
@@ -1918,24 +2086,30 @@ def probe_uniq_bucket(cfg: FmConfig, files: Sequence[str],
                                  f"byte {start}): "
                                  f"{_strip_line_prefix(str(e))}"
                                  ) from None
-            u_max = max(u_max, len(np.unique(block.ids)))
+            uniq = np.unique(block.ids)
+            u_max = max(u_max, len(uniq) if shards is None else
+                        shards.n * np.bincount(
+                            uniq // shards.rows).max(initial=0))
     if not got_lines:
         return min(1 << 10, top)
     b = 64
-    while b < 2 * (u_max + 2) or b <= cfg.max_features_per_example:
+    while b < 2 * (u_max + 2 * n) or b // n <= cfg.max_features_per_example:
         b *= 2
     return min(b, top)
 
 
-def uniq_bucket_top(cfg: FmConfig, batch_size: Optional[int] = None) -> int:
-    """The worst-case unique bucket (ladder top) — the ceiling for
+def uniq_bucket_top(cfg: FmConfig, batch_size: Optional[int] = None,
+                    shards: Optional[RowShards] = None) -> int:
+    """The worst-case unique bucket (ladder top; on a mesh every row
+    could sit on one shard, so that per segment) — the ceiling for
     train()'s epoch-boundary adaptive raise."""
-    return _uniq_ladder(batch_size or cfg.batch_size,
-                        effective_L_cap(cfg))[-1]
+    return (shards.n if shards else 1) * _uniq_ladder(
+        batch_size or cfg.batch_size, effective_L_cap(cfg))[-1]
 
 
 def empty_batch(cfg: FmConfig, batch_size: Optional[int] = None,
-                uniq_bucket: int = 0) -> DeviceBatch:
+                uniq_bucket: int = 0,
+                shards: Optional[RowShards] = None) -> DeviceBatch:
     """An all-padding batch (num_real=0, zero weights): the SPMD filler a
     data-exhausted process feeds while peers finish their shards — every
     term it contributes to loss/grad/reg is exactly zero by the padding
@@ -1947,7 +2121,8 @@ def empty_batch(cfg: FmConfig, batch_size: Optional[int] = None,
                         vals=np.zeros(0, np.float32), fields=fields)
     return make_device_batch(block, cfg, batch_size=batch_size,
                              fixed_shape=True,
-                             uniq_bucket=uniq_bucket or cfg.uniq_bucket)
+                             uniq_bucket=uniq_bucket or cfg.uniq_bucket,
+                             shards=shards)
 
 
 def _fast_path_eligible(cfg: FmConfig,

@@ -619,7 +619,7 @@ class StreamSource:
                  uniq_bucket: int = 0, raw_ids: bool = False,
                  workers: int = 1,
                  bad_lines: Optional[BadLineTracker] = None,
-                 vocab=None):
+                 vocab=None, row_shards=None):
         from fast_tffm_tpu.data import cparser
         from fast_tffm_tpu.data.pipeline import (_BatchEmitter,
                                                  effective_L_cap)
@@ -629,6 +629,10 @@ class StreamSource:
         # to physical rows (vocab.remap) before it reaches the ready
         # deque — the same seam batch_iterator applies in epoch mode.
         self._vocab = vocab
+        # A mesh train step's feed (pipeline.RowShards): unique rows
+        # ordered by owning row shard; under vocab the remap orders
+        # them, once the rows are known.
+        self._row_shards = row_shards if vocab is None else None
         bcfg = cfg if vocab is None else vocab.build_cfg(cfg)
         self._bcfg = bcfg
         self.tracker = tracker
@@ -648,7 +652,8 @@ class StreamSource:
                                       effective_L_cap(bcfg),
                                       fixed_shape, uniq_bucket,
                                       shuffle=False, seed=cfg.seed,
-                                      stats=self.stats)
+                                      stats=self.stats,
+                                      shards=self._row_shards)
         self._ready: collections.deque = collections.deque()
         self._pos: Dict[int, Tuple[int, int]] = {}  # idx -> (bytes, lines)
         for i, fs in enumerate(tracker.files):
@@ -677,7 +682,8 @@ class StreamSource:
                                                        False)
                 self._make_builder = functools.partial(
                     pl._make_builder, bcfg, self.B, raw_ids, False,
-                    fixed_shape, uniq_bucket, feed_threads)
+                    fixed_shape, uniq_bucket, feed_threads,
+                    shards=self._row_shards)
                 self._init_ring()
             else:
                 # The serial stream builder REQUIRES the single-thread
@@ -688,7 +694,7 @@ class StreamSource:
                 # plane's spill rewind.
                 self._make_builder = functools.partial(
                     pl._make_builder, bcfg, self.B, raw_ids, False,
-                    fixed_shape, uniq_bucket, 1)
+                    fixed_shape, uniq_bucket, 1, shards=self._row_shards)
                 self._bb = self._make_builder()
         else:
             self._pending: List[Tuple[str, int, int, int]] = []
@@ -783,7 +789,7 @@ class StreamSource:
             if self._ring is not None:
                 self._ring_flush()
             else:
-                out = self._bb.finish(self._emitter.cols)
+                out = self._emitter.finish(self._bb)
                 if out[0]:
                     self._emit(out, spilled=False)
         else:
@@ -804,7 +810,7 @@ class StreamSource:
             if not full:
                 return
             try:
-                out = self._bb.finish(self._emitter.cols)
+                out = self._emitter.finish(self._bb)
             except ParseError as e:
                 raise self._attach_source(e) from None
             # A finish() under the fixed unique budget that closed
@@ -821,7 +827,7 @@ class StreamSource:
             self._workers, depth=2 * self._workers,
             work=pl._fast_group_work,
             make_state=lambda: pl._FastWorkerState(
-                self._make_builder, self._emitter.cols))
+                self._make_builder, self._emitter.finish))
         self._buf = b""
         self._buf_pos = 0
         self._segments: collections.deque = collections.deque()
@@ -979,7 +985,8 @@ class StreamSource:
             out_batch = make_device_batch(
                 block, self._bcfg, batch_size=self.B,
                 fixed_shape=self.fixed_shape,
-                uniq_bucket=self.uniq_bucket, raw_ids=self.raw_ids)
+                uniq_bucket=self.uniq_bucket, raw_ids=self.raw_ids,
+                shards=self._row_shards)
             if self._vocab is not None:
                 out_batch = self._vocab.remap(out_batch)
             # EVERY file the chunk touches advances — a batch spanning
@@ -1170,8 +1177,8 @@ def stream_workers(cfg: FmConfig, fixed_shape: bool = False) -> int:
     return workers
 
 
-def probe_stream_uniq_bucket(cfg: FmConfig,
-                             tracker: StreamTracker) -> int:
+def probe_stream_uniq_bucket(cfg: FmConfig, tracker: StreamTracker,
+                             shards=None) -> int:
     """Fixed unique-row bucket for lockstep stream mode: probe the
     SEALED files present at startup (same math as
     pipeline.probe_uniq_bucket), or a safe default when the stream is
@@ -1184,7 +1191,7 @@ def probe_stream_uniq_bucket(cfg: FmConfig,
     tracker.discover()  # collective in lockstep mode: all call it
 
     def decide() -> int:
-        top = pl.uniq_bucket_top(cfg)
+        top = pl.uniq_bucket_top(cfg, shards=shards)
         quiet_ok = tracker.seal_policy in ("auto", "quiet")
         quiet = QUIET_POLLS * tracker.poll_seconds
         candidates = []
@@ -1207,7 +1214,7 @@ def probe_stream_uniq_bucket(cfg: FmConfig,
                 continue
         if not candidates:
             return min(1 << 10, top)
-        return pl.probe_uniq_bucket(cfg, candidates)
+        return pl.probe_uniq_bucket(cfg, candidates, shards=shards)
 
     if not tracker.lockstep:
         return decide()

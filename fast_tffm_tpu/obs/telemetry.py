@@ -354,20 +354,37 @@ class RunTelemetry:
         B, L = batch.local_idx.shape
         self.count("pipeline/batches")
         self.count("pipeline/examples", batch.num_real)
+        # Real feature cells: the builder's own count where it made
+        # one, else a pass over the batch's B x L cells here, on the
+        # thread every batch goes through (at B = 32768 that pass was
+        # 7 to 20 ms a batch and set a four-chip run's pace; PERF.md
+        # section 6, PR 33).
+        real = batch.nnz
         if batch.uniq_ids is None:
             # raw-ids mode (dedup=device): pad cells hold pad_id
             # directly; the unique set is computed on device, so no
             # dedup-rate numerator exists host-side.
-            real = int((batch.local_idx != pad_id).sum())
+            if real is None:
+                real = int((batch.local_idx != pad_id).sum())
         else:
-            real_uniq = int((batch.uniq_ids != pad_id).sum())
-            real = int(np.count_nonzero(np.take(
-                np.asarray(batch.uniq_ids) != pad_id, batch.local_idx)))
-            self.count("pipeline/uniq_rows", real_uniq)
+            real_slot = np.asarray(batch.uniq_ids) != pad_id
+            # One segment per row shard of the mesh (one list off it).
+            shard_rows = real_slot.reshape(batch.row_shards, -1).sum(1)
+            if real is None:
+                real = int(np.count_nonzero(np.take(real_slot,
+                                                    batch.local_idx)))
+            self.count("pipeline/uniq_rows", int(shard_rows.sum()))
             # The U shipped (ladder rung, pad slots included): rows
             # over slots is the fill of the fitted unique table, the
             # share of the step's gather/scatter slots that do work.
             self.count("pipeline/uniq_slots", len(batch.uniq_ids))
+            # The fullest shard's rows over a segment's slots: U is the
+            # rung the fullest shard fits (pipeline.segment_plan), so
+            # this ratio near 1 is a batch near the next rung, which
+            # doubles every shard's gather and scatter walk.
+            self.count("pipeline/shard_rows_max", int(shard_rows.max()))
+            self.count("pipeline/shard_slots",
+                       len(batch.uniq_ids) // batch.row_shards)
         self.count("pipeline/feature_slots", B * L)
         self.count("pipeline/feature_nnz", real)
         if build_seconds is not None:

@@ -13,11 +13,21 @@ The TPU-native design here replaces all of that with SPMD over a
   **row-sharded over every device** (``P(("data", "model"))``) — the mesh
   *is* the parameter server, and FSDP-style row sharding means the table's
   memory scales with the slice, exactly like adding PS tasks.
-- the per-step gather of the batch's unique rows and the scatter-add of
-  their gradients cross shard boundaries; XLA/GSPMD inserts the
-  collectives (all-gather of the small unique-id set, psum of gathered
-  rows, sharded scatter) over ICI — no hand-written transport, per the
-  scaling-book recipe (annotate shardings, let XLA place collectives).
+- the train step's lookup is told which rows it holds: the host data
+  plane orders each batch's unique rows by owning shard
+  (data/pipeline.segment_plan), so shard s finds its rows' slots in
+  segment s of ``uniq_ids``, gathers them from its own block, and the
+  ``[U / n, D]`` pieces are all-gathered; backward, the slot gradients
+  are reduce-scattered and each shard applies sparse Adagrad to its own
+  block over ``U / n`` slots (``sharded_train_step_body``). A shard
+  that walked all U slots masked, as GSPMD partitions the one-device
+  step, paid for every slot what a row it holds costs (PERF.md
+  section 6, PR 33).
+- everything between the lookup's two halves (expand, interaction,
+  loss, their backward) and the scoring path are the one-device
+  bodies under ``jax.jit`` with shardings: GSPMD partitions them over
+  the batch and inserts the collectives over ICI — no hand-written
+  transport there, per the scaling-book recipe.
 - updates are **synchronous**: every step sees every gradient. This is a
   deliberate semantics upgrade over the reference's lock-free async
   (hogwild) PS updates — a documented divergence (SURVEY §7 hard part #2).
@@ -40,11 +50,15 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from fast_tffm_tpu.config import FmConfig
-from fast_tffm_tpu.models.fm import ModelSpec, score_body, train_step_body
+from fast_tffm_tpu.data.pipeline import UNIQ_LADDER_MIN
+from fast_tffm_tpu.models.fm import ModelSpec, score_body
 
 # Table rows are sharded across *all* mesh devices — both axes — so table
 # memory per chip shrinks linearly with slice size (the PS-scaling analogue).
 ROW_SPEC = P(("data", "model"), None)
+# The unique-row slots of a mesh train step's feed, one segment per row
+# shard (data/pipeline.segment_plan).
+SLOT_SPEC = P(("data", "model"))
 
 
 def make_mesh(devices: Optional[Sequence[jax.Device]] = None,
@@ -66,13 +80,15 @@ def make_mesh(devices: Optional[Sequence[jax.Device]] = None,
             f"device count {n} must be a power of two <= 4096 so the "
             "4096-aligned table rows (FmConfig.ckpt_rows) shard evenly")
     n_data = n // model_axis
-    # The pipeline's unique-id buckets are powers of two (>= 64), so the
-    # data axis must be a power of two <= 64 for the U axis to shard
-    # evenly; TPU slices are powers of two anyway.
-    if n_data & (n_data - 1) or n_data > 64:
+    # A batch's unique rows ship as one segment per ROW shard (data x
+    # model; data/pipeline.segment_plan) of a power-of-two rung, so
+    # the ladder's smallest rung has to cut into that many segments.
+    if n > UNIQ_LADDER_MIN:
         raise ValueError(
-            f"data axis size {n_data} must be a power of two <= 64 so the "
-            "pipeline's power-of-two unique-id buckets shard evenly")
+            f"{n} row shards (data axis {n_data} x model axis "
+            f"{model_axis}) do not divide the unique-row ladder's "
+            f"smallest rung, {UNIQ_LADDER_MIN} slots: a batch's unique "
+            "rows are cut into one segment per row shard")
     # Multi-process: each data-axis row must stay within one process —
     # global_batch concatenates PER-PROCESS local batches along the data
     # axis (make_array_from_process_local_data), so a data row spanning
@@ -93,16 +109,14 @@ def _require_host_dedup(spec: ModelSpec) -> None:
     fixed buckets; global_batch offsets local_idx into the concatenated
     unique axis) — a raw-ids spec here would feed garbage indices.
 
-    Design position, not a gap: on a single chip raw ids win because
-    the only cost is H2D bytes and an on-chip unique (~3 us), while
-    host dedup burns the scarce 1-core host. On a mesh the economics
-    invert — the gather/scatter against the ROW-SHARDED table is
-    cross-device traffic sized by the index vector, so deduping
-    B*L raw slots down to U uniques host-side shrinks the all-to-all
-    and the scatter-add by the batch's duplication factor, and the
-    fixed-U lockstep protocol (multi-process global_batch) needs the
-    static unique budget anyway. Shipping raw ids to the mesh would
-    trade cheap distributed host CPU for scarce ICI bandwidth."""
+    Design position, not a gap: the mesh train step's lookup rests on
+    the host knowing the batch's distinct rows. It orders them by
+    owning shard (data/pipeline.segment_plan), so each shard gathers
+    and scatters the U / n slots of rows it holds and the collectives
+    carry [U, D] once each way; raw ids would leave every shard B*L
+    slots to unique, mask and walk. The fixed-U lockstep protocol
+    (multi-process global_batch) needs the static unique budget
+    anyway."""
     if spec.dedup == "device":
         raise ValueError(
             "dedup = device is for the plain single-device jit only; "
@@ -140,17 +154,106 @@ def _shardings(mesh: Mesh, with_fields: bool):
     return tuple(in_sh), out_sh
 
 
+def _block_index(mesh: Mesh, block, ids):
+    """``ids`` as indices into this shard's ``block`` of table rows
+    (inside a shard_map over every mesh axis). A slot whose row another
+    shard holds gets the index one past the block: a gather told to
+    fill reads zeros there and a scatter drops its update. A segmented
+    feed has such slots only as padding: ``pad_id`` names one dead row,
+    which one shard holds."""
+    rows = block.shape[0]
+    local = ids - jax.lax.axis_index(mesh.axis_names) * rows
+    return jnp.where((local >= 0) & (local < rows), local, rows)
+
+
+def _regroup(slots, outer: int, inner: int):
+    """``[U, ...]`` slots lying as ``outer`` groups of ``inner`` pieces
+    put as ``inner`` groups of ``outer`` pieces. With several feeds
+    side by side (multi-process ``global_batch``), each cut into one
+    segment per row shard, ``_regroup(x, feeds, shards)`` is
+    shard-major, so that ``SLOT_SPEC`` hands shard s its segment of
+    every feed, and ``_regroup(x, shards, feeds)`` is back in the order
+    ``local_idx`` indexes; the identity for one feed."""
+    if outer == 1 or inner == 1:
+        return slots
+    cut = slots.reshape(outer, inner, -1, *slots.shape[1:])
+    return jnp.swapaxes(cut, 0, 1).reshape(slots.shape)
+
+
+def sharded_train_step_body(spec: ModelSpec, mesh: Mesh, blocks: int,
+                            table, acc, labels, weights, uniq_ids,
+                            local_idx, vals, fields=None):
+    """models.fm.train_step_body on a mesh whose feed is SEGMENTED
+    (data/pipeline.segment_plan): ``uniq_ids`` is one segment of
+    ``U / n`` slots per row shard, segment s naming rows that shard s
+    holds (and ``pad_id`` in its spare slots), so each shard walks its
+    own slots and nobody walks all U.
+
+    - ``gather``: every shard gathers its segment from its own block of
+      the table (``shard_map``; no mask over U) and the ``[U / n, D]``
+      pieces are all-gathered into the ``[U, D]`` that ``expand`` needs
+      on every shard.
+    - the middle is ``grad_body`` as on one device, the batch cut over
+      ``data`` by GSPMD (which all-reduces the slot gradients).
+    - ``adagrad``: every shard takes the gradients of its segment and
+      runs ``sparse_adagrad_apply`` on its own blocks of table and
+      accumulator, over ``U / n`` slots.
+
+    A feed in another order is NOT an error the step can see: a real
+    row in a segment whose shard does not hold it reads as zeros and
+    its update is dropped. ``DeviceBatch.row_shards`` says how a batch
+    was cut, and the train loop checks it (train.StepLoop.place).
+    ``blocks`` > 1 (multi-process: ``global_batch`` lays the processes'
+    feeds side by side, each segmented) costs two local transposes of
+    ``[U, D]``."""
+    from fast_tffm_tpu.models.fm import grad_body, sparse_adagrad_apply
+    n = int(mesh.devices.size)
+    ids = _regroup(uniq_ids, blocks, n)
+
+    def gather(block, ids):
+        with jax.named_scope("gather"):
+            mine = block.at[_block_index(mesh, block, ids)].get(
+                mode="fill", fill_value=0.0)
+            return jax.lax.all_gather(mine, mesh.axis_names, tiled=True)
+
+    # check_vma=False: an all_gather's result is typed as varying over
+    # its axes, though every shard holds the same [U, D].
+    gathered = jax.shard_map(
+        gather, mesh=mesh, in_specs=(ROW_SPEC, SLOT_SPEC),
+        out_specs=P(), check_vma=False)(table, ids)
+    loss, scores, grad = grad_body(
+        spec, _regroup(gathered, n, blocks), labels, weights,
+        uniq_ids, local_idx, vals, fields, mesh=mesh)
+
+    def apply(block, acc_block, ids, grad):
+        # The scatter-adds drop a slot indexed past the block (jax's
+        # default for a scatter), and the accumulator's gather clamps
+        # it to a row whose update is then dropped.
+        return sparse_adagrad_apply(
+            block, acc_block, _block_index(mesh, block, ids), grad,
+            spec.learning_rate)
+
+    table, acc = jax.shard_map(
+        apply, mesh=mesh,
+        in_specs=(ROW_SPEC, ROW_SPEC, SLOT_SPEC, ROW_SPEC),
+        out_specs=(ROW_SPEC, ROW_SPEC))(
+            table, acc, ids, _regroup(grad, blocks, n))
+    return table, acc, loss, scores
+
+
 @functools.lru_cache(maxsize=None)
 def make_sharded_train_step(spec: ModelSpec, mesh: Mesh,
-                            with_fields: Optional[bool] = None):
-    """The same step as models.fm.make_train_step, jitted with mesh
-    shardings so GSPMD partitions it: batch over ``data``, table rows over
-    the whole mesh, loss replicated. Cached per (spec, mesh)."""
+                            with_fields: Optional[bool] = None,
+                            blocks: int = 1):
+    """The train step on a mesh (``sharded_train_step_body``): batch
+    over ``data``, table rows over the whole mesh, loss replicated;
+    the feed is segmented by row shard, ``blocks`` feeds side by side.
+    Cached per (spec, mesh, blocks)."""
     if with_fields is None:
         with_fields = spec.model_type == "ffm"
     _require_host_dedup(spec)
     in_sh, out_sh = _shardings(mesh, with_fields)
-    fn = functools.partial(train_step_body, spec, mesh=mesh)
+    fn = functools.partial(sharded_train_step_body, spec, mesh, blocks)
     fn.__name__ = "fm_sharded_train_step"  # module/trace name (fm._bind)
     jitted = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
                      donate_argnums=(0, 1))

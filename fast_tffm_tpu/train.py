@@ -645,6 +645,10 @@ class _Session:
         # container).
         self.mesh_devices = (int(self.mesh.devices.size)
                              if self.mesh is not None else 1)
+        # How the mesh cuts the rows: the data plane orders a batch's
+        # unique rows by owning shard (parallel/sharded.py). None off it.
+        from fast_tffm_tpu.data.pipeline import RowShards
+        self.row_shards = RowShards.of(cfg, self.mesh_devices)
         preflight_capacity(cfg, "train", shards=self.mesh_devices)
         if tel is not None:
             # Set once: a reader of the stream can tell a mesh run (and
@@ -931,7 +935,7 @@ def _restore(s: _Session) -> None:
         # chief-decided — data/stream.probe_stream_uniq_bucket.)
         from fast_tffm_tpu.data.pipeline import probe_uniq_bucket
         s.uniq_bucket = cfg.uniq_bucket or probe_uniq_bucket(
-            cfg, cfg.train_files)
+            cfg, cfg.train_files, shards=s.row_shards)
         logger.info("fixed unique-row bucket: %d", s.uniq_bucket)
     if multi_process and cfg.validation_files:
         from fast_tffm_tpu.data.pipeline import probe_uniq_bucket
@@ -953,6 +957,7 @@ def _restore(s: _Session) -> None:
                 "leg). Run admit-mode training on one process.")
         from fast_tffm_tpu.vocab.table import VocabRuntime
         s.vocab = VocabRuntime.from_config(cfg)
+        s.vocab.row_shards = s.row_shards
         logger.info(
             "vocab admission: %d physical rows (row 0 = shared "
             "cold row) over a 2^30 hashed id space; admit/evict "
@@ -1053,7 +1058,9 @@ def _build_state_and_step(s: _Session):
             table, acc = restored["table"], restored["acc"]
         else:
             table, acc = init_sharded_state(cfg, s.mesh, cfg.seed)
-        step_fn = make_sharded_train_step(spec, s.mesh)
+        # global_batch lays one segmented feed a process side by side
+        step_fn = make_sharded_train_step(spec, s.mesh,
+                                          blocks=jax.process_count())
         # Logged once the state exists, so the line can say where
         # it landed: a row-sharded table shows near-equal bytes on
         # every local device, one that fell onto the first chip
@@ -1303,6 +1310,13 @@ class StepLoop:
         encoded batch (the offload step takes host arrays and never
         gets here)."""
         s = self.s
+        if s.mesh is not None and batch.row_shards != s.mesh_devices:
+            # The mesh step cannot see this: it reads a row outside
+            # its shard's segment as zeros and drops its update.
+            raise ValueError(
+                f"a batch of {batch.row_shards} segment(s) of unique "
+                f"rows fed to a mesh of {s.mesh_devices} row shards: "
+                "build it with row_shards = RowShards.of(cfg, mesh size)")
         if s.multi_process:
             # The global-array assembly ships every shard's bytes.
             return self._global_batch(s.mesh, len(batch.uniq_ids),
@@ -1685,7 +1699,8 @@ def _agreed_batch(s: _Session, loop: StepLoop, batch, epoch: int):
         return None
     if batch is None:
         from fast_tffm_tpu.data.pipeline import empty_batch
-        batch = empty_batch(s.cfg, uniq_bucket=s.uniq_bucket)
+        batch = empty_batch(s.cfg, uniq_bucket=s.uniq_bucket,
+                            shards=s.row_shards)
     return batch
 
 
@@ -1711,7 +1726,8 @@ def _run_epochs(s: _Session, loop: StepLoop) -> None:
                 epochs=1, seed=cfg.seed + epoch,
                 fixed_shape=s.multi_process, uniq_bucket=s.uniq_bucket,
                 stats=epoch_stats, raw_ids=s.raw_mode,
-                bad_lines=s.bad_tracker, vocab=s.vocab),
+                bad_lines=s.bad_tracker, vocab=s.vocab,
+                row_shards=s.row_shards),
                 depth=cfg.prefetch_depth,
                 gil_bound=gil_bound_iteration(cfg, cfg.weight_files))
             # fmlint: disable=R003 -- anchors the per-epoch
@@ -1810,7 +1826,7 @@ def _epoch_barrier(s: _Session, loop: StepLoop, epoch: int,
         s.uniq_bucket = adapt_uniq_bucket(
             cfg, s.uniq_bucket, int(tot[:, 0].sum()),
             int(tot[:, 1].sum()), logger,
-            max_uniq=int(tot[:, 2].max()))
+            max_uniq=int(tot[:, 2].max()), shards=s.row_shards)
     if not stopping:
         # The epoch boundary IS a vocab barrier point: the epoch's
         # observations admit/evict here, so the next epoch (and the
@@ -2076,7 +2092,8 @@ def _run_stream(s: _Session, loop: StepLoop) -> None:
     u_bucket = 0
     if multi_process:
         u_bucket = (cfg.uniq_bucket
-                    or streamlib.probe_stream_uniq_bucket(cfg, tracker))
+                    or streamlib.probe_stream_uniq_bucket(
+                        cfg, tracker, shards=s.row_shards))
         logger.info("fixed unique-row bucket: %d", u_bucket)
     workers = streamlib.stream_workers(cfg, fixed_shape=multi_process)
     if workers > 1:
@@ -2090,7 +2107,8 @@ def _run_stream(s: _Session, loop: StepLoop) -> None:
         stop=(None if multi_process else (lambda: bool(s.preempted))),
         fixed_shape=multi_process, uniq_bucket=u_bucket,
         raw_ids=s.raw_mode, workers=workers,
-        bad_lines=s.bad_tracker, vocab=s.vocab)
+        bad_lines=s.bad_tracker, vocab=s.vocab,
+        row_shards=s.row_shards)
     clock = _StreamClock(s, tracker)
     if tel is not None:
         tel.set("stream/publish_interval_seconds", clock.publish_every)
@@ -2149,7 +2167,8 @@ def _stream_lockstep(s: _Session, loop: StepLoop, clock: _StreamClock,
         if bool(flags[:, 2].all()) and not bool(flags[:, 0].any()):
             break
         if bool(flags[:, 0].any()):
-            batch = (b if has else empty_batch(cfg, uniq_bucket=u_bucket))
+            batch = (b if has else empty_batch(cfg, uniq_bucket=u_bucket,
+                                               shards=s.row_shards))
             _stream_step(s, loop, clock, batch)
         else:
             if tel is not None:
@@ -2332,7 +2351,8 @@ SHRINK_FILL_FRACTION = 0.35
 
 
 def adapt_uniq_bucket(cfg: FmConfig, uniq_bucket: int, spilled: int,
-                      batches: int, logger, max_uniq: int = 0) -> int:
+                      batches: int, logger, max_uniq: int = 0,
+                      shards=None) -> int:
     """Next epoch's fixed unique-row bucket, given THIS epoch's job-wide
     stats: double (up to the worst-case ladder top) while the spill
     fraction stays above SPILL_WARN_FRACTION; halve (never below 64 or
@@ -2343,12 +2363,14 @@ def adapt_uniq_bucket(cfg: FmConfig, uniq_bucket: int, spilled: int,
     rest of the job (round-4 review). Deterministic in its inputs —
     callers must feed every process the same totals (train() allgathers
     them) so all agree on the new batch shapes without negotiation. An
-    explicit ``uniq_bucket`` config is never overridden.
+    explicit ``uniq_bucket`` config is never overridden. On a mesh
+    (``shards``) the bounds hold per segment of the bucket and
+    ``max_uniq`` follows the fullest one (pipeline._num_uniq).
     """
     if cfg.uniq_bucket or not batches:
         return uniq_bucket
     if spilled / batches > SPILL_WARN_FRACTION:
-        top = uniq_bucket_top(cfg)
+        top = uniq_bucket_top(cfg, shards=shards)
         if uniq_bucket >= top:
             return uniq_bucket
         new_bucket = min(uniq_bucket * 2, top)
@@ -2361,9 +2383,11 @@ def adapt_uniq_bucket(cfg: FmConfig, uniq_bucket: int, spilled: int,
     if (spilled == 0 and max_uniq
             and max_uniq <= uniq_bucket * SHRINK_FILL_FRACTION
             and half >= 64
-            # config invariant: the bucket must exceed the per-example
-            # feature cap or one dense example could overflow it outright
-            and half > cfg.max_features_per_example):
+            # config invariant: the bucket (on a mesh each segment)
+            # must exceed the per-example feature cap or one dense
+            # example could overflow it outright
+            and half // (shards.n if shards else 1)
+            > cfg.max_features_per_example):
         logger.info(
             "lowering uniq_bucket %d -> %d for the next epoch (densest "
             "batch used %d unique rows, %.0f%% fill — recovering "

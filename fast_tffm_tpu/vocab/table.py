@@ -127,6 +127,9 @@ class VocabMap:
         # False on eval_view() snapshots: a validation sweep's unique
         # tail must not skew the training stream's cold-hit rate.
         self.count_telemetry = True
+        # A mesh train session sets this (data/pipeline.RowShards): the
+        # remap then orders each batch's physical rows by owning shard.
+        self.row_shards = None
 
     @staticmethod
     def build_cfg(cfg):
@@ -231,6 +234,16 @@ class VocabMap:
             if n_miss:
                 new_uniq[0] = COLD_ROW
             new_uniq[base:base + n_hits] = phys[hit]
+            if self.row_shards is not None:
+                # A mesh train step's feed: the physical rows ordered
+                # by owning row shard, U the rung the fullest shard
+                # fits (data/pipeline.segment_plan).
+                from fast_tffm_tpu.data.pipeline import (_ladder_fit,
+                                                         segment_slots)
+                new_uniq, inv = segment_slots(
+                    new_uniq, inv, self.row_shards,
+                    lambda need: _ladder_fit(need, [len(u)]))
+                batch.row_shards = self.row_shards.n
             batch.uniq_ids = new_uniq
             batch.local_idx = inv[batch.local_idx]
             obs = v64[real]  # unique by the host-dedup contract

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from fast_tffm_tpu.config import FmConfig
-from fast_tffm_tpu.data.pipeline import batch_iterator
+from fast_tffm_tpu.data.pipeline import RowShards, batch_iterator
 from fast_tffm_tpu.models.fm import (ModelSpec, batch_args, init_accumulator,
                                      init_table, make_score_fn,
                                      make_train_step)
@@ -40,6 +40,15 @@ def _write_data(tmp_path, n=96, seed=3, field_aware=False):
     return str(p)
 
 
+def _mesh_batches(cfg, mesh, **kw):
+    """The batches a mesh train step is fed: unique rows ordered by
+    owning row shard (data/pipeline.segment_slots)."""
+    kw.setdefault("training", True)
+    return batch_iterator(
+        cfg, cfg.train_files,
+        row_shards=RowShards.of(cfg, int(mesh.devices.size)), **kw)
+
+
 def _cfg(path, **kw):
     base = dict(vocabulary_size=64, factor_num=4, batch_size=16,
                 train_files=(path,), epoch_num=1, shuffle=False,
@@ -65,7 +74,7 @@ def test_sharded_step_matches_single_device(tmp_path, model_axis):
 
     step_1 = make_train_step(spec)
     step_s = make_sharded_train_step(spec, mesh)
-    for batch in batch_iterator(cfg, cfg.train_files, training=True):
+    for batch in _mesh_batches(cfg, mesh):
         args = batch_args(batch)
         table_1, acc_1, loss_1, scores_1 = step_1(table_1, acc_1, **args)
         placed = shard_batch(mesh, **args)
@@ -108,7 +117,7 @@ def test_sharded_ffm_step(tmp_path):
     table_s, acc_s = init_sharded_state(cfg, mesh, seed=0)
     step_1 = make_train_step(spec)
     step_s = make_sharded_train_step(spec, mesh)
-    for batch in batch_iterator(cfg, cfg.train_files, training=True):
+    for batch in _mesh_batches(cfg, mesh):
         args = batch_args(batch)
         table_1, acc_1, loss_1, _ = step_1(table_1, acc_1, **args)
         placed = shard_batch(mesh, **args)
@@ -130,7 +139,7 @@ def test_ladder_overflow_stays_power_of_two(tmp_path):
     table_s, acc_s = init_sharded_state(cfg, mesh)
     step_s = make_sharded_train_step(spec, mesh)
     loss = None
-    for batch in batch_iterator(cfg, cfg.train_files, training=True):
+    for batch in _mesh_batches(cfg, mesh):
         assert len(batch.uniq_ids) % 8 == 0
         args = batch_args(batch)
         table_s, acc_s, loss, _ = step_s(table_s, acc_s,
@@ -204,7 +213,7 @@ def test_pallas_kernel_on_mesh_matches_xla(tmp_path):
         spec = ModelSpec.from_config(cfg)
         table_s, acc_s = init_sharded_state(cfg, mesh)
         step_s = make_sharded_train_step(spec, mesh)
-        for batch in batch_iterator(cfg, cfg.train_files, training=True):
+        for batch in _mesh_batches(cfg, mesh):
             table_s, acc_s, loss, scores = step_s(
                 table_s, acc_s, **shard_batch(mesh, **batch_args(batch)))
         results[kernel] = (float(loss), np.asarray(scores),
@@ -230,7 +239,7 @@ def test_sharded_order3_step_matches_single_device(tmp_path):
     table_1, acc_1 = init_table(cfg, 0), init_accumulator(cfg)
     step_1 = make_train_step(spec)
     step_s = make_sharded_train_step(spec, mesh)
-    for batch in batch_iterator(cfg, cfg.train_files, training=True):
+    for batch in _mesh_batches(cfg, mesh):
         args = batch_args(batch)
         table_1, acc_1, loss_1, _ = step_1(table_1, acc_1, **args)
         table_s, acc_s, loss_s, _ = step_s(table_s, acc_s,
@@ -292,8 +301,7 @@ def test_mesh_step_on_a_global_batch_matches_the_float64_oracle(tmp_path):
     acc = np.asarray(acc_s, np.float64)[:cfg.num_rows]
     step = make_sharded_train_step(spec, mesh)
     n = 0
-    for n, batch in enumerate(batch_iterator(cfg, cfg.train_files,
-                                             training=True), 1):
+    for n, batch in enumerate(_mesh_batches(cfg, mesh), 1):
         assert len(batch.uniq_ids) % 4 == 0
         examples = _oracle_examples(batch)
         labels = np.asarray(batch.labels, np.float64)
@@ -325,12 +333,12 @@ def test_mesh_step_on_a_global_batch_matches_the_float64_oracle(tmp_path):
 
 def test_each_shards_rows_add_up_to_the_uncut_gather(tmp_path):
     """The share tied to the whole: what each shard gathers from its
-    own rows for the slots of ``uniq_ids`` that fall in its range,
-    the rest left zero (the masked local pass GSPMD makes of the
-    gather), adds up to the gather from the uncut table, every slot
-    claimed once. The host unique is in first-seen order, pad slot
-    first, NOT sorted: a shard's slots are no contiguous range, so an
-    explicit exchange would have to sort or mask as GSPMD does."""
+    own rows for ITS SEGMENT of ``uniq_ids`` (ISSUE 33: the feed is
+    ordered by owning shard, so a shard walks U / 4 slots and masks
+    nothing), laid side by side, is the gather from the uncut table.
+    The host unique as the builder gives it is in first-seen order,
+    pad slot first, NOT sorted: that feed's slots of one shard are no
+    contiguous range, which is why the data plane orders them."""
     rng = np.random.default_rng(28)
     path = tmp_path / "train.txt"
     cfg = _cfg(str(path), vocabulary_size=1500, batch_size=32,
@@ -338,23 +346,38 @@ def test_each_shards_rows_add_up_to_the_uncut_gather(tmp_path):
     path.write_text("\n".join(_global_batches(cfg, rng, 1, 40)) + "\n")
     mesh = make_mesh(jax.devices()[:4])
     table_s, _ = init_sharded_state(cfg, mesh, seed=6)
-    batch = next(iter(batch_iterator(cfg, cfg.train_files, training=True)))
-    uniq = np.asarray(batch.uniq_ids)
+    first_seen = next(iter(batch_iterator(cfg, cfg.train_files,
+                                          training=True)))
+    uniq = np.asarray(first_seen.uniq_ids)
     assert uniq[0] == cfg.pad_id and (np.diff(uniq) < 0).any()
+    batch = next(iter(_mesh_batches(cfg, mesh)))
+    assert batch.row_shards == 4
+    uniq = np.asarray(batch.uniq_ids)
+    seg = len(uniq) // 4
     whole = np.asarray(table_s)[uniq]
-    parts, claimed = np.zeros_like(whole), np.zeros(len(uniq), int)
-    sizes = []
-    for shard in table_s.addressable_shards:
+    parts, sizes = [], []
+    for s, shard in enumerate(table_s.addressable_shards):
         lo, hi = shard.index[0].start, shard.index[0].stop
-        mine = np.flatnonzero((uniq >= lo) & (uniq < hi))
-        sizes.append(int((uniq[mine] != cfg.pad_id).sum()))
-        parts[mine] += np.asarray(shard.data)[uniq[mine] - lo]
-        claimed[mine] += 1
-    np.testing.assert_array_equal(claimed, 1)
-    np.testing.assert_array_equal(parts, whole)
-    # real rows: a hot shard, a lukewarm one (which also answers every
-    # pad slot), and two that own no slot at all
+        assert (lo, hi) == (1024 * s, 1024 * (s + 1))
+        mine = uniq[s * seg:(s + 1) * seg]
+        real = mine != cfg.pad_id
+        assert ((mine[real] >= lo) & (mine[real] < hi)).all()
+        sizes.append(int(real.sum()))
+        # a pad slot names the one dead row, which only its own shard
+        # holds: the others read zeros for it (the dead row's value)
+        local = np.where((mine >= lo) & (mine < hi), mine - lo, 0)
+        rows = np.asarray(shard.data)[local]
+        parts.append(np.where(((mine >= lo) & (mine < hi))[:, None],
+                              rows, 0.0))
+    np.testing.assert_array_equal(np.concatenate(parts), whole)
+    # real rows: a hot shard, a lukewarm one (which also holds the pad
+    # row), and two that own no slot at all; U follows the hot one
     assert sizes[0] > sizes[1] > 0 and sizes[2:] == [0, 0]
+    assert seg > sizes[0] >= seg // 2 or seg == 16
+    # the same cells name the same rows, example by example
+    np.testing.assert_array_equal(
+        uniq[batch.local_idx],
+        np.asarray(first_seen.uniq_ids)[first_seen.local_idx])
 
 
 def test_mesh_run_feeds_the_counters_a_reader_needs(tmp_path):
