@@ -112,7 +112,13 @@ class FmConfig:
     validation_max_batches: int = 0
     # Static-shape bucketing (TPU-specific; SURVEY §7 hard part #1):
     max_features_per_example: int = 256   # hard cap on nnz/example (truncate)
-    bucket_ladder: Tuple[int, ...] = (8, 16, 32, 64, 128, 256)
+    # Quarter-octave rungs, each a multiple of 8 (the sublane tile of
+    # the step's [B, L, D] arrays): from 32 up a batch is padded by at
+    # most a quarter of its widest example. Every pass over cells pays
+    # for a pad cell what it pays for a real one (PERF.md section 5).
+    bucket_ladder: Tuple[int, ...] = (
+        8, 16, 24, 32, 40, 48, 56, 64, 80, 96, 112, 128, 160, 192, 224,
+        256)
     # Fixed unique-row count per batch in multi-process (fixed-shape)
     # training. 0 = auto: measured from the data at startup
     # (data/pipeline.probe_uniq_bucket). Overfull batches spill safely.

@@ -10,8 +10,9 @@ started with (no torn scores).
 
 - ``server.py``   ScorerServer: verified load of the published step,
                   a pre-compiled [batch rung, L rung] shape ladder
-                  (reusing the pipeline's ``bucket_ladder`` so no
-                  request shape ever recompiles), and an admission
+                  (widths a doubling subset of the pipeline's
+                  ``bucket_ladder``, so no request shape ever
+                  recompiles), and an admission
                   queue that micro-batches concurrent requests under
                   ``serve_max_batch`` / ``serve_max_wait_ms``. Plus
                   the in-process ScoreClient tests and the soak use.

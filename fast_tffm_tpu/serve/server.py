@@ -11,11 +11,11 @@ raw-gather forward pass (scoring.CompiledScorer with dedup='device':
 no U axis, so a flush's device shape is exactly [B rung, L rung]).
 
 Shape discipline is the TPU serving contract: B rungs are powers of
-two up to ``serve_max_batch``, L rungs are the pipeline's
-``bucket_ladder`` (the same rungs batch training/predict compile), and
-every (B, L) pair is compiled at startup — steady state never
-recompiles, whatever request sizes arrive. ``require_bounded_examples``
-guarantees no parsed example can exceed the ladder.
+two up to ``serve_max_batch``, L rungs are a doubling subset of the
+pipeline's ``bucket_ladder`` (``width_rung_ladder``), and every (B, L)
+pair is compiled at startup — steady state never recompiles, whatever
+request sizes arrive. ``require_bounded_examples`` guarantees no parsed
+example can exceed the ladder.
 
 Hot reload (serve/reload.py drives it): ``reload_step`` restores the
 named step through the same verified-restore path every driver uses
@@ -124,6 +124,22 @@ def batch_rung_ladder(serve_max_batch: int) -> Tuple[int, ...]:
     while rungs[-1] < serve_max_batch:
         rungs.append(rungs[-1] * 2)
     return tuple(rungs)
+
+
+def width_rung_ladder(ladder: Sequence[int],
+                      max_features: int) -> Tuple[int, ...]:
+    """Padded feature-width rungs: the rung of ``ladder`` that covers
+    ``max_features`` and, below it, each rung at most half the one kept
+    above. The pipeline's ladder is spaced for training batches of
+    thousands of examples, where a pad cell costs the step a scatter
+    update; a flush holds at most ``serve_max_batch`` examples and a
+    width costs one compiled program per batch rung, so the server
+    keeps widths that double, whatever spacing it is handed."""
+    kept = [_ladder_fit(max(1, max_features), ladder)]
+    for b in reversed(ladder):
+        if 2 * b <= kept[-1]:
+            kept.append(b)
+    return tuple(reversed(kept))
 
 
 def _concat_blocks(blocks: Sequence[ParsedBlock]) -> ParsedBlock:
@@ -237,10 +253,12 @@ class ScorerServer:
             self._build_cfg = cfg
         self._vocab_map = None
         self._b_ladder = batch_rung_ladder(cfg.serve_max_batch)
-        self._l_rungs = tuple(
-            b for b in cfg.bucket_ladder
-            if b <= _ladder_fit(max(1, cfg.max_features_per_example),
-                                cfg.bucket_ladder))
+        self._l_rungs = width_rung_ladder(cfg.bucket_ladder,
+                                          cfg.max_features_per_example)
+        # A flush pads onto the widths that were compiled: its batches
+        # are built on the thinned ladder.
+        self._build_cfg = dataclasses.replace(
+            self._build_cfg, bucket_ladder=self._l_rungs)
         self._table_lock = threading.Lock()  # guards the (table,
         # served_step) pair: a flush must capture both from the same
         # swap (fmlint R008)

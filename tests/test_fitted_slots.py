@@ -291,6 +291,10 @@ def test_a_one_device_cell_ships_the_fitted_unique_and_checks_out(
     slots = (shown["h2d_bytes_per_example"]["value"] - rect) * 64 / 4
     assert _uniq_ladder(64, 8)[0] <= slots <= 256 < 64 * 8 + 1
     assert 0.5 <= shown["uniq_slot_fill"]["value"] <= 1.0
+    # ISSUE 34's ``cell_fill``: every example has the corpus's features
+    # (2 numeric + 3 categorical; FFM 1 + 3) in the 8 columns it ships.
+    assert shown["cell_fill"]["value"] == pytest.approx(
+        {"tiny-train": 5, "tiny-ffm-train": 4}[workload] / 8)
     if cparser.available():
         assert shown["host_build_s_per_batch"]["value"] > 0
     assert "dedup_sort_ms" not in shown      # no such scope in the step

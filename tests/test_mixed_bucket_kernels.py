@@ -7,7 +7,10 @@ byte-equal results vs forcing each kernel globally would differ — so
 instead we pin that the mixed run equals a run where each batch's
 kernel is resolved the same way manually."""
 
+import dataclasses
+
 import numpy as np
+import pytest
 
 from fast_tffm_tpu.config import FmConfig
 from fast_tffm_tpu.data.pipeline import batch_iterator
@@ -44,7 +47,6 @@ def test_one_job_spans_both_kernel_regimes(tmp_path, rng):
     spec = ModelSpec.from_config(cfg)
     # On the CPU rig from_config resolves auto -> xla; force the
     # TPU-side behavior (auto survives) to exercise mixed dispatch.
-    import dataclasses
     spec = dataclasses.replace(spec, kernel="auto")
     step = make_train_step(spec)
     table, acc = init_table(cfg), init_accumulator(cfg)
@@ -76,3 +78,37 @@ def test_one_job_spans_both_kernel_regimes(tmp_path, rng):
                                   np.asarray(losses2))
     np.testing.assert_array_equal(np.asarray(table),
                                   np.asarray(table2))
+
+
+@pytest.mark.parametrize("L", [24, 40, 80])
+def test_an_explicit_pallas_step_runs_at_a_rung_that_is_no_power_of_two(
+        tmp_path, rng, L):
+    """ISSUE 34: the default ladder's rungs are multiples of 8, not
+    powers of two. ``kernel = pallas`` with ``dedup = device`` at such a
+    width runs (the kernel's blocks take the whole of L) and agrees
+    with the XLA step; ``auto`` still draws its line at 64."""
+    vocab = 512
+    data = tmp_path / "d.txt"
+    data.write_text("\n".join(_lines(rng, 32, L - 6, L, vocab)) + "\n")
+    cfg = FmConfig(vocabulary_size=vocab, factor_num=4, batch_size=32,
+                   shuffle=False, kernel="pallas", dedup="device",
+                   max_features_per_example=L, learning_rate=0.1,
+                   model_file=str(tmp_path / "m" / "fm"))
+    assert L in cfg.bucket_ladder
+    spec = ModelSpec.from_config(cfg, training=True)
+    assert (spec.kernel, spec.dedup) == ("pallas", "device")
+    batch, = batch_iterator(cfg, [str(data)], training=True, epochs=1,
+                            raw_ids=True)
+    assert batch.vals.shape == (32, L)
+    auto = dataclasses.replace(spec, kernel="auto")
+    assert resolved_kernel(auto, L) == ("pallas" if L >= 64 else "xla")
+    out = {}
+    for k in ("pallas", "xla"):
+        step = make_train_step(dataclasses.replace(spec, kernel=k))
+        out[k] = step(init_table(cfg), init_accumulator(cfg),
+                      **batch_args(batch))
+    np.testing.assert_allclose(float(out["pallas"][2]),
+                               float(out["xla"][2]), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(out["pallas"][0]),
+                               np.asarray(out["xla"][0]),
+                               rtol=1e-4, atol=1e-6)

@@ -217,6 +217,42 @@ def test_no_new_shapes_after_warmup(trained):
         server.close()
 
 
+@pytest.mark.parametrize("n_features", [5, 17, 24, 39, 56])
+def test_warmup_compiles_what_it_compiled_under_the_doubling_ladder(
+        trained, n_features):
+    """ISSUE 34: the pipeline's default ladder has 16 rungs where it
+    had 6; the server keeps a doubling subset of whatever ladder it is
+    given, so its warm-up compiles the programs it compiled before, and
+    a request at a width between two kept rungs pads onto one of them
+    (no shape beyond the warmed matrix)."""
+    cfg, steps, _wd = trained
+    doubling = (8, 16, 32, 64, 128, 256)
+    assert FmConfig().bucket_ladder != doubling
+    shapes = {}
+    for ladder in (FmConfig().bucket_ladder, doubling):
+        server = _server(dataclasses.replace(
+            cfg, bucket_ladder=ladder, max_features_per_example=64))
+        try:
+            shapes[ladder] = server.compiled_shapes
+            assert server._reg.snapshot()["gauges"][
+                "serve/compiled_shapes"] == len(server.compiled_shapes)
+            flushed = []
+            score = server._scorer.score_batch
+            server._scorer.score_batch = lambda table, batch: (
+                flushed.append(batch.vals.shape), score(table, batch))[1]
+            rng = np.random.default_rng(n_features)
+            lines = ["1 " + " ".join(
+                f"{i}:1.0" for i in sorted(rng.choice(
+                    200, size=n_features, replace=False)))
+                for _ in range(3)]
+            assert len(server.score_lines(lines, timeout=30).scores) == 3
+            assert flushed and set(flushed) <= set(server.compiled_shapes)
+        finally:
+            server.close()
+    assert shapes[FmConfig().bucket_ladder] == shapes[doubling]
+    assert len(shapes[doubling]) == 4 * 4   # B 1..8 x L 8, 16, 32, 64
+
+
 # --- hot reload ------------------------------------------------------------
 
 

@@ -24,7 +24,7 @@ from jax.sharding import SingleDeviceSharding
 
 from fast_tffm_tpu.checkpoint import CheckpointState
 from fast_tffm_tpu.config import FmConfig
-from fast_tffm_tpu.data.pipeline import batch_iterator
+from fast_tffm_tpu.data.pipeline import _ladder_fit, batch_iterator
 from fast_tffm_tpu.models import fm
 from fast_tffm_tpu.models.fm import (ModelSpec, TrainStep, batch_args,
                                      init_accumulator, init_table,
@@ -317,12 +317,13 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-# The two one-chip cells of BENCHMARK.json: rows, batch, rung, slots.
+# The two one-chip cells of BENCHMARK.json: rows, batch, rung, slots
+# (22 fields ride the 24 rung and 39 features the 40 rung since PR 34).
 CELLS = {
     "ffm4-train-zipf": (ModelSpec("ffm", 2, 4, 22, 1 << 23, "logistic",
-                                  0.0, 0.0, 0.01), 8192, 32, 16384),
+                                  0.0, 0.0, 0.01), 8192, 24, 16384),
     "fm16-train-zipf": (ModelSpec("fm", 2, 16, 0, 1 << 26, "logistic",
-                                  0.0, 0.0, 0.01), 8192, 64, 32768),
+                                  0.0, 0.0, 0.01), 8192, 40, 32768),
 }
 
 
@@ -336,6 +337,7 @@ def test_v5e_step_has_no_copy_of_the_whole_state(one_chip, cell):
     times."""
     from jax.experimental.compilation_cache import compilation_cache
     spec, B, L, U = CELLS[cell]
+    assert L == _ladder_fit(spec.field_num or 39, FmConfig().bucket_ladder)
     rows, dim = spec.vocabulary_size + 1, spec.row_dim
 
     def sd(shape, dtype):
