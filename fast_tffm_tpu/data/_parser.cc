@@ -74,6 +74,7 @@ struct ShardOut {
                                  // first_lineno convention)
   int64_t lines_scanned = 0;  // lines walked by parse_range (left 0 on
                               // a parse failure; callers fall back)
+  int64_t truncated = 0;  // feature tokens skipped past max_feats
   bool failed = false;
   // Error site, kept as (lineno, message) instead of preformatted text
   // so parse_threaded can rebase shard-relative linenos after the join
@@ -492,6 +493,7 @@ void parse_range(const char* blob, const char* end, int64_t first_lineno,
         // the line; skipping (not erroring) matches that. Only the
         // token boundary matters here, not its structure.
         while (q < line_end && !is_ws(*q)) q++;
+        out->truncated++;
         continue;
       }
       if (!try_fast_token(q, line_end, vocab, hash_ids, field_aware,
@@ -603,8 +605,12 @@ extern "C" {
 // tolerant/weighted keep_empty input fell back to the Python parser
 // and the tolerant keep_empty shape routed serial); 8 = the builder
 // stages cells flat and fm_bb_finish takes the output width (fm_bb_peek
-// sizes it): a batch costs its own cells, not B x the feature cap.
-int64_t fm_abi_version() { return 9; }
+// sizes it): a batch costs its own cells, not B x the feature cap;
+// 9 = fm_bb_row_shards, fm_bb_uniq, fm_bb_cells and fm_bb_finish's
+// remap (a mesh's feed); 10 = the feature tokens skipped past the
+// per-example cap are counted (fm_parse_block's truncated_out,
+// fm_bb_truncated).
+int64_t fm_abi_version() { return 10; }
 
 // Scan complete lines of [blob, blob+blob_len) until `n_target` lines
 // that PRODUCE AN EXAMPLE have been seen. The counting rule must equal
@@ -659,6 +665,7 @@ int fm_auto_threads() {
 // Returns 0 on success. Outputs:
 //   labels[n_examples], poses[n_examples+1], ids[nnz], vals[nnz]
 //   (+ fields[nnz] when field_aware — FFM `field:fid[:val]` tokens)
+// truncated_out: feature tokens skipped past max_feats, all examples.
 // Caller allocates: labels/poses sized for the line count, ids/vals/
 // fields for the worst-case token count (cparser.py sizes them from the
 // blob). fields_out may be null when !field_aware. `keep_empty` turns
@@ -668,6 +675,7 @@ int fm_parse_block(const char* blob, int64_t blob_len, int64_t vocab,
                    int hash_ids, int field_aware, int64_t field_num,
                    int max_feats, int keep_empty, int num_threads,
                    int64_t* n_examples_out, int64_t* nnz_out,
+                   int64_t* truncated_out,
                    float* labels_out, int32_t* poses_out, int32_t* ids_out,
                    float* vals_out, int32_t* fields_out, char* err_out,
                    int64_t err_cap) {
@@ -713,6 +721,8 @@ int fm_parse_block(const char* blob, int64_t blob_len, int64_t vocab,
   }
   *n_examples_out = b;
   *nnz_out = z;
+  *truncated_out = 0;
+  for (const auto& o : outs) *truncated_out += o.truncated;
   return 0;
 }
 
@@ -772,6 +782,9 @@ struct BatchBuilder {
   int64_t n_ex = 0;
   int32_t n_uniq = 1;  // slot 0 = pad
   int32_t max_nnz = 0;
+  // Feature tokens skipped past max_feats since fm_bb_truncated last
+  // asked: a line cut at the cap trains on its head alone.
+  int64_t truncated = 0;
   int64_t lineno = 0;
   std::string error;
   // Threaded feed (T > 1): each fed chunk's complete lines are parsed
@@ -1014,6 +1027,7 @@ int bb_feed_threaded(BatchBuilder* bb, const char* blob, int64_t blob_len,
     // before the error (labels may hold one half-parsed extra entry;
     // sizes is the count of COMPLETE examples).
     const size_t n_ok = o.sizes.size();
+    bb->truncated += o.truncated;
     int64_t nnz_ok = 0;
     for (size_t i = 0; i < n_ok; i++) nnz_ok += o.sizes[i];
     bb->p_labels.insert(bb->p_labels.end(), o.labels.begin(),
@@ -1149,6 +1163,7 @@ int fm_bb_feed(void* h, const char* blob, int64_t blob_len,
     }
     const size_t cells = bb->idx.size();
     int n_feats = 0;
+    int n_cut = 0;
     const int32_t saved_uniq = bb->n_uniq;
     q = tok_end;
     while (true) {
@@ -1157,6 +1172,7 @@ int fm_bb_feed(void* h, const char* blob, int64_t blob_len,
       Token t;
       if (n_feats >= bb->max_feats) {  // cap: skip tail like Python
         while (q < line_end && !is_ws(*q)) q++;  // boundary only
+        n_cut++;
         continue;
       }
       if (!try_fast_token(q, line_end, bb->vocab, bb->hash_ids,
@@ -1200,6 +1216,7 @@ int fm_bb_feed(void* h, const char* blob, int64_t blob_len,
       return 1;
     }
     bb_commit(bb, cells, label);
+    bb->truncated += n_cut;  // a spilled line counts when it is re-fed
     p = line_end + 1;
   }
   *consumed_out = p - blob;
@@ -1219,6 +1236,15 @@ int64_t fm_bb_peek(void* h, int64_t* n_uniq_out, int64_t* max_nnz_out) {
 // features; padding is not staged).
 int64_t fm_bb_cells(void* h) {
   return int64_t(static_cast<BatchBuilder*>(h)->idx.size());
+}
+
+// Feature tokens the builder skipped past its per-example cap since
+// the last call (the count starts again at 0).
+int64_t fm_bb_truncated(void* h) {
+  auto* bb = static_cast<BatchBuilder*>(h);
+  const int64_t n = bb->truncated;
+  bb->truncated = 0;
+  return n;
 }
 
 // The unique slots of the batch under construction, uniq_out[n_uniq]

@@ -116,7 +116,7 @@ def _build(out: str) -> None:
 
 # Must equal fm_abi_version() in _parser.cc. Bump both together whenever
 # an exported signature changes.
-_ABI_VERSION = 9
+_ABI_VERSION = 10
 
 
 def _open_checked(path: str) -> ctypes.CDLL:
@@ -130,7 +130,7 @@ def _open_checked(path: str) -> ctypes.CDLL:
                     "fm_dedup_ids", "fm_scan_examples", "fm_bb_new",
                     "fm_bb_feed", "fm_bb_peek", "fm_bb_finish",
                     "fm_bb_row_shards", "fm_bb_uniq", "fm_bb_cells",
-                    "fm_bb_free"):
+                    "fm_bb_truncated", "fm_bb_free"):
             getattr(lib, sym)
         lib.fm_abi_version.restype = ctypes.c_int64
         lib.fm_abi_version.argtypes = []
@@ -181,6 +181,7 @@ def _load() -> ctypes.CDLL:
             ctypes.c_int,                                 # num threads
             ctypes.POINTER(ctypes.c_int64),               # out: n_examples
             ctypes.POINTER(ctypes.c_int64),               # out: nnz
+            ctypes.POINTER(ctypes.c_int64),               # out: truncated
             np.ctypeslib.ndpointer(np.float32),           # labels buf
             np.ctypeslib.ndpointer(np.int32),             # poses buf
             np.ctypeslib.ndpointer(np.int32),             # ids buf
@@ -232,6 +233,8 @@ def _load() -> ctypes.CDLL:
             ctypes.c_void_p]                              # remap or NULL
         lib.fm_bb_cells.restype = ctypes.c_int64
         lib.fm_bb_cells.argtypes = [ctypes.c_void_p]
+        lib.fm_bb_truncated.restype = ctypes.c_int64
+        lib.fm_bb_truncated.argtypes = [ctypes.c_void_p]
         lib.fm_bb_uniq.restype = None
         lib.fm_bb_uniq.argtypes = [ctypes.c_void_p,
                                    np.ctypeslib.ndpointer(np.int32)]
@@ -300,12 +303,13 @@ def parse_lines_fast(lines: Sequence[str], vocabulary_size: int,
     fields = np.empty(max_nnz if field_aware else 1, dtype=np.int32)
     n_ex = ctypes.c_int64(0)
     nnz = ctypes.c_int64(0)
+    cut = ctypes.c_int64(0)
     errbuf = ctypes.create_string_buffer(512)
     rc = lib.fm_parse_block(
         blob, len(blob), vocabulary_size, int(hash_feature_id),
         int(field_aware), field_num,
         max_features_per_example, int(keep_empty), num_threads,
-        ctypes.byref(n_ex), ctypes.byref(nnz),
+        ctypes.byref(n_ex), ctypes.byref(nnz), ctypes.byref(cut),
         labels, poses, ids, vals, fields, errbuf, len(errbuf))
     tel = _tel()
     if rc != 0:
@@ -318,7 +322,8 @@ def parse_lines_fast(lines: Sequence[str], vocabulary_size: int,
     z = nnz.value
     return ParsedBlock(labels=labels[:b].copy(), poses=poses[:b + 1].copy(),
                        ids=ids[:z].copy(), vals=vals[:z].copy(),
-                       fields=fields[:z].copy() if field_aware else None)
+                       fields=fields[:z].copy() if field_aware else None,
+                       truncated=int(cut.value))
 
 
 def scan_examples(data: bytes, n_target: int, keep_empty: bool = False,
@@ -499,8 +504,11 @@ class BatchBuilder:
         tuple then holds ``uniq_ids`` for ``uniq``, and where ``remap``
         is not None every cell of ``local_idx`` is ``remap[slot]``,
         re-pointed as the cells are padded out. ``self.cells`` then
-        holds the batch's feature cells (padding not counted)."""
+        holds the batch's feature cells (padding not counted) and
+        ``self.truncated`` the feature tokens skipped past the
+        per-example cap since the last finish()."""
         self.cells = int(self._lib.fm_bb_cells(self._h))
+        self.truncated = int(self._lib.fm_bb_truncated(self._h))
         n_uniq = ctypes.c_int64(0)
         max_nnz = ctypes.c_int64(0)
         self._lib.fm_bb_peek(self._h, ctypes.byref(n_uniq),

@@ -51,6 +51,9 @@ class ParsedBlock:
     ids: np.ndarray           # i32 [nnz] row indices in [0, vocab)
     vals: np.ndarray          # f32 [nnz]
     fields: Optional[np.ndarray] = None   # i32 [nnz], FFM only
+    # Feature tokens skipped past max_features_per_example, all
+    # examples: cells the lines had and the block has not.
+    truncated: int = 0
 
     @property
     def batch_size(self) -> int:
@@ -114,6 +117,7 @@ def parse_lines(lines: Sequence[str], vocabulary_size: int,
     ids: List[int] = []
     vals: List[float] = []
     flds: List[int] = []
+    truncated = 0
 
     for lineno, line in enumerate(lines):
         toks = split_tokens(line)
@@ -127,9 +131,10 @@ def parse_lines(lines: Sequence[str], vocabulary_size: int,
         # already appended; the block must hold only whole examples.
         n_labels, n_ids, n_flds = len(labels), len(ids), len(flds)
         try:
-            _parse_one(toks, lineno, labels, ids, vals, flds,
-                       vocabulary_size, hash_feature_id, field_aware,
-                       field_num, max_features_per_example)
+            truncated += _parse_one(
+                toks, lineno, labels, ids, vals, flds, vocabulary_size,
+                hash_feature_id, field_aware, field_num,
+                max_features_per_example)
         except ParseError as e:
             if bad_lines is None:
                 raise
@@ -150,17 +155,19 @@ def parse_lines(lines: Sequence[str], vocabulary_size: int,
         ids=np.asarray(ids, dtype=np.int32),
         vals=np.asarray(vals, dtype=np.float32),
         fields=np.asarray(flds, dtype=np.int32) if field_aware else None,
+        truncated=truncated,
     )
 
 
 def _parse_one(toks: List[str], lineno: int, labels, ids, vals, flds,
                vocabulary_size: int, hash_feature_id: bool,
                field_aware: bool, field_num: int,
-               max_features_per_example: int) -> None:
+               max_features_per_example: int) -> int:
     """Parse one line's tokens, appending onto the CSR buffers (the
-    one per-line implementation both strict and tolerant modes run).
-    Raises ParseError mid-append on a bad token; parse_lines' tolerant
-    mode rolls the partial appends back."""
+    one per-line implementation both strict and tolerant modes run);
+    returns the feature tokens left out past the cap. Raises
+    ParseError mid-append on a bad token; parse_lines' tolerant mode
+    rolls the partial appends back."""
     try:
         label = _strict_float(toks[0])
     except ValueError:
@@ -169,7 +176,7 @@ def _parse_one(toks: List[str], lineno: int, labels, ids, vals, flds,
     n = 0
     for tok in toks[1:]:
         if max_features_per_example and n >= max_features_per_example:
-            break
+            return len(toks) - 1 - n
         parts = tok.split(":")
         if field_aware:
             if len(parts) == 2:
@@ -220,3 +227,4 @@ def _parse_one(toks: List[str], lineno: int, labels, ids, vals, flds,
         ids.append(fid)
         vals.append(val)
         n += 1
+    return 0

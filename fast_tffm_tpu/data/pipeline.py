@@ -148,6 +148,9 @@ class DeviceBatch:
     # Real feature cells (padding not counted), where the C++ builder
     # counted them as it parsed; None: a reader counts them itself.
     nnz: Optional[int] = None
+    # Feature cells the lines had and the batch has not: what the
+    # parser skipped past max_features_per_example.
+    truncated: int = 0
     # Streaming run mode only (data/stream.py): the durable stream
     # position AFTER this batch's lines — a watermark payload dict the
     # train loop adopts once the batch has actually been stepped, so
@@ -467,7 +470,8 @@ def make_device_batch(block: ParsedBlock, cfg: FmConfig,
     return DeviceBatch(labels=labels, weights=w, uniq_ids=uniq_ids,
                        local_idx=local_idx, vals=vals, fields=fields,
                        num_real=n_real,
-                       row_shards=shards.n if shards else 1)
+                       row_shards=shards.n if shards else 1,
+                       truncated=block.truncated)
 
 
 def epoch_file_order(files: List[str], shuffle: bool, seed: int,
@@ -948,9 +952,11 @@ class _BatchEmitter:
 
     def finish(self, bb):
         """A builder's batch as ``emit_drain`` takes it: its finish()
-        at the width and in the slots it ships, and its cell count.
-        Pure in ``bb``: build workers run it."""
-        return bb.finish(self.cols, self.slots) + (bb.cells,)
+        at the width and in the slots it ships, its cell count and
+        the cells its lines lost at the per-example cap. Pure in
+        ``bb``: build workers run it."""
+        return bb.finish(self.cols, self.slots) + (bb.cells,
+                                                   bb.truncated)
 
     def emit_drain(self, out, spilled: bool) -> Iterator[DeviceBatch]:
         """Emit one ``finish(bb)`` tuple and drain through the bounded
@@ -970,7 +976,8 @@ class _BatchEmitter:
                 self.pyrng.randrange(len(self.window)))
 
     def _emit(self, n, labels, uniq_ids, li, vals, fields, max_nnz,
-              cells=None, spilled: bool = False) -> DeviceBatch:
+              cells=None, truncated: int = 0,
+              spilled: bool = False) -> DeviceBatch:
         cfg, B = self.cfg, self.B
         row_shards = self.shards.n if self.shards else 1
         if self.stats is not None:
@@ -998,7 +1005,8 @@ class _BatchEmitter:
         return DeviceBatch(labels=labels, weights=weights,
                            uniq_ids=uniq_ids, local_idx=li, vals=vals,
                            fields=fields, num_real=n,
-                           row_shards=row_shards, nnz=cells)
+                           row_shards=row_shards, nnz=cells,
+                           truncated=truncated)
 
 
 class _BuildRing:

@@ -363,6 +363,7 @@ class TrainStep:
         self._layout = None     # the compiler's choice, once made
         self._relabel = None    # set where that is not the default
         self._programs = {}     # argument shapes -> compiled program
+        self._last = None       # the program the last call ran
 
     def __call__(self, table, acc, labels, weights, uniq_ids, local_idx,
                  vals, fields=None):
@@ -378,6 +379,14 @@ class TrainStep:
         program = self._programs.get(key)
         if program is None:
             program = self._programs[key] = self.compile(*args)
+            _count("train/step_programs")
+        if program is not self._last:
+            # A job whose batches ship at more than one shape (lines of
+            # unequal length: the width's rung follows a batch's widest)
+            # holds a program a shape and moves between them.
+            if self._last is not None:
+                _count("train/program_switches")
+            self._last = program
         if self._relabel is None:
             return program(table, acc, *batch)
         table, acc, loss, scores = program(*self._laid(table, acc), *batch)
@@ -431,9 +440,7 @@ class TrainStep:
     def _relay(self, x):
         if x.format.layout == self._layout:
             return x
-        tel = active()
-        if tel is not None:
-            tel.count("train/state_relayouts")
+        _count("train/state_relayouts")
         laid = jax.device_put(x, Format(self._layout, x.sharding))
         # The old buffer goes once the copy has run, by hand and waited
         # for: a buffer of another size is no use to the copy as a
@@ -444,6 +451,12 @@ class TrainStep:
         jax.block_until_ready(laid)
         x.delete()
         return laid
+
+
+def _count(name: str) -> None:
+    tel = active()
+    if tel is not None:
+        tel.count(name)
 
 
 class _Relabel:

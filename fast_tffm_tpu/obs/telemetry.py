@@ -387,6 +387,9 @@ class RunTelemetry:
                        len(batch.uniq_ids) // batch.row_shards)
         self.count("pipeline/feature_slots", B * L)
         self.count("pipeline/feature_nnz", real)
+        # Cells the lines had and the batch has not (the parsers' cut
+        # at max_features_per_example): 0 on a sound configuration.
+        self.count("pipeline/truncated_cells", batch.truncated)
         if build_seconds is not None:
             self.count("pipeline/build_seconds", build_seconds)
             self.observe("pipeline/batch_build_seconds", build_seconds)

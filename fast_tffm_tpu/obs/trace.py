@@ -16,7 +16,10 @@ stage, and whatever is listening gets the same interval:
   hand): the span lands on its thread's line in the trace's
   ``/host:CPU`` plane, on the clock of the device's operations, so an
   idle gap of the device can be named after the phase the host was in.
-  Fields stay in the JSONL so that names group. ``leaf=False`` (a span
+  The name stays plain so that names group; ``fields`` ride the
+  annotation as the event's stats (the step, the width a batch shipped
+  at), so a reader of the trace can tell one step's execution from
+  another's. ``leaf=False`` (a span
   that encloses a loop: a sweep, a validation pass) keeps the span out
   of the profiler: a gap is named after the host event that covers
   most of it, and that should be the phase, not the loop around it.
@@ -90,7 +93,7 @@ def span(name: str, seconds: Optional[str] = None, leaf: bool = True,
     if sink is None and counted is None and not annotate:
         return _NULL
     return _Span(name, fields or None, sink, counted, seconds,
-                 ann(name) if annotate else None)
+                 ann(name, **fields) if annotate else None)
 
 
 class _Held:

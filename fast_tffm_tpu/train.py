@@ -43,7 +43,7 @@ from fast_tffm_tpu.obs.memory import (LEDGER, local_bytes_in_use,
 from fast_tffm_tpu.obs.telemetry import (active, make_telemetry,
                                          pop_active, push_active)
 from fast_tffm_tpu.obs.trace import begin, span
-from fast_tffm_tpu.utils.fetch import ChunkedFetcher, bulk_fetch
+from fast_tffm_tpu.utils.fetch import ChunkedFetcher, bulk_fetch, note_link
 from fast_tffm_tpu.utils.logging import get_logger
 from fast_tffm_tpu.utils.timing import StepTimer
 
@@ -1130,11 +1130,11 @@ def _build_state_and_step(s: _Session):
         # What only a sync point or an epoch barrier feeds starts
         # at 0: a reader that differences two snapshots of the
         # stream must find "none yet" as 0, not as absent.
-        # So does what only a re-laid state feeds (models/fm.py,
-        # TrainStep): an FM run's answer is 0, not silence.
+        # So does what only a re-laid state or a second batch shape feeds
+        # (models/fm.py, TrainStep): an FM run's answer is 0, not silence.
         for name in ("train/epochs", "train/epoch_barrier_seconds",
-                     "train/loss_sync_seconds",
-                     "train/state_relayouts"):
+                     "train/loss_sync_seconds", "train/state_relayouts",
+                     "train/step_programs", "train/program_switches"):
             tel.count(name, 0)
     return table, acc
 
@@ -1237,6 +1237,7 @@ def _probe_link(cfg: FmConfig, logger) -> str:
         float(probe)
         # fmlint: disable=R003 -- closes the probe sample
         cost = min(cost, time.perf_counter() - t0)
+    note_link(cost)  # the barrier's drain of buffered scalars asks it
     if cost < LIVE_FETCH_BUDGET_S:
         # Log the decision either way: a user wondering why loss
         # lines are (or aren't) live gets the probe's answer.
@@ -1355,7 +1356,7 @@ class StepLoop:
         self.sync_live_line()
         s = self.s
         with span("train/step", seconds="train/dispatch_seconds",
-                  step=step):
+                  step=step, width=wb.L):
             with oom_guard("train/step"):
                 if s.multi_process:
                     # The sharded step IS a collective program: on a

@@ -28,6 +28,29 @@ FETCH_CHUNK_BATCHES = 256
 # daemon thread — leaked, but the process stays live and honest.
 CLOSE_DRAIN_TIMEOUT_S = 10.0
 
+# What one scalar fetch costs on this process's device link, where
+# somebody timed it (train._probe_link, before the hot loop); None =
+# nobody has. Stacking a group of scalars into one transfer saves the
+# link's round trips and costs a compiled program per group size, made
+# ready where the group is first fetched: a job's FIRST epoch barrier,
+# inside its steady state (0.45 s on the v5e where 16 direct fetches
+# cost 0.05 ms; PERF.md section 6, PR 35). So a group of scalars is
+# stacked only where the round trips it saves are worth a program.
+STACK_WORTH_S = 0.05
+_scalar_fetch_s = None
+
+
+def note_link(seconds: float) -> None:
+    """What one scalar fetch costs on this process's device link."""
+    global _scalar_fetch_s
+    _scalar_fetch_s = float(seconds)
+
+
+def _stack_pays(shape, n: int) -> bool:
+    if shape != () or _scalar_fetch_s is None:
+        return True     # arrays, or an untimed link: as ever
+    return n * _scalar_fetch_s > STACK_WORTH_S
+
 
 def bulk_fetch(pairs, consume) -> None:
     """One-shot bulk device->host fetch: ``pairs`` of (value, meta) are
@@ -217,18 +240,19 @@ class ChunkedFetcher:
         # one list-flush, ~200 ms/array). So: group device arrays by
         # (shape, dtype) and fetch each multi-member group as ONE
         # stacked transfer (one compiled stack per (arity, shape),
-        # compile-cached); singletons and non-array values (python
-        # floats pass through device_get) ride a single final list
-        # fetch. This is the one implementation of the bulk-fetch
-        # workaround — train.flush_log and ScalarSummaries.flush route
-        # through it rather than hand-rolling variants.
+        # compile-cached); singletons, non-array values (python
+        # floats pass through device_get) and scalars on a link timed
+        # as fast (_stack_pays) ride a single final list fetch. This
+        # is the one implementation of the bulk-fetch workaround —
+        # train.flush_log and ScalarSummaries.flush route through it
+        # rather than hand-rolling variants.
         groups: dict = {}
         for i, a in enumerate(arrs):
             if isinstance(a, jax.Array):
                 groups.setdefault((a.shape, str(a.dtype)), []).append(i)
         fetched: dict = {}
-        for idxs in groups.values():
-            if len(idxs) > 1:
+        for (shape, _), idxs in groups.items():
+            if len(idxs) > 1 and _stack_pays(shape, len(idxs)):
                 import jax.numpy as jnp
                 try:
                     host = np.asarray(jax.device_get(

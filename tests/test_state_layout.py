@@ -369,3 +369,48 @@ def test_v5e_step_has_no_copy_of_the_whole_state(one_chip, cell):
     else:
         assert step._layout != default
         assert step._layout.major_to_minor == (0, 1)    # a row contiguous
+
+
+# The cell whose lines are of unequal length (ISSUE 35): FM k=8, 2^26
+# rows, and ONE job ships [8192, 96] and [8192, 112], so it holds two
+# programs, the second compiled with the first's choice pinned.
+BAGS = (ModelSpec("fm", 2, 8, 0, 1 << 26, "logistic", 0.0, 0.0, 0.01),
+        8192, 32768)
+
+
+@pytest.mark.parametrize("widths", [(96, 112), (112, 96)])
+def test_v5e_second_width_takes_the_firsts_layout(one_chip, widths):
+    """Whichever width a job sees first, the compiler chooses the same
+    layout for the state (so the pinned one is what it would choose at
+    the other width too: the runtime's own), and neither program copies
+    the whole state or breaks the aliasing of its two state arguments."""
+    from jax.experimental.compilation_cache import compilation_cache
+    spec, B, U = BAGS
+    ladder = FmConfig().bucket_ladder
+    assert all(w in ladder for w in widths)
+    rows, dim = spec.vocabulary_size + 1, spec.row_dim
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    state = sd((rows, dim), jnp.float32)
+    step = TrainStep(spec)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        texts = [step.compile(
+            state, state, sd((B,), jnp.float32), sd((B,), jnp.float32),
+            sd((U,), jnp.int32), sd((B, L), jnp.int32),
+            sd((B, L), jnp.float32), None).as_text() for L in widths]
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+    device = one_chip._device_assignment[0]
+    assert step._layout == Layout.from_pjrt_layout(
+        device.client.get_default_layout(jnp.dtype(jnp.float32),
+                                         (rows, dim), device))
+    assert step._relabel is None
+    for text in texts:
+        assert not re.findall(rf"= f32\[{rows},{dim}\]\S* copy\(", text)
+        alias = re.search(r"input_output_alias=\{(.*?)\}, \w+=",
+                          text).group(1)
+        assert "{0}: (0, {}" in alias and "{1}: (1, {}" in alias, alias
