@@ -132,8 +132,10 @@ class FmConfig:
     kernel: str = "auto"            # "auto" | "xla" | "pallas"
     # Where the per-batch unique-id pass runs. "host": the pipeline
     # dedups (the C++ builder, while it parses) and ships (uniq_ids[U],
-    # local_idx), U the power-of-two rung of the batch's distinct rows
-    # — required by mesh, multi-process, and offload paths. "device":
+    # local_idx), U the rung over the batch's distinct rows (a quarter
+    # octave apart on one device, doubling for a mesh train step:
+    # data/pipeline._uniq_ladder) — required by mesh, multi-process,
+    # and offload paths. "device":
     # the pipeline ships raw ids; a scorer gathers them directly, a
     # train step runs jnp.unique on the chip over U = B*L + 1 slots
     # (single-device jit only). "auto" on one device resolves by use:

@@ -8,8 +8,10 @@ batches XLA can compile once per bucket:
 - per-example feature counts are padded to a bucket ladder (``L``),
 - the batch's **unique** feature ids are computed on the host (the
   reference does ``tf.unique`` in-graph; SURVEY §3.1) and padded to their
-  own ladder (``U``), so the device gathers ``U`` table rows instead of
-  ``B*L`` and gradient scatter-adds are already deduplicated,
+  own ladder (``U``: quarter-octave rungs on one device, doubling ones
+  for a mesh train step; ``_uniq_ladder``), so the device gathers ``U``
+  table rows instead of ``B*L`` and gradient scatter-adds are already
+  deduplicated,
 - short final batches are padded with zero-weight dummy examples.
 
 Padding invariants (relied on by ops/ and tests):
@@ -271,23 +273,30 @@ def _ladder_fit(n: int, ladder: Sequence[int]) -> int:
     return b
 
 
-# The unique-row ladder's smallest rung. A mesh cuts every rung into
-# one segment per row shard (segment_plan), so it may have no more row
-# shards than this (parallel/sharded.make_mesh checks).
+# The unique-row ladder's smallest rung, and the least distance between
+# two rungs: every rung is a multiple of it. A mesh cuts a batch's
+# slots into one segment per row shard (segment_plan) or along its data
+# axis (a mesh scorer's feed), so it may have no more row shards than
+# this (parallel/sharded.make_mesh checks).
 UNIQ_LADDER_MIN = 64
 
 
-def _uniq_ladder(batch_size: int, max_l: int) -> List[int]:
-    """Power-of-two ladder for the unique-row bucket; the top rung is the
-    first power of two > B*L (so a padding slot exists even when every id
-    is distinct). All rungs stay powers of two because mesh-sharded runs
-    split the U axis across devices (parallel/sharded.py: one segment of
-    every rung per row shard) and explicit shardings need divisible
-    dims."""
+def _uniq_ladder(batch_size: int, max_l: int,
+                 doubling: bool = False) -> List[int]:
+    """The rungs of the unique-row bucket U: a quarter octave apart
+    (2^k x {1, 1.25, 1.5, 1.75}: 256, 320, 384, 448, 512, 640, ...) and
+    never closer than ``UNIQ_LADDER_MIN`` (64, 128, 192, 256), so from
+    256 slots up a batch is padded by less than a quarter of what it
+    needs. The step's gather and its three Adagrad passes pay for a
+    pad slot what they pay for a row (PERF.md section 5). ``doubling``
+    keeps the powers of two alone: the mesh's ladder (_fit_slots says
+    why). Either way the top rung is the first power of two > B*L, so
+    a padding slot exists even when every id is distinct."""
     cap = batch_size * max_l + 1
     out, b = [], UNIQ_LADDER_MIN
     while b < cap:
-        out.append(b)
+        out.extend(range(b, 2 * b, b if doubling
+                         else max(b // 4, UNIQ_LADDER_MIN)))
         b *= 2
     out.append(b)
     return out
@@ -368,14 +377,22 @@ def segment_slots(uniq: np.ndarray, idx: np.ndarray, shards: RowShards,
 
 
 def _fit_slots(need: int, B: int, L: int, fixed_shape: bool,
-               uniq_bucket: int) -> int:
+               uniq_bucket: int, mesh: bool = False) -> int:
     """U for a batch that needs ``need`` unique-row slots, its pad slot
     counted: the ladder's rung, or under ``fixed_shape`` the pinned
-    bucket (UniqOverflow where the batch does not fit it)."""
-    uladder = _uniq_ladder(B, L)
+    bucket (UniqOverflow where the batch does not fit it).
+
+    ``mesh`` (a mesh train step's feed: ``need`` is the row shards
+    times one more than the fullest shard's rows) keeps the doubling
+    rungs. On a mesh U follows the fullest shard, which moves from
+    batch to batch across a quarter-octave rung where one device's
+    total does not (Criteo at a global batch of 32,768 over four
+    shards: 12,232 to 12,603 slots around the rung at 12,288, 2 to 6%
+    of batches under it), and a mesh program is the dearer one to make
+    ready in the middle of an epoch (ROADMAP.md S4)."""
     if not fixed_shape:
-        return _ladder_fit(need, uladder)
-    U = uniq_bucket or uladder[-1]
+        return _ladder_fit(need, _uniq_ladder(B, L, doubling=mesh))
+    U = uniq_bucket or _uniq_ladder(B, L)[-1]
     if need > U:
         raise UniqOverflow(
             f"the batch needs {need} unique-row slots (on a mesh: the "
@@ -435,7 +452,8 @@ def make_device_batch(block: ParsedBlock, cfg: FmConfig,
             uniq, inverse = np.unique(block.ids, return_inverse=True)
         fit = functools.partial(_fit_slots, B=B, L=L,
                                 fixed_shape=fixed_shape,
-                                uniq_bucket=uniq_bucket)
+                                uniq_bucket=uniq_bucket,
+                                mesh=shards is not None)
         if shards is not None:
             uniq_ids, inverse = segment_slots(uniq, inverse, shards, fit)
         else:
@@ -937,7 +955,8 @@ class _BatchEmitter:
         fit = functools.partial(_fit_slots, B=self.B,
                                 L=self.cols(max_nnz),
                                 fixed_shape=self.fixed_shape,
-                                uniq_bucket=self.uniq_bucket)
+                                uniq_bucket=self.uniq_bucket,
+                                mesh=self.shards is not None)
         if self.shards is not None:
             return segment_plan(uniq, self.shards, fit)
         # The builder's uniq already CONTAINS the reserved pad slot

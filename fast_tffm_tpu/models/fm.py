@@ -50,12 +50,13 @@ class ModelSpec:
     learning_rate: float
     kernel: str = "xla"
     # "host": the pipeline dedups ids and ships (uniq_ids[U],
-    # local_idx), U fitted to the batch's distinct rows (power-of-two
-    # ladder). "device": the pipeline ships raw ids [B, L]; a scorer
-    # gathers them directly, a train step runs jnp.unique on device
-    # over U = B*L + 1 slots. Only the single-device jit paths support
-    # "device" (mesh/offload/multi-process need the host-side unique
-    # contract). Resolution of "auto": from_config.
+    # local_idx), U fitted to the batch's distinct rows
+    # (data/pipeline._uniq_ladder). "device": the pipeline ships raw
+    # ids [B, L]; a scorer gathers them directly, a train step runs
+    # jnp.unique on device over U = B*L + 1 slots. Only the
+    # single-device jit paths support "device" (mesh/offload/
+    # multi-process need the host-side unique contract). Resolution of
+    # "auto": from_config.
     dedup: str = "host"
 
     @classmethod

@@ -81,8 +81,9 @@ def make_mesh(devices: Optional[Sequence[jax.Device]] = None,
             "4096-aligned table rows (FmConfig.ckpt_rows) shard evenly")
     n_data = n // model_axis
     # A batch's unique rows ship as one segment per ROW shard (data x
-    # model; data/pipeline.segment_plan) of a power-of-two rung, so
-    # the ladder's smallest rung has to cut into that many segments.
+    # model; data/pipeline.segment_plan), and every rung of their
+    # ladder is a multiple of its smallest, so that one has to cut
+    # into that many segments.
     if n > UNIQ_LADDER_MIN:
         raise ValueError(
             f"{n} row shards (data axis {n_data} x model axis "

@@ -236,13 +236,19 @@ class VocabMap:
             new_uniq[base:base + n_hits] = phys[hit]
             if self.row_shards is not None:
                 # A mesh train step's feed: the physical rows ordered
-                # by owning row shard, U the rung the fullest shard
-                # fits (data/pipeline.segment_plan).
-                from fast_tffm_tpu.data.pipeline import (_ladder_fit,
+                # by owning row shard, U the mesh's (doubling) rung
+                # over the fullest shard's need and over the slots
+                # the hashed batch shipped at, which is one device's
+                # finer rung or the fixed bucket
+                # (data/pipeline.segment_plan, _fit_slots).
+                from fast_tffm_tpu.data.pipeline import (_fit_slots,
                                                          segment_slots)
+                B, L = batch.local_idx.shape
                 new_uniq, inv = segment_slots(
                     new_uniq, inv, self.row_shards,
-                    lambda need: _ladder_fit(need, [len(u)]))
+                    lambda need: _fit_slots(max(need, len(u)), B, L,
+                                            fixed_shape=False,
+                                            uniq_bucket=0, mesh=True))
                 batch.row_shards = self.row_shards.n
             batch.uniq_ids = new_uniq
             batch.local_idx = inv[batch.local_idx]

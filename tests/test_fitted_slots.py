@@ -1,9 +1,11 @@
 """The one-chip train step runs on slots fitted to the batch's distinct
 rows: ``dedup = auto`` resolves by use (training: host unique on the
-power-of-two ladder; scoring: raw ids), the pipeline counts the slots
-it ships, and the benchmark's two metric files read that count."""
+quarter-octave ladder, ISSUE 36; scoring: raw ids), the pipeline counts
+the slots it ships, and the benchmark's two metric files read that
+count."""
 
 import dataclasses
+import functools
 import json
 import os
 import re
@@ -16,12 +18,14 @@ import pytest
 
 from fast_tffm_tpu.config import FmConfig
 from fast_tffm_tpu.data import cparser
-from fast_tffm_tpu.data.pipeline import (_ladder_fit, _uniq_ladder,
-                                         batch_iterator)
+from fast_tffm_tpu.data import pipeline
+from fast_tffm_tpu.data.pipeline import (UNIQ_LADDER_MIN, RowShards,
+                                         _fit_slots, _ladder_fit,
+                                         _uniq_ladder, batch_iterator)
 from fast_tffm_tpu.models.fm import (ModelSpec, batch_args,
                                      init_accumulator, init_table,
                                      make_train_step, regime_line,
-                                     ships_raw_batches)
+                                     ships_raw_batches, train_step_body)
 from fast_tffm_tpu.obs.telemetry import RunTelemetry, activate
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -111,6 +115,12 @@ def test_every_program_says_what_its_spec_is_for():
 
 # ---- (b) the step's U, and the same three steps either way -------------
 
+def _pad_is_small(need, U):
+    """One device's rung over ``need`` slots: under a quarter of the
+    need is padding from 256 slots up, under one smallest rung below."""
+    return need <= U < need + max(UNIQ_LADDER_MIN, need / 4)
+
+
 def _three_steps(cfg, spec):
     table, acc = init_table(cfg, 0), init_accumulator(cfg)
     step = make_train_step(spec)
@@ -144,7 +154,7 @@ def test_one_chip_auto_step_runs_on_the_rung_of_distinct_rows(
         distinct = len(np.unique(r.local_idx[r.local_idx != cfg.pad_id]))
         U = f.uniq_ids.shape[0]
         assert U == _ladder_fit(distinct + 1, ladder)   # + the pad slot
-        assert U < B * L + 1 and U <= 2 * (distinct + 1)
+        assert U < B * L + 1 and _pad_is_small(distinct + 1, U)
         # the same rows, each once, pad row first (the builder's order)
         real = f.uniq_ids[f.uniq_ids != cfg.pad_id]
         assert len(real) == distinct == len(np.unique(real))
@@ -161,18 +171,22 @@ def test_one_chip_auto_step_runs_on_the_rung_of_distinct_rows(
 
 # ---- (c) the counter ---------------------------------------------------
 
+@pytest.mark.parametrize("row_shards", [1, 4])
 @pytest.mark.parametrize("host_threads", [1, pytest.param(
     4, marks=pytest.mark.skipif(not cparser.available(),
                                 reason="C++ parser extension unavailable"))])
-def test_uniq_slots_counts_the_slots_shipped(tmp_path, host_threads):
+def test_uniq_slots_counts_the_slots_shipped(tmp_path, host_threads,
+                                             row_shards):
     from fast_tffm_tpu.obs.attribution import attribution
     cfg = _cfg(_zipf_corpus(tmp_path, ffm=False, n=5 * B),
                host_threads=host_threads)
+    shards = RowShards.of(cfg, row_shards)
     tel = RunTelemetry(str(tmp_path / "m.jsonl"), meta={"kind": "t"})
     try:
         with activate(tel):
             batches = list(batch_iterator(cfg, cfg.train_files,
-                                          training=True))
+                                          training=True,
+                                          row_shards=shards))
             snap = tel.registry.snapshot()["counters"]
             raw = list(batch_iterator(cfg, cfg.train_files, training=True,
                                       raw_ids=True))
@@ -184,7 +198,17 @@ def test_uniq_slots_counts_the_slots_shipped(tmp_path, host_threads):
     rows = sum(int((b.uniq_ids != cfg.pad_id).sum()) for b in batches)
     assert snap["pipeline/uniq_slots"] == slots
     assert snap["pipeline/uniq_rows"] == rows
-    assert 0.5 <= rows / slots <= 1.0    # a power-of-two ladder: half or more
+    for b in batches:
+        real = (b.uniq_ids != cfg.pad_id).reshape(row_shards, -1)
+        U, fullest = real.size, int(real.sum(axis=1).max())
+        if shards is None:
+            # one device: a quarter-octave rung over rows + pad slot
+            assert _pad_is_small(fullest + 1, U)
+        else:
+            # a mesh: the doubling rung over the fullest shard's need
+            need = row_shards * (fullest + 1)
+            assert U & (U - 1) == 0
+            assert 0.5 < need / U <= 1.0 or U == UNIQ_LADDER_MIN
     att = attribution({"counters": snap, "gauges": {}, "hists": {}})
     assert att["uniq_slot_fill"] == pytest.approx(rows / slots)
     # raw ids ship no unique table: the sweep adds batches and no slots
@@ -349,3 +373,118 @@ def test_benchmark_json_lists_every_metric_with_its_file_and_reader():
             assert own[k] == m[k], (m["name"], k)
         assert os.path.exists(os.path.join(
             REPO, "benchmarks", "readers", own["reader"] + ".py"))
+
+
+# ---- (e) ISSUE 36: the ladder, and the same step at either U -----------
+
+SHAPES = [(4, 4), (64, 16), (8192, 24), (8192, 40), (8192, 112),
+          (32768, 40)]
+
+
+@pytest.mark.parametrize("rule", [
+    "strictly rising", "starts at UNIQ_LADDER_MIN",
+    "every rung a multiple of UNIQ_LADDER_MIN",
+    "multiples of 128 from 512 up",
+    "ends on the first power of two over B*L",
+    "holds every doubling rung",
+    "under a quarter of a need is padding from 256 slots up",
+    "a mesh feed keeps the doubling rungs",
+    "a fixed bucket is kept, or the top rung"])
+@pytest.mark.parametrize("batch,width", SHAPES)
+def test_uniq_ladder(batch, width, rule):
+    lad = _uniq_ladder(batch, width)
+    doubling = _uniq_ladder(batch, width, doubling=True)
+    top = max(UNIQ_LADDER_MIN,                     # first 2^k > B*L
+              1 << (batch * width).bit_length())
+    one = functools.partial(_fit_slots, B=batch, L=width,
+                            fixed_shape=False, uniq_bucket=0)
+    needs = sorted({n for r in lad for n in (r - 1, r, r + 1)
+                    if 1 <= n <= batch * width + 1})
+    assert {
+        "strictly rising": all(a < b for a, b in zip(lad, lad[1:])),
+        "starts at UNIQ_LADDER_MIN": lad[0] == UNIQ_LADDER_MIN == 64,
+        "every rung a multiple of UNIQ_LADDER_MIN": all(
+            r % UNIQ_LADDER_MIN == 0 for r in lad),
+        "multiples of 128 from 512 up": all(
+            r % 128 == 0 for r in lad if r >= 512),
+        "ends on the first power of two over B*L":
+            lad[-1] == doubling[-1] == top,
+        "holds every doubling rung": set(doubling) <= set(lad) and all(
+            r & (r - 1) == 0 and b == 2 * r
+            for r, b in zip(doubling, doubling[1:])),
+        "under a quarter of a need is padding from 256 slots up": all(
+            _pad_is_small(n, one(n)) and one(n) in lad for n in needs),
+        "a mesh feed keeps the doubling rungs": all(
+            one(n, mesh=True) == _ladder_fit(n, doubling)
+            == max(UNIQ_LADDER_MIN, 1 << (n - 1).bit_length())
+            for n in needs),
+        "a fixed bucket is kept, or the top rung": all(
+            _fit_slots(n, batch, width, True, bucket, mesh=mesh)
+            == (bucket or top)
+            for n in needs[:4] for bucket in (0, 256)
+            for mesh in (False, True) if n <= (bucket or top)),
+    }[rule]
+
+
+@pytest.mark.parametrize("need,rung,doubling", [
+    (19_261, 20_480, 32_768),      # fm16-train-zipf's mean batch
+    (19_523, 20_480, 32_768),      # and its fullest (ISSUE 36's table)
+    (8_961, 10_240, 16_384),       # ffm4-train-zipf
+    (25_591, 28_672, 32_768),      # fm8-train-bags' fullest
+    (4 * 12_401, 57_344, 65_536),  # fm16x4: four shards x the fullest's need
+    (20_480, 20_480, 32_768), (20_481, 24_576, 32_768),
+    (1, 64, 64), (65, 128, 128), (129, 192, 256), (257, 320, 512)])
+def test_the_cells_ride_the_rungs_issue_36_sized(need, rung, doubling):
+    fit = functools.partial(_fit_slots, need, 32_768, 40, False, 0)
+    assert (fit(), fit(mesh=True)) == (rung, doubling)
+
+
+@pytest.mark.parametrize("model", ["fm", "ffm"])
+def test_the_step_at_the_fitted_rung_equals_the_step_at_the_power_of_two(
+        tmp_path, model):
+    """Pad slots are no-ops: the same batch through ``train_step_body``
+    at its quarter-octave U and padded out to the next power of two
+    gives the same loss and scores and, row for row, the same table
+    and accumulator."""
+    ffm = model == "ffm"
+    cfg = _cfg(_zipf_corpus(tmp_path, ffm, n=40),
+               **(dict(model_type="ffm", field_num=4) if ffm else {}))
+    spec = ModelSpec.from_config(cfg, training=True)
+    batch, = batch_iterator(cfg, cfg.train_files, training=True)
+    U, wide = len(batch.uniq_ids), 256
+    assert U == 192           # 150 to 170 rows: between the powers of two
+    args = batch_args(batch)
+    padded = dict(args, uniq_ids=np.concatenate(
+        [batch.uniq_ids, np.full(wide - U, cfg.pad_id, np.int32)]))
+    step = jax.jit(functools.partial(train_step_body, spec))
+    table, acc = init_table(cfg, 3), init_accumulator(cfg)
+    fitted = step(table, acc, **args)
+    doubled = step(table, acc, **padded)
+    for a, b in zip(fitted, doubled):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    moved = np.flatnonzero((np.asarray(fitted[0]) != np.asarray(table))
+                           .any(axis=1))
+    real = batch.uniq_ids[batch.uniq_ids != cfg.pad_id]
+    assert set(moved) <= set(real) and len(moved) > 0.9 * len(real)
+
+
+@pytest.mark.skipif(not cparser.available(),
+                    reason="C++ parser extension unavailable")
+@pytest.mark.parametrize("model", ["fm", "ffm"])
+def test_the_python_and_the_cpp_builder_ship_the_same_slots(
+        tmp_path, monkeypatch, model):
+    ffm = model == "ffm"
+    cfg = _cfg(_zipf_corpus(tmp_path, ffm, n=B + 40),
+               **(dict(model_type="ffm", field_num=4) if ffm else {}))
+    fast = list(batch_iterator(cfg, cfg.train_files, training=True))
+
+    def no_builder(*a, **k):
+        raise RuntimeError("forced generic path")
+    monkeypatch.setattr(pipeline, "_make_builder", no_builder)
+    generic = list(batch_iterator(cfg, cfg.train_files, training=True))
+    # a full batch on a doubling rung, a short one between two
+    assert [len(a.uniq_ids) for a in fast] == [256, 192]
+    for a, b in zip(fast, generic):
+        assert len(a.uniq_ids) == len(b.uniq_ids)
+        np.testing.assert_array_equal(a.uniq_ids[a.local_idx],
+                                      b.uniq_ids[b.local_idx])

@@ -109,9 +109,9 @@ def test_every_slot_of_a_segment_names_a_row_its_shard_holds(
 def test_a_batch_whose_fullest_shard_overflows_takes_the_next_rung(
         tmp_path, shape, model):
     """All the batch's rows lie in the first shard's block: the rows
-    would fit U = 256 in one list, the first shard's would not fit its
-    segment of it, so the batch ships at the rung where they do, and
-    nothing is dropped."""
+    fit 192 slots in one list (one device's rung), the first shard's
+    would not fit its segment of the mesh's 256, so the batch ships at
+    the doubling rung where they do, and nothing is dropped."""
     path = tmp_path / "train.txt"
     cfg = _cfg(path, model)
     _write(path, cfg, np.random.default_rng(34), 1, 2000)
@@ -120,7 +120,7 @@ def test_a_batch_whose_fullest_shard_overflows_takes_the_next_rung(
     cut = next(iter(batch_iterator(cfg, cfg.train_files, training=True,
                                    row_shards=shards)))
     rows = int((plain.uniq_ids != cfg.pad_id).sum())
-    assert len(plain.uniq_ids) == 256 and 128 <= rows < 256
+    assert len(plain.uniq_ids) == 192 and 128 <= rows < 192
     assert len(cut.uniq_ids) == 256 * shards.n
     seg = cut.uniq_ids.reshape(shards.n, -1) != cfg.pad_id
     assert seg.sum(axis=1).tolist() == [rows] + [0] * (shards.n - 1)

@@ -24,7 +24,8 @@ from jax.sharding import SingleDeviceSharding
 
 from fast_tffm_tpu.checkpoint import CheckpointState
 from fast_tffm_tpu.config import FmConfig
-from fast_tffm_tpu.data.pipeline import _ladder_fit, batch_iterator
+from fast_tffm_tpu.data.pipeline import (_fit_slots, _ladder_fit,
+                                         batch_iterator)
 from fast_tffm_tpu.models import fm
 from fast_tffm_tpu.models.fm import (ModelSpec, TrainStep, batch_args,
                                      init_accumulator, init_table,
@@ -317,13 +318,16 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-# The two one-chip cells of BENCHMARK.json: rows, batch, rung, slots
-# (22 fields ride the 24 rung and 39 features the 40 rung since PR 34).
+# The two one-chip cells of BENCHMARK.json: rows, batch, rung, and the
+# slots a batch needs (22 fields ride the 24 rung and 39 features the
+# 40 rung since PR 34; since PR 36 their 9.0k and 19.3k distinct rows
+# ride the quarter-octave rungs 10,240 and 20,480, not 16,384 and
+# 32,768).
 CELLS = {
     "ffm4-train-zipf": (ModelSpec("ffm", 2, 4, 22, 1 << 23, "logistic",
-                                  0.0, 0.0, 0.01), 8192, 24, 16384),
+                                  0.0, 0.0, 0.01), 8192, 24, 8960, 10240),
     "fm16-train-zipf": (ModelSpec("fm", 2, 16, 0, 1 << 26, "logistic",
-                                  0.0, 0.0, 0.01), 8192, 40, 32768),
+                                  0.0, 0.0, 0.01), 8192, 40, 19260, 20480),
 }
 
 
@@ -336,8 +340,9 @@ def test_v5e_step_has_no_copy_of_the_whole_state(one_chip, cell):
     to re-lay), for FFM's 89 another one. A compile says nothing of
     times."""
     from jax.experimental.compilation_cache import compilation_cache
-    spec, B, L, U = CELLS[cell]
+    spec, B, L, need, U = CELLS[cell]
     assert L == _ladder_fit(spec.field_num or 39, FmConfig().bucket_ladder)
+    assert U == _fit_slots(need, B, L, fixed_shape=False, uniq_bucket=0)
     rows, dim = spec.vocabulary_size + 1, spec.row_dim
 
     def sd(shape, dtype):
@@ -375,7 +380,7 @@ def test_v5e_step_has_no_copy_of_the_whole_state(one_chip, cell):
 # rows, and ONE job ships [8192, 96] and [8192, 112], so it holds two
 # programs, the second compiled with the first's choice pinned.
 BAGS = (ModelSpec("fm", 2, 8, 0, 1 << 26, "logistic", 0.0, 0.0, 0.01),
-        8192, 32768)
+        8192, 28672)      # 25.3k distinct rows: the rung under 32,768
 
 
 @pytest.mark.parametrize("widths", [(96, 112), (112, 96)])

@@ -171,6 +171,32 @@ def test_remap_host_dedup_invariants():
     assert sorted(batch.vocab_obs.tolist()) == [100, 200, 300, 400]
 
 
+@pytest.mark.parametrize("slots,want", [(192, 256), (320, 512), (512, 512)])
+def test_remap_for_a_mesh_keeps_the_doubling_rung(slots, want):
+    """Under admit a mesh train step's batch is built in the hashed
+    space with no row shards in sight, so at one device's
+    quarter-octave rung; the remap then orders the physical rows by
+    owning shard at the MESH's doubling rung over it (ISSUE 36)."""
+    from fast_tffm_tpu.data.pipeline import RowShards
+    rt = _runtime(capacity=8)
+    _admit(rt, [100, 200])
+    rt.row_shards = RowShards(4, 4, rt.pad_id)
+    orig_uniq = np.full(slots, HASH_SPACE, np.int64)
+    orig_uniq[:4] = [100, 300, 200, 400]
+    local_idx = np.array([[0, 1, 4], [2, 3, slots - 1]], np.int32)
+    batch = SimpleNamespace(uniq_ids=orig_uniq.copy(),
+                            local_idx=local_idx.copy())
+    rt.remap(batch)
+    assert len(batch.uniq_ids) == want and batch.row_shards == 4
+    assert batch.uniq_ids[-1] == rt.pad_id
+    segs = batch.uniq_ids.reshape(4, -1)
+    for s, seg in enumerate(segs):
+        real = seg[seg != rt.pad_id]
+        assert (real // 4 == s).all()
+    assert (batch.uniq_ids[batch.local_idx]
+            == rt.lookup(orig_uniq[local_idx])).all()
+
+
 def test_remap_raw_ids_batch():
     """dedup=device batches (uniq_ids None) remap cellwise."""
     rt = _runtime(capacity=8)
