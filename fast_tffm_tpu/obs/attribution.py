@@ -16,6 +16,7 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 
 from fast_tffm_tpu.obs.registry import Histogram, MetricsRegistry
 from fast_tffm_tpu.obs.sink import read_events
+from fast_tffm_tpu.obs.telemetry import ANATOMY_PHASES
 
 # Verdict thresholds over the train-loop time split. Above HOST_BOUND
 # of loop wall spent waiting on the input pipeline, the host is the
@@ -42,6 +43,7 @@ def summarize(paths: Sequence[str]) -> Dict[str, Any]:
     metas: List[Dict[str, Any]] = []
     health_events: List[Dict[str, Any]] = []
     crash_events: List[Dict[str, Any]] = []
+    slow_steps: List[Dict[str, Any]] = []
     n_events = 0
     n_spans = 0
     run_starts = 0
@@ -54,6 +56,7 @@ def summarize(paths: Sequence[str]) -> Dict[str, Any]:
         # is what this file contributes.
         f_health: List[Dict[str, Any]] = []
         f_crash: List[Dict[str, Any]] = []
+        f_slow: List[Dict[str, Any]] = []
         f_started = 0
         f_ended = 0
         for rec in read_events(path):
@@ -67,7 +70,7 @@ def summarize(paths: Sequence[str]) -> Dict[str, Any]:
                 scalars.append(rec)
             elif ev == "run_start":
                 metas.append(rec.get("meta") or {})
-                f_health, f_crash = [], []
+                f_health, f_crash, f_slow = [], [], []
                 f_started, f_ended = 1, 0
             elif ev == "run_end":
                 f_ended = 1
@@ -75,10 +78,13 @@ def summarize(paths: Sequence[str]) -> Dict[str, Any]:
                 f_health.append(rec)
             elif ev == "crash":
                 f_crash.append(rec)
+            elif ev == "slow_step":
+                f_slow.append(rec)
             elif ev == "span":
                 n_spans += 1
         health_events.extend(f_health)
         crash_events.extend(f_crash)
+        slow_steps.extend(f_slow)
         run_starts += f_started
         run_ends += f_ended
 
@@ -107,6 +113,7 @@ def summarize(paths: Sequence[str]) -> Dict[str, Any]:
         "run_ends": run_ends,
         "health_events": health_events,
         "crash_events": crash_events,
+        "slow_steps": slow_steps,
         "counters": snap["counters"],
         "hists": snap["hists"],
         "gauges": flat_gauges,
@@ -146,6 +153,11 @@ def attribution(summary: Dict[str, Any]) -> Dict[str, Any]:
 
     step = h.get("train/step_seconds") or {}
     loop_s = step.get("sum") or 0.0
+    # The loop thread's wall on one anchor (train/loop_seconds): the
+    # steps, the pauses and every epoch barrier's flush and cold
+    # pipeline, which the step histogram's per-epoch anchor leaves
+    # out. A stream from before the counter adds the pauses it knows.
+    loop_wall = c.get("train/loop_seconds") or 0.0
     steps = c.get("train/steps") or step.get("count") or 0
     examples = c.get("train/examples", 0)
     input_wait = c.get("train/input_wait_seconds", 0.0)
@@ -163,7 +175,10 @@ def attribution(summary: Dict[str, Any]) -> Dict[str, Any]:
         "examples": examples,
         "steps": steps,
         "loop_seconds": loop_s,
-        "examples_per_sec": _frac(examples, loop_s + pauses),
+        "loop_wall_seconds": loop_wall or None,
+        "loop_unnamed_seconds": (c.get("train/loop_unnamed_seconds")
+                                 if loop_wall else None),
+        "examples_per_sec": _frac(examples, loop_wall or loop_s + pauses),
         "loop_examples_per_sec": _frac(examples, loop_s),
         "step_p50_s": step.get("p50"),
         "step_p99_s": step.get("p99"),
@@ -826,45 +841,43 @@ def worker_table(summary: Dict[str, Any]) -> List[str]:
 
 # The EFFICIENCY section's gauge surface (README "Step anatomy"): the
 # per-process anatomy/* gauges telemetry.anatomy_gauges pre-aggregates
-# at barrier flushes — phase seconds split into local work vs
-# cross-rank coordination waits. The verdict here works from the JSONL
-# alone; the straggler-wait vs transport split needs the trace replay
+# at every flush, read off the one list of the loop's phases
+# (telemetry.ANATOMY_PHASES) and split into local work vs cross-rank
+# coordination waits. The verdict here works from the JSONL alone; the
+# straggler-wait vs transport split needs the trace replay
 # (fmtrace --anatomy).
-ANATOMY_LOCAL_PHASES = (
-    ("input wait", "anatomy/input_wait_seconds"),
-    ("host build", "anatomy/host_build_seconds"),
-    ("h2d", "anatomy/h2d_seconds"),
-    ("dispatch", "anatomy/dispatch_seconds"),
-    ("window fill", "anatomy/window_fill_seconds"),
-    ("d2h fetch", "anatomy/fetch_seconds"),
-)
-ANATOMY_WAIT_PHASES = (
-    ("flags wait", "anatomy/flags_wait_seconds"),
-    ("lockstep allgather", "anatomy/allgather_seconds"),
-)
+ANATOMY_LOCAL_PHASES = tuple((p.label, g) for g, p in ANATOMY_PHASES.items()
+                             if not p.wait)
+ANATOMY_WAIT_PHASES = tuple((p.label, g) for g, p in ANATOMY_PHASES.items()
+                            if p.wait)
+UNNAMED = "unnamed"  # the loop's wall under no phase
 
 
 def efficiency_table(summary: Dict[str, Any]) -> Optional[Dict[str, Any]]:
-    """Per-worker efficiency rows from the pre-aggregated anatomy/*
-    gauges: efficiency = the fraction of step wall NOT parked in
-    cross-rank coordination waits (flags allgather + lockstep
-    allgather). None when no process published coordination waits
-    (single-process runs, anatomy off, or pre-anatomy streams) — the
-    section only exists where there is a cluster to explain. The
-    straggler is the rank that waits LEAST: everyone else's wait is
-    time spent waiting for it."""
+    """Per-worker phase rows from the pre-aggregated anatomy/* gauges,
+    over the loop thread's wall (``anatomy/loop_seconds``; the step
+    histogram's sum in a stream from before it). efficiency = the
+    fraction of that wall NOT parked in cross-rank coordination waits
+    (flags allgather + lockstep allgather); a one-process run has none
+    and gets the same table, with its largest phase and the wall no
+    phase names. None when no process published a phase (anatomy off,
+    or pre-anatomy streams). The straggler is the rank that waits
+    LEAST: everyone else's wait is time spent waiting for it."""
     ranks: Dict[Any, Dict[str, Any]] = {}
     for proc in sorted(summary.get("gauges_by_process") or {}):
         g = summary["gauges_by_process"][proc]
-        wall = g.get("anatomy/step_wall_seconds")
+        wall = (g.get("anatomy/loop_seconds")
+                or g.get("anatomy/step_wall_seconds"))
         if not wall:
             continue
         wait = sum(g.get(key) or 0.0 for _, key in ANATOMY_WAIT_PHASES)
-        if wait <= 0:
-            continue
         phases = {label: g.get(key) or 0.0
                   for label, key in (ANATOMY_LOCAL_PHASES
                                      + ANATOMY_WAIT_PHASES)}
+        if not any(phases.values()):
+            continue
+        if g.get("anatomy/loop_seconds"):
+            phases[UNNAMED] = g.get("anatomy/unnamed_seconds") or 0.0
         ex = g.get("anatomy/examples") or 0.0
         ranks[proc] = {
             "wall_seconds": wall,
@@ -880,14 +893,23 @@ def efficiency_table(summary: Dict[str, Any]) -> Optional[Dict[str, Any]]:
     wall_tot = sum(r["wall_seconds"] for r in ranks.values())
     wait_tot = sum(r["wait_seconds"] for r in ranks.values())
     wait_frac = wait_tot / wall_tot if wall_tot else 0.0
+    waits = dict(ANATOMY_WAIT_PHASES)
     local = {label: v
              for label, v in ranks[straggler]["phases"].items()
-             if label not in dict(ANATOMY_WAIT_PHASES)}
+             if label not in waits and label != UNNAMED}
     dom = max(local, key=local.get) if any(local.values()) else None
-    verdict = (f"collective wait {wait_frac:.0%} of step"
-               + (f"; rank {straggler} is the straggler"
-                  f" (its dominant local phase: {dom})"
-                  if len(ranks) > 1 and dom else ""))
+    if wait_tot > 0:
+        verdict = (f"collective wait {wait_frac:.0%} of step"
+                   + (f"; rank {straggler} is the straggler"
+                      f" (its dominant local phase: {dom})"
+                      if len(ranks) > 1 and dom else ""))
+    else:
+        r = ranks[straggler]
+        verdict = (f"no cross-rank wait; largest phase: {dom} "
+                   f"{local[dom] / r['wall_seconds']:.0%} of the loop's "
+                   "wall" + (f", {UNNAMED} "
+                             f"{r['phases'][UNNAMED] / r['wall_seconds']:.1%}"
+                             if UNNAMED in r["phases"] else ""))
     return {
         "ranks": ranks,
         "straggler_rank": straggler if len(ranks) > 1 else None,
@@ -953,6 +975,14 @@ def render(summary: Dict[str, Any]) -> str:
                  f"{summary.get('spans', 0)} spans")
     hv = health_verdict(summary)
     lines.append(f"health: {hv['verdict']} — {hv['detail']}")
+    for ev in summary.get("slow_steps") or []:
+        # A step or barrier whose wall reached train.SLOW_STEP_SECONDS,
+        # with the phases its time went to (largest first).
+        top = ", ".join(f"{k} {_fmt(v)} s" for k, v in
+                        list((ev.get("phases") or {}).items())[:3])
+        lines.append(f"  slow {ev.get('what', 'step')} at step "
+                     f"{ev.get('step')}: {_fmt(ev.get('wall'))} s "
+                     f"[{top}]")
     lines.append("")
     rows = [
         ("examples", att["examples"]),
@@ -1158,6 +1188,14 @@ def render(summary: Dict[str, Any]) -> str:
                 f"    p{proc}: efficiency {r['efficiency']:.2f}  "
                 f"wall {r['wall_seconds']:.1f}s  "
                 f"rate {_fmt(r['examples_per_sec'])}/s  [{phases}]")
+        if len(eff["ranks"]) == 1:
+            # One process: the loop's wall, phase by phase.
+            (r,) = eff["ranks"].values()
+            for label, v in sorted(r["phases"].items(),
+                                   key=lambda kv: -kv[1]):
+                if v:
+                    lines.append(f"      {label:<30} {_fmt(v)} s  "
+                                 f"{v / r['wall_seconds']:.1%}")
         lines.append(f"    {eff['verdict']}")
     worker_rows = worker_table(summary)
     if worker_rows:

@@ -23,7 +23,7 @@ import contextlib
 import hashlib
 import json
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 from fast_tffm_tpu.obs.registry import MetricsRegistry
 from fast_tffm_tpu.obs.sink import JsonlSink
@@ -154,6 +154,12 @@ class RunTelemetry:
         self.sink = JsonlSink(path, meta=meta)
         self.flush_steps = max(0, int(flush_steps))
         self._closed = False
+        # The loop thread's wall (loop_start .. loop_stop): where the
+        # clock stood when train/loop_seconds was last brought up to
+        # date, and the partition's counters as the last flush wrote
+        # them (what a slow_step event differences against).
+        self._loop_t: Optional[float] = None
+        self._loop_flushed: Dict[str, float] = {}
         # The step of the latest heartbeat: what a ``compile`` event is
         # stamped with, so a program compiled mid-run says when.
         self.step = -1
@@ -276,12 +282,76 @@ class RunTelemetry:
         from fast_tffm_tpu.obs.trace import span
         self.heartbeat(step)  # a barrier IS progress — don't let a long
         # epoch-end fetch read as a stall
-        with span("obs/barrier_flush", step=step):
+        # A leaf of the loop's partition, so counted while the loop's
+        # clock runs (a train run's epoch barriers) and not in a
+        # teardown, a predict sweep or a fleet poll.
+        with span("obs/barrier_flush", step=step,
+                  seconds=("obs/barrier_flush_seconds"
+                           if self._loop_t is not None else None)):
             self._emit_metrics(step)
             self.sink.barrier()
 
-    def _emit_metrics(self, step: int) -> None:
+    # -- the loop thread's wall ------------------------------------------
+    def loop_start(self) -> None:
+        """Start the clock of ``train/loop_seconds``: the wall of the
+        thread that drives ``StepLoop.step``, on one anchor that no
+        epoch re-sets. The leaf phases (``LOOP_LEAVES``) and the
+        residue every flush derives partition it."""
+        self._loop_t = time.perf_counter()
+        for name in ("train/loop_seconds", "train/slow_steps",
+                     *LOOP_LEAVES):
+            self.registry.count(name, 0)
+        self._loop_flushed = {}
+
+    def loop_stop(self) -> None:
+        """Stop it: what the thread does after its last step (final
+        save, export) is no part of the loop's wall."""
+        if self._loop_t is not None:
+            self._loop_tick(time.perf_counter())
+            self._loop_t = None
+
+    def _loop_tick(self, now: float) -> None:
+        self.registry.count("train/loop_seconds", now - self._loop_t)
+        self._loop_t = now
+
+    def slow_step(self, step: int, wall: float, what: str = "step",
+                  **fields) -> None:
+        """One ``slow_step`` event: a step (or an epoch barrier) whose
+        wall reached ``train.SLOW_STEP_SECONDS`` says which phases the
+        time went to, as the growth of every leaf's counter and of the
+        residue since the last flush's snapshot (at most
+        ``flush_steps`` steady steps beside the stall). Host values
+        only."""
+        self.count("train/slow_steps")
+        if self._loop_t is not None:
+            self._loop_tick(time.perf_counter())
+        now = loop_partition(self.registry.snapshot()["counters"])
+        grew = {k: v - self._loop_flushed.get(k, 0.0)
+                for k, v in now.items()}
+        self.sink.emit("slow_step", {
+            "step": int(step), "what": what, "wall": wall,
+            "phases": {k: v for k, v in sorted(
+                grew.items(), key=lambda kv: -kv[1]) if v > 0},
+            **fields})
+
+    def _snapshot(self) -> Dict[str, Any]:
+        """The registry as a ``metrics`` event carries it. A run with
+        a loop clock gets the loop's wall brought up to this instant
+        (so that it holds every leaf counted so far) and the residue
+        ``train/loop_unnamed_seconds`` derived beside it: like the
+        anatomy gauges at no per-step cost, but under ``counters``,
+        where a reader differences it like one."""
+        if self._loop_t is not None:
+            self._loop_tick(time.perf_counter())
         snap = self.registry.snapshot()
+        if "train/loop_seconds" in snap["counters"]:
+            self._loop_flushed = loop_partition(snap["counters"])
+            snap["counters"][LOOP_UNNAMED] = self._loop_flushed[
+                LOOP_UNNAMED]
+        return snap
+
+    def _emit_metrics(self, step: int) -> None:
+        snap = self._snapshot()
         lease = self.lease
         if lease is not None:
             # Per-worker liveness row (fmstat worker table): this
@@ -341,7 +411,7 @@ class RunTelemetry:
         if step >= 0:
             self._emit_metrics(step)
         else:
-            self.sink.emit_metrics(-1, self.registry.snapshot())
+            self.sink.emit_metrics(-1, self._snapshot())
         self.sink.close()
 
     # -- shared instrumentation helpers ---------------------------------
@@ -417,33 +487,118 @@ class RunTelemetry:
                    else h2d_bytes_logical)
 
 
-# The step-anatomy phase map (README "Step anatomy"): cumulative
-# host-side seconds counters -> per-process anatomy/* gauges. Counters
-# fold across processes at merge time; the SAME numbers re-emitted as
-# gauges stay per-process (gauges_by_process), which is what the fmstat
-# EFFICIENCY section and bench --multihost need to rank stragglers.
-# Everything here is a float already sitting in the snapshot dict —
-# deriving the gauges can never add a device fetch.
-ANATOMY_PHASES = {
-    "anatomy/input_wait_seconds": "train/input_wait_seconds",
-    "anatomy/host_build_seconds": "pipeline/build_seconds",
-    "anatomy/h2d_seconds": "train/h2d_seconds",
-    "anatomy/flags_wait_seconds": "train/step_flags_seconds",
-    "anatomy/dispatch_seconds": "train/dispatch_seconds",
-    "anatomy/window_fill_seconds": "lockstep/window_fill_seconds",
-    "anatomy/allgather_seconds": "lockstep/allgather_seconds",
-    "anatomy/fetch_seconds": "lockstep/fetch_seconds",
+class Phase(NamedTuple):
+    """One phase of the step anatomy: the ``*_seconds`` counter its
+    spans count into, the spans' names as a trace and the JSONL show
+    them, and fmstat's word for it. ``leaf``: one of the phases that
+    partition the wall of the thread driving ``StepLoop.step`` (no
+    other thread counts into it, and no two of them are ever open at
+    once). ``wait``: a cross-rank coordination wait."""
+    counter: str
+    spans: Tuple[str, ...]
+    label: str
+    leaf: bool = True
+    wait: bool = False
+
+
+# The step-anatomy phase map (README "Step anatomy"), and the ONE list
+# of the host loop's phases: cumulative host-side seconds counters ->
+# per-process anatomy/* gauges. Counters fold across processes at merge
+# time; the SAME numbers re-emitted as gauges stay per-process
+# (gauges_by_process), which is what the fmstat EFFICIENCY section and
+# bench --multihost need to rank stragglers. Everything here is a float
+# already sitting in the snapshot dict — deriving the gauges can never
+# add a device fetch. fmstat's table (obs/attribution.py), the residue
+# (LOOP_LEAVES) and the README's list of phases
+# (tests/test_loop_phases.py) are read off this map.
+ANATOMY_PHASES: Dict[str, Phase] = {
+    # an epoch's first next() is pipeline/first_batch (the cold
+    # plane's), by its dur also in pipeline/first_batch_seconds
+    "anatomy/input_wait_seconds": Phase(
+        "train/input_wait_seconds",
+        ("train/input_wait", "pipeline/first_batch"), "input wait"),
+    "anatomy/batch_checks_seconds": Phase(
+        "train/batch_checks_seconds", ("train/batch_checks",),
+        "batch checks"),
+    "anatomy/encode_seconds": Phase(
+        "train/encode_seconds", ("train/encode",), "encode"),
+    "anatomy/h2d_seconds": Phase(
+        "train/h2d_seconds", ("train/h2d",), "h2d"),
+    "anatomy/flags_wait_seconds": Phase(
+        "train/step_flags_seconds",
+        ("train/step_flags", "stream/step_flags"), "flags wait",
+        wait=True),
+    "anatomy/dispatch_seconds": Phase(
+        "train/dispatch_seconds", ("train/step",), "dispatch"),
+    "anatomy/bookkeeping_seconds": Phase(
+        "train/bookkeeping_seconds", ("train/bookkeeping",),
+        "bookkeeping"),
+    "anatomy/loss_sync_seconds": Phase(
+        "train/loss_sync_seconds", ("train/loss_sync",), "loss sync"),
+    "anatomy/log_line_seconds": Phase(
+        "train/log_line_seconds", ("train/log_line",), "log line"),
+    "anatomy/flush_seconds": Phase(
+        "obs/flush_seconds", ("obs/flush",), "metrics flush"),
+    "anatomy/checkpoint_pause_seconds": Phase(
+        "train/checkpoint_pause_seconds",
+        ("train/checkpoint_pause", "checkpoint/publish"),
+        "checkpoint pause"),
+    # the epoch barrier's parts, in the order they run
+    "anatomy/barrier_reports_seconds": Phase(
+        "train/barrier_reports_seconds", ("train/barrier_reports",),
+        "barrier reports"),
+    "anatomy/validation_seconds": Phase(
+        "train/validation_seconds", ("train/validation",), "validation"),
+    "anatomy/summary_flush_seconds": Phase(
+        "train/summary_pause_seconds", ("train/summary_flush",),
+        "summary flush"),
+    "anatomy/barrier_flush_seconds": Phase(
+        "obs/barrier_flush_seconds", ("obs/barrier_flush",),
+        "barrier flush"),
+    "anatomy/pipeline_open_seconds": Phase(
+        "pipeline/open_seconds", ("pipeline/open",), "pipeline open"),
+    # other threads', or inside a validation sweep: no leaves
+    "anatomy/host_build_seconds": Phase(
+        "pipeline/build_seconds", ("pipeline/build",), "host build",
+        leaf=False),
+    "anatomy/window_fill_seconds": Phase(
+        "lockstep/window_fill_seconds", ("lockstep/window_fill",),
+        "window fill", leaf=False),
+    "anatomy/allgather_seconds": Phase(
+        "lockstep/allgather_seconds", ("lockstep/allgather",),
+        "lockstep allgather", leaf=False, wait=True),
+    "anatomy/fetch_seconds": Phase(
+        "lockstep/fetch_seconds", ("lockstep/score_fetch",),
+        "d2h fetch", leaf=False),
 }
+LOOP_LEAVES = tuple(p.counter for p in ANATOMY_PHASES.values() if p.leaf)
+LOOP_UNNAMED = "train/loop_unnamed_seconds"
+
+
+def loop_partition(counters: Dict[str, float]) -> Dict[str, float]:
+    """The loop thread's wall as one snapshot's counters split it:
+    every leaf's seconds and, under ``LOOP_UNNAMED``, the residue:
+    ``train/loop_seconds`` less their sum, the wall under no leaf.
+    Leaves are disjoint by construction, so a negative residue means a
+    nested pair (tests/test_loop_phases.py forbids it)."""
+    parts = {name: float(counters.get(name, 0.0)) for name in LOOP_LEAVES}
+    parts[LOOP_UNNAMED] = (float(counters.get("train/loop_seconds", 0.0))
+                           - sum(parts.values()))
+    return parts
 
 
 def anatomy_gauges(snap: Dict[str, Any]) -> Dict[str, float]:
     """This process's anatomy/* gauge rows for one registry snapshot:
-    the phase-seconds counters above, plus the step wall and example
-    totals the EFFICIENCY math divides by. Phases that never ticked are
-    omitted (a predict run has no train/ rows and vice versa)."""
+    the phase-seconds counters above, plus the loop's wall and residue,
+    the step wall and the example totals the EFFICIENCY math divides
+    by. Phases that never ticked are omitted (a predict run has no
+    train/ rows and vice versa)."""
     c = snap.get("counters") or {}
-    rows = {g: float(c[src]) for g, src in ANATOMY_PHASES.items()
-            if c.get(src)}
+    rows = {g: float(c[p.counter]) for g, p in ANATOMY_PHASES.items()
+            if c.get(p.counter)}
+    if c.get("train/loop_seconds"):
+        rows["anatomy/loop_seconds"] = float(c["train/loop_seconds"])
+        rows["anatomy/unnamed_seconds"] = float(c.get(LOOP_UNNAMED, 0.0))
     h = (snap.get("hists") or {}).get("train/step_seconds")
     if h and h.get("count"):
         rows["anatomy/step_wall_seconds"] = float(h["sum"])

@@ -105,10 +105,14 @@ class _Held:
         self._span = sp
         sp.__enter__()
 
-    def end(self) -> None:
+    def end(self) -> Optional[float]:
+        """Exit the span; its seconds the first time, where something
+        listened, else None."""
         sp, self._span = self._span, None
-        if sp is not None:
-            sp.__exit__(None, None, None)
+        if sp is None:
+            return None
+        sp.__exit__(None, None, None)
+        return getattr(sp, "dur", None)
 
 
 def begin(name: str, seconds: Optional[str] = None, **fields) -> _Held:

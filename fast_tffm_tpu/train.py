@@ -60,6 +60,11 @@ LIVE_FETCH_BUDGET_S = 0.005
 # how often a slow link pays a mid-epoch log sync.
 LOG_BUFFER_MAX = 1024
 
+# A step or epoch barrier whose wall reaches this says where it was
+# slow (RunTelemetry.slow_step). Steps take 6 to 20 ms on the chip, the
+# stalls this is for 1.5 to 5.4 s (PERF.md); tests patch it down.
+SLOW_STEP_SECONDS = 1.0
+
 
 def evaluate(cfg: FmConfig, table: jax.Array, files,
              max_batches: Optional[int] = None,
@@ -771,6 +776,8 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                             "to %s", cfg.save_summaries_steps,
                             s.summaries.logdir)
         loop.log_mode = _probe_link(cfg, logger)
+        if tel is not None:
+            tel.loop_start()  # stopped in _finish, after the last step
         if s.stream_mode:
             _run_stream(s, loop)
         else:
@@ -1293,12 +1300,6 @@ class StepLoop:
         # resumes with no example duplicated or skipped. None in epoch
         # mode (saves then carry no watermark sidecar).
         self.stream_watermark = None
-        # Step-anatomy join keys (obs/anatomy.py; README "Step
-        # anatomy"): when on, the loops stamp the step id into the
-        # h2d/step/flags spans (so fmtrace --anatomy can join phases
-        # across ranks) and feed the host-side phase-seconds counters
-        # the anatomy/* gauges aggregate at barrier flushes.
-        self.anat = s.tel is not None and getattr(s.tel, "anatomy", False)
         if s.mesh is not None:
             from fast_tffm_tpu.parallel.sharded import (global_batch,
                                                         shard_batch)
@@ -1330,19 +1331,18 @@ class StepLoop:
         # at the head of this step's dispatch.
         return s.wire_enc.device_put(wb)
 
-    def wire_place(self, batch, step=0):
-        """Encode one batch and place its arrays for dispatch.
-        h2d_bytes = wb.wire_bytes sizes the arrays ACTUALLY shipped;
-        the padded-layout size rides on wb.logical_bytes for the
-        savings counter. ``step`` (anatomy on) rides the h2d span as
-        the cross-rank join key."""
-        with span("train/encode", seconds="train/encode_seconds"):
+    def wire_place(self, batch, step):
+        """Encode one batch and place its arrays for dispatch. h2d_bytes
+        = wb.wire_bytes sizes the arrays ACTUALLY shipped; the padded
+        layout's size rides on wb.logical_bytes for the savings counter.
+        ``step`` rides both spans: the h2d span's is the cross-rank key."""
+        with span("train/encode", seconds="train/encode_seconds",
+                  step=step):
             wb = self.s.wire_enc.encode_train(batch)
         if self.s.offload:
             return wb, wb.args
-        ids = {"step": step} if (self.anat and step) else {}
         with span("train/h2d", seconds="train/h2d_seconds",
-                  bytes=wb.wire_bytes, **ids):
+                  bytes=wb.wire_bytes, step=step):
             return wb, self.place(batch, wb)
 
     def dispatch(self, wb, args, step):
@@ -1383,81 +1383,84 @@ class StepLoop:
         everything else a mode does, it does around this call."""
         s = self.s
         cfg, tel, vocab = s.cfg, s.tel, s.vocab
+        step = self.global_step + 1
         if vocab is not None:
             # A publish barrier may have moved the slot map while this
             # batch sat in the prefetch queue — redo its remap so it
             # never scatters into rows the barrier evicted/reset/
-            # reassigned (one int compare when nothing moved; epoch
-            # barriers only run once the iterator is exhausted, so
-            # there it is insurance).
-            batch = vocab.ensure_current(batch)
-        wb, args = self.wire_place(batch, self.global_step + 1)
-        self.table, self.acc, self.loss, _ = self.dispatch(
-            wb, args, self.global_step + 1)
-        self.end_barrier()
-        self.global_step = step = self.global_step + 1
-        self.last_val = None  # table advanced; any cached AUC is stale
-        if vocab is not None:
-            # Adopt-on-step, like the stream watermark: the sketch
-            # advances only for TRAINED batches, so the checkpointed
-            # admission state and the stream position describe the
-            # same prefix.
-            vocab.note_trained(batch)
-        # Log-line rate: the job-global estimate (x P assumes
-        # symmetric shards — exact under line sharding, an estimate
-        # under whole-file stream ownership). The COUNTER is this
-        # worker's OWN real examples: shard files merge by sum, so
-        # anything else would inflate the exactly-once accounting
-        # P-fold (and whole-file ownership pays fillers as phantom
-        # examples).
-        n_global = batch.num_real * (jax.process_count()
-                                     if s.multi_process else 1)
-        self.timer.tick(n_global)
-        if tel is not None:
-            # Wall time since the previous step's bookkeeping —
-            # dispatch-loop time, never a device sync. Re-anchored per
-            # epoch so validation/pause gaps stay out of the histogram
-            # (they have their own counters).
-            # fmlint: disable=R003 -- feeds the train/step_seconds
-            # histogram (always-on aggregate; the train/step span is
-            # the timeline view)
-            now = time.perf_counter()
-            tel.train_step(now - self.t_prev, batch.num_real,
-                           wb.wire_bytes, wb.logical_bytes)
-            self.t_prev = now
-            # Watchdog progress beat: one tuple assignment
-            # (obs/health.py) — the stall detector's only hot-path
-            # cost.
-            tel.heartbeat(step)
-        self.profile_tick(step)
-        log_due = cfg.log_steps and step % cfg.log_steps == 0
-        sum_due = (summaries is not None
-                   and step % cfg.save_summaries_steps == 0)
-        tel_due = tel is not None and tel.flush_due(step)
-        # One windowed-rate read per step: the read consumes the
-        # window, so the log line, the summary, and the metrics gauge
-        # all share it.
-        eps_now = (self.timer.consume_window_rate()
-                   if (log_due or sum_due or tel_due) else None)
-        if log_due:
-            self.log_tick(step, epoch, self.loss, eps_now)
-        if sum_due:
-            summaries.add("train/loss", step, self.loss)
-            summaries.add("train/examples_per_sec", step, eps_now)
+            # reassigned (one int compare when nothing moved).
+            with span("train/batch_checks",
+                      seconds="train/batch_checks_seconds", step=step):
+                batch = vocab.ensure_current(batch)
+        wb, args = self.wire_place(batch, step)
+        out = self.dispatch(wb, args, step)
+        self.end_barrier()  # an enclosure: closed before the next leaf opens
+        # From here to the flush: one leaf of the loop's wall. Nothing in
+        # it opens a span (a loss line or a flush is queued, not paid).
+        with span("train/bookkeeping", seconds="train/bookkeeping_seconds",
+                  step=step):
+            # The last step's state and loss and this step's placed batch
+            # are let go HERE, under the phase (250 us a step: PERF.md).
+            self.table, self.acc, self.loss, _ = out
+            del out, args
+            self.global_step = step
+            self.last_val = None  # table advanced; a cached AUC is stale
+            if vocab is not None:
+                # Adopt-on-step, like the stream watermark: the sketch
+                # advances only for TRAINED batches, so the checkpointed
+                # admission state and the stream position describe the
+                # same prefix.
+                vocab.note_trained(batch)
+            # Log-line rate: the job-global estimate (x P assumes symmetric
+            # shards). The COUNTER is this worker's OWN real examples: shard
+            # files merge by sum, anything else would count them P-fold.
+            self.timer.tick(batch.num_real * (jax.process_count()
+                                              if s.multi_process else 1))
+            if tel is not None:
+                # Wall time since the previous step's clock read, never a
+                # device sync; re-anchored per epoch (no barrier in it).
+                # fmlint: disable=R003 -- feeds the train/step_seconds
+                # histogram (always-on aggregate)
+                now = time.perf_counter()
+                dt, self.t_prev = now - self.t_prev, now
+                tel.train_step(dt, batch.num_real, wb.wire_bytes,
+                               wb.logical_bytes)
+                if dt >= SLOW_STEP_SECONDS:
+                    tel.slow_step(step, dt, epoch=epoch)
+                tel.heartbeat(step)  # the watchdog's beat (obs/health.py)
+            self.profile_tick(step)
+            log_due = cfg.log_steps and step % cfg.log_steps == 0
+            sum_due = (summaries is not None
+                       and step % cfg.save_summaries_steps == 0)
+            tel_due = tel is not None and tel.flush_due(step)
+            # One windowed-rate read per step: the read consumes the
+            # window, so line, summary and gauge all share it.
+            eps_now = (self.timer.consume_window_rate()
+                       if (log_due or sum_due or tel_due) else None)
+            if log_due:
+                self.log_tick(step, epoch, self.loss, eps_now)
+            if sum_due:
+                summaries.add("train/loss", step, self.loss)
+                summaries.add("train/examples_per_sec", step, eps_now)
+            if tel_due:
+                # loss is a DEVICE scalar: buffered, fetched only at the
+                # next barrier flush (sink link-safety contract).
+                tel.add_scalar("train/loss", step, self.loss)
+                tel.set("train/examples_per_sec_window", eps_now)
+                if gauges is not None:
+                    gauges()
         if tel_due:
-            # loss is a DEVICE scalar: buffered, fetched only at the
-            # next barrier flush (sink link-safety contract).
-            tel.add_scalar("train/loss", step, self.loss)
-            tel.set("train/examples_per_sec_window", eps_now)
-            if gauges is not None:
-                gauges()
-            with span("obs/flush", seconds="obs/flush_seconds"):
+            with span("obs/flush", seconds="obs/flush_seconds", step=step):
                 tel.maybe_flush(step)  # file I/O only
+        # log_steps=1 on a months-long epoch: one rare sync, bounded memory
+        if len(self.log_buffer) >= LOG_BUFFER_MAX:
+            self.flush_log()
 
     def end_barrier(self) -> None:
         if self.barrier is not None:
-            self.barrier.end()
-            self.barrier = None
+            wall, self.barrier = self.barrier.end(), None
+            if self.s.tel is not None and (wall or 0) >= SLOW_STEP_SECONDS:
+                self.s.tel.slow_step(self.global_step + 1, wall, "barrier")
 
     def profile_tick(self, step_done: int) -> None:
         cfg = self.s.cfg
@@ -1486,50 +1489,47 @@ class StepLoop:
                            step, epoch, val, eps)
 
     def log_tick(self, step, epoch, loss_arr, eps) -> None:
-        if self.log_mode == "deferred":
-            self.log_buffer.append((step, epoch, loss_arr, eps))
-            # Bound the buffer: log_steps=1 on a months-long epoch must
-            # not retain unbounded device scalars; one rare mid-epoch
-            # sync is the lesser evil.
-            if len(self.log_buffer) >= LOG_BUFFER_MAX:
-                self.flush_log()
-            return
-        # Live: the sync is taken at the NEXT dispatch, once the next
-        # batch is fetched and placed: the device then waits for the
-        # host one dispatch after each loss line, and not a placement
-        # too (13 ms of every eight steps on the four-chip mesh).
-        self.sync_live_line()  # never two owed
-        self.live_line.append((step, epoch, loss_arr, eps))
+        """Queue one loss line (no span: bookkeeping calls it). Live: the
+        sync is taken at the NEXT dispatch, once the next batch is placed:
+        the device then waits for the host one dispatch after a line, and
+        not a placement too (13 ms of eight steps on the four-chip mesh)."""
+        (self.log_buffer if self.log_mode == "deferred"
+         else self.live_line).append((step, epoch, loss_arr, eps))
 
     def sync_live_line(self) -> None:
         """The loop's sync point: the host waits here until the device
-        has caught up, so this phase's share of the wall says how far
-        the device sets the pace. The line itself is written outside
-        the phase."""
-        if not self.live_line:
+        has caught up, so this phase's share of the wall says how far the
+        device sets the pace. The line is written in a phase of its own."""
+        if not self.live_line:  # at most one: every dispatch syncs first
             return
         step, epoch, loss_arr, eps = self.live_line.pop()
-        with span("train/loss_sync", seconds="train/loss_sync_seconds"):
+        with span("train/loss_sync", seconds="train/loss_sync_seconds",
+                  step=step):
             val = float(loss_arr)
-        self.log_line(step, epoch, val, eps)
+        with span("train/log_line", seconds="train/log_line_seconds",
+                  step=step):
+            self.log_line(step, epoch, val, eps)
 
     def flush_log(self) -> None:
         self.sync_live_line()
         if not self.log_buffer:
             return
         # bulk_fetch stacks the same-shaped scalars into ONE transfer:
-        # deferred mode is only ever active on a slow device link,
-        # where a per-element list fetch costs ~200 ms EACH
-        # (utils/fetch.py) — a full 1024-entry buffer would stall for
-        # minutes.
+        # deferred mode is only ever active on a slow device link, where
+        # a per-element list fetch costs ~200 ms EACH (utils/fetch.py) —
+        # a full 1024-entry buffer would stall for minutes.
         lines: list = []
-        with span("train/loss_sync", seconds="train/loss_sync_seconds"):
+        step = self.log_buffer[-1][0]
+        with span("train/loss_sync", seconds="train/loss_sync_seconds",
+                  step=step):
             bulk_fetch([(arr, (step, epoch, eps))
                         for step, epoch, arr, eps in self.log_buffer],
                        lambda v, m: lines.append(
                            (m[0], m[1], float(v), m[2])))
-        for line in lines:
-            self.log_line(*line)
+        with span("train/log_line", seconds="train/log_line_seconds",
+                  step=step):
+            for line in lines:
+                self.log_line(*line)
         self.log_buffer.clear()
 
     # -- what a barrier or a save needs of the state ------------------
@@ -1672,23 +1672,24 @@ def _agreed_batch(s: _Session, loop: StepLoop, batch, epoch: int):
     WorkerLostError naming it instead of parking the survivors here
     forever (parallel/liveness.py)."""
     if not s.multi_process:
-        if s.preempted:
-            # fmlint: disable=R001 -- preempted holds host signal
-            # numbers from the handler, never device arrays
-            loop.note_preempted(epoch, "saving and exiting",
-                                signals=[int(x) for x in s.preempted])
-            return None
-        return batch
+        with span("train/batch_checks",
+                  seconds="train/batch_checks_seconds",
+                  step=loop.global_step + 1):
+            if s.preempted:
+                # fmlint: disable=R001 -- preempted holds host signal
+                # numbers from the handler, never device arrays
+                loop.note_preempted(epoch, "saving and exiting",
+                                    signals=[int(x) for x in s.preempted])
+                return None
+            return batch
     from jax.experimental import multihost_utils
     from fast_tffm_tpu.parallel.liveness import guarded_collective
-    # The epoch loop's rank barrier (anatomy flags-wait phase; span
-    # step id = cross-rank join key). On CPU+gloo this wait also
-    # absorbs the PREVIOUS step's still-executing program — allgather
-    # blocks behind queued device work — which is exactly what the
-    # anatomy report names.
-    ids = {"step": loop.global_step + 1} if loop.anat else {}
+    # The epoch loop's rank barrier (anatomy flags-wait phase; span step
+    # id = cross-rank join key). On CPU+gloo this wait also absorbs the
+    # PREVIOUS step's still-executing program — allgather blocks behind
+    # queued device work — which is exactly what the anatomy report names.
     with span("train/step_flags", seconds="train/step_flags_seconds",
-              **ids):
+              step=loop.global_step + 1):
         flags = guarded_collective(
             multihost_utils.process_allgather,
             np.asarray([batch is None, bool(s.preempted)]),
@@ -1714,12 +1715,9 @@ def _run_epochs(s: _Session, loop: StepLoop) -> None:
         if loop.stopping:
             break
         epoch_stats = SpillStats()
-        # Building the input pipeline until its first batch is out
-        # (ended after the epoch's first next(); again by the finally,
-        # for whatever an exception left open).
-        starting = begin("pipeline/start",
-                         seconds="pipeline/start_seconds")
-        try:
+        # Threads, builders, files: until the first next() can be called.
+        with span("pipeline/open", seconds="pipeline/open_seconds",
+                  epoch=epoch):
             it = prefetch(batch_iterator(
                 cfg, cfg.train_files, training=True,
                 weight_files=cfg.weight_files,
@@ -1731,44 +1729,43 @@ def _run_epochs(s: _Session, loop: StepLoop) -> None:
                 row_shards=s.row_shards),
                 depth=cfg.prefetch_depth,
                 gil_bound=gil_bound_iteration(cfg, cfg.weight_files))
-            # fmlint: disable=R003 -- anchors the per-epoch
-            # step-seconds window (always-on aggregate)
-            loop.t_prev = time.perf_counter()
-            while True:
-                # Consumer-side stall: time blocked INSIDE next() only —
-                # bracketing it any wider would fold end-of-step
-                # bookkeeping (notably live-mode's deliberate
-                # float(loss) device sync) into the host-bound signal
-                # and misdiagnose a device-bound run (the producer-side
-                # build cost is timed separately in
-                # pipeline.batch_iterator on the worker thread).
-                with span("train/input_wait",
-                          seconds="train/input_wait_seconds"):
-                    batch = next(it, None)
-                starting.end()
-                batch = _agreed_batch(s, loop, batch, epoch)
-                # fmlint: disable=R014 -- _agreed_batch returns None
-                # on every process together in multi-process mode (it
-                # agrees on exhaustion and preemption through the
-                # train/step_flags allgather first); single-process,
-                # the loop's collectives are gated on multi_process, so
-                # this escape leaves no peer unmatched
-                if batch is None:
-                    break
-                loop.step(batch, epoch, summaries=s.summaries)
-                if cfg.save_steps and loop.global_step % cfg.save_steps == 0:
-                    with span("train/checkpoint_pause",
-                              seconds="train/checkpoint_pause_seconds"
-                              ) as pause:
-                        # Host-offload state: wait, because the
-                        # background writer would race the in-place
-                        # numpy Adagrad updates.
-                        loop.save(loop.completed_epochs, wait=s.offload)
-                    if s.tel is not None:
-                        loop.t_prev += pause.dur  # keep the pause out
-                        # of the next step's step_seconds sample
-        finally:
-            starting.end()
+        # fmlint: disable=R003 -- anchors the per-epoch
+        # step-seconds window (always-on aggregate)
+        loop.t_prev = time.perf_counter()
+        first = True  # the cold plane's first batch goes by its own name
+        while True:
+            # Consumer-side stall: time blocked INSIDE next() only —
+            # bracketing it any wider would fold end-of-step bookkeeping
+            # (notably live-mode's deliberate float(loss) device sync)
+            # into the host-bound signal and misdiagnose a device-bound
+            # run (the producer-side build cost is timed separately in
+            # pipeline.batch_iterator on the worker thread).
+            with span("pipeline/first_batch" if first else "train/input_wait",
+                      seconds="train/input_wait_seconds",
+                      step=loop.global_step + 1) as wait:
+                batch = next(it, None)
+            if first and s.tel is not None:
+                s.tel.count("pipeline/first_batch_seconds", wait.dur)
+            first = False
+            batch = _agreed_batch(s, loop, batch, epoch)
+            # fmlint: disable=R014 -- _agreed_batch returns None on
+            # every process together in multi-process mode (it agrees on
+            # exhaustion and preemption through the train/step_flags
+            # allgather first); single-process, the loop's collectives
+            # are gated on multi_process, so this escape leaves no peer
+            # unmatched
+            if batch is None:
+                break
+            loop.step(batch, epoch, summaries=s.summaries)
+            if cfg.save_steps and loop.global_step % cfg.save_steps == 0:
+                with span("train/checkpoint_pause",
+                          seconds="train/checkpoint_pause_seconds",
+                          step=loop.global_step) as pause:
+                    # Host-offload state: wait, because the background
+                    # writer would race the in-place numpy Adagrad updates.
+                    loop.save(loop.completed_epochs, wait=s.offload)
+                if s.tel is not None:  # keep the pause out of the next
+                    loop.t_prev += pause.dur  # step's step_seconds sample
         _epoch_barrier(s, loop, epoch, epoch_stats)
 
 
@@ -1782,64 +1779,65 @@ def _epoch_barrier(s: _Session, loop: StepLoop, epoch: int,
     stopping = loop.stopping
     loop.sync_live_line()  # the epoch's last line, ahead of the barrier
     if not stopping:
-        # The epoch barrier: from the iterator's exhaustion until the
-        # NEXT epoch's first dispatch returns (or the loop's end) —
-        # telemetry flush, validation, the cold input pipeline: what
-        # the steady step rate leaves out.
+        # The epoch barrier, an enclosure of leaf phases: from the
+        # iterator's exhaustion until the NEXT epoch's first dispatch
+        # returns (or the loop's end): what the steady rate leaves out.
         loop.barrier = begin("train/epoch_barrier",
-                             seconds="train/epoch_barrier_seconds")
+                             seconds="train/epoch_barrier_seconds",
+                             epoch=epoch)
     loop.flush_log()  # deferred loss lines land at the epoch barrier
-    if s.bad_tracker is not None and s.bad_tracker.bad:
-        # Cumulative run-level view: the breaker and quarantine are
-        # run-scoped, so the log line is too.
-        logger.info("bad-line policy through epoch %d: %s",
-                    epoch, s.bad_tracker.describe())
-    if epoch_stats.spilled_batches or (s.multi_process
-                                       and epoch_stats.batches):
-        # Spill visibility (fixed-U mode): a probe-missed dense
-        # stretch degrades fill silently otherwise.
-        logger.info("epoch %d input: %s", epoch, epoch_stats.describe())
-        if epoch_stats.spill_fraction > SPILL_WARN_FRACTION:
-            logger.warning(
-                "uniq_bucket %d is undersized for this data: "
-                "%.0f%% of batches closed early on the "
-                "unique-row budget; raise uniq_bucket (or set 0 "
-                "to re-probe) to recover effective batch size",
-                s.uniq_bucket, 100 * epoch_stats.spill_fraction)
-    if s.multi_process and not stopping and epoch + 1 < cfg.epoch_num:
-        # Adaptive bucket: a probe-missed dense stretch spills every
-        # epoch otherwise. The job-wide spill fraction is allgathered
-        # (per-process stats see only their own shard — a local
-        # decision would desynchronize shapes and deadlock the
-        # collective program), and every process applies the same
-        # doubling.
-        from jax.experimental import multihost_utils
-        from fast_tffm_tpu.parallel.liveness import guarded_collective
-        tot = guarded_collective(
-            multihost_utils.process_allgather,
-            np.asarray(
-                [epoch_stats.spilled_batches, epoch_stats.batches,
-                 epoch_stats.max_uniq]),
-            label="train/spill_stats")
-        tot = tot.reshape(-1, 3)
-        # fmlint: disable=R001 -- tot is the HOST numpy result
-        # of process_allgather; these ints never touch a device
-        s.uniq_bucket = adapt_uniq_bucket(
-            cfg, s.uniq_bucket, int(tot[:, 0].sum()),
-            int(tot[:, 1].sum()), logger,
-            max_uniq=int(tot[:, 2].max()), shards=s.row_shards)
-    if not stopping:
-        # The epoch boundary IS a vocab barrier point: the epoch's
-        # observations admit/evict here, so the next epoch (and the
-        # validation sweep just below) runs against the refreshed map
-        # + reset rows.
-        loop.vocab_barrier(f"epoch {epoch}")
+    with span("train/barrier_reports",
+              seconds="train/barrier_reports_seconds", epoch=epoch):
+        if s.bad_tracker is not None and s.bad_tracker.bad:
+            # Cumulative run-level view: the breaker and quarantine are
+            # run-scoped, so the log line is too.
+            logger.info("bad-line policy through epoch %d: %s",
+                        epoch, s.bad_tracker.describe())
+        if epoch_stats.spilled_batches or (s.multi_process
+                                           and epoch_stats.batches):
+            # Spill visibility (fixed-U mode): a probe-missed dense
+            # stretch degrades fill silently otherwise.
+            logger.info("epoch %d input: %s", epoch, epoch_stats.describe())
+            if epoch_stats.spill_fraction > SPILL_WARN_FRACTION:
+                logger.warning(
+                    "uniq_bucket %d is undersized for this data: "
+                    "%.0f%% of batches closed early on the "
+                    "unique-row budget; raise uniq_bucket (or set 0 "
+                    "to re-probe) to recover effective batch size",
+                    s.uniq_bucket, 100 * epoch_stats.spill_fraction)
+        if s.multi_process and not stopping and epoch + 1 < cfg.epoch_num:
+            # Adaptive bucket: a probe-missed dense stretch spills every
+            # epoch otherwise. The job-wide spill fraction is allgathered
+            # (per-process stats see only their own shard — a local
+            # decision would desynchronize shapes and deadlock the
+            # collective program), and every process applies the same
+            # doubling.
+            from jax.experimental import multihost_utils
+            from fast_tffm_tpu.parallel.liveness import guarded_collective
+            tot = guarded_collective(
+                multihost_utils.process_allgather,
+                np.asarray(
+                    [epoch_stats.spilled_batches, epoch_stats.batches,
+                     epoch_stats.max_uniq]),
+                label="train/spill_stats")
+            tot = tot.reshape(-1, 3)
+            # fmlint: disable=R001 -- tot is the HOST numpy result
+            # of process_allgather; these ints never touch a device
+            s.uniq_bucket = adapt_uniq_bucket(
+                cfg, s.uniq_bucket, int(tot[:, 0].sum()),
+                int(tot[:, 1].sum()), logger,
+                max_uniq=int(tot[:, 2].max()), shards=s.row_shards)
+        if not stopping:
+            # The epoch boundary IS a vocab barrier point: the epoch's
+            # observations admit/evict here, so the next epoch (and the
+            # validation sweep just below) runs against the refreshed map
+            # + reset rows.
+            loop.vocab_barrier(f"epoch {epoch}")
     if cfg.validation_files and not stopping:
         with span("train/validation", leaf=False,
                   seconds="train/validation_seconds", epoch=epoch):
-            # A preempted sweep stops on every worker together; the
-            # step loop then drains the flag and all workers save
-            # together.
+            # A preempted sweep stops on every worker together; the step
+            # loop then drains the flag and all workers save together.
             auc, n = s.validate(loop.table,
                                 preempt=lambda: bool(s.preempted))
         loop.last_val = (auc, n)
@@ -1857,7 +1855,7 @@ def _epoch_barrier(s: _Session, loop: StepLoop, epoch: int,
                            float(auc))
     if s.summaries is not None:  # epoch barrier: bulk-fetch + write
         with span("train/summary_flush",
-                  seconds="train/summary_pause_seconds"):
+                  seconds="train/summary_pause_seconds", epoch=epoch):
             s.summaries.flush()
     if tel is not None:
         # Epoch barrier: the one point buffered device scalars
@@ -1869,18 +1867,19 @@ def _epoch_barrier(s: _Session, loop: StepLoop, epoch: int,
     if (s.grow_ctx is not None and not stopping
             and loop.completed_epochs < cfg.epoch_num):
         # The epoch boundary IS the grow barrier in epochs mode: every
-        # worker is synchronized here (the same point the vocab
-        # barrier uses), and the chief's admission plan is broadcast
-        # so everyone raises together or nobody does. The barrier
-        # state is saved durably FIRST (force rewrites a same-step
-        # periodic save with the completed epoch count) — it is
-        # exactly what the newcomer's verified restore comes up on.
-        # The last epoch never grows: the run is about to finish, and
-        # a reform would only delay its exit.
-        plan = s.grow_ctx.check_barrier()
-        if plan is not None:
-            loop.save(loop.completed_epochs, wait=True, force=True)
-            raise ClusterGrowth(plan)
+        # worker is synchronized here (the same point the vocab barrier
+        # uses), and the chief's admission plan is broadcast so everyone
+        # raises together or nobody does. The barrier state is saved
+        # durably FIRST (force rewrites a same-step periodic save with
+        # the completed epoch count) — it is exactly what the newcomer's
+        # verified restore comes up on. The last epoch never grows: the
+        # run is about to finish, and a reform would only delay its exit.
+        with span("train/barrier_reports",
+                  seconds="train/barrier_reports_seconds", epoch=epoch):
+            plan = s.grow_ctx.check_barrier()
+            if plan is not None:
+                loop.save(loop.completed_epochs, wait=True, force=True)
+                raise ClusterGrowth(plan)
 
 
 class _StreamClock:
@@ -2152,12 +2151,11 @@ def _stream_lockstep(s: _Session, loop: StepLoop, clock: _StreamClock,
         done = b is streamlib.DONE
         pub_due = _publish_due(s, clock)
         # The flags allgather is the stream loop's rank barrier: time
-        # parked here is waiting for the slowest peer (anatomy
-        # flags-wait phase; the span's step id is the cross-rank join
-        # key).
-        ids = {"step": loop.global_step + 1} if loop.anat else {}
+        # parked here is waiting for the slowest peer (anatomy flags-wait
+        # phase; the span's step id is the cross-rank join key).
         with span("stream/step_flags",
-                  seconds="train/step_flags_seconds", **ids):
+                  seconds="train/step_flags_seconds",
+                  step=loop.global_step + 1):
             flags = np.asarray(guarded_collective(
                 multihost_utils.process_allgather,
                 np.asarray([has, bool(s.preempted), done, pub_due]),
@@ -2230,6 +2228,8 @@ def _finish(s: _Session, loop: StepLoop) -> None:
     cfg, logger, tel = s.cfg, s.logger, s.tel
     loop.end_barrier()
     loop.flush_log()
+    if tel is not None:
+        tel.loop_stop()  # the final save and the export are no step's
     if loop.loss is not None:
         loop.loss_val = float(loop.loss)
     # The final save IS a barrier point (vocab/table.py's contract):

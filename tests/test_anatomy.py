@@ -315,8 +315,21 @@ def test_efficiency_table_names_the_straggler():
     assert "host build" in eff["verdict"]
 
 
-def test_efficiency_table_absent_without_coordination():
-    # Single-process run: anatomy gauges but no collective waits.
+def test_efficiency_table_of_one_process_and_absent_without_phases():
+    # A single-process run has no collective waits and gets the phase
+    # table all the same, over the loop's wall where the stream has it.
+    one = {"gauges_by_process": {
+        0: {"anatomy/step_wall_seconds": 8.0, "anatomy/loop_seconds": 10.0,
+            "anatomy/dispatch_seconds": 6.0, "anatomy/h2d_seconds": 1.0,
+            "anatomy/unnamed_seconds": 0.05, "anatomy/examples": 640.0}}}
+    eff = efficiency_table(one)
+    assert eff["straggler_rank"] is None and eff["efficiency"] == 1.0
+    r = eff["ranks"][0]
+    assert r["wall_seconds"] == 10.0 and r["examples_per_sec"] == 64.0
+    assert r["phases"]["dispatch"] == 6.0 and r["phases"]["unnamed"] == 0.05
+    assert "largest phase: dispatch 60%" in eff["verdict"]
+    assert "unnamed 0.5%" in eff["verdict"]
+    # Anatomy gauges but no phase, or none at all: no section.
     summary = {"gauges_by_process": {
         0: {"anatomy/step_wall_seconds": 10.0,
             "anatomy/examples": 640.0}}}

@@ -244,24 +244,31 @@ def traced_run(tmp_path_factory):
     return train_events, list(read_events(cfg.model_file + ".metrics.jsonl"))
 
 
-@pytest.mark.parametrize("name,counter,count", [
-    ("train/input_wait", "train/input_wait_seconds", 10),  # 8 + 2 ends
+@pytest.mark.parametrize("names,counter,count", [
+    # 8 + 2 ends; an epoch's first wait (2) goes by another name
+    ("train/input_wait pipeline/first_batch", "train/input_wait_seconds",
+     10),
+    ("pipeline/first_batch", "pipeline/first_batch_seconds", 2),
+    ("train/batch_checks", "train/batch_checks_seconds", 10),
     ("train/encode", "train/encode_seconds", 8),
     ("train/h2d", "train/h2d_seconds", 8),
     ("train/step", "train/dispatch_seconds", 8),
+    ("train/bookkeeping", "train/bookkeeping_seconds", 8),
     ("train/loss_sync", "train/loss_sync_seconds", 4),
+    ("train/log_line", "train/log_line_seconds", 4),
     ("obs/flush", "obs/flush_seconds", 4),
     ("train/epoch_barrier", "train/epoch_barrier_seconds", 2),
-    ("pipeline/start", "pipeline/start_seconds", 2),
+    ("train/barrier_reports", "train/barrier_reports_seconds", 2),
+    ("pipeline/open", "pipeline/open_seconds", 2),
     ("train/validation", "train/validation_seconds", 2),
     ("obs/barrier_flush", None, None),
 ])
-def test_train_loop_phase(traced_run, name, counter, count):
+def test_train_loop_phase(traced_run, names, counter, count):
     """2 epochs of 4 steps, a loss line and a flush every 2: each phase
     is a span of the loop, and its counter is the sum of its spans."""
     events, _ = traced_run
     spans = [e for e in events if e["event"] == "span"
-             and e["name"] == name]
+             and e["name"] in names.split()]
     assert spans
     if counter is None:
         return
@@ -271,7 +278,7 @@ def test_train_loop_phase(traced_run, name, counter, count):
         sum(s["dur"] for s in spans), rel=1e-9)
 
 
-def test_the_epoch_barrier_encloses_flush_and_pipeline_start(traced_run):
+def test_the_epoch_barrier_encloses_its_parts(traced_run):
     events, _ = traced_run
     spans = [e for e in events if e["event"] == "span"]
     barrier = [s for s in spans if s["name"] == "train/epoch_barrier"][0]
@@ -280,10 +287,12 @@ def test_the_epoch_barrier_encloses_flush_and_pipeline_start(traced_run):
               if lo <= s["ts"] and s["ts"] + s["dur"] <= hi + 1e-6
               and s is not barrier}
     # the first barrier ends when the second epoch's first dispatch
-    # returns: the flush, the validation pass, the cold pipeline and
-    # that first step lie inside it
-    assert {"obs/barrier_flush", "train/validation", "pipeline/start",
+    # returns: the reports, the validation pass, the flush, the cold
+    # pipeline, its first batch and that first step lie inside it
+    assert {"train/barrier_reports", "train/validation",
+            "obs/barrier_flush", "pipeline/open", "pipeline/first_batch",
             "train/step"} <= inside
+    assert "pipeline/start" not in {s["name"] for s in spans}
 
 
 @pytest.mark.parametrize("which", ["mid-epoch", "epoch's last"])
