@@ -144,8 +144,10 @@ class HostOffloadLookup:
     def apply_grad(self, uniq_ids: np.ndarray, grad_rows: np.ndarray,
                    lr: float) -> None:
         """Sparse Adagrad on the touched rows: acc += g^2;
-        table -= lr * g / sqrt(acc). Mirrors models.fm
-        sparse_adagrad_apply (same math, host-side)."""
+        table -= lr * g / sqrt(acc). The same maths as
+        models.fm.sparse_adagrad_apply, in NumPy on the host; that
+        one's two-operand scatter (PR 38) is the device backend's
+        alone."""
         g = np.asarray(grad_rows, dtype=np.float32)
         ids = np.asarray(uniq_ids)
         a = self.acc[ids] + np.square(g)
@@ -379,7 +381,10 @@ def _apply_fn(pinned: bool):
     """jit: sparse Adagrad on host-resident state, gradients already on
     device. Same math as models.fm.sparse_adagrad_apply (uniq ids;
     padding rows carry zero grads, so duplicate pad-slot writes all
-    store identical values)."""
+    store identical values), but its own program: gathers and
+    scatter-sets in host memory space, where that one gathers the
+    accumulator and adds to both arrays in one two-operand scatter
+    (PR 38)."""
     import jax
     from jax import lax
     s_host, s_dev, ctx = _placement(pinned)

@@ -30,6 +30,7 @@ from fast_tffm_tpu.config import FmConfig
 from fast_tffm_tpu.data.pipeline import DeviceBatch
 from fast_tffm_tpu.ops.interaction import (batch_reg, ffm_batch_scores,
                                            fm_batch_scores, gather_rows)
+from fast_tffm_tpu.ops.pair_scatter import pair_scatter_add
 from fast_tffm_tpu.compile_cache import uncached
 from fast_tffm_tpu.obs.telemetry import active
 from fast_tffm_tpu.utils.logging import get_logger
@@ -98,8 +99,8 @@ class ModelSpec:
             # plain single-device jit, scoring needs no unique at all
             # (score_body gathers the raw ids) and is host-bound, so it
             # ships raw ids; a train step needs unique slots for its
-            # backward scatter and pays for every slot it walks (113
-            # ns in each scatter-add on the v5e, pad slot or real row;
+            # backward scatter and pays for every slot it walks (160
+            # ns in adagrad's scatter on the v5e, pad slot or real row;
             # PERF.md section 5), so it takes the host unique, whose U
             # is the ladder rung of the batch's distinct rows, not the
             # device unique's B*L + 1.
@@ -255,14 +256,26 @@ def sparse_adagrad_apply(table: jax.Array, acc: jax.Array,
                          lr: float) -> Tuple[jax.Array, jax.Array]:
     """acc[rows] += g²; table[rows] -= lr * g / sqrt(acc[rows]).
 
-    ``uniq_ids`` are unique except padding slots, whose gradient rows are
-    already masked to zero, so duplicate scatter-adds at the dead row are
-    no-ops and the dense-Adagrad semantics on touched rows are exact.
+    Two visits of the U slots where there were three (PR 38): the
+    accumulator's rows are gathered, the float32 maths is the same
+    three operations in the same order (``a + g²``, ``rsqrt``,
+    ``-lr * g * ...``), and ONE two-operand scatter
+    (``ops/pair_scatter.py``) adds the update to the table's rows and
+    ``g²`` to the accumulator's in the same walk over the slots.
+
+    ``uniq_ids`` are unique except padding slots, which all name the
+    dead row with gradient rows already masked to zero: their adds are
+    ``w + (-lr * 0 * rsqrt(a))`` and ``a + 0``, identities in whatever
+    order the scatter applies them, so the dense-Adagrad semantics on
+    touched rows are exact. An index past the block (the mesh's slots
+    whose row another shard holds, ``parallel/sharded._block_index``)
+    is dropped by the scatter; the gather in front clamps it to a row
+    whose update is then dropped with it.
     """
     with jax.named_scope("adagrad"):
-        acc = acc.at[uniq_ids].add(jnp.square(grad_rows))
-        upd = -lr * grad_rows * lax.rsqrt(acc[uniq_ids])
-        return table.at[uniq_ids].add(upd), acc
+        sq = jnp.square(grad_rows)
+        upd = -lr * grad_rows * lax.rsqrt(acc[uniq_ids] + sq)
+        return pair_scatter_add(table, acc, uniq_ids, upd, sq)
 
 
 def grad_body(spec: ModelSpec, gathered, labels, weights, uniq_ids,

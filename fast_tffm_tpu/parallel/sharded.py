@@ -198,7 +198,8 @@ def sharded_train_step_body(spec: ModelSpec, mesh: Mesh, blocks: int,
       ``data`` by GSPMD (which all-reduces the slot gradients).
     - ``adagrad``: every shard takes the gradients of its segment and
       runs ``sparse_adagrad_apply`` on its own blocks of table and
-      accumulator, over ``U / n`` slots.
+      accumulator, over ``U / n`` slots (a gather and one two-operand
+      scatter a shard).
 
     A feed in another order is NOT an error the step can see: a real
     row in a segment whose shard does not hold it reads as zeros and
@@ -227,9 +228,10 @@ def sharded_train_step_body(spec: ModelSpec, mesh: Mesh, blocks: int,
         uniq_ids, local_idx, vals, fields, mesh=mesh)
 
     def apply(block, acc_block, ids, grad):
-        # The scatter-adds drop a slot indexed past the block (jax's
-        # default for a scatter), and the accumulator's gather clamps
-        # it to a row whose update is then dropped.
+        # The one scatter over block and accumulator drops a slot
+        # indexed past the block (XLA's scatter semantics), and the
+        # accumulator's gather in front of it clamps that index to a
+        # row whose update is then dropped.
         return sparse_adagrad_apply(
             block, acc_block, _block_index(mesh, block, ids), grad,
             spec.learning_rate)

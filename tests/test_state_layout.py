@@ -331,14 +331,34 @@ CELLS = {
 }
 
 
+def _adagrad_walks_the_slots_twice(text, rows, dim):
+    """The ``adagrad`` scope of an optimized step (ISSUE 38): two
+    custom fusions, the accumulator's gather and ONE scatter whose two
+    operands and two results are the table and the accumulator; the
+    step has no other scatter into an array of the state's shape."""
+    custom = re.findall(
+        r'kind=kCustom, [^\n]*?op_name="[^"]*/adagrad/([^"/]+)"', text)
+    assert sorted(custom) == ["gather", "scatter-add"], custom
+    state = rf"f32\[{rows},{dim}\]\S*"
+    walks = [ln for ln in text.splitlines()
+             if re.search(r"\s(scatter|gather)\(", ln)
+             and "/adagrad/" in ln]
+    assert len(walks) == 2, walks
+    scatter, = [ln for ln in walks if " scatter(" in ln]
+    assert re.search(rf"= \({state}, {state}\) scatter\(", scatter), scatter
+    assert len(re.search(r" scatter\(([^)]*)\)", scatter).group(1)
+               .split(",")) == 5, scatter       # 2 operands, ids, 2 updates
+    assert len(re.findall(rf"{state}\)? scatter\(", text)) == 1
+
+
 @pytest.mark.parametrize("cell", sorted(CELLS))
 def test_v5e_step_has_no_copy_of_the_whole_state(one_chip, cell):
     """Compiled for a described v5e at the cell's real size through the
     step's own ``compile``: no ``copy`` whose result has the table's
     shape, both state arguments aliased to their results, and for FM's
     17 columns the layout the runtime gives the state anyway (nothing
-    to re-lay), for FFM's 89 another one. A compile says nothing of
-    times."""
+    to re-lay), for FFM's 89 another one; ``adagrad`` is a gather and
+    one two-operand scatter. A compile says nothing of times."""
     from jax.experimental.compilation_cache import compilation_cache
     spec, B, L, need, U = CELLS[cell]
     assert L == _ladder_fit(spec.field_num or 39, FmConfig().bucket_ladder)
@@ -366,6 +386,7 @@ def test_v5e_step_has_no_copy_of_the_whole_state(one_chip, cell):
     assert not copies, copies
     alias = re.search(r"input_output_alias=\{(.*?)\}, \w+=", text).group(1)
     assert "{0}: (0, {}" in alias and "{1}: (1, {}" in alias, alias
+    _adagrad_walks_the_slots_twice(text, rows, dim)
     device = one_chip._device_assignment[0]
     default = Layout.from_pjrt_layout(device.client.get_default_layout(
         jnp.dtype(jnp.float32), (rows, dim), device))
@@ -388,7 +409,8 @@ def test_v5e_second_width_takes_the_firsts_layout(one_chip, widths):
     """Whichever width a job sees first, the compiler chooses the same
     layout for the state (so the pinned one is what it would choose at
     the other width too: the runtime's own), and neither program copies
-    the whole state or breaks the aliasing of its two state arguments."""
+    the whole state, breaks the aliasing of its two state arguments or
+    walks the slots more than twice in ``adagrad``."""
     from jax.experimental.compilation_cache import compilation_cache
     spec, B, U = BAGS
     ladder = FmConfig().bucket_ladder
@@ -419,3 +441,4 @@ def test_v5e_second_width_takes_the_firsts_layout(one_chip, widths):
         alias = re.search(r"input_output_alias=\{(.*?)\}, \w+=",
                           text).group(1)
         assert "{0}: (0, {}" in alias and "{1}: (1, {}" in alias, alias
+        _adagrad_walks_the_slots_twice(text, rows, dim)

@@ -6,13 +6,19 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
+from jax import lax
 
 from fast_tffm_tpu.config import FmConfig
 from fast_tffm_tpu.data.pipeline import make_device_batch
 from fast_tffm_tpu.data.parser import parse_lines
 from fast_tffm_tpu.models import oracle
-from fast_tffm_tpu.models.fm import (ModelSpec, batch_args, init_accumulator,
-                                     init_table, make_train_step)
+from fast_tffm_tpu.models.fm import (ModelSpec, TrainStep, batch_args,
+                                     grad_body, init_accumulator,
+                                     init_table, make_train_step,
+                                     sparse_adagrad_apply)
+from fast_tffm_tpu.ops import gather_rows
+from fast_tffm_tpu.ops.pair_scatter import pair_scatter_add
 
 V, K = 30, 3
 CFG = FmConfig(vocabulary_size=V, factor_num=K, batch_size=4,
@@ -202,3 +208,178 @@ def test_ffm_step_matches_fd_oracle():
                                     cfg.learning_rate)
     np.testing.assert_allclose(t1[:-1], want_t, rtol=2e-3, atol=2e-4)
     assert float(loss) == pytest.approx(total(t64), rel=1e-4)
+
+
+# ---- the update is one pass over the slots (ISSUE 38) -------------------
+
+def three_visits(table, acc, uniq_ids, grad_rows, lr):
+    """``sparse_adagrad_apply`` as it stood until PR 38: a scatter-add,
+    a gather of the same rows and a scatter-add. The scatter-adds drop
+    an index past the block and the gather clamps it."""
+    acc = acc.at[uniq_ids].add(jnp.square(grad_rows))
+    upd = -lr * grad_rows * lax.rsqrt(acc[uniq_ids])
+    return table.at[uniq_ids].add(upd), acc
+
+
+R, U_SLOTS, LR = 257, 64, 0.05
+
+
+def slots(case, rng, dim):
+    """``(ids, grad_rows, rows that must change)``: U slots over a
+    block of R rows whose last row is the dead one."""
+    ids = rng.choice(R - 1, size=U_SLOTS, replace=False).astype(np.int32)
+    grad = rng.normal(size=(U_SLOTS, dim)).astype(np.float32)
+    if case == "pad_slots":         # the batch's tail: dead row, zero grad
+        ids[-20:] = R - 1
+        grad[-20:] = 0.0
+    elif case == "past_the_block":  # another shard's rows (_block_index)
+        ids[::3] = R
+        ids[1] = R + 1000
+    return ids, grad, np.unique(ids[ids < R - 1])
+
+
+@pytest.mark.parametrize("dim", [9, 17, 89])
+@pytest.mark.parametrize("case", ["distinct", "pad_slots",
+                                  "past_the_block"])
+def test_one_pass_equals_the_three_visits(case, dim):
+    """Accumulator exactly and table to two ulps of the largest of the
+    row's value, before or after, and its update (XLA's CPU rounds
+    ``rsqrt`` of the gathered sum and the add of the update once each
+    otherwise than in the three-visit program's fusions); a row no slot
+    names, the dead row under repeated zero-gradient slots and
+    everything an index past the block would touch stay bit for bit
+    what they were."""
+    rng = np.random.default_rng(dim)
+    table = rng.normal(size=(R, dim)).astype(np.float32)
+    acc = (0.1 + rng.random((R, dim))).astype(np.float32)
+    ids, grad, named = slots(case, rng, dim)
+    got_t, got_a = jax.jit(sparse_adagrad_apply, static_argnums=4)(
+        table, acc, ids, grad, LR)
+    want_t, want_a = jax.jit(three_visits, static_argnums=4)(
+        table, acc, ids, grad, LR)
+    got_t, got_a = np.asarray(got_t), np.asarray(got_a)
+    np.testing.assert_array_equal(got_a, np.asarray(want_a))
+    want_t = np.asarray(want_t)
+    ulp = np.spacing(np.maximum(np.maximum(np.abs(table), np.abs(want_t)),
+                                np.abs(want_t - table)))
+    assert (np.abs(got_t - want_t) <= 2 * ulp).all()
+    assert (got_t[named] != table[named]).any(axis=1).all()
+    assert (got_a[named] > acc[named]).all()
+    rest = np.setdiff1d(np.arange(R), named)
+    assert R - 1 in rest
+    assert got_t[rest].tobytes() == table[rest].tobytes()
+    assert got_a[rest].tobytes() == acc[rest].tobytes()
+
+
+def test_the_eager_call_is_the_jitted_one():
+    rng = np.random.default_rng(1)
+    table = rng.normal(size=(R, 5)).astype(np.float32)
+    acc = np.full((R, 5), 0.1, np.float32)
+    ids, grad, _ = slots("pad_slots", rng, 5)
+    eager = sparse_adagrad_apply(jnp.asarray(table), jnp.asarray(acc),
+                                 jnp.asarray(ids), jnp.asarray(grad), LR)
+    jitted = jax.jit(sparse_adagrad_apply, static_argnums=4)(
+        table, acc, ids, grad, LR)
+    for a, b in zip(eager, jitted):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("transform", ["grad", "vmap"])
+def test_no_rule_for_autodiff_or_batching_fails_by_name(transform):
+    table, acc = jnp.ones((8, 3)), jnp.ones((8, 3))
+    ids, grad = jnp.arange(4, dtype=jnp.int32), jnp.ones((4, 3))
+    if transform == "grad":
+        f = jax.grad(lambda t: pair_scatter_add(t, acc, ids, grad, grad)[0]
+                     .sum())
+        args = (table,)
+    else:
+        f = jax.vmap(lambda g: pair_scatter_add(table, acc, ids, g, g))
+        args = (jnp.ones((2, 4, 3)),)
+    # jax's one-operand scatter-add has both rules: the name in the
+    # error is this primitive's (ops/pair_scatter.py says why it is)
+    with pytest.raises(NotImplementedError, match="'scatter-add'"):
+        f(*args)
+
+
+def test_four_steps_equal_the_three_visit_formulas_four_steps():
+    """``TrainStep`` against the same step with the update written out
+    as three operations, on four different batches."""
+    spec = ModelSpec.from_config(CFG)
+
+    def plain(table, acc, labels, weights, uniq_ids, local_idx, vals):
+        loss, _, grad = grad_body(spec, gather_rows(table, uniq_ids),
+                                  labels, weights, uniq_ids, local_idx,
+                                  vals)
+        return (*three_visits(table, acc, uniq_ids, grad,
+                              spec.learning_rate), loss)
+    plain = jax.jit(plain)
+    step = TrainStep(spec)
+    rng = np.random.default_rng(8)
+    table, acc = init_table(CFG, seed=2), init_accumulator(CFG)
+    want_t, want_a = init_table(CFG, seed=2), init_accumulator(CFG)
+    for _ in range(4):
+        lines = [f"{int(rng.integers(0, 2))} " + " ".join(
+            f"{i}:{rng.random() + 0.1:.3f}"
+            for i in rng.choice(V, size=int(rng.integers(1, 5)),
+                                replace=False)) for _ in range(4)]
+        args = batch_args(make_device_batch(parse_lines(lines, V), CFG))
+        table, acc, loss, _ = step(table, acc, **args)
+        want_t, want_a, want_loss = plain(want_t, want_a, **args)
+        assert float(loss) == pytest.approx(float(want_loss), rel=1e-6)
+    np.testing.assert_allclose(np.asarray(table), np.asarray(want_t),
+                               rtol=1e-6, atol=1e-7)
+    np.testing.assert_allclose(np.asarray(acc), np.asarray(want_a),
+                               rtol=1e-6, atol=1e-7)
+    assert (np.asarray(acc) != np.asarray(init_accumulator(CFG))).any()
+
+
+def test_the_mesh_step_drops_a_slot_whose_row_another_shard_holds():
+    """Four virtual devices, 2,048 rows a shard. Segment 0 of the feed
+    names a row of shard 1 (a feed cut wrongly: the step cannot see
+    it): its update is dropped, table and accumulator row bit for bit
+    as they were; nothing clamps the index onto shard 0's last row any
+    more, which stays as it was too, and the rows in their own
+    segments move."""
+    from fast_tffm_tpu.parallel.sharded import (make_mesh,
+                                                make_sharded_train_step,
+                                                shard_batch)
+    spec = ModelSpec(model_type="fm", order=2, factor_num=4, field_num=0,
+                     vocabulary_size=8191, loss_type="logistic",
+                     factor_lambda=1e-4, bias_lambda=1e-4,
+                     learning_rate=0.1, kernel="xla", dedup="host")
+    mesh = make_mesh(jax.devices()[:4], model_axis=1)
+    rng = np.random.default_rng(38)
+    B, L, U, shard = 16, 4, 256, 2048
+    seg = U // 4
+    uniq = np.full(U, 8191, np.int32)
+    for s in range(4):
+        uniq[s * seg:s * seg + 8] = s * shard + rng.choice(
+            shard - 1, size=8, replace=False)
+    stray = shard + 77
+    assert stray not in uniq
+    uniq[8] = stray                         # segment 0, shard 1's row
+    real = np.flatnonzero(uniq != 8191)
+    local_idx = rng.choice(real, size=(B, L)).astype(np.int32)
+    local_idx[0, 0] = 8                     # the stray slot has a gradient
+    table0 = rng.normal(scale=0.1, size=(8192, spec.row_dim)).astype(
+        np.float32)
+    acc0 = np.full((8192, spec.row_dim), 0.1, np.float32)
+    step = make_sharded_train_step(spec, mesh)
+    table, acc, loss, _ = step(
+        jnp.asarray(table0), jnp.asarray(acc0), **shard_batch(
+            mesh, labels=(rng.random(B) < 0.5).astype(np.float32),
+            weights=np.ones(B, np.float32), uniq_ids=uniq,
+            local_idx=local_idx,
+            vals=(rng.random((B, L)) + 0.1).astype(np.float32)))
+    table, acc = np.asarray(table), np.asarray(acc)
+    assert np.isfinite(float(loss))
+    for row in (stray, shard - 1):
+        assert table[row].tobytes() == table0[row].tobytes()
+        assert acc[row].tobytes() == acc0[row].tobytes()
+    moved = np.setdiff1d(uniq[local_idx], [stray])
+    assert (acc[moved] > acc0[moved]).any(axis=1).all()
+    assert (table[moved] != table0[moved]).any(axis=1).all()
+    # (the regulariser alone moves a row that no cell names)
+    rest = np.setdiff1d(np.arange(8192), np.setdiff1d(uniq[real], [stray]))
+    assert table[rest].tobytes() == table0[rest].tobytes()
+    assert acc[rest].tobytes() == acc0[rest].tobytes()
