@@ -105,8 +105,10 @@ def run_meta(cfg, kind: str, process_index: Optional[int] = None,
              process_count: Optional[int] = None) -> Dict[str, Any]:
     """Run metadata stamped into every metrics event: config hash,
     backend, the first device's platform and kind as jax reports them
-    (what a reader needs to tell a chip run from a CPU run),
-    device/process topology, git rev. ``process_index`` /
+    (what a reader needs to tell a chip run from a CPU run), the model
+    (``model_type``, ``order``, ``factor_num``: an order-3 run's step
+    holds a scan an order-2 run's does not), device/process topology,
+    git rev. ``process_index`` /
     ``process_count`` override jax's view — the train driver creates
     telemetry BEFORE the cluster join (so bring-up failures land in
     the stream), when jax would still claim a 1-process local world on
@@ -121,6 +123,9 @@ def run_meta(cfg, kind: str, process_index: Optional[int] = None,
     return {
         "kind": kind,
         "config_hash": config_hash(cfg) if cfg is not None else None,
+        "model": None if cfg is None else {
+            "model_type": cfg.model_type, "order": cfg.order,
+            "factor_num": cfg.factor_num},
         "backend": jax.default_backend(),
         "platform": dev.platform,
         "device_kind": dev.device_kind,

@@ -82,17 +82,31 @@ def _anova_terms(z: jax.Array, order: int) -> jax.Array:
     """Sum of ANOVA kernels of degree 2..order, all latent dims.
 
     Classic DP (a_new[t] = a[t] + a[t-1]*z_j) run as a ``lax.scan`` over
-    the L feature slots — static trip count, TPU-friendly; padded slots
-    have z_j = 0 and leave the state unchanged. O(L * order * k).
+    the L feature slots — static trip count, O(L * order * k), L
+    sequential steps forward and L backward (autodiff keeps the L
+    carries ``[B, order+1, k]``). Scope ``anova_scan``, nested inside
+    ``interaction``: both names ride the scan's op paths, forward and
+    backward, so ``interaction_ms`` still holds it and
+    benchmarks/readers/op_scope_device_ms.py reads the scan alone.
+
+    What a pad slot must look like: ``z_j = 0`` in every factor, which
+    ``fm_batch_scores`` gives it by ``vals == 0`` whatever row the slot
+    indexes. The step is then ``a[t] + a[t-1] * 0``: the state passes
+    unchanged and the slot's gradient w.r.t. ``z_j`` is multiplied by
+    the slot's value on its way to the row, so score and row gradients
+    are those of the line without it. A pad slot of any other form (a
+    masked lane, an index past U read as NaN or as a fill that is not
+    finite) is NOT neutral here: ``0 * nan`` poisons every later slot.
     """
-    B, L, k = z.shape
-    a0 = jnp.zeros((B, order + 1, k), dtype=z.dtype).at[:, 0].set(1.0)
+    with jax.named_scope("anova_scan"):
+        B, L, k = z.shape
+        a0 = jnp.zeros((B, order + 1, k), dtype=z.dtype).at[:, 0].set(1.0)
 
-    def step(a, z_j):                              # z_j: [B, k]
-        return a.at[:, 1:].add(a[:, :-1] * z_j[:, None, :]), None
+        def step(a, z_j):                              # z_j: [B, k]
+            return a.at[:, 1:].add(a[:, :-1] * z_j[:, None, :]), None
 
-    a, _ = lax.scan(step, a0, jnp.moveaxis(z, 1, 0))
-    return a[:, 2:].sum(axis=(1, 2))
+        a, _ = lax.scan(step, a0, jnp.moveaxis(z, 1, 0))
+        return a[:, 2:].sum(axis=(1, 2))
 
 
 def ffm_batch_scores(params: jax.Array, field_num: int,

@@ -262,3 +262,50 @@ def test_empty_example_scores_zero(rng):
     got = np.asarray(fm_batch_scores(gather_rows(table, b.uniq_ids),
                                      b.local_idx, b.vals))
     assert np.all(got[1:] == 0.0)
+
+
+@pytest.mark.parametrize("where", ["trailing", "leading", "between"])
+@pytest.mark.parametrize("row", ["the dead row", "a live row", "a cell's own row"])
+def test_order3_pad_slot_of_any_row_and_value_0_changes_nothing(rng, where,
+                                                                row):
+    """What ``_anova_terms`` asks of a pad slot is ``z_j = 0``: value 0,
+    whatever row the slot indexes and wherever it sits in the line. The
+    scan's state passes such a slot unchanged (``a[t] + a[t-1] * 0``),
+    so the kernels are those of the line without it to the bit, and
+    score and row gradient to the rounding of the linear term's sum
+    over a longer line. Changes to how pad cells are shipped (PERF.md section 7: an
+    index past U, a second rectangle, flat cells) go through here."""
+    B, n, pads, U, k = 4, 6, 3, 12, K
+    params = jnp.asarray(rng.normal(size=(U, k + 1)) * 0.3, jnp.float32)
+    params = params.at[-1].set(0.0)
+    idx = rng.integers(0, U - 1, size=(B, n)).astype(np.int32)
+    vals = rng.normal(size=(B, n)).astype(np.float32)
+    pad_row = {"the dead row": U - 1, "a live row": 3,
+               "a cell's own row": int(idx[0, 0])}[row]
+    at = {"trailing": n, "leading": 0, "between": n // 2}[where]
+    idx_p = np.insert(idx, [at] * pads, pad_row, axis=1)
+    vals_p = np.insert(vals, [at] * pads, 0.0, axis=1)
+    ds = jnp.asarray(rng.normal(size=B), jnp.float32)
+
+    def f(p, i, v):
+        s = fm_batch_scores(p, jnp.asarray(i), jnp.asarray(v), order=3)
+        return (s * ds).sum(), s
+
+    (_, s0), g0 = jax.value_and_grad(f, has_aux=True)(params, idx, vals)
+    (_, s1), g1 = jax.value_and_grad(f, has_aux=True)(params, idx_p, vals_p)
+    np.testing.assert_allclose(np.asarray(s1), np.asarray(s0), rtol=1e-6,
+                               atol=1e-7)
+    np.testing.assert_allclose(np.asarray(g1), np.asarray(g0), rtol=1e-6,
+                               atol=1e-7)
+    assert np.abs(np.asarray(g0)).max() > 0
+    from fast_tffm_tpu.ops.interaction import _anova_terms
+    z, z_p = (params[jnp.asarray(i), :-1] * jnp.asarray(v)[..., None]
+              for i, v in ((idx, vals), (idx_p, vals_p)))
+    np.testing.assert_array_equal(np.asarray(_anova_terms(z_p, 3)),
+                                  np.asarray(_anova_terms(z, 3)))
+    # a slot that is NOT neutral, for contrast: value 0 is what counts
+    vals_bad = vals_p.copy()
+    vals_bad[:, at] = 0.5
+    (_, s2), _ = jax.value_and_grad(f, has_aux=True)(params, idx_p, vals_bad)
+    if row != "the dead row":
+        assert np.abs(np.asarray(s2) - np.asarray(s0)).max() > 1e-4

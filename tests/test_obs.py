@@ -176,6 +176,16 @@ def test_run_meta_fields(tmp_path):
             != meta["config_hash"])
 
 
+def test_run_meta_says_the_model_and_its_order():
+    """The header of every event names the model: an order-3 run's step
+    holds a scan an order-2 run's does not (ISSUE 41)."""
+    assert run_meta(FmConfig(), "train")["model"] == {
+        "model_type": "fm", "order": 2, "factor_num": FmConfig().factor_num}
+    assert run_meta(FmConfig(order=3, factor_num=8), "train")["model"] == {
+        "model_type": "fm", "order": 3, "factor_num": 8}
+    assert run_meta(None, "serve")["model"] is None
+
+
 def test_flush_cadence_writes_metrics_events(tmp_path):
     path = str(tmp_path / "m.jsonl")
     tel = RunTelemetry(path, meta={"kind": "t"}, flush_steps=2)
