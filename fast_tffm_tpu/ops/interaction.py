@@ -13,6 +13,17 @@ functions *is* the ``fm_grad`` equivalent (a hand-fused Pallas version with
 a custom VJP lives in ops/pallas_fm.py). Padding contributes exactly zero
 because padded ``vals`` are 0 and every term carries an ``x_j`` factor.
 
+One rule for both scorers: the expanded rows ``[B, L, D]`` are consumed
+whole. A TPU tiles an array's last two dimensions (8, 128), so the
+gather lays a row of 9, 17 or 89 columns on a 128-lane line, and an
+array with 1 or k as the minor dimension of a ``[B, L, ...]`` shape (the
+w column, the factor columns, sliced off the rows) is a full pass over
+those padded lines to make, another to re-lay, and pads up to 128-fold
+itself. Slices are taken of the per-example sums ``[B, D]``, or where
+the column axis is a major one; the row gradient is born ``[B, L, D]``,
+the shape ``expand_rows``' segment-sum takes (PERF.md section 6, PR 30
+for FFM, PR 42 for FM).
+
 Shapes: ``params`` are the batch's gathered unique rows ``[U, D]``
 (D = k+1 for FM, field_num*k+1 for FFM); ``local_idx [B, L]`` indexes
 into them; ``vals [B, L]``.
@@ -65,33 +76,63 @@ def expand_rows(params: jax.Array, local_idx: jax.Array) -> jax.Array:
 def fm_batch_scores(params: jax.Array, local_idx: jax.Array,
                     vals: jax.Array, order: int = 2) -> jax.Array:
     """Per-example FM scores. order==2 uses the (Σv)²−Σv² identity; order>2
-    adds ANOVA-kernel terms of degree 2..order (BASELINE config #4)."""
+    adds ANOVA-kernel terms of degree 2..order (BASELINE config #4).
+
+    The expanded rows ``[B, L, D]`` (D = k+1) are consumed WHOLE (the
+    module's rule): no array here is a minor-axis slice of ``[B, L, D]``
+    and none has 1 or k as the minor dimension of a ``[B, L, ...]``
+    shape. ``expand_rows``' gather writes a row a cell, 9 or 17 columns
+    on a 128-lane line: 512 B a cell, 403 MB at ``[8192, 96, 9]`` for
+    28 MB of numbers. ``rows[..., -1]`` reads all of that to write one
+    number a line, another 403 MB, and w and v sliced apart are each
+    re-laid batch-minor: on the v5e that slice and second copy were
+    0.73 ms of FM's 8.60 ms step and 1.85 / 2.08 of the bags cells'
+    15.75 / 17.50 (PERF.md section 6, PR 42). So the slices are taken
+    where they cost nothing:
+
+    - order 2: ``s = Σ_l rows·x`` and ``q = Σ_l rows²·x²`` over all D
+      columns, ``[B, D]``; the linear term is ``s[:, -1]``, the pair
+      term is over the other k columns. The w column's square sum is
+      computed and thrown away (a D-th of one pass).
+    - order > 2: ``z = rows·x`` is re-laid ONCE to ``[L, D, B]`` (the
+      batch on the lanes, D on the sublanes), where the w column is a
+      major-axis slice: the linear term is its sum over L, and the scan
+      takes the k factor columns ``[L, k, B]``. (The w column riding
+      the scan as a ninth factor pads the carries from 8 sublanes to 16
+      and the scan takes 1.8 times as long; same section.)
+
+    Either way the compiled step holds one layout copy of ``[B, L, D]``
+    forward and one backward, and the row gradient is born ``[B, L, D]``,
+    the shape ``expand_rows``' segment-sum takes
+    (tests/test_state_layout.py compiles it for a described v5e)."""
     rows = expand_rows(params, local_idx)         # [B, L, k+1]
     with jax.named_scope("interaction"):
-        v, w = rows[..., :-1], rows[..., -1]
-        linear = jnp.einsum("bl,bl->b", w, vals, precision=_F32)
-        z = v * vals[..., None]                   # [B, L, k]
         if order == 2:
-            s = z.sum(axis=1)                     # [B, k]
-            q = jnp.square(z).sum(axis=1)
-            return linear + 0.5 * (jnp.square(s) - q).sum(axis=-1)
-        return linear + _anova_terms(z, order)
+            s = jnp.einsum("bld,bl->bd", rows, vals, precision=_F32)
+            q = jnp.einsum("bld,bl->bd", jnp.square(rows), jnp.square(vals),
+                           precision=_F32)
+            return s[:, -1] + 0.5 * (jnp.square(s[:, :-1])
+                                     - q[:, :-1]).sum(axis=-1)
+        z = jnp.transpose(rows * vals[..., None], (1, 2, 0))   # [L, k+1, B]
+        return z[:, -1].sum(axis=0) + _anova_terms(z[:, :-1], order)
 
 
 def _anova_terms(z: jax.Array, order: int) -> jax.Array:
-    """Sum of ANOVA kernels of degree 2..order, all latent dims.
+    """Sum of ANOVA kernels of degree 2..order, all latent dims, of
+    ``z [L, k, B]`` (slot-major, the batch minor).
 
     Classic DP (a_new[t] = a[t] + a[t-1]*z_j) run as a ``lax.scan`` over
     the L feature slots — static trip count, O(L * order * k), L
     sequential steps forward and L backward (autodiff keeps the L
-    carries ``[B, order+1, k]``). Scope ``anova_scan``, nested inside
+    carries ``[order+1, k, B]``). Scope ``anova_scan``, nested inside
     ``interaction``: both names ride the scan's op paths, forward and
     backward, so ``interaction_ms`` still holds it and
     benchmarks/readers/op_scope_device_ms.py reads the scan alone.
 
     What a pad slot must look like: ``z_j = 0`` in every factor, which
     ``fm_batch_scores`` gives it by ``vals == 0`` whatever row the slot
-    indexes. The step is then ``a[t] + a[t-1] * 0``: the state passes
+    indexes (in the w column too, whose sum is the linear term). The
+    step is then ``a[t] + a[t-1] * 0``: the state passes
     unchanged and the slot's gradient w.r.t. ``z_j`` is multiplied by
     the slot's value on its way to the row, so score and row gradients
     are those of the line without it. A pad slot of any other form (a
@@ -99,14 +140,14 @@ def _anova_terms(z: jax.Array, order: int) -> jax.Array:
     finite) is NOT neutral here: ``0 * nan`` poisons every later slot.
     """
     with jax.named_scope("anova_scan"):
-        B, L, k = z.shape
-        a0 = jnp.zeros((B, order + 1, k), dtype=z.dtype).at[:, 0].set(1.0)
+        L, k, B = z.shape
+        a0 = jnp.zeros((order + 1, k, B), dtype=z.dtype).at[0].set(1.0)
 
-        def step(a, z_j):                              # z_j: [B, k]
-            return a.at[:, 1:].add(a[:, :-1] * z_j[:, None, :]), None
+        def step(a, z_j):                              # z_j: [k, B]
+            return a.at[1:].add(a[:-1] * z_j), None
 
-        a, _ = lax.scan(step, a0, jnp.moveaxis(z, 1, 0))
-        return a[:, 2:].sum(axis=(1, 2))
+        a, _ = lax.scan(step, a0, z)
+        return a[2:].sum(axis=(0, 1))
 
 
 def ffm_batch_scores(params: jax.Array, field_num: int,

@@ -30,6 +30,12 @@ measured single-chip — the sharded-assembly regime itself has no
 direct measurement — so a cluster operator who measures otherwise can
 still force ``kernel = pallas`` (it runs under shard_map).
 
+The XLA column also predates PR 42: ``fm_batch_scores`` then sliced
+the w column off the expanded rows and re-laid w and v apart, which on
+the v5e cost the forward a slice and a second layout copy (0.73 ms at
+``[8192, 40, 17]``; PERF.md section 6, PR 42). The XLA path it names
+is faster now than when these pairs were taken; the rule is as it was.
+
 Re-measure with ``python tools/kernel_probe.py`` (interleaved A/B at
 your shapes) and,
 if the regime boundary moved, override per job with ``kernel =

@@ -299,8 +299,9 @@ def test_order3_pad_slot_of_any_row_and_value_0_changes_nothing(rng, where,
                                atol=1e-7)
     assert np.abs(np.asarray(g0)).max() > 0
     from fast_tffm_tpu.ops.interaction import _anova_terms
-    z, z_p = (params[jnp.asarray(i), :-1] * jnp.asarray(v)[..., None]
-              for i, v in ((idx, vals), (idx_p, vals_p)))
+    z, z_p = (jnp.transpose(params[jnp.asarray(i), :-1]
+                            * jnp.asarray(v)[..., None], (1, 2, 0))
+              for i, v in ((idx, vals), (idx_p, vals_p)))   # [L, k, B]
     np.testing.assert_array_equal(np.asarray(_anova_terms(z_p, 3)),
                                   np.asarray(_anova_terms(z, 3)))
     # a slot that is NOT neutral, for contrast: value 0 is what counts
@@ -309,3 +310,87 @@ def test_order3_pad_slot_of_any_row_and_value_0_changes_nothing(rng, where,
     (_, s2), _ = jax.value_and_grad(f, has_aux=True)(params, idx_p, vals_bad)
     if row != "the dead row":
         assert np.abs(np.asarray(s2) - np.asarray(s0)).max() > 1e-4
+
+
+# ---- the expanded rows kept whole (ISSUE 42) --------------------------------
+
+def _row_a_cell(rng, D, B=5, n=7, pads=3):
+    """A batch whose every cell has a row of its own (``params[b*L + l]``
+    IS the row of cell (b, l), so the gradient w.r.t. ``params`` is the
+    row gradient ``[B, L, D]``), real-valued cells, and ``pads`` cells a
+    line of value 0 at any index, each on a live, non-zero row."""
+    L = n + pads
+    rows = (rng.normal(size=(B, L, D)) * 0.3).astype(np.float32)
+    vals = rng.normal(size=(B, L)).astype(np.float32)
+    pad = np.zeros((B, L), bool)
+    for b in range(B):
+        pad[b, rng.choice(L, size=pads, replace=False)] = True
+    vals[pad] = 0.0
+    idx = np.arange(B * L, dtype=np.int32).reshape(B, L)
+    cot = rng.normal(size=B).astype(np.float32)   # d loss / d score
+    return rows, idx, vals, pad, cot
+
+
+def _oracle_scores_and_row_grads(rows, vals, pad, cot, order, eps=1e-5):
+    """``oracle.fm_score`` at float64 on each line WITHOUT its pad cells,
+    and the gradient of ``cot_b * score_b`` w.r.t. every cell's row by
+    central differences (the score is a polynomial of degree <= order
+    in a row's entries, so the step's error is ~eps^2)."""
+    B, L, D = rows.shape
+    scores, grads = np.zeros(B), np.zeros((B, L, D))
+    for b in range(B):
+        table = rows[b].astype(np.float64)
+        ids = np.flatnonzero(~pad[b])
+        x = vals[b, ids].astype(np.float64)
+        scores[b] = oracle.fm_score(table, ids, x, order=order)
+        for l in range(L):
+            for d in range(D):
+                hi, lo = table.copy(), table.copy()
+                hi[l, d] += eps
+                lo[l, d] -= eps
+                grads[b, l, d] = cot[b] * (
+                    oracle.fm_score(hi, ids, x, order=order)
+                    - oracle.fm_score(lo, ids, x, order=order)) / (2 * eps)
+    return scores, grads
+
+
+@pytest.mark.parametrize("D", [9, 17])
+@pytest.mark.parametrize("order", [2, 3])
+def test_whole_rows_scores_and_row_gradients_match_the_oracle(rng, order, D):
+    """``fm_batch_scores`` takes the linear term from the last column of
+    per-example sums over whole rows, never from ``rows[..., -1]``
+    (ISSUE 42). So, beside scores and row gradients against the oracle:
+    the w column of the row gradient is exactly ``g_b * x_bl`` (the
+    pair and ANOVA terms leave that column out of their sums and give
+    it nothing, not a rounding's worth), a pad cell's gradient is
+    exactly zero in every column though its row is not, and the same
+    lines at a wider rung score the same."""
+    rows, idx, vals, pad, cot = _row_a_cell(rng, D)
+    B, L, _ = rows.shape
+
+    def f(p, i, v):
+        s = fm_batch_scores(p, jnp.asarray(i), jnp.asarray(v), order=order)
+        return (s * cot).sum(), s
+
+    (_, got_s), got_g = jax.value_and_grad(f, has_aux=True)(
+        jnp.asarray(rows.reshape(B * L, D)), idx, vals)
+    got_s, got_g = np.asarray(got_s), np.asarray(got_g).reshape(B, L, D)
+    want_s, want_g = _oracle_scores_and_row_grads(rows, vals, pad, cot,
+                                                  order)
+    np.testing.assert_allclose(got_s, want_s, rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(got_g, want_g, rtol=1e-5,
+                               atol=1e-5 * float(np.abs(want_g).max()))
+    np.testing.assert_array_equal(got_g[..., -1], cot[:, None] * vals)
+    assert np.abs(rows[pad]).min() > 0.0
+    np.testing.assert_array_equal(got_g[pad], 0.0)
+    assert np.abs(got_g[~pad]).min() > 0.0
+    # the same lines at a wider rung: five more cells of value 0, each
+    # on a live row
+    wide_i = np.concatenate([idx, np.tile(idx[:, :1], (1, 5))], axis=1)
+    wide_v = np.concatenate([vals, np.zeros((B, 5), np.float32)], axis=1)
+    (_, wide_s), wide_g = jax.value_and_grad(f, has_aux=True)(
+        jnp.asarray(rows.reshape(B * L, D)), wide_i, wide_v)
+    np.testing.assert_allclose(np.asarray(wide_s), got_s, rtol=1e-6,
+                               atol=1e-7)
+    np.testing.assert_allclose(np.asarray(wide_g).reshape(B, L, D), got_g,
+                               rtol=1e-6, atol=1e-7)

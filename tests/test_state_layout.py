@@ -8,6 +8,7 @@ object to drive the re-lay path; the compile-only tests at the bottom
 ask the TPU's own compiler, for a described v5e, what it chooses at the
 benchmark's two one-chip sizes."""
 
+import contextlib
 import dataclasses
 import os
 import re
@@ -318,6 +319,20 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@contextlib.contextmanager
+def _no_persistent_cache():
+    """A program compiled for a described chip can be written to the
+    persistent cache but not read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
+
+
 # The two one-chip cells of BENCHMARK.json: rows, batch, rung, and the
 # slots a batch needs (22 fields ride the 24 rung and 39 features the
 # 40 rung since PR 34; since PR 36 their 9.0k and 19.3k distinct rows
@@ -359,7 +374,6 @@ def test_v5e_step_has_no_copy_of_the_whole_state(one_chip, cell):
     17 columns the layout the runtime gives the state anyway (nothing
     to re-lay), for FFM's 89 another one; ``adagrad`` is a gather and
     one two-operand scatter. A compile says nothing of times."""
-    from jax.experimental.compilation_cache import compilation_cache
     spec, B, L, need, U = CELLS[cell]
     assert L == _ladder_fit(spec.field_num or 39, FmConfig().bucket_ladder)
     assert U == _fit_slots(need, B, L, fixed_shape=False, uniq_bucket=0)
@@ -373,15 +387,8 @@ def test_v5e_step_has_no_copy_of_the_whole_state(one_chip, cell):
              sd((B, L), jnp.float32),
              sd((B, L), jnp.int32) if spec.model_type == "ffm" else None)
     step = TrainStep(spec)
-    # A program compiled for a described chip can be written to the
-    # persistent cache but not read back without one.
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
+    with _no_persistent_cache():
         text = step.compile(state, state, *batch).as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", True)
-        compilation_cache.reset_cache()
     copies = re.findall(rf"= f32\[{rows},{dim}\]\S* copy\(", text)
     assert not copies, copies
     alias = re.search(r"input_output_alias=\{(.*?)\}, \w+=", text).group(1)
@@ -411,7 +418,6 @@ def test_v5e_second_width_takes_the_firsts_layout(one_chip, widths):
     the other width too: the runtime's own), and neither program copies
     the whole state, breaks the aliasing of its two state arguments or
     walks the slots more than twice in ``adagrad``."""
-    from jax.experimental.compilation_cache import compilation_cache
     spec, B, U = BAGS
     ladder = FmConfig().bucket_ladder
     assert all(w in ladder for w in widths)
@@ -421,16 +427,11 @@ def test_v5e_second_width_takes_the_firsts_layout(one_chip, widths):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     state = sd((rows, dim), jnp.float32)
     step = TrainStep(spec)
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
+    with _no_persistent_cache():
         texts = [step.compile(
             state, state, sd((B,), jnp.float32), sd((B,), jnp.float32),
             sd((U,), jnp.int32), sd((B, L), jnp.int32),
             sd((B, L), jnp.float32), None).as_text() for L in widths]
-    finally:
-        jax.config.update("jax_enable_compilation_cache", True)
-        compilation_cache.reset_cache()
     device = one_chip._device_assignment[0]
     assert step._layout == Layout.from_pjrt_layout(
         device.client.get_default_layout(jnp.dtype(jnp.float32),
@@ -442,3 +443,57 @@ def test_v5e_second_width_takes_the_firsts_layout(one_chip, widths):
                           text).group(1)
         assert "{0}: (0, {}" in alias and "{1}: (1, {}" in alias, alias
         _adagrad_walks_the_slots_twice(text, rows, dim)
+
+
+# ---- fm_batch_scores keeps the expanded rows whole (ISSUE 42) --------------
+
+# (order, B, L, U, D) of the three one-chip FM cells: fm16-train-zipf,
+# and the two widths of fm8-train-bags and fm3-train-bags.
+WHOLE_ROWS = [(2, 8192, 40, 20480, 17), (2, 8192, 96, 28672, 9),
+              (2, 8192, 112, 28672, 9), (3, 8192, 96, 28672, 9),
+              (3, 8192, 112, 28672, 9)]
+
+
+@pytest.mark.parametrize("order,B,L,U,D", WHOLE_ROWS)
+def test_v5e_fm_grad_keeps_the_expanded_rows_whole(one_chip, order, B, L,
+                                                   U, D):
+    """The gradient of a logistic loss through ``fm_batch_scores``,
+    compiled for a described v5e at the cells' sizes: nothing slices
+    the ``[B, L, D]`` rows a 128-lane line holds 9 or 17 to, no array
+    has the w column alone (``f32[B,L,1]``) or, at order 2, the k
+    factor columns alone as the minor dimension of a ``[B, L, ...]``
+    shape, and the rows are re-laid once forward and once backward.
+    The function as it was until PR 42 (``w = rows[..., -1]``, ``v =
+    rows[..., :-1]``) fails all five cases on each count: a slice of
+    ``f32[B,L,D]`` to ``f32[B,L,1]``, a copy of it, a copy of
+    ``f32[B,L,k]`` and the backward copy: 0.73 ms of 8.60 a step on the
+    chip at ``[8192, 40, 17]``, 1.85 of 15.75 at ``[8192, 96, 9]``. A
+    compile says nothing of times."""
+    from fast_tffm_tpu.ops.interaction import fm_batch_scores
+
+    def loss(params, local_idx, vals, labels):
+        s = fm_batch_scores(params, local_idx, vals, order=order)
+        return (jax.nn.softplus(s) - labels * s).sum()
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    with _no_persistent_cache():
+        text = jax.jit(jax.grad(loss)).lower(
+            sd((U, D), jnp.float32), sd((B, L), jnp.int32),
+            sd((B, L), jnp.float32), sd((B,), jnp.float32)
+        ).compile().as_text()
+    rows = f"f32[{B},{L},{D}]"
+    assert text.count(rows) > 4, "the expanded rows are in the program"
+    shape_of = dict(re.findall(r"%(\S+) = (\w+\[[\d,]*\])", text))
+    sliced = [ln.strip()[:160] for ln in text.splitlines()
+              for m in [re.search(r" (?:dynamic-)?slice\(%([^,)\s]+)", ln)]
+              if m and shape_of.get(m.group(1)) == rows]
+    assert not sliced, sliced
+    assert f"f32[{B},{L},1]" not in text
+    if order == 2:
+        assert f"f32[{B},{L},{D - 1}]" not in text
+    copies = [ln.strip() for ln in text.splitlines()
+              if re.search(rf"= f32\[{B},{L},\d+\]\S* copy\(", ln)]
+    backward = [ln for ln in copies if "transpose(jvp(" in ln]
+    assert len(backward) <= 1 and len(copies) - len(backward) <= 1, \
+        [ln[:160] for ln in copies]
