@@ -103,6 +103,16 @@ class SpillStats:
 # an undersized uniq_bucket and train() warns with the fix.
 SPILL_WARN_FRACTION = 0.1
 
+# The prefix a plane's counters and gauges carry in the run's telemetry
+# (batches, examples, feature_nnz, feature_slots, worker_build_seconds,
+# ...; ``batch_iterator(counters=)``). A validation sweep inside a
+# training run opens a plane of its own every epoch: its batches count
+# under their own names, so that what reads ``pipeline/*`` (fmstat's
+# fill and build rows, the benchmark's cell_fill and
+# host_build_s_per_batch) reads the training plane alone.
+TRAIN_PLANE = "pipeline"
+VALIDATION_PLANE = "validation_plane"
+
 
 def require_bounded_examples(cfg: FmConfig, context: str) -> None:
     """Fixed-shape (multi-process) modes cap L at the ladder top; an
@@ -1051,7 +1061,8 @@ class _BuildRing:
     leaks the pool."""
 
     def __init__(self, workers: int, depth: int, work,
-                 make_state=None):
+                 make_state=None, counters: str = TRAIN_PLANE):
+        self._build_seconds = counters + "/worker_build_seconds"
         self._lock = threading.Lock()
         self._cv = threading.Condition(self._lock)
         self._tasks: collections.deque = collections.deque()
@@ -1162,7 +1173,7 @@ class _BuildRing:
                         with span("pipeline/build_worker"):
                             res = ("ok", self._work(state, payload))
                         # fmlint: disable=R003 -- closes the sample
-                        tel.count("pipeline/worker_build_seconds",
+                        tel.count(self._build_seconds,
                                   _time.perf_counter() - t0)
                 except BaseException as e:  # delivered at wait(seq)
                     res = ("error", e)
@@ -1367,7 +1378,8 @@ def _parallel_fast_batch_iterator(cfg: FmConfig, files: List[str],
                                   raw_ids: bool, keep_empty: bool,
                                   workers: int,
                                   file_marks: Optional[FileMarks] = None,
-                                  row_shards: Optional[RowShards] = None
+                                  row_shards: Optional[RowShards] = None,
+                                  counters: str = TRAIN_PLANE
                                   ) -> Iterator[DeviceBatch]:
     """Parallel host data plane, fast path: parse+hash+dedup+pack fans
     out across ``workers`` pool threads — each owning its own C++
@@ -1410,10 +1422,11 @@ def _parallel_fast_batch_iterator(cfg: FmConfig, files: List[str],
     ring = _BuildRing(workers, depth=2 * workers,
                       work=_fast_group_work,
                       make_state=lambda: _FastWorkerState(
-                          make_builder, emitter.finish))
+                          make_builder, emitter.finish),
+                      counters=counters)
     tel = active()
     if tel is not None:
-        tel.set("pipeline/host_threads", workers)
+        tel.set(counters + "/host_threads", workers)
     ahead = None
     try:
         for epoch in range(n_epochs):
@@ -1456,7 +1469,7 @@ def _parallel_fast_batch_iterator(cfg: FmConfig, files: List[str],
                 g = inflight.pop(s)
                 kind, payload = ring.wait(s)
                 if tel is not None:
-                    tel.set("pipeline/ring_occupancy",
+                    tel.set(counters + "/ring_occupancy",
                             ring.occupancy())
                 if kind == "error":
                     if isinstance(payload, ParseError):
@@ -1640,7 +1653,8 @@ def batch_iterator(cfg: FmConfig, files: Sequence[str],
                    bad_lines: Optional[BadLineTracker] = None,
                    file_marks: Optional[FileMarks] = None,
                    vocab=None,
-                   row_shards: Optional[RowShards] = None
+                   row_shards: Optional[RowShards] = None,
+                   counters: str = TRAIN_PLANE
                    ) -> Iterator[DeviceBatch]:
     """Epoch/shuffle/batch loop over text files (see _batch_iterator_impl
     for the full contract). This wrapper is the pipeline's telemetry
@@ -1664,7 +1678,10 @@ def batch_iterator(cfg: FmConfig, files: Sequence[str],
     table's rows; every batch's unique rows come ordered by owning
     shard (segment_plan; ``DeviceBatch.row_shards`` says so). Under
     ``vocab`` the rows are only known after the remap, which then
-    orders them itself (``vocab.row_shards``)."""
+    orders them itself (``vocab.row_shards``).
+
+    ``counters``: the prefix this plane's counts carry
+    (``TRAIN_PLANE``; a validation sweep's ``VALIDATION_PLANE``)."""
     from fast_tffm_tpu.obs.telemetry import active
     it = _batch_iterator_impl(cfg if vocab is None
                               else vocab.build_cfg(cfg), files,
@@ -1679,7 +1696,8 @@ def batch_iterator(cfg: FmConfig, files: Sequence[str],
                               raw_ids=raw_ids, bad_lines=bad_lines,
                               file_marks=file_marks,
                               row_shards=(row_shards if vocab is None
-                                          else None))
+                                          else None),
+                              counters=counters)
     tel = active()
     if tel is None:
         if vocab is None:
@@ -1709,7 +1727,8 @@ def batch_iterator(cfg: FmConfig, files: Sequence[str],
             batch = vocab.remap(batch)
         # fmlint: disable=R003 -- closes the build-seconds sample
         tel.pipeline_batch(batch, pad_id,
-                           build_seconds=_time.perf_counter() - t0)
+                           build_seconds=_time.perf_counter() - t0,
+                           prefix=counters)
         yield batch
 
 
@@ -1727,7 +1746,8 @@ def _batch_iterator_impl(cfg: FmConfig, files: Sequence[str],
                          raw_ids: bool = False,
                          bad_lines: Optional[BadLineTracker] = None,
                          file_marks: Optional[FileMarks] = None,
-                         row_shards: Optional[RowShards] = None
+                         row_shards: Optional[RowShards] = None,
+                         counters: str = TRAIN_PLANE
                          ) -> Iterator[DeviceBatch]:
     """Epoch/shuffle/batch loop over text files.
 
@@ -1818,7 +1838,8 @@ def _batch_iterator_impl(cfg: FmConfig, files: Sequence[str],
                     cfg, files, B, n_epochs, do_shuffle, seed,
                     fixed_shape, shard_index, num_shards, uniq_bucket,
                     stats, raw_ids, keep_empty, workers,
-                    file_marks=file_marks, row_shards=row_shards)
+                    file_marks=file_marks, row_shards=row_shards,
+                    counters=counters)
             else:
                 yield from _fast_batch_iterator(
                     cfg, bb, files, B, n_epochs, do_shuffle, seed,
@@ -1909,11 +1930,11 @@ def _batch_iterator_impl(cfg: FmConfig, files: Sequence[str],
                                      uniq_bucket=uniq_bucket,
                                      raw_ids=raw_ids, shards=row_shards)
         pool = _BuildRing(workers, depth=2 * workers,
-                          work=_pool_work)
+                          work=_pool_work, counters=counters)
         from fast_tffm_tpu.obs.telemetry import active as _active
         _tel = _active()
         if _tel is not None:
-            _tel.set("pipeline/host_threads", workers)
+            _tel.set(counters + "/host_threads", workers)
 
     def pool_drain(limit: int) -> Iterator[DeviceBatch]:
         """Yield completed pool batches in submit order: every
@@ -1926,7 +1947,7 @@ def _batch_iterator_impl(cfg: FmConfig, files: Sequence[str],
             s = pool_order.popleft()
             kind, val = pool.wait(s)
             if tel is not None:
-                tel.set("pipeline/ring_occupancy", pool.occupancy())
+                tel.set(counters + "/ring_occupancy", pool.occupancy())
             if kind == "error":
                 raise val
             if val is None:

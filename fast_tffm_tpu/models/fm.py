@@ -647,11 +647,15 @@ def score_body(spec: ModelSpec, table, uniq_ids, local_idx, vals,
     """Inference forward (gather -> scorer). Shared by the single-device
     and mesh-sharded score functions — single source of truth, like
     train_step_body. dedup='device': raw ids in ``local_idx``,
-    ``uniq_ids=None`` — and NO device unique: dedup buys the forward
-    pass nothing (its U is padded to B*L+1, so ``table[uniq]`` moves
-    the same bytes a direct raw gather moves) while the device unique
-    costs a train step 14.7 ms at B=8192 x 64 slots on the v5e (scope
-    ``dedup``; PERF.md section 5, PR 25), most of one score call.
+    ``uniq_ids=None`` — and NO device unique: the gather walks the
+    batch's B*L raw cells one row after another, where a train step's
+    walks the U slots its host unique fitted (PR 26, PR 36: 20,480 for
+    19.4k distinct rows of 327,680 cells at B=8192 x 40 on the v5e).
+    The device unique that would shrink the walk costs more than the
+    walk (a sort of B*L ids: 14.7 ms at B=8192 x 64, scope ``dedup``,
+    PERF.md section 5, PR 25); the host unique and the fitted slots
+    have not reached the scorer (ROADMAP S16; PERF.md sections 5 and 7
+    have what the raw gather costs a validation sweep).
     The direct gather is BIT-identical: same table rows summed in the
     same slot order. A train step needs unique rows for its backward
     scatter (exact sparse Adagrad): ``auto`` gives it the host unique,

@@ -421,14 +421,21 @@ class RunTelemetry:
 
     # -- shared instrumentation helpers ---------------------------------
     def pipeline_batch(self, batch, pad_id: int,
-                       build_seconds: Optional[float] = None) -> None:
+                       build_seconds: Optional[float] = None,
+                       prefix: str = "pipeline") -> None:
         """Per-DeviceBatch pipeline counters: examples/lines, padding
         waste, dedup hit rate inputs, build time. Runs on the pipeline
-        (prefetch worker) thread; everything here is host numpy."""
+        (prefetch worker) thread; everything here is host numpy.
+        ``prefix``: the plane's (data/pipeline.py ``TRAIN_PLANE``; a
+        validation sweep's plane counts under its own)."""
         import numpy as np
         B, L = batch.local_idx.shape
-        self.count("pipeline/batches")
-        self.count("pipeline/examples", batch.num_real)
+
+        def count(name, n=1):
+            self.count(f"{prefix}/{name}", n)
+
+        count("batches")
+        count("examples", batch.num_real)
         # Real feature cells: the builder's own count where it made
         # one, else a pass over the batch's B x L cells here, on the
         # thread every batch goes through (at B = 32768 that pass was
@@ -448,26 +455,25 @@ class RunTelemetry:
             if real is None:
                 real = int(np.count_nonzero(np.take(real_slot,
                                                     batch.local_idx)))
-            self.count("pipeline/uniq_rows", int(shard_rows.sum()))
+            count("uniq_rows", int(shard_rows.sum()))
             # The U shipped (ladder rung, pad slots included): rows
             # over slots is the fill of the fitted unique table, the
             # share of the step's gather/scatter slots that do work.
-            self.count("pipeline/uniq_slots", len(batch.uniq_ids))
+            count("uniq_slots", len(batch.uniq_ids))
             # The fullest shard's rows over a segment's slots: U is the
             # rung the fullest shard fits (pipeline.segment_plan), so
             # this ratio near 1 is a batch near the next rung, which
             # doubles every shard's gather and scatter walk.
-            self.count("pipeline/shard_rows_max", int(shard_rows.max()))
-            self.count("pipeline/shard_slots",
-                       len(batch.uniq_ids) // batch.row_shards)
-        self.count("pipeline/feature_slots", B * L)
-        self.count("pipeline/feature_nnz", real)
+            count("shard_rows_max", int(shard_rows.max()))
+            count("shard_slots", len(batch.uniq_ids) // batch.row_shards)
+        count("feature_slots", B * L)
+        count("feature_nnz", real)
         # Cells the lines had and the batch has not (the parsers' cut
         # at max_features_per_example): 0 on a sound configuration.
-        self.count("pipeline/truncated_cells", batch.truncated)
+        count("truncated_cells", batch.truncated)
         if build_seconds is not None:
-            self.count("pipeline/build_seconds", build_seconds)
-            self.observe("pipeline/batch_build_seconds", build_seconds)
+            count("build_seconds", build_seconds)
+            self.observe(f"{prefix}/batch_build_seconds", build_seconds)
 
     def train_step(self, dt: float, n_examples: int,
                    h2d_bytes: int,
@@ -552,8 +558,31 @@ ANATOMY_PHASES: Dict[str, Phase] = {
     "anatomy/barrier_reports_seconds": Phase(
         "train/barrier_reports_seconds", ("train/barrier_reports",),
         "barrier reports"),
+    # an enclosure: train.evaluate()'s leaves partition it (a lockstep
+    # sweep is one leaf, its parts counted apart below)
     "anatomy/validation_seconds": Phase(
-        "train/validation_seconds", ("train/validation",), "validation"),
+        "train/validation_seconds", ("train/validation",), "validation",
+        leaf=False),
+    "anatomy/validation_open_seconds": Phase(
+        "validation/open_seconds", ("validation/open",),
+        "validation open"),
+    "anatomy/validation_first_batch_seconds": Phase(
+        "validation/first_batch_seconds", ("validation/first_batch",),
+        "validation first batch"),
+    "anatomy/validation_input_wait_seconds": Phase(
+        "validation/input_wait_seconds", ("validation/input_wait",),
+        "validation input wait"),
+    "anatomy/validation_score_dispatch_seconds": Phase(
+        "validation/score_dispatch_seconds",
+        ("validation/score_dispatch",), "validation dispatch"),
+    "anatomy/validation_drain_seconds": Phase(
+        "validation/drain_seconds", ("validation/drain",),
+        "validation drain"),
+    "anatomy/validation_auc_seconds": Phase(
+        "validation/auc_seconds", ("validation/auc",), "validation auc"),
+    "anatomy/validation_lockstep_seconds": Phase(
+        "validation/lockstep_seconds", ("validation/lockstep",),
+        "validation lockstep"),
     "anatomy/summary_flush_seconds": Phase(
         "train/summary_pause_seconds", ("train/summary_flush",),
         "summary flush"),
@@ -562,7 +591,7 @@ ANATOMY_PHASES: Dict[str, Phase] = {
         "barrier flush"),
     "anatomy/pipeline_open_seconds": Phase(
         "pipeline/open_seconds", ("pipeline/open",), "pipeline open"),
-    # other threads', or inside a validation sweep: no leaves
+    # other threads', or inside a lockstep validation sweep: no leaves
     "anatomy/host_build_seconds": Phase(
         "pipeline/build_seconds", ("pipeline/build",), "host build",
         leaf=False),

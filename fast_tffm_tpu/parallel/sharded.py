@@ -635,6 +635,93 @@ def lockstep_score_batches(cfg: FmConfig, it, mesh: Mesh, score_fn,
             yield batch, local
 
 
+def evaluate_distributed(cfg: FmConfig, table: jax.Array, files, mesh,
+                         shard_index: int, num_shards: int,
+                         uniq_bucket: int = 0,
+                         max_batches: Optional[int] = None,
+                         weight_files=(),
+                         bad_lines=None,
+                         preempt=None, collect=None) -> Tuple[float, int]:
+    """Multi-process sharded AUC: every process scores its own input
+    shard through the mesh score fn in lockstep (the shared
+    lockstep_score_batches protocol), then the per-process binned-AUC
+    histograms are allgathered and merged — no table or score set ever
+    materializes on one host. Returns the same (auc, n_examples) on
+    every process. ``max_batches`` caps real batches per input shard.
+
+    ``uniq_bucket``: pass the caller's once-probed value; 0 re-probes
+    (deterministic — same bytes on every process, so all agree without
+    a collective). ``preempt`` rides the lockstep fill allgather
+    (parallel/sharded.py): a SIGTERM on one worker stops the sweep on
+    EVERY worker at the same window boundary — the partial histograms
+    still merge below (everyone exits the loop together, so the final
+    allgather stays matched). ``collect`` (obs/quality.QualityStats):
+    fed the per-batch local scores like the AUC update, and its four
+    sums ride INSIDE the existing histogram-merge allgather payload —
+    the quality loop adds no collective and no device fetch; after the
+    merge the collector holds the job-wide totals. Its presence is
+    config-deterministic, so every process ships the same payload
+    width."""
+    from jax.experimental import multihost_utils
+    from fast_tffm_tpu.data.pipeline import (VALIDATION_PLANE,
+                                             batch_iterator,
+                                             probe_uniq_bucket)
+    from fast_tffm_tpu.metrics import StreamingAUC
+    from fast_tffm_tpu.parallel.liveness import guarded_collective
+    spec = ModelSpec.from_config(cfg, training=False)
+    score_fn = make_sharded_score_fn(spec, mesh)
+    auc = StreamingAUC()
+    n = 0
+    ub = uniq_bucket or cfg.uniq_bucket or probe_uniq_bucket(cfg, files)
+    it = batch_iterator(cfg, files, training=False, epochs=1,
+                        weight_files=weight_files,
+                        shard_index=shard_index, num_shards=num_shards,
+                        fixed_shape=True, uniq_bucket=ub,
+                        bad_lines=bad_lines, counters=VALIDATION_PLANE)
+    for batch, local in lockstep_score_batches(cfg, it, mesh, score_fn,
+                                               table, ub,
+                                               max_batches=max_batches,
+                                               preempt=preempt):
+        nr = batch.num_real
+        auc.update(local[:nr], batch.labels[:nr], batch.weights[:nr])
+        if collect is not None:
+            collect.update(local[:nr], batch.labels[:nr],
+                           batch.weights[:nr])
+        n += batch.num_real
+    # process_allgather device_puts its payload and this runtime never
+    # enables x64, so float64 histograms (and int64 counts) silently
+    # downcast to 32 bits in transit — bins past 2^24 examples lose
+    # integer precision and a per-process n past 2^31 wraps, both real
+    # at the Criteo-1TB north star. Ship every f64 value as a (hi, lo)
+    # float32 pair (lo = v - f64(f32(v))): hi + lo recovers ~48 bits
+    # exactly, enough for any count this side of 10^14.
+    bins = auc.num_bins
+    # The quality collector's four sums ride the same payload (its
+    # presence is config-driven, so every process agrees on the
+    # width) — the publish-gate quality loop adds zero collectives.
+    extra = (collect.sums() if collect is not None
+             else np.zeros(0, np.float64))
+    payload = np.concatenate([auc.pos, auc.neg,
+                              np.asarray([n], np.float64), extra])
+    width = 2 * bins + 1 + extra.shape[0]
+    hi = payload.astype(np.float32)
+    lo = (payload - hi.astype(np.float64)).astype(np.float32)
+    gathered = guarded_collective(
+        multihost_utils.process_allgather,
+        np.stack([hi, lo]),
+        label="validation/auc_merge")          # [P, 2, width] f32
+    gathered = gathered.reshape(-1, 2, width)
+    vals = (gathered[:, 0, :].astype(np.float64)
+            + gathered[:, 1, :].astype(np.float64)).sum(axis=0)
+    merged = StreamingAUC(num_bins=bins)
+    merged.pos[:] = vals[:bins]
+    merged.neg[:] = vals[bins:2 * bins]
+    n_total = int(round(vals[2 * bins]))
+    if collect is not None:
+        collect.load_sums(vals[2 * bins + 1:])
+    return merged.result(), n_total
+
+
 def shard_batch(mesh: Mesh, **arrays) -> dict:
     """Place host batch arrays with their mesh shardings (keeps per-step
     host->device transfers going straight to the right shards)."""

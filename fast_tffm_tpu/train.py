@@ -26,7 +26,8 @@ from fast_tffm_tpu.checkpoint import (CheckpointState,
                                       export_npz, resume_start_epoch)
 from fast_tffm_tpu.config import FmConfig
 from fast_tffm_tpu.data.badlines import BadLineTracker
-from fast_tffm_tpu.data.pipeline import (SPILL_WARN_FRACTION, SpillStats,
+from fast_tffm_tpu.data.pipeline import (SPILL_WARN_FRACTION,
+                                         VALIDATION_PLANE, SpillStats,
                                          batch_iterator,
                                          gil_bound_iteration,
                                          host_parallel_workers, prefetch,
@@ -43,6 +44,7 @@ from fast_tffm_tpu.obs.memory import (LEDGER, local_bytes_in_use,
 from fast_tffm_tpu.obs.telemetry import (active, make_telemetry,
                                          pop_active, push_active)
 from fast_tffm_tpu.obs.trace import begin, span
+from fast_tffm_tpu.parallel.sharded import evaluate_distributed
 from fast_tffm_tpu.utils.fetch import ChunkedFetcher, bulk_fetch, note_link
 from fast_tffm_tpu.utils.logging import get_logger
 from fast_tffm_tpu.utils.timing import StepTimer
@@ -70,7 +72,8 @@ def evaluate(cfg: FmConfig, table: jax.Array, files,
              max_batches: Optional[int] = None,
              mesh=None, backend=None,
              weight_files=(), bad_lines=None,
-             vocab=None, collect=None) -> Tuple[float, int]:
+             vocab=None, collect=None,
+             phases: bool = True) -> Tuple[float, int]:
     """Streamed AUC over ``files``; returns (auc, n_examples). Pass the
     training mesh to score a row-sharded table in place, or a lookup
     ``backend`` (lookup.HostOffloadLookup) to score a host-offloaded
@@ -83,150 +86,97 @@ def evaluate(cfg: FmConfig, table: jax.Array, files,
     obs/quality.QualityStats or anything with the same
     ``update(scores, labels, weights)`` surface) is fed the SAME host
     score chunks the AUC update consumes — the publish-gate quality
-    loop's zero-added-device-fetch seam."""
-    spec = ModelSpec.from_config(cfg, training=False)
-    score_fn = make_batch_scorer(spec, mesh=mesh, backend=backend)
-    raw = ships_raw_batches(spec, mesh=mesh, backend=backend)
-    if vocab is not None:
-        # Telemetry-silent snapshot: a held-out sweep's unique tail is
-        # disproportionately unadmitted and would otherwise inflate
-        # the training stream's cold-hit rate (the COLD-ROW SATURATION
-        # verdict's input).
-        vocab = vocab.eval_view()
-    auc = StreamingAUC()
+    loop's zero-added-device-fetch seam. ``phases``: whether the
+    sweep's spans count into the loop's partition; a caller that sweeps
+    inside a leaf of its own (the stream loop's ``checkpoint/publish``)
+    says no, and the interval is counted once."""
+    tel = active()
+    # The sweep's wall on the calling thread, as leaves of the loop's
+    # partition (obs/telemetry.py ANATOMY_PHASES), named as predict's
+    # are. The plane's threads, builders and files are made at its first
+    # next(): ``validation/first_batch`` holds them, as
+    # ``pipeline/first_batch`` does for a training epoch.
+    step = tel.step if tel is not None else -1  # the table's: the last step
+
+    def counter(phase):
+        return f"validation/{phase}_seconds" if phases else None
+
+    with span("validation/open", seconds=counter("open"), step=step):
+        spec = ModelSpec.from_config(cfg, training=False)
+        score_fn = make_batch_scorer(spec, mesh=mesh, backend=backend)
+        raw = ships_raw_batches(spec, mesh=mesh, backend=backend)
+        if vocab is not None:
+            # Telemetry-silent snapshot: a held-out sweep's unique tail
+            # is disproportionately unadmitted and would otherwise
+            # inflate the training stream's cold-hit rate (the COLD-ROW
+            # SATURATION verdict's input).
+            vocab = vocab.eval_view()
+        auc = StreamingAUC()
+
+        def _consume(scores, m):
+            s, y, w = scores[:m[1]], m[0][:m[1]], m[2][:m[1]]
+            auc.update(s, y, w)
+            if collect is not None:
+                collect.update(s, y, w)
+
+        # Chunked fetches (utils/fetch.py): a per-batch sync stalls
+        # async dispatch every step, whole-sweep buffering is unbounded.
+        fetcher = ChunkedFetcher(
+            _consume,
+            overlap=True)  # D2H of chunk N overlaps scoring of chunk N+1
+        # The plane counts under names of its own (``validation_plane/``):
+        # ``pipeline/*`` stays the training plane's.
+        it = prefetch(batch_iterator(cfg, files, training=False,
+                                     weight_files=weight_files,
+                                     epochs=1, raw_ids=raw,
+                                     bad_lines=bad_lines,
+                                     vocab=vocab,
+                                     counters=VALIDATION_PLANE),
+                      depth=cfg.prefetch_depth,
+                      gil_bound=gil_bound_iteration(cfg, weight_files))
     n = 0
     n_batches = 0
-
-    def _consume(scores, m):
-        s, y, w = scores[:m[1]], m[0][:m[1]], m[2][:m[1]]
-        auc.update(s, y, w)
-        if collect is not None:
-            collect.update(s, y, w)
-
-    # Chunked fetches (utils/fetch.py): a per-batch sync stalls async
-    # dispatch every step, whole-sweep buffering is unbounded.
-    fetcher = ChunkedFetcher(
-        _consume,
-        overlap=True)  # D2H of chunk N overlaps scoring of chunk N+1
-    tel = active()
     # try/finally (ADVICE round 5): an exception mid-sweep must not
     # leave the overlap worker parked on queue.get forever with a
     # queued chunk of device score arrays pinned in HBM — close()
     # drains and joins the worker without masking the original error.
     try:
-        for batch in prefetch(batch_iterator(cfg, files, training=False,
-                                             weight_files=weight_files,
-                                             epochs=1, raw_ids=raw,
-                                             bad_lines=bad_lines,
-                                             vocab=vocab),
-                              depth=cfg.prefetch_depth,
-                              gil_bound=gil_bound_iteration(
-                                  cfg, weight_files)):
-            args = batch_args(batch)
-            args.pop("labels"), args.pop("weights")
-            fetcher.add(score_fn(table, args),
-                        (batch.labels, batch.num_real, batch.weights))
-            n += batch.num_real
-            n_batches += 1
-            if tel is not None:
-                # A full validation sweep can outlast the watchdog's
-                # stall budget; scored batches are progress.
-                tel.heartbeat()
+        while True:
+            wait = "input_wait" if n_batches else "first_batch"
+            with span("validation/" + wait, seconds=counter(wait),
+                      step=step):
+                batch = next(it, None)
+            if batch is None:
+                break
+            with span("validation/score_dispatch",
+                      seconds=counter("score_dispatch"), step=step):
+                args = batch_args(batch)
+                args.pop("labels"), args.pop("weights")
+                fetcher.add(score_fn(table, args),
+                            (batch.labels, batch.num_real, batch.weights))
+                n += batch.num_real
+                n_batches += 1
+                if tel is not None:
+                    # A full validation sweep can outlast the watchdog's
+                    # stall budget; scored batches are progress.
+                    tel.heartbeat()
             # Batch-count cap — the same per-input-shard unit the
             # distributed path uses, so AUC samples are comparable.
             if max_batches and n_batches >= max_batches:
                 break
-        fetcher.flush()
+        # The tail after the last dispatch: the chip finishes, the last
+        # chunk's D2H and its two histogram updates a batch.
+        with span("validation/drain", seconds=counter("drain"), step=step):
+            fetcher.flush()
     finally:
         fetcher.close()
-    return auc.result(), n
-
-
-def evaluate_distributed(cfg: FmConfig, table: jax.Array, files, mesh,
-                         shard_index: int, num_shards: int,
-                         uniq_bucket: int = 0,
-                         max_batches: Optional[int] = None,
-                         weight_files=(),
-                         bad_lines=None,
-                         preempt=None, collect=None) -> Tuple[float, int]:
-    """Multi-process sharded AUC: every process scores its own input
-    shard through the mesh score fn in lockstep (the shared
-    lockstep_score_batches protocol), then the per-process binned-AUC
-    histograms are allgathered and merged — no table or score set ever
-    materializes on one host. Returns the same (auc, n_examples) on
-    every process. ``max_batches`` caps real batches per input shard.
-
-    ``uniq_bucket``: pass the caller's once-probed value; 0 re-probes
-    (deterministic — same bytes on every process, so all agree without
-    a collective). ``preempt`` rides the lockstep fill allgather
-    (parallel/sharded.py): a SIGTERM on one worker stops the sweep on
-    EVERY worker at the same window boundary — the partial histograms
-    still merge below (everyone exits the loop together, so the final
-    allgather stays matched). ``collect`` (obs/quality.QualityStats):
-    fed the per-batch local scores like the AUC update, and its four
-    sums ride INSIDE the existing histogram-merge allgather payload —
-    the quality loop adds no collective and no device fetch; after the
-    merge the collector holds the job-wide totals. Its presence is
-    config-deterministic, so every process ships the same payload
-    width."""
-    import numpy as np
-    from jax.experimental import multihost_utils
-    from fast_tffm_tpu.data.pipeline import probe_uniq_bucket
-    from fast_tffm_tpu.parallel.liveness import guarded_collective
-    from fast_tffm_tpu.parallel.sharded import (lockstep_score_batches,
-                                                make_sharded_score_fn)
-    spec = ModelSpec.from_config(cfg, training=False)
-    score_fn = make_sharded_score_fn(spec, mesh)
-    auc = StreamingAUC()
-    n = 0
-    ub = uniq_bucket or cfg.uniq_bucket or probe_uniq_bucket(cfg, files)
-    it = batch_iterator(cfg, files, training=False, epochs=1,
-                        weight_files=weight_files,
-                        shard_index=shard_index, num_shards=num_shards,
-                        fixed_shape=True, uniq_bucket=ub,
-                        bad_lines=bad_lines)
-    for batch, local in lockstep_score_batches(cfg, it, mesh, score_fn,
-                                               table, ub,
-                                               max_batches=max_batches,
-                                               preempt=preempt):
-        nr = batch.num_real
-        auc.update(local[:nr], batch.labels[:nr], batch.weights[:nr])
-        if collect is not None:
-            collect.update(local[:nr], batch.labels[:nr],
-                           batch.weights[:nr])
-        n += batch.num_real
-    # process_allgather device_puts its payload and this runtime never
-    # enables x64, so float64 histograms (and int64 counts) silently
-    # downcast to 32 bits in transit — bins past 2^24 examples lose
-    # integer precision and a per-process n past 2^31 wraps, both real
-    # at the Criteo-1TB north star. Ship every f64 value as a (hi, lo)
-    # float32 pair (lo = v - f64(f32(v))): hi + lo recovers ~48 bits
-    # exactly, enough for any count this side of 10^14.
-    bins = auc.num_bins
-    # The quality collector's four sums ride the same payload (its
-    # presence is config-driven, so every process agrees on the
-    # width) — the publish-gate quality loop adds zero collectives.
-    extra = (collect.sums() if collect is not None
-             else np.zeros(0, np.float64))
-    payload = np.concatenate([auc.pos, auc.neg,
-                              np.asarray([n], np.float64), extra])
-    width = 2 * bins + 1 + extra.shape[0]
-    hi = payload.astype(np.float32)
-    lo = (payload - hi.astype(np.float64)).astype(np.float32)
-    gathered = guarded_collective(
-        multihost_utils.process_allgather,
-        np.stack([hi, lo]),
-        label="validation/auc_merge")          # [P, 2, width] f32
-    gathered = gathered.reshape(-1, 2, width)
-    vals = (gathered[:, 0, :].astype(np.float64)
-            + gathered[:, 1, :].astype(np.float64)).sum(axis=0)
-    merged = StreamingAUC(num_bins=bins)
-    merged.pos[:] = vals[:bins]
-    merged.neg[:] = vals[bins:2 * bins]
-    n_total = int(round(vals[2 * bins]))
-    if collect is not None:
-        collect.load_sums(vals[2 * bins + 1:])
-    return merged.result(), n_total
+    with span("validation/auc", seconds=counter("auc"), step=step):
+        result = auc.result()
+    if tel is not None:
+        tel.count("validation/sweeps")
+        tel.count("validation/batches", n_batches)
+        tel.count("validation/examples", n)
+    return result, n
 
 
 class ClusterGrowth(Exception):
@@ -695,9 +645,11 @@ class _Session:
             except ValueError:  # not the main thread (e.g. under a test)
                 pass
 
-    def validate(self, table, collect=None, preempt=None):
+    def validate(self, table, collect=None, preempt=None, phases=True):
         """One validation sweep of ``table`` on this session's dispatch
-        path; returns ``(auc, n_examples)``. ``preempt`` rides the
+        path; returns ``(auc, n_examples)``. ``phases``: whether the
+        sweep counts as leaves of the loop's partition (``evaluate``);
+        not where the caller's own leaf holds it. ``preempt`` rides the
         lockstep window allgather of a multi-process sweep: a SIGTERM
         mid-sweep stops EVERY worker at the same window boundary (the
         signalled worker alone bailing would desync the collective
@@ -705,19 +657,25 @@ class _Session:
         cfg = self.cfg
         vmb = cfg.validation_max_batches or None
         if self.multi_process:
-            return evaluate_distributed(
-                cfg, table, cfg.validation_files, self.mesh,
-                self.shard_index, self.num_shards,
-                uniq_bucket=self.val_bucket, max_batches=vmb,
-                weight_files=cfg.validation_weight_files,
-                bad_lines=self.bad_tracker, collect=collect,
-                preempt=preempt)
+            # One leaf for the whole lockstep sweep: its parts (window
+            # fill, allgather, fetch) are counted apart, as no leaves.
+            with span("validation/lockstep",
+                      seconds=("validation/lockstep_seconds" if phases
+                               else None),
+                      step=self.tel.step if self.tel is not None else -1):
+                return evaluate_distributed(
+                    cfg, table, cfg.validation_files, self.mesh,
+                    self.shard_index, self.num_shards,
+                    uniq_bucket=self.val_bucket, max_batches=vmb,
+                    weight_files=cfg.validation_weight_files,
+                    bad_lines=self.bad_tracker, collect=collect,
+                    preempt=preempt)
         return evaluate(
             cfg, table, cfg.validation_files, mesh=self.mesh,
             backend=self.lk, max_batches=vmb,
             weight_files=cfg.validation_weight_files,
             bad_lines=self.bad_tracker, vocab=self.vocab,
-            collect=collect)
+            collect=collect, phases=phases)
 
 
 def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
@@ -1292,6 +1250,7 @@ class StepLoop:
         # The epoch barrier, held open across loop iterations
         # (obs/trace.begin): the first dispatch to return ends it.
         self.barrier = None
+        self.barrier_sweep = 0.0    # the validation sweep's wall inside it
         self.completed_epochs = s.start_epoch
         self.last_periodic_save = (None, None)  # (step, epoch) of the latest
         # Streaming run mode (README "Streaming / online learning"):
@@ -1459,7 +1418,10 @@ class StepLoop:
     def end_barrier(self) -> None:
         if self.barrier is not None:
             wall, self.barrier = self.barrier.end(), None
-            if self.s.tel is not None and (wall or 0) >= SLOW_STEP_SECONDS:
+            # A barrier that holds a validation sweep is no stall for
+            # that: the sweep's own phases say where its wall went.
+            wall = (wall or 0) - self.barrier_sweep
+            if self.s.tel is not None and wall >= SLOW_STEP_SECONDS:
                 self.s.tel.slow_step(self.global_step + 1, wall, "barrier")
 
     def profile_tick(self, step_done: int) -> None:
@@ -1618,8 +1580,11 @@ def _publish_decision(s: _Session, loop: StepLoop) -> Optional[dict]:
         # preempt: a SIGTERM mid-sweep stops ALL workers at the same
         # window boundary instead of finishing the full validation
         # pass inside the kill grace window.
+        # phases=False: this sweep lies inside the publish's own leaf
+        # (checkpoint/publish, or the exit publish outside the loop).
         auc, n = s.validate(loop.table, collect=stats,
-                            preempt=lambda: bool(s.preempted))
+                            preempt=lambda: bool(s.preempted),
+                            phases=False)
     if jax.process_index() == 0:
         # Chief-only: n and the merged stats are already job-global,
         # and per-worker shard counters merge by SUM in fmstat — every
@@ -1785,6 +1750,7 @@ def _epoch_barrier(s: _Session, loop: StepLoop, epoch: int,
         loop.barrier = begin("train/epoch_barrier",
                              seconds="train/epoch_barrier_seconds",
                              epoch=epoch)
+        loop.barrier_sweep = 0.0
     loop.flush_log()  # deferred loss lines land at the epoch barrier
     with span("train/barrier_reports",
               seconds="train/barrier_reports_seconds", epoch=epoch):
@@ -1834,12 +1800,16 @@ def _epoch_barrier(s: _Session, loop: StepLoop, epoch: int,
             # + reset rows.
             loop.vocab_barrier(f"epoch {epoch}")
     if cfg.validation_files and not stopping:
+        # An enclosure of the sweep's leaves (evaluate()'s
+        # validation/*; a lockstep sweep is one leaf).
         with span("train/validation", leaf=False,
-                  seconds="train/validation_seconds", epoch=epoch):
+                  seconds="train/validation_seconds", epoch=epoch) as sweep:
             # A preempted sweep stops on every worker together; the step
             # loop then drains the flag and all workers save together.
             auc, n = s.validate(loop.table,
                                 preempt=lambda: bool(s.preempted))
+        # what this barrier's slow_step leaves out (StepLoop.end_barrier)
+        loop.barrier_sweep = sweep.dur if tel is not None else 0.0
         loop.last_val = (auc, n)
         if jax.process_index() == 0:
             logger.info(
@@ -2273,7 +2243,8 @@ def _finish(s: _Session, loop: StepLoop) -> None:
         # publishing streams already validated through the exit
         # publish's quality sweep just above) — silently
         # accepting-and-ignoring the knob would be a config trap.
-        auc, n = s.validate(loop.table)
+        # phases=False: the loop's wall has stopped (loop_stop above)
+        auc, n = s.validate(loop.table, phases=False)
         logger.info("final validation AUC %.6f over %d examples",
                     auc, n)
         if tel is not None:
