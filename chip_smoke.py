@@ -69,9 +69,10 @@ DEADLINE_SECONDS = 1100  # the contract allows 1200, compile included
 # BASELINE config #1 at the widths tools/criteo_bench.py runs it, except
 # the bucket: 39 features land in L=64 under the default ladder, the
 # width at which the Pallas kernel applies. ``kernel = auto`` takes it
-# there for one-chip scoring (raw ids); a one-chip train step runs on
-# the host unique, where auto resolves to XLA (PERF.md section 6,
-# PR 26), so leg 1 asks for the kernel by name.
+# there for raw ids only (serve); a one-chip train step and, since
+# PR 45, a one-chip predict run on the host unique, where auto
+# resolves to XLA (PERF.md section 6, PR 26), so leg 1 asks for the
+# kernel by name.
 VOCAB, K, BATCH, L, EPOCHS, LR, LAM = 1 << 22, 8, 8192, 64, 2, 0.05, 1e-6
 SEED = 17
 # Corpus length (train, test): not a width, and not a knob either.

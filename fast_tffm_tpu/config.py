@@ -138,11 +138,11 @@ class FmConfig:
     # and offload paths. "device":
     # the pipeline ships raw ids; a scorer gathers them directly, a
     # train step runs jnp.unique on the chip over U = B*L + 1 slots
-    # (single-device jit only). "auto" on one device resolves by use:
-    # training takes "host", because the step pays for every slot its
-    # scatters walk (the v5e's readings: PERF.md section 5); scoring
-    # needs no unique and ships raw ids. Resolved in
-    # ModelSpec.from_config.
+    # (single-device jit only). "auto" is "host" whatever the use: a
+    # train step and a sweep's scorer both pay for every slot they
+    # walk (the v5e's readings: PERF.md section 5). serve alone ships
+    # raw ids, by its own override (scoring.CompiledScorer). Resolved
+    # in ModelSpec.from_config.
     dedup: str = "auto"             # "auto" | "host" | "device"
     # Wire format (README "Wire format"; fast_tffm_tpu/wire.py): how a
     # built batch crosses the host->device boundary. "padded" (default)

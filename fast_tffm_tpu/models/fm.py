@@ -61,13 +61,7 @@ class ModelSpec:
     dedup: str = "host"
 
     @classmethod
-    def from_config(cls, cfg: FmConfig, *,
-                    training: Optional[bool] = None) -> "ModelSpec":
-        """``training`` says what the spec's programs are for: True a
-        train step, False scoring. It decides ``dedup = auto`` on one
-        device (below), and there is no default: a caller that leaves
-        it out where the answer depends on it gets an error, not the
-        other use's wire."""
+    def from_config(cls, cfg: FmConfig) -> "ModelSpec":
         kernel = cfg.kernel
         if kernel == "pallas" and (cfg.model_type == "ffm"
                                    or cfg.order != 2):
@@ -94,23 +88,17 @@ class ModelSpec:
                 kernel = "xla"
         dedup = cfg.dedup
         if dedup == "auto":
-            # Resolved by use. Mesh, offload and multi-process rely on
-            # the host-side unique contract whatever they run. On the
-            # plain single-device jit, scoring needs no unique at all
-            # (score_body gathers the raw ids) and is host-bound, so it
-            # ships raw ids; a train step needs unique slots for its
-            # backward scatter and pays for every slot it walks (160
-            # ns in adagrad's scatter on the v5e, pad slot or real row;
-            # PERF.md section 5), so it takes the host unique, whose U
-            # is the ladder rung of the batch's distinct rows, not the
-            # device unique's B*L + 1.
-            one_chip = jax.device_count() == 1 and cfg.lookup == "device"
-            if one_chip and training is None:
-                raise TypeError(
-                    "ModelSpec.from_config: dedup = auto on one device "
-                    "resolves by use; pass training=True (a train "
-                    "step) or training=False (scoring)")
-            dedup = "device" if one_chip and not training else "host"
+            # The host unique, whatever the programs are for. Mesh,
+            # offload and multi-process rely on its contract. On the
+            # plain single-device jit both uses pay for every slot
+            # they walk, pad slot or real row (a gather 29 ns, adagrad's
+            # scatter 160 ns on the v5e; PERF.md section 5), and the
+            # host unique's U is the ladder rung of the batch's
+            # distinct rows, not B*L raw cells (a scorer) or the device
+            # unique's B*L + 1 (a train step). The one caller that
+            # ships raw ids, serve, says so itself
+            # (scoring.CompiledScorer(dedup="device")).
+            dedup = "host"
         return cls(model_type=cfg.model_type, order=cfg.order,
                    factor_num=cfg.factor_num, field_num=cfg.field_num,
                    vocabulary_size=cfg.vocabulary_size,
@@ -646,17 +634,21 @@ def score_body(spec: ModelSpec, table, uniq_ids, local_idx, vals,
                fields=None, *, mesh=None):
     """Inference forward (gather -> scorer). Shared by the single-device
     and mesh-sharded score functions — single source of truth, like
-    train_step_body. dedup='device': raw ids in ``local_idx``,
-    ``uniq_ids=None`` — and NO device unique: the gather walks the
-    batch's B*L raw cells one row after another, where a train step's
-    walks the U slots its host unique fitted (PR 26, PR 36: 20,480 for
-    19.4k distinct rows of 327,680 cells at B=8192 x 40 on the v5e).
-    The device unique that would shrink the walk costs more than the
-    walk (a sort of B*L ids: 14.7 ms at B=8192 x 64, scope ``dedup``,
-    PERF.md section 5, PR 25); the host unique and the fitted slots
-    have not reached the scorer (ROADMAP S16; PERF.md sections 5 and 7
-    have what the raw gather costs a validation sweep).
-    The direct gather is BIT-identical: same table rows summed in the
+    train_step_body. dedup='host' (what ``auto`` gives every sweep:
+    validation, predict, on one device since PR 45): ``uniq_ids[U]``
+    and slot indices in ``local_idx``; the gather walks the U slots
+    the host unique fitted (20,480 for 19.4k distinct rows of 327,680
+    cells at B=8192 x 40), as the train step's does. dedup='device'
+    (serve's own choice, or an explicit ``dedup = device``): raw ids in
+    ``local_idx``, ``uniq_ids=None`` — and NO device unique: the
+    gather walks the batch's B*L raw cells one row after another (28.9
+    ns a slot on the v5e whatever the count: 9.46 ms where the fitted
+    slots take 0.59; PERF.md section 6, PR 44 and PR 45), and a second
+    gather over an iota expands what the first wrote (ROADMAP S16(i):
+    it waits for a serve cell). The device unique that would shrink
+    the walk costs more than the walk (a sort of B*L ids: 14.7 ms at
+    B=8192 x 64, scope ``dedup``, PERF.md section 5, PR 25).
+    Either way BIT-identical scores: the same table rows summed in the
     same slot order. A train step needs unique rows for its backward
     scatter (exact sparse Adagrad): ``auto`` gives it the host unique,
     an explicit ``dedup = device`` ``_device_dedup``."""

@@ -30,6 +30,16 @@ measured single-chip — the sharded-assembly regime itself has no
 direct measurement — so a cluster operator who measures otherwise can
 still force ``kernel = pallas`` (it runs under shard_map).
 
+Who is under which dedup moved since the pairs were taken. A one-chip
+train step has run the host unique since PR 26 (on the v5e XLA 1.26 ms
+against Pallas 1.73 there, B=8192, K=16, L=64), and since PR 45 so do
+one-chip sweeps (``predict``, validation: ``dedup = auto`` is the host
+unique whatever the use, models/fm.ModelSpec.from_config): a sweep
+over buckets of L >= 64 changed from Pallas to XLA with its wire. The
+(device, L >= 64) cell is left to serve, which forces raw ids itself
+(scoring.CompiledScorer(dedup="device")), and to an explicit ``dedup =
+device``. The matrix is as it was.
+
 The XLA column also predates PR 42: ``fm_batch_scores`` then sliced
 the w column off the expanded rows and re-laid w and v apart, which on
 the v5e cost the forward a slice and a second layout copy (0.73 ms at

@@ -668,7 +668,7 @@ def evaluate_distributed(cfg: FmConfig, table: jax.Array, files, mesh,
                                              probe_uniq_bucket)
     from fast_tffm_tpu.metrics import StreamingAUC
     from fast_tffm_tpu.parallel.liveness import guarded_collective
-    spec = ModelSpec.from_config(cfg, training=False)
+    spec = ModelSpec.from_config(cfg)
     score_fn = make_sharded_score_fn(spec, mesh)
     auc = StreamingAUC()
     n = 0

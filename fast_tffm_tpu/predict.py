@@ -379,7 +379,7 @@ def _predict_multiprocess(cfg: FmConfig, table, logger,
         from fast_tffm_tpu.checkpoint import refuse_fixed_mode_admit_step
         refuse_fixed_mode_admit_step(
             cfg, os.path.abspath(cfg.model_file) + ".ckpt", vstep)
-    spec = ModelSpec.from_config(cfg, training=False)
+    spec = ModelSpec.from_config(cfg)
     score_fn = make_sharded_score_fn(spec, mesh)
     p, P = jax.process_index(), jax.process_count()
     os.makedirs(cfg.score_path, exist_ok=True)

@@ -60,12 +60,18 @@ class CompiledScorer:
     host-deduped batches), and the spec resolution, so a caller can't
     pair a scorer with the wrong batch builder.
 
-    ``dedup`` overrides the config's resolution — the serving process
-    forces ``"device"`` (the raw-gather path: no U axis, so its
-    pre-compiled shape ladder is exactly [B rung, L rung] and every
-    padded request shape is known at warmup). jit executables are
-    cached per (spec, shape) process-wide (models/fm lru caches), so a
-    handle is cheap to construct and compiled code outlives it."""
+    The config's resolution gives a sweep (batch predict, like a
+    validation sweep) the host unique on one device too since PR 45:
+    ``raw`` is false, the C++ builder dedups while it parses, U rides
+    the quarter-octave ladder and the scorer gathers U fitted slots
+    where a raw-id scorer walks B*L cells twice (PERF.md section 6,
+    PR 45). ``dedup`` overrides that — the serving process forces
+    ``"device"`` (the raw-gather path: no U axis, so its pre-compiled
+    shape ladder is exactly [B rung, L rung] and every padded request
+    shape is known at warmup; a request of a few lines has nothing to
+    dedup). jit executables are cached per (spec, shape) process-wide
+    (models/fm lru caches), so a handle is cheap to construct and
+    compiled code outlives it."""
 
     def __init__(self, cfg: FmConfig, mesh=None, backend=None,
                  dedup: Optional[str] = None, serve_ladder: bool = False):
@@ -74,7 +80,7 @@ class CompiledScorer:
                                              make_batch_scorer,
                                              ships_raw_batches)
         from fast_tffm_tpu.wire import WireEncoder, resolve_wire
-        spec = ModelSpec.from_config(cfg, training=False)
+        spec = ModelSpec.from_config(cfg)
         if dedup is not None:
             spec = dataclasses.replace(spec, dedup=dedup)
         self.spec = spec

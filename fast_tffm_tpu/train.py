@@ -102,7 +102,7 @@ def evaluate(cfg: FmConfig, table: jax.Array, files,
         return f"validation/{phase}_seconds" if phases else None
 
     with span("validation/open", seconds=counter("open"), step=step):
-        spec = ModelSpec.from_config(cfg, training=False)
+        spec = ModelSpec.from_config(cfg)
         score_fn = make_batch_scorer(spec, mesh=mesh, backend=backend)
         raw = ships_raw_batches(spec, mesh=mesh, backend=backend)
         if vocab is not None:
@@ -567,7 +567,7 @@ class _Session:
         # ... and by _arm_publish_gate.
         self.gate = None
         self.quality_on = False
-        self.spec = ModelSpec.from_config(cfg, training=True)
+        self.spec = ModelSpec.from_config(cfg)
         logger.info("train regime: %s", regime_line(self.spec, cfg))
         self.multi_process = jax.process_count() > 1
         self.stream_mode = getattr(cfg, "run_mode", "epochs") == "stream"

@@ -1,7 +1,8 @@
 """dedup=device (raw-ids) mode: the pipeline ships raw feature ids and
 the jitted step runs jnp.unique on device — must be bit-equivalent to
 the host-dedup path; and dedup=auto on one device, wired end-to-end
-through the CLI (training: host unique; scoring: raw ids)."""
+through the CLI (the host unique for a train step and for a sweep's
+scorer alike; raw ids where the caller asks, as serve does)."""
 
 import dataclasses
 import os
@@ -137,12 +138,14 @@ def test_mode_mismatch_raises(tmp_path):
 
 
 def test_cli_e2e_auto_resolves_by_use(tmp_path):
-    """On a single device, dedup=auto resolves by use: training takes
-    the host unique (slots fitted to the batch's distinct rows),
-    scoring ships raw ids and no uniq_ids. The full CLI train->predict
-    must work and produce sane scores (run in a subprocess with exactly
-    one CPU device — the in-process test env pins 8 virtual devices,
-    which resolves auto to host for every use)."""
+    """On a single device, dedup=auto takes the host unique whatever
+    the use: the train step, evaluate()'s sweep and predict's all get
+    ``uniq_ids`` with U a ladder rung under B*L + 1, and both regime
+    lines say ``dedup=host``; serve's ``CompiledScorer(cfg,
+    dedup="device")`` still ships raw batches and needs no U axis. The
+    full CLI train->predict must work and produce sane scores (run in
+    a subprocess with exactly one CPU device — the in-process test env
+    pins 8 virtual devices, which resolves auto to host anyway)."""
     path = _write(tmp_path, n=64, seed=17)
     cfg_path = tmp_path / "dd.cfg"
     cfg_path.write_text(f"""
@@ -153,6 +156,7 @@ model_file = {tmp_path}/model/fm
 
 [Train]
 train_files = {path}
+validation_files = {path}
 epoch_num = 2
 batch_size = 16
 learning_rate = 0.1
@@ -168,40 +172,66 @@ score_path = {tmp_path}/score
         "import jax, numpy as np, run_tffm\n"
         "from fast_tffm_tpu import scoring, train as tr\n"
         "from fast_tffm_tpu.config import load_config\n"
+        "from fast_tffm_tpu.data import pipeline\n"
         "from fast_tffm_tpu.models import fm\n"
         "assert jax.device_count() == 1, jax.device_count()\n"
         f"cfg = load_config(r'{cfg_path}')\n"
-        "t = fm.ModelSpec.from_config(cfg, training=True)\n"
-        "s = fm.ModelSpec.from_config(cfg, training=False)\n"
-        "assert (t.dedup, s.dedup) == ('host', 'device')\n"
-        "assert 'dedup=host ' in fm.regime_line(t, cfg)\n"
-        "assert 'dedup=device ' in fm.regime_line(s, cfg)\n"
-        "assert fm.ships_raw_batches(s) and not fm.ships_raw_batches(t)\n"
-        "slots, raw = [], []\n"
+        "s = fm.ModelSpec.from_config(cfg)\n"
+        "assert s.dedup == 'host' and not fm.ships_raw_batches(s)\n"
+        "assert 'dedup=host ' in fm.regime_line(s, cfg)\n"
+        "def rung(u, li):\n"
+        "    B, L = li.shape\n"
+        "    return u in pipeline._uniq_ladder(B, L) and u < B * L + 1\n"
+        "steps, sweeps, preds = [], [], []\n"
         "make = tr.make_train_step\n"
         "def probed(spec):\n"
         "    step = make(spec)\n"
         "    def call(table, acc, **kw):\n"
-        "        slots.append((kw['uniq_ids'].shape[0],\n"
-        "                      kw['local_idx'].size + 1))\n"
+        "        steps.append(rung(kw['uniq_ids'].shape[0],\n"
+        "                          kw['local_idx']))\n"
         "        return step(table, acc, **kw)\n"
         "    return call\n"
         "tr.make_train_step = probed\n"
+        "scorer = tr.make_batch_scorer\n"
+        "def swept(spec, **kw):\n"
+        "    fn = scorer(spec, **kw)\n"
+        "    def call(table, args):\n"
+        "        sweeps.append(rung(args['uniq_ids'].shape[0],\n"
+        "                           args['local_idx']))\n"
+        "        return fn(table, args)\n"
+        "    return call\n"
+        "tr.make_batch_scorer = swept\n"
         "score_batch = scoring.CompiledScorer.score_batch\n"
         "def scored(self, table, batch):\n"
-        "    raw.append(batch.uniq_ids is None)\n"
+        "    preds.append(rung(batch.uniq_ids.shape[0], batch.local_idx))\n"
         "    return score_batch(self, table, batch)\n"
         "scoring.CompiledScorer.score_batch = scored\n"
         f"assert run_tffm.main(['train', r'{cfg_path}']) == 0\n"
         f"assert run_tffm.main(['predict', r'{cfg_path}']) == 0\n"
-        "assert len(slots) == 8 and all(u < bl1 for u, bl1 in slots), slots\n"
-        "assert raw and all(raw), raw\n"
+        "assert len(steps) == 8 and all(steps), steps\n"
+        "assert len(sweeps) == 8 and all(sweeps), sweeps\n"
+        "assert len(preds) == 4 and all(preds), preds\n"
+        # serve's handle: raw batches, [B, L] the only axes
+        "scoring.CompiledScorer.score_batch = score_batch\n"
+        "srv = scoring.CompiledScorer(cfg, dedup='device')\n"
+        "assert srv.raw and srv.spec.dedup == 'device'\n"
+        "assert 'dedup=device ' in fm.regime_line(srv.spec, cfg)\n"
+        "b = next(pipeline.batch_iterator(cfg, cfg.predict_files,\n"
+        "         training=False, epochs=1, raw_ids=srv.raw))\n"
+        "assert b.uniq_ids is None\n"
+        "table = fm.init_table(cfg, 0)\n"
+        "got = np.asarray(srv.score_batch(table, b))\n"
+        "h = next(pipeline.batch_iterator(cfg, cfg.predict_files,\n"
+        "         training=False, epochs=1))\n"
+        "want = np.asarray(scoring.CompiledScorer(cfg)\n"
+        "                  .score_batch(table, h))\n"
+        "np.testing.assert_array_equal(got, want)\n"
     )
     out = subprocess.run([sys.executable, "-c", code], cwd=REPO, env=env,
                          capture_output=True, text=True, timeout=300)
     assert out.returncode == 0, out.stdout + out.stderr
     assert "train regime: backend=cpu devices=1 dedup=host" in out.stderr
-    assert "predict regime: backend=cpu devices=1 dedup=device" in out.stderr
+    assert "predict regime: backend=cpu devices=1 dedup=host" in out.stderr
     scores = np.loadtxt(tmp_path / "score" / "d.txt.score")
     assert len(scores) == 64
     assert np.isfinite(scores).all() and (0 <= scores).all() \

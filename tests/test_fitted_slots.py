@@ -1,8 +1,8 @@
-"""The one-chip train step runs on slots fitted to the batch's distinct
-rows: ``dedup = auto`` resolves by use (training: host unique on the
-quarter-octave ladder, ISSUE 36; scoring: raw ids), the pipeline counts
-the slots it ships, and the benchmark's two metric files read that
-count."""
+"""One device runs on slots fitted to the batch's distinct rows:
+``dedup = auto`` resolves to the host unique on the quarter-octave
+ladder for a train step (ISSUE 36) and for a sweep's scorer (ISSUE 45)
+alike, the pipeline counts the slots it ships, and the benchmark's two
+metric files read that count."""
 
 import dataclasses
 import functools
@@ -56,61 +56,70 @@ def _cfg(path, **kw):
     return FmConfig(**base)
 
 
-# ---- (a) the resolution, by use ----------------------------------------
+# ---- (a) the resolution: the host unique, whatever the use -----------
 
-@pytest.mark.parametrize("devices,lookup,configured,training,want", [
-    (1, "device", "auto", True, "host"),     # one chip trains on fitted slots
-    (1, "device", "auto", False, "device"),  # and scores raw ids
-    (8, "device", "auto", True, "host"),     # a mesh: as before, both uses
-    (8, "device", "auto", False, "host"),
-    (1, "host", "auto", True, "host"),       # offload: as before
-    (1, "host", "auto", False, "host"),
-    (1, "device", "device", True, "device"),  # explicit values keep their
-    (1, "device", "host", False, "host"),     # meaning for either use
+@pytest.mark.parametrize("devices,lookup,configured,use,want", [
+    (1, "device", "auto", "train", "host"),  # one chip trains on fitted slots
+    (1, "device", "auto", "score", "host"),  # and sweeps on them (ISSUE 45)
+    (8, "device", "auto", "train", "host"),  # a mesh: as before, both uses
+    (8, "device", "auto", "score", "host"),
+    (1, "host", "auto", "train", "host"),    # offload: as before
+    (1, "host", "auto", "score", "host"),
+    (1, "device", "device", "train", "device"),  # explicit values keep
+    (1, "device", "host", "score", "host"),      # their meaning
 ])
-def test_auto_dedup_resolves_by_use(monkeypatch, tmp_path, devices, lookup,
-                                    configured, training, want):
+def test_auto_dedup_resolves_to_the_host_unique(monkeypatch, tmp_path,
+                                                devices, lookup,
+                                                configured, use, want):
     monkeypatch.setattr(jax, "device_count", lambda: devices)
-    cfg = _cfg(str(tmp_path / "none.txt"), lookup=lookup, dedup=configured)
-    spec = ModelSpec.from_config(cfg, training=training)
+    path = _zipf_corpus(tmp_path, ffm=False, n=B)
+    cfg = _cfg(path, lookup=lookup, dedup=configured)
+    spec = ModelSpec.from_config(cfg)
     assert spec.dedup == want
     assert f"dedup={want} " in regime_line(spec, cfg)
     assert ships_raw_batches(spec) is (want == "device")
-    # no default use: where the answer depends on it, leaving it out is
-    # an error, not the other use's wire
-    if devices == 1 and lookup == "device" and configured == "auto":
-        with pytest.raises(TypeError, match="training=True"):
-            ModelSpec.from_config(cfg)
+    # the rule has no use in it: the feed either use asks for is the same
+    batch, = batch_iterator(cfg, (path,), training=use == "train",
+                            epochs=1, raw_ids=ships_raw_batches(spec))
+    if want == "device":
+        assert batch.uniq_ids is None
     else:
-        assert ModelSpec.from_config(cfg) == spec
+        assert len(batch.uniq_ids) in _uniq_ladder(B, L)[:-1]
 
 
-def test_every_program_says_what_its_spec_is_for():
-    """The 8-device rig never reaches the one-chip resolution, so a
-    caller that forgets ``training=`` would pass every test here and
-    fail on the chip. Hold the programs to it by their source."""
+def test_only_serve_asks_for_raw_ids():
+    """Raw ids are the caller's choice by its kind, not a rule's by the
+    use: of the programs, serve alone overrides the spec's ``dedup`` or
+    builds ``raw_ids=True`` by hand (its shapes are compiled ahead over
+    B x widths and a request of a few lines has nothing to dedup).
+    Every other feed follows ``ships_raw_batches(spec)``."""
     import ast
-    roots = [os.path.join(REPO, "fast_tffm_tpu"), os.path.join(REPO, "tools"),
-             os.path.join(REPO, "benchmarks")]
+    roots = [os.path.join(REPO, "fast_tffm_tpu"), os.path.join(REPO, "tools")]
     files = [os.path.join(REPO, f) for f in (
         "chip_smoke.py", "run_tffm.py", "__graft_entry__.py")]
     for root in roots:
         for d, _, names in os.walk(root):
             files += [os.path.join(d, n) for n in names if n.endswith(".py")]
-    calls, missing = 0, []
+    specs, forced = 0, set()
     for path in files:
         with open(path) as fh:
             tree = ast.parse(fh.read())
         for node in ast.walk(tree):
-            if (isinstance(node, ast.Call)
-                    and isinstance(node.func, ast.Attribute)
+            if not isinstance(node, ast.Call):
+                continue
+            if (isinstance(node.func, ast.Attribute)
                     and node.func.attr == "from_config"
                     and isinstance(node.func.value, ast.Name)
                     and node.func.value.id == "ModelSpec"):
-                calls += 1
-                if not any(k.arg == "training" for k in node.keywords):
-                    missing.append(f"{path}:{node.lineno}")
-    assert calls >= 11 and missing == []
+                specs += 1
+                assert not node.keywords, f"{path}:{node.lineno}"
+            for k in node.keywords:
+                if (isinstance(k.value, ast.Constant)
+                        and (k.arg, k.value.value) in (
+                            ("dedup", "device"), ("raw_ids", True))):
+                    forced.add(os.path.relpath(path, REPO))
+    assert specs >= 11
+    assert forced == {os.path.join("fast_tffm_tpu", "serve", "server.py")}
 
 
 # ---- (b) the step's U, and the same three steps either way -------------
@@ -141,10 +150,10 @@ def test_one_chip_auto_step_runs_on_the_rung_of_distinct_rows(
     cfg = _cfg(_zipf_corpus(tmp_path, ffm),
                **(dict(model_type="ffm", field_num=4) if ffm else {}))
     monkeypatch.setattr(jax, "device_count", lambda: 1)
-    auto = ModelSpec.from_config(cfg, training=True)
+    auto = ModelSpec.from_config(cfg)
     assert auto.dedup == "host"
     explicit = ModelSpec.from_config(
-        dataclasses.replace(cfg, dedup="device"), training=True)
+        dataclasses.replace(cfg, dedup="device"))
     assert explicit.dedup == "device"
     t_a, acc_a, loss_a, fitted = _three_steps(cfg, auto)
     t_d, acc_d, loss_d, raw = _three_steps(cfg, explicit)
@@ -270,6 +279,37 @@ def test_new_metric_files_read_the_stream_or_nothing(tmp_path, name,
         assert value is None
     else:
         assert value == pytest.approx(want)
+
+
+@pytest.mark.parametrize("with_counter,want", [
+    (True, 19300 / 20480), (False, None)])   # ISSUE 45's change; its parent
+def test_the_sweeps_fill_metric_reads_the_stream_or_nothing(
+        tmp_path, with_counter, want):
+    """``validation_uniq_slot_fill``: the validation plane's counts,
+    which a sweep on raw ids (the parent's, serve's wire) never makes."""
+    from benchmarks.readers import telemetry_window
+    with open(os.path.join(REPO, "benchmarks", "layer_metrics",
+                           "validation_uniq_slot_fill.json")) as fh:
+        spec = json.load(fh)
+    with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
+        entry = next(m for m in json.load(fh)["per_layer"]
+                     if m["name"] == "validation_uniq_slot_fill")
+    assert entry == dict(
+        {k: spec[k] for k in ("name", "unit", "better", "source", "layer",
+                              "moves")}, workloads=["fm16-train-eval"])
+    assert spec["reader"] == "telemetry_window"
+    assert spec["layer"] == "host parse + build (data/)"
+    first = {"validation_plane/batches": 54, "train/examples": 16}
+    last = {"validation_plane/batches": 540, "train/examples": 816}
+    if with_counter:
+        first.update({"validation_plane/uniq_rows": 19300 * 54,
+                      "validation_plane/uniq_slots": 20480 * 54})
+        last.update({"validation_plane/uniq_rows": 19300 * 540,
+                     "validation_plane/uniq_slots": 20480 * 540})
+    ctx = {"telemetry_path": _stream(tmp_path, {16: first, 816: last}),
+           "window_steps": (16, 816), "window_wall_s": 30.0}
+    value = telemetry_window.read(ctx, **spec["args"])
+    assert value is None if want is None else value == pytest.approx(want)
 
 
 # ---- (e) a benchmark cell on one device, end to end --------------------
@@ -449,7 +489,7 @@ def test_the_step_at_the_fitted_rung_equals_the_step_at_the_power_of_two(
     ffm = model == "ffm"
     cfg = _cfg(_zipf_corpus(tmp_path, ffm, n=40),
                **(dict(model_type="ffm", field_num=4) if ffm else {}))
-    spec = ModelSpec.from_config(cfg, training=True)
+    spec = ModelSpec.from_config(cfg)
     batch, = batch_iterator(cfg, cfg.train_files, training=True)
     U, wide = len(batch.uniq_ids), 256
     assert U == 192           # 150 to 170 rows: between the powers of two

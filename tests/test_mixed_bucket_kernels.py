@@ -95,7 +95,7 @@ def test_an_explicit_pallas_step_runs_at_a_rung_that_is_no_power_of_two(
                    max_features_per_example=L, learning_rate=0.1,
                    model_file=str(tmp_path / "m" / "fm"))
     assert L in cfg.bucket_ladder
-    spec = ModelSpec.from_config(cfg, training=True)
+    spec = ModelSpec.from_config(cfg)
     assert (spec.kernel, spec.dedup) == ("pallas", "device")
     batch, = batch_iterator(cfg, [str(data)], training=True, epochs=1,
                             raw_ids=True)

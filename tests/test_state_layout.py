@@ -9,7 +9,6 @@ ask the TPU's own compiler, for a described v5e, what it chooses at the
 benchmark's two one-chip sizes."""
 
 import contextlib
-import dataclasses
 import os
 import re
 import subprocess
@@ -70,8 +69,7 @@ def _cfg(tmp_path, model, **kw):
 
 def _setup(tmp_path, model):
     cfg = _cfg(tmp_path, model)
-    spec = dataclasses.replace(ModelSpec.from_config(cfg, training=True),
-                               dedup="host")
+    spec = ModelSpec.from_config(cfg)
     batches = list(batch_iterator(cfg, [_corpus(tmp_path, model)],
                                   training=True, epochs=1))
     assert {b.vals.shape[-1] for b in batches} == {4, 8}

@@ -6,7 +6,6 @@ builders (C++ and Python) count the cells they cut at
 ``max_features_per_example``; the ``train/step`` span carries the width
 its batch shipped at, into the stream and into a profiler's trace."""
 
-import dataclasses
 import glob
 import json
 
@@ -67,8 +66,7 @@ def test_programs_and_switches_of_a_two_width_job(tmp_path, tel):
     steps whose program is not the last step's, and the second program
     takes the state as the first left it (nothing re-laid, ever)."""
     cfg = _cfg(tmp_path)
-    spec = dataclasses.replace(ModelSpec.from_config(cfg, training=True),
-                               dedup="host")
+    spec = ModelSpec.from_config(cfg)
     batches = list(batch_iterator(cfg, [_corpus(tmp_path)[0]],
                                   training=True, epochs=1))
     assert [b.vals.shape[1] for b in batches] == [4, 8, 8, 4, 4, 8]

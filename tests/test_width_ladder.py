@@ -156,7 +156,7 @@ def test_a_step_on_the_narrow_batch_is_the_step_on_the_wide_one(
     path = tmp_path / "train.txt"
     _write(path, model, np.random.default_rng(35), n_batches=1)
     cfg = _cfg(path, model)
-    spec = ModelSpec.from_config(cfg, training=True)
+    spec = ModelSpec.from_config(cfg)
     if shape is None:
         shards, place = None, (lambda **a: a)
         step = make_train_step(spec)

@@ -70,7 +70,7 @@ def probe_cell(L, dedup, k, B, steps, trials):
                        batch_size=B, max_features_per_example=L,
                        bucket_ladder=(L,), train_files=(path,),
                        dedup=dedup, shuffle=False)
-        spec = ModelSpec.from_config(cfg, training=True)
+        spec = ModelSpec.from_config(cfg)
         raw = spec.dedup == "device"
         batch = next(batch_iterator(cfg, cfg.train_files, training=True,
                                     raw_ids=raw))

@@ -88,7 +88,7 @@ def main():
                        hash_feature_id=True, lookup="host",
                        max_features_per_example=64, bucket_ladder=(64,),
                        train_files=(path,), shuffle=False)
-        spec = ModelSpec.from_config(cfg, training=True)
+        spec = ModelSpec.from_config(cfg)
 
         import jax
         baseline = memory_report()  # corpus transients already freed
