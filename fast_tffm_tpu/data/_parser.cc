@@ -609,8 +609,9 @@ extern "C" {
 // 9 = fm_bb_row_shards, fm_bb_uniq, fm_bb_cells and fm_bb_finish's
 // remap (a mesh's feed); 10 = the feature tokens skipped past the
 // per-example cap are counted (fm_parse_block's truncated_out,
-// fm_bb_truncated).
-int64_t fm_abi_version() { return 10; }
+// fm_bb_truncated); 11 = fm_bb_finish's row permutation (the shuffle's
+// within-batch order, written where the rows are padded out).
+int64_t fm_abi_version() { return 11; }
 
 // Scan complete lines of [blob, blob+blob_len) until `n_target` lines
 // that PRODUCE AN EXAMPLE have been seen. The counting rule must equal
@@ -1265,27 +1266,68 @@ void fm_bb_uniq(void* h, int32_t* uniq_out) {
 // every cell is written as remap[slot], for a caller that ships the
 // unique slots in another order (a mesh's feed, by owning row shard) —
 // the cells are re-pointed as they are padded out, not in a pass of
-// their own. Returns n_examples (0 if the batch is empty), -1 when
-// ``cols`` is too narrow (nothing is reset).
+// their own. ``perm`` (may be null; [n_examples], a permutation of
+// 0..n_examples-1): example r is written at row perm[r], labels and
+// fields with its cells (the shuffle's within-batch order, written
+// where the rows ship and not gathered afterwards); the rows past
+// n_examples, the padding block, stay at the tail. Returns n_examples
+// (0 if the batch is empty), -1 when ``cols`` is too narrow, -2 when
+// ``perm`` is no permutation (nothing is reset either way).
 int64_t fm_bb_finish(void* h, int64_t cols, float* labels_out,
                      int32_t* uniq_out, int32_t* li_out, float* vals_out,
-                     int32_t* fields_out, const int32_t* remap) {
+                     int32_t* fields_out, const int32_t* remap,
+                     const int32_t* perm) {
   auto* bb = static_cast<BatchBuilder*>(h);
   if (cols < bb->max_nnz || cols > bb->L || cols <= 0) return -1;
   const int64_t n = bb->n_ex;
+  // The rows are written in order, one after the other (fresh pages,
+  // sequential stores); under a permutation the staged example each
+  // row takes is read where the inverse points: src[perm[r]] = r.
+  std::vector<int32_t> src;
+  std::vector<size_t> start;  // where a staged example's cells begin
+  constexpr int64_t kAhead = 8;
+  if (perm != nullptr) {
+    src.assign(size_t(n), -1);
+    start.resize(size_t(n));
+    size_t z = 0;
+    for (int64_t r = 0; r < n; r++) {
+      const int32_t d = perm[r];
+      if (d < 0 || d >= n || src[size_t(d)] >= 0) return -2;
+      src[size_t(d)] = int32_t(r);
+      start[size_t(r)] = z;
+      z += size_t(bb->sizes[size_t(r)]);
+    }
+  }
   const size_t C = size_t(cols);
   const int32_t pad =
       bb->raw_ids ? int32_t(bb->vocab) : (remap != nullptr ? remap[0] : 0);
   const bool with_fields = bb->field_aware && fields_out != nullptr;
-  std::memcpy(labels_out, bb->labels.data(), size_t(n) * sizeof(float));
-  std::fill(labels_out + n, labels_out + bb->B, 0.0f);
   std::memcpy(uniq_out, bb->uniq.data(),
               size_t(bb->n_uniq) * sizeof(int32_t));
   size_t z = 0;
-  for (int64_t r = 0; r < bb->B; r++) {
-    const size_t nf = r < n ? size_t(bb->sizes[size_t(r)]) : 0;
-    int32_t* irow = li_out + size_t(r) * C;
-    float* vrow = vals_out + size_t(r) * C;
+  for (int64_t i = 0; i < bb->B; i++) {
+    size_t nf = 0;
+    float label = 0.0f;
+    if (i < n) {
+      const size_t r = perm != nullptr ? size_t(src[size_t(i)]) : size_t(i);
+      if (perm != nullptr) {
+        z = start[r];
+        if (i + kAhead < n) {  // the staged cells a later row will read
+          const size_t zn = start[size_t(src[size_t(i + kAhead)])];
+          for (size_t b = 0; b < C * sizeof(int32_t); b += 64) {
+            __builtin_prefetch(
+                reinterpret_cast<const char*>(bb->idx.data() + zn) + b);
+            __builtin_prefetch(
+                reinterpret_cast<const char*>(bb->vals.data() + zn) + b);
+          }
+        }
+      }
+      nf = size_t(bb->sizes[r]);
+      label = bb->labels[r];
+    }
+    labels_out[i] = label;
+    int32_t* irow = li_out + size_t(i) * C;
+    float* vrow = vals_out + size_t(i) * C;
     if (remap != nullptr) {
       const int32_t* cells = bb->idx.data() + z;
       for (size_t j = 0; j < nf; j++) irow[j] = remap[cells[j]];
@@ -1296,7 +1338,7 @@ int64_t fm_bb_finish(void* h, int64_t cols, float* labels_out,
     std::memcpy(vrow, bb->vals.data() + z, nf * sizeof(float));
     std::memset(vrow + nf, 0, (C - nf) * sizeof(float));
     if (with_fields) {
-      int32_t* frow = fields_out + size_t(r) * C;
+      int32_t* frow = fields_out + size_t(i) * C;
       std::memcpy(frow, bb->fields.data() + z, nf * sizeof(int32_t));
       std::memset(frow + nf, 0, (C - nf) * sizeof(int32_t));
     }

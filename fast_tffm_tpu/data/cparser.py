@@ -116,7 +116,7 @@ def _build(out: str) -> None:
 
 # Must equal fm_abi_version() in _parser.cc. Bump both together whenever
 # an exported signature changes.
-_ABI_VERSION = 10
+_ABI_VERSION = 11
 
 
 def _open_checked(path: str) -> ctypes.CDLL:
@@ -230,7 +230,8 @@ def _load() -> ctypes.CDLL:
             np.ctypeslib.ndpointer(np.int32),             # local_idx
             np.ctypeslib.ndpointer(np.float32),           # vals
             np.ctypeslib.ndpointer(np.int32),             # fields
-            ctypes.c_void_p]                              # remap or NULL
+            ctypes.c_void_p,                              # remap or NULL
+            ctypes.c_void_p]                              # perm or NULL
         lib.fm_bb_cells.restype = ctypes.c_int64
         lib.fm_bb_cells.argtypes = [ctypes.c_void_p]
         lib.fm_bb_truncated.restype = ctypes.c_int64
@@ -493,7 +494,7 @@ class BatchBuilder:
             tel.count("pipeline/bytes_fed", consumed.value)
         return rc == 1, consumed.value
 
-    def finish(self, cols=None, slots=None):
+    def finish(self, cols=None, slots=None, rows=None):
         """-> (n_examples, labels[B], uniq[n_uniq], local_idx[B,C],
         vals[B,C], fields[B,C]-or-None, max_nnz); resets the builder.
         C is ``max_cols``, or ``cols(max_nnz)`` where a caller fits the
@@ -503,7 +504,11 @@ class BatchBuilder:
         how the unique slots ship (pipeline._BatchEmitter.slots): the
         tuple then holds ``uniq_ids`` for ``uniq``, and where ``remap``
         is not None every cell of ``local_idx`` is ``remap[slot]``,
-        re-pointed as the cells are padded out. ``self.cells`` then
+        re-pointed as the cells are padded out. ``rows(n_examples) ->
+        perm`` (or None) gives the shuffle's within-batch order
+        (pipeline._BatchEmitter.row_perm): example ``r`` is written at
+        row ``perm[r]``, its label with it, as the rows are padded out;
+        the padding block stays at the tail. ``self.cells`` then
         holds the batch's feature cells (padding not counted) and
         ``self.truncated`` the feature tokens skipped past the
         per-example cap since the last finish()."""
@@ -511,8 +516,14 @@ class BatchBuilder:
         self.truncated = int(self._lib.fm_bb_truncated(self._h))
         n_uniq = ctypes.c_int64(0)
         max_nnz = ctypes.c_int64(0)
-        self._lib.fm_bb_peek(self._h, ctypes.byref(n_uniq),
-                             ctypes.byref(max_nnz))
+        n_ex = self._lib.fm_bb_peek(self._h, ctypes.byref(n_uniq),
+                                    ctypes.byref(max_nnz))
+        perm = None if rows is None else rows(int(n_ex))
+        if perm is not None:
+            perm = np.ascontiguousarray(perm, np.int32)
+            if len(perm) != n_ex:
+                raise ValueError(f"finish: a permutation of {len(perm)} "
+                                 f"rows for a batch of {n_ex}")
         C = self.L if cols is None else int(cols(int(max_nnz.value)))
         labels = np.empty(self.B, np.float32)
         uniq = np.empty(n_uniq.value, np.int32)
@@ -528,7 +539,11 @@ class BatchBuilder:
                           np.int32)
         n = self._lib.fm_bb_finish(
             self._h, C, labels, uniq, li, vals, fields,
-            None if remap is None else remap.ctypes.data)
+            None if remap is None else remap.ctypes.data,
+            None if perm is None else perm.ctypes.data)
+        if n == -2:
+            raise ValueError("finish: rows() gave no permutation of the "
+                             "batch's examples")
         if n < 0:
             raise ValueError(f"finish: {C} columns do not hold the "
                              f"batch's widest example ({max_nnz.value}) "

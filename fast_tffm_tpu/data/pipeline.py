@@ -924,16 +924,23 @@ def _make_builder(cfg: FmConfig, B: int, raw_ids: bool, keep_empty: bool,
 
 class _BatchEmitter:
     """Builder-output tuple -> DeviceBatch, plus the window-shuffle
-    drain: ONE implementation shared by the serial fast path and the
-    parallel ring coordinator. The host_threads=1 vs >1 bit-identical
-    parity guarantee rests on this being the same object — same rng
-    construction, same draw order per emitted batch, same window
-    bookkeeping — fed batches in the same stream order."""
+    drain: ONE implementation shared by the serial fast path, the
+    parallel ring coordinator and stream mode. The host_threads=1 vs
+    >1 bit-identical parity guarantee rests on this being the same
+    object, fed batches in the same stream order: the window's picks
+    come from one generator in emit order, and a shuffled batch's row
+    order from ``(the emitter's seed, the batch's number in the
+    stream)`` alone (``row_perm``), so whoever runs ``finish`` (this
+    thread or a build worker) writes the same rows in the same places
+    and a batch the spill-rewind protocol discards takes no draw from
+    another. ``_emit`` gathers nothing: a batch is permuted once, where
+    the builder pads it out."""
 
     def __init__(self, cfg: FmConfig, B: int, L_cap: int,
                  fixed_shape: bool, uniq_bucket: int, shuffle: bool,
                  seed: Optional[int], stats: Optional[SpillStats],
-                 shards: Optional[RowShards] = None):
+                 shards: Optional[RowShards] = None,
+                 counters: str = TRAIN_PLANE):
         self.cfg = cfg
         self.B = B
         self.L_cap = L_cap
@@ -943,7 +950,9 @@ class _BatchEmitter:
         self.shuffle = shuffle
         self.stats = stats
         self.pyrng = random.Random(cfg.seed if seed is None else seed)
-        self.nprng = np.random.default_rng(self.pyrng.getrandbits(64))
+        self.perm_seed = self.pyrng.getrandbits(64)
+        self.seq = 0  # batches finish()ed here: the serial path's count
+        self._emit_span = counters + "/emit"  # the plane's own name
         self.window: List[DeviceBatch] = []
         self.window_cap = (max(2, cfg.queue_size // B) if shuffle
                            else 1)
@@ -979,24 +988,43 @@ class _BatchEmitter:
         uniq_ids[:len(uniq)] = uniq  # slot 0 already pad_id (C++)
         return uniq_ids, None
 
-    def finish(self, bb):
+    def row_perm(self, seq: int, n: int) -> Optional[np.ndarray]:
+        """Where the ``n`` real rows of the stream's ``seq``-th batch
+        ship under shuffle: row ``r`` at ``perm[r]``. Only the real
+        rows move: consumers rely on the padding block staying at the
+        tail ([:num_real] slicing). Pure, so build workers draw it."""
+        if not self.shuffle or n <= 1:
+            return None
+        return np.random.default_rng((self.perm_seed, seq)).permutation(n)
+
+    def finish(self, bb, seq: Optional[int] = None):
         """A builder's batch as ``emit_drain`` takes it: its finish()
-        at the width and in the slots it ships, its cell count and
-        the cells its lines lost at the per-example cap. Pure in
-        ``bb``: build workers run it."""
-        return bb.finish(self.cols, self.slots) + (bb.cells,
-                                                   bb.truncated)
+        at the width, in the slots and in the row order it ships, its
+        cell count and the cells its lines lost at the per-example
+        cap. ``seq``: the batch's number in the emitted stream, from a
+        coordinator that hands groups to build workers (pure in ``bb``
+        then); None counts the batches finished here, the serial
+        path's."""
+        serial = seq is None
+        out = bb.finish(self.cols, self.slots, functools.partial(
+            self.row_perm, self.seq if serial else seq))
+        if serial and out[0]:
+            self.seq += 1
+        return out + (bb.cells, bb.truncated)
 
     def emit_drain(self, out, spilled: bool) -> Iterator[DeviceBatch]:
         """Emit one ``finish(bb)`` tuple and drain through the bounded
         shuffle window (a passthrough when shuffle is off)."""
-        batch = self._emit(*out, spilled=spilled)
-        if self.shuffle:
-            self.window.append(batch)
-            if len(self.window) >= self.window_cap:
-                yield self.window.pop(
+        from fast_tffm_tpu.obs.trace import span
+        with span(self._emit_span,
+                  seconds=self._emit_span + "_seconds"):
+            batch = self._emit(*out, spilled=spilled)
+            if self.shuffle:
+                self.window.append(batch)
+                batch = (self.window.pop(
                     self.pyrng.randrange(len(self.window)))
-        else:
+                    if len(self.window) >= self.window_cap else None)
+        if batch is not None:
             yield batch
 
     def flush_window(self) -> Iterator[DeviceBatch]:
@@ -1022,15 +1050,6 @@ class _BatchEmitter:
         weights = np.zeros(B, np.float32)
         weights[:n] = 1.0
         labels[n:] = 0.0  # C++ buffer may hold stale labels past n
-        if self.shuffle and n > 1:
-            # Permute only the real rows: consumers rely on the padding
-            # block staying at the tail ([:num_real] slicing).
-            perm = np.concatenate([self.nprng.permutation(n),
-                                   np.arange(n, B)])
-            labels, weights = labels[perm], weights[perm]
-            li, vals = li[perm], vals[perm]
-            if fields is not None:
-                fields = fields[perm]
         return DeviceBatch(labels=labels, weights=weights,
                            uniq_ids=uniq_ids, local_idx=li, vals=vals,
                            fields=fields, num_real=n,
@@ -1191,14 +1210,17 @@ class _Group:
     """One dispatched line group: the raw bytes of exactly one batch's
     worth of example-producing lines (newline-terminated), plus its
     stream provenance — the count of stream lines before it and inside
-    it (for error rebasing and spill rewind)."""
+    it (for error rebasing and spill rewind). ``seq``: the number its
+    batch has in the emitted stream (what a shuffled batch's row order
+    is drawn from), set by the coordinator that submits it."""
 
-    __slots__ = ("blob", "line_start", "lines")
+    __slots__ = ("blob", "line_start", "lines", "seq")
 
     def __init__(self, blob: bytes, line_start: int, lines: int):
         self.blob = blob
         self.line_start = line_start
         self.lines = lines
+        self.seq = 0
 
 
 class _GroupScanner:
@@ -1330,8 +1352,9 @@ class _FastWorkerState:
         self._make_builder = make_builder
         self.bb = make_builder()
         # How a built batch leaves the builder (_BatchEmitter.finish:
-        # the width and the slots it ships at, its cell count).
-        self.finish = finish or (lambda bb: bb.finish())
+        # the width, the slots and the row order it ships at, its cell
+        # count).
+        self.finish = finish or (lambda bb, seq: bb.finish())
         self.fed = 0  # lines consumed by self.bb since creation
 
     def reset(self) -> None:
@@ -1355,7 +1378,7 @@ def _fast_group_work(state: _FastWorkerState, group: _Group):
     fed_before = state.fed
     try:
         _full, consumed = bb.feed(group.blob, 0)
-        out = state.finish(bb)
+        out = state.finish(bb, group.seq)
     except ParseError as e:
         state.reset()
         m = _LINE_MSG.match(str(e))
@@ -1398,9 +1421,11 @@ def _parallel_fast_batch_iterator(cfg: FmConfig, files: List[str],
     - each group meets a fresh-state builder (finish() resets; the C++
       library clears row buffers per batch), so batch arrays cannot
       depend on which worker built them or what it built before;
-    - batches re-serialize in group order, and all shuffle-window/rng
-      work happens in the shared _BatchEmitter on the consuming side —
-      same rng, same draw order as serial;
+    - batches re-serialize in group order; the window's picks happen
+      in the shared _BatchEmitter on the consuming side (same rng, same
+      draw order as serial), and a shuffled batch's row order is drawn
+      in the worker's finish() from the batch's number in the stream
+      (_BatchEmitter.row_perm), which serial counts the same way;
     - a unique-budget spill (fixed-U mode) invalidates every in-flight
       group past it and re-cuts from the spilled line — the serial
       stream's requeue replayed at group granularity; speculative work
@@ -1416,9 +1441,10 @@ def _parallel_fast_batch_iterator(cfg: FmConfig, files: List[str],
                                      shards=row_shards)
     emitter = _BatchEmitter(cfg, B, effective_L_cap(cfg), fixed_shape,
                             uniq_bucket, shuffle, seed, stats,
-                            shards=row_shards)
+                            shards=row_shards, counters=counters)
     retry = RetryPolicy.from_config(cfg)
     file_seed = cfg.seed if seed is None else seed
+    emitted = 0  # batches handed to the emitter: the stream's count
     ring = _BuildRing(workers, depth=2 * workers,
                       work=_fast_group_work,
                       make_state=lambda: _FastWorkerState(
@@ -1460,6 +1486,10 @@ def _parallel_fast_batch_iterator(cfg: FmConfig, files: List[str],
                     if g is None:
                         scan_done = True
                         break
+                    # The batch's number in the stream: a rewind drops
+                    # what is in flight, and the re-cut groups count on
+                    # from the spilled batch.
+                    g.seq = emitted + len(order)
                     s = ring.submit(g)
                     inflight[s] = g
                     order.append(s)
@@ -1479,6 +1509,7 @@ def _parallel_fast_batch_iterator(cfg: FmConfig, files: List[str],
                     raise payload
                 out, consumed = payload
                 spilled = consumed < len(g.blob)
+                emitted += 1
                 yield from emitter.emit_drain(out, spilled)
                 if spilled:
                     # Rewind: the unconsumed tail of this group plus
@@ -1509,7 +1540,8 @@ def _fast_batch_iterator(cfg: FmConfig, bb, files: List[str], B: int,
                          uniq_bucket: int = 0,
                          stats: Optional[SpillStats] = None,
                          file_marks: Optional[FileMarks] = None,
-                         row_shards: Optional[RowShards] = None
+                         row_shards: Optional[RowShards] = None,
+                         counters: str = TRAIN_PLANE
                          ) -> Iterator[DeviceBatch]:
     """Chunked C++ fast path: raw file bytes stream straight into the
     C++ BatchBuilder (parse + hash + dedup + padded scatter in one native
@@ -1529,13 +1561,15 @@ def _fast_batch_iterator(cfg: FmConfig, bb, files: List[str], B: int,
     caps each batch's unique rows; a too-dense batch closes early with
     n < B real examples (the spill protocol) and shapes stay constant.
 
-    Emission (stats counting, window shuffle, per-batch row
-    permutation) is the shared _BatchEmitter — the same code the
-    parallel plane's ring coordinator runs, which is what makes
+    Emission (stats counting, window shuffle) and the per-batch row
+    permutation handed to the builder's finish() are the shared
+    _BatchEmitter's — the same code the parallel plane's ring
+    coordinator and its workers run, which is what makes
     ``host_threads`` a pure throughput knob (bit-identical streams).
     """
     emitter = _BatchEmitter(cfg, B, bb.L, fixed_shape, uniq_bucket,
-                            shuffle, seed, stats, shards=row_shards)
+                            shuffle, seed, stats, shards=row_shards,
+                            counters=counters)
 
     tail = b""
     fed_lines = 0       # complete lines fed to the builder so far —
@@ -1845,7 +1879,7 @@ def _batch_iterator_impl(cfg: FmConfig, files: Sequence[str],
                     cfg, bb, files, B, n_epochs, do_shuffle, seed,
                     fixed_shape, shard_index, num_shards, uniq_bucket,
                     stats=stats, file_marks=file_marks,
-                    row_shards=row_shards)
+                    row_shards=row_shards, counters=counters)
             return
     # Blank-line-preserving parse rides the C++ block parser too since
     # ABI 7 (keep_empty mode); _parse_block threads the flag through.
@@ -2236,6 +2270,12 @@ def prefetch(iterator: Iterator[DeviceBatch], depth: int = 2,
     Python and would CONTEND with jax dispatch on a single core
     (measured 4x slower in round 2, when Python was the only parser) —
     that combination keeps the passthrough.
+
+    What this hands over are HOST batches. Where they cross to the
+    device is the consumer's: the train loop's feed adds a stage of its
+    own behind this one (``place_ahead``: wire encoding and placement a
+    batch ahead, off the loop's thread), a validation sweep and
+    predict place on their own threads as they dispatch.
     """
     if gil_bound:
         if _host_cpus() <= 1:
@@ -2270,11 +2310,49 @@ def prefetch(iterator: Iterator[DeviceBatch], depth: int = 2,
         LEDGER.release("prefetch_batches")
 
 
+def place_ahead(batches: Iterator[DeviceBatch], place,
+                depth: int) -> Iterator[tuple]:
+    """The feed's last stage: ``(batch, placed)`` for every batch of
+    ``batches``. ``place(batch) -> (batch, placed)`` (train.py
+    ``StepLoop.feed_place``: wire encoding and host-to-device
+    placement) runs on a thread of its own, ``fm-place``, at most
+    ``depth`` batches ahead of the consumer, under the span
+    ``feed/place`` [``train/place_seconds``]: no leaf of the loop's
+    partition, since the loop's thread does not wait for it. A separate
+    stage and not the emitting thread's work: emit and placement in
+    series would be one thread's. What ``place`` raises is raised at
+    the consumer's next(); a consumer that stops closes the stage, and
+    the batches it had placed are let go with it. ``place`` None (the
+    loop places for itself): every batch with ``placed`` None, on the
+    consumer's thread."""
+    feed = _each_placed(batches, place)
+    return feed if place is None else _read_ahead(feed, depth, "fm-place")
+
+
+def _each_placed(batches: Iterator[DeviceBatch], place) -> Iterator[tuple]:
+    from fast_tffm_tpu.obs.trace import span
+    try:
+        for batch in batches:
+            if place is None:
+                yield batch, None
+                continue
+            with span("feed/place", seconds="train/place_seconds"):
+                item = place(batch)
+            yield item
+    finally:  # closed with the stage: the stages behind it stop too
+        batches.close()
+
+
 def _read_ahead(iterator: Iterator, depth: int, name: str) -> Iterator:
     """``iterator`` run on a daemon thread called ``name``, at most
     ``depth`` items ahead of the consumer; what it raises is raised
-    here. Shared by prefetch() (batches ahead of the step loop) and the
-    parallel plane's group scanner (groups ahead of the build ring)."""
+    here. Shared by prefetch() (batches ahead of the step loop), the
+    parallel plane's group scanner (groups ahead of the build ring) and
+    place_ahead() (placed batches ahead of the step loop). Closing it
+    stops the thread, closes ``iterator`` on the thread that ran it (a
+    generator's ``finally`` blocks stop the stages behind it) and waits
+    for the thread, bounded: what it held is let go before the caller
+    goes on."""
     import queue
     import threading
 
@@ -2285,18 +2363,23 @@ def _read_ahead(iterator: Iterator, depth: int, name: str) -> Iterator:
 
     def worker():
         try:
-            for item in iterator:
-                # Bounded put + stop checks so an abandoned consumer
-                # (step raised, caller broke out) can't strand this
-                # thread blocked forever holding file handles/batches.
-                while not stop.is_set():
-                    try:
-                        q.put(item, timeout=0.1)
-                        break
-                    except queue.Full:
-                        continue
-                if stop.is_set():
-                    return
+            try:
+                for item in iterator:
+                    # Bounded put + stop checks so an abandoned consumer
+                    # (step raised, caller broke out) can't strand this
+                    # thread blocked forever holding file handles/batches.
+                    while not stop.is_set():
+                        try:
+                            q.put(item, timeout=0.1)
+                            break
+                        except queue.Full:
+                            continue
+                    if stop.is_set():
+                        return
+            finally:
+                close = getattr(iterator, "close", None)
+                if close is not None:
+                    close()
         except BaseException as e:  # re-raised on the consumer side
             errbox.append(e)
         finally:
@@ -2311,7 +2394,8 @@ def _read_ahead(iterator: Iterator, depth: int, name: str) -> Iterator:
 
     # Named thread: span events from the pipeline carry the thread name
     # as their Perfetto track (tools/fmtrace).
-    threading.Thread(target=worker, name=name, daemon=True).start()
+    thread = threading.Thread(target=worker, name=name, daemon=True)
+    thread.start()
     try:
         while True:
             item = q.get()
@@ -2322,6 +2406,12 @@ def _read_ahead(iterator: Iterator, depth: int, name: str) -> Iterator:
             yield item
     finally:
         stop.set()
+        try:  # a put that waits for room goes through, and sees the stop
+            while True:
+                q.get_nowait()
+        except queue.Empty:
+            pass
+        thread.join(timeout=5.0)
 
 
 def _salvage_block(lines: Sequence[str], cfg: FmConfig,

@@ -304,7 +304,7 @@ class RunTelemetry:
         residue every flush derives partition it."""
         self._loop_t = time.perf_counter()
         for name in ("train/loop_seconds", "train/slow_steps",
-                     *LOOP_LEAVES):
+                     *LOOP_LEAVES, *FEED_PLACE):
             self.registry.count(name, 0)
         self._loop_flushed = {}
 
@@ -477,7 +477,8 @@ class RunTelemetry:
 
     def train_step(self, dt: float, n_examples: int,
                    h2d_bytes: int,
-                   h2d_bytes_logical: Optional[int] = None) -> None:
+                   h2d_bytes_logical: Optional[int] = None,
+                   placed_ahead: bool = False) -> None:
         """Per-train-step host-side points: wall time between step
         dispatches (NOT a device sync — the honest measurable without a
         fetch), examples, H2D payload bytes.
@@ -488,9 +489,12 @@ class RunTelemetry:
         sizes the padded layout the legacy wire would have shipped, so
         the packed-vs-padded savings ratio is observable per run
         (fmstat's bytes-per-example row). Omitted = same as actual
-        (the padded wire)."""
+        (the padded wire). ``placed_ahead``: the step's batch reached
+        ``StepLoop.step`` already placed (by the feed's own thread)."""
         self.observe("train/step_seconds", dt)
         self.count("train/steps")
+        if placed_ahead:
+            self.count(PLACED_AHEAD)
         self.count("train/examples", n_examples)
         self.count("train/h2d_bytes", h2d_bytes)
         self.count("train/h2d_bytes_logical",
@@ -607,6 +611,15 @@ ANATOMY_PHASES: Dict[str, Phase] = {
 }
 LOOP_LEAVES = tuple(p.counter for p in ANATOMY_PHASES.values() if p.leaf)
 LOOP_UNNAMED = "train/loop_unnamed_seconds"
+# Placement a batch ahead, OFF the loop's thread (train.py
+# ``StepLoop.feed_place`` under data/pipeline.py ``place_ahead``): the
+# steps whose batch reached ``StepLoop.step`` already placed, beside
+# ``train/steps``, and the placing thread's seconds inside encode +
+# place (span ``feed/place`` on the thread ``fm-place``). No phase of
+# the loop: ``train/h2d`` stays the name of placement done ON the
+# loop's thread, so the leaves keep summing to the loop thread's wall.
+PLACED_AHEAD, PLACE_SECONDS = FEED_PLACE = ("train/placed_ahead",
+                                            "train/place_seconds")
 
 
 def loop_partition(counters: Dict[str, float]) -> Dict[str, float]:

@@ -26,7 +26,7 @@ from fast_tffm_tpu.checkpoint import (CheckpointState,
                                       export_npz, resume_start_epoch)
 from fast_tffm_tpu.config import FmConfig
 from fast_tffm_tpu.data.badlines import BadLineTracker
-from fast_tffm_tpu.data.pipeline import (SPILL_WARN_FRACTION,
+from fast_tffm_tpu.data.pipeline import (SPILL_WARN_FRACTION, place_ahead,
                                          VALIDATION_PLANE, SpillStats,
                                          batch_iterator,
                                          gil_bound_iteration,
@@ -1252,6 +1252,8 @@ class StepLoop:
         self.barrier = None
         self.barrier_sweep = 0.0    # the validation sweep's wall inside it
         self.completed_epochs = s.start_epoch
+        self.placed = None  # (wb, args) of the next batch, where the feed
+        # placed it ahead (_run_epochs); step() takes it and lets it go
         self.last_periodic_save = (None, None)  # (step, epoch) of the latest
         # Streaming run mode (README "Streaming / online learning"):
         # the durable stream position adopted from STEPPED batches —
@@ -1266,10 +1268,11 @@ class StepLoop:
 
     # -- one step ---------------------------------------------------
 
-    def place(self, batch, wb):
+    def place(self, batch, wb, ahead: int = 0):
         """This dispatch path's host-to-device placement of one
         encoded batch (the offload step takes host arrays and never
-        gets here)."""
+        gets here); ``ahead``: placed batches queued beyond the two of
+        the double buffer."""
         s = self.s
         if s.mesh is not None and batch.row_shards != s.mesh_devices:
             # The mesh step cannot see this: it reads a row outside
@@ -1288,13 +1291,21 @@ class StepLoop:
         # explicit async put rides the copy stream while the
         # PREVIOUS step is still executing, instead of serializing
         # at the head of this step's dispatch.
-        return s.wire_enc.device_put(wb)
+        return s.wire_enc.device_put(wb, window=2 + ahead)
+
+    def feed_place(self, batch):
+        """A batch as the feed hands it to the loop where batches are
+        final when emitted: encoded and placed on the feed's own thread
+        (pipeline.place_ahead), up to ``prefetch_depth`` ahead."""
+        wb = self.s.wire_enc.encode_train(batch)
+        return batch, (wb, self.place(batch, wb, self.s.cfg.prefetch_depth))
 
     def wire_place(self, batch, step):
-        """Encode one batch and place its arrays for dispatch. h2d_bytes
-        = wb.wire_bytes sizes the arrays ACTUALLY shipped; the padded
-        layout's size rides on wb.logical_bytes for the savings counter.
-        ``step`` rides both spans: the h2d span's is the cross-rank key."""
+        """Encode one batch and place its arrays for dispatch, on the
+        loop's own thread (where the feed does not: ``_run_epochs``).
+        h2d_bytes = wb.wire_bytes sizes the arrays ACTUALLY shipped; the
+        padded layout's size rides on wb.logical_bytes for the savings
+        counter. ``step`` rides both spans (the h2d's: cross-rank key)."""
         with span("train/encode", seconds="train/encode_seconds",
                   step=step):
             wb = self.s.wire_enc.encode_train(batch)
@@ -1339,7 +1350,9 @@ class StepLoop:
         loop's TensorBoard writer) and ``gauges`` (a callable the
         stream loop sets its freshness gauges with, just ahead of a
         due telemetry flush) are the two things a mode hands in;
-        everything else a mode does, it does around this call."""
+        everything else a mode does, it does around this call. Where
+        the feed placed the batch ahead (``self.placed``) it is
+        dispatched as it came; else the loop places it (``wire_place``)."""
         s = self.s
         cfg, tel, vocab = s.cfg, s.tel, s.vocab
         step = self.global_step + 1
@@ -1351,7 +1364,9 @@ class StepLoop:
             with span("train/batch_checks",
                       seconds="train/batch_checks_seconds", step=step):
                 batch = vocab.ensure_current(batch)
-        wb, args = self.wire_place(batch, step)
+        placed, self.placed = self.placed, None
+        ahead = placed is not None  # placed by the feed, on its thread
+        wb, args = placed or self.wire_place(batch, step)
         out = self.dispatch(wb, args, step)
         self.end_barrier()  # an enclosure: closed before the next leaf opens
         # From here to the flush: one leaf of the loop's wall. Nothing in
@@ -1361,7 +1376,7 @@ class StepLoop:
             # The last step's state and loss and this step's placed batch
             # are let go HERE, under the phase (250 us a step: PERF.md).
             self.table, self.acc, self.loss, _ = out
-            del out, args
+            del out, args, placed
             self.global_step = step
             self.last_val = None  # table advanced; a cached AUC is stale
             if vocab is not None:
@@ -1383,7 +1398,7 @@ class StepLoop:
                 now = time.perf_counter()
                 dt, self.t_prev = now - self.t_prev, now
                 tel.train_step(dt, batch.num_real, wb.wire_bytes,
-                               wb.logical_bytes)
+                               wb.logical_bytes, ahead)
                 if dt >= SLOW_STEP_SECONDS:
                     tel.slow_step(step, dt, epoch=epoch)
                 tel.heartbeat(step)  # the watchdog's beat (obs/health.py)
@@ -1680,10 +1695,15 @@ def _run_epochs(s: _Session, loop: StepLoop) -> None:
         if loop.stopping:
             break
         epoch_stats = SpillStats()
+        # The feed places where a batch is final when emitted; the loop,
+        # where a publish barrier may re-point a queued batch (admit), the
+        # processes agree before anything is placed, or under offload.
+        place = (None if s.vocab is not None or s.multi_process or s.offload
+                 else loop.feed_place)
         # Threads, builders, files: until the first next() can be called.
         with span("pipeline/open", seconds="pipeline/open_seconds",
                   epoch=epoch):
-            it = prefetch(batch_iterator(
+            it = place_ahead(prefetch(batch_iterator(
                 cfg, cfg.train_files, training=True,
                 weight_files=cfg.weight_files,
                 shard_index=s.shard_index, num_shards=s.num_shards,
@@ -1693,44 +1713,49 @@ def _run_epochs(s: _Session, loop: StepLoop) -> None:
                 bad_lines=s.bad_tracker, vocab=s.vocab,
                 row_shards=s.row_shards),
                 depth=cfg.prefetch_depth,
-                gil_bound=gil_bound_iteration(cfg, cfg.weight_files))
+                gil_bound=gil_bound_iteration(cfg, cfg.weight_files)),
+                place, cfg.prefetch_depth)
         # fmlint: disable=R003 -- anchors the per-epoch
         # step-seconds window (always-on aggregate)
         loop.t_prev = time.perf_counter()
         first = True  # the cold plane's first batch goes by its own name
-        while True:
-            # Consumer-side stall: time blocked INSIDE next() only —
-            # bracketing it any wider would fold end-of-step bookkeeping
-            # (notably live-mode's deliberate float(loss) device sync)
-            # into the host-bound signal and misdiagnose a device-bound
-            # run (the producer-side build cost is timed separately in
-            # pipeline.batch_iterator on the worker thread).
-            with span("pipeline/first_batch" if first else "train/input_wait",
-                      seconds="train/input_wait_seconds",
-                      step=loop.global_step + 1) as wait:
-                batch = next(it, None)
-            if first and s.tel is not None:
-                s.tel.count("pipeline/first_batch_seconds", wait.dur)
-            first = False
-            batch = _agreed_batch(s, loop, batch, epoch)
-            # fmlint: disable=R014 -- _agreed_batch returns None on
-            # every process together in multi-process mode (it agrees on
-            # exhaustion and preemption through the train/step_flags
-            # allgather first); single-process, the loop's collectives
-            # are gated on multi_process, so this escape leaves no peer
-            # unmatched
-            if batch is None:
-                break
-            loop.step(batch, epoch, summaries=s.summaries)
-            if cfg.save_steps and loop.global_step % cfg.save_steps == 0:
-                with span("train/checkpoint_pause",
-                          seconds="train/checkpoint_pause_seconds",
-                          step=loop.global_step) as pause:
-                    # Host-offload state: wait, because the background
-                    # writer would race the in-place numpy Adagrad updates.
-                    loop.save(loop.completed_epochs, wait=s.offload)
-                if s.tel is not None:  # keep the pause out of the next
-                    loop.t_prev += pause.dur  # step's step_seconds sample
+        try:
+            while True:
+                # Consumer-side stall: time blocked INSIDE next() only.
+                # Any wider would fold end-of-step bookkeeping (notably
+                # live-mode's deliberate float(loss) device sync) into the
+                # host-bound signal and misdiagnose a device-bound run (the
+                # build cost is timed on the producing threads).
+                with span("pipeline/first_batch" if first
+                          else "train/input_wait",
+                          seconds="train/input_wait_seconds",
+                          step=loop.global_step + 1) as wait:
+                    batch, loop.placed = next(it, (None, None))
+                if first and s.tel is not None:
+                    s.tel.count("pipeline/first_batch_seconds", wait.dur)
+                first = False
+                batch = _agreed_batch(s, loop, batch, epoch)
+                # fmlint: disable=R014 -- _agreed_batch returns None on
+                # every process together in multi-process mode (it agrees
+                # on exhaustion and preemption through the
+                # train/step_flags allgather first); single-process, the
+                # loop's collectives are gated on multi_process, so this
+                # escape leaves no peer unmatched
+                if batch is None:
+                    break
+                loop.step(batch, epoch, summaries=s.summaries)
+                if cfg.save_steps and loop.global_step % cfg.save_steps == 0:
+                    with span("train/checkpoint_pause",
+                              seconds="train/checkpoint_pause_seconds",
+                              step=loop.global_step) as pause:
+                        # Host-offload state: wait, the background writer
+                        # would race the in-place numpy Adagrad updates.
+                        loop.save(loop.completed_epochs, wait=s.offload)
+                    if s.tel is not None:  # keep the pause out of the next
+                        loop.t_prev += pause.dur  # step's step_seconds
+        finally:  # a step that raised, a preemption: the feed's threads
+            loop.placed = None  # stop, and what they placed is let go
+            it.close()
         _epoch_barrier(s, loop, epoch, epoch_stats)
 
 

@@ -289,7 +289,7 @@ class WireEncoder:
                          logical_bytes=logical, host_uniq=host_uniq)
 
     # -- the depth-2 double buffer ---------------------------------------
-    def device_put(self, wb: WireBatch) -> Dict[str, Any]:
+    def device_put(self, wb: WireBatch, window: int = 2) -> Dict[str, Any]:
         """Explicit async H2D of the encoded args — the double-buffered
         half of the wire layer. Dispatch is async, so by the time this
         runs for batch N, batch N-1's step is still executing on the
@@ -298,14 +298,16 @@ class WireEncoder:
         serializing at the head of N's step execution (the padded-era
         behavior, where the jit call transferred its numpy args
         inline). Single-device paths only — the mesh/lockstep paths
-        have their own placement (shard_batch / global_batch)."""
+        have their own placement (shard_batch / global_batch).
+        ``window``: how many batches' buffers stand on the device at
+        once, for the ledger (a feed that places ahead holds more)."""
         import jax
         from fast_tffm_tpu.obs.memory import LEDGER
         # Ledger (obs/memory.py): depth-2 window — this batch's bytes
         # on the copy stream plus the previous batch's still feeding
         # the executing step. wire_bytes is host metadata; an upsert
         # per put, no device interaction.
-        LEDGER.register("wire_buffers", 2 * wb.wire_bytes)
+        LEDGER.register("wire_buffers", window * wb.wire_bytes)
         return jax.device_put(wb.args)
 
 

@@ -17,9 +17,9 @@ import pytest
 from fast_tffm_tpu import train as train_mod
 from fast_tffm_tpu.obs.attribution import attribution, render, summarize
 from fast_tffm_tpu.obs.sink import read_events
-from fast_tffm_tpu.obs.telemetry import (ANATOMY_PHASES, LOOP_LEAVES,
-                                         LOOP_UNNAMED, RunTelemetry,
-                                         loop_partition)
+from fast_tffm_tpu.obs.telemetry import (ANATOMY_PHASES, FEED_PLACE,
+                                         LOOP_LEAVES, LOOP_UNNAMED,
+                                         RunTelemetry, loop_partition)
 
 from tests.test_health_trace import _train_cfg
 
@@ -129,6 +129,33 @@ def test_no_two_leaves_of_the_loop_thread_overlap(phased_run):
     assert not [s for s in _spans(events, *LEAF_SPANS) if s["tid"] != tid]
 
 
+def test_feed_place_is_off_the_loop_and_on_no_leaf_list(phased_run):
+    """The feed's placement (ISSUE 46) runs on a thread of its own: its
+    span is on that thread's track and on no leaf list, its seconds
+    count beside the partition and not into it, every step's batch
+    reaches the loop placed, and ``train/h2d`` (placement ON the loop's
+    thread) stays 0 in a run whose feed places."""
+    _, events = phased_run
+    place = _spans(events, "feed/place")
+    assert len(place) == 8
+    assert {s["tid"] for s in place} == {"fm-place"}
+    assert _loop_thread(events) != "fm-place"
+    assert "feed/place" not in LEAF_SPANS
+    assert not set(FEED_PLACE) & set(LOOP_LEAVES)
+    assert not _spans(events, "train/h2d", "train/encode")
+    c = [e for e in events if e["event"] == "metrics"][-1]["counters"]
+    assert c["train/placed_ahead"] == c["train/steps"] == 8
+    assert c["train/h2d_seconds"] == c["train/encode_seconds"] == 0
+    assert c["train/place_seconds"] == pytest.approx(
+        sum(s["dur"] for s in place), rel=1e-9)
+    # the emitting thread's own seconds, a batch: counted where it emits
+    emit = _spans(events, "pipeline/emit")
+    assert len(emit) == 8 and _loop_thread(events) not in {
+        s["tid"] for s in emit}
+    assert c["pipeline/emit_seconds"] == pytest.approx(
+        sum(s["dur"] for s in emit), rel=1e-9)
+
+
 def test_the_loop_threads_spans_nest(phased_run):
     """Enclosures too: a span of the loop's thread lies inside the one
     open when it began or after it (the epoch barrier ends before the
@@ -190,17 +217,23 @@ def test_the_readme_lists_every_leaf_of_the_one_list():
 
 # ---- a slow step says where it was slow ------------------------------------
 
+@pytest.mark.parametrize("stage,kw", [
+    # the feed places ahead: its last stage is what the loop waits for
+    ("place_ahead", {}),
+    # the loop places for itself (admit): the prefetch is
+    ("prefetch", {"vocab_mode": "admit", "hash_feature_id": True}),
+])
 def test_a_stalled_next_yields_one_slow_step_naming_input_wait(
-        tmp_path, monkeypatch, capsys):
+        tmp_path, monkeypatch, capsys, stage, kw):
     cfg = _train_cfg(tmp_path, np.random.default_rng(1), epoch_num=1,
-                     validation_files=())
-    real = train_mod.prefetch
+                     validation_files=(), **kw)
+    real = getattr(train_mod, stage)
     # The constant comes down around the stall only: a CPU's first
     # compile (step 1) is slow too, and is not what is tested.
     monkeypatch.setattr(train_mod, "SLOW_STEP_SECONDS", 1e9)
 
-    def stalled(it, **kw):
-        for i, batch in enumerate(real(it, **kw)):
+    def stalled(it, *a, **kw):
+        for i, batch in enumerate(real(it, *a, **kw)):
             if i == 2:
                 monkeypatch.setattr(train_mod, "SLOW_STEP_SECONDS", 0.25)
                 time.sleep(0.6)
@@ -208,7 +241,7 @@ def test_a_stalled_next_yields_one_slow_step_naming_input_wait(
                 monkeypatch.setattr(train_mod, "SLOW_STEP_SECONDS", 1e9)
             yield batch
 
-    monkeypatch.setattr(train_mod, "prefetch", stalled)
+    monkeypatch.setattr(train_mod, stage, stalled)
     train_mod.train(cfg)
     path = cfg.model_file + ".metrics.jsonl"
     events = list(read_events(path))
@@ -257,7 +290,14 @@ def test_slow_step_differences_against_the_last_flush(tmp_path):
 
 # ---- the contract the spans keep -----------------------------------------
 
-def test_zero_midstream_fetches_with_every_new_span_on(tmp_path, monkeypatch):
+@pytest.mark.parametrize("kw,absent", [
+    # the feed places: encode and h2d open on no thread, feed/place does
+    ({}, {"train/encode", "train/h2d"}),
+    # the loop places for itself (admit): today's sequence
+    ({"vocab_mode": "admit", "hash_feature_id": True}, {"feed/place"}),
+])
+def test_zero_midstream_fetches_with_every_new_span_on(tmp_path, monkeypatch,
+                                                       kw, absent):
     """Spans, their JSONL events, the residue and the loop's clock are
     host values: with all of it on at a flush every step, bulk_fetch
     still runs only at the two epoch barriers."""
@@ -272,14 +312,16 @@ def test_zero_midstream_fetches_with_every_new_span_on(tmp_path, monkeypatch):
     monkeypatch.setattr(fetch, "bulk_fetch", counting)
     monkeypatch.setattr(train_mod, "SLOW_STEP_SECONDS", 0.0)  # every step
     cfg = _train_cfg(tmp_path, np.random.default_rng(2), trace_spans=True,
-                     metrics_flush_steps=1)
+                     metrics_flush_steps=1, **kw)
     train_mod.train(cfg)
     assert calls == [5, 5]      # loss x4 + AUC, one call a barrier
     events = list(read_events(cfg.model_file + ".metrics.jsonl"))
-    assert {s["name"] for s in _spans(events)} >= LEAF_SPANS - {
+    names = {s["name"] for s in _spans(events)}
+    assert names >= (LEAF_SPANS | {"feed/place"}) - absent - {
         "train/step_flags", "stream/step_flags", "train/checkpoint_pause",
         "checkpoint/publish", "train/summary_flush", "train/loss_sync",
         "train/log_line", "validation/lockstep"}
+    assert not names & absent
     assert len([e for e in events if e["event"] == "slow_step"]) >= 8
 
 

@@ -96,6 +96,125 @@ def test_fast_path_parity_shuffle(tmp_path):
                    it_kw=dict(epochs=2, seed=11))
 
 
+def _rows(b):
+    """A batch's real rows as a sorted multiset of (label, weight, the
+    table rows its cells name, values, fields): what a permutation of
+    the rows leaves alone. Cells are read through ``uniq_ids``, so a
+    mesh feed's re-pointed slots compare by the rows they mean."""
+    n = b.num_real
+    ids = b.local_idx[:n] if b.uniq_ids is None else np.asarray(
+        b.uniq_ids)[b.local_idx[:n]]
+    return sorted(
+        (float(b.labels[r]), float(b.weights[r]), ids[r].tobytes(),
+         b.vals[r].tobytes(),
+         None if b.fields is None else b.fields[r].tobytes())
+        for r in range(n))
+
+
+@needs_cpp
+@pytest.mark.parametrize("kw", [
+    dict(),                                             # one device's feed
+    dict(mesh=4),                                       # a mesh's: remap
+    dict(model_type="ffm", field_num=4),                # fields move too
+    dict(raw_ids=True),
+])
+@pytest.mark.parametrize("workers", [1, 4])
+def test_a_shuffled_batch_is_a_row_permutation_of_the_unshuffled_one(
+        tmp_path, kw, workers):
+    """ISSUE 46: the shuffle's row order is written by the builder's
+    finish(), from (the epoch's seed, the batch's number in the stream).
+    The window over whole batches may hand them over in another order,
+    so batches pair by their examples: a shuffled batch holds one
+    unshuffled batch's examples, each whole (label, cells, values,
+    fields; a mesh feed's cells re-pointed alike), in another order;
+    the padding block of the short last batch stays at the tail with
+    weight 0; the unique slots do not move."""
+    from fast_tffm_tpu.data.pipeline import RowShards
+    kw = dict(kw)
+    mesh, raw = kw.pop("mesh", 0), kw.pop("raw_ids", False)
+    path = _write(tmp_path, n=150, seed=8)
+    if kw.get("model_type") == "ffm":
+        lines = open(path).read().split("\n")
+        open(path, "w").write("\n".join(
+            " ".join([ln.split()[0]] + [f"{i % 4}:{tok}" for i, tok in
+                                        enumerate(ln.split()[1:])])
+            for ln in lines if ln) + "\n")
+
+    def batches(shuffle, seed=11):
+        cfg = _cfg(path, workers, shuffle=shuffle, queue_size=16, **kw)
+        return list(batch_iterator(
+            cfg, cfg.train_files, training=True, epochs=1, seed=seed,
+            raw_ids=raw, row_shards=RowShards.of(cfg, mesh) if mesh
+            else None))
+
+    plain, mixed = batches(False), batches(True)
+    assert len(plain) == len(mixed) == 10 and plain[-1].num_real == 6
+    by_rows = {repr(_rows(b)): b for b in plain}
+    assert len(by_rows) == 10
+    moved = 0
+    for b in mixed:
+        a = by_rows.pop(repr(_rows(b)))     # the same examples, each whole
+        n = b.num_real
+        assert n == a.num_real
+        if a.uniq_ids is not None:
+            np.testing.assert_array_equal(b.uniq_ids, a.uniq_ids)
+        assert b.row_shards == a.row_shards == (mesh or 1)
+        # the padding block: at the tail, as the unshuffled batch has it
+        np.testing.assert_array_equal(b.weights, a.weights)
+        assert b.weights[:n].all() and not b.weights[n:].any()
+        for name in ("labels", "local_idx", "vals"):
+            np.testing.assert_array_equal(getattr(b, name)[n:],
+                                          getattr(a, name)[n:])
+        moved += not np.array_equal(b.local_idx, a.local_idx)
+    assert not by_rows and moved >= 9
+    # one seed repeats, another seed and another epoch's differ
+    again, other = batches(True), batches(True, seed=12)
+    assert [_key(b) for b in again] == [_key(b) for b in mixed]
+    assert [_key(b) for b in other] != [_key(b) for b in mixed]
+    cfg = _cfg(path, workers, shuffle=True, queue_size=16, **kw)
+    two = list(batch_iterator(cfg, cfg.train_files, training=True, epochs=2,
+                              seed=11, raw_ids=raw))
+    if not mesh:
+        assert [_key(b) for b in two[:10]] == [_key(b) for b in mixed]
+    assert sorted(map(repr, map(_rows, two[10:]))) == sorted(
+        map(repr, map(_rows, two[:10])))
+    assert [_key(b) for b in two[10:]] != [_key(b) for b in two[:10]]
+
+
+@needs_cpp
+@pytest.mark.parametrize("workers", [1, 4])
+def test_a_plane_that_does_not_train_is_not_permuted(tmp_path, workers):
+    """``training=False`` (a validation sweep, predict): shuffle on in
+    the configuration, the lines in file order all the same."""
+    path = _write(tmp_path, n=100, seed=9)
+    on = _cfg(path, workers, shuffle=True, queue_size=16)
+    off = _cfg(path, workers, shuffle=False)
+    assert [_key(b) for b in batch_iterator(on, on.train_files,
+                                            training=False)] == [
+        _key(b) for b in batch_iterator(off, off.train_files,
+                                        training=False)]
+
+
+@needs_cpp
+def test_emit_gathers_nothing(tmp_path):
+    """The emitting thread permutes no array: a batch is permuted once,
+    where the builder pads it out (``fm_bb_finish``), and the arrays
+    ``_emit`` is handed are the arrays the batch ships."""
+    import inspect
+    from fast_tffm_tpu.data import pipeline
+    src = inspect.getsource(pipeline._BatchEmitter._emit)
+    assert "perm" not in src and "nprng" not in src
+    emitter = pipeline._BatchEmitter(
+        _cfg(_write(tmp_path), 1, shuffle=True), 16, 16, False, 0, True,
+        3, None)
+    bb = pipeline._make_builder(emitter.cfg, 16, False, False, False, 0)
+    bb.feed(open(emitter.cfg.train_files[0], "rb").read())
+    out = emitter.finish(bb)
+    batch = emitter._emit(*out)
+    assert batch.labels is out[1] and batch.local_idx is out[3]
+    assert batch.vals is out[4]
+
+
 @needs_cpp
 def test_fast_path_parity_keep_empty(tmp_path):
     s = _assert_parity(_write(tmp_path, blanks=True),
@@ -410,38 +529,51 @@ def test_build_ring_orders_and_recovers():
 
 @needs_cpp
 def test_parallel_build_scales(tmp_path):
-    """Tier-1 scaling smoke for the BENCH host_only row: the 4-worker
-    plane must beat the serial plane by >= 1.3x on a synthetic Criteo-
-    like corpus. Same-window INTERLEAVED paired ratios (the repo's A/B
-    doctrine — see test_threaded_builder_scales): each trial measures
-    W=1 and W=4 back to back and the best paired ratio decides, so
-    ambient load on a shared host can't flake the gate; the bar exists
-    to catch the plane accidentally SERIALIZING (~1.0x), not to pin
-    the ~2-3x a quiet multi-core box shows."""
-    if (os.cpu_count() or 1) < 4:
-        pytest.skip("needs >= 4 cores to measure scaling")
+    """Tier-1 scaling smoke for the parallel plane: its four workers
+    build side by side. Asserted as what it means, from the plane's own
+    spans, and not as a ratio of two rates (the 1.3x gate of W=4 over
+    W=1 measured the host's load: it failed on a quiet sandbox with
+    either plane's code, and took turns with its siblings in the
+    driver's runs, ROADMAP D13): the seconds inside
+    ``pipeline/build_worker`` spans, summed over the ``fm-build-<i>``
+    threads, are at least one and a half times the wall they span (3.1
+    on a quiet sandbox, 2.4 beside six busy processes: a starved
+    scanner leaves workers without a group), every worker has built,
+    and the workers' intervals overlap. A plane that serialised (a held
+    GIL, one worker fed at a time) reads 1.0 whatever the host's
+    load."""
+    from fast_tffm_tpu.obs.sink import read_events
+    from fast_tffm_tpu.obs.telemetry import RunTelemetry, activate
     rng = np.random.default_rng(0)
     lines = []
     for _ in range(40000):
         ids = rng.choice(100000, size=39, replace=False)
         lines.append("1 " + " ".join(f"{j}:1.5" for j in ids))
     path = tmp_path / "big.txt"
-    path.write_text("\n".join(lines) + "\n")
-
-    def rate(w):
-        cfg = FmConfig(vocabulary_size=100000, batch_size=8192,
-                       train_files=(str(path),), shuffle=False,
-                       max_features_per_example=48, bucket_ladder=(48,),
-                       host_threads=w)
-        n = 0
-        t0 = time.perf_counter()
-        for b in batch_iterator(cfg, cfg.train_files, training=True):
-            n += b.num_real
-        return n / (time.perf_counter() - t0)
-
-    ratios = []
-    for _ in range(4):
-        r1 = rate(1)
-        ratios.append(rate(4) / r1)
-    assert max(ratios) >= 1.3, (
-        f"W=4/W=1 paired ratios {[f'{r:.2f}' for r in ratios]}")
+    path.write_text(("\n".join(lines) + "\n") * 3)
+    cfg = FmConfig(vocabulary_size=100000, batch_size=8192,
+                   train_files=(str(path),), shuffle=True,
+                   max_features_per_example=48, bucket_ladder=(48,),
+                   host_threads=4)
+    tel = RunTelemetry(str(tmp_path / "m.jsonl"), meta={}, trace_spans=True)
+    with activate(tel):
+        n = sum(b.num_real for b in batch_iterator(
+            cfg, cfg.train_files, training=True, epochs=1))
+    tel.close()
+    assert n == 3 * len(lines)
+    spans = [e for e in read_events(str(tmp_path / "m.jsonl"))
+             if e["event"] == "span" and e["name"] == "pipeline/build_worker"]
+    assert len(spans) == 15     # 120,000 lines in batches of 8192
+    by_worker = {}
+    for e in spans:
+        by_worker.setdefault(e["tid"], []).append((e["ts"], e["ts"] + e["dur"]))
+    assert sorted(by_worker) == [f"fm-build-{i}" for i in range(4)]
+    wall = max(b for _, b in sum(by_worker.values(), [])) - min(
+        a for a, _ in sum(by_worker.values(), []))
+    busy = sum(e["dur"] for e in spans)
+    assert busy >= 1.5 * wall, (
+        f"{busy:.3f} s inside build spans over {wall:.3f} s of wall: "
+        f"{busy / wall:.2f} workers building on average, of 4")
+    lo = [min(a for a, _ in v) for v in by_worker.values()]
+    hi = [max(b for _, b in v) for v in by_worker.values()]
+    assert max(lo) < min(hi)

@@ -382,10 +382,13 @@ def test_each_shards_rows_add_up_to_the_uncut_gather(tmp_path):
 
 def test_mesh_run_feeds_the_counters_a_reader_needs(tmp_path):
     """ISSUE 27, step 4: on the mesh path ``train/examples`` counts
-    the global batch, ``train/h2d`` covers ``shard_batch`` with the
-    bytes of the arrays shipped, the unique counters are fed with the
-    mesh's U, and ``train/mesh_devices`` tells the run from a
-    one-device run's stream."""
+    the global batch, ``shard_batch`` is timed with the bytes of the
+    arrays shipped (since ISSUE 46 by the feed's own thread, as
+    ``feed/place`` [``train/place_seconds``]: one process, so every
+    batch reaches the loop placed and ``train/h2d`` stays 0), the
+    unique counters are fed with the mesh's U, and
+    ``train/mesh_devices`` tells the run from a one-device run's
+    stream."""
     from fast_tffm_tpu.obs.sink import read_events
     from fast_tffm_tpu.train import train
     rng = np.random.default_rng(29)
@@ -407,4 +410,5 @@ def test_mesh_run_feeds_the_counters_a_reader_needs(tmp_path):
     # labels, weights, uniq_ids[U], local_idx[B, L], vals[B, L]: 4 B each
     assert c["train/h2d_bytes"] == (
         3 * (32 * 4 * 2 + 2 * 32 * 8 * 4) + 4 * c["pipeline/uniq_slots"])
-    assert c["train/h2d_seconds"] > 0
+    assert c["train/place_seconds"] > 0 and c["train/h2d_seconds"] == 0
+    assert c["train/placed_ahead"] == c["train/steps"]

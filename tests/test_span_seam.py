@@ -244,14 +244,29 @@ def traced_run(tmp_path_factory):
     return train_events, list(read_events(cfg.model_file + ".metrics.jsonl"))
 
 
+@pytest.fixture(scope="module")
+def loop_placed_run(tmp_path_factory):
+    """The same two epochs under ``vocab_mode = admit``: a publish
+    barrier may re-point a queued batch, so the loop encodes and places
+    for itself (``StepLoop.wire_place``), as every run did until the
+    feed placed ahead (ISSUE 46)."""
+    from fast_tffm_tpu.train import train
+    d = tmp_path_factory.mktemp("seam_loop")
+    cfg = _train_cfg(d, np.random.default_rng(0), trace_spans=True,
+                     log_steps=2, vocab_mode="admit", hash_feature_id=True)
+    train(cfg)
+    return list(read_events(cfg.model_file + ".metrics.jsonl"))
+
+
 @pytest.mark.parametrize("names,counter,count", [
     # 8 + 2 ends; an epoch's first wait (2) goes by another name
     ("train/input_wait pipeline/first_batch", "train/input_wait_seconds",
      10),
     ("pipeline/first_batch", "pipeline/first_batch_seconds", 2),
     ("train/batch_checks", "train/batch_checks_seconds", 10),
-    ("train/encode", "train/encode_seconds", 8),
-    ("train/h2d", "train/h2d_seconds", 8),
+    # encode + placement, a batch ahead on the feed's own thread
+    ("feed/place", "train/place_seconds", 8),
+    ("pipeline/emit", "pipeline/emit_seconds", 8),
     ("train/step", "train/dispatch_seconds", 8),
     ("train/bookkeeping", "train/bookkeeping_seconds", 8),
     ("train/loss_sync", "train/loss_sync_seconds", 4),
@@ -278,6 +293,31 @@ def test_train_loop_phase(traced_run, names, counter, count):
         sum(s["dur"] for s in spans), rel=1e-9)
 
 
+@pytest.mark.parametrize("name,counter", [
+    ("train/encode", "train/encode_seconds"),
+    ("train/h2d", "train/h2d_seconds"),
+])
+def test_train_loop_phase_where_the_loop_places(loop_placed_run, name,
+                                                counter):
+    """Re-pinned from ``test_train_loop_phase`` (ISSUE 46): encode and
+    h2d are phases of the loop's thread where the loop places for
+    itself, 8 spans each, and nothing is placed ahead there."""
+    spans = [e for e in loop_placed_run if e["event"] == "span"
+             and e["name"] == name]
+    assert len(spans) == 8
+    (tid,) = {e["tid"] for e in loop_placed_run if e["event"] == "span"
+              and e["name"] == "train/step"}
+    assert {s["tid"] for s in spans} == {tid}
+    counters = [e for e in loop_placed_run
+                if e["event"] == "metrics"][-1]["counters"]
+    assert counters[counter] == pytest.approx(
+        sum(s["dur"] for s in spans), rel=1e-9)
+    assert counters["train/placed_ahead"] == 0
+    assert counters["train/place_seconds"] == 0
+    assert not [e for e in loop_placed_run if e["event"] == "span"
+                and e["name"] == "feed/place"]
+
+
 def test_the_epoch_barrier_encloses_its_parts(traced_run):
     events, _ = traced_run
     spans = [e for e in events if e["event"] == "span"]
@@ -295,13 +335,19 @@ def test_the_epoch_barrier_encloses_its_parts(traced_run):
     assert "pipeline/start" not in {s["name"] for s in spans}
 
 
-@pytest.mark.parametrize("which", ["mid-epoch", "epoch's last"])
-def test_a_loss_line_syncs_after_the_next_batch_is_placed(traced_run, which):
+@pytest.mark.parametrize("which", ["mid-epoch", "epoch's last",
+                                   "mid-epoch, the loop placing"])
+def test_a_loss_line_syncs_after_the_next_batch_is_placed(
+        traced_run, loop_placed_run, which):
     """A live loss line waits for the device only once the next batch is
     fetched and placed, just ahead of its dispatch, so the device waits
     for the host one dispatch after a line and not a placement too; the
-    epoch's last line syncs before the barrier opens."""
+    epoch's last line syncs before the barrier opens. Where the feed
+    places (ISSUE 46) the batch arrives placed, and nothing of the
+    loop's lies between the last dispatch and the sync."""
     events, _ = traced_run
+    if which.endswith("the loop placing"):
+        events = loop_placed_run
     spans = sorted((e for e in events if e["event"] == "span"
                     and e["name"] in ("train/loss_sync", "train/h2d",
                                       "train/step", "train/epoch_barrier")),
@@ -309,9 +355,11 @@ def test_a_loss_line_syncs_after_the_next_batch_is_placed(traced_run, which):
     names = [s["name"] for s in spans]
     syncs = [i for i, n in enumerate(names) if n == "train/loss_sync"]
     assert len(syncs) == 4          # steps 2, 4 (epoch 1), 6, 8 (epoch 2)
-    if which == "mid-epoch":
+    if which.startswith("mid-epoch"):
+        before = ("train/h2d" if which.endswith("the loop placing")
+                  else "train/step")    # step 3's, step 7's h2d; or none
         for i in (syncs[0], syncs[2]):
-            assert names[i - 1] == "train/h2d"      # step 3's, step 7's
+            assert names[i - 1] == before
             assert names[i + 1] == "train/step"
             assert (spans[i]["ts"] + spans[i]["dur"]
                     <= spans[i + 1]["ts"] + 1e-6)
