@@ -7,7 +7,7 @@ ships in this environment (SURVEY.md §0: no network). This module
 synthesizes data with the distributional properties that make Criteo
 hard — and, unlike the real thing, a KNOWN generative model, so measured
 AUC can be compared against an independent oracle trained on the same
-draws (tests/test_criteo_like.py, tools/criteo_bench.py):
+draws (tests/test_criteo_like.py):
 
 - 26 categorical fields with mixed vocabulary sizes (tens to ~100k) and
   Zipf-skewed id frequencies (head ids dominate, a long rare tail);

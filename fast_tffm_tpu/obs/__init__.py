@@ -1,7 +1,7 @@
 """obs/ — unified run telemetry (ISSUE 2) + timeline/health (ISSUE 3).
 
 A dependency-free metrics registry (counters, gauges, fixed-bucket
-histograms), a buffered JSONL sink that follows the same link-safety
+histograms), a buffered JSONL sink that follows the same sync-safety
 discipline as ``utils/summaries.ScalarSummaries`` (device scalars are
 buffered and bulk-fetched only at epoch/flush barriers, never per
 step), and the per-run wiring that lets every stage — data pipeline,

@@ -19,7 +19,7 @@ chunks the validation AUC update consumes (``train.evaluate`` passes
 it into its ChunkedFetcher callback; the lockstep path folds its four
 sums into the existing AUC-histogram allgather payload), so the
 quality loop introduces no device fetch beyond the sweep's own D2H —
-the same link-safety discipline as the rest of obs/.
+the same sync-safety discipline as the rest of obs/.
 
 Multi-host: every worker computes the same deterministic decision from
 the same merged AUC, and the chief's decision is additionally

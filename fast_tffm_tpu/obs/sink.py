@@ -1,14 +1,13 @@
-"""Buffered JSONL event sink with ScalarSummaries' link-safety rules.
+"""Buffered JSONL event sink with ScalarSummaries' sync-safety rules.
 
 Events buffer in host memory and reach disk only at ``flush()`` —
 called from the flush-step cadence and epoch barriers, never per step.
 Device scalars (a jitted step's loss is a device array; materializing
-it mid-stream stalls the async dispatch pipeline, for seconds on a
-slow device link) are buffered
-AS DEVICE REFERENCES and bulk-fetched in ONE ``utils/fetch.bulk_fetch``
-transfer at ``barrier()`` — the epoch-boundary call — with the same
-1024-entry safety cap as ``train.LOG_BUFFER_MAX``. A plain ``flush()``
-performs zero device fetches, so a mid-epoch flush cadence
+it mid-stream stalls the async dispatch pipeline until the device has
+caught up, on any device) are buffered AS DEVICE REFERENCES and fetched
+in ONE ``utils/fetch.bulk_fetch`` at ``barrier()``, the epoch-boundary
+call, under a 1024-entry safety cap (``SCALAR_BUFFER_MAX``). A plain
+``flush()`` performs zero device fetches, so a mid-epoch flush cadence
 (``metrics_flush_steps``) costs file I/O only.
 
 Thread-safety: ``emit``/``flush``/``close`` serialize on one internal
@@ -16,7 +15,7 @@ lock — span events arrive from the prefetch and fetcher worker
 threads, and health events from the watchdog thread, concurrently with
 the driver's flush cadence. ``add_scalar``/``barrier`` stay
 driver-thread-only (they are the device-reference path; see the
-link-safety contract above).
+sync-safety contract above).
 
 The barrier drain is also the run-health seam for non-finite values
 (obs/health.py): the loss scalars are ALREADY host-side right after
@@ -45,10 +44,9 @@ import threading
 import time
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
-# Buffered device-scalar cap — the same bound (and rationale) as
-# train.LOG_BUFFER_MAX / summaries.SUMMARY_BUFFER_MAX: a tiny cadence
-# on a months-long epoch must not retain unbounded device scalars; one
-# rare mid-epoch bulk sync is the lesser evil.
+# Buffered device-scalar cap: a tiny cadence on a months-long epoch
+# must not retain unbounded device scalars; one rare mid-epoch bulk
+# sync is the lesser evil.
 SCALAR_BUFFER_MAX = 1024
 
 # Host-event buffer cap: spans at per-batch cadence with an epoch-only
@@ -62,7 +60,7 @@ RING_EVENTS = 32
 
 class JsonlSink:
     """Append-mode JSONL writer; see module docstring for the buffering
-    and link-safety contract."""
+    and sync-safety contract."""
 
     def __init__(self, path: str, meta: Optional[Dict[str, Any]] = None):
         d = os.path.dirname(os.path.abspath(path))
@@ -137,8 +135,8 @@ class JsonlSink:
     def _drain_scalars(self) -> None:
         if not self._scalars:
             return
-        # ONE grouped-stacking transfer for the whole buffer (the same
-        # entry point ScalarSummaries.flush and train.flush_log use).
+        # ONE bulk fetch for the whole buffer (the entry point
+        # ScalarSummaries.flush uses too).
         from fast_tffm_tpu.utils.fetch import bulk_fetch
         rows: List[Tuple[str, int, float]] = []
         bulk_fetch([(v, (name, step))

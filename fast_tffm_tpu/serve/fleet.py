@@ -128,6 +128,7 @@ class ReplicaProc:
                                     clock=clock)
         self.proc: Optional[subprocess.Popen] = None
         self.probe_failures = 0
+        self.death_seen = False  # this child's exit has been recorded
         self._clock = clock
         # Wedge clock: last moment this replica answered healthz (or
         # was spawned — a fresh child gets the full silence window to
@@ -170,6 +171,7 @@ class ReplicaProc:
              self.cfg_path],
             env=env, stdout=self._log_fh, stderr=subprocess.STDOUT)
         self.probe_failures = 0
+        self.death_seen = False
         self.last_answer = self._clock()
         self._logger.info(
             "fleet: replica %d%s spawned (pid %d, port %d)",
@@ -409,11 +411,14 @@ class FleetSupervisor:
         i = r.index
         if r.exited():
             r.row.set_health(False, False)
-            if r.proc is not None and r.probe_failures == 0:
+            if r.proc is not None and not r.death_seen:
                 # First observation of this death: schedule the
-                # backed-off restart.
+                # backed-off restart. A flag of its own, not the probe
+                # count: a probe that failed just ahead of the exit (a
+                # starved host, a kill between the two checks) must
+                # not hide the death from the count and the backoff.
                 delay = r.policy.record_death()
-                r.probe_failures = 1
+                r.death_seen = True
                 self._reg.count("fleet/deaths")
                 self._logger.warning(
                     "fleet: replica %d (pid %s) exited rc=%s; restart "

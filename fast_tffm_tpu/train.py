@@ -45,22 +45,10 @@ from fast_tffm_tpu.obs.telemetry import (active, make_telemetry,
                                          pop_active, push_active)
 from fast_tffm_tpu.obs.trace import begin, span
 from fast_tffm_tpu.parallel.sharded import evaluate_distributed
-from fast_tffm_tpu.utils.fetch import ChunkedFetcher, bulk_fetch, note_link
+from fast_tffm_tpu.utils.fetch import ChunkedFetcher
 from fast_tffm_tpu.utils.logging import get_logger
 from fast_tffm_tpu.utils.timing import StepTimer
 
-
-# First-log-step probe threshold (train()): a materialized-scalar fetch
-# slower than this marks the device link as slow and defers loss log
-# lines to epoch boundaries. Module-level so tests can force either
-# mode.
-LIVE_FETCH_BUDGET_S = 0.005
-
-# Deferred-mode loss-log buffer cap: scalar device arrays retained
-# between flushes. Deliberately its own constant — FETCH_CHUNK_BATCHES
-# is tuned for bulk [B]-score memory, and retuning that must not change
-# how often a slow link pays a mid-epoch log sync.
-LOG_BUFFER_MAX = 1024
 
 # A step or epoch barrier whose wall reaches this says where it was
 # slow (RunTelemetry.slow_step). Steps take 6 to 20 ms on the chip, the
@@ -733,7 +721,6 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
                 logger.info("writing TensorBoard summaries every %d steps "
                             "to %s", cfg.save_summaries_steps,
                             s.summaries.logdir)
-        loop.log_mode = _probe_link(cfg, logger)
         if tel is not None:
             tel.loop_start()  # stopped in _finish, after the last step
         if s.stream_mode:
@@ -846,14 +833,13 @@ def _close_sinks(s: _Session, loop) -> None:
             s.ckpt.close()
         except Exception:
             logger.exception("checkpoint close failed")
-    # A crash mid-epoch must not drop everything buffered since the
-    # last flush — the log buffer and the TensorBoard scalars drain
-    # here.
+    # A step that raised must not drop what was queued before it: the
+    # owed loss line and the TensorBoard scalars drain here.
     if loop is not None:
         try:
-            loop.flush_log()
+            loop.sync_live_line()
         except Exception:
-            logger.exception("deferred loss-log flush failed")
+            logger.exception("the owed loss line's sync failed")
     if s.summaries is not None:
         # Buffered scalars must reach the event file even when the
         # loop raised or a preemption cut the final epoch.
@@ -1171,51 +1157,6 @@ def _gate_published(s: _Session, decision) -> None:
         write_gate_baseline(s.ckpt.directory, gate.baseline)
 
 
-# Adaptive loss logging. float(loss) is a synchronous device->host
-# fetch: a mid-stream scalar fetch stalls async dispatch until the
-# device has caught up. On a direct-attached device the fetch itself
-# costs microseconds; over a slow or proxied link it can cost seconds
-# (copy_to_host_async is no better). So the link is measured once: if
-# the fetch is cheap, logging stays live (the normal-hardware
-# behavior); if not, loss values are buffered ON DEVICE (scalars) and
-# flushed at epoch boundaries — a natural barrier — with correct
-# per-step attribution.
-def _probe_link(cfg: FmConfig, logger) -> str:
-    """``"live"`` or ``"deferred"``. Probes the link BEFORE the hot
-    loop, with an empty dispatch queue: a mid-stream probe on a slow
-    link drains the queue through the slow path, where this costs one
-    clean round-trip."""
-    if cfg.log_steps <= 0:
-        return "deferred"  # mode never consulted without log lines
-    # fmlint: disable=R013 -- a one-scalar link-latency probe,
-    # not a batch: the wire encoder has nothing to encode here
-    probe = jax.device_put(np.float32(0.0))
-    jax.block_until_ready(probe)
-    float(probe)  # throwaway: lazy transfer-path init stays untimed
-    cost = float("inf")
-    for _ in range(3):  # min of 3: jitter must not misclassify
-        # fmlint: disable=R003 -- this IS the link probe's
-        # deliberate timer, before the hot loop starts
-        t0 = time.perf_counter()
-        # fmlint: disable=R001 -- this IS the link probe: one
-        # deliberate timed scalar fetch, before the hot loop starts
-        float(probe)
-        # fmlint: disable=R003 -- closes the probe sample
-        cost = min(cost, time.perf_counter() - t0)
-    note_link(cost)  # the barrier's drain of buffered scalars asks it
-    if cost < LIVE_FETCH_BUDGET_S:
-        # Log the decision either way: a user wondering why loss
-        # lines are (or aren't) live gets the probe's answer.
-        logger.info("scalar fetch costs %.3f ms on this device link; "
-                    "loss log lines stay live", cost * 1e3)
-        return "live"
-    logger.info(
-        "scalar fetch costs %.0f ms on this device link; deferring "
-        "loss log lines to epoch boundaries to keep the dispatch "
-        "pipeline hot", cost * 1e3)
-    return "deferred"
-
-
 class StepLoop:
     """The one step body both run modes drive, and the state a step
     advances: the train state (``table``, ``acc``), ``global_step``,
@@ -1241,9 +1182,7 @@ class StepLoop:
         # (auc, n) of the most recent validation pass of the table as
         # it stands; a step clears it.
         self.last_val = None
-        self.log_mode = "deferred"  # set from _probe_link before a step
-        self.log_buffer: list = []  # deferred: (step, epoch, loss_arr, eps)
-        self.live_line: list = []   # live: the one line whose sync is due
+        self.live_line: list = []   # the one loss line whose sync is due
         # Where the train/step_seconds sample of the next step starts;
         # a mode's loop re-anchors it around its own pauses.
         self.t_prev = time.perf_counter()
@@ -1321,7 +1260,7 @@ class StepLoop:
         enqueue), so time spent HERE is queue backpressure — the
         previous program still executing somewhere. Runs under
         oom_guard: a RESOURCE_EXHAUSTED here re-raises with the
-        per-owner ledger attached (obs/memory.py). A live loss line
+        per-owner ledger attached (obs/memory.py). A loss line
         still owed (log_tick) is synced first."""
         self.sync_live_line()
         s = self.s
@@ -1418,7 +1357,7 @@ class StepLoop:
                 summaries.add("train/examples_per_sec", step, eps_now)
             if tel_due:
                 # loss is a DEVICE scalar: buffered, fetched only at the
-                # next barrier flush (sink link-safety contract).
+                # next barrier flush (sink sync-safety contract).
                 tel.add_scalar("train/loss", step, self.loss)
                 tel.set("train/examples_per_sec_window", eps_now)
                 if gauges is not None:
@@ -1426,9 +1365,6 @@ class StepLoop:
         if tel_due:
             with span("obs/flush", seconds="obs/flush_seconds", step=step):
                 tel.maybe_flush(step)  # file I/O only
-        # log_steps=1 on a months-long epoch: one rare sync, bounded memory
-        if len(self.log_buffer) >= LOG_BUFFER_MAX:
-            self.flush_log()
 
     def end_barrier(self) -> None:
         if self.barrier is not None:
@@ -1466,12 +1402,12 @@ class StepLoop:
                            step, epoch, val, eps)
 
     def log_tick(self, step, epoch, loss_arr, eps) -> None:
-        """Queue one loss line (no span: bookkeeping calls it). Live: the
-        sync is taken at the NEXT dispatch, once the next batch is placed:
-        the device then waits for the host one dispatch after a line, and
-        not a placement too (13 ms of eight steps on the four-chip mesh)."""
-        (self.log_buffer if self.log_mode == "deferred"
-         else self.live_line).append((step, epoch, loss_arr, eps))
+        """Queue one loss line (no span: bookkeeping calls it). float(loss)
+        stalls async dispatch until the device has caught up, so the sync
+        is taken at the NEXT dispatch, once the next batch is placed: the
+        device then waits for the host one dispatch after a line, and not
+        a placement too (13 ms of eight steps on the four-chip mesh)."""
+        self.live_line.append((step, epoch, loss_arr, eps))
 
     def sync_live_line(self) -> None:
         """The loop's sync point: the host waits here until the device
@@ -1486,28 +1422,6 @@ class StepLoop:
         with span("train/log_line", seconds="train/log_line_seconds",
                   step=step):
             self.log_line(step, epoch, val, eps)
-
-    def flush_log(self) -> None:
-        self.sync_live_line()
-        if not self.log_buffer:
-            return
-        # bulk_fetch stacks the same-shaped scalars into ONE transfer:
-        # deferred mode is only ever active on a slow device link, where
-        # a per-element list fetch costs ~200 ms EACH (utils/fetch.py) —
-        # a full 1024-entry buffer would stall for minutes.
-        lines: list = []
-        step = self.log_buffer[-1][0]
-        with span("train/loss_sync", seconds="train/loss_sync_seconds",
-                  step=step):
-            bulk_fetch([(arr, (step, epoch, eps))
-                        for step, epoch, arr, eps in self.log_buffer],
-                       lambda v, m: lines.append(
-                           (m[0], m[1], float(v), m[2])))
-        with span("train/log_line", seconds="train/log_line_seconds",
-                  step=step):
-            for line in lines:
-                self.log_line(*line)
-        self.log_buffer.clear()
 
     # -- what a barrier or a save needs of the state ------------------
 
@@ -1723,7 +1637,7 @@ def _run_epochs(s: _Session, loop: StepLoop) -> None:
             while True:
                 # Consumer-side stall: time blocked INSIDE next() only.
                 # Any wider would fold end-of-step bookkeeping (notably
-                # live-mode's deliberate float(loss) device sync) into the
+                # a loss line's deliberate float(loss) device sync) into the
                 # host-bound signal and misdiagnose a device-bound run (the
                 # build cost is timed on the producing threads).
                 with span("pipeline/first_batch" if first
@@ -1776,7 +1690,6 @@ def _epoch_barrier(s: _Session, loop: StepLoop, epoch: int,
                              seconds="train/epoch_barrier_seconds",
                              epoch=epoch)
         loop.barrier_sweep = 0.0
-    loop.flush_log()  # deferred loss lines land at the epoch barrier
     with span("train/barrier_reports",
               seconds="train/barrier_reports_seconds", epoch=epoch):
         if s.bad_tracker is not None and s.bad_tracker.bad:
@@ -2119,7 +2032,7 @@ def _run_stream(s: _Session, loop: StepLoop) -> None:
         source.close()
     clock.gauges()  # the exit metrics snapshot carries the
     # freshness gauges even when the run never hit a flush step
-    loop.flush_log()
+    loop.sync_live_line()
     if s.bad_tracker is not None and s.bad_tracker.bad:
         logger.info("bad-line policy through the stream run: "
                     "%s", s.bad_tracker.describe())
@@ -2222,7 +2135,7 @@ def _finish(s: _Session, loop: StepLoop) -> None:
     publish, a stream run's one validation pass, and the export."""
     cfg, logger, tel = s.cfg, s.logger, s.tel
     loop.end_barrier()
-    loop.flush_log()
+    loop.sync_live_line()
     if tel is not None:
         tel.loop_stop()  # the final save and the export are no step's
     if loop.loss is not None:

@@ -9,15 +9,14 @@ the model path). The import is lazy (TF costs ~25 s to load, paid only
 when the knob is set) and failure-tolerant: without TF the knob warns
 once and training proceeds.
 
-Link-safety: scalar values may be DEVICE arrays; they are buffered
-as-is and fetched in one bulk ``jax.device_get`` at ``flush()`` —
-called from epoch boundaries, the same barrier the deferred loss log
-uses — so summaries add no mid-stream device fetches up to
-SUMMARY_BUFFER_MAX retained entries (a hot-loop scalar fetch stalls
-dispatch, for seconds on a slow device link). An epoch longer than SUMMARY_BUFFER_MAX/2 sampled cadences
-pays one bulk mid-epoch fetch per cap hit — the bound on retained
-device references is the lesser evil, and README/config state the
-same caveat.
+Sync-safety: scalar values may be DEVICE arrays; they are buffered
+as-is and fetched in one bulk ``jax.device_get`` at ``flush()``, called
+from epoch boundaries, so summaries add no mid-stream device fetches up
+to SUMMARY_BUFFER_MAX retained entries (a hot-loop scalar fetch stalls
+async dispatch until the device has caught up, on any device). An epoch
+longer than SUMMARY_BUFFER_MAX/2 sampled cadences pays one bulk
+mid-epoch fetch per cap hit: the bound on retained device references is
+the lesser evil, and README/config state the same caveat.
 """
 
 from __future__ import annotations
@@ -28,9 +27,8 @@ from typing import List, Optional, Tuple
 from fast_tffm_tpu.config import FmConfig
 
 
-# Buffered-scalar cap: device references retained between flushes. The
-# same bound (and rationale) as train.py's LOG_BUFFER_MAX — a tiny
-# cadence on a months-long epoch must not retain unbounded device
+# Buffered-scalar cap: device references retained between flushes. A
+# tiny cadence on a months-long epoch must not retain unbounded device
 # scalars; one rare mid-epoch sync is the lesser evil.
 SUMMARY_BUFFER_MAX = 1024
 
@@ -54,10 +52,8 @@ class ScalarSummaries:
     def flush(self) -> None:
         if not self._buf:
             return
-        # bulk_fetch groups the device scalars into stacked bulk
-        # transfers (a per-element list fetch costs a link round-trip
-        # EACH on slow links — the exact stall the buffering avoids);
-        # python-float values pass through untouched.
+        # One device_get of the whole buffer; python-float values pass
+        # through untouched.
         from fast_tffm_tpu.utils.fetch import bulk_fetch
         rows = []
         bulk_fetch([(v, (tag, step)) for tag, step, v in self._buf],

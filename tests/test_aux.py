@@ -112,38 +112,78 @@ def test_validation_max_batches_caps_eval(tmp_path, rng):
     assert "over 64 examples" in log
 
 
-def test_deferred_loss_logging_emits_every_line(tmp_path, monkeypatch):
-    """Forcing the slow-link path: every per-interval loss line must
-    still be emitted (at epoch boundaries) with correct step numbers and
-    real loss values — nothing dropped, nothing stale."""
-    import re
-    import numpy as np
-    from fast_tffm_tpu import train as train_mod
+def _loss_line_cfg(tmp_path):
+    """2 epochs x 4 batches of 16 lines, a loss line every step."""
+    from tests.test_e2e import make_dataset
     from fast_tffm_tpu.config import FmConfig
+    make_dataset(tmp_path / "d.txt", 64, np.random.default_rng(5), vocab=50)
+    return FmConfig(vocabulary_size=50, factor_num=2, batch_size=16,
+                    train_files=(str(tmp_path / "d.txt"),), epoch_num=2,
+                    log_steps=1, shuffle=False, learning_rate=0.1,
+                    log_file=str(tmp_path / "t.log"),
+                    model_file=str(tmp_path / "m" / "fm"))
 
-    rng = np.random.default_rng(5)
-    lines = []
-    for _ in range(64):
-        ids = rng.choice(50, size=4, replace=False)
-        lines.append(" ".join(["1" if rng.random() < 0.5 else "0"]
-                              + [f"{i}:1" for i in ids]))
-    p = tmp_path / "d.txt"
-    p.write_text("\n".join(lines) + "\n")
-    log_file = tmp_path / "t.log"
-    cfg = FmConfig(vocabulary_size=50, factor_num=2, batch_size=16,
-                   train_files=(str(p),), epoch_num=2, log_steps=1,
-                   shuffle=False, learning_rate=0.1,
-                   log_file=str(log_file),
-                   model_file=str(tmp_path / "m" / "fm"))
-    monkeypatch.setattr(train_mod, "LIVE_FETCH_BUDGET_S", -1.0)
+
+def _logged_steps(cfg):
+    import re
+    with open(cfg.log_file) as fh:
+        text = fh.read()
+    return ([int(m) for m in re.findall(r"step (\d+) epoch \d+ loss", text)],
+            [float(m) for m in
+             re.findall(r"loss (\d+\.\d+) examples/sec", text)])
+
+
+def test_every_loss_line_is_written_before_the_next_is_queued(
+        tmp_path, monkeypatch):
+    """One way to write a loss line: a step queues its line, the next
+    dispatch (or the barrier, or the loop's end) syncs and writes it. N
+    steps write N lines in step order with real loss values, and the
+    loop never holds more than one line unsynced."""
+    from fast_tffm_tpu import train as train_mod
+    cfg = _loss_line_cfg(tmp_path)
+    events, held = [], []
+    tick, line = train_mod.StepLoop.log_tick, train_mod.StepLoop.log_line
+
+    def log_tick(self, step, *a):
+        tick(self, step, *a)
+        events.append(("queued", step))
+        held.append(len(self.live_line))
+
+    def log_line(self, step, *a):
+        events.append(("written", step))
+        line(self, step, *a)
+    monkeypatch.setattr(train_mod.StepLoop, "log_tick", log_tick)
+    monkeypatch.setattr(train_mod.StepLoop, "log_line", log_line)
     train_mod.train(cfg)
-    text = log_file.read_text()
-    assert "deferring loss log lines" in text
-    steps = [int(m) for m in re.findall(r"step (\d+) epoch \d+ loss", text)]
+    assert events == [(what, step) for step in range(1, 9)
+                      for what in ("queued", "written")]
+    assert held == [1] * 8
+    steps, losses = _logged_steps(cfg)
     assert steps == list(range(1, 9)), steps  # 2 epochs x 4 batches
-    losses = [float(m) for m in
-              re.findall(r"loss (\d+\.\d+) examples/sec", text)]
     assert len(set(losses)) > 1  # real per-step values, not one repeated
+
+
+def test_a_step_that_raises_still_gets_its_owed_loss_line(
+        tmp_path, monkeypatch):
+    """The line a step queued is owed even when the NEXT step never
+    dispatches: the session's close syncs and writes it."""
+    from fast_tffm_tpu import train as train_mod
+    cfg = _loss_line_cfg(tmp_path)
+    seen = []
+    dispatch = train_mod.StepLoop.dispatch
+
+    def failing(self, wb, args, step):
+        if step == 3:
+            seen.append([owed[0] for owed in self.live_line])
+            raise RuntimeError("step 3 fails before its dispatch")
+        return dispatch(self, wb, args, step)
+    monkeypatch.setattr(train_mod.StepLoop, "dispatch", failing)
+    with pytest.raises(RuntimeError, match="step 3 fails"):
+        train_mod.train(cfg)
+    assert seen == [[2]]  # step 2's line, and no other, was owed
+    steps, losses = _logged_steps(cfg)
+    assert steps == [1, 2], steps
+    assert len(set(losses)) == 2
 
 
 def test_chunked_fetcher_stacked_and_mixed_paths():

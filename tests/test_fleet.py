@@ -330,6 +330,45 @@ def test_restart_policy_caps_and_resets():
     assert p.record_death() == 1.0  # streak reset: back to base
 
 
+def test_a_death_behind_a_failed_probe_is_still_counted(tmp_path):
+    """The supervisor's poll of a replica whose probe failed once (a
+    starved host; a kill between the exit check and the probe) and that
+    has exited by the next poll: the death is counted once, the restart
+    backs off, and the respawn is counted when the backoff is over."""
+    from fast_tffm_tpu.config import FmConfig
+    from fast_tffm_tpu.serve.fleet import FleetSupervisor
+    cfg = FmConfig(vocabulary_size=16, factor_num=2,
+                   model_file=str(tmp_path / "m" / "fm"),
+                   serve_replicas=2, serve_port=1, serve_proxy_port=0,
+                   serve_restart_backoff_seconds=100.0)
+    sup = FleetSupervisor(cfg, str(tmp_path / "fleet.cfg"))
+    r = sup.replicas[1]
+
+    class Child:
+        pid, returncode = 4242, None
+
+        def poll(self):
+            return self.returncode
+    r.proc, spawned = Child(), []
+    r.probe = lambda timeout: None
+    r.spawn = lambda: spawned.append(r.proc.pid)
+
+    def counters():
+        c = sup._reg.snapshot()["counters"]
+        return c.get("fleet/deaths", 0), c.get("fleet/restarts", 0)
+    sup._poll_replica(r)                  # alive, its probe fails
+    assert r.probe_failures == 1 and counters() == (0, 0)
+    r.proc.returncode = -9                # SIGKILL lands
+    sup._poll_replica(r)
+    assert counters() == (1, 0) and r.policy.failures == 1
+    assert not r.policy.can_restart() and spawned == []
+    sup._poll_replica(r)                  # still inside the backoff
+    assert counters() == (1, 0)
+    r.policy._not_before = 0.0            # the backoff is over
+    sup._poll_replica(r)
+    assert counters() == (1, 1) and spawned == [4242]
+
+
 # --- staggered reload ----------------------------------------------------
 
 

@@ -168,13 +168,13 @@ def test_the_step_span_says_its_width(tmp_path):
             if s.get("name") == "train/step"] == [(1, 96), (2, 112)]
 
 
-def test_scalars_on_a_fast_link_are_fetched_without_a_stack_program(
-        monkeypatch):
-    """The barrier's drain of buffered loss scalars: on a link timed as
-    fast no stack is compiled (it was a job's first program made ready
-    inside its steady state); untimed or slow, the group is stacked
-    into one transfer as ever; arrays are stacked either way."""
+def test_scalars_are_fetched_without_a_stack_program(monkeypatch, tmp_path):
+    """A barrier's drain of buffered scalars: no stack is made and
+    nothing is compiled (a stack is a program per group size, and was a
+    job's first program made ready inside its steady state); arrays of
+    one shape are stacked into one transfer."""
     import jax.numpy as jnp
+    from fast_tffm_tpu.obs.telemetry import RunTelemetry
     from fast_tffm_tpu.utils import fetch
     stacked = []
     real = jnp.stack
@@ -189,13 +189,14 @@ def test_scalars_on_a_fast_link_are_fetched_without_a_stack_program(
         fetch.bulk_fetch(pairs, lambda v, m: got.append((float(np.sum(v)),
                                                          m)))
         return got
-    monkeypatch.setattr(fetch, "_scalar_fetch_s", None)
-    assert fetched(scalars) == [(float(i), i) for i in range(16)]
-    assert stacked == [16]
-    fetch.note_link(3e-6)                    # the v5e's: 16 cost 0.05 ms
-    assert fetched(scalars) == [(float(i), i) for i in range(16)]
-    assert stacked == [16]
+    tel = RunTelemetry(str(tmp_path / "m.jsonl"), meta={})
+    try:
+        assert fetched(scalars) == [(float(i), i) for i in range(16)]
+        compiles = tel.registry.snapshot()["counters"][
+            "compile/backend_compiles"]
+    finally:
+        tel.close()
+    assert stacked == [] and compiles == 0
     assert fetched(rows) == [(6.0 + 4 * i, i) for i in range(3)]
-    assert stacked == [16, 3]
-    fetch.note_link(0.2)                     # a proxied link: 3.2 s
-    assert fetched(scalars)[-1] == (15.0, 15) and stacked == [16, 3, 16]
+    assert stacked == [3]
+    assert fetched(scalars + rows)[-1] == (14.0, 2) and stacked == [3, 3]

@@ -1,9 +1,8 @@
-"""Operational tooling: benches, probes, and the fmstat/fmlint/fmtrace
-CLIs.
+"""Operational tooling: probes and the fmstat/fmlint/fmtrace CLIs.
 
 A package (not loose scripts) so `python -m tools.fmstat` /
 `python -m tools.fmlint` / `python -m tools.fmtrace` work from the
-repo root — the standalone scripts (criteo_bench.py, kernel_probe.py,
+repo root — the standalone scripts (kernel_probe.py,
 offload_smoke.py) still run directly as before.
 """
 

@@ -10,8 +10,8 @@ Per-file rules (stdlib-``ast``, tools/fmlint/rules.py):
 
 R001  per-scalar device fetch in a hot-loop module (``float``/``int``
       in a loop body, any ``.item()``) — one synchronous scalar
-      materialization in the hot stream stalls async dispatch, and
-      costs seconds over a slow device link.
+      materialization in the hot stream stalls async dispatch until
+      the device has caught up.
 R002  bare ``print(`` in a hot-loop module.
 R003  raw ``perf_counter()`` pairs in hot loops (use obs.trace.span).
 R004  broad swallow-and-continue handlers in hot modules.

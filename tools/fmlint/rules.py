@@ -39,9 +39,8 @@ def r001_scalar_fetch(path: str, tree: ast.AST) -> List[Finding]:
     """float(x)/int(x) inside any loop body, and .item() anywhere, in
     hot modules: each is a synchronous per-scalar device->host fetch
     when x is a device array — one such fetch in the hot stream stalls
-    the async dispatch pipeline (for seconds over a slow device
-    link). Host-value exceptions carry a
-    justified pragma; bulk paths go through utils/fetch.bulk_fetch."""
+    the async dispatch pipeline until the device has caught up.
+    Host-value exceptions carry a justified pragma; bulk paths go through utils/fetch.bulk_fetch."""
     if not is_hot_module(path):
         return []
     found: List[Finding] = []
