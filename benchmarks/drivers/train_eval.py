@@ -1,7 +1,8 @@
 """Driver of ``kind: train_eval`` traffic: ``drivers/train.py``'s run
 with a held-out corpus that the program sweeps whole after every epoch
 (``validation_files``), so that the job's wall holds the scorer, the
-raw-id data plane, the chunked fetch and the streaming AUC beside the
+sweep's own data plane (the host unique in its builders, U fitted
+slots a batch since PR 45), the chunked fetch and the streaming AUC beside the
 train step: training to a quality target. The rate is the TRAINED
 examples over all the time of the span, sweeps included.
 

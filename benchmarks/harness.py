@@ -9,8 +9,9 @@ import glob
 import importlib
 import json
 import os
+import sys
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Sequence
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 BENCH_DIR = os.path.join(ROOT, "benchmarks")
@@ -266,14 +267,17 @@ def memory_peak_bytes() -> int:
     return max(peaks) if peaks else 0
 
 
+def _check_said(c: dict) -> str:
+    return f"check {c['name']}: {c['value']!r} (limit {c['limit']!r})"
+
+
 def print_checks(checks: List[dict]) -> bool:
     """Every number compared beside its limit; all must hold."""
     ok = True
     for c in checks:
         good = bool(c["value"] <= c["limit"])
         ok = ok and good
-        say(f"check {c['name']}: {c['value']!r} (limit {c['limit']!r}) "
-            f"{'ok' if good else 'FAILED'}")
+        say(f"{_check_said(c)} {'ok' if good else 'FAILED'}")
     return ok
 
 
@@ -342,12 +346,22 @@ def finish(run: Run, device: dict, end_to_end: Dict[str, float],
             f"{trace.busy_s:.3f} s, idle share "
             f"{1 - trace.busy_s / trace.window_s:.4f}")
     return result_line(run, dev, correct, attempted, failed, metrics,
-                       breakdown)
+                       breakdown, checks)
+
+
+def _plain(v):
+    """A NumPy scalar is no JSON number."""
+    return v.item() if hasattr(v, "item") else v
 
 
 def result_line(run: Run, device: dict, correct: bool, attempted: int,
                 failed: int, metrics: Dict[str, dict],
-                breakdown: Optional[dict] = None) -> str:
+                breakdown: Optional[dict] = None,
+                checks: Sequence[dict] = ()) -> str:
+    """The run's last line of stdout. Each number compared stands
+    beside its limit under ``check``, the line's last key, and on the
+    last lines of stderr: what a record of a run that was not correct
+    keeps."""
     say(f"metrics: {json.dumps(metrics)}")
     if run.rehearse:
         # A rehearsal proves the control flow; its numbers come from
@@ -358,4 +372,9 @@ def result_line(run: Run, device: dict, correct: bool, attempted: int,
             "failed": int(failed), "metrics": metrics, "device": device}
     if breakdown:
         line["breakdown"] = breakdown
+    line["check"] = {c["name"]: {"value": _plain(c["value"]),
+                                 "limit": _plain(c["limit"])}
+                     for c in checks}
+    for c in checks:
+        print(_check_said(c), file=sys.stderr, flush=True)
     return json.dumps(line)

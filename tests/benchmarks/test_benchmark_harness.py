@@ -27,7 +27,8 @@ from benchmarks.drivers import predict as predict_driver
 from benchmarks.drivers import train as train_driver
 
 REPO = tiny_tree.REPO
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "check"}
 
 
 @pytest.fixture(autouse=True)
@@ -230,11 +231,12 @@ def _model(family, model_type, order, F, k):
 
 
 FAMILIES = [("fm_order2", "fm", 2, 0, 4), ("ffm", "ffm", 2, 5, 3),
+            ("fm_order3", "fm", 3, 0, 4),
             ("fm_order3_tiny", "fm", 3, 0, 4)]
 
 
 def test_every_family_file_of_the_directory_is_tried_below():
-    assert reference.families() == sorted(f for f, *_ in FAMILIES[:2])
+    assert reference.families() == sorted(f for f, *_ in FAMILIES[:3])
     assert reference.families() == sorted(
         f[:-3] for f in os.listdir(os.path.join(REPO, "benchmarks",
                                                 "references"))
@@ -492,6 +494,39 @@ def test_predict_cell_runs_and_checks_its_scores(tiny_root):
     last = json.loads(out[-1])
     assert set(last) == RESULT_KEYS and last["correct"] is True
     assert any(l.startswith("check score_abs_gap_max") for l in out)
+
+
+PREDICT_LAYER = set(tiny_tree.PREDICT_LAYER)
+
+
+@pytest.mark.parametrize("trace,names", [
+    (0, {"predict_examples_per_s", "setup_s"}),
+    (1, PREDICT_LAYER | {"setup_start_s", "setup_compile_s"})])
+def test_the_repos_predict_traffic_runs_on_the_tiny_table(tiny_root, trace,
+                                                          names):
+    """``traffic/predict-sweep.json``, the file the cell
+    ``fm16-predict-sweep`` will run (its passes, its one short warm
+    call, its checked lines), under the tiny configuration: the
+    metrics the result line would carry on a chip are that cell's, by
+    name, and every phase's reader finds its span in the trace."""
+    moves = {}
+    for f in os.listdir(os.path.join(REPO, "benchmarks", "layer_metrics")):
+        with open(os.path.join(REPO, "benchmarks", "layer_metrics", f)) as fh:
+            moves[f[:-len(".json")]] = json.load(fh)["moves"]
+    assert PREDICT_LAYER == {n for n, m in moves.items()
+                             if m == "predict_examples_per_s"}
+    rc, out, err = _bench(tiny_root, "--workload", "tiny-predict-sweep",
+                          "--seed", str(2 ** 31 + 48), "--seconds", "4",
+                          "--trace", str(trace), "--rehearse-cpu")
+    assert rc == 0, err[-3000:]
+    last = json.loads(out[-1])
+    assert last["correct"] is True and last["failed"] == 0
+    assert list(last)[-1] == "check" and set(last["check"]) == {
+        "score_lines_missing", "score_abs_gap_max"}
+    assert err.strip().splitlines()[-1].startswith("check score_abs_gap_max")
+    shown = json.loads(next(l for l in out if l.startswith("metrics: "))
+                       [len("metrics: "):])
+    assert set(shown) == names
 
 
 @pytest.mark.parametrize("workload,correct", [

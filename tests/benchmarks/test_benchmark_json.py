@@ -22,26 +22,54 @@ from benchmarks import harness
 REPO = tiny_tree.REPO
 ONE_CHIP = ["fm16-train-zipf", "ffm4-train-zipf"]
 TRAIN = ONE_CHIP + ["fm16x4-train-zipf"]
+BAGS = ["fm8-train-bags", "fm3-train-bags"]             # PR 35, PR 41
+EVAL = ["fm16-train-eval"]                              # PR 44
+FOUR = TRAIN + BAGS[:1]         # PR 37's host-loop metrics (PERF.md 7(e))
+SIX = TRAIN + BAGS + EVAL
 # name -> the cells its ``workloads`` list was accepted with (None: the
 # entry has no such key and every cell reports it), in list order.
 ACCEPTED = {
-    "configs": {"fm-k16-criteo1tb": None, "ffm-k4-avazu": None,
-                "fm-k16-criteo1tb-x4": None},
-    "workloads": dict.fromkeys(TRAIN),
-    "end_to_end": {"train_examples_per_s_per_chip": TRAIN, "setup_s": None},
+    "configs": dict.fromkeys([
+        "fm-k16-criteo1tb", "ffm-k4-avazu", "fm-k16-criteo1tb-x4",
+        "fm-k8-kdd12-bags", "fm3-k8-kdd12-bags", "fm-k16-criteo1tb-eval"]),
+    "workloads": dict.fromkeys(SIX),
+    "end_to_end": {"train_examples_per_s_per_chip": SIX, "setup_s": None},
     "per_layer": {
         "setup_start_s": None, "setup_compile_s": None,
-        "input_wait_share": TRAIN, "h2d_bytes_per_example": TRAIN,
-        "step_device_ms": TRAIN, "step_roofline": TRAIN,
-        "steady_rate.train": TRAIN,
+        "input_wait_share": SIX, "h2d_bytes_per_example": SIX,
+        "step_device_ms": SIX, "step_roofline": SIX,
+        "steady_rate.train": SIX,
         # PR 25's nine, six scopes of the step and three counters
-        "dedup_sort_ms": ONE_CHIP, "table_gather_ms": TRAIN,
-        "slot_expand_ms": TRAIN, "interaction_ms": TRAIN,
-        "table_scatter_ms": TRAIN, "step_unscoped_ms": TRAIN,
-        "loss_sync_share": TRAIN, "epoch_barrier_s": TRAIN,
-        "compiles_per_epoch": TRAIN,
-        "uniq_slot_fill": TRAIN, "host_build_s_per_batch": TRAIN,   # PR 26
-        "collective_exposed_ms": TRAIN[2:]},                        # PR 27
+        "dedup_sort_ms": ONE_CHIP + EVAL, "table_gather_ms": SIX,
+        "slot_expand_ms": SIX, "interaction_ms": SIX,
+        "table_scatter_ms": SIX, "step_unscoped_ms": FOUR + EVAL,
+        "loss_sync_share": SIX, "epoch_barrier_s": SIX,
+        "compiles_per_epoch": SIX,
+        "uniq_slot_fill": SIX, "host_build_s_per_batch": SIX,       # PR 26
+        "collective_exposed_ms": TRAIN[2:],                         # PR 27
+        "shard_slot_fill": TRAIN[2:], "cell_fill": SIX,         # PR 33, 34
+        # PR 35's three of lines of unequal length
+        "cells_per_example": BAGS, "program_switches_per_step": BAGS,
+        "truncated_cells_per_example": BAGS,
+        # PR 37's ten of the host loop
+        "bookkeeping_s_per_step": FOUR, "loop_unnamed_share": FOUR,
+        "barrier_flush_s": FOUR, "pipeline_open_s": FOUR,
+        "first_batch_s": FOUR, "idle_unnamed": FOUR,
+        "idle_in_bookkeeping": FOUR, "idle_in_barrier_flush": FOUR,
+        "idle_in_pipeline_open": FOUR, "idle_in_first_batch": FOUR,
+        "anova_scan_ms": BAGS[1:], "anova_scan_roofline": BAGS[1:],  # PR 41
+        # PR 44's nine of the validation sweep, PR 45's tenth
+        "validation_share": EVAL, "validation_s_per_sweep": EVAL,
+        "validation_examples_per_sweep": EVAL,
+        "validation_score_device_ms": EVAL, "validation_gather_ms": EVAL,
+        "idle_in_validation_first_batch": EVAL,
+        "idle_in_validation_drain": EVAL,
+        "idle_in_validation_dispatch": EVAL,
+        "validation_score_roofline": EVAL,
+        "validation_uniq_slot_fill": EVAL,
+        # PR 46's four of the feed
+        "loop_h2d_s_per_step": SIX, "place_s_per_step": SIX,
+        "placed_ahead_share": SIX, "emit_s_per_batch": SIX},
 }
 KEYS = {"command", "paths", "run_seconds", "configs", "workloads",
         "end_to_end", "per_layer"}
@@ -113,7 +141,6 @@ def hold_cell(root, name):
     setup_s, another end-to-end metric and a per-layer one, and its
     configuration names a reference family that is there."""
     cell = harness.load_cell(name, root)
-    assert cell.kind in ("train", "predict")
     assert os.path.exists(os.path.join(root, "benchmarks", "drivers",
                                        cell.kind + ".py"))
     assert {m["name"] for m in cell.end_to_end} > {"setup_s"}
@@ -193,9 +220,8 @@ def _cell_gone(s):
             m["workloads"].remove(gone)
 
 
-def _new_cell(s, chips=1):
-    s["workloads"].append(dict(s["workloads"][0], name="later-cell",
-                               chips=chips))
+def _new_cell(s, chips=1, name="later-cell"):
+    s["workloads"].append(dict(s["workloads"][0], name=name, chips=chips))
 
 
 MUTATIONS = {
@@ -221,8 +247,9 @@ MUTATIONS = {
     "a workloads key on a metric accepted without one": (
         lambda s: s["per_layer"][0].update(workloads=list(TRAIN)),
         "per_layer"),
-    "a second four-chip cell among four": (
-        lambda s: _new_cell(s, chips=4), None),
+    "a second and a third four-chip cell among eight": (
+        lambda s: [_new_cell(s, 4, n) for n in ("later-a", "later-b")],
+        None),
     "a cell a metric lists and BENCHMARK.json has not": (
         lambda s: s["per_layer"][4]["workloads"].append("no-such-cell"),
         None),

@@ -55,10 +55,11 @@ def _checks(said):
 
 def test_the_cell_runs_by_rehearse_cpu(eval_root):
     """One CPU device: the one-chip path, ``TrainStep`` on the host
-    unique's slots and ``fm_score`` on raw ids, two programs on one
-    table. Every sweep check holds, the probed calls agree with the
-    reference, the new metrics read the stream and the trace, and the
-    score program is ready before the window opens."""
+    unique's slots and ``fm_score`` on the host unique's fitted slots
+    too (PR 45), two programs on one table. Every sweep check holds,
+    the probed calls agree with the reference, the new metrics read
+    the stream and the trace, and the score program is ready before
+    the window opens."""
     p = subprocess.run(
         [sys.executable, "-m", "benchmarks.run", "--workload", CELL,
          "--seed", str(SEED), "--seconds", "1.5", "--trace", "1",
@@ -608,7 +609,8 @@ def test_the_cell_is_on_the_lists_the_issue_names():
         "validation_examples_per_sweep", "validation_score_device_ms",
         "validation_gather_ms", "idle_in_validation_first_batch",
         "idle_in_validation_drain", "idle_in_validation_dispatch",
-        "validation_score_roofline"}
+        "validation_score_roofline",
+        "validation_uniq_slot_fill"}                # PR 45, by ISSUE 45
     for m in spec["per_layer"]:
         if m["name"] in own:
             assert m["moves"] == "train_examples_per_s_per_chip"
@@ -622,8 +624,7 @@ def test_the_cell_is_on_the_lists_the_issue_names():
     with open(os.path.join(REPO, "benchmarks", "layer_metrics",
                            "score_device_ms.json")) as fh:
         assert json.load(fh)["moves"] == "predict_examples_per_s"
-    # what test_benchmark_json.py's hold_cell holds of a cell, but for
-    # the kinds it knows (PERF.md section 7, note (b))
+    # what test_benchmark_json.py's hold_cell holds of every cell
     held = harness.load_cell("fm16-train-eval")
     assert os.path.exists(os.path.join(REPO, "benchmarks", "drivers",
                                        held.kind + ".py"))
@@ -633,7 +634,9 @@ def test_the_cell_is_on_the_lists_the_issue_names():
     assert os.path.exists(os.path.join(
         REPO, "benchmarks", "references",
         held.config["reference_family"] + ".py"))
-    cell = spec["workloads"][-1]
-    assert cell["name"] == "fm16-train-eval" and len(cell["why"]) <= 200
-    assert spec["configs"][-1]["name"] == cell["config"]
-    assert len(spec["configs"][-1]["source"]) <= 200
+    cell = next(w for w in spec["workloads"]
+                if w["name"] == "fm16-train-eval")
+    assert len(cell["why"]) <= 200
+    assert cell["config"] == "fm-k16-criteo1tb-eval"
+    config = next(c for c in spec["configs"] if c["name"] == cell["config"])
+    assert len(config["source"]) <= 200
