@@ -328,9 +328,10 @@ def parse_lines_fast(lines: Sequence[str], vocabulary_size: int,
 
 
 def scan_examples(data: bytes, n_target: int, keep_empty: bool = False,
-                  offset: int = 0) -> "tuple[int, int, int]":
+                  offset: int = 0,
+                  end: Optional[int] = None) -> "tuple[int, int, int]":
     """Count example-producing lines in the COMPLETE lines of
-    ``data[offset:]`` up to ``n_target``, without parsing: returns
+    ``data[offset:end]`` up to ``n_target``, without parsing: returns
     ``(found, bytes_consumed, lines_consumed)`` where ``bytes_consumed``
     ends at the last counted line's newline (relative to ``offset``)
     and ``lines_consumed`` includes the blank lines inside that span.
@@ -343,8 +344,9 @@ def scan_examples(data: bytes, n_target: int, keep_empty: bool = False,
     base = ctypes.cast(ctypes.c_char_p(data), ctypes.c_void_p).value
     consumed = ctypes.c_int64(0)
     nlines = ctypes.c_int64(0)
+    stop = len(data) if end is None else min(int(end), len(data))
     found = lib.fm_scan_examples(ctypes.c_void_p((base or 0) + offset),
-                                 len(data) - offset, n_target,
+                                 max(stop - offset, 0), n_target,
                                  int(keep_empty), ctypes.byref(consumed),
                                  ctypes.byref(nlines))
     return int(found), int(consumed.value), int(nlines.value)

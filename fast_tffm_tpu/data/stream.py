@@ -64,6 +64,7 @@ import numpy as np
 from fast_tffm_tpu.config import FmConfig
 from fast_tffm_tpu.data.badlines import BadLineTracker
 from fast_tffm_tpu.data.parser import WHITESPACE, ParseError
+from fast_tffm_tpu.obs.trace import span
 from fast_tffm_tpu.utils.logging import get_logger
 from fast_tffm_tpu.utils.retry import (RetryPolicy, open_with_retry,
                                        retry_io)
@@ -93,7 +94,26 @@ QUIET_POLLS = 3
 # reads past the budget simply continue next poll.
 MAX_POLL_BYTES = 64 << 20
 
+# The ring route's round (host_threads > 1). Its bytes are joined to one
+# scan buffer and cut into groups on the thread that pumps, before the
+# builders see the next group, and the ring holds 2 x workers groups of
+# cover: a 64 MB round was seventeen batches of B = 8192 x 39 read,
+# joined and cut in one go, 0.75 s on a v5e's host (PERF.md, PR 49), so
+# the train loop's get (0.5 s) gave up once a round with the backlog
+# still there. Two such batches a round, in pieces the allocator hands
+# back without fresh pages.
+RING_ROUND_BYTES = 8 << 20
+
 WATERMARK_FORMAT = 1
+
+# The leaves of the span ``stream/pump`` (one service round of a
+# StreamSource, on the thread that drives it), each counted under
+# ``<name>_seconds``; the emitter's is the plane's own. What is left of
+# ``stream/pump_seconds`` lies under no leaf: the ledger walk, the
+# per-batch counters, and on the serial and generic routes the parse
+# and the build.
+PUMP_LEAVES = ("stream/discover", "stream/read", "stream/scan",
+               "stream/harvest", "pipeline/emit", "stream/snapshot")
 
 # Lockstep-mode bound on completed-but-unstepped batches: once this
 # many are queued, per-iteration pumps run discovery-only until the
@@ -261,9 +281,10 @@ class StreamTracker:
             if (name == STOP_MARKER or name.startswith(".")
                     or name.endswith(DONE_SUFFIX)):
                 continue
-            if not os.path.isfile(p):
-                continue
-            if p not in self._by_path:
+            if p in self._by_path:
+                continue  # before the stat: a ledger of N files is N
+                # stats a poll otherwise (0.2 s at 1,024 on a v5e's host)
+            if os.path.isfile(p):
                 new.append(p)
         return new, stop
 
@@ -274,6 +295,10 @@ class StreamTracker:
             self._count("stream/files_discovered")
             self._log.info("stream: discovered shard %s (ledger index "
                            "%d)", p, self._by_path[p])
+        if new:
+            tel = self._tel()
+            if tel is not None:
+                tel.set("stream/ledger_files", len(self.files))
         if stop and not self.stop_seen:
             self.stop_seen = True
             self._log.info("stream: STOP marker seen; will finish once "
@@ -300,7 +325,8 @@ class StreamTracker:
                               bool(payload.get("stop")))
 
     # -- the read plane ---------------------------------------------------
-    def poll(self, read: bool = True) -> List[Tuple[int, bytes]]:
+    def poll(self, read: bool = True,
+             budget: Optional[int] = None) -> List[Tuple[int, bytes]]:
         """One service round: run discovery, then tail the owned head
         file(s), releasing newline-terminated chunks in strict ledger
         order. Several files can drain in one round (a backlog of
@@ -310,18 +336,23 @@ class StreamTracker:
         ``read=False`` runs ONLY discovery (the collective half, in
         lockstep mode) and skips the local read plane — the lockstep
         driver uses it to keep its per-iteration collective cadence
-        while the consumer is already holding enough batches."""
-        self.discover()
+        while the consumer is already holding enough batches.
+
+        ``budget``: the round's bytes (None: ``MAX_POLL_BYTES``)."""
+        with span("stream/discover", seconds="stream/discover_seconds"):
+            self.discover()
         if not read:
             return []
         out: List[Tuple[int, bytes]] = []
-        budget = MAX_POLL_BYTES
+        if budget is None:
+            budget = MAX_POLL_BYTES
         for i, fs in enumerate(self.files):
             if not self.owned(i):
                 continue
             if fs.eof:
                 continue
-            chunk = self._service(fs, budget)
+            with span("stream/read", seconds="stream/read_seconds"):
+                chunk = self._service(fs, budget)
             if chunk:
                 out.append((i, chunk))
                 budget -= len(chunk)
@@ -407,9 +438,11 @@ class StreamTracker:
         # Bounded round: a huge backlog streams across polls instead
         # of materializing in RAM; the remainder reads next poll.
         limit = min(limit, read_off + max(budget, 0))
+        fresh = b""  # joined to the held-back tail ONCE, where it is cut
         if limit > read_off:
             try:
-                fs.tail += self._read_range(fs.path, read_off, limit)
+                fresh = self._read_range(fs.path, read_off, limit)
+                self._count("stream/bytes_read", len(fresh))
             except FileNotFoundError:
                 # Deleted in the stat->open window: same tolerated
                 # event as the stat-time deletion, same outcome.
@@ -443,9 +476,9 @@ class StreamTracker:
             self._log.info("stream: sealed %s at %d bytes", fs.path,
                            fs.end)
         at_end = (fs.sealed and fs.end is not None
-                  and fs.released + len(fs.tail) >= fs.end)
+                  and fs.released + len(fs.tail) + len(fresh) >= fs.end)
         if at_end:
-            chunk = fs.tail
+            chunk = fs.tail + fresh
             fs.tail = b""
             fs.released += len(chunk)
             if chunk and not chunk.endswith(b"\n"):
@@ -454,17 +487,23 @@ class StreamTracker:
                 # The synthesized byte is NOT part of the file; the
                 # consumer's position accounting clamps at `end`.
                 chunk += b"\n"
-            fs.released_lines += chunk.count(b"\n")
+            fs.released_lines += _newlines(chunk)
             return chunk
         # Not yet at the (sealed or growing) end: release only whole
         # lines — a budget-capped mid-file read must never synthesize
         # a terminator into the middle of a line.
-        cut = fs.tail.rfind(b"\n")
+        # The held-back tail is what followed the last newline
+        # released, so the last newline in hand lies in the fresh
+        # bytes: one copy makes the chunk (a round's bytes were copied
+        # three times here, each a byte string of its own size).
+        cut = fresh.rfind(b"\n")
         if cut < 0:
+            fs.tail += fresh
             return b""  # torn trailing line: held back in full
-        chunk, fs.tail = fs.tail[:cut + 1], fs.tail[cut + 1:]
+        chunk = b"".join((fs.tail, memoryview(fresh)[:cut + 1]))
+        fs.tail = fresh[cut + 1:]
         fs.released += len(chunk)
-        fs.released_lines += chunk.count(b"\n")
+        fs.released_lines += _newlines(chunk)
         return chunk
 
     def _seal_due(self, fs: _FileState, st) -> bool:
@@ -490,7 +529,8 @@ class StreamTracker:
             pos = start
             fh.seek(start)
             while pos < end:
-                want = min(4 << 20, end - pos)
+                # a ring route's round in one piece: nothing to join
+                want = min(max(4 << 20, RING_ROUND_BYTES), end - pos)
 
                 def attempt(p=pos, w=want):
                     fh.seek(p)
@@ -503,6 +543,22 @@ class StreamTracker:
                 parts.append(b)
                 pos += len(b)
         return b"".join(parts)
+
+
+def _newlines(data: bytes, start: int = 0,
+              end: Optional[int] = None) -> int:
+    """The newlines of ``data[start:end]``: the C++ scanner's memchr
+    walk where the extension is there (with ``keep_empty`` every
+    complete line counts), off the GIL. ``bytes.count`` walks text of
+    short lines at under 1 GB/s and holds the GIL while it does: three
+    such walks of every byte were 13 of the 17 ms a batch cost the
+    stream's producer thread, and the train loop's phases beside it
+    read twice their length (PERF.md, PR 49)."""
+    from fast_tffm_tpu.data import cparser
+    if cparser.available():
+        return cparser.scan_examples(data, 1 << 62, True, offset=start,
+                                     end=end)[0]
+    return data.count(b"\n", start, len(data) if end is None else end)
 
 
 # -- multi-worker agreement helpers ---------------------------------------
@@ -732,7 +788,9 @@ class StreamSource:
                 # telemetry sees the batch: the pad-waste counter
                 # below reads the PHYSICAL pad_id.
                 batch = self._vocab.remap(batch)
-            batch.stream_pos = self._snapshot()
+            with span("stream/snapshot",
+                      seconds="stream/snapshot_seconds"):
+                batch.stream_pos = self._snapshot()
             tel = StreamTracker._tel()
             if tel is not None:
                 tel.pipeline_batch(batch, self.cfg.pad_id)
@@ -765,23 +823,50 @@ class StreamSource:
                           f"{m.group(2)}")
 
     # -- the pump ---------------------------------------------------------
-    def _pump(self, read: bool = True) -> None:
-        chunks = self.tracker.poll(read=read)
-        for fi, data in chunks:
-            if self._fast:
-                if self._ring is not None:
-                    self._scan_feed(fi, data)
+    def _pump(self, read: bool = True, wait: bool = False) -> None:
+        """One service round of the source, on whichever thread drives
+        it (span ``stream/pump``; its leaves: the tracker's
+        ``stream/discover`` and ``stream/read``, the ring route's
+        ``stream/scan`` and ``stream/harvest``, the emitter's own
+        ``pipeline/emit`` and the watermark tag ``stream/snapshot``;
+        the serial and generic routes parse and build under no leaf).
+
+        The ring route cuts its groups from one scan buffer, which a
+        backlog of sealed shards would fill a round a pump while the
+        builders drain it a group a batch: a round (``RING_ROUND_BYTES``)
+        is read only while the buffer holds less than one, so it never
+        holds two (found at 1,024 sealed shards: the buffer grew 270 MB
+        every 50 batches and every pump copied all of it). ``wait``
+        (the prefetch producer): a round that leaves nothing ready
+        while the builders hold groups waits for the ring's head, as
+        the epoch plane's emitter does, where the caller would sleep a
+        poll (0.2 s where a batch takes 10 ms)."""
+        tel = StreamTracker._tel()
+        if tel is not None:
+            tel.count("stream/pumps")
+        with span("stream/pump", seconds="stream/pump_seconds"):
+            budget = None
+            if self._ring is not None:
+                budget = min(RING_ROUND_BYTES, MAX_POLL_BYTES)
+                read = read and len(self._buf) - self._buf_pos < budget
+            chunks = self.tracker.poll(read=read, budget=budget)
+            for fi, data in chunks:
+                if self._fast:
+                    if self._ring is not None:
+                        self._scan_feed(fi, data)
+                    else:
+                        self._note_file_start(fi)
+                        self._serial_feed(fi, data)
                 else:
-                    self._note_file_start(fi)
-                    self._serial_feed(fi, data)
-            else:
-                self._generic_feed(fi, data)
-        if self._ring is not None:
-            self._ring_drive()
-        if self.tracker.finished and not self._flushed:
-            self._flush_final()
-        self.tracker.note_consumed_through(
-            caught_up=not self._ready and not chunks)
+                    self._generic_feed(fi, data)
+            if self._ring is not None:
+                self._ring_drive()
+                if wait and not self._ready:
+                    self._harvest(block=True, once=True)
+            if self.tracker.finished and not self._flushed:
+                self._flush_final()
+            self.tracker.note_consumed_through(
+                caught_up=not self._ready and not chunks)
 
     def _flush_final(self) -> None:
         self._flushed = True
@@ -803,7 +888,7 @@ class StreamSource:
                 full, c = self._bb.feed(data, off)
             except ParseError as e:
                 raise self._attach_source(e) from None
-            nl = data.count(b"\n", off, off + c)
+            nl = _newlines(data, off, off + c)
             self._advance(fi, c, nl)
             self._stream_lines += nl
             off += c
@@ -845,15 +930,22 @@ class StreamSource:
             tel.set("pipeline/host_threads", self._workers)
 
     def _scan_feed(self, fi: int, data: bytes) -> None:
-        self._buf = self._buf[self._buf_pos:] + data
-        self._buf_pos = 0
-        self._segments.append([fi, len(data)])
+        with span("stream/scan", seconds="stream/scan_seconds"):
+            # one new string, what is left of the old one copied once
+            self._buf = b"".join(
+                (memoryview(self._buf)[self._buf_pos:], data))
+            self._buf_pos = 0
+            self._segments.append([fi, len(data)])
 
-    def _cut_positions(self, consumed: int) -> Dict[int, Tuple[int, int]]:
-        """Advance the scanner-side counters by ``consumed`` bytes off
-        the buffer head; returns the ABSOLUTE (bytes, lines) position
-        per touched file after the cut. Also records the error-span map
-        in cut-line units (the units group.line_start uses)."""
+    def _cut_positions(self, consumed: int,
+                       lines: int) -> Dict[int, Tuple[int, int]]:
+        """Advance the scanner-side counters by ``consumed`` bytes, the
+        scanner's ``lines`` lines, off the buffer head; returns the
+        ABSOLUTE (bytes, lines) position per touched file after the
+        cut. Also records the error-span map in cut-line units (the
+        units group.line_start uses). Only a cut that spans chunks
+        walks bytes again: every part but the last is counted, the last
+        holds what is left of ``lines``."""
         out: Dict[int, Tuple[int, int]] = {}
         taken = 0
         while taken < consumed:
@@ -861,8 +953,12 @@ class StreamSource:
             fi, seg_len = seg
             self._note_file_start(fi)
             n = min(seg_len, consumed - taken)
-            nl = self._buf.count(b"\n", self._buf_pos + taken,
-                                 self._buf_pos + taken + n)
+            if taken + n == consumed:
+                nl = lines
+            else:
+                nl = _newlines(self._buf, self._buf_pos + taken,
+                               self._buf_pos + taken + n)
+            lines -= nl
             b, l = self._cut_pos.get(
                 fi, (self.tracker.files[fi].resume_bytes,
                      self.tracker.files[fi].resume_lines))
@@ -876,12 +972,14 @@ class StreamSource:
                 seg[1] -= n
         return out
 
-    def _cut_one_group(self, blob: bytes, consumed: int,
-                       line_start: int) -> None:
-        positions = self._cut_positions(consumed)
+    def _cut_one_group(self, consumed: int, lines: int) -> None:
+        """Hand the ring the next ``consumed`` bytes of the scan buffer,
+        ``lines`` lines by the scanner's own count, as one group."""
+        blob = self._buf[self._buf_pos:self._buf_pos + consumed]
+        line_start = self._stream_lines
+        positions = self._cut_positions(consumed, lines)
         self._buf_pos += consumed
-        seq = self._ring.submit(
-            _pipeline()._Group(blob, line_start, blob.count(b"\n")))
+        seq = self._ring.submit(_pipeline()._Group(blob, line_start, lines))
         self._inflight.append((seq, positions))
 
     def _ring_drive(self) -> None:
@@ -891,22 +989,25 @@ class StreamSource:
         held-back torn tails stay in the tracker and sub-B leftovers
         stay in this buffer."""
         from fast_tffm_tpu.data.cparser import scan_examples
-        while len(self._inflight) < self._ring.depth:
-            found, consumed, _nl = scan_examples(
-                self._buf, self.B, False, offset=self._buf_pos)
-            if found < self.B:
-                break
-            blob = self._buf[self._buf_pos:self._buf_pos + consumed]
-            self._cut_one_group(blob, consumed, self._stream_lines)
+        with span("stream/scan", seconds="stream/scan_seconds"):
+            while len(self._inflight) < self._ring.depth:
+                found, consumed, lines = scan_examples(
+                    self._buf, self.B, False, offset=self._buf_pos)
+                if found < self.B:
+                    break
+                self._cut_one_group(consumed, lines)
         self._harvest(block=False)
 
-    def _harvest(self, block: bool) -> None:
+    def _harvest(self, block: bool, once: bool = False) -> None:
+        """Emit the finished heads of the ring, in submit order;
+        ``block`` waits for each (``once``: for the first alone)."""
         while self._inflight:
             seq, positions = self._inflight[0]
-            if not block and not self._ring.has(seq):
-                return
-            self._inflight.popleft()
-            kind, payload = self._ring.wait(seq)
+            with span("stream/harvest", seconds="stream/harvest_seconds"):
+                if not block and not self._ring.has(seq):
+                    return
+                self._inflight.popleft()
+                kind, payload = self._ring.wait(seq)
             if kind == "error":
                 if isinstance(payload, ParseError):
                     raise self._attach_source(payload) from None
@@ -915,16 +1016,17 @@ class StreamSource:
             for fi, pos in positions.items():
                 self._pos[fi] = pos
             self._emit(out, spilled=False)
+            if once:
+                return
 
     def _ring_flush(self) -> None:
         from fast_tffm_tpu.data.cparser import scan_examples
         while True:
-            found, consumed, _nl = scan_examples(
+            found, consumed, lines = scan_examples(
                 self._buf, self.B, False, offset=self._buf_pos)
             if not found:
                 break
-            blob = self._buf[self._buf_pos:self._buf_pos + consumed]
-            self._cut_one_group(blob, consumed, self._stream_lines)
+            self._cut_one_group(consumed, lines)
             if found < self.B:
                 break  # the final short group
         self._harvest(block=True)
@@ -997,7 +1099,9 @@ class StreamSource:
             # chunk IS its consumed-through position).
             for _, fi, byte_end, line_end in take:
                 self._pos[fi] = (byte_end, line_end)
-            out_batch.stream_pos = self._snapshot()
+            with span("stream/snapshot",
+                      seconds="stream/snapshot_seconds"):
+                out_batch.stream_pos = self._snapshot()
             if self.stats is not None:
                 self.stats.count(out_batch.num_real, self.B, False)
             tel = StreamTracker._tel()
@@ -1058,7 +1162,7 @@ class StreamSource:
                 # producer thread must exit its poll loop, not keep
                 # polling a dead run's directory forever.
                 return DONE
-            self._pump()
+            self._pump(wait=True)
             if self._ready or self._flushed:
                 continue
             tel = StreamTracker._tel()

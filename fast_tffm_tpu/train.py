@@ -2096,12 +2096,24 @@ def _stream_single(s: _Session, loop: StepLoop, clock: _StreamClock,
     from fast_tffm_tpu.data import stream as streamlib
     cfg, tel = s.cfg, s.tel
     pf = streamlib.StreamPrefetcher(source, depth=cfg.prefetch_depth)
+    if tel is not None:  # read as differences: there from the first flush
+        tel.count("stream/gets", 0)
+        tel.count("stream/gets_idle", 0)
     try:
         while True:
             if s.preempted:
                 loop.note_preempted(0, _STREAM_PREEMPTED)
                 break
-            batch = pf.get(timeout=min(cfg.stream_poll_seconds, 0.5))
+            # The consumer's stall, as _run_epochs counts it: the time
+            # blocked inside the get alone, a leaf of the loop's wall.
+            with span("train/input_wait",
+                      seconds="train/input_wait_seconds",
+                      step=loop.global_step + 1):
+                batch = pf.get(timeout=min(cfg.stream_poll_seconds, 0.5))
+            if tel is not None:
+                tel.count("stream/gets")
+                if batch is streamlib.IDLE:
+                    tel.count("stream/gets_idle")
             # fmlint: disable=R007 -- single-process loop
             # (_stream_lockstep is the multi-worker path): the step's
             # collectives are themselves gated on multi_process, so no

@@ -805,3 +805,207 @@ def test_restored_sealed_file_never_reads_late_bytes(tmp_path):
     assert got == list(range(8, 10))  # never feature 999
     assert batches[-1].stream_pos["files"][0]["bytes"] == \
         wm["files"][0]["end"]
+
+
+# --- ISSUE 49: the read plane's spans and counters, the backlog ----------
+
+def _shards(sd, n, lines_each=60, seed=3, done=True):
+    """``n`` seeded shards of multi-feature lines; the paths, in
+    ledger order."""
+    rng = np.random.default_rng(seed)
+    paths = []
+    for i in range(n):
+        lines = []
+        for j in range(lines_each):
+            ids = rng.choice(500, size=int(rng.integers(1, 6)),
+                             replace=False)
+            lines.append(" ".join([str(j % 2)] + [
+                f"{k}:{rng.random():.3f}" for k in ids]))
+        _write_lines(sd / f"p{i:03d}.txt", lines)
+        if done:
+            (sd / f"p{i:03d}.txt.done").touch()
+        paths.append(str(sd / f"p{i:03d}.txt"))
+    return paths
+
+
+STREAM_LEAVES = ("stream/discover", "stream/read", "stream/scan",
+                 "stream/harvest", "pipeline/emit", "stream/snapshot")
+
+
+@pytest.fixture(scope="module")
+def stream_run(tmp_path_factory):
+    """One whole ``train()`` in stream mode over five sealed shards and
+    a STOP marker, four build workers, spans traced: its last metrics
+    snapshot and the names of its span events, by thread."""
+    from fast_tffm_tpu.data import cparser
+    from fast_tffm_tpu.obs.sink import read_events
+    from fast_tffm_tpu.train import train
+    if not cparser.available():
+        pytest.skip("C++ extension unavailable")
+    tmp = tmp_path_factory.mktemp("stream_run")
+    sd = tmp / "s"
+    sd.mkdir()
+    _shards(sd, 5)
+    (sd / "STOP").touch()
+    cfg = _cfg(str(sd), vocabulary_size=512, batch_size=16,
+               host_threads=4, model_file=str(tmp / "m" / "fm"),
+               metrics_file="auto", metrics_flush_steps=2, log_steps=0,
+               trace_spans=True)
+    train(cfg)
+    events = list(read_events(cfg.model_file + ".metrics.jsonl"))
+    snap = [e for e in events if e["event"] == "metrics"][-1]
+    spans = {}
+    for e in events:
+        if e["event"] == "span":
+            spans.setdefault(e["name"], set()).add(e["tid"])
+    return snap["counters"], snap["gauges"], spans
+
+
+@pytest.mark.parametrize("counter", [
+    "stream/pumps", "stream/bytes_read", "stream/pump_seconds",
+    "stream/read_seconds", "stream/snapshot_seconds",
+    "stream/discover_seconds", "stream/scan_seconds",
+    "stream/harvest_seconds", "stream/gets",
+    "train/input_wait_seconds", "pipeline/worker_build_seconds",
+    "pipeline/emit_seconds", "pipeline/batches", "pipeline/uniq_rows",
+    "pipeline/uniq_slots"])
+def test_a_stream_run_counts_its_read_plane(stream_run, counter):
+    """What ``batch_iterator``'s plane counts, the stream's ring counts
+    too (the benchmark holds every cell to ``uniq_slot_fill`` and
+    ``host_build_s_per_batch``), and the read plane has counters of its
+    own; the loop's wait in ``pf.get`` is its ``train/input_wait``."""
+    counters, _, _ = stream_run
+    assert counters.get(counter, 0) > 0, sorted(counters)
+
+
+def test_a_stream_run_counts_what_it_read_and_stepped(stream_run):
+    counters, gauges, _ = stream_run
+    assert gauges["stream/ledger_files"] == 5
+    assert counters["stream/bytes_read"] == counters["pipeline/bytes_fed"]
+    assert counters["pipeline/batches"] == counters["train/steps"]
+    assert counters["stream/gets"] >= counters["train/steps"]
+    assert "stream/gets_idle" in counters
+    assert counters["pipeline/uniq_rows"] <= counters["pipeline/uniq_slots"]
+
+
+def test_the_pump_holds_its_leaves(stream_run):
+    """``stream/pump`` is one service round; its leaves are disjoint
+    and inside it, so their counters cannot sum to more than its own
+    (sealed by ``PUMP_LEAVES``, which the benchmark's driver prints)."""
+    counters, _, _ = stream_run
+    assert sl.PUMP_LEAVES == STREAM_LEAVES
+    leaves = sum(counters[name + "_seconds"] for name in STREAM_LEAVES)
+    assert 0 < leaves <= counters["stream/pump_seconds"]
+
+
+@pytest.mark.parametrize("span", ("stream/pump",) + STREAM_LEAVES)
+def test_the_read_planes_spans_lie_on_the_producer_thread(stream_run, span):
+    _, _, spans = stream_run
+    assert spans.get(span) == {"fm-stream-prefetch"}, spans.get(span)
+
+
+def test_the_loops_wait_is_a_span_on_the_loops_thread(stream_run):
+    _, _, spans = stream_run
+    assert spans["train/input_wait"] == spans["train/step"]
+    assert "fm-stream-prefetch" not in spans["train/input_wait"]
+
+
+@pytest.mark.parametrize("workers", [1, 4])
+def test_a_stream_yields_the_unshuffled_epoch_runs_batches(tmp_path,
+                                                           workers):
+    """The stream is a log: over the sealed files of an epoch run with
+    ``shuffle`` off it builds that run's batches, array for array,
+    whatever shard boundaries fall inside a batch (60 lines a shard, 16
+    a batch), on the serial route and through the ring."""
+    from fast_tffm_tpu.data import cparser
+    from fast_tffm_tpu.data.pipeline import batch_iterator
+    if not cparser.available():
+        pytest.skip("C++ extension unavailable")
+    sd = tmp_path / "s"
+    sd.mkdir()
+    paths = _shards(sd, 3)
+    (sd / "STOP").touch()
+    cfg = _cfg(str(sd), vocabulary_size=512, batch_size=16,
+               host_threads=workers)
+    tr = sl.StreamTracker(str(sd), 0.01, "done")
+    src = sl.StreamSource(cfg, tr, workers=workers)
+    streamed = _drain(src)
+    src.close()
+    ecfg = FmConfig(vocabulary_size=512, factor_num=2, batch_size=16,
+                    shuffle=False, seed=0, host_threads=workers,
+                    train_files=tuple(paths))
+    epoch = list(batch_iterator(ecfg, paths, training=True, epochs=1))
+    assert len(streamed) == len(epoch) == 12       # 180 lines: 11.25
+    for a, b in zip(streamed, epoch):
+        assert a.num_real == b.num_real
+        for name in ("labels", "weights", "uniq_ids", "local_idx", "vals"):
+            np.testing.assert_array_equal(getattr(a, name),
+                                          getattr(b, name))
+
+
+def test_a_backlog_is_read_a_round_at_a_time_and_waited_for(tmp_path,
+                                                            monkeypatch):
+    """Found at 1,024 sealed shards (ISSUE 49): every pump read another
+    round into the ring route's scan buffer while the builders drained
+    it a group a batch (270 MB more every 50 batches, each pump copying
+    all of it), and a producer with groups in flight slept a poll
+    instead of waiting for the head. Now the buffer never holds two
+    rounds and a backlog is eaten without one sleep; the batches are
+    the serial route's, bit for bit."""
+    from fast_tffm_tpu.data import cparser
+    if not cparser.available():
+        pytest.skip("C++ extension unavailable")
+    sd = tmp_path / "s"
+    sd.mkdir()
+    _shards(sd, 12)
+    (sd / "STOP").touch()
+    cfg = _cfg(str(sd), vocabulary_size=512, batch_size=16)
+    monkeypatch.setattr(sl, "MAX_POLL_BYTES", 2048)     # a shard: ~1.9 KB
+    sleeps = []
+    monkeypatch.setattr(sl.time, "sleep", sleeps.append)
+
+    def run(workers):
+        tr = sl.StreamTracker(str(sd), 0.01, "done")
+        src = sl.StreamSource(cfg, tr, workers=workers)
+        out, held = [], 0
+        while True:
+            b = src.next_batch(block=True)
+            if b is sl.DONE:
+                break
+            out.append(b)
+            if workers > 1:
+                held = max(held, len(src._buf) - src._buf_pos)
+        src.close()
+        return out, held
+
+    serial, _ = run(1)
+    del sleeps[:]
+    ring, held = run(4)
+    assert sleeps == []
+    assert 0 < held < 2 * sl.MAX_POLL_BYTES + 200       # + a held-back line
+    assert len(ring) == len(serial) == 45
+    for a, b in zip(ring, serial):
+        np.testing.assert_array_equal(a.local_idx, b.local_idx)
+        np.testing.assert_array_equal(a.uniq_ids, b.uniq_ids)
+        # what was CONSUMED agrees; how far ahead the tracker has read
+        # and sealed (``sealed``, ``end``, ``ino``) is each route's own
+        assert ([(f["path"], f["bytes"], f["lines"])
+                 for f in a.stream_pos["files"]]
+                == [(f["path"], f["bytes"], f["lines"])
+                    for f in b.stream_pos["files"]])
+
+
+@pytest.mark.parametrize("start,end", [(0, None), (0, 0), (3, 40), (7, 8),
+                                       (10, 10_000), (0, 57)])
+def test_newlines_is_the_scanners_count_and_bytes_count(start, end,
+                                                        monkeypatch):
+    """The read plane counts lines with the C++ scanner's memchr walk
+    (a torn last line, blank lines and lines of blanks all count by
+    their newline) and, without the extension, with ``bytes.count``."""
+    from fast_tffm_tpu.data import cparser
+    data = b"1 2:1\n\n0 3:0.5 4:1\n   \n1 9:1\n0 torn"
+    want = data.count(b"\n", start, len(data) if end is None else end)
+    if cparser.available():
+        assert sl._newlines(data, start, end) == want
+    monkeypatch.setattr(cparser, "available", lambda: False)
+    assert sl._newlines(data, start, end) == want
