@@ -439,9 +439,7 @@ def make_device_batch(block: ParsedBlock, cfg: FmConfig,
     n_real = block.batch_size
     if n_real > B:
         raise ValueError(f"block of {n_real} examples exceeds batch_size {B}")
-    if raw_ids and fixed_shape:
-        raise ValueError("raw_ids (dedup=device) has no fixed-U protocol; "
-                         "multi-process mode needs dedup=host")
+    _refuse_raw_fixed(raw_ids, fixed_shape)
     sizes = block.sizes
     max_l = int(sizes.max()) if n_real else 1
     ladder = cfg.bucket_ladder
@@ -951,7 +949,10 @@ class _BatchEmitter:
         self.stats = stats
         self.pyrng = random.Random(cfg.seed if seed is None else seed)
         self.perm_seed = self.pyrng.getrandbits(64)
-        self.seq = 0  # batches finish()ed here: the serial path's count
+        # The number the stream's next batch takes: counted by finish()
+        # on the serial path, by the coordinator that cuts the groups
+        # on the parallel one.
+        self.seq = 0
         self._emit_span = counters + "/emit"  # the plane's own name
         self.window: List[DeviceBatch] = []
         self.window_cap = (max(2, cfg.queue_size // B) if shuffle
@@ -1212,15 +1213,19 @@ class _Group:
     stream provenance — the count of stream lines before it and inside
     it (for error rebasing and spill rewind). ``seq``: the number its
     batch has in the emitted stream (what a shuffled batch's row order
-    is drawn from), set by the coordinator that submits it."""
+    is drawn from), set by the coordinator that submits it, and
+    ``epoch`` the ``_Epoch`` it was cut from, where a ring builds one
+    epoch's groups behind another's (None: the workers keep one
+    emitter, the stream's)."""
 
-    __slots__ = ("blob", "line_start", "lines", "seq")
+    __slots__ = ("blob", "line_start", "lines", "seq", "epoch")
 
     def __init__(self, blob: bytes, line_start: int, lines: int):
         self.blob = blob
         self.line_start = line_start
         self.lines = lines
         self.seq = 0
+        self.epoch: Optional[_Epoch] = None
 
 
 class _GroupScanner:
@@ -1349,19 +1354,23 @@ class _FastWorkerState:
     inside the worker thread and never shared."""
 
     def __init__(self, make_builder, finish=None):
-        self._make_builder = make_builder
+        self.make_builder = make_builder
         self.bb = make_builder()
         # How a built batch leaves the builder (_BatchEmitter.finish:
         # the width, the slots and the row order it ships at, its cell
-        # count).
+        # count), where the groups name no epoch of their own.
         self.finish = finish or (lambda bb, seq: bb.finish())
         self.fed = 0  # lines consumed by self.bb since creation
 
-    def reset(self) -> None:
+    def reset(self, make_builder=None) -> None:
         # After a parse error the builder holds a half-built batch and
         # an unrecoverable line counter; a fresh builder restores both
-        # invariants (the old handle frees via __del__).
-        self.bb = self._make_builder()
+        # invariants (the old handle frees via __del__). Another
+        # ``make_builder``: an epoch whose unique budget moved at its
+        # barrier.
+        if make_builder is not None:
+            self.make_builder = make_builder
+        self.bb = self.make_builder()
         self.fed = 0
 
 
@@ -1374,11 +1383,16 @@ def _fast_group_work(state: _FastWorkerState, group: _Group):
     builder-relative to stream-relative line numbers HERE, where the
     group's line offset is known; the coordinator then attaches file
     provenance exactly like the serial path."""
+    ep, finish = group.epoch, state.finish
+    if ep is not None:
+        finish = ep.emitter.finish
+        if ep.make_builder is not state.make_builder:
+            state.reset(ep.make_builder)
     bb = state.bb
     fed_before = state.fed
     try:
         _full, consumed = bb.feed(group.blob, 0)
-        out = state.finish(bb, group.seq)
+        out = finish(bb, group.seq)
     except ParseError as e:
         state.reset()
         m = _LINE_MSG.match(str(e))
@@ -1390,6 +1404,194 @@ def _fast_group_work(state: _FastWorkerState, group: _Group):
     state.fed += (group.lines if consumed >= len(group.blob)
                   else group.blob[:consumed].count(b"\n"))
     return out, consumed
+
+
+class _Epoch:
+    """One epoch of a ring-fed stream, as the group source opens it:
+    its number, the ``_BatchEmitter`` its batches leave through (seed,
+    window, stats: the job-long feed makes one an epoch, the plain
+    iterator keeps one for all), the ``_GroupScanner`` over its files,
+    and the builder factory its groups need (another one than the last
+    epoch's only where a barrier moved the unique budget)."""
+
+    __slots__ = ("number", "emitter", "scanner", "make_builder")
+
+    def __init__(self, number: int, emitter: _BatchEmitter,
+                 scanner: _GroupScanner, make_builder):
+        self.number = number
+        self.emitter = emitter
+        self.scanner = scanner
+        self.make_builder = make_builder
+
+
+class _EpochEnd:
+    """In the group stream, after an epoch's last group."""
+
+    __slots__ = ("epoch",)
+
+    def __init__(self, epoch: _Epoch):
+        self.epoch = epoch
+
+
+class EpochMark:
+    """In a feed's batch stream, after an epoch's last batch: the
+    epoch's number and its ``SpillStats`` (``EpochFeed``)."""
+
+    __slots__ = ("epoch", "stats")
+
+    def __init__(self, epoch: int, stats: Optional[SpillStats]):
+        self.epoch = epoch
+        self.stats = stats
+
+
+class _GroupSource:
+    """The groups of one epoch after another, an ``_EpochEnd`` behind
+    each epoch's last, None when there is no further epoch. ``epochs``
+    opens an epoch when it is asked for one (and may wait there: a feed
+    held at a mark), so the next epoch's files open the moment this
+    one's run out. ``epoch`` is the one being cut: a spill rewind that
+    hands lines back to a scanner this source had finished with sets it
+    back."""
+
+    def __init__(self, epochs: Iterator[_Epoch]):
+        self._epochs = epochs
+        self.epoch: Optional[_Epoch] = None
+
+    def next(self):
+        if self.epoch is None:
+            self.epoch = next(self._epochs, None)
+            if self.epoch is None:
+                return None
+        g = self.epoch.scanner.next_group()
+        if g is None:
+            end, self.epoch = _EpochEnd(self.epoch), None
+            return end
+        g.epoch = self.epoch
+        return g
+
+
+def _ring_batches(epochs: Iterator[_Epoch], make_builder, workers: int,
+                  spill_capable: bool, num_shards: int,
+                  counters: str = TRAIN_PLANE, marks: bool = False,
+                  hold: bool = False) -> Iterator:
+    """The parallel plane's coordinator: ONE ``_BuildRing`` (its worker
+    threads, a C++ builder each from ``make_builder``) and one scanner
+    thread for every epoch of ``epochs``. An epoch's groups go into the
+    ring behind the last one's, so no worker idles at a boundary;
+    batches leave in group order through their own epoch's emitter,
+    whose window is flushed where its ``_EpochEnd`` comes up, followed
+    (``marks``) by an ``EpochMark``. Nothing is cut past an epoch's end
+    until that end has been emitted where a spill may still hand lines
+    back to the epoch's scanner (``spill_capable``) or where the
+    consumer holds the source at the mark (``hold``)."""
+    from fast_tffm_tpu.obs.telemetry import active
+    ring = _BuildRing(workers, depth=2 * workers,
+                      work=_fast_group_work,
+                      make_state=lambda: _FastWorkerState(make_builder),
+                      counters=counters)
+    tel = active()
+    if tel is not None:
+        tel.set(counters + "/host_threads", workers)
+    source = _GroupSource(epochs)
+    next_group, ahead = source.next, None
+    if not spill_capable:
+        # No rewind ever reaches a scanner, so groups are cut on a
+        # thread of its own, ahead of the ring: reading, appending and
+        # cutting a 15 MB group (14 ms at B = 32768) no longer waits
+        # for the emit beside it.
+        ahead = _read_ahead(iter(source.next, None), 2, "fm-scan")
+        next_group = functools.partial(next, ahead, None)
+    inflight: Dict[int, _Group] = {}
+    order: collections.deque = collections.deque()  # ring seqs, _EpochEnds
+    ends = 0            # _EpochEnds in ``order``
+    scan_done = False
+
+    def head_ready() -> bool:
+        return isinstance(order[0], _EpochEnd) or ring.has(order[0])
+
+    try:
+        while True:
+            # Fill the ring — a group for the batch just emitted, then
+            # on to depth, but not past a finished head: the batch that
+            # is ready goes out first (an epoch's first batch sat behind
+            # the cutting of depth groups, 0.35 s at B = 32768).
+            filled = 0
+            while (not scan_done and len(inflight) < ring.depth
+                   and not (ends and (spill_capable or hold))
+                   and not (filled and head_ready())):
+                filled += 1
+                g = next_group()
+                if g is None:
+                    scan_done = True
+                elif isinstance(g, _EpochEnd):
+                    order.append(g)
+                    ends += 1
+                else:
+                    # The batch's number in its emitter's stream: a
+                    # rewind drops what is in flight, and the re-cut
+                    # groups count on from the spilled batch.
+                    g.seq = g.epoch.emitter.seq
+                    g.epoch.emitter.seq += 1
+                    s = ring.submit(g)
+                    inflight[s] = g
+                    order.append(s)
+            if not order:
+                break
+            s = order.popleft()
+            if isinstance(s, _EpochEnd):
+                ends -= 1
+                yield from s.epoch.emitter.flush_window()
+                if marks:
+                    yield EpochMark(s.epoch.number, s.epoch.emitter.stats)
+                continue
+            g = inflight.pop(s)
+            kind, payload = ring.wait(s)
+            if tel is not None:
+                tel.set(counters + "/ring_occupancy", ring.occupancy())
+            scanner = g.epoch.scanner
+            if kind == "error":
+                if isinstance(payload, ParseError):
+                    raise _attach_stream_source(
+                        payload, scanner.file_spans, num_shards) from None
+                raise payload
+            out, consumed = payload
+            spilled = consumed < len(g.blob)
+            yield from g.epoch.emitter.emit_drain(out, spilled)
+            if spilled:
+                # Rewind: the unconsumed tail of this group plus every
+                # in-flight group after it returns to the scanner,
+                # which re-cuts from the spilled line — exactly the
+                # lines the serial builder would open the next batch
+                # with. All of them are this epoch's: nothing was cut
+                # past its end.
+                lines_used = g.blob[:consumed].count(b"\n")
+                leftover = g.blob[consumed:] + b"".join(
+                    inflight[t].blob for t in order
+                    if not isinstance(t, _EpochEnd))
+                ring.invalidate_after(s)
+                inflight.clear()
+                order.clear()
+                ends = 0
+                g.epoch.emitter.seq = g.seq + 1
+                scanner.pushback(leftover, g.line_start + lines_used)
+                source.epoch = g.epoch
+                scan_done = False
+    finally:
+        if ahead is not None:
+            ahead.close()
+        ring.close()
+
+
+def _worker_builder_factory(cfg: FmConfig, B: int, raw_ids: bool,
+                            keep_empty: bool, fixed_shape: bool,
+                            uniq_bucket: int, workers: int,
+                            row_shards: Optional[RowShards]):
+    """What a ring's worker makes its C++ builder with."""
+    return functools.partial(
+        _make_builder, cfg, B, raw_ids, keep_empty, fixed_shape,
+        uniq_bucket,
+        _worker_feed_threads(workers, bool(fixed_shape and uniq_bucket)),
+        shards=row_shards)
 
 
 def _parallel_fast_batch_iterator(cfg: FmConfig, files: List[str],
@@ -1408,8 +1610,8 @@ def _parallel_fast_batch_iterator(cfg: FmConfig, files: List[str],
     out across ``workers`` pool threads — each owning its own C++
     BatchBuilder — over a deterministic per-batch interleave of the
     shard's line groups; finished batches re-serialize through a
-    bounded ordered ring (_BuildRing) that the existing prefetch() H2D
-    stage drains.
+    bounded ordered ring (_BuildRing, driven by ``_ring_batches``) that
+    the existing prefetch() H2D stage drains.
 
     Parity guarantee (pinned by tests/test_parallel_pipeline.py): the
     emitted batch stream is BIT-IDENTICAL to ``host_threads = 1`` for
@@ -1431,106 +1633,26 @@ def _parallel_fast_batch_iterator(cfg: FmConfig, files: List[str],
       stream's requeue replayed at group granularity; speculative work
       is discarded, never emitted (spills cost a little wasted build,
       never correctness, mirroring the spill protocol's own contract).
-    """
-    from fast_tffm_tpu.obs.telemetry import active
+
+    As the serial path does over ``n_epochs``, ONE emitter serves every
+    epoch and epoch e's file order is drawn from ``(seed, e)``; the
+    training feed's epochs, an emitter and a seed each, are
+    ``EpochFeed``'s."""
     spill_capable = bool(fixed_shape and uniq_bucket)
-    feed_threads = _worker_feed_threads(workers, spill_capable)
-    make_builder = functools.partial(_make_builder, cfg, B, raw_ids,
-                                     keep_empty, fixed_shape,
-                                     uniq_bucket, feed_threads,
-                                     shards=row_shards)
+    make_builder = _worker_builder_factory(
+        cfg, B, raw_ids, keep_empty, fixed_shape, uniq_bucket, workers,
+        row_shards)
     emitter = _BatchEmitter(cfg, B, effective_L_cap(cfg), fixed_shape,
                             uniq_bucket, shuffle, seed, stats,
                             shards=row_shards, counters=counters)
     retry = RetryPolicy.from_config(cfg)
     file_seed = cfg.seed if seed is None else seed
-    emitted = 0  # batches handed to the emitter: the stream's count
-    ring = _BuildRing(workers, depth=2 * workers,
-                      work=_fast_group_work,
-                      make_state=lambda: _FastWorkerState(
-                          make_builder, emitter.finish),
-                      counters=counters)
-    tel = active()
-    if tel is not None:
-        tel.set(counters + "/host_threads", workers)
-    ahead = None
-    try:
-        for epoch in range(n_epochs):
-            scanner = _GroupScanner(
-                epoch_file_order(files, shuffle, file_seed, epoch),
-                shard_index, num_shards, B, keep_empty, retry,
-                file_marks=file_marks)
-            inflight: Dict[int, _Group] = {}
-            order: collections.deque = collections.deque()
-            scan_done = False
-            next_group = scanner.next_group
-            if not spill_capable:
-                # No rewind ever reaches the scanner, so it cuts groups
-                # on a thread of its own, ahead of the ring: reading,
-                # appending and cutting a 15 MB group (14 ms at B =
-                # 32768) no longer waits for the emit beside it.
-                ahead = _read_ahead(iter(scanner.next_group, None), 2,
-                                    "fm-scan")
-                next_group = functools.partial(next, ahead, None)
-            while True:
-                # Fill the ring — a group for the batch just emitted,
-                # then on to depth, but not past a finished head: the
-                # batch that is ready goes out first (an epoch's first
-                # batch sat behind the cutting of depth groups, 0.35 s
-                # at B = 32768).
-                filled = 0
-                while (not scan_done and len(inflight) < ring.depth
-                       and not (filled and ring.has(order[0]))):
-                    filled += 1
-                    g = next_group()
-                    if g is None:
-                        scan_done = True
-                        break
-                    # The batch's number in the stream: a rewind drops
-                    # what is in flight, and the re-cut groups count on
-                    # from the spilled batch.
-                    g.seq = emitted + len(order)
-                    s = ring.submit(g)
-                    inflight[s] = g
-                    order.append(s)
-                if not order:
-                    break
-                s = order.popleft()
-                g = inflight.pop(s)
-                kind, payload = ring.wait(s)
-                if tel is not None:
-                    tel.set(counters + "/ring_occupancy",
-                            ring.occupancy())
-                if kind == "error":
-                    if isinstance(payload, ParseError):
-                        raise _attach_stream_source(
-                            payload, scanner.file_spans,
-                            num_shards) from None
-                    raise payload
-                out, consumed = payload
-                spilled = consumed < len(g.blob)
-                emitted += 1
-                yield from emitter.emit_drain(out, spilled)
-                if spilled:
-                    # Rewind: the unconsumed tail of this group plus
-                    # every in-flight group after it returns to the
-                    # scanner, which re-cuts from the spilled line —
-                    # exactly the lines the serial builder would open
-                    # the next batch with.
-                    lines_used = g.blob[:consumed].count(b"\n")
-                    leftover = g.blob[consumed:] + b"".join(
-                        inflight[t].blob for t in order)
-                    ring.invalidate_after(s)
-                    inflight.clear()
-                    order.clear()
-                    scanner.pushback(leftover,
-                                     g.line_start + lines_used)
-                    scan_done = False
-            yield from emitter.flush_window()
-    finally:
-        if ahead is not None:
-            ahead.close()
-        ring.close()
+    epochs = (_Epoch(epoch, emitter, _GroupScanner(
+        epoch_file_order(files, shuffle, file_seed, epoch), shard_index,
+        num_shards, B, keep_empty, retry, file_marks=file_marks),
+        make_builder) for epoch in range(n_epochs))
+    return _ring_batches(epochs, make_builder, workers, spill_capable,
+                         num_shards, counters)
 
 
 def _fast_batch_iterator(cfg: FmConfig, bb, files: List[str], B: int,
@@ -1716,7 +1838,6 @@ def batch_iterator(cfg: FmConfig, files: Sequence[str],
 
     ``counters``: the prefix this plane's counts carry
     (``TRAIN_PLANE``; a validation sweep's ``VALIDATION_PLANE``)."""
-    from fast_tffm_tpu.obs.telemetry import active
     it = _batch_iterator_impl(cfg if vocab is None
                               else vocab.build_cfg(cfg), files,
                               training=training,
@@ -1732,13 +1853,21 @@ def batch_iterator(cfg: FmConfig, files: Sequence[str],
                               row_shards=(row_shards if vocab is None
                                           else None),
                               counters=counters)
+    return _seamed(it, cfg, vocab, counters)
+
+
+def _seamed(it: Iterator, cfg: FmConfig, vocab, counters: str) -> Iterator:
+    """``batch_iterator``'s telemetry and vocab seam over a stream of
+    built batches; a feed's ``EpochMark`` goes through as it is."""
+    from fast_tffm_tpu.obs.telemetry import active
     tel = active()
     if tel is None:
         if vocab is None:
             yield from it
         else:
             for batch in it:
-                yield vocab.remap(batch)
+                yield (batch if isinstance(batch, EpochMark)
+                       else vocab.remap(batch))
         return
     import time as _time
     from fast_tffm_tpu.obs.trace import span
@@ -1754,6 +1883,9 @@ def batch_iterator(cfg: FmConfig, files: Sequence[str],
             batch = next(it, None)
         if batch is None:
             return
+        if isinstance(batch, EpochMark):
+            yield batch
+            continue
         if vocab is not None:
             # Remap INSIDE the build bracket (it is build cost) and
             # before pipeline_batch: the padding-waste counter must
@@ -1826,9 +1958,7 @@ def _batch_iterator_impl(cfg: FmConfig, files: Sequence[str],
     rng = random.Random(cfg.seed if seed is None else seed)
     do_shuffle = training and cfg.shuffle
     uniq_bucket = uniq_bucket or cfg.uniq_bucket
-    if raw_ids and fixed_shape:
-        raise ValueError("raw_ids (dedup=device) has no fixed-U protocol; "
-                         "multi-process mode needs dedup=host")
+    _refuse_raw_fixed(raw_ids, fixed_shape)
     if file_marks is not None:
         # The ledger maps example offsets to files; that mapping only
         # exists for a single in-order keep_empty pass (one example per
@@ -1852,18 +1982,8 @@ def _batch_iterator_impl(cfg: FmConfig, files: Sequence[str],
     workers = host_parallel_workers(cfg, weight_files, keep_empty,
                                     fixed_shape)
     if _fast_path_eligible(cfg, weight_files):
-        # ONE C++-or-generic decision for both planes, taken here
-        # before any pool thread exists: a builder that cannot be made
-        # sends the serial AND the parallel plane down the generic
-        # path (cparser logged why, at WARNING). The decision is the
-        # construction, not cparser.available(): "no C++" is the
-        # builder's RuntimeError, which is also the seam
-        # tests/test_sharded_input.py forces the generic path through.
-        try:
-            bb = _make_builder(cfg, B, raw_ids, keep_empty, fixed_shape,
-                               uniq_bucket, shards=row_shards)
-        except RuntimeError:
-            bb = None
+        bb = _proven_builder(cfg, B, raw_ids, keep_empty, fixed_shape,
+                             uniq_bucket, row_shards)
         if bb is not None:
             if workers > 1:
                 # The pool's workers each make their own builder; this
@@ -2207,6 +2327,29 @@ def empty_batch(cfg: FmConfig, batch_size: Optional[int] = None,
                              shards=shards)
 
 
+def _refuse_raw_fixed(raw_ids: bool, fixed_shape: bool) -> None:
+    if raw_ids and fixed_shape:
+        raise ValueError("raw_ids (dedup=device) has no fixed-U protocol; "
+                         "multi-process mode needs dedup=host")
+
+
+def _proven_builder(cfg: FmConfig, B: int, raw_ids: bool,
+                    keep_empty: bool, fixed_shape: bool, uniq_bucket: int,
+                    row_shards: Optional[RowShards]):
+    """ONE C++-or-generic decision for both planes, taken before any
+    pool thread exists: the serial path's builder, or None where none
+    can be made, which sends the serial AND the parallel plane down
+    the generic path (cparser logged why, at WARNING). The decision is
+    the construction, not cparser.available(): "no C++" is the
+    builder's RuntimeError, which is also the seam
+    tests/test_sharded_input.py forces the generic path through."""
+    try:
+        return _make_builder(cfg, B, raw_ids, keep_empty, fixed_shape,
+                             uniq_bucket, shards=row_shards)
+    except RuntimeError:
+        return None
+
+
 def _fast_path_eligible(cfg: FmConfig,
                         weight_files: Sequence[str]) -> bool:
     """The ONE gate for the chunked C++ fast path: no per-line Python
@@ -2333,6 +2476,9 @@ def _each_placed(batches: Iterator[DeviceBatch], place) -> Iterator[tuple]:
     from fast_tffm_tpu.obs.trace import span
     try:
         for batch in batches:
+            if isinstance(batch, EpochMark):
+                yield batch
+                continue
             if place is None:
                 yield batch, None
                 continue
@@ -2341,6 +2487,210 @@ def _each_placed(batches: Iterator[DeviceBatch], place) -> Iterator[tuple]:
             yield item
     finally:  # closed with the stage: the stages behind it stop too
         batches.close()
+
+
+class EpochFeed:
+    """An epochs-mode job's training feed, opened once: ``(batch,
+    placed)`` for every batch of epochs ``epochs`` (a range) and, in
+    band, one ``EpochMark`` behind each epoch's last batch. Epoch e's
+    batches are, array for array and in order, those of
+    ``batch_iterator(cfg, files, epochs=1, seed=cfg.seed + e, ...)``;
+    what is an epoch's stays an epoch's (the emitter with its seed and
+    shuffle window, the scanner, the ``SpillStats``) and what is the
+    plane's is made once (the ``prefetch`` and ``fm-place`` threads
+    and, on the parallel fast path, the build ring with its workers'
+    C++ builders and the ``fm-scan`` thread: ``_ring_batches``; the
+    other routes open each epoch's ``_batch_iterator_impl`` on the
+    producing thread as the last one runs out). So epoch e + 1's first
+    batches are cut, built and placed while epoch e's last steps run,
+    as far ahead as the queues there are allow.
+
+    ``hold``: a barrier can change what the next epoch's batches are
+    (the caller's predicate). Nothing of epoch e + 1 is then cut until
+    the consumer has called ``release(e)``, and ``uniq_bucket()`` is
+    read after that. ``place``: ``place_ahead``'s. ``stats(e)``: the
+    ``SpillStats`` of an epoch whose mark has not been taken yet (a
+    loop that stops inside it). ``close()`` stops every thread of the
+    feed within ``_read_ahead``'s bound and lets go of what was placed.
+    Counts ``pipeline/epochs_fed_ahead``: barriers the loop came out of
+    (``release``) with the next epoch's first batch already out of the
+    builders."""
+
+    def __init__(self, cfg: FmConfig, files: Sequence[str], epochs: range,
+                 place, hold: bool, uniq_bucket,
+                 weight_files: Sequence[str] = (), shard_index: int = 0,
+                 num_shards: int = 1, fixed_shape: bool = False,
+                 raw_ids: bool = False,
+                 bad_lines: Optional[BadLineTracker] = None, vocab=None,
+                 row_shards: Optional[RowShards] = None):
+        from fast_tffm_tpu.obs.telemetry import active
+        self._cfg, self._files, self._epochs = cfg, files, epochs
+        self._hold, self._uniq_bucket = hold, uniq_bucket
+        self._vocab = vocab
+        self._build_cfg = cfg if vocab is None else vocab.build_cfg(cfg)
+        self._plane = dict(
+            weight_files=weight_files, shard_index=shard_index,
+            num_shards=num_shards, fixed_shape=fixed_shape,
+            raw_ids=raw_ids, bad_lines=bad_lines,
+            row_shards=row_shards if vocab is None else None)
+        self._cv = threading.Condition()
+        self._closed = False
+        self._released = epochs.start - 1   # barriers the loop is past
+        self._first_out = epochs.start - 1  # newest epoch with a batch out
+        self._stats: Dict[int, SpillStats] = {}
+        self._tel = active()
+        if self._tel is not None:
+            self._tel.count("pipeline/epochs_fed_ahead", 0)
+        self._it = place_ahead(
+            prefetch(self._host_batches(), depth=cfg.prefetch_depth,
+                     gil_bound=gil_bound_iteration(cfg, weight_files)),
+            place, cfg.prefetch_depth)
+
+    # -- the consumer's side ---------------------------------------------
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        item = next(self._it)
+        if isinstance(item, EpochMark):
+            with self._cv:
+                self._stats.pop(item.epoch, None)
+        return item
+
+    def release(self, epoch: int) -> None:
+        """The loop is past ``epoch``'s barrier and about to ask for the
+        next epoch's first batch: fed ahead, if that batch has left the
+        builders by now (never where the feed was held until this)."""
+        with self._cv:
+            fed_ahead = self._first_out > epoch
+            self._released = epoch
+            self._cv.notify_all()
+        if fed_ahead and self._tel is not None:
+            self._tel.count("pipeline/epochs_fed_ahead")
+
+    def stats(self, epoch: int) -> SpillStats:
+        with self._cv:
+            return self._stats.get(epoch) or SpillStats()
+
+    def close(self) -> None:
+        with self._cv:  # first: a producer held at a mark wakes and ends
+            self._closed = True
+            self._cv.notify_all()
+        self._it.close()
+
+    # -- the producers' side ---------------------------------------------
+
+    def _budget(self) -> int:
+        """The unique budget as the session has it now."""
+        return self._uniq_bucket() or self._build_cfg.uniq_bucket
+
+    def _past_barrier(self, epoch: int) -> bool:
+        """Wait until the loop is past ``epoch``'s barrier (at once
+        where nothing holds the feed there); False where the feed was
+        closed meanwhile."""
+        with self._cv:
+            while (self._hold and self._released < epoch
+                   and not self._closed):
+                self._cv.wait()
+            return not self._closed
+
+    def _open(self, epoch: int) -> Optional[SpillStats]:
+        """``epoch``'s stats once it may be cut, None where the feed
+        was closed meanwhile."""
+        if not self._past_barrier(epoch - 1):
+            return None
+        with self._cv:
+            stats = self._stats[epoch] = SpillStats()
+        return stats
+
+    def _host_batches(self) -> Iterator:
+        """The batches as the placement stage takes them, behind
+        ``batch_iterator``'s seam. A held feed waits here too, outside
+        the seam's build bracket."""
+        it = _seamed(self._stream(), self._cfg, self._vocab, TRAIN_PLANE)
+        epoch, first = self._epochs.start, True
+        try:
+            for item in it:
+                if isinstance(item, EpochMark):
+                    yield item
+                    epoch, first = item.epoch + 1, True
+                    if (epoch < self._epochs.stop
+                            and not self._past_barrier(item.epoch)):
+                        return
+                    continue
+                if first:
+                    first = False
+                    with self._cv:
+                        self._first_out = epoch
+                yield item
+        finally:
+            it.close()
+
+    def _stream(self) -> Iterator:
+        cfg, plane = self._build_cfg, self._plane
+        fixed_shape = plane["fixed_shape"]
+        workers = host_parallel_workers(cfg, plane["weight_files"], False,
+                                        fixed_shape)
+        if (workers > 1 and _fast_path_eligible(cfg, plane["weight_files"])
+                and _proven_builder(
+                    cfg, cfg.batch_size, plane["raw_ids"], False,
+                    fixed_shape, self._budget(),
+                    plane["row_shards"]) is not None):
+            return self._ring_stream(workers)
+        return self._chained_stream()
+
+    def _chained_stream(self) -> Iterator:
+        for epoch in self._epochs:
+            stats = self._open(epoch)
+            if stats is None:
+                return
+            yield from _batch_iterator_impl(
+                self._build_cfg, self._files, training=True, epochs=1,
+                seed=self._cfg.seed + epoch,
+                uniq_bucket=self._budget(), stats=stats, **self._plane)
+            yield EpochMark(epoch, stats)
+
+    def _ring_stream(self, workers: int) -> Iterator:
+        cfg, plane = self._build_cfg, self._plane
+        files = expand_files(self._files)
+        B, shuffle = cfg.batch_size, cfg.shuffle
+        fixed_shape, row_shards = plane["fixed_shape"], plane["row_shards"]
+        _refuse_raw_fixed(plane["raw_ids"], fixed_shape)
+        retry = RetryPolicy.from_config(cfg)
+        # A budget's builder factory, the same object while the budget
+        # stands: how a worker knows its builder is still the right one.
+        makers: Dict[int, functools.partial] = {}
+
+        def make_builder(uniq_bucket: int):
+            if uniq_bucket not in makers:
+                makers[uniq_bucket] = _worker_builder_factory(
+                    cfg, B, plane["raw_ids"], False, fixed_shape,
+                    uniq_bucket, workers, row_shards)
+            return makers[uniq_bucket]
+
+        def epochs() -> Iterator[_Epoch]:
+            for epoch in self._epochs:
+                stats = self._open(epoch)
+                if stats is None:
+                    return
+                uniq_bucket = self._budget()
+                seed = self._cfg.seed + epoch
+                yield _Epoch(
+                    epoch,
+                    _BatchEmitter(cfg, B, effective_L_cap(cfg), fixed_shape,
+                                  uniq_bucket, shuffle, seed, stats,
+                                  shards=row_shards),
+                    _GroupScanner(epoch_file_order(files, shuffle, seed, 0),
+                                  plane["shard_index"], plane["num_shards"],
+                                  B, False, retry),
+                    make_builder(uniq_bucket))
+
+        first_bucket = self._budget()
+        return _ring_batches(
+            epochs(), make_builder(first_bucket), workers,
+            bool(fixed_shape and first_bucket), plane["num_shards"],
+            marks=True, hold=self._hold)
 
 
 def _read_ahead(iterator: Iterator, depth: int, name: str) -> Iterator:

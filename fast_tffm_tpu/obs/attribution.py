@@ -154,8 +154,8 @@ def attribution(summary: Dict[str, Any]) -> Dict[str, Any]:
     step = h.get("train/step_seconds") or {}
     loop_s = step.get("sum") or 0.0
     # The loop thread's wall on one anchor (train/loop_seconds): the
-    # steps, the pauses and every epoch barrier's flush and cold
-    # pipeline, which the step histogram's per-epoch anchor leaves
+    # steps, the pauses and every epoch barrier's flush and first
+    # batch, which the step histogram's per-epoch anchor leaves
     # out. A stream from before the counter adds the pauses it knows.
     loop_wall = c.get("train/loop_seconds") or 0.0
     steps = c.get("train/steps") or step.get("count") or 0

@@ -527,8 +527,8 @@ class Phase(NamedTuple):
 # (LOOP_LEAVES) and the README's list of phases
 # (tests/test_loop_phases.py) are read off this map.
 ANATOMY_PHASES: Dict[str, Phase] = {
-    # an epoch's first next() is pipeline/first_batch (the cold
-    # plane's), by its dur also in pipeline/first_batch_seconds
+    # an epoch's first next() is pipeline/first_batch (the job's first
+    # is the cold plane's), by its dur also in pipeline/first_batch_seconds
     "anatomy/input_wait_seconds": Phase(
         "train/input_wait_seconds",
         ("train/input_wait", "pipeline/first_batch"), "input wait"),

@@ -26,9 +26,9 @@ from fast_tffm_tpu.checkpoint import (CheckpointState,
                                       export_npz, resume_start_epoch)
 from fast_tffm_tpu.config import FmConfig
 from fast_tffm_tpu.data.badlines import BadLineTracker
-from fast_tffm_tpu.data.pipeline import (SPILL_WARN_FRACTION, place_ahead,
-                                         VALIDATION_PLANE, SpillStats,
-                                         batch_iterator,
+from fast_tffm_tpu.data.pipeline import (SPILL_WARN_FRACTION,
+                                         VALIDATION_PLANE, EpochFeed,
+                                         EpochMark, batch_iterator,
                                          gil_bound_iteration,
                                          host_parallel_workers, prefetch,
                                          uniq_bucket_top)
@@ -1603,51 +1603,66 @@ def _agreed_batch(s: _Session, loop: StepLoop, batch, epoch: int):
 def _run_epochs(s: _Session, loop: StepLoop) -> None:
     """``run_mode = epochs``: ``epoch_num`` passes over ``train_files``
     from where the restored schedule stands, each ending in the epoch
-    barrier (``_epoch_barrier``)."""
+    barrier (``_epoch_barrier``). ONE feed for the job
+    (``pipeline.EpochFeed``): an epoch's end comes in band, as a mark
+    behind its last batch, and the next epoch's first batches are built
+    and placed while this one's last steps run."""
     cfg = s.cfg
-    for epoch in range(s.start_epoch, cfg.epoch_num):
-        if loop.stopping:
-            break
-        epoch_stats = SpillStats()
-        # The feed places where a batch is final when emitted; the loop,
-        # where a publish barrier may re-point a queued batch (admit), the
-        # processes agree before anything is placed, or under offload.
-        place = (None if s.vocab is not None or s.multi_process or s.offload
-                 else loop.feed_place)
-        # Threads, builders, files: until the first next() can be called.
-        with span("pipeline/open", seconds="pipeline/open_seconds",
-                  epoch=epoch):
-            it = place_ahead(prefetch(batch_iterator(
-                cfg, cfg.train_files, training=True,
-                weight_files=cfg.weight_files,
-                shard_index=s.shard_index, num_shards=s.num_shards,
-                epochs=1, seed=cfg.seed + epoch,
-                fixed_shape=s.multi_process, uniq_bucket=s.uniq_bucket,
-                stats=epoch_stats, raw_ids=s.raw_mode,
-                bad_lines=s.bad_tracker, vocab=s.vocab,
-                row_shards=s.row_shards),
-                depth=cfg.prefetch_depth,
-                gil_bound=gil_bound_iteration(cfg, cfg.weight_files)),
-                place, cfg.prefetch_depth)
-        # fmlint: disable=R003 -- anchors the per-epoch
-        # step-seconds window (always-on aggregate)
-        loop.t_prev = time.perf_counter()
-        first = True  # the cold plane's first batch goes by its own name
-        try:
+    # Where a barrier can change what the next epoch's batches are, the
+    # feed waits at the mark and the loop places: a vocab barrier
+    # re-points rows (admit), the processes agree on U and on every step
+    # before anything is placed, offload.
+    hold = s.vocab is not None or s.multi_process or s.offload
+    feed = None
+    try:
+        for epoch in range(s.start_epoch, cfg.epoch_num):
+            if loop.stopping:
+                break
+            # What the loop does to have an epoch's first next() to
+            # call: the job's first epoch opens the feed (generators:
+            # its threads, builders and files are the first next()'s),
+            # every later one tells it that the barrier is over.
+            with span("pipeline/open", seconds="pipeline/open_seconds",
+                      epoch=epoch):
+                if feed is None:
+                    feed = EpochFeed(
+                        cfg, cfg.train_files,
+                        range(s.start_epoch, cfg.epoch_num),
+                        place=None if hold else loop.feed_place,
+                        hold=hold, uniq_bucket=lambda: s.uniq_bucket,
+                        weight_files=cfg.weight_files,
+                        shard_index=s.shard_index,
+                        num_shards=s.num_shards,
+                        fixed_shape=s.multi_process, raw_ids=s.raw_mode,
+                        bad_lines=s.bad_tracker, vocab=s.vocab,
+                        row_shards=s.row_shards)
+                else:
+                    feed.release(epoch - 1)
+            # fmlint: disable=R003 -- anchors the per-epoch
+            # step-seconds window (always-on aggregate)
+            loop.t_prev = time.perf_counter()
+            first = True  # an epoch's first batch goes by its own name
+            mark = None   # the epoch's end, once the feed has handed it over
             while True:
-                # Consumer-side stall: time blocked INSIDE next() only.
-                # Any wider would fold end-of-step bookkeeping (notably
-                # a loss line's deliberate float(loss) device sync) into the
-                # host-bound signal and misdiagnose a device-bound run (the
-                # build cost is timed on the producing threads).
-                with span("pipeline/first_batch" if first
-                          else "train/input_wait",
-                          seconds="train/input_wait_seconds",
-                          step=loop.global_step + 1) as wait:
-                    batch, loop.placed = next(it, (None, None))
-                if first and s.tel is not None:
-                    s.tel.count("pipeline/first_batch_seconds", wait.dur)
-                first = False
+                batch = None
+                if mark is None:  # in lockstep the others may still step
+                    # Consumer-side stall: time blocked INSIDE next() only.
+                    # Any wider would fold end-of-step bookkeeping (notably
+                    # a loss line's deliberate float(loss) device sync) into
+                    # the host-bound signal and misdiagnose a device-bound
+                    # run (the build cost is timed on the producing threads).
+                    with span("pipeline/first_batch" if first
+                              else "train/input_wait",
+                              seconds="train/input_wait_seconds",
+                              step=loop.global_step + 1) as wait:
+                        item = next(feed)
+                    if first and s.tel is not None:
+                        s.tel.count("pipeline/first_batch_seconds", wait.dur)
+                    first = False
+                    if isinstance(item, EpochMark):
+                        mark = item
+                    else:
+                        batch, loop.placed = item
                 batch = _agreed_batch(s, loop, batch, epoch)
                 # fmlint: disable=R014 -- _agreed_batch returns None on
                 # every process together in multi-process mode (it agrees
@@ -1667,10 +1682,13 @@ def _run_epochs(s: _Session, loop: StepLoop) -> None:
                         loop.save(loop.completed_epochs, wait=s.offload)
                     if s.tel is not None:  # keep the pause out of the next
                         loop.t_prev += pause.dur  # step's step_seconds
-        finally:  # a step that raised, a preemption: the feed's threads
-            loop.placed = None  # stop, and what they placed is let go
-            it.close()
-        _epoch_barrier(s, loop, epoch, epoch_stats)
+            loop.placed = None  # a preemption's: the batch it did not step
+            _epoch_barrier(s, loop, epoch, mark.stats if mark is not None
+                           else feed.stats(epoch))
+    finally:  # a step that raised, a preemption, the job's end: the
+        loop.placed = None  # feed's threads stop, what they placed is let go
+        if feed is not None:
+            feed.close()
 
 
 def _epoch_barrier(s: _Session, loop: StepLoop, epoch: int,

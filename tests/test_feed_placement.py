@@ -21,6 +21,7 @@ import pytest
 
 from fast_tffm_tpu import train as train_mod
 from fast_tffm_tpu.config import FmConfig
+from fast_tffm_tpu.data import pipeline
 from fast_tffm_tpu.obs.sink import read_events
 from fast_tffm_tpu.parallel import sharded
 from fast_tffm_tpu.wire import WireBatch
@@ -57,8 +58,8 @@ def _cfg(d, **kw):
 def _loop_places(monkeypatch):
     """The same feed with its last stage handing batches over unplaced:
     what a session that must place for itself gets."""
-    real = train_mod.place_ahead
-    monkeypatch.setattr(train_mod, "place_ahead",
+    real = pipeline.place_ahead
+    monkeypatch.setattr(pipeline, "place_ahead",
                         lambda it, place, depth: real(it, None, depth))
 
 
@@ -180,7 +181,7 @@ def test_the_loop_places_where_the_session_says_it_must(
             raise _Decided()
 
         monkeypatch.setattr(train_mod, "_run_epochs", as_two)
-        monkeypatch.setattr(train_mod, "place_ahead", spy)
+        monkeypatch.setattr(pipeline, "place_ahead", spy)
         with pytest.raises(_Decided):
             train_mod.train(_cfg(tmp_path))
         assert handed == [None]
@@ -269,13 +270,13 @@ def test_a_batch_cut_for_another_mesh_is_refused_in_the_caller(
     """The refusal is raised on the placement thread and re-raised where
     the loop asks for its next batch, with the same words."""
     _devices(monkeypatch, 4)
-    real = train_mod.batch_iterator
+    real = train_mod.EpochFeed
 
     def uncut(*a, **kw):
         kw["row_shards"] = None     # one segment, for a mesh of four
         return real(*a, **kw)
 
-    monkeypatch.setattr(train_mod, "batch_iterator", uncut)
+    monkeypatch.setattr(train_mod, "EpochFeed", uncut)
     with pytest.raises(ValueError, match="a batch of 1 segment"
                        r"\(s\) of unique rows fed to a mesh of 4"):
         train_mod.train(_cfg(tmp_path))

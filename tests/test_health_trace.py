@@ -235,7 +235,8 @@ def test_stalled_train_run_emits_health_and_stacks(tmp_path, rng,
     cfg = _train_cfg(tmp_path, rng, watchdog_stall_seconds=0.25,
                      epoch_num=1)
     from fast_tffm_tpu import train as train_mod
-    real_prefetch = train_mod.prefetch
+    from fast_tffm_tpu.data import pipeline
+    real_prefetch = pipeline.prefetch
 
     def stalling_prefetch(it, **kw):
         inner = real_prefetch(it, **kw)
@@ -247,7 +248,7 @@ def test_stalled_train_run_emits_health_and_stacks(tmp_path, rng,
                 yield batch
         return gen()
 
-    monkeypatch.setattr(train_mod, "prefetch", stalling_prefetch)
+    monkeypatch.setattr(pipeline, "prefetch", stalling_prefetch)
     train_mod.train(cfg)
     path = cfg.model_file + ".metrics.jsonl"
     health = [e for e in read_events(path) if e["event"] == "health"]

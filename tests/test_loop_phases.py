@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from fast_tffm_tpu import train as train_mod
+from fast_tffm_tpu.data import pipeline
 from fast_tffm_tpu.obs.attribution import attribution, render, summarize
 from fast_tffm_tpu.obs.sink import read_events
 from fast_tffm_tpu.obs.telemetry import (ANATOMY_PHASES, FEED_PLACE,
@@ -227,7 +228,7 @@ def test_a_stalled_next_yields_one_slow_step_naming_input_wait(
         tmp_path, monkeypatch, capsys, stage, kw):
     cfg = _train_cfg(tmp_path, np.random.default_rng(1), epoch_num=1,
                      validation_files=(), **kw)
-    real = getattr(train_mod, stage)
+    real = getattr(pipeline, stage)
     # The constant comes down around the stall only: a CPU's first
     # compile (step 1) is slow too, and is not what is tested.
     monkeypatch.setattr(train_mod, "SLOW_STEP_SECONDS", 1e9)
@@ -241,7 +242,7 @@ def test_a_stalled_next_yields_one_slow_step_naming_input_wait(
                 monkeypatch.setattr(train_mod, "SLOW_STEP_SECONDS", 1e9)
             yield batch
 
-    monkeypatch.setattr(train_mod, stage, stalled)
+    monkeypatch.setattr(pipeline, stage, stalled)
     train_mod.train(cfg)
     path = cfg.model_file + ".metrics.jsonl"
     events = list(read_events(path))

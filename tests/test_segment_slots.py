@@ -232,8 +232,8 @@ def test_a_train_run_refuses_a_feed_cut_for_another_mesh(tmp_path,
                log_steps=0)
     _write(path, cfg, np.random.default_rng(37), 1, 6000)
     monkeypatch.setattr(
-        train_mod, "batch_iterator",
-        lambda *a, row_shards=None, **k: batch_iterator(*a, **k))
+        train_mod, "EpochFeed",
+        lambda *a, row_shards=None, **k: pipeline.EpochFeed(*a, **k))
     with pytest.raises(ValueError, match="1 segment.* 8 row shards"):
         train_mod.train(cfg)
 

@@ -206,15 +206,14 @@ def test_a_barrier_that_holds_a_sweep_is_no_slow_step_for_that(
     # and a barrier slow beside its sweep still says so
     (tmp_path / "b").mkdir()
     cfg2 = _train_cfg(tmp_path / "b", np.random.default_rng(4), epoch_num=2)
-    real_iter = train_mod.batch_iterator
+    real_release = train_mod.EpochFeed.release
 
-    def slow_open(*a, **k):
-        if k.get("training", True):
-            time.sleep(0.4)
-        return real_iter(*a, **k)
+    def slow_release(self, epoch):  # the feed is the job's: no epoch opens
+        time.sleep(0.4)             # one; the loop tells it to go on
+        return real_release(self, epoch)
 
     monkeypatch.setattr(train_mod, "evaluate", real)
-    monkeypatch.setattr(train_mod, "batch_iterator", slow_open)
+    monkeypatch.setattr(train_mod.EpochFeed, "release", slow_release)
     train_mod.train(cfg2)
     slow = [e for e in _events(cfg2) if e["event"] == "slow_step"
             and e["what"] == "barrier"]
