@@ -28,6 +28,7 @@ import collections
 import dataclasses
 import functools
 import glob as globlib
+import itertools
 import os
 import random
 import re
@@ -1412,16 +1413,21 @@ class _Epoch:
     window, stats: the job-long feed makes one an epoch, the plain
     iterator keeps one for all), the ``_GroupScanner`` over its files,
     and the builder factory its groups need (another one than the last
-    epoch's only where a barrier moved the unique budget)."""
+    epoch's only where a barrier moved the unique budget). ``groups``:
+    how many of its groups may still be cut (a capped sweep's; None: as
+    many as its files hold). A group is a batch only where no spill
+    hands lines back, so a capped epoch has no fixed-U protocol."""
 
-    __slots__ = ("number", "emitter", "scanner", "make_builder")
+    __slots__ = ("number", "emitter", "scanner", "make_builder", "groups")
 
     def __init__(self, number: int, emitter: _BatchEmitter,
-                 scanner: _GroupScanner, make_builder):
+                 scanner: _GroupScanner, make_builder,
+                 groups: Optional[int] = None):
         self.number = number
         self.emitter = emitter
         self.scanner = scanner
         self.make_builder = make_builder
+        self.groups = groups
 
 
 class _EpochEnd:
@@ -1462,11 +1468,14 @@ class _GroupSource:
             self.epoch = next(self._epochs, None)
             if self.epoch is None:
                 return None
-        g = self.epoch.scanner.next_group()
+        ep = self.epoch
+        g = None if ep.groups == 0 else ep.scanner.next_group()
         if g is None:
-            end, self.epoch = _EpochEnd(self.epoch), None
+            end, self.epoch = _EpochEnd(ep), None
             return end
-        g.epoch = self.epoch
+        if ep.groups is not None:
+            ep.groups -= 1
+        g.epoch = ep
         return g
 
 
@@ -1853,21 +1862,23 @@ def batch_iterator(cfg: FmConfig, files: Sequence[str],
                               row_shards=(row_shards if vocab is None
                                           else None),
                               counters=counters)
-    return _seamed(it, cfg, vocab, counters)
+    return _seamed(it, cfg, None if vocab is None else vocab.remap,
+                   counters)
 
 
-def _seamed(it: Iterator, cfg: FmConfig, vocab, counters: str) -> Iterator:
+def _seamed(it: Iterator, cfg: FmConfig, remap, counters: str) -> Iterator:
     """``batch_iterator``'s telemetry and vocab seam over a stream of
-    built batches; a feed's ``EpochMark`` goes through as it is."""
+    built batches (``remap``: the vocab's, or None); a feed's
+    ``EpochMark`` goes through as it is."""
     from fast_tffm_tpu.obs.telemetry import active
     tel = active()
     if tel is None:
-        if vocab is None:
+        if remap is None:
             yield from it
         else:
             for batch in it:
                 yield (batch if isinstance(batch, EpochMark)
-                       else vocab.remap(batch))
+                       else remap(batch))
         return
     import time as _time
     from fast_tffm_tpu.obs.trace import span
@@ -1886,11 +1897,11 @@ def _seamed(it: Iterator, cfg: FmConfig, vocab, counters: str) -> Iterator:
         if isinstance(batch, EpochMark):
             yield batch
             continue
-        if vocab is not None:
+        if remap is not None:
             # Remap INSIDE the build bracket (it is build cost) and
             # before pipeline_batch: the padding-waste counter must
             # see the physical pad_id the remap writes.
-            batch = vocab.remap(batch)
+            batch = remap(batch)
         # fmlint: disable=R003 -- closes the build-seconds sample
         tel.pipeline_batch(batch, pad_id,
                            build_seconds=_time.perf_counter() - t0,
@@ -2415,10 +2426,11 @@ def prefetch(iterator: Iterator[DeviceBatch], depth: int = 2,
     that combination keeps the passthrough.
 
     What this hands over are HOST batches. Where they cross to the
-    device is the consumer's: the train loop's feed adds a stage of its
-    own behind this one (``place_ahead``: wire encoding and placement a
-    batch ahead, off the loop's thread), a validation sweep and
-    predict place on their own threads as they dispatch.
+    device is the consumer's: a job's feeds (``EpochFeed``: the
+    training plane's, a validating job's sweeps') add a stage of their
+    own behind this one (``place_ahead``: placement a batch ahead, off
+    the loop's thread), predict places on its own thread as it
+    dispatches.
     """
     if gil_bound:
         if _host_cpus() <= 1:
@@ -2453,14 +2465,16 @@ def prefetch(iterator: Iterator[DeviceBatch], depth: int = 2,
         LEDGER.release("prefetch_batches")
 
 
-def place_ahead(batches: Iterator[DeviceBatch], place,
-                depth: int) -> Iterator[tuple]:
+def place_ahead(batches: Iterator[DeviceBatch], place, depth: int,
+                seconds: str) -> Iterator[tuple]:
     """The feed's last stage: ``(batch, placed)`` for every batch of
     ``batches``. ``place(batch) -> (batch, placed)`` (train.py
     ``StepLoop.feed_place``: wire encoding and host-to-device
-    placement) runs on a thread of its own, ``fm-place``, at most
-    ``depth`` batches ahead of the consumer, under the span
-    ``feed/place`` [``train/place_seconds``]: no leaf of the loop's
+    placement; a sweep's, models/fm.py ``make_score_placer``: the score
+    call's arguments) runs on a thread of its own, ``fm-place``, at
+    most ``depth`` batches ahead of the consumer, under the span
+    ``feed/place`` [``seconds``: ``train/place_seconds``, a sweep's
+    feed ``validation/place_seconds``]: no leaf of the loop's
     partition, since the loop's thread does not wait for it. A separate
     stage and not the emitting thread's work: emit and placement in
     series would be one thread's. What ``place`` raises is raised at
@@ -2468,11 +2482,12 @@ def place_ahead(batches: Iterator[DeviceBatch], place,
     the batches it had placed are let go with it. ``place`` None (the
     loop places for itself): every batch with ``placed`` None, on the
     consumer's thread."""
-    feed = _each_placed(batches, place)
+    feed = _each_placed(batches, place, seconds)
     return feed if place is None else _read_ahead(feed, depth, "fm-place")
 
 
-def _each_placed(batches: Iterator[DeviceBatch], place) -> Iterator[tuple]:
+def _each_placed(batches: Iterator[DeviceBatch], place,
+                 seconds: str) -> Iterator[tuple]:
     from fast_tffm_tpu.obs.trace import span
     try:
         for batch in batches:
@@ -2482,7 +2497,7 @@ def _each_placed(batches: Iterator[DeviceBatch], place) -> Iterator[tuple]:
             if place is None:
                 yield batch, None
                 continue
-            with span("feed/place", seconds="train/place_seconds"):
+            with span("feed/place", seconds=seconds):
                 item = place(batch)
             yield item
     finally:  # closed with the stage: the stages behind it stop too
@@ -2490,31 +2505,46 @@ def _each_placed(batches: Iterator[DeviceBatch], place) -> Iterator[tuple]:
 
 
 class EpochFeed:
-    """An epochs-mode job's training feed, opened once: ``(batch,
-    placed)`` for every batch of epochs ``epochs`` (a range) and, in
-    band, one ``EpochMark`` behind each epoch's last batch. Epoch e's
-    batches are, array for array and in order, those of
-    ``batch_iterator(cfg, files, epochs=1, seed=cfg.seed + e, ...)``;
-    what is an epoch's stays an epoch's (the emitter with its seed and
-    shuffle window, the scanner, the ``SpillStats``) and what is the
-    plane's is made once (the ``prefetch`` and ``fm-place`` threads
-    and, on the parallel fast path, the build ring with its workers'
-    C++ builders and the ``fm-scan`` thread: ``_ring_batches``; the
-    other routes open each epoch's ``_batch_iterator_impl`` on the
-    producing thread as the last one runs out). So epoch e + 1's first
-    batches are cut, built and placed while epoch e's last steps run,
-    as far ahead as the queues there are allow.
+    """A job's feed of one plane, opened once: ``(batch, placed)`` for
+    every batch of epochs ``epochs`` (a range; a validating job's
+    sweeps are the epochs of a feed of their own, as many as come) and,
+    in band, one ``EpochMark`` behind each epoch's last batch. What is
+    an epoch's stays an epoch's (the emitter with its seed and shuffle
+    window, the scanner, the ``SpillStats``) and what is the plane's is
+    made once (the ``prefetch`` and ``fm-place`` threads and, on the
+    parallel fast path, the build ring with its workers' C++ builders
+    and the ``fm-scan`` thread: ``_ring_batches``; the other routes
+    open each epoch's ``_batch_iterator_impl`` on the producing thread
+    as the last one runs out). So epoch e + 1's first batches are cut,
+    built and placed while epoch e's last steps run (a sweep's: while
+    the training interval runs), as far ahead as the queues there are
+    allow.
 
-    ``hold``: a barrier can change what the next epoch's batches are
-    (the caller's predicate). Nothing of epoch e + 1 is then cut until
-    the consumer has called ``release(e)``, and ``uniq_bucket()`` is
-    read after that. ``place``: ``place_ahead``'s. ``stats(e)``: the
+    What the plane IS, the session's to say. ``training`` (the
+    default): epoch e's batches are, array for array and in order,
+    those of ``batch_iterator(cfg, files, epochs=1, seed=cfg.seed + e,
+    ...)``. A sweep's plane (``training`` False): every epoch's are
+    those of ``batch_iterator(cfg, files, training=False, epochs=1,
+    ...)``, no shuffle and the files in their order, at most
+    ``max_batches`` of them where the session caps a sweep (the next
+    one starts at the files' start again), remapped through the
+    ``vocab.eval_view()`` taken once the epoch may be cut. ``counters``:
+    the prefix the plane's counts carry; ``place_seconds``: the counter
+    of its ``feed/place`` spans.
+
+    ``hold``: a barrier can change what the next epoch's batches are,
+    or the consumer wants the next epoch's builders out of the way of
+    its own work behind a mark (a sweep's drain): the caller's
+    predicate. Nothing of epoch e + 1 is then cut until the consumer
+    has called ``release(e)``, and ``uniq_bucket()`` is read after
+    that. ``place``: ``place_ahead``'s. ``stats(e)``: the
     ``SpillStats`` of an epoch whose mark has not been taken yet (a
-    loop that stops inside it). ``close()`` stops every thread of the
-    feed within ``_read_ahead``'s bound and lets go of what was placed.
-    Counts ``pipeline/epochs_fed_ahead``: barriers the loop came out of
-    (``release``) with the next epoch's first batch already out of the
-    builders."""
+    loop that stops inside it). ``marked``: the newest epoch whose mark
+    the consumer has taken. ``close()`` stops every thread of the feed
+    within ``_read_ahead``'s bound and lets go of what was placed.
+    Counts ``<counters>/epochs_fed_ahead``: barriers the loop came out
+    of (``release``) with the next epoch's first batch already out of
+    the builders."""
 
     def __init__(self, cfg: FmConfig, files: Sequence[str], epochs: range,
                  place, hold: bool, uniq_bucket,
@@ -2522,11 +2552,21 @@ class EpochFeed:
                  num_shards: int = 1, fixed_shape: bool = False,
                  raw_ids: bool = False,
                  bad_lines: Optional[BadLineTracker] = None, vocab=None,
-                 row_shards: Optional[RowShards] = None):
+                 row_shards: Optional[RowShards] = None,
+                 training: bool = True, counters: str = TRAIN_PLANE,
+                 place_seconds: str = "train/place_seconds",
+                 max_batches: Optional[int] = None):
         from fast_tffm_tpu.obs.telemetry import active
+        if max_batches and fixed_shape:
+            raise ValueError("a capped epoch counts groups as batches; "
+                             "under fixed shapes a spill re-cuts them")
         self._cfg, self._files, self._epochs = cfg, files, epochs
         self._hold, self._uniq_bucket = hold, uniq_bucket
-        self._vocab = vocab
+        self._training, self._counters = training, counters
+        self._max_batches = max_batches or None
+        # The map an epoch's batches are remapped through: a training
+        # epoch's the runtime itself, a sweep's the view _open takes.
+        self._vocab = self._view = vocab
         self._build_cfg = cfg if vocab is None else vocab.build_cfg(cfg)
         self._plane = dict(
             weight_files=weight_files, shard_index=shard_index,
@@ -2537,14 +2577,16 @@ class EpochFeed:
         self._closed = False
         self._released = epochs.start - 1   # barriers the loop is past
         self._first_out = epochs.start - 1  # newest epoch with a batch out
+        self.marked = epochs.start - 1
         self._stats: Dict[int, SpillStats] = {}
         self._tel = active()
+        self._fed_ahead = counters + "/epochs_fed_ahead"
         if self._tel is not None:
-            self._tel.count("pipeline/epochs_fed_ahead", 0)
+            self._tel.count(self._fed_ahead, 0)
         self._it = place_ahead(
             prefetch(self._host_batches(), depth=cfg.prefetch_depth,
                      gil_bound=gil_bound_iteration(cfg, weight_files)),
-            place, cfg.prefetch_depth)
+            place, cfg.prefetch_depth, place_seconds)
 
     # -- the consumer's side ---------------------------------------------
 
@@ -2554,6 +2596,7 @@ class EpochFeed:
     def __next__(self):
         item = next(self._it)
         if isinstance(item, EpochMark):
+            self.marked = item.epoch
             with self._cv:
                 self._stats.pop(item.epoch, None)
         return item
@@ -2561,13 +2604,18 @@ class EpochFeed:
     def release(self, epoch: int) -> None:
         """The loop is past ``epoch``'s barrier and about to ask for the
         next epoch's first batch: fed ahead, if that batch has left the
-        builders by now (never where the feed was held until this)."""
+        builders by now (never where the feed was held until this). A
+        sweep's barrier is the training interval behind it: its consumer
+        (train.py ``evaluate``) says ``release(marked)`` as the next
+        sweep starts, which is where this counts, and once before,
+        behind the sweep's drain, where no barrier changes the next
+        sweep's batches: the feed cuts them from then on."""
         with self._cv:
             fed_ahead = self._first_out > epoch
             self._released = epoch
             self._cv.notify_all()
         if fed_ahead and self._tel is not None:
-            self._tel.count("pipeline/epochs_fed_ahead")
+            self._tel.count(self._fed_ahead)
 
     def stats(self, epoch: int) -> SpillStats:
         with self._cv:
@@ -2585,6 +2633,9 @@ class EpochFeed:
         """The unique budget as the session has it now."""
         return self._uniq_bucket() or self._build_cfg.uniq_bucket
 
+    def _seed(self, epoch: int) -> Optional[int]:
+        return self._cfg.seed + epoch if self._training else None
+
     def _past_barrier(self, epoch: int) -> bool:
         """Wait until the loop is past ``epoch``'s barrier (at once
         where nothing holds the feed there); False where the feed was
@@ -2600,15 +2651,27 @@ class EpochFeed:
         was closed meanwhile."""
         if not self._past_barrier(epoch - 1):
             return None
+        if self._vocab is not None and not self._training:
+            # A telemetry-silent snapshot (a held-out sweep's unique
+            # tail is mostly unadmitted and would inflate the training
+            # stream's cold-hit rate), taken after the barrier that
+            # admits and evicts: a vocab's feed is held, so the last
+            # sweep's batches are all through the old one by now.
+            self._view = self._vocab.eval_view()
         with self._cv:
             stats = self._stats[epoch] = SpillStats()
         return stats
+
+    def _remap(self, batch):
+        return self._view.remap(batch)
 
     def _host_batches(self) -> Iterator:
         """The batches as the placement stage takes them, behind
         ``batch_iterator``'s seam. A held feed waits here too, outside
         the seam's build bracket."""
-        it = _seamed(self._stream(), self._cfg, self._vocab, TRAIN_PLANE)
+        it = _seamed(self._stream(), self._cfg,
+                     None if self._vocab is None else self._remap,
+                     self._counters)
         epoch, first = self._epochs.start, True
         try:
             for item in it:
@@ -2645,16 +2708,21 @@ class EpochFeed:
             stats = self._open(epoch)
             if stats is None:
                 return
-            yield from _batch_iterator_impl(
-                self._build_cfg, self._files, training=True, epochs=1,
-                seed=self._cfg.seed + epoch,
-                uniq_bucket=self._budget(), stats=stats, **self._plane)
+            it = _batch_iterator_impl(
+                self._build_cfg, self._files, training=self._training,
+                epochs=1, seed=self._seed(epoch),
+                uniq_bucket=self._budget(), stats=stats,
+                counters=self._counters, **self._plane)
+            try:
+                yield from itertools.islice(it, self._max_batches)
+            finally:  # a capped epoch's, or the feed's close: the
+                it.close()  # iterator's pool and files go with it
             yield EpochMark(epoch, stats)
 
     def _ring_stream(self, workers: int) -> Iterator:
         cfg, plane = self._build_cfg, self._plane
         files = expand_files(self._files)
-        B, shuffle = cfg.batch_size, cfg.shuffle
+        B, shuffle = cfg.batch_size, self._training and cfg.shuffle
         fixed_shape, row_shards = plane["fixed_shape"], plane["row_shards"]
         _refuse_raw_fixed(plane["raw_ids"], fixed_shape)
         retry = RetryPolicy.from_config(cfg)
@@ -2675,22 +2743,23 @@ class EpochFeed:
                 if stats is None:
                     return
                 uniq_bucket = self._budget()
-                seed = self._cfg.seed + epoch
+                seed = self._seed(epoch)
                 yield _Epoch(
                     epoch,
                     _BatchEmitter(cfg, B, effective_L_cap(cfg), fixed_shape,
                                   uniq_bucket, shuffle, seed, stats,
-                                  shards=row_shards),
+                                  shards=row_shards,
+                                  counters=self._counters),
                     _GroupScanner(epoch_file_order(files, shuffle, seed, 0),
                                   plane["shard_index"], plane["num_shards"],
                                   B, False, retry),
-                    make_builder(uniq_bucket))
+                    make_builder(uniq_bucket), self._max_batches)
 
         first_bucket = self._budget()
         return _ring_batches(
             epochs(), make_builder(first_bucket), workers,
             bool(fixed_shape and first_bucket), plane["num_shards"],
-            marks=True, hold=self._hold)
+            self._counters, marks=True, hold=self._hold)
 
 
 def _read_ahead(iterator: Iterator, depth: int, name: str) -> Iterator:

@@ -718,6 +718,30 @@ def make_batch_scorer(spec: ModelSpec, mesh=None, backend=None):
     return score
 
 
+def score_args(batch: DeviceBatch) -> Dict[str, np.ndarray]:
+    """What a score call takes of a batch: ``batch_args`` without the
+    labels and weights, which stay on the host for whoever reads the
+    scores."""
+    args = batch_args(batch)
+    del args["labels"], args["weights"]
+    return args
+
+
+def make_score_placer(mesh=None, backend=None):
+    """How a sweep's feed places a batch for ``make_batch_scorer``'s
+    call, on its own thread (data/pipeline.py ``place_ahead``):
+    ``place(batch) -> (batch, args)`` with ``score_args`` on the device
+    (``shard_batch`` on a mesh, whose scorer then finds them laid out).
+    None for a lookup backend: its gather is the host's, the call takes
+    host arrays."""
+    if backend is not None:
+        return None
+    if mesh is not None:
+        from fast_tffm_tpu.parallel.sharded import shard_batch
+        return lambda batch: (batch, shard_batch(mesh, **score_args(batch)))
+    return lambda batch: (batch, jax.device_put(score_args(batch)))
+
+
 def batch_args(batch: DeviceBatch) -> Dict[str, np.ndarray]:
     args = dict(labels=batch.labels, weights=batch.weights,
                 uniq_ids=batch.uniq_ids, local_idx=batch.local_idx,

@@ -28,15 +28,14 @@ from fast_tffm_tpu.config import FmConfig
 from fast_tffm_tpu.data.badlines import BadLineTracker
 from fast_tffm_tpu.data.pipeline import (SPILL_WARN_FRACTION,
                                          VALIDATION_PLANE, EpochFeed,
-                                         EpochMark, batch_iterator,
-                                         gil_bound_iteration,
-                                         host_parallel_workers, prefetch,
+                                         EpochMark, host_parallel_workers,
                                          uniq_bucket_top)
 from fast_tffm_tpu.utils.retry import RetryPolicy
 from fast_tffm_tpu.metrics import StreamingAUC
-from fast_tffm_tpu.models.fm import (ModelSpec, batch_args, init_accumulator,
+from fast_tffm_tpu.models.fm import (ModelSpec, init_accumulator,
                                      init_table, make_batch_scorer,
-                                     make_train_step, regime_line,
+                                     make_score_placer, make_train_step,
+                                     regime_line, score_args,
                                      ships_raw_batches)
 from fast_tffm_tpu.obs.memory import (LEDGER, local_bytes_in_use,
                                       oom_guard, preflight_capacity,
@@ -56,12 +55,39 @@ from fast_tffm_tpu.utils.timing import StepTimer
 SLOW_STEP_SECONDS = 1.0
 
 
+def sweep_feed(cfg: FmConfig, files, sweeps: range, mesh=None, backend=None,
+               max_batches: Optional[int] = None, weight_files=(),
+               bad_lines=None, vocab=None) -> EpochFeed:
+    """The feed ``evaluate()`` reads ``sweeps`` sweeps of ``files``
+    from: a sweep is an epoch of it, its batches
+    ``batch_iterator(training=False, epochs=1, counters=VALIDATION_PLANE)``'s
+    and at most ``max_batches`` of them, each placed for the scorer of
+    this dispatch path on the feed's own thread (``make_score_placer``).
+    Held at a sweep's mark until ``evaluate()`` lets it go: behind the
+    sweep's drain, so that the next sweep's builders run beside the
+    interval's steps and not beside the drain's fetch; where ``vocab``
+    is set only as the next sweep starts (its batches are the eval
+    view's, taken after the barriers in between), and as under the
+    training feed's hold the consumer places."""
+    spec = ModelSpec.from_config(cfg)
+    return EpochFeed(
+        cfg, files, sweeps,
+        place=(None if vocab is not None
+               else make_score_placer(mesh, backend)),
+        hold=True, uniq_bucket=lambda: 0,
+        weight_files=weight_files, bad_lines=bad_lines, vocab=vocab,
+        raw_ids=ships_raw_batches(spec, mesh=mesh, backend=backend),
+        training=False, counters=VALIDATION_PLANE,
+        place_seconds="validation/place_seconds", max_batches=max_batches)
+
+
 def evaluate(cfg: FmConfig, table: jax.Array, files,
              max_batches: Optional[int] = None,
              mesh=None, backend=None,
              weight_files=(), bad_lines=None,
              vocab=None, collect=None,
-             phases: bool = True) -> Tuple[float, int]:
+             phases: bool = True,
+             feed: Optional[EpochFeed] = None) -> Tuple[float, int]:
     """Streamed AUC over ``files``; returns (auc, n_examples). Pass the
     training mesh to score a row-sharded table in place, or a lookup
     ``backend`` (lookup.HostOffloadLookup) to score a host-offloaded
@@ -77,28 +103,32 @@ def evaluate(cfg: FmConfig, table: jax.Array, files,
     loop's zero-added-device-fetch seam. ``phases``: whether the
     sweep's spans count into the loop's partition; a caller that sweeps
     inside a leaf of its own (the stream loop's ``checkpoint/publish``)
-    says no, and the interval is counted once."""
+    says no, and the interval is counted once.
+
+    ``feed``: the ``sweep_feed`` of a job that sweeps again and again
+    (``_Session.validate``: made for these files, this dispatch path
+    and the session's cap, which ``max_batches`` and the arguments
+    behind it then only repeat). This sweep is its next epoch: the
+    first batches were built and placed while the interval trained,
+    and the sweep ends at the feed's mark. Without one the sweep reads
+    from a feed of ONE sweep, made here and closed here: the cold
+    plane, every call."""
     tel = active()
     # The sweep's wall on the calling thread, as leaves of the loop's
     # partition (obs/telemetry.py ANATOMY_PHASES), named as predict's
-    # are. The plane's threads, builders and files are made at its first
-    # next(): ``validation/first_batch`` holds them, as
-    # ``pipeline/first_batch`` does for a training epoch.
+    # are. ``validation/first_batch`` is the sweep's first next(): a
+    # feed's first makes the plane's threads, builders and files (as
+    # ``pipeline/first_batch`` does for a job's first epoch), every
+    # later sweep of that feed takes a batch that is already placed.
     step = tel.step if tel is not None else -1  # the table's: the last step
 
     def counter(phase):
         return f"validation/{phase}_seconds" if phases else None
 
+    own = feed is None
     with span("validation/open", seconds=counter("open"), step=step):
         spec = ModelSpec.from_config(cfg)
         score_fn = make_batch_scorer(spec, mesh=mesh, backend=backend)
-        raw = ships_raw_batches(spec, mesh=mesh, backend=backend)
-        if vocab is not None:
-            # Telemetry-silent snapshot: a held-out sweep's unique tail
-            # is disproportionately unadmitted and would otherwise
-            # inflate the training stream's cold-hit rate (the COLD-ROW
-            # SATURATION verdict's input).
-            vocab = vocab.eval_view()
         auc = StreamingAUC()
 
         def _consume(scores, m):
@@ -112,16 +142,15 @@ def evaluate(cfg: FmConfig, table: jax.Array, files,
         fetcher = ChunkedFetcher(
             _consume,
             overlap=True)  # D2H of chunk N overlaps scoring of chunk N+1
-        # The plane counts under names of its own (``validation_plane/``):
-        # ``pipeline/*`` stays the training plane's.
-        it = prefetch(batch_iterator(cfg, files, training=False,
-                                     weight_files=weight_files,
-                                     epochs=1, raw_ids=raw,
-                                     bad_lines=bad_lines,
-                                     vocab=vocab,
-                                     counters=VALIDATION_PLANE),
-                      depth=cfg.prefetch_depth,
-                      gil_bound=gil_bound_iteration(cfg, weight_files))
+        if own:
+            feed = sweep_feed(cfg, files, range(1), mesh=mesh,
+                              backend=backend, max_batches=max_batches,
+                              weight_files=weight_files,
+                              bad_lines=bad_lines, vocab=vocab)
+        # The interval behind the feed's last sweep is over: counted as
+        # fed ahead if this sweep's first batch has left the builders;
+        # a vocab's feed (the eval view of now) starts cutting here.
+        feed.release(feed.marked)
     n = 0
     n_batches = 0
     # try/finally (ADVICE round 5): an exception mid-sweep must not
@@ -133,31 +162,35 @@ def evaluate(cfg: FmConfig, table: jax.Array, files,
             wait = "input_wait" if n_batches else "first_batch"
             with span("validation/" + wait, seconds=counter(wait),
                       step=step):
-                batch = next(it, None)
-            if batch is None:
+                item = next(feed)
+            if isinstance(item, EpochMark):
                 break
             with span("validation/score_dispatch",
                       seconds=counter("score_dispatch"), step=step):
-                args = batch_args(batch)
-                args.pop("labels"), args.pop("weights")
+                batch, args = item
+                if args is None:  # a lookup backend's, a held feed's
+                    args = score_args(batch)
                 fetcher.add(score_fn(table, args),
                             (batch.labels, batch.num_real, batch.weights))
+                # the placed arrays are let go under this leaf, not at
+                # the next batch's unpacking under none
+                item = args = None
                 n += batch.num_real
                 n_batches += 1
                 if tel is not None:
                     # A full validation sweep can outlast the watchdog's
                     # stall budget; scored batches are progress.
                     tel.heartbeat()
-            # Batch-count cap — the same per-input-shard unit the
-            # distributed path uses, so AUC samples are comparable.
-            if max_batches and n_batches >= max_batches:
-                break
         # The tail after the last dispatch: the chip finishes, the last
         # chunk's D2H and its two histogram updates a batch.
         with span("validation/drain", seconds=counter("drain"), step=step):
             fetcher.flush()
+        if vocab is None:  # no barrier changes the next sweep's batches:
+            feed.release(feed.marked)  # cut them while the interval trains
     finally:
         fetcher.close()
+        if own:
+            feed.close()
     with span("validation/auc", seconds=counter("auc"), step=step):
         result = auc.result()
     if tel is not None:
@@ -555,6 +588,8 @@ class _Session:
         # ... and by _arm_publish_gate.
         self.gate = None
         self.quality_on = False
+        # The feed of the epochs' sweeps: made at the first (validate).
+        self.sweeps: Optional[EpochFeed] = None
         self.spec = ModelSpec.from_config(cfg)
         logger.info("train regime: %s", regime_line(self.spec, cfg))
         self.multi_process = jax.process_count() > 1
@@ -633,7 +668,8 @@ class _Session:
             except ValueError:  # not the main thread (e.g. under a test)
                 pass
 
-    def validate(self, table, collect=None, preempt=None, phases=True):
+    def validate(self, table, collect=None, preempt=None, phases=True,
+                 of_epoch: bool = False):
         """One validation sweep of ``table`` on this session's dispatch
         path; returns ``(auc, n_examples)``. ``phases``: whether the
         sweep counts as leaves of the loop's partition (``evaluate``);
@@ -641,7 +677,11 @@ class _Session:
         lockstep window allgather of a multi-process sweep: a SIGTERM
         mid-sweep stops EVERY worker at the same window boundary (the
         signalled worker alone bailing would desync the collective
-        program stream)."""
+        program stream). ``of_epoch``: one of the sweeps an epochs-mode
+        job makes a barrier (``_epoch_barrier``): they read from ONE
+        feed, made inside the first and closed by ``close_sweeps``
+        where the training feed is (``_run_epochs``); any other sweep
+        makes and closes its own (``evaluate``)."""
         cfg = self.cfg
         vmb = cfg.validation_max_batches or None
         if self.multi_process:
@@ -658,12 +698,25 @@ class _Session:
                     weight_files=cfg.validation_weight_files,
                     bad_lines=self.bad_tracker, collect=collect,
                     preempt=preempt)
-        return evaluate(
-            cfg, table, cfg.validation_files, mesh=self.mesh,
-            backend=self.lk, max_batches=vmb,
-            weight_files=cfg.validation_weight_files,
-            bad_lines=self.bad_tracker, vocab=self.vocab,
-            collect=collect, phases=phases)
+        sweep = dict(mesh=self.mesh, backend=self.lk, max_batches=vmb,
+                     weight_files=cfg.validation_weight_files,
+                     bad_lines=self.bad_tracker, vocab=self.vocab)
+        if of_epoch and self.sweeps is None:
+            # a sweep behind every epoch still to run: the last has no
+            # next, and nothing is built for one
+            self.sweeps = sweep_feed(cfg, cfg.validation_files,
+                                     range(self.start_epoch, cfg.epoch_num),
+                                     **sweep)
+        return evaluate(cfg, table, cfg.validation_files, collect=collect,
+                        phases=phases,
+                        feed=self.sweeps if of_epoch else None, **sweep)
+
+    def close_sweeps(self) -> None:
+        """Stop the sweeps' feed, if one was made: its threads end and
+        what it had placed ahead is let go."""
+        feed, self.sweeps = self.sweeps, None
+        if feed is not None:
+            feed.close()
 
 
 def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
@@ -1686,9 +1739,12 @@ def _run_epochs(s: _Session, loop: StepLoop) -> None:
             _epoch_barrier(s, loop, epoch, mark.stats if mark is not None
                            else feed.stats(epoch))
     finally:  # a step that raised, a preemption, the job's end: the
-        loop.placed = None  # feed's threads stop, what they placed is let go
-        if feed is not None:
-            feed.close()
+        loop.placed = None  # feeds' threads stop, what they placed is let go
+        try:
+            if feed is not None:
+                feed.close()
+        finally:
+            s.close_sweeps()
 
 
 def _epoch_barrier(s: _Session, loop: StepLoop, epoch: int,
@@ -1763,7 +1819,8 @@ def _epoch_barrier(s: _Session, loop: StepLoop, epoch: int,
             # A preempted sweep stops on every worker together; the step
             # loop then drains the flag and all workers save together.
             auc, n = s.validate(loop.table,
-                                preempt=lambda: bool(s.preempted))
+                                preempt=lambda: bool(s.preempted),
+                                of_epoch=True)
         # what this barrier's slow_step leaves out (StepLoop.end_barrier)
         loop.barrier_sweep = sweep.dur if tel is not None else 0.0
         loop.last_val = (auc, n)

@@ -60,7 +60,8 @@ def _loop_places(monkeypatch):
     what a session that must place for itself gets."""
     real = pipeline.place_ahead
     monkeypatch.setattr(pipeline, "place_ahead",
-                        lambda it, place, depth: real(it, None, depth))
+                        lambda it, place, depth, *a: real(it, None, depth,
+                                                          *a))
 
 
 def _run(cfg, monkeypatch):
@@ -175,7 +176,7 @@ def test_the_loop_places_where_the_session_says_it_must(
             finally:
                 s.multi_process = False     # the teardown's is one's
 
-        def spy(it, place, depth):
+        def spy(it, place, depth, *a):
             handed.append(place)
             it.close()
             raise _Decided()

@@ -1,8 +1,9 @@
 """A validation sweep inside ``train()`` (ISSUE 44): its wall on the
 loop's thread is a partition of six leaves under the enclosure
 ``train/validation``, it counts its sweeps, batches and examples, its
-data plane counts under names of its own, and a barrier that holds a
-sweep is no slow step for that."""
+data plane counts under names of its own, a barrier that holds a
+sweep is no slow step for that, and the placement of its batches
+(ISSUE 51) is the feed's thread's and no leaf."""
 
 import time
 
@@ -103,6 +104,26 @@ def test_the_leaves_partition_the_sweeps_wall(swept):
             sum(s["dur"] for s in leaves if s["name"] == name), rel=1e-9)
     # and the loop's residue is still a residue
     assert 0 <= c[LOOP_UNNAMED] < c["train/loop_seconds"]
+
+
+def test_the_sweeps_placement_is_off_the_loop_and_on_no_leaf_list(swept):
+    """Since the sweeps read from a feed of the job's (ISSUE 51),
+    ``validation/score_dispatch`` holds the call alone: a held-out
+    batch is placed under ``feed/place`` [``validation/place_seconds``]
+    on the feed's own thread, once a batch, and neither the span nor
+    its counter is a leaf's (tests/test_sweep_feed.py has the rest)."""
+    (tid,) = {s["tid"] for s in _spans(swept, "train/validation")}
+    place = _spans(swept, "feed/place")
+    assert {s["tid"] for s in place} == {"fm-place"} != {tid}
+    c = _last_counters(swept)
+    assert len(place) == c["train/steps"] + c["validation/batches"]
+    assert c["validation/place_seconds"] > 0
+    assert c["validation/place_seconds"] + c["train/place_seconds"] == (
+        pytest.approx(sum(s["dur"] for s in place), rel=1e-9))
+    by_span = {name for p in ANATOMY_PHASES.values() for name in p.spans}
+    assert "feed/place" not in by_span
+    assert "validation/place_seconds" not in LOOP_LEAVES
+    assert c["validation_plane/epochs_fed_ahead"] == EPOCHS - 1
 
 
 def test_no_residue_over_one_percent_of_a_sweep(tmp_path, monkeypatch):
