@@ -23,6 +23,7 @@ manifest existed carry nothing to verify against and stay restorable.
 
 from __future__ import annotations
 
+import functools
 import json
 import math
 import os
@@ -46,6 +47,14 @@ from fast_tffm_tpu.utils.retry import RetryPolicy, retry_io
 # "full" additionally re-hashes every byte (catches silent bit rot; a
 # full pass over a config-#5 checkpoint reads the whole state once).
 CKPT_VERIFY_MODES = ("off", "size", "full")
+
+# What only a periodic save feeds, counted from 0 by a job that has
+# ``save_steps`` (train.py): a reader that differences two snapshots of
+# the stream finds "none yet" as 0, not as absent.
+SAVE_COUNTERS = ("checkpoint/saves", "checkpoint/save_seconds",
+                 "checkpoint/settle_seconds", "checkpoint/snapshot_seconds",
+                 "checkpoint/snapshot_bytes",
+                 "train/checkpoint_pause_seconds")
 
 # Quarantined step dirs: ``corrupt-<step>`` (+ ``.k`` suffixes when a
 # step is quarantined more than once). Never auto-deleted — operators
@@ -546,6 +555,16 @@ def _tel():
     return active()
 
 
+def _count_restored(restored):
+    """``checkpoint/restore_bytes``: the arrays a restore brought in."""
+    tel = _tel()
+    if tel is not None and restored is not None:
+        tel.count("checkpoint/restore_bytes", sum(
+            int(getattr(restored.get(k), "nbytes", 0))
+            for k in ("table", "acc")))
+    return restored
+
+
 def align_orbax_barrier_counters() -> None:
     """Re-zero orbax's cross-process barrier counters — the broadcast-
     to-newcomer seam elastic GROW needs.
@@ -618,6 +637,10 @@ class CheckpointState:
         # a committed step is a full sequential re-read — at real table
         # scale that must overlap the train loop, not block it.
         self._manifest_thread: Optional[threading.Thread] = None
+        # (step, wall clock at dispatch, bytes) of the write in flight:
+        # counted into checkpoint/commit_seconds where its commit is
+        # first known (_note_commit).
+        self._dispatched: Optional[Tuple[int, float, int]] = None
         os.makedirs(self.directory, exist_ok=True)
         multi_process = jax.process_count() > 1
         if multi_process:
@@ -652,21 +675,19 @@ class CheckpointState:
         (orbax's own back-pressure), bounding in-flight state to one
         snapshot. ``wait=True`` — the final/preemption save — blocks
         until the bytes are durably committed before returning."""
-        # Timeline span (obs/trace; no-op without an active
-        # tracing run): checkpoint pauses are a classic silent
-        # stall — the span shows the snapshot cost, `wait=True`
-        # saves show the full write.
-        with span("checkpoint/save", step=int(step), wait=wait):
-            # Settle the PREVIOUS async save's manifest before
-            # dispatching a new one: orbax back-pressures a new save on
-            # the in-flight write anyway, so the explicit wait here
-            # costs nothing extra and guarantees the manifest describes
-            # a finalized step dir. The hash itself runs on a
-            # background thread — it's a full re-read of the step dir,
-            # which must overlap the next save interval, not stall it.
+        # Timeline spans (obs/trace; no-op without an active tracing
+        # run): checkpoint pauses are a classic silent stall. Inside
+        # checkpoint/save, checkpoint/settle is the wait for the write
+        # before this one and checkpoint/snapshot the part the loop
+        # waits for of this one; `wait=True` saves show the full write.
+        with span("checkpoint/save", seconds="checkpoint/save_seconds",
+                  step=int(step), wait=wait):
+            # Callers that snapshot the state themselves (ckpt_state's
+            # host copy of a one-device state) settle BEFORE they do,
+            # so that one snapshot is in flight at a time; anyone else
+            # settles here.
             if self._pending_manifest is not None:
-                self._mngr.wait_until_finished()
-                self._flush_pending_manifest(background=True)
+                self.settle()
             # Plain python ints for the scalar leaves: orbax's
             # StandardSave supported types are (int, float, np.ndarray,
             # jax.Array) — numpy SCALARS (np.int64) are rejected outright
@@ -683,8 +704,17 @@ class CheckpointState:
                 # save whose first attempt half-created the step dir
                 # would surface as the benign StepAlreadyExists path
                 # below and silently skip the save.
-                self._mngr.save(step, args=ocp.args.StandardSave(payload),
-                                force=force)
+                nbytes = int(table.nbytes) + int(acc.nbytes)
+                # From the call into orbax until the loop may go on: a
+                # device array's copy to the host; for a host snapshot
+                # (ckpt_state made the copy, under the same name) the
+                # hand-over alone.
+                with span("checkpoint/snapshot",
+                          seconds="checkpoint/snapshot_seconds"):
+                    self._mngr.save(step,
+                                    args=ocp.args.StandardSave(payload),
+                                    force=force)
+                self._dispatched = (int(step), time.time(), nbytes)
                 self._pending_manifest = (int(step), int(epoch),
                                           int(vocabulary_size))
                 # A FRESH save at this step carries authoritative metadata:
@@ -702,6 +732,7 @@ class CheckpointState:
                     tel = _tel()
                     if tel is not None:
                         tel.count("checkpoint/saves")
+                        tel.count("checkpoint/snapshot_bytes", nbytes)
             except ocp.checkpoint_manager.StepAlreadyExistsError:
                 # The final/preemption save can land on the same step as the
                 # last periodic save (save_steps divides the step count).
@@ -748,12 +779,43 @@ class CheckpointState:
                 write_vocab_sidecar(self.directory, int(step),
                                     vocab_state)
             if wait:
+                self.wait_until_finished()
+
+    def settle(self) -> None:
+        """Wait for the write before the save about to be made, and
+        start its owed manifest. The hash runs on a background thread:
+        it re-reads the whole step dir, which must overlap the next
+        save interval, not stall it. orbax back-pressures a new save on
+        the in-flight write anyway, so the wait costs nothing extra and
+        guarantees the manifest describes a finalized step dir."""
+        with span("checkpoint/settle", seconds="checkpoint/settle_seconds"):
+            if self._pending_manifest is not None:
                 self._mngr.wait_until_finished()
-                self._flush_pending_manifest()
+                self._note_commit()
+                self._flush_pending_manifest(background=True)
 
     def wait_until_finished(self) -> None:
         self._mngr.wait_until_finished()
+        self._note_commit()
         self._flush_pending_manifest()
+
+    def _note_commit(self) -> None:
+        """Count the write in flight as committed, the first time its
+        commit is known (call after the manager's wait): dispatch to
+        finalized, by the mtime orbax's last write left on the step dir
+        (a periodic save's commit is first KNOWN at the next save, an
+        interval later)."""
+        sent, self._dispatched = self._dispatched, None
+        tel = _tel()
+        if sent is None or tel is None or jax.process_index() != 0:
+            return
+        step, t_sent, nbytes = sent
+        try:
+            done = os.stat(os.path.join(self.directory, str(step))).st_mtime
+        except OSError:
+            done = time.time()
+        tel.count("checkpoint/commit_seconds", max(done - t_sent, 0.0))
+        tel.count("checkpoint/committed_bytes", nbytes)
 
     def _flush_pending_manifest(self, background: bool = False) -> None:
         """Write the manifest for the last committed save. Call only
@@ -1092,7 +1154,8 @@ class CheckpointState:
         PyTree format; partial restore is a PyTreeRestore feature).
         Latest-step selection goes through the same verify + quarantine
         + broadcast decision as restore()."""
-        with span("checkpoint/restore", partial=True):
+        with span("checkpoint/restore",
+                  seconds="checkpoint/restore_seconds", partial=True):
             self.wait_until_finished()
             s = step
             if s is None:
@@ -1114,7 +1177,8 @@ class CheckpointState:
                         policy=self._retry, op="checkpoint_restore"))
                 if err is not None:
                     raise err
-                return self._apply_epoch_override(s, restored)
+                return _count_restored(
+                    self._apply_epoch_override(s, restored))
             finally:
                 reader.close()
 
@@ -1136,7 +1200,8 @@ class CheckpointState:
         deleted) while restore walks back to the next older step. An
         EXPLICIT step is verified but never quarantined or walked past:
         the caller asked for those exact bytes."""
-        with span("checkpoint/restore"):
+        with span("checkpoint/restore",
+                  seconds="checkpoint/restore_seconds"):
             self.wait_until_finished()  # in-flight async save first
             if step is not None:
                 reason = self.verify_step(step)
@@ -1150,9 +1215,10 @@ class CheckpointState:
                 restored, err = self._attempt_restore(step, template)
                 if err is not None:
                     self._raise_restore_error(step, err)
-                return self._attach_vocab(step, self._attach_stream(
-                    step, self._apply_epoch_override(step, restored)))
-            return self._restore_newest_intact(template)
+                return _count_restored(self._attach_vocab(
+                    step, self._attach_stream(
+                        step, self._apply_epoch_override(step, restored))))
+            return _count_restored(self._restore_newest_intact(template))
 
     def _restore_newest_intact(self, template
                                ) -> Optional[Dict[str, Any]]:
@@ -1245,7 +1311,14 @@ class CheckpointState:
                                 policy=self._retry,
                                 op="checkpoint_restore"), None
             multi_process = jax.process_count() > 1
-            if multi_process:
+            to_host = any(isinstance(v, jax.ShapeDtypeStruct)
+                          and v.sharding is None for v in template.values())
+            if multi_process or to_host:
+                # A template whose leaves name no sharding asks for
+                # HOST arrays (the offload backend's, a one-device
+                # job's): the reader is told so leaf by leaf, because
+                # orbax otherwise hands a step a MESH saved back as
+                # device arrays under the saved sharding.
                 # Multi-process restores stage through HOST RAM: orbax's
                 # direct-to-device deserialization in the multi-process
                 # restore-then-step shape hits a known jaxlib defect
@@ -1345,27 +1418,161 @@ class CheckpointState:
         before releasing the manager — close is the last point a
         crashed-out driver can make the newest step verifiable."""
         try:
-            self._mngr.wait_until_finished()
-            self._flush_pending_manifest()
+            self.wait_until_finished()
         finally:
             self._mngr.close()
 
 
-def ckpt_state(cfg, table: jax.Array, acc: jax.Array):
+# Rows a block of a one-device state's snapshot and of its placement
+# after a restore; one block is on its way while the one before it
+# lands. A block of 2^16 rows of a k=16 table is 6.3 MB on the device (a
+# row of 17 floats tiles to 24) and 4.5 MB flat: two alive raise the
+# v5e's peak 20 MB over the resident state, under the 27.6 MB of the
+# train step's own temporaries at fm-k16-criteo1tb's size, so a save or
+# a resume leaves the device's peak where training put it (a second
+# block on its way: 25 MB and 5.1 GB/s where 3.7; 2^17 rows: 43 MB).
+# PERF.md, PR 52.
+STATE_BLOCK_ROWS = 1 << 16
+
+
+class HostSnapshot(np.ndarray):
+    """A host array that a save owns from the moment it is made: nothing
+    writes to it again, so orbax's defensive ``copy.deepcopy`` of a
+    NumPy leaf (type_handlers.NumpyHandler.serialize, on the caller's
+    thread) would only double the pause and the host's memory."""
+
+    def __deepcopy__(self, memo):
+        return self
+
+
+def _block_starts(rows: int, block: int):
+    """Starts of ``block``-row blocks that cover ``rows`` rows; the last
+    is moved back to end on the last row (it overlaps the one before),
+    so that one program serves every block."""
+    return [min(a, rows - block) for a in range(0, rows, block)]
+
+
+def _rows_to_host(arr: jax.Array, out: np.ndarray) -> None:
+    """``out[:] = arr`` in row blocks: the next block's slice and copy
+    to the host are under way while this one lands in ``out``."""
+    rows = int(arr.shape[0])
+    block = min(STATE_BLOCK_ROWS, rows)
+    landing = None
+    for a in _block_starts(rows, block) + [None]:
+        ahead = None
+        if a is not None:
+            ahead = (a, _take_rows(arr, a, block=block))
+            ahead[1].copy_to_host_async()
+        if landing is not None:
+            at, blk = landing
+            out[at:at + block] = np.asarray(blk).reshape(block, -1)
+        landing = ahead
+
+
+@functools.partial(jax.jit, static_argnames="block")
+def _take_rows(arr, start, *, block: int):
+    """``arr[start:start + block]`` flattened: one row after another is
+    what the copy to the host moves at the link's pace, whatever the
+    tiling of a narrow table on the device."""
+    return jax.lax.dynamic_slice_in_dim(arr, start, block).reshape(-1)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _put_rows(dst, blk, start):
+    return jax.lax.dynamic_update_slice_in_dim(dst, blk, start, 0)
+
+
+def snapshot_buffers(cfg):
+    """The host side of a one-device state's save: a [ckpt_rows, D]
+    pair whose dead tail is the contract's (zero for the table,
+    adagrad_init for the accumulator); the rows before it are the
+    snapshot's to fill."""
+    pair = []
+    for tail in (0.0, cfg.adagrad_init):
+        host = np.empty((cfg.ckpt_rows, cfg.row_dim),
+                        np.float32).view(HostSnapshot)
+        host[cfg.num_rows:] = tail
+        pair.append(host)
+    return tuple(pair)
+
+
+def saver_buffers(cfg, table: jax.Array, acc: jax.Array):
+    """What a one-device job that saves periodically makes at its
+    start and keeps for its life: the host pair every save is taken
+    into, filled once by a snapshot of the state it starts from. On the
+    v5e's host a fresh page costs more than the copy that fills it (4.6
+    s of faults a 4.6 GB array where the copy takes 1.2), and a
+    process's first pass over the copy's path 3.3 s more than a later
+    one (PERF.md, PR 52): paid here, a save's pause is the copy alone
+    and compiles nothing. A save sees a train step's results, which are
+    committed to their device, and jax keeps a program apart for a
+    fresh array that is not: the same buffers go in under a committed
+    label (no copy is made). The write before a save is settled before
+    the save overwrites the pair."""
+    pair = snapshot_buffers(cfg)
+    for arr, host in zip((table, acc), pair):
+        _rows_to_host(jax.device_put(arr, arr.sharding),
+                      host[:cfg.num_rows])
+    return pair
+
+
+def ckpt_state(cfg, table: jax.Array, acc: jax.Array, into=None):
     """Checkpoint contract: always store [ckpt_rows, D] — the fixed
     4096-aligned row layout (FmConfig.ckpt_rows) every topology shares,
     so a checkpoint saved by any mesh restores row-sharded on any other
     without assembling the table on one host. Mesh tables are already
     this shape (orbax saves them sharded — each host writes only its
-    rows); single-device tables get the dead pad tail appended."""
-    n_pad = cfg.ckpt_rows - int(table.shape[0])
-    if n_pad == 0:
+    rows, and snapshots them itself). A one-device state is
+    [num_rows, D]: its snapshot is taken HERE, in row blocks into the
+    host pair ``into`` (``snapshot_buffers``; a pair of this save's own
+    where none is given), so that the device holds no second table
+    while it is saved (a padded copy on the device was 6.4 GB beside a
+    resident 12.9 at fm-k16-criteo1tb's size on a 16 GB chip). What
+    comes back is the state after the steps dispatched so far, whole:
+    the caller's next step may donate ``table`` and ``acc``. Whoever
+    passes ``into`` has settled the save that last used it
+    (``CheckpointState.settle``)."""
+    n = int(table.shape[0])
+    if n == cfg.ckpt_rows:
         return table, acc
-    import jax.numpy as jnp
-    pad_t = jnp.zeros((n_pad, cfg.row_dim), jnp.float32)
-    pad_a = jnp.full((n_pad, cfg.row_dim), cfg.adagrad_init, jnp.float32)
-    return (jnp.concatenate([table, pad_t], axis=0),
-            jnp.concatenate([acc, pad_a], axis=0))
+    with span("checkpoint/snapshot", seconds="checkpoint/snapshot_seconds"):
+        if into is None:
+            into = snapshot_buffers(cfg)
+        # The step that makes the state has run before a block is cut:
+        # its temporaries are gone when the blocks' buffers are made.
+        jax.block_until_ready((table, acc))
+        for arr, host in zip((table, acc), into):
+            _rows_to_host(arr, host[:n])
+    return into
+
+
+def place_restored(restored: Dict[str, Any], rows: int):
+    """``(table, acc)`` on the default device from a restore that
+    landed on the host (``checkpoint_template(host=True)``): the first
+    ``rows`` rows of each, placed in blocks, so that the device never
+    holds the [ckpt_rows, D] pair beside the state (a second table,
+    which does not fit at fm-k16-criteo1tb's size). Each host copy is
+    let go as soon as it is placed."""
+    out = []
+    with span("checkpoint/place", seconds="checkpoint/place_seconds"):
+        for name in ("table", "acc"):
+            out.append(device_rows(restored[name], rows))
+            restored[name] = None
+    return tuple(out)
+
+
+def device_rows(host: np.ndarray, rows: int) -> jax.Array:
+    """The first ``rows`` rows of a restored host array as a new array
+    on the default device, placed in row blocks into a donated buffer:
+    the device never holds more than the array and two blocks (a
+    [ckpt_rows, D] restore sliced on the device was a second table)."""
+    block = min(STATE_BLOCK_ROWS, rows)
+    dst = jax.numpy.zeros((rows,) + host.shape[1:], host.dtype)
+    for i, a in enumerate(_block_starts(rows, block)):
+        dst = _put_rows(dst, host[a:a + block], a)
+        if i % 2:       # the host runs no further ahead than two blocks
+            dst.block_until_ready()
+    return dst
 
 
 def checkpoint_template(cfg, mesh=None, host: bool = False):

@@ -220,6 +220,23 @@ def attribution(summary: Dict[str, Any]) -> Dict[str, Any]:
         # fallback"): saves committed, restores that fell back past a
         # bad step, and step dirs quarantined (corrupt-<step>).
         "checkpoint_saves": c.get("checkpoint/saves", 0),
+        # What a save costs (README "What a save costs"): the loop's
+        # pause a periodic save, its parts (the wait for the write
+        # before it; the snapshot the loop waits for, and its pace),
+        # and the background write from dispatch to finalized.
+        "checkpoint_pause_s_per_save": _frac(
+            c.get("train/checkpoint_pause_seconds"),
+            c.get("checkpoint/saves")),
+        "checkpoint_settle_s_per_save": _frac(
+            c.get("checkpoint/settle_seconds"), c.get("checkpoint/saves")),
+        "checkpoint_snapshot_s_per_save": _frac(
+            c.get("checkpoint/snapshot_seconds"),
+            c.get("checkpoint/saves")),
+        "checkpoint_snapshot_bytes_per_sec": _frac(
+            c.get("checkpoint/snapshot_bytes"),
+            c.get("checkpoint/snapshot_seconds")),
+        "checkpoint_commit_s_per_save": _frac(
+            c.get("checkpoint/commit_seconds"), c.get("checkpoint/saves")),
         "checkpoint_fallbacks": c.get("checkpoint/fallbacks", 0),
         "checkpoint_quarantined": c.get("checkpoint/quarantined_steps",
                                         0),
@@ -1011,6 +1028,13 @@ def render(summary: Dict[str, Any]) -> str:
         ("bad lines skipped", att["bad_lines"]),
         ("io retries", att["io_retries"]),
         ("checkpoint saves", att["checkpoint_saves"]),
+        ("ckpt pause / settle / snapshot (s/save)",
+         f"{_fmt(att['checkpoint_pause_s_per_save'])} / "
+         f"{_fmt(att['checkpoint_settle_s_per_save'])} / "
+         f"{_fmt(att['checkpoint_snapshot_s_per_save'])}"),
+        ("ckpt snapshot bytes/sec, commit (s/save)",
+         f"{_fmt(att['checkpoint_snapshot_bytes_per_sec'])}, "
+         f"{_fmt(att['checkpoint_commit_s_per_save'])}"),
         ("ckpt fallbacks / quarantined steps",
          f"{_fmt(att['checkpoint_fallbacks'])} / "
          f"{_fmt(att['checkpoint_quarantined'])}"),

@@ -410,7 +410,15 @@ def plan(cfg, kind: str = "train",
     ``kind="train"``: table + accumulator + wire double-buffers (+
     prefetch window). With ``lookup = host`` the table/accumulator
     move to the host-owner list — they are exactly what the offload
-    mode keeps OUT of device memory. ``kind="serve"``: the resident
+    mode keeps OUT of device memory. A save and a resume add nothing
+    to a device's plan: a mesh's shards are snapshotted by orbax, a
+    one-device state goes to the host and comes back in row blocks
+    (checkpoint.ckpt_state, device_rows). What they cost is HOST
+    memory: with ``save_steps`` set a one-device job keeps
+    ``ckpt_snapshot``, the [ckpt_rows, D] float32 pair its saves are
+    taken into (9.13 GB at fm-k16-criteo1tb's size), for its life; an
+    exit save alone, and a resume's restored pair, hold as much for
+    as long as they take. ``kind="serve"``: the resident
     table plus the old+new reload transient headroom a hot reload
     needs (serve/server._load_step holds both until the swap)."""
     o = dict(overrides or {})
@@ -450,6 +458,10 @@ def plan(cfg, kind: str = "train",
         else:
             owners["table"] = per_shard_tbl
             owners["adagrad_acc"] = per_shard_acc
+            if shards == 1 and getattr(cfg, "save_steps", 0):
+                from fast_tffm_tpu.config import mesh_rows
+                host_owners["ckpt_snapshot"] = 2 * table_bytes(
+                    rows=mesh_rows(rows), dim=dim)
         owners["wire_buffers"] = wire
     total = sum(owners.values())
     cap = device_capacity_bytes()

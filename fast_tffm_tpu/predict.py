@@ -26,7 +26,7 @@ import numpy as np
 
 from fast_tffm_tpu.checkpoint import (CheckpointState,
                                       check_restored_vocab,
-                                      checkpoint_template)
+                                      checkpoint_template, device_rows)
 from fast_tffm_tpu.config import FmConfig
 from fast_tffm_tpu.data.pipeline import expand_files
 from fast_tffm_tpu.metrics import sigmoid
@@ -54,13 +54,13 @@ def load_table(cfg: FmConfig, mesh=None,
     ``with_step=True`` returns ``(table, step)`` — callers that must
     pair the table with its step's sidecars (the admit-mode vocab slot
     map) need to know which step the walk-back actually restored."""
-    import jax.numpy as jnp
     from fast_tffm_tpu.utils.retry import RetryPolicy
     ckpt = CheckpointState(cfg.model_file,
                            retry=RetryPolicy.from_config(cfg),
                            verify=getattr(cfg, "ckpt_verify", "size"))
-    restored = ckpt.restore(step=step,
-                            template=checkpoint_template(cfg, mesh))
+    restored = ckpt.restore(
+        step=step, template=checkpoint_template(cfg, mesh,
+                                                host=mesh is None))
     ckpt.close()
     if restored is None:
         raise FileNotFoundError(
@@ -72,9 +72,9 @@ def load_table(cfg: FmConfig, mesh=None,
         table = restored["table"]
     else:
         # Checkpoints store the 4096-aligned [ckpt_rows, D] layout;
-        # the single-device scorer wants the logical table.
-        table = jnp.asarray(restored["table"][:cfg.num_rows],
-                            dtype=jnp.float32)
+        # the single-device scorer wants the logical table. The
+        # restore landed on the host: only those rows reach the device.
+        table = device_rows(restored["table"], cfg.num_rows)
     return (table, loaded_step) if with_step else table
 
 

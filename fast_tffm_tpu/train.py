@@ -20,10 +20,11 @@ from typing import Optional, Tuple
 import jax
 import numpy as np
 
-from fast_tffm_tpu.checkpoint import (CheckpointState,
+from fast_tffm_tpu.checkpoint import (SAVE_COUNTERS, CheckpointState,
                                       check_restored_vocab,
                                       checkpoint_template, ckpt_state,
-                                      export_npz, resume_start_epoch)
+                                      export_npz, place_restored,
+                                      resume_start_epoch, saver_buffers)
 from fast_tffm_tpu.config import FmConfig
 from fast_tffm_tpu.data.badlines import BadLineTracker
 from fast_tffm_tpu.data.pipeline import (SPILL_WARN_FRACTION,
@@ -585,6 +586,7 @@ class _Session:
         self.vocab_fresh_over_restore = False
         # ... by _build_state_and_step ...
         self.lk = self.step_fn = self.packed_step = self.wire_enc = None
+        self.snapshot = None    # a periodic saver's host pair
         # ... and by _arm_publish_gate.
         self.gate = None
         self.quality_on = False
@@ -804,7 +806,7 @@ def _train_session(cfg: FmConfig, logger, tel, bad_tracker,
         # peak watermark (deliberately NOT reset) keeps the high-water
         # answer across recoveries.
         for _owner in ("table", "adagrad_acc", "offload_table",
-                       "offload_acc", "wire_buffers",
+                       "offload_acc", "ckpt_snapshot", "wire_buffers",
                        "prefetch_batches", "lockstep_window"):
             LEDGER.release(_owner)
         try:
@@ -972,8 +974,9 @@ def _restore(s: _Session) -> None:
     s.ckpt = CheckpointState(cfg.model_file,
                              retry=RetryPolicy.from_config(cfg),
                              verify=getattr(cfg, "ckpt_verify", "size"))
-    restored = s.restored = s.ckpt.restore(
-        template=checkpoint_template(cfg, s.mesh, host=s.offload))
+    restored = s.restored = s.ckpt.restore(  # off a mesh: to HOST memory
+        template=checkpoint_template(cfg, s.mesh,
+                                     host=s.offload or s.mesh is None))
     if restored is not None:
         check_restored_vocab(cfg, restored)
         s.restored_step = int(restored["step"])
@@ -1077,17 +1080,15 @@ def _build_state_and_step(s: _Session):
             jax.process_count(), local_bytes_in_use() or "unmeasured")
     else:
         if restored is not None:
-            table = restored["table"][:cfg.num_rows]
-            acc = restored["acc"][:cfg.num_rows]
-            # The slices above are NEW device buffers; drop the full
-            # [ckpt_rows, D] restored arrays so they free once the
-            # slice completes — holding them for the whole run is a
-            # sustained ~2x HBM cost that only bites on resume.
-            restored["table"] = restored["acc"] = None
+            table, acc = place_restored(restored, cfg.num_rows)
         else:
             table = init_table(cfg, cfg.seed)
             acc = init_accumulator(cfg)
         step_fn = make_train_step(spec)
+        if cfg.save_steps:  # a periodic saver's host pair, for its life
+            s.snapshot = saver_buffers(cfg, table, acc)
+            LEDGER.register("ckpt_snapshot", 2 * s.snapshot[0].nbytes,
+                            host=True)
     s.step_fn = step_fn
 
     # Ownership ledger (obs/memory.py; README "Memory observability"):
@@ -1140,6 +1141,8 @@ def _build_state_and_step(s: _Session):
                      "train/loss_sync_seconds", "train/state_relayouts",
                      "train/step_programs", "train/program_switches"):
             tel.count(name, 0)
+        for name in SAVE_COUNTERS if cfg.save_steps else ():
+            tel.count(name, 0)  # so does what only a periodic save feeds
     return table, acc
 
 
@@ -1524,12 +1527,13 @@ class StepLoop:
              rewrite_stale_metadata: bool = False) -> None:
         """Checkpoint the state as it stands at ``global_step``, with
         the watermark and admission sidecars that describe the same
-        prefix. Device arrays save async unless ``wait`` (orbax
-        D2H-snapshots synchronously, writes in background — the loop
-        doesn't stall for serialization)."""
+        prefix. Device arrays save async unless ``wait``: the snapshot
+        to the host is taken here (orbax's of a mesh's shards, or
+        ``ckpt_state``'s), the write runs in the background."""
         s = self.s
-        state = (s.lk.state() if s.offload
-                 else ckpt_state(s.cfg, self.table, self.acc))
+        s.ckpt.settle()
+        state = (s.lk.state() if s.offload else
+                 ckpt_state(s.cfg, self.table, self.acc, into=s.snapshot))
         s.ckpt.save(self.global_step, *state,
                     vocabulary_size=s.cfg.vocabulary_size, force=force,
                     wait=wait, epoch=epoch,
