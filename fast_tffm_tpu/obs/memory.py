@@ -136,6 +136,44 @@ def device_capacity_bytes() -> Optional[int]:
     return int(limit)
 
 
+# A validating job's held-out sweep, placed once and kept on the device
+# for the job's later sweeps (data/resident.py), where its placed bytes
+# come under this share of one device's capacity: 1/32, 0.53 GB of the
+# v5e's 16.9. It admits a sweep of a million examples a validation
+# (fm16-train-eval's 54 batches: 146 MB) and refuses a held-out day
+# (3 GB and up), which streams as before.
+RESIDENT_SWEEP_OWNER = "resident_sweep"
+RESIDENT_SWEEP_SHARE = 32
+# Where the backend reports no capacity (the CPU): a constant, so that
+# which way a job goes there does not hang on the host.
+RESIDENT_SWEEP_UNMEASURED_BYTES = 64 << 20
+
+
+def resident_sweep_budget(capacity: Optional[int] = None,
+                          used: Optional[int] = None) -> int:
+    """The placed bytes a sweep may have and stay on the device: the
+    share above of one device's capacity, and never more than HALF of
+    what the device has left over ``used`` (the other half is a later,
+    wider batch's). ``used``, not given, is the runtime's own
+    high-water mark (``peak_bytes_in_use``, else ``bytes_in_use``):
+    the holder asks as a job's first sweep opens, an epoch of steps
+    behind it, so the mark holds the train step's peak (the v5e's
+    12.92 GB of 16.9 at the benchmark's 9.13 GB state leaves 1.99 GB,
+    over the share). A sweep is kept out of what is left and streams
+    where that is too little: the pre-flight books nothing for it, and
+    no job that started without it is refused for it. ``capacity`` and
+    ``used``: the planner's, where it sizes from a config."""
+    stats = None if capacity else device_memory_stats()
+    cap = capacity or (stats or {}).get("bytes_limit")
+    if not cap:
+        return RESIDENT_SWEEP_UNMEASURED_BYTES
+    if used is None:
+        used = ((stats or {}).get("peak_bytes_in_use")
+                or (stats or {}).get("bytes_in_use") or 0)
+    return min(int(cap) // RESIDENT_SWEEP_SHARE,
+               max(0, int(cap) - int(used)) // 2)
+
+
 # --- ownership ledger ------------------------------------------------------
 
 class MemoryLedger:
@@ -387,7 +425,7 @@ def parse_what_if(spec: str) -> Dict[str, Any]:
 
 def plan(cfg, kind: str = "train",
          overrides: Optional[Dict[str, Any]] = None,
-         shards: int = 1) -> Dict[str, Any]:
+         shards: int = 1, capacity: Optional[int] = None) -> Dict[str, Any]:
     """Predicted resident bytes per owner on one device, from config
     alone — what ``fmstat capacity`` renders and
     ``preflight_capacity`` enforces, cross-checked against the live
@@ -420,7 +458,13 @@ def plan(cfg, kind: str = "train",
     exit save alone, and a resume's restored pair, hold as much for
     as long as they take. ``kind="serve"``: the resident
     table plus the old+new reload transient headroom a hot reload
-    needs (serve/server._load_step holds both until the swap)."""
+    needs (serve/server._load_step holds both until the swap).
+    ``resident_sweep_bytes``: the most a validating job's kept
+    held-out sweep may hold (``resident_sweep_budget``), 0 where it
+    keeps none; beside ``total_bytes`` and not in it, because the
+    holder keeps a sweep only out of what the device has left.
+    ``capacity``: the device's, where the caller sizes for another
+    chip than the backend's (``fmstat capacity --capacity-bytes``)."""
     o = dict(overrides or {})
     vocab = int(o.get("vocabulary_size", cfg.vocabulary_size))
     k = int(o.get("factor_num", cfg.factor_num))
@@ -464,7 +508,24 @@ def plan(cfg, kind: str = "train",
                     rows=mesh_rows(rows), dim=dim)
         owners["wire_buffers"] = wire
     total = sum(owners.values())
-    cap = device_capacity_bytes()
+    cap = capacity or device_capacity_bytes()
+    # A validating job's kept sweep, at its ceiling and beside the
+    # total, not in it: the budget out of what this plan leaves, or a
+    # capped sweep's batches at their widest (0: no validation_files,
+    # or the holder's rule refuses the job).
+    kept = 0
+    if kind != "serve" and getattr(cfg, "validation_files", ()):
+        from fast_tffm_tpu.data.resident import sweep_refusal
+        if sweep_refusal(
+                places=getattr(cfg, "lookup", "device") != "host",
+                view=getattr(cfg, "vocab_mode", "fixed") == "admit") is None:
+            # ids, values and as many unique rows as cells; FFM's fields
+            ffm = getattr(cfg, "model_type", "fm") == "ffm"
+            widest = batch * feats * (3 + ffm) * F32_BYTES
+            capped = int(getattr(cfg, "validation_max_batches", 0) or 0)
+            kept = resident_sweep_budget(cap, total)
+            if capped:
+                kept = min(kept, capped * widest)
     out: Dict[str, Any] = {
         "kind": kind,
         "overrides": o,
@@ -476,6 +537,8 @@ def plan(cfg, kind: str = "train",
         "total_bytes": int(total),
         "capacity_bytes": cap,
     }
+    if kind != "serve":
+        out[RESIDENT_SWEEP_OWNER + "_bytes"] = kept
     if cap:
         out["utilization_fraction"] = total / float(cap)
         out["verdict"] = "EXCEEDS" if total > cap else "FITS"
@@ -502,6 +565,12 @@ def render_plan(p: Dict[str, Any]) -> str:
     lines.append(f"  {'predicted device total':<24} "
                  f"{_mb(p['total_bytes'])}"
                  + (" per device" if whole else ""))
+    kept = p.get(RESIDENT_SWEEP_OWNER + "_bytes")
+    if kept is not None:
+        lines.append(f"  {RESIDENT_SWEEP_OWNER:<24} {_mb(kept)}"
+                     + (" (at most, and not in the total: a validating "
+                        "job's held-out sweep, kept where the device "
+                        "has the room)" if kept else ""))
     cap = p.get("capacity_bytes")
     if cap:
         lines.append(f"  {'device capacity':<24} {_mb(cap)}")

@@ -31,6 +31,7 @@ from fast_tffm_tpu.data.pipeline import (SPILL_WARN_FRACTION,
                                          VALIDATION_PLANE, EpochFeed,
                                          EpochMark, host_parallel_workers,
                                          uniq_bucket_top)
+from fast_tffm_tpu.data.resident import ResidentSweeps
 from fast_tffm_tpu.utils.retry import RetryPolicy
 from fast_tffm_tpu.metrics import StreamingAUC
 from fast_tffm_tpu.models.fm import (ModelSpec, init_accumulator,
@@ -58,28 +59,27 @@ SLOW_STEP_SECONDS = 1.0
 
 def sweep_feed(cfg: FmConfig, files, sweeps: range, mesh=None, backend=None,
                max_batches: Optional[int] = None, weight_files=(),
-               bad_lines=None, vocab=None) -> EpochFeed:
+               bad_lines=None, vocab=None) -> ResidentSweeps:
     """The feed ``evaluate()`` reads ``sweeps`` sweeps of ``files``
-    from: a sweep is an epoch of it, its batches
-    ``batch_iterator(training=False, epochs=1, counters=VALIDATION_PLANE)``'s
-    and at most ``max_batches`` of them, each placed for the scorer of
-    this dispatch path on the feed's own thread (``make_score_placer``).
-    Held at a sweep's mark until ``evaluate()`` lets it go: behind the
-    sweep's drain, so that the next sweep's builders run beside the
-    interval's steps and not beside the drain's fetch; where ``vocab``
-    is set only as the next sweep starts (its batches are the eval
-    view's, taken after the barriers in between), and as under the
-    training feed's hold the consumer places."""
-    spec = ModelSpec.from_config(cfg)
-    return EpochFeed(
-        cfg, files, sweeps,
-        place=(None if vocab is not None
-               else make_score_placer(mesh, backend)),
-        hold=True, uniq_bucket=lambda: 0,
-        weight_files=weight_files, bad_lines=bad_lines, vocab=vocab,
-        raw_ids=ships_raw_batches(spec, mesh=mesh, backend=backend),
-        training=False, counters=VALIDATION_PLANE,
-        place_seconds="validation/place_seconds", max_batches=max_batches)
+    from (data/resident.py): a sweep is an epoch of its plane, its
+    batches ``batch_iterator(training=False, epochs=1)``'s, at most
+    ``max_batches`` of them, each placed for this dispatch path's scorer
+    on the plane's own thread. A sweep that fits stays on the device and
+    the plane is closed at its mark. Else the plane is held at a mark
+    until ``evaluate()`` lets it go: behind the drain; under ``vocab``
+    as the next sweep starts (its eval view), and the consumer places."""
+    raw = ships_raw_batches(ModelSpec.from_config(cfg), mesh=mesh,
+                            backend=backend)
+    place = None if vocab is not None else make_score_placer(mesh, backend)
+    return ResidentSweeps(
+        lambda sweeps: EpochFeed(
+            cfg, files, sweeps, place=place, hold=True,
+            uniq_bucket=lambda: 0, weight_files=weight_files,
+            bad_lines=bad_lines, vocab=vocab, raw_ids=raw, training=False,
+            counters=VALIDATION_PLANE, max_batches=max_batches,
+            place_seconds="validation/place_seconds"),
+        sweeps, tuple(files) + tuple(weight_files),
+        places=place is not None, view=vocab is not None)
 
 
 def evaluate(cfg: FmConfig, table: jax.Array, files,
@@ -88,7 +88,7 @@ def evaluate(cfg: FmConfig, table: jax.Array, files,
              weight_files=(), bad_lines=None,
              vocab=None, collect=None,
              phases: bool = True,
-             feed: Optional[EpochFeed] = None) -> Tuple[float, int]:
+             feed: Optional[ResidentSweeps] = None) -> Tuple[float, int]:
     """Streamed AUC over ``files``; returns (auc, n_examples). Pass the
     training mesh to score a row-sharded table in place, or a lookup
     ``backend`` (lookup.HostOffloadLookup) to score a host-offloaded
@@ -109,11 +109,11 @@ def evaluate(cfg: FmConfig, table: jax.Array, files,
     ``feed``: the ``sweep_feed`` of a job that sweeps again and again
     (``_Session.validate``: made for these files, this dispatch path
     and the session's cap, which ``max_batches`` and the arguments
-    behind it then only repeat). This sweep is its next epoch: the
-    first batches were built and placed while the interval trained,
-    and the sweep ends at the feed's mark. Without one the sweep reads
-    from a feed of ONE sweep, made here and closed here: the cold
-    plane, every call."""
+    behind it then only repeat). This sweep is its next epoch: its
+    batches have been on the device since the job's first sweep or,
+    streamed, the first were built and placed while the interval
+    trained; it ends at the feed's mark. Without one the sweep reads
+    from a feed of ONE sweep, made and closed here: the cold plane."""
     tel = active()
     # The sweep's wall on the calling thread, as leaves of the loop's
     # partition (obs/telemetry.py ANATOMY_PHASES), named as predict's
@@ -591,7 +591,7 @@ class _Session:
         self.gate = None
         self.quality_on = False
         # The feed of the epochs' sweeps: made at the first (validate).
-        self.sweeps: Optional[EpochFeed] = None
+        self.sweeps: Optional[ResidentSweeps] = None
         self.spec = ModelSpec.from_config(cfg)
         logger.info("train regime: %s", regime_line(self.spec, cfg))
         self.multi_process = jax.process_count() > 1

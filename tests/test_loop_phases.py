@@ -140,8 +140,10 @@ def test_feed_place_is_off_the_loop_and_on_no_leaf_list(phased_run):
     place = _spans(events, "feed/place")
     c = [e for e in events if e["event"] == "metrics"][-1]["counters"]
     # the sweeps' feed places under the same name, on an ``fm-place`` of
-    # its own and into a counter of its own (ISSUE 51)
-    assert len(place) == 8 + c["validation/batches"]
+    # its own and into a counter of its own (ISSUE 51): the first
+    # sweep's batches, which the later ones score again (ISSUE 53)
+    assert c["validation/sweeps"] == 1 + c["validation/resident_sweeps"]
+    assert len(place) == 8 + c["validation/batches"] / c["validation/sweeps"]
     assert {s["tid"] for s in place} == {"fm-place"}
     assert _loop_thread(events) != "fm-place"
     assert "feed/place" not in LEAF_SPANS

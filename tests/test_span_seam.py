@@ -265,8 +265,9 @@ def loop_placed_run(tmp_path_factory):
     ("pipeline/first_batch", "pipeline/first_batch_seconds", 2),
     ("train/batch_checks", "train/batch_checks_seconds", 10),
     # encode + placement, a batch ahead on the feed's own thread; the
-    # sweeps' feed places its 2 x 2 batches under the same name (ISSUE 51)
-    ("feed/place", "train/place_seconds validation/place_seconds", 12),
+    # sweeps' feed places its 2 batches under the same name (ISSUE 51),
+    # once: the second sweep scores what the first placed (ISSUE 53)
+    ("feed/place", "train/place_seconds validation/place_seconds", 10),
     ("pipeline/emit", "pipeline/emit_seconds", 8),
     ("train/step", "train/dispatch_seconds", 8),
     ("train/bookkeeping", "train/bookkeeping_seconds", 8),

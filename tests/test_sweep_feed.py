@@ -11,7 +11,13 @@ first batch is out of the builders before the loop asks; a held feed
 (``vocab_mode = admit``) cuts nothing until the sweep starts; the cap;
 and a feed that is closed, or a sweep that raises, leaves nothing
 behind. That the sweep's six leaves still partition its wall, with the
-feed's placement on none of them, is tests/test_validation_phases.py's."""
+feed's placement on none of them, is tests/test_validation_phases.py's.
+
+Since ISSUE 53 a sweep that fits stays on the device and its plane is
+closed at the first mark (tests/test_resident_sweep.py). This file is
+the plane's: what a held-out set over the budget, an admit-mode job and
+a lookup backend still run at every sweep. Its jobs are given no
+budget, so that every sweep of them streams."""
 
 import gc
 import os
@@ -38,6 +44,12 @@ from tests.test_epoch_feed import (_counters, _feed_threads, _one_device,
 B, PER_SWEEP = 32, 12       # three files of 4 batches, the last one short
 STEPS = 4                   # an epoch's
 CALL = ("uniq_ids", "local_idx", "vals", "fields")
+
+
+@pytest.fixture(autouse=True)
+def _every_sweep_streams(monkeypatch):
+    from fast_tffm_tpu.obs import memory
+    monkeypatch.setattr(memory, "RESIDENT_SWEEP_UNMEASURED_BYTES", 0)
 
 
 def _held_out(d):

@@ -248,14 +248,8 @@ def main_capacity(argv=None) -> int:
     from fast_tffm_tpu.config import load_config
     cfg = load_config(args.config)
     overrides = parse_what_if(args.what_if)
-    p = plan(cfg, args.kind, overrides)
-    if args.capacity_bytes:
-        p["capacity_bytes"] = args.capacity_bytes
-        p["utilization_fraction"] = (p["total_bytes"]
-                                     / float(args.capacity_bytes))
-        p["verdict"] = ("EXCEEDS"
-                        if p["total_bytes"] > args.capacity_bytes
-                        else "FITS")
+    p = plan(cfg, args.kind, overrides,
+             capacity=args.capacity_bytes or None)
     if args.json:
         print(json.dumps(p, default=str))
     else:
