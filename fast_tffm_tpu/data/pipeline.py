@@ -25,6 +25,7 @@ Padding invariants (relied on by ops/ and tests):
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import functools
 import glob as globlib
@@ -1084,6 +1085,8 @@ class _BuildRing:
     def __init__(self, workers: int, depth: int, work,
                  make_state=None, counters: str = TRAIN_PLANE):
         self._build_seconds = counters + "/worker_build_seconds"
+        self._idle_seconds = counters + "/worker_idle_seconds"
+        self._ring_wait = counters + "/ring_wait"
         self._lock = threading.Lock()
         self._cv = threading.Condition(self._lock)
         self._tasks: collections.deque = collections.deque()
@@ -1117,31 +1120,39 @@ class _BuildRing:
         with self._lock:
             return seq in self._results
 
-    def occupancy(self) -> int:
-        """Completed-but-unconsumed results parked in the ring — the
-        occupancy gauge (full ring = consumer-bound, empty = builders
-        can't keep up)."""
-        with self._lock:
-            return len(self._results)
-
     def wait(self, seq: int) -> tuple:
         """Block until ``seq``'s result is ready and take it:
         ("ok", value) or ("error", exception). Raises instead when the
         pool itself is unusable (a worker's state factory failed, or
         every worker exited) — the consumer must never park forever on
-        a ring nobody will fill."""
+        a ring nobody will fill. A result that is not there yet is
+        waited for under the span ``<plane>/ring_wait``
+        [``<plane>/ring_wait_seconds``]: open only while the builders
+        are what the coordinator waits for."""
+        from fast_tffm_tpu.obs.trace import span
         with self._lock:
-            while True:
-                res = self._results.pop(seq, None)
-                if res is not None:
-                    return res
-                if self._pool_error is not None:
-                    raise self._pool_error
-                if self._started >= self.workers and self._alive == 0:
-                    raise RuntimeError(
-                        "all batch-build workers exited; the host "
-                        "data plane cannot make progress")
-                self._cv.wait()
+            res = self._take(seq)
+        if res is not None:
+            return res
+        with span(self._ring_wait, seconds=self._ring_wait + "_seconds"):
+            with self._lock:
+                while True:
+                    res = self._take(seq)
+                    if res is not None:
+                        return res
+                    self._cv.wait()
+
+    def _take(self, seq: int) -> Optional[tuple]:
+        """``seq``'s result if it is in, under the lock."""
+        res = self._results.pop(seq, None)
+        if res is None:
+            if self._pool_error is not None:
+                raise self._pool_error
+            if self._started >= self.workers and self._alive == 0:
+                raise RuntimeError(
+                    "all batch-build workers exited; the host "
+                    "data plane cannot make progress")
+        return res
 
     def invalidate_after(self, seq: int) -> None:
         with self._lock:
@@ -1175,13 +1186,26 @@ class _BuildRing:
             self._alive += 1
         try:
             while True:
+                idle = 0.0
                 with self._lock:
-                    while not self._tasks and not self._stop:
-                        self._cv.wait()
+                    if not self._tasks and not self._stop:
+                        # fmlint: disable=R003 -- feeds the plane's
+                        # worker_idle_seconds counter (summed over the
+                        # workers, as worker_build_seconds is): a
+                        # worker with no task, not a stage's span
+                        t0 = (_time.perf_counter()
+                              if active() is not None else None)
+                        while not self._tasks and not self._stop:
+                            self._cv.wait()
+                        if t0 is not None:
+                            # fmlint: disable=R003 -- closes the sample
+                            idle = _time.perf_counter() - t0
                     if self._stop:
                         return
                     gen, seq, payload = self._tasks.popleft()
                 tel = active()
+                if idle and tel is not None:
+                    tel.count(self._idle_seconds, idle)
                 try:
                     if tel is None:
                         res = ("ok", self._work(state, payload))
@@ -1252,7 +1276,9 @@ class _GroupScanner:
     def __init__(self, files: Sequence[str], shard_index: int,
                  num_shards: int, B: int, keep_empty: bool,
                  retry: Optional[RetryPolicy],
-                 file_marks: Optional[FileMarks] = None):
+                 file_marks: Optional[FileMarks] = None,
+                 counters: str = TRAIN_PLANE):
+        self._read_span = counters + "/scan_read"
         self._files = list(files)
         self._fi = 0
         self._chunks: Optional[Iterator[bytes]] = None
@@ -1308,9 +1334,15 @@ class _GroupScanner:
         return g
 
     def _next_chunk(self) -> Optional[bytes]:
+        from fast_tffm_tpu.obs.trace import span
         while True:
             if self._chunks is not None:
-                chunk = next(self._chunks, None)
+                # The file read alone (its open too: the generator's
+                # first next()), inside the group's <plane>/scan: what
+                # scan holds beyond it is the buffer's appends and cut.
+                with span(self._read_span,
+                          seconds=self._read_span + "_seconds"):
+                    chunk = next(self._chunks, None)
                 if chunk is not None:
                     return chunk
                 self._chunks = None
@@ -1457,19 +1489,26 @@ class _GroupSource:
     held at a mark), so the next epoch's files open the moment this
     one's run out. ``epoch`` is the one being cut: a spill rewind that
     hands lines back to a scanner this source had finished with sets it
-    back."""
+    back. One group's read, append and cut run under the span
+    ``<counters>/scan`` [``<counters>/scan_seconds``] on whichever
+    thread asks (``fm-scan``, or the coordinator's where a spill may
+    rewind); the wait for an epoch that may not be cut yet does not."""
 
-    def __init__(self, epochs: Iterator[_Epoch]):
+    def __init__(self, epochs: Iterator[_Epoch],
+                 counters: str = TRAIN_PLANE):
         self._epochs = epochs
+        self._scan_span = counters + "/scan"
         self.epoch: Optional[_Epoch] = None
 
     def next(self):
+        from fast_tffm_tpu.obs.trace import span
         if self.epoch is None:
             self.epoch = next(self._epochs, None)
             if self.epoch is None:
                 return None
         ep = self.epoch
-        g = None if ep.groups == 0 else ep.scanner.next_group()
+        with span(self._scan_span, seconds=self._scan_span + "_seconds"):
+            g = None if ep.groups == 0 else ep.scanner.next_group()
         if g is None:
             end, self.epoch = _EpochEnd(ep), None
             return end
@@ -1501,14 +1540,14 @@ def _ring_batches(epochs: Iterator[_Epoch], make_builder, workers: int,
     tel = active()
     if tel is not None:
         tel.set(counters + "/host_threads", workers)
-    source = _GroupSource(epochs)
+    source = _GroupSource(epochs, counters)
     next_group, ahead = source.next, None
     if not spill_capable:
         # No rewind ever reaches a scanner, so groups are cut on a
         # thread of its own, ahead of the ring: reading, appending and
         # cutting a 15 MB group (14 ms at B = 32768) no longer waits
         # for the emit beside it.
-        ahead = _read_ahead(iter(source.next, None), 2, "fm-scan")
+        ahead = _read_ahead(iter(source.next, None), 2, "fm-scan", counters)
         next_group = functools.partial(next, ahead, None)
     inflight: Dict[int, _Group] = {}
     order: collections.deque = collections.deque()  # ring seqs, _EpochEnds
@@ -1555,8 +1594,6 @@ def _ring_batches(epochs: Iterator[_Epoch], make_builder, workers: int,
                 continue
             g = inflight.pop(s)
             kind, payload = ring.wait(s)
-            if tel is not None:
-                tel.set(counters + "/ring_occupancy", ring.occupancy())
             scanner = g.epoch.scanner
             if kind == "error":
                 if isinstance(payload, ParseError):
@@ -1658,8 +1695,8 @@ def _parallel_fast_batch_iterator(cfg: FmConfig, files: List[str],
     file_seed = cfg.seed if seed is None else seed
     epochs = (_Epoch(epoch, emitter, _GroupScanner(
         epoch_file_order(files, shuffle, file_seed, epoch), shard_index,
-        num_shards, B, keep_empty, retry, file_marks=file_marks),
-        make_builder) for epoch in range(n_epochs))
+        num_shards, B, keep_empty, retry, file_marks=file_marks,
+        counters=counters), make_builder) for epoch in range(n_epochs))
     return _ring_batches(epochs, make_builder, workers, spill_capable,
                          num_shards, counters)
 
@@ -2105,14 +2142,10 @@ def _batch_iterator_impl(cfg: FmConfig, files: Sequence[str],
         """Yield completed pool batches in submit order: every
         already-finished head eagerly, plus (blocking) enough to keep
         the in-flight count within ``limit`` (0 = drain everything)."""
-        from fast_tffm_tpu.obs.telemetry import active as _active
-        tel = _active()
         while pool_order and (len(pool_order) > limit
                               or pool.has(pool_order[0])):
             s = pool_order.popleft()
             kind, val = pool.wait(s)
-            if tel is not None:
-                tel.set(counters + "/ring_occupancy", pool.occupancy())
             if kind == "error":
                 raise val
             if val is None:
@@ -2408,7 +2441,8 @@ def gil_bound_iteration(cfg: FmConfig, weight_files: Sequence[str] = (),
 
 
 def prefetch(iterator: Iterator[DeviceBatch], depth: int = 2,
-             gil_bound: bool = False) -> Iterator[DeviceBatch]:
+             gil_bound: bool = False,
+             counters: Optional[str] = None) -> Iterator[DeviceBatch]:
     """Run ``iterator`` in a background thread, ``depth`` batches ahead.
 
     The reference overlaps input with compute via TF queue-runner threads
@@ -2430,7 +2464,8 @@ def prefetch(iterator: Iterator[DeviceBatch], depth: int = 2,
     training plane's, a validating job's sweeps') add a stage of their
     own behind this one (``place_ahead``: placement a batch ahead, off
     the loop's thread), predict places on its own thread as it
-    dispatches.
+    dispatches. ``counters``: ``_read_ahead``'s (a job's feed hands
+    its plane's prefix; predict's sweep and the stream count nothing).
     """
     if gil_bound:
         if _host_cpus() <= 1:
@@ -2438,7 +2473,7 @@ def prefetch(iterator: Iterator[DeviceBatch], depth: int = 2,
             return
 
     ledgered = False
-    ahead = _read_ahead(iterator, depth, "prefetch")
+    ahead = _read_ahead(iterator, depth, "prefetch", counters)
     try:
         for item in ahead:
             if not ledgered:
@@ -2466,24 +2501,29 @@ def prefetch(iterator: Iterator[DeviceBatch], depth: int = 2,
 
 
 def place_ahead(batches: Iterator[DeviceBatch], place, depth: int,
-                seconds: str) -> Iterator[tuple]:
+                loop: str) -> Iterator[tuple]:
     """The feed's last stage: ``(batch, placed)`` for every batch of
     ``batches``. ``place(batch) -> (batch, placed)`` (train.py
     ``StepLoop.feed_place``: wire encoding and host-to-device
     placement; a sweep's, models/fm.py ``make_score_placer``: the score
     call's arguments) runs on a thread of its own, ``fm-place``, at
     most ``depth`` batches ahead of the consumer, under the span
-    ``feed/place`` [``seconds``: ``train/place_seconds``, a sweep's
-    feed ``validation/place_seconds``]: no leaf of the loop's
-    partition, since the loop's thread does not wait for it. A separate
+    ``feed/place`` [``<loop>/place_seconds``, ``loop`` the consumer's
+    prefix: ``train``, a sweep's feed ``validation``]: no leaf of the
+    loop's partition, since its thread does not wait for it. A separate
     stage and not the emitting thread's work: emit and placement in
     series would be one thread's. What ``place`` raises is raised at
     the consumer's next(); a consumer that stops closes the stage, and
     the batches it had placed are let go with it. ``place`` None (the
     loop places for itself): every batch with ``placed`` None, on the
-    consumer's thread."""
-    feed = _each_placed(batches, place, seconds)
-    return feed if place is None else _read_ahead(feed, depth, "fm-place")
+    consumer's thread. The stage's blocked seconds count beside them
+    (``<loop>/fm_place_put_wait_seconds``); the wait for it is the
+    consumer's own span (``train/input_wait``), counted there and not
+    here a second time."""
+    feed = _each_placed(batches, place, loop + "/place_seconds")
+    if place is None:
+        return feed
+    return _read_ahead(feed, depth, "fm-place", loop, get_wait=False)
 
 
 def _each_placed(batches: Iterator[DeviceBatch], place,
@@ -2529,8 +2569,8 @@ class EpochFeed:
     ``max_batches`` of them where the session caps a sweep (the next
     one starts at the files' start again), remapped through the
     ``vocab.eval_view()`` taken once the epoch may be cut. ``counters``:
-    the prefix the plane's counts carry; ``place_seconds``: the counter
-    of its ``feed/place`` spans.
+    the prefix the plane's counts carry; ``loop``: the consumer's, which
+    its ``feed/place`` spans count under (``<loop>/place_seconds``).
 
     ``hold``: a barrier can change what the next epoch's batches are,
     or the consumer wants the next epoch's builders out of the way of
@@ -2554,9 +2594,9 @@ class EpochFeed:
                  bad_lines: Optional[BadLineTracker] = None, vocab=None,
                  row_shards: Optional[RowShards] = None,
                  training: bool = True, counters: str = TRAIN_PLANE,
-                 place_seconds: str = "train/place_seconds",
+                 loop: str = "train",
                  max_batches: Optional[int] = None):
-        from fast_tffm_tpu.obs.telemetry import active
+        from fast_tffm_tpu.obs.telemetry import active, feed_counters
         if max_batches and fixed_shape:
             raise ValueError("a capped epoch counts groups as batches; "
                              "under fixed shapes a spill re-cuts them")
@@ -2582,11 +2622,16 @@ class EpochFeed:
         self._tel = active()
         self._fed_ahead = counters + "/epochs_fed_ahead"
         if self._tel is not None:
-            self._tel.count(self._fed_ahead, 0)
+            # From 0, whichever route the plane takes: a window in
+            # which no stage waited reads 0.0 and not nothing.
+            for name in (self._fed_ahead, *feed_counters(
+                    counters, None if place is None else loop)):
+                self._tel.count(name, 0)
         self._it = place_ahead(
             prefetch(self._host_batches(), depth=cfg.prefetch_depth,
-                     gil_bound=gil_bound_iteration(cfg, weight_files)),
-            place, cfg.prefetch_depth, place_seconds)
+                     gil_bound=gil_bound_iteration(cfg, weight_files),
+                     counters=counters),
+            place, cfg.prefetch_depth, loop)
 
     # -- the consumer's side ---------------------------------------------
 
@@ -2752,7 +2797,8 @@ class EpochFeed:
                                   counters=self._counters),
                     _GroupScanner(epoch_file_order(files, shuffle, seed, 0),
                                   plane["shard_index"], plane["num_shards"],
-                                  B, False, retry),
+                                  B, False, retry,
+                                  counters=self._counters),
                     make_builder(uniq_bucket), self._max_batches)
 
         first_bucket = self._budget()
@@ -2762,7 +2808,9 @@ class EpochFeed:
             self._counters, marks=True, hold=self._hold)
 
 
-def _read_ahead(iterator: Iterator, depth: int, name: str) -> Iterator:
+def _read_ahead(iterator: Iterator, depth: int, name: str,
+                counters: Optional[str] = None,
+                get_wait: bool = True) -> Iterator:
     """``iterator`` run on a daemon thread called ``name``, at most
     ``depth`` items ahead of the consumer; what it raises is raised
     here. Shared by prefetch() (batches ahead of the step loop), the
@@ -2771,28 +2819,63 @@ def _read_ahead(iterator: Iterator, depth: int, name: str) -> Iterator:
     stops the thread, closes ``iterator`` on the thread that ran it (a
     generator's ``finally`` blocks stop the stages behind it) and waits
     for the thread, bounded: what it held is let go before the caller
-    goes on."""
+    goes on.
+
+    The one seam where the feed hands an item from a thread to the
+    next, so where who waited for whom is counted. ``counters`` (a
+    plane's prefix; ``name`` with ``-`` folded to ``_``):
+    ``<counters>/<name>_put_wait_seconds`` on the producing thread, the
+    time this stage stood BLOCKED with an item in hand and no room (the
+    stage behind it is slower), and ``<counters>/<name>_get_wait_seconds``
+    on the consuming thread, the time the consumer was STARVED by this
+    stage (``get_wait`` False: the consumer times that wait itself).
+    A hand-over that does not wait reads no clock. Spans with
+    ``leaf=False``: counters and JSONL events, never profiler
+    annotations, because a healthy feed's stages are blocked nearly all
+    the time and a device's idle gap must not be named after a blocked
+    worker; on the timeline a stage waits where its work span is not
+    open. No ``counters``: nothing is counted and no clock read."""
     import queue
     import threading
+    from fast_tffm_tpu.obs.trace import span
 
     q: "queue.Queue" = queue.Queue(maxsize=max(depth, 1))
     sentinel = object()
     stop = threading.Event()
     errbox: List[BaseException] = []
+    put_wait = starved = None
+    if counters:
+        stage = counters + "/" + name.replace("-", "_")
+        put_wait = stage + "_put_wait"
+        starved = stage + "_get_wait" if get_wait else None
+
+    def waiting(what: Optional[str]):
+        if what is None:
+            return contextlib.nullcontext()
+        return span(what, seconds=what + "_seconds", leaf=False)
+
+    def put(item) -> None:
+        """Bounded put + stop checks so an abandoned consumer (step
+        raised, caller broke out) can't strand this thread blocked
+        forever holding file handles/batches."""
+        try:
+            q.put_nowait(item)
+            return
+        except queue.Full:
+            pass
+        with waiting(put_wait):
+            while not stop.is_set():
+                try:
+                    q.put(item, timeout=0.1)
+                    break
+                except queue.Full:
+                    continue
 
     def worker():
         try:
             try:
                 for item in iterator:
-                    # Bounded put + stop checks so an abandoned consumer
-                    # (step raised, caller broke out) can't strand this
-                    # thread blocked forever holding file handles/batches.
-                    while not stop.is_set():
-                        try:
-                            q.put(item, timeout=0.1)
-                            break
-                        except queue.Full:
-                            continue
+                    put(item)
                     if stop.is_set():
                         return
             finally:
@@ -2817,7 +2900,11 @@ def _read_ahead(iterator: Iterator, depth: int, name: str) -> Iterator:
     thread.start()
     try:
         while True:
-            item = q.get()
+            try:
+                item = q.get_nowait()
+            except queue.Empty:
+                with waiting(starved):
+                    item = q.get()
             if item is sentinel:
                 if errbox:
                     raise errbox[0]

@@ -44,6 +44,7 @@ def summarize(paths: Sequence[str]) -> Dict[str, Any]:
     health_events: List[Dict[str, Any]] = []
     crash_events: List[Dict[str, Any]] = []
     slow_steps: List[Dict[str, Any]] = []
+    feed_stalls: List[Dict[str, Any]] = []
     n_events = 0
     n_spans = 0
     run_starts = 0
@@ -57,6 +58,7 @@ def summarize(paths: Sequence[str]) -> Dict[str, Any]:
         f_health: List[Dict[str, Any]] = []
         f_crash: List[Dict[str, Any]] = []
         f_slow: List[Dict[str, Any]] = []
+        f_stalls: List[Dict[str, Any]] = []
         f_started = 0
         f_ended = 0
         for rec in read_events(path):
@@ -70,7 +72,7 @@ def summarize(paths: Sequence[str]) -> Dict[str, Any]:
                 scalars.append(rec)
             elif ev == "run_start":
                 metas.append(rec.get("meta") or {})
-                f_health, f_crash, f_slow = [], [], []
+                f_health, f_crash, f_slow, f_stalls = [], [], [], []
                 f_started, f_ended = 1, 0
             elif ev == "run_end":
                 f_ended = 1
@@ -80,11 +82,14 @@ def summarize(paths: Sequence[str]) -> Dict[str, Any]:
                 f_crash.append(rec)
             elif ev == "slow_step":
                 f_slow.append(rec)
+            elif ev == "feed_stall":
+                f_stalls.append(rec)
             elif ev == "span":
                 n_spans += 1
         health_events.extend(f_health)
         crash_events.extend(f_crash)
         slow_steps.extend(f_slow)
+        feed_stalls.extend(f_stalls)
         run_starts += f_started
         run_ends += f_ended
 
@@ -114,6 +119,7 @@ def summarize(paths: Sequence[str]) -> Dict[str, Any]:
         "health_events": health_events,
         "crash_events": crash_events,
         "slow_steps": slow_steps,
+        "feed_stalls": feed_stalls,
         "counters": snap["counters"],
         "hists": snap["hists"],
         "gauges": flat_gauges,
@@ -198,13 +204,12 @@ def attribution(summary: Dict[str, Any]) -> Dict[str, Any]:
         # build workers, their summed build seconds over the
         # consumer-observed build+wait time (values near the worker
         # count = the fan-out is real; near 1 = the plane added no
-        # overlap), and the ordered ring's last-seen occupancy (full =
-        # consumer-bound, empty = builders can't keep up).
+        # overlap), and who waited for whom along the feed.
         "host_threads": g.get("pipeline/host_threads"),
         "host_build_concurrency": _frac(
             c.get("pipeline/worker_build_seconds"),
             c.get("pipeline/build_seconds")),
-        "ring_occupancy": g.get("pipeline/ring_occupancy"),
+        "feed_stages": feed_table(c),
         "dedup_hit_rate": dedup_hit_rate(c),
         # Distinct rows over the uniq_ids slots shipped (telemetry.
         # pipeline_batch). None in raw-ids mode.
@@ -974,6 +979,31 @@ def _fmt(v: Any) -> str:
     return str(v)
 
 
+def feed_table(c: Dict[str, float], plane: str = "pipeline",
+               loop: str = "train") -> List[Dict[str, Any]]:
+    """The job's feed stage by stage (telemetry.FEED_STAGES), seconds a
+    batch of the plane: inside the stage's work, its consumer starved
+    by it, and the stage blocked (the builders: without a task). The
+    last stage's consumer is the loop, whose wait is its own
+    ``input_wait``. Empty where the run had no such feed."""
+    from fast_tffm_tpu.obs.telemetry import FEED_STAGES
+    batches = c.get(plane + "/batches")
+
+    def per_batch(st, name):
+        if name is None:
+            return None
+        return _frac(c.get((loop if st.loop else plane) + "/" + name),
+                     batches)
+
+    rows = [{"stage": st.label,
+             "work": per_batch(st, st.work),
+             "starved": per_batch(st, st.starved or (
+                 "input_wait_seconds" if st.loop else None)),
+             "blocked": per_batch(st, st.blocked)}
+            for st in FEED_STAGES]
+    return [r for r in rows if r["work"] is not None]
+
+
 def render(summary: Dict[str, Any]) -> str:
     """Human-readable attribution table for one merged summary — the
     fmstat output body."""
@@ -1000,6 +1030,15 @@ def render(summary: Dict[str, Any]) -> str:
         lines.append(f"  slow {ev.get('what', 'step')} at step "
                      f"{ev.get('step')}: {_fmt(ev.get('wall'))} s "
                      f"[{top}]")
+    for ev in summary.get("feed_stalls") or []:
+        # One next(feed) that waited telemetry.FEED_STALL_SECONDS or more,
+        # with the feed's counters that grew since the last flush.
+        top = ", ".join(f"{k} {_fmt(v)} s" for k, v in
+                        list((ev.get("stages") or {}).items())[:3])
+        lines.append(f"  feed stall at step {ev.get('step')}: "
+                     f"{_fmt(ev.get('wall'))} s [in the "
+                     f"{_fmt(ev.get('window'))} s since the last flush: "
+                     f"{top}]")
     lines.append("")
     rows = [
         ("examples", att["examples"]),
@@ -1020,7 +1059,6 @@ def render(summary: Dict[str, Any]) -> str:
         ("host threads / build concurrency",
          f"{_fmt(att['host_threads'])} / "
          f"{_fmt(att['host_build_concurrency'])}"),
-        ("ring occupancy (last)", att["ring_occupancy"]),
         ("dedup hit rate", att["dedup_hit_rate"]),
         ("unique-slot fill", att["uniq_slot_fill"]),
         ("padding-waste fraction", att["padding_waste_fraction"]),
@@ -1058,6 +1096,15 @@ def render(summary: Dict[str, Any]) -> str:
         ]
     for k, v in rows:
         lines.append(f"  {k:<34} {_fmt(v)}")
+    if att["feed_stages"]:
+        # The stage that sets the feed's beat neither waits nor is
+        # blocked; every stage before it is blocked, every stage
+        # behind it starves.
+        lines.append("  FEED (s a batch: the stage's work, its consumer "
+                     "starved by it, the stage blocked):")
+        for r in att["feed_stages"]:
+            lines.append(f"    {r['stage']:<32} {_fmt(r['work'])} / "
+                         f"{_fmt(r['starved'])} / {_fmt(r['blocked'])}")
     if att["stream_files_discovered"] or att[
             "stream_publish_interval_seconds"]:
         lines.append("  STREAMING (run_mode = stream):")

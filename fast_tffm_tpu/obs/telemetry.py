@@ -165,6 +165,10 @@ class RunTelemetry:
         # them (what a slow_step event differences against).
         self._loop_t: Optional[float] = None
         self._loop_flushed: Dict[str, float] = {}
+        # Every counter as the last flush wrote it, and when (what a
+        # feed_stall event differences the feed's against).
+        self._flushed: Dict[str, float] = {}
+        self._flushed_t = time.perf_counter()
         # The step of the latest heartbeat: what a ``compile`` event is
         # stamped with, so a program compiled mid-run says when.
         self.step = -1
@@ -304,7 +308,7 @@ class RunTelemetry:
         residue every flush derives partition it."""
         self._loop_t = time.perf_counter()
         for name in ("train/loop_seconds", "train/slow_steps",
-                     *LOOP_LEAVES, *FEED_PLACE):
+                     *LOOP_LEAVES, *FEED_PLACE, *FEED_STALL):
             self.registry.count(name, 0)
         self._loop_flushed = {}
 
@@ -339,6 +343,35 @@ class RunTelemetry:
                 grew.items(), key=lambda kv: -kv[1]) if v > 0},
             **fields})
 
+    def waited(self, step: int, wall: float, first: bool) -> None:
+        """The epochs loop's ``next(feed)`` before ``step`` took
+        ``wall`` seconds: an epoch's ``first`` counts them under
+        ``pipeline/first_batch_seconds`` too, and one that reached
+        ``FEED_STALL_SECONDS`` is a stall of the feed (a shorter one
+        costs that comparison and no more)."""
+        if first:
+            self.count("pipeline/first_batch_seconds", wall)
+        if wall >= FEED_STALL_SECONDS:
+            self.feed_stall(step, wall)
+
+    def feed_stall(self, step: int, wall: float) -> None:
+        """One ``feed_stall`` event: where the feed was, as the growth
+        of every counter of ``FEED_STAGES`` since the last flush's
+        snapshot, ``window`` seconds ago (a stage that grew by
+        ``window`` was at that the whole time; the builders by
+        ``window`` times their number). Host values only."""
+        stalls, seconds = FEED_STALL
+        self.count(stalls)
+        self.count(seconds, wall)
+        c = self.registry.snapshot()["counters"]
+        grew = {k: float(c.get(k, 0.0)) - self._flushed.get(k, 0.0)
+                for k in TRAIN_FEED}
+        self.sink.emit("feed_stall", {
+            "step": int(step), "wall": wall,
+            "window": time.perf_counter() - self._flushed_t,
+            "stages": {k: v for k, v in sorted(
+                grew.items(), key=lambda kv: -kv[1]) if v > 0}})
+
     def _snapshot(self) -> Dict[str, Any]:
         """The registry as a ``metrics`` event carries it. A run with
         a loop clock gets the loop's wall brought up to this instant
@@ -349,6 +382,7 @@ class RunTelemetry:
         if self._loop_t is not None:
             self._loop_tick(time.perf_counter())
         snap = self.registry.snapshot()
+        self._flushed, self._flushed_t = snap["counters"], time.perf_counter()
         if "train/loop_seconds" in snap["counters"]:
             self._loop_flushed = loop_partition(snap["counters"])
             snap["counters"][LOOP_UNNAMED] = self._loop_flushed[
@@ -620,6 +654,62 @@ LOOP_UNNAMED = "train/loop_unnamed_seconds"
 # loop's thread, so the leaves keep summing to the loop thread's wall.
 PLACED_AHEAD, PLACE_SECONDS = FEED_PLACE = ("train/placed_ahead",
                                             "train/place_seconds")
+
+
+class FeedStage(NamedTuple):
+    """One stage of a job's feed (data/pipeline.py ``EpochFeed``:
+    ``fm-scan`` -> the coordinator on ``prefetch`` over the ring of
+    ``fm-build-<i>`` workers -> ``fm-place`` -> the loop), as three
+    ``*_seconds`` counters, each the name behind its prefix or None.
+    ``work``: inside the stage's own span. ``starved``: whoever takes
+    this stage's output waited for it (the get of a ``_read_ahead``
+    queue, the coordinator at the ring's head). ``blocked``: the stage
+    stood with its output in hand and no room behind it (the put), or,
+    the builders', with no task to take: it is ahead of its
+    neighbours. ``loop``: counted under the consumer's prefix
+    (``train``, a sweep's ``validation``) and not the plane's."""
+    label: str
+    work: Optional[str]
+    starved: Optional[str]
+    blocked: Optional[str]
+    loop: bool = False
+
+
+# The feed's stages from the files to the loop: the ONE list that the
+# zero-start (``EpochFeed``), a ``feed_stall`` event's ``stages`` and
+# fmstat's feed table (obs/attribution.py) are read off. The stage that
+# sets the feed's beat is the one that neither waits nor is blocked;
+# every stage before it is blocked, every stage behind it starves. The
+# last stage's starved seconds are the loop's own ``input_wait``.
+FEED_STAGES: Tuple[FeedStage, ...] = (
+    FeedStage("scan", "scan_seconds", "fm_scan_get_wait_seconds",
+              "fm_scan_put_wait_seconds"),
+    FeedStage("scan: file read", "scan_read_seconds", None, None),
+    FeedStage("build (all workers)", "worker_build_seconds",
+              "ring_wait_seconds", "worker_idle_seconds"),
+    FeedStage("emit", "emit_seconds", "prefetch_get_wait_seconds",
+              "prefetch_put_wait_seconds"),
+    FeedStage("place", "place_seconds", None,
+              "fm_place_put_wait_seconds", loop=True),
+)
+# One next(feed) of the epochs loop that waits this long is a stall of
+# the feed (``RunTelemetry.waited``): five to eight device steps, where
+# a ``slow_step`` takes 1.0 s of a whole step; tests patch it down.
+FEED_STALL_SECONDS = 0.05
+FEED_STALL = ("train/feed_stalls", "train/feed_stall_seconds")
+
+
+def feed_counters(plane: str = "pipeline",
+                  loop: Optional[str] = "train") -> Tuple[str, ...]:
+    """Every counter of ``FEED_STAGES`` by its full name, for a plane
+    that counts under ``plane`` and a consumer under ``loop`` (None: a
+    feed whose consumer places for itself has no stage on that side)."""
+    return tuple((loop if st.loop else plane) + "/" + name
+                 for st in FEED_STAGES if loop or not st.loop
+                 for name in (st.work, st.starved, st.blocked) if name)
+
+
+TRAIN_FEED = feed_counters()    # the training plane's, under pipeline/
 
 
 def loop_partition(counters: Dict[str, float]) -> Dict[str, float]:

@@ -77,7 +77,7 @@ def sweep_feed(cfg: FmConfig, files, sweeps: range, mesh=None, backend=None,
             uniq_bucket=lambda: 0, weight_files=weight_files,
             bad_lines=bad_lines, vocab=vocab, raw_ids=raw, training=False,
             counters=VALIDATION_PLANE, max_batches=max_batches,
-            place_seconds="validation/place_seconds"),
+            loop="validation"),
         sweeps, tuple(files) + tuple(weight_files),
         places=place is not None, view=vocab is not None)
 
@@ -1713,8 +1713,8 @@ def _run_epochs(s: _Session, loop: StepLoop) -> None:
                               seconds="train/input_wait_seconds",
                               step=loop.global_step + 1) as wait:
                         item = next(feed)
-                    if first and s.tel is not None:
-                        s.tel.count("pipeline/first_batch_seconds", wait.dur)
+                    if s.tel is not None:  # an epoch's first; a feed_stall
+                        s.tel.waited(loop.global_step + 1, wait.dur, first)
                     first = False
                     if isinstance(item, EpochMark):
                         mark = item
