@@ -9,9 +9,11 @@ The reference computes, in a multithreaded C++ TF op over a CSR batch
 
 Here the same math runs on fixed-shape bucketed batches (data/pipeline.py)
 as einsums the TPU compiler fuses end-to-end; ``jax.grad`` through these
-functions *is* the ``fm_grad`` equivalent (a hand-fused Pallas version with
-a custom VJP lives in ops/pallas_fm.py). Padding contributes exactly zero
-because padded ``vals`` are 0 and every term carries an ``x_j`` factor.
+functions *is* the ``fm_grad`` equivalent for FM (a hand-fused Pallas
+version with a custom VJP lives in ops/pallas_fm.py); FFM's interaction
+carries a hand-written VJP of its own (``ffm_batch_scores``). Padding
+contributes exactly zero because padded ``vals`` are 0 and every term
+carries an ``x_j`` factor.
 
 One rule for both scorers: the expanded rows ``[B, L, D]`` are consumed
 whole. A TPU tiles an array's last two dimensions (8, 128), so the
@@ -22,7 +24,13 @@ those padded lines to make, another to re-lay, and pads up to 128-fold
 itself. Slices are taken of the per-example sums ``[B, D]``, or where
 the column axis is a major one; the row gradient is born ``[B, L, D]``,
 the shape ``expand_rows``' segment-sum takes (PERF.md section 6, PR 30
-for FFM, PR 42 for FM).
+for FFM, PR 42 for FM). The backward's half of the rule: a layout swap
+the forward has made is KEPT for the backward, not made again on the
+cotangent. Autodiff transposes every re-lay of the forward, so a
+cotangent walks each of them back, pass by pass; where the backward
+needs the forward's re-laid array itself (FFM: the field-transpose P
+of the per-field sums IS the sums' gradient), a hand-written VJP saves
+it and the backward is the one pass that writes ``[B, L, D]`` (PR 55).
 
 Shapes: ``params`` are the batch's gathered unique rows ``[U, D]``
 (D = k+1 for FM, field_num*k+1 for FFM); ``local_idx [B, L]`` indexes
@@ -30,6 +38,8 @@ into them; ``vals [B, L]``.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -164,50 +174,185 @@ def ffm_batch_scores(params: jax.Array, field_num: int,
     here has the factor axis k as its minor dimension (a TPU tiles the
     last two dimensions (8, 128), so a [..., k=4] array pads 32-fold or
     is re-laid, forward and backward; PERF.md section 6, PR 30). With
-    F = field_num, D = F*k + 1 and the columns taken factor-major,
-    column κ*F + f holding v[., f][κ]:
+    F = field_num and the columns taken factor-major, column κ*F + f
+    holding v[., f][κ] and w the last (``_factor_major``: a 0/1 matrix
+    on the U gathered slots, not on the B·L expanded rows, exact in
+    float32 at ``HIGHEST``; its transpose brings the slot gradient
+    back, so the table and its checkpoints keep their layout):
 
-        a[b, l, g] = [fields[b, l] = g] · x[b, l]                [B, L, F]
-        S[b, g, :] = Σ_l a[b, l, g] · rows[b, l, :]               [B, F, D]
-        Σ_{i,j} <v_i[f_j], v_j[f_i]> x_i x_j
-                   = Σ_κ Σ_{g,f} S[b, g, κ*F+f] · S[b, f, κ*F+g]
+        S[b, g, :] = Σ_l [fields[b, l] = g] · x[b, l] · rows[b, l, :]
+        P[b, f, κ*F+g] = S[b, g, κ*F+f],   P[b, f, last] = 1
+        cross = Σ_{i,j} <v_i[f_j], v_j[f_i]> x_i x_j
+              = Σ_κ Σ_{g,f} S[b, g, κ*F+f] · S[b, f, κ*F+g] = Σ S ⊙ P
 
     (each ordered pair (i, j) lands in the (g, f) = (f_i, f_j) bucket
     exactly once: per κ an [F, F] slab against its own transpose), then
     the i=j diagonal Σ_l x_l²·||v_l[f_l]||², a mask over the columns,
-    is subtracted and the sum halved. S is ONE batched matmul over L
-    whose last column is the linear term, summed by field, so the rows
-    are never sliced and the row gradient is born [B, L, D], the shape
-    ``expand_rows``' segment-sum takes: a·dS less the masked diagonal.
-    The columns are put factor-major on the U gathered slots, not on
-    the B·L expanded rows, by a 0/1 matrix (exact in float32 at
-    ``HIGHEST``; its transpose brings the slot gradient back), so the
-    table and its checkpoints keep their layout. Nothing here depends
-    on which of F, k, L is large: the biggest intermediates are
-    [B, L, D] and [B, F, D]. Padded slots have x=0 and contribute
-    exactly zero to score and gradient; ``fields`` lie in [0, F) (the
-    parsers refuse any other), a feature outside would drop out whole.
+    is subtracted and the sum halved; the linear term Σ_l x_l·w_l is
+    taken in the diagonal's pass over the rows, not from S.
+
+    **The gradient is written by hand** (``_ffm_interaction``, a
+    ``jax.custom_vjp``) on one identity. P, the field-transpose of S,
+    is what the cross term's gradient w.r.t. S is: the cross term is a
+    symmetric quadratic form in S, so half its gradient at S[g, κF+f]
+    is S[f, κF+g] = P[g, κF+f], and the linear term's is P's last
+    column, 1. Through S's sum over the cells that is, for a cell's row,
+
+        ∂score_b / ∂rows[b, l, :] = x_l · P[b, f_l, :]
+                                    − x_l² · own[b, l, :] ⊙ rows[b, l, :]
+
+    (``own``: the columns the cell uses against its own field). So the
+    lane-to-sublane swap is made ONCE, forward, and P is the residual:
+    the backward is one pass, ``one_hot(fields)·P`` scaled by x·ds less
+    the masked rows, born [B, L, D] as ``expand_rows``' segment-sum
+    takes it, and needs neither S nor a batch-minor array. Autodiff of
+    the same forward walked dS through batch-minor and back instead.
+    The passes of the scope at ``ffm4-train-zipf``'s size (B 8192, L 24,
+    F 22, k 4; MB in tiled bytes of the program compiled for a v5e, ms
+    a step on the chip; PERF.md section 6, PR 55):
+
+        autodiff (until PR 55)            MB    ms | hand-written VJP             MB    ms
+        S = a·rows (6 bf16 passes)       210  0.45 | x·rows, diagonal + linear   205  0.29
+        the diagonal                     109  0.54 | S = one_hot·(x·rows), 1 x 3 210  0.31
+        the linear term, read off S      101  0.16 | S to batch-minor            176  0.14
+        S to batch-minor, reshape        297  0.17 | the (g, f) swap             151  0.12
+        cross                             69  0.05 | cross                        76  0.10
+        bwd: dS in batch-minor, swap     276  0.20 | P back to row-major, kept   176  0.12
+        bwd: reshape, column 88 put back 266  0.31 | bwd: one_hot·P less rows    315  0.49
+        bwd: dS back to row-major        170  0.30 |
+        bwd: a·dS less the diagonal      310  0.47 |
+        the 0/1 matrix, both ways         18  0.02 | the same                     18  0.03
+        no op path (copies, slices)            0.22 |                                  0.04
+        sum                            1,826  2.92 |                           1,327  1.63
+
+    Three things besides the identity make the right-hand column:
+
+    - **F is padded to the sublane count** where that keeps the row on
+      as many 128-lane lines (``_padded_fields``: 22 → 24, a row of 97
+      columns where 89, one line either way, so ``expand`` moves what
+      it moved). In batch-minor S is ``[F, k·F, B]``, and the swap
+      needs ``[F, k, F, B]``: with 22 fields on 8 sublanes that split
+      is a re-lay (the two ``reshape`` passes, 0.20 ms each on the
+      chip); with 24 it is a bitcast. The two fields no feature has
+      give zero columns, zero rows of S and zero gradient.
+    - **The one-hot operand of both matmuls is exact in ONE bf16
+      pass**, so x rides the other operand (forward: ``x·rows``, three
+      passes keep its float32) or the result (backward: ``x·ds``
+      scales ``one_hot·P``). At ``HIGHEST`` on both operands the S
+      matmul was bound by its six passes, not by its bytes.
+    - The compiler keeps arrays in the chip's 128 MiB of fast memory
+      where they fit, and copies there run twice as fast; which arrays
+      fit is its choice per program, and only a chip run says.
+
+    The forward-only scorers (validation, predict, serve) run the
+    primal alone: P is dead code there and no array goes back from
+    batch-minor to row-major (tests/test_state_layout.py).
+
+    **What is not differentiated.** The VJP returns a cotangent for
+    the rows alone; ``vals`` and ``fields`` get none. Nothing in the
+    tree differentiates w.r.t. either (``grad_body`` takes the
+    gradient w.r.t. the gathered rows); a caller that did would read
+    zeros.
+
+    **What a pad cell must look like**: x = 0 on a FINITE row, whatever
+    row it indexes. Its one-hot row then meets ``x·rows = 0`` forward,
+    and backward ``one_hot·P`` (finite) is scaled by ``x·ds = 0``: score
+    and gradient are exactly 0.0, as for an example whose cotangent is
+    0. The order matters: x·ds multiplies P's rows, never the other way
+    round with a P that is not finite. A row holding inf or NaN under a
+    pad cell poisons its example (0·inf), as it did before PR 55.
+    ``fields`` lie in [0, F) (the parsers refuse any other); a feature
+    outside would drop out of the cross term whole. Nothing here
+    depends on which of F, k, L is large: the biggest arrays are
+    [B, L, D] and [B, F, D].
     """
     with jax.named_scope("interaction"):
         F, D = field_num, params.shape[-1]
         k = (D - 1) // F
-        major = np.arange(F * k).reshape(F, k).T.ravel()   # κ*F+f <- f*k+κ
-        params = jnp.dot(
-            params, np.eye(D, dtype=params.dtype)[:, np.append(major, D - 1)],
-            precision=_F32)
-    rows = expand_rows(params, local_idx)          # [B, L, D], factor-major
+        Fp = _padded_fields(F, k)
+        params = jnp.dot(params, _factor_major(F, k, Fp, params.dtype),
+                         precision=_F32)
+    rows = expand_rows(params, local_idx)          # [B, L, k*Fp+1]
+    return _ffm_interaction(Fp, rows, fields, vals)
+
+
+def _padded_fields(F: int, k: int) -> int:
+    """F rounded up to the sublane count 8 where the row ``k*F + 1``
+    stays on as many 128-lane lines as before (22 -> 24 at k = 4: 89
+    and 97 columns are one line each), else F."""
+    Fp = -(-F // 8) * 8
+    return Fp if -(-(k * Fp + 1) // 128) == -(-(k * F + 1) // 128) else F
+
+
+def _factor_major(F: int, k: int, Fp: int, dtype) -> np.ndarray:
+    """The 0/1 matrix ``[F*k+1, k*Fp+1]`` that puts column ``f*k + κ``
+    of a table row at ``κ*Fp + f`` and w last; the columns of the
+    ``Fp - F`` fields no feature has stay zero."""
+    m = np.zeros((F * k + 1, k * Fp + 1), dtype)
+    f, kappa = np.divmod(np.arange(F * k), k)
+    m[np.arange(F * k), kappa * Fp + f] = 1.0
+    m[-1, -1] = 1.0
+    return m
+
+
+def _own_columns(F: int, k: int, fields: jax.Array) -> jax.Array:
+    """``[B, L, k*F+1]``: the columns a cell uses against its own field
+    (column κ*F+f belongs to field f; the last, w, to none)."""
+    return np.append(np.tile(np.arange(F), k), -1) == fields[..., None]
+
+
+def _ffm_forward(F: int, rows: jax.Array, fields: jax.Array,
+                 vals: jax.Array):
+    """Scores ``[B]`` and P ``[B, F, D]`` of ``ffm_batch_scores``' maths
+    (F the padded field count, ``rows [B, L, D = k*F+1]`` factor-major)."""
     with jax.named_scope("interaction"):
-        a = jax.nn.one_hot(fields, F, dtype=rows.dtype) * vals[..., None]
-        s = jnp.einsum("blg,blm->bgm", a, rows, precision=_F32)
-        linear = s[:, :, -1].sum(axis=1)
-        slabs = s[:, :, :-1].reshape(-1, F, k, F)  # [b, g, κ, f]
+        B, _, D = rows.shape
+        k = (D - 1) // F
+        xr = rows * vals[..., None]
+        # one_hot is exact in one bf16 pass; x rides the rows, whose
+        # three bf16 parts the right-hand HIGHEST keeps
+        s = jnp.einsum("blg,blm->bgm",
+                       jax.nn.one_hot(fields, F, dtype=rows.dtype), xr,
+                       precision=(lax.Precision.DEFAULT, _F32))
+        slabs = s[:, :, :-1].reshape(B, F, k, F)        # [b, g, κ, f]
         cross = jnp.einsum("bgkf,bfkg->b", slabs, slabs, precision=_F32)
-        # i=j diagonal: the columns each feature uses against its own
-        # field (column κ*F+f belongs to field f; the last to none).
-        own = np.append(np.tile(np.arange(F), k), -1) == fields[..., None]
-        diag = jnp.sum(jnp.where(own, jnp.square(rows * vals[..., None]),
-                                 0.0), axis=(1, 2))
-        return linear + 0.5 * (cross - diag)
+        p = jnp.transpose(slabs, (0, 3, 2, 1)).reshape(B, F, F * k)
+        p = jnp.concatenate([p, jnp.ones((B, F, 1), rows.dtype)], axis=-1)
+        # the linear term and the i=j diagonal in ONE pass over the rows
+        last = np.arange(D) == D - 1
+        rest = jnp.sum(jnp.where(_own_columns(F, k, fields),
+                                 -0.5 * jnp.square(xr),
+                                 jnp.where(last, xr, 0.0)), axis=(1, 2))
+        return rest + 0.5 * cross, p
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _ffm_interaction(F, rows, fields, vals):
+    """FFM scores of whole factor-major rows; differentiable w.r.t.
+    ``rows`` alone, by the VJP ``ffm_batch_scores`` describes. Called
+    outside ``jax.grad`` (the scorers) it is the forward alone."""
+    return _ffm_forward(F, rows, fields, vals)[0]
+
+
+def _ffm_interaction_fwd(F, rows, fields, vals):
+    scores, p = _ffm_forward(F, rows, fields, vals)
+    return scores, (p, rows, fields, vals)
+
+
+def _ffm_interaction_bwd(F, residuals, ds):
+    p, rows, fields, vals = residuals
+    with jax.named_scope("interaction"):
+        k = (rows.shape[-1] - 1) // F
+        xd = vals * ds[:, None]
+        picked = jnp.einsum("blg,bgm->blm",
+                            jax.nn.one_hot(fields, F, dtype=rows.dtype), p,
+                            precision=(lax.Precision.DEFAULT, _F32))
+        grad = picked * xd[..., None] - jnp.where(
+            _own_columns(F, k, fields), rows * (vals * xd)[..., None], 0.0)
+        return grad, None, None     # no cotangent for fields and vals
+
+
+_ffm_interaction.defvjp(_ffm_interaction_fwd, _ffm_interaction_bwd)
 
 
 def batch_reg(params: jax.Array, uniq_ids: jax.Array, vocabulary_size: int,

@@ -140,34 +140,136 @@ def ffm_pairwise_scores(params, field_num, local_idx, fields, vals):
             + 0.5 * (pair * off_diagonal).sum(axis=(1, 2)))
 
 
-def random_ffm_arrays(rng, B, L, field_num, k, U):
-    params = rng.normal(size=(U, field_num * k + 1)).astype(np.float32) * 0.3
+def ffm_einsum_scores(params, field_num, local_idx, fields, vals):
+    """The bucketed body as it stood until PR 55, left to autodiff: the
+    per-field sums S, each factor's [F, F] slab against its own
+    transpose, less the i = j diagonal. Kept HERE, and nowhere in
+    fast_tffm_tpu/, as the second reference of the hand-written VJP."""
+    F, D = field_num, params.shape[-1]
+    k = (D - 1) // F
+    major = np.arange(F * k).reshape(F, k).T.ravel()
+    rows = params[:, np.append(major, D - 1)][local_idx]
+    a = jax.nn.one_hot(fields, F, dtype=rows.dtype) * vals[..., None]
+    s = jnp.einsum("blg,blm->bgm", a, rows)
+    slabs = s[:, :, :-1].reshape(-1, F, k, F)
+    cross = jnp.einsum("bgkf,bfkg->b", slabs, slabs)
+    own = np.append(np.tile(np.arange(F), k), -1) == fields[..., None]
+    diag = jnp.sum(jnp.where(own, jnp.square(rows * vals[..., None]), 0.0),
+                   axis=(1, 2))
+    return s[:, :, -1].sum(axis=1) + 0.5 * (cross - diag)
+
+
+def random_ffm_arrays(rng, B, L, field_num, k, U, dtype=np.float32):
+    params = rng.normal(size=(U, field_num * k + 1)).astype(dtype) * 0.3
     local_idx = rng.integers(0, U, size=(B, L)).astype(np.int32)
     fields = rng.integers(0, field_num, size=(B, L)).astype(np.int32)
-    vals = rng.normal(size=(B, L)).astype(np.float32)
+    vals = rng.normal(size=(B, L)).astype(dtype)
     return params, local_idx, fields, vals
 
 
-@pytest.mark.parametrize("field_num,k,L", FFM_SHAPES)
-def test_ffm_gradient_matches_pairwise_reference(rng, field_num, k, L):
-    B, U = 6, 40
-    params, local_idx, fields, vals = random_ffm_arrays(rng, B, L, field_num,
-                                                        k, U)
-    cot = rng.normal(size=B).astype(np.float32)   # d loss / d score
+def _fields_of(how, rng, B, L, F):
+    """The cells' fields of a case of ``FFM_VJP_CASES``."""
+    if how == "random":
+        return rng.integers(0, F, size=(B, L))
+    if how == "one field an example":       # every cell of an example in one
+        return np.repeat(rng.integers(0, F, size=(B, 1)), L, axis=1)
+    assert how == "a field no cell has" and F > 1
+    missing = rng.integers(0, F, size=(B, 1))
+    return (missing + rng.integers(1, F, size=(B, L))) % F
 
-    def summed(score_fn):
-        return lambda p: (score_fn(p, field_num, local_idx, fields, vals)
-                          * cot).sum()
 
-    with jax.default_matmul_precision("highest"):
-        want_s = ffm_pairwise_scores(params, field_num, local_idx, fields,
-                                     vals)
-        want_g = jax.grad(summed(ffm_pairwise_scores))(params)
-    got_s = ffm_batch_scores(params, field_num, local_idx, fields, vals)
-    got_g = jax.grad(summed(ffm_batch_scores))(params)
-    np.testing.assert_allclose(got_s, want_s, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(got_g, want_g, rtol=1e-5,
-                               atol=1e-5 * float(np.abs(want_g).max()))
+# (F, k, L, how the fields are drawn): the shapes of FFM_SHAPES, then D
+# no multiple of 8 (15, 13), F = 1, k = 1, L over and under F, a field
+# no cell of an example has, every cell of an example in one field.
+FFM_VJP_CASES = [(F, k, L, "random") for F, k, L in FFM_SHAPES] + [
+    (7, 2, 8, "random"), (1, 4, 8, "random"), (6, 1, 8, "random"),
+    (3, 4, 16, "random"), (22, 4, 8, "random"),
+    (5, 3, 8, "a field no cell has"), (22, 4, 24, "a field no cell has"),
+    (5, 3, 8, "one field an example"), (22, 4, 24, "one field an example"),
+    (1, 1, 4, "random"),
+    # fields NOT padded: 30 x 4 + 1 = 121 columns are one 128-lane line
+    # and 32 x 4 + 1 would be two; 8 fields are their own sublane count
+    (30, 4, 8, "random"), (8, 2, 8, "a field no cell has")]
+
+
+@pytest.mark.parametrize("F,k,padded", [
+    (22, 4, 24), (39, 4, 40), (3, 4, 8), (1, 1, 8), (8, 2, 8), (24, 5, 24),
+    (30, 4, 30), (31, 4, 31), (63, 2, 63), (33, 4, 40)])
+def test_ffm_fields_pad_to_a_sublane_multiple_only_on_the_same_lines(F, k,
+                                                                      padded):
+    """``_padded_fields``: F goes up to a multiple of 8 (so that the
+    batch-minor split ``[F, k*F, B]`` → ``[F, k, F, B]`` is a bitcast)
+    where the row ``k*F + 1`` keeps its count of 128-lane lines, and
+    stays where a line more would double what ``expand`` moves."""
+    from fast_tffm_tpu.ops.interaction import _factor_major, _padded_fields
+    assert _padded_fields(F, k) == padded
+    m = _factor_major(F, k, padded, np.float32)
+    assert m.shape == (F * k + 1, k * padded + 1)
+    assert (m.sum(axis=1) == 1).all() and m.sum() == F * k + 1
+    row = np.arange(F * k + 1, dtype=np.float32)    # column f*k+κ holds f*k+κ
+    moved = row @ m
+    for f in range(F):
+        for kappa in range(k):
+            assert moved[kappa * padded + f] == f * k + kappa
+    assert moved[-1] == F * k
+    np.testing.assert_array_equal(moved @ m.T, row)     # and back
+
+
+@pytest.mark.parametrize("reference", ["pairwise", "einsum"])
+@pytest.mark.parametrize("field_num,k,L,how", FFM_VJP_CASES)
+def test_ffm_vjp_matches_autodiff_of_a_reference(rng, field_num, k, L, how,
+                                                 reference):
+    """``ffm_batch_scores``' hand-written VJP (ISSUE 55) against
+    autodiff of two references that share no code with it: scores, and
+    the gradient w.r.t. a row of every cell under a random cotangent
+    (``params[b*L + l]`` IS the row of cell (b, l)), and the gradient
+    w.r.t. shared slots, where the segment-sum adds cells up."""
+    score_fn = {"pairwise": ffm_pairwise_scores,
+                "einsum": ffm_einsum_scores}[reference]
+    B = 6
+    fields = _fields_of(how, rng, B, L, field_num).astype(np.int32)
+    vals = rng.normal(size=(B, L)).astype(np.float32)
+    vals[rng.random(size=(B, L)) < 0.2] = 0.0          # pad cells, anywhere
+    cot = rng.normal(size=B).astype(np.float32)        # d loss / d score
+    own_rows = np.arange(B * L, dtype=np.int32).reshape(B, L)
+    shared = rng.integers(0, 40, size=(B, L)).astype(np.int32)
+    for local_idx, U in ((own_rows, B * L), (shared, 40)):
+        params = (rng.normal(size=(U, field_num * k + 1)) * 0.3).astype(
+            np.float32)
+
+        def summed(fn):
+            return lambda p: (fn(p, field_num, local_idx, fields, vals)
+                              * cot).sum()
+
+        with jax.default_matmul_precision("highest"):
+            want_s = score_fn(params, field_num, local_idx, fields, vals)
+            want_g = jax.grad(summed(score_fn))(params)
+        got_s = ffm_batch_scores(params, field_num, local_idx, fields, vals)
+        got_g = jax.grad(summed(ffm_batch_scores))(params)
+        np.testing.assert_allclose(got_s, want_s, rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(got_g, want_g, rtol=1e-5,
+                                   atol=1e-5 * float(np.abs(want_g).max()))
+        assert np.abs(np.asarray(want_g)).max() > 0.0
+
+
+@pytest.mark.parametrize("field_num,k,L,how", [
+    (3, 4, 8, "random"), (7, 2, 5, "random"), (1, 3, 4, "random"),
+    (5, 1, 6, "a field no cell has"), (4, 2, 6, "one field an example")])
+def test_ffm_vjp_first_order_in_float64(rng, field_num, k, L, how):
+    """``jax.test_util.check_grads`` in float64 on the CPU, reverse mode,
+    first order: the VJP against central differences of the function
+    itself, where float32 against a reference shows 1e-5."""
+    from jax.test_util import check_grads
+    B, U = 3, 12
+    with jax.enable_x64():
+        params, local_idx, _, vals = random_ffm_arrays(
+            rng, B, L, field_num, k, U, dtype=np.float64)
+        fields = _fields_of(how, rng, B, L, field_num).astype(np.int32)
+        vals[:, -1] = 0.0
+        check_grads(
+            lambda p: ffm_batch_scores(p, field_num, local_idx, fields, vals),
+            (jnp.asarray(params),), order=1, modes=["rev"], atol=1e-7,
+            rtol=1e-7)
 
 
 def test_ffm_padded_slots_and_zero_weight_rows_are_exact_zeros(rng):

@@ -495,3 +495,159 @@ def test_v5e_fm_grad_keeps_the_expanded_rows_whole(one_chip, order, B, L,
     backward = [ln for ln in copies if "transpose(jvp(" in ln]
     assert len(backward) <= 1 and len(copies) - len(backward) <= 1, \
         [ln[:160] for ln in copies]
+
+
+# ---- ffm_batch_scores' hand-written VJP (ISSUE 55) --------------------------
+
+# B, L, U, F, k of ffm4-train-zipf; the interaction pads F to 24, so its
+# arrays are [8192, 24, 96 | 97] (and [8192, 24, 4, 24] for the swap).
+FFM_CELL = (8192, 24, 10240, 22, 4)
+_ASYNC = ("copy-start", "copy-done", "slice-start", "slice-done",
+          "async-start", "async-done")
+
+
+def _entry_ops(text):
+    """(name, result, opcode, op path, line) of every operation of the
+    ENTRY computation: the passes the chip runs one after another."""
+    entry = text[text.index("ENTRY "):]
+    ops = []
+    for ln in entry.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\(", ln)
+        if m:
+            path = re.search(r'op_name="([^"]*)"', ln)
+            ops.append((m.group(1), m.group(2), m.group(3),
+                        path.group(1) if path else "", ln))
+    return ops
+
+
+@pytest.fixture(scope="module")
+def ffm_programs(one_chip):
+    """The gradient of a logistic loss through ``ffm_batch_scores`` and
+    the forward-only scorer, compiled for a described v5e at the
+    cell's size (7 s each)."""
+    from fast_tffm_tpu.ops.interaction import ffm_batch_scores
+    B, L, U, F, k = FFM_CELL
+
+    def scores(params, local_idx, fields, vals):
+        return ffm_batch_scores(params, F, local_idx, fields, vals)
+
+    def loss(params, local_idx, fields, vals, labels):
+        s = scores(params, local_idx, fields, vals)
+        return (jax.nn.softplus(s) - labels * s).sum()
+
+    def sd(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    args = (sd((U, F * k + 1), jnp.float32), sd((B, L), jnp.int32),
+            sd((B, L), jnp.int32), sd((B, L), jnp.float32))
+    with _no_persistent_cache():
+        grad = jax.jit(jax.grad(loss)).lower(
+            *args, sd((B,), jnp.float32)).compile().as_text()
+        forward = jax.jit(scores).lower(*args).compile().as_text()
+    return grad, forward
+
+
+def _slices_of_the_expanded_rows(text):
+    """Every ``slice`` of what ``expand``'s gather wrote, as an
+    operation of its own or inside a fusion that is handed the rows
+    (S has the rows' shape at this cell, 24 fields on 24 cells, so the
+    shape alone does not tell them apart)."""
+    ops = _entry_ops(text)
+    operands = {op[0]: re.findall(r"%([^,\s)]+)", op[4].split("(", 1)[1]
+                                  .split("), ")[0]) for op in ops}
+    rows = {op[0] for op in ops
+            if re.search(r"jvp\(expand\)/gather|[(/]expand/gather", op[3])}
+    assert rows, "expand's gather is in the program"
+    for op in ops:       # the same bytes under another name
+        if op[2] in ("bitcast", "copy", "reshape", "get-tuple-element") \
+                + _ASYNC and operands[op[0]][:1] and \
+                operands[op[0]][0] in rows:
+            rows.add(op[0])
+    found = []
+    for name, _, opcode, _, line in ops:
+        mine = [i for i, o in enumerate(operands[name]) if o in rows]
+        if not mine or name in rows:
+            continue
+        if opcode in ("slice", "dynamic-slice"):
+            found.append(line.strip()[:160])
+        called = re.search(r"calls=%(\S+?)[,\s]", line)
+        if opcode == "fusion" and called:
+            body = text[text.index(f"%{called.group(1)} ("):]
+            body = body[:body.index("\n}")]
+            for i in mine:
+                param = re.search(rf"%(\S+) = \S+ parameter\({i}\)", body)
+                found += [ln.strip()[:160] for ln in body.splitlines()
+                          if re.search(rf"slice\(%{re.escape(param.group(1))}"
+                                       r"[,)]", ln)]
+    return found
+
+
+def _sums(result):
+    """Whether a result is one of the interaction's per-field arrays,
+    S, P or the swap's ``[B, F, k, F]``, and whether it is batch-minor
+    (dimension 0 the minor-most: ``{0,2,1}``, ``{0,3,2,1}``, ...)."""
+    m = re.match(r"f32\[8192,(2[24]),(?:(8[89]|9[67])|4,(2[24]))\]"
+                 r"\{(\d)", result)
+    return (bool(m), bool(m) and m.group(4) == "0")
+
+
+def test_v5e_ffm_grad_swaps_the_fields_once_and_forward(ffm_programs):
+    """What the hand-written VJP is for, read off the compiled gradient
+    program (a compile says nothing of times; beside each assertion the
+    chip reading it guards, PERF.md section 6, PR 55):
+
+    - ONE trip through batch-minor, forward: one array of S's shape
+      there, the swap's ``[B, F, k, F]`` beside it, one copy back (P),
+      and NOTHING batch-minor under ``transpose(jvp(interaction))``
+      (the parent's backward walked dS there and back in five passes,
+      0.82 of ``interaction_ms`` + ``step_unscoped_ms`` 2.92);
+    - no ``reshape`` pass of the sums (F padded to 24 makes the split
+      ``[F, k*F, B]`` → ``[F, k, F, B]`` a bitcast: 0.20 ms each on the
+      chip at F = 22) and no ``pad`` of them (the ones column is a
+      fused operand of the backward's matmul, not a pass of 0.21 ms);
+    - nothing slices the expanded rows (the linear term rides the
+      diagonal's pass; read off S it was 0.16 ms);
+    - every pass over the sums or the rows carries ``interaction`` or
+      ``expand`` on its op path, so the trace's scopes place it
+      (``step_unscoped_ms``; the compiler's own asynchronous copies
+      between its two memories have no path and are left out: 0.017 ms
+      on the chip, the rows' eviction)."""
+    grad, _ = ffm_programs
+    B, L, U, F, k = FFM_CELL
+    ops = [op for op in _entry_ops(grad) if op[2] not in _ASYNC
+           and op[2] not in ("bitcast", "get-tuple-element", "parameter")]
+    sums = [op for op in ops if _sums(op[1])[0]]
+    assert len(sums) >= 4, "the per-field sums are in the program"
+    backward = [op for op in sums if "transpose(jvp(" in op[3]]
+    assert not [op[4][:160] for op in backward if _sums(op[1])[1]]
+    forward_minor = [op for op in sums if _sums(op[1])[1]
+                     and "transpose(jvp(" not in op[3]]
+    three_d = [op for op in forward_minor if op[1].count(",") == 4]
+    assert len(three_d) <= 1 and len(forward_minor) <= 2, \
+        [op[4][:160] for op in forward_minor]
+    back = [op for op in sums if op[2] == "copy" and not _sums(op[1])[1]]
+    assert len(back) <= 1, [op[4][:160] for op in back]
+    assert not [op[4][:160] for op in sums
+                if op[2] in ("reshape", "pad") or op[0].startswith("pad")]
+    rows = (f"f32[{B},{L},", f"f32[{B * L},")
+    assert not _slices_of_the_expanded_rows(grad)
+    unplaced = [op[4][:200] for op in ops
+                if (_sums(op[1])[0] or op[1].startswith(rows))
+                and not re.search(r"interaction|expand", op[3])]
+    assert not unplaced, unplaced
+
+
+def test_v5e_ffm_scorer_makes_no_field_transpose(ffm_programs):
+    """The forward-only scorer (validation, predict, serve) runs the
+    primal alone: P, the VJP's residual, is dead code there. Its
+    program takes S to batch-minor once, for the cross term, as it did
+    before PR 55, and brings nothing back: no swap's ``[B, F, k, F]``
+    array and no copy of the sums to row-major (0.24 of the train
+    step's forward on the chip: the swap and the copy back)."""
+    _, forward = ffm_programs
+    sums = [op for op in _entry_ops(forward)
+            if op[2] not in _ASYNC + ("bitcast",) and _sums(op[1])[0]]
+    assert sums, "the per-field sums are in the program"
+    minor = [op for op in sums if _sums(op[1])[1]]
+    assert len(minor) <= 1, [op[4][:160] for op in minor]
+    assert not [op[4][:160] for op in sums
+                if op[2] == "copy" and not _sums(op[1])[1]]
